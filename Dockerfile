@@ -41,7 +41,7 @@ WORKDIR /app
 COPY pyproject.toml ./
 COPY opsagent_tpu ./opsagent_tpu
 COPY configs ./configs
-COPY --from=builder /src/opsagent_tpu/native/_native.so ./opsagent_tpu/native/_native.so
+COPY --from=builder /src/opsagent_tpu/native/_native-*.so ./opsagent_tpu/native/
 
 # Agent runtime deps. jax[cpu] serves the agent layers; TPU pods get the
 # TPU jaxlib from their node image / a requirements overlay.

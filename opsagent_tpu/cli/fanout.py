@@ -42,10 +42,16 @@ def run_audit_fanout(
     from ..serving.engine import Engine, EngineConfig
     from ..serving.fleet.router import FleetRouter
 
-    on_tpu = jax.default_backend() == "tpu"
+    # The replicas are N engines in THIS process — the one process that
+    # may hold the chip — and each is sized below as if it had the device
+    # to itself: what they run on, at what dtype, and how many share it
+    # is part of the stats line, and the device's allocator refuses the
+    # sum if it does not fit.
+    dev = jax.devices()[0]
+    dtype = jnp.bfloat16 if dev.platform == "tpu" else jnp.float32
     cfg = EngineConfig(
         model=model,
-        dtype=jnp.bfloat16 if on_tpu else jnp.float32,
+        dtype=dtype,
         max_batch_size=8,
         page_size=16,
         num_pages=2048,
@@ -82,5 +88,10 @@ def run_audit_fanout(
         print(json.dumps(rep.report, indent=2))
     stats = dict(rep.stats)
     stats["recall"] = rep.recall(cluster)
+    stats["device"] = {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(jax.devices()), "dtype": jnp.dtype(dtype).name,
+        "engines_sharing": len(stacks),
+    }
     print(json.dumps({"fanout_stats": stats}), file=sys.stderr)
     return 0 if stats["outcomes"].get("ok", 0) == resources else 1

@@ -179,21 +179,23 @@ def init_params(
 
 def init_params_random_quantized(
     cfg: ModelConfig, seed: int, dtype: jnp.dtype = jnp.bfloat16,
-    mode: str = "int8",
+    mode: str = "int8", out_shardings: Any = None,
 ) -> Params:
     """Random weights built DIRECTLY in the quantized serving form
     (models.quant.QuantizedLinear, or QuantizedLinear4 with
     ``mode="int4"``), ON DEVICE, without ever materializing a
     full-precision tree and without any bulk host->device transfer.
+    ``out_shardings`` (a pytree of shardings matching the result) makes
+    every leaf come into being sharded, so the tree never has to fit one
+    device; the values do not depend on it.
 
     Why both constraints matter at 8B scale:
     - ``init_params`` + ``quantize_params`` needs a full-precision tree
       (16 GB bf16 + f32 intermediates) that does not fit a 16 GB v5e chip,
       and on the host backend the threefry RNG takes tens of minutes.
     - Host-side numpy generation is fast, but then 8+ GB of weights must
-      cross the host->device link; on tunneled/remote-device setups that
-      transfer is the bottleneck (or worse). Generating on device moves
-      only PRNG keys.
+      cross the host->device link. Generating on device moves only PRNG
+      keys.
 
     Benchmarks and smoke runs only need *plausible* weights: q is uniform
     int8 with a constant per-tensor scale chosen so the dequantized std
@@ -202,7 +204,9 @@ def init_params_random_quantized(
     filled with ``lax.map`` over per-layer keys so peak transient memory
     is one layer slice, not a full-tensor wide intermediate.
     """
-    from .quant import QuantizedLinear, QuantizedLinear4, pack_int4
+    from .quant import (
+        INT4_GROUP, QuantizedLinear, QuantizedLinear4, _group_size, pack_int4,
+    )
 
     int4 = mode == "int4"
 
@@ -230,10 +234,13 @@ def init_params_random_quantized(
         else:
             q = gen(key)
         if int4:
-            # ONE whole-axis scale group (random weights need no locality):
+            # The group layout a quantized checkpoint has (one scale row
+            # per ``_group_size`` contraction rows), so the random tree
+            # moves the same scale bytes and runs the same kernels;
             # std(U[-7,7]) = 7/sqrt3, matched to init_params' fan-in std.
             s = float(fan_in**-0.5) * (3.0**0.5) / 7.0
-            scale = jnp.full(lead + (1, 1, mat[-1]), s, jnp.float32)
+            groups = mat[0] // _group_size(mat[0], INT4_GROUP)
+            scale = jnp.full(lead + (groups, 1, mat[-1]), s, jnp.float32)
             return QuantizedLinear4(q, scale)
         s = float(fan_in**-0.5) * (3.0**0.5) / 127.0
         scale = jnp.full(lead + (1, mat[-1]), s, jnp.float32)
@@ -250,7 +257,9 @@ def init_params_random_quantized(
             ),
         )
 
-    return jax.jit(build)(jax.random.PRNGKey(seed))
+    return jax.jit(build, out_shardings=out_shardings)(
+        jax.random.PRNGKey(seed)
+    )
 
 
 def _attn_block_specs(cfg: ModelConfig) -> Params:
@@ -426,47 +435,10 @@ def _ep_constrain(x: jax.Array, spec: P) -> jax.Array:
     rather than left to GSPMD propagation (which is free to all-gather
     the expert weights instead, defeating the memory scale-out).
 
-    The ``with mesh:`` context every caller uses (trainer/engine) is
-    ``pxla.thread_resources`` under the hood — read at TRACE time; the
-    accessor is deprecated but there is no public replacement readable
-    inside jit (``get_mesh`` forbids it, ``get_abstract_mesh`` is only
-    populated by ``use_mesh``, which this codebase does not adopt)."""
-    import warnings
-
-    mesh = None
-    try:
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            from jax.interpreters import pxla
-
-            m = pxla.thread_resources.env.physical_mesh
-        if not m.empty:
-            mesh = m
-    except Exception:  # pragma: no cover - accessor removed upstream
-        mesh = None
-    if mesh is None:
-        am = None
-        try:
-            am = jax.sharding.get_abstract_mesh()
-        except AttributeError:
-            # jax 0.4.x keeps the accessor private; same thread-local.
-            try:
-                from jax._src.mesh import get_abstract_mesh
-
-                am = get_abstract_mesh()
-            except Exception:  # pragma: no cover - accessor moved again
-                am = None
-        if am is not None and getattr(am, "axis_names", ()):
-            mesh = am
-    if (
-        mesh is not None
-        and "ep" in mesh.axis_names
-        and dict(mesh.shape).get("ep", 1) > 1
-    ):
-        if isinstance(mesh, jax.sharding.Mesh):
-            return jax.lax.with_sharding_constraint(
-                x, jax.sharding.NamedSharding(mesh, spec)
-            )
+    The ambient mesh is the one ``jax.set_mesh`` installs (the trainer's
+    step runs under it); it is readable at trace time."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if dict(mesh.shape).get("ep", 1) > 1:
         return jax.lax.with_sharding_constraint(x, spec)
     return x
 
@@ -522,6 +494,30 @@ def _mm(x: jax.Array, w: Any) -> jax.Array:
                 return y.reshape(*lead, y.shape[-1])
         return x @ w.dequantize().astype(x.dtype)
     return x @ w
+
+
+def weight_stream_leaf_paths(params: Params) -> dict[str, int]:
+    """How many quantized leaves ``_mm`` routes through the Pallas
+    weight-stream kernel and how many it leaves on the XLA dequant under
+    ``weight_stream="pallas-dma"`` — the same ``supports`` test, applied
+    to each leaf as the layer scan presents it (stacked leaves lose their
+    leading layer axis)."""
+    from ..ops import quant_matmul_pallas as qmp
+    from .quant import QuantizedBase
+
+    def is_q(x):
+        return isinstance(x, QuantizedBase)
+
+    counts = {"pallas-dma": 0, "xla": 0}
+    for key, sub in params.items():
+        if key in ("layers", "moe_layers"):
+            sub = jax.eval_shape(
+                lambda t: jax.tree.map(lambda a: a[0], t), sub
+            )
+        for leaf in jax.tree.leaves(sub, is_leaf=is_q):
+            if is_q(leaf):
+                counts["pallas-dma" if qmp.supports(leaf) else "xla"] += 1
+    return counts
 
 
 def _ein(sub: str, x: jax.Array, w: Any) -> jax.Array:

@@ -7,15 +7,19 @@ matcher (fsm_matcher.cc) — eagerly precomputed [states x vocab] token
 admissibility + destination tables with O(row-copy) per-step cost.
 
 Build story: no pybind11 in this image, so the module is a flat C ABI
-compiled on first use with g++ into ``_native.so`` next to the sources
-(skipped when already fresh). Everything degrades gracefully: if g++ or the
-build is unavailable, callers fall back to the pure-Python implementations
-(`OPSAGENT_NATIVE=0` forces the fallback).
+compiled on first use with g++ into ``_native-<digest>.so`` next to the
+sources, the digest being that of ``fsm_matcher.cc`` as it stands: a
+library built from other source text has another name and is never
+loaded, whatever its mtime. If g++ or the build is unavailable, callers
+use the pure-Python implementations (`OPSAGENT_NATIVE=0` forces that);
+``impl()`` says which one serves, and ``/healthz`` reports it.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -28,17 +32,23 @@ log = get_logger("native")
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "fsm_matcher.cc")
-_SO = os.path.join(_DIR, "_native.so")
 
 _lib = None
 _lib_lock = threading.Lock()
 _build_failed = False
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"_native-{digest}.so")
+
+
+def _build(so: str) -> bool:
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-pthread",
-        "-o", _SO, _SRC,
+        "-o", tmp, _SRC,
     ]
     try:
         proc = subprocess.run(
@@ -53,15 +63,29 @@ def _build() -> bool:
             proc.stderr[-500:],
         )
         return False
+    os.replace(tmp, so)  # atomic: a concurrent loader never sees half a file
+    for stale in glob.glob(os.path.join(_DIR, "_native*.so")):
+        if stale != so:
+            try:
+                os.remove(stale)
+            except OSError:
+                pass
     return True
 
 
 def build_native() -> None:
     """Ahead-of-time build entry point (Docker image build / CI): compile
-    ``_native.so`` now and fail loudly, instead of the lazy build-on-first-
+    the library now and fail loudly, instead of the lazy build-on-first-
     use with graceful fallback that ``get_lib`` does at runtime."""
-    if not _build():
+    if not _build(_so_path()):
         raise RuntimeError("native build failed (see log for compiler output)")
+
+
+def impl() -> str:
+    """Which FSM-table implementation serves constrained requests in this
+    process: "native" (the C++ library, built from the source as it
+    stands) or "python" (the lazy numpy path)."""
+    return "native" if get_lib() is not None else "python"
 
 
 def get_lib():
@@ -74,14 +98,12 @@ def get_lib():
     with _lib_lock:
         if _lib is not None or _build_failed:
             return _lib
-        fresh = os.path.exists(_SO) and (
-            os.path.getmtime(_SO) >= os.path.getmtime(_SRC)
-        )
-        if not fresh and not _build():
+        so = _so_path()
+        if not os.path.exists(so) and not _build(so):
             _build_failed = True
             return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
         except OSError as e:
             log.warning("native load failed (%s); using Python fallback", e)
             _build_failed = True
@@ -105,7 +127,7 @@ def get_lib():
         lib.opsagent_fsm_free.restype = None
         lib.opsagent_fsm_free.argtypes = [ctypes.c_void_p]
         _lib = lib
-        log.info("native runtime loaded (%s)", _SO)
+        log.info("native runtime loaded (%s)", so)
         return _lib
 
 

@@ -44,10 +44,10 @@ request).
 from __future__ import annotations
 
 import math
-import os
 import threading
 import time
 from collections import deque
+from dataclasses import dataclass
 from typing import Any
 
 from .metrics import get_registry
@@ -83,8 +83,9 @@ ATTR_DISPATCHES = _reg.counter(
 )
 ATTR_MODELED_STEP_SECONDS = _reg.gauge(
     "opsagent_attr_modeled_step_seconds",
-    "Roofline-modeled wall time of the most recent dispatch "
-    "(modeled bytes / configured HBM bandwidth)",
+    "Roofline-modeled wall time of the most recent dispatch (modeled "
+    "bytes / the device's published HBM bandwidth, DEVICE_PEAKS); not "
+    "emitted on a device without published peaks",
 )
 ATTR_MEASURED_STEP_SECONDS = _reg.histogram(
     "opsagent_attr_measured_step_seconds",
@@ -103,12 +104,14 @@ ATTR_MODEL_DRIFT = _reg.gauge(
 ATTR_MFU = _reg.gauge(
     "opsagent_attr_mfu",
     "Model FLOP utilization over the rate window: modeled useful FLOP/s "
-    "divided by OPSAGENT_PEAK_TFLOPS (default 197, v5e bf16)",
+    "divided by the device's published bf16 peak (DEVICE_PEAKS, keyed by "
+    "device_kind); not emitted on a device without published peaks",
 )
 ATTR_HBM_UTIL = _reg.gauge(
     "opsagent_attr_hbm_utilization",
     "Modeled HBM-bandwidth utilization over the rate window: modeled "
-    "bytes/s divided by OPSAGENT_HBM_GBPS (default 820, v5e)",
+    "bytes/s divided by the device's published HBM bandwidth "
+    "(DEVICE_PEAKS); not emitted on a device without published peaks",
 )
 GOODPUT_SECONDS = _reg.counter(
     "opsagent_goodput_seconds_total",
@@ -120,21 +123,36 @@ GOODPUT_SECONDS = _reg.counter(
     labelnames=("phase",),
 )
 
-_ENV_HBM = "OPSAGENT_HBM_GBPS"
-_ENV_TFLOPS = "OPSAGENT_PEAK_TFLOPS"
-DEFAULT_HBM_GBPS = 820.0      # v5e HBM bandwidth (PERF.md roofline)
-DEFAULT_PEAK_TFLOPS = 197.0   # v5e bf16 peak
+@dataclass(frozen=True)
+class DevicePeaks:
+    hbm_gbps: float       # HBM bandwidth, GB/s per chip
+    bf16_tflops: float    # dense bf16 peak, TFLOP/s per chip
+    source: str
+
+
+# Published per-chip peaks, keyed by the ``device_kind`` JAX reports. The
+# one place a roofline denominator comes from: a device that is not here
+# has none, and is an error (``device_peaks``), never a v5e default.
+DEVICE_PEAKS: dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        hbm_gbps=819.0, bf16_tflops=197.0,
+        source='Google Cloud documentation, "TPU v5e"',
+    ),
+}
 RATE_WINDOW_S = 60.0
 
 _BYTE_KINDS = ("weights", "weights_prefetch", "kv_read", "kv_write", "other")
 
 
-def _env_float(name: str, default: float) -> float:
+def device_peaks(device_kind: str) -> DevicePeaks:
     try:
-        v = float(os.environ.get(name, ""))
-        return v if v > 0 else default
-    except ValueError:
-        return default
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device_kind {device_kind!r}: add it "
+            f"to obs.attribution.DEVICE_PEAKS with its source (known: "
+            f"{sorted(DEVICE_PEAKS)})"
+        ) from None
 
 
 def prefill_attn_positions(start: int, chunk: int) -> int:
@@ -166,9 +184,12 @@ class Attribution:
         kv_quantize: str = "",
         weight_stream: str = "",
         mla_latent_dim: int = 0,
-        hbm_gbps: float | None = None,
-        peak_tflops: float | None = None,
+        device_kind: str | None = None,
     ):
+        """``device_kind``: the accelerator whose published peaks
+        (``DEVICE_PEAKS``) price time and utilization; None (the CPU)
+        keeps the byte/FLOP counts and emits no modeled time, MFU, HBM
+        utilization or drift."""
         self.num_params = int(num_params)
         self.num_layers = num_layers
         self.num_heads = num_heads
@@ -204,9 +225,11 @@ class Attribution:
         # "other": the logits each sampled row materializes (f32 [V] per
         # query token that reaches the sampler).
         self.logits_bytes = vocab_size * 4
-        self.hbm_bytes_s = _env_float(_ENV_HBM, hbm_gbps or DEFAULT_HBM_GBPS) * 1e9
+        self.device_kind = device_kind
+        peaks = None if device_kind is None else device_peaks(device_kind)
+        self.hbm_bytes_s = None if peaks is None else peaks.hbm_gbps * 1e9
         self.peak_flops_s = (
-            _env_float(_ENV_TFLOPS, peak_tflops or DEFAULT_PEAK_TFLOPS) * 1e12
+            None if peaks is None else peaks.bf16_tflops * 1e12
         )
         self._lock = threading.Lock()
         self._window: deque[tuple[float, float, float]] = deque()
@@ -223,12 +246,14 @@ class Attribution:
         ``weight_stream`` is the engine's RESOLVED impl ("xla" or
         "pallas-dma"), not the raw config string — the engine passes it
         after applying its own fallback gates."""
+        import jax
         import numpy as np
 
         try:
             dtype_bytes = int(np.dtype(engine_cfg.dtype).itemsize)
         except TypeError:
             dtype_bytes = 2
+        dev = jax.devices()[0]
         mla = getattr(model_cfg, "mla", None)
         latent = (
             mla.latent_dim if mla is not None and mla.latent_cache else 0
@@ -245,6 +270,7 @@ class Attribution:
             kv_quantize=getattr(engine_cfg, "kv_quantize", ""),
             weight_stream=weight_stream,
             mla_latent_dim=latent,
+            device_kind=None if dev.platform == "cpu" else dev.device_kind,
         )
 
     # -- pricing -------------------------------------------------------------
@@ -259,8 +285,9 @@ class Attribution:
         copy_bytes: float = 0.0,
     ) -> dict[str, float]:
         """The closed-form arithmetic: bytes by kind, FLOPs, and the
-        bandwidth-roofline modeled seconds for one dispatch. Pure — the
-        unit tests drive this directly against hand arithmetic."""
+        bandwidth-roofline modeled seconds for one dispatch (None without
+        published peaks). Pure — the unit tests drive this directly
+        against hand arithmetic."""
         b_weights = weight_streams * self.weight_stream_bytes
         b_kv_read = kv_read_tokens * self.kv_token_bytes
         b_kv_write = kv_write_tokens * self.kv_token_bytes
@@ -278,9 +305,11 @@ class Attribution:
         # their serial bytes-only sum — the same total bytes, but the
         # kernel earns credit for hiding DMA issue latency only up to
         # the bandwidth/compute roofline, never below it.
-        modeled_s = total / self.hbm_bytes_s
-        if overlapped:
-            modeled_s = max(modeled_s, flops / self.peak_flops_s)
+        modeled_s = None
+        if self.hbm_bytes_s is not None:
+            modeled_s = total / self.hbm_bytes_s
+            if overlapped:
+                modeled_s = max(modeled_s, flops / self.peak_flops_s)
         return {
             "weights": 0.0 if overlapped else b_weights,
             "weights_prefetch": b_weights if overlapped else 0.0,
@@ -332,7 +361,11 @@ class Attribution:
                 ATTR_BYTES.inc(c[kind], kind=kind)
             ATTR_STEP_BYTES.set(c[kind], kind=kind)
         ATTR_FLOPS.inc(c["flops"])
-        ATTR_MODELED_STEP_SECONDS.set(c["modeled_s"])
+        modeled_s = c["modeled_s"]
+        if modeled_s is not None:
+            ATTR_MODELED_STEP_SECONDS.set(modeled_s)
+        if measured_s is not None:
+            ATTR_MEASURED_STEP_SECONDS.observe(measured_s, op=op)
         now = time.perf_counter()
         with self._lock:
             self.dispatches += 1
@@ -344,6 +377,8 @@ class Attribution:
                 and now - self._window[0][0] > RATE_WINDOW_S
             ):
                 self._window.popleft()
+            if modeled_s is None:
+                return
             t0, f0, b0 = self._window[0]
             dt = now - t0
             # Materialized even before the window has two points: an
@@ -356,9 +391,8 @@ class Attribution:
                 (self._cum_bytes - b0) / dt / self.hbm_bytes_s
                 if dt > 0 else 0.0
             )
-            if measured_s is not None and c["modeled_s"] > 0:
-                ATTR_MEASURED_STEP_SECONDS.observe(measured_s, op=op)
-                ratio = measured_s / c["modeled_s"]
+            if measured_s is not None and modeled_s > 0:
+                ratio = measured_s / modeled_s
                 if math.isfinite(ratio):
                     ema = self._drift_ema
                     self._drift_ema = (
@@ -377,18 +411,29 @@ class Attribution:
             "weight_stream": self.weight_stream,
             "weight_stream_bytes": round(self.weight_stream_bytes),
             "kv_token_bytes": round(self.kv_token_bytes),
-            "hbm_gbps": round(self.hbm_bytes_s / 1e9, 1),
-            "peak_tflops": round(self.peak_flops_s / 1e12, 1),
+            "device_kind": self.device_kind,
+            "hbm_gbps": (
+                None if self.hbm_bytes_s is None
+                else round(self.hbm_bytes_s / 1e9, 1)
+            ),
+            "peak_tflops": (
+                None if self.peak_flops_s is None
+                else round(self.peak_flops_s / 1e12, 1)
+            ),
             "dispatches": n,
             "bytes_total": round(cum_b),
             "flops_total": round(cum_f),
             "bytes_by_kind": {
                 k: round(ATTR_BYTES.value(kind=k)) for k in _BYTE_KINDS
             },
-            "mfu": round(ATTR_MFU.value(), 6),
-            "hbm_utilization": round(ATTR_HBM_UTIL.value(), 6),
-            "modeled_last_step_s": round(
-                ATTR_MODELED_STEP_SECONDS.value(), 6
+            **(
+                {} if self.hbm_bytes_s is None else {
+                    "mfu": round(ATTR_MFU.value(), 6),
+                    "hbm_utilization": round(ATTR_HBM_UTIL.value(), 6),
+                    "modeled_last_step_s": round(
+                        ATTR_MODELED_STEP_SECONDS.value(), 6
+                    ),
+                }
             ),
             "drift_ema": None if drift is None else round(drift, 3),
         }

@@ -273,13 +273,20 @@ class SLOWatchdog:
                 )
             elif slo.name == "decode_tok_s_chip":
                 rate = self._decode_rate()
-                chips = _chip_count()
-                v["chips"] = chips
-                v["value"] = (
-                    None if rate is None else round(rate / chips, 3)
-                )
                 if rate is None:
+                    v["value"] = None
                     v["note"] = "insufficient data (need a rate window)"
+                else:
+                    # Tokens were decoded, so an engine — and with it a
+                    # JAX backend — is up in this process: its device
+                    # count is the divisor, and the platform rides along
+                    # so a CPU reading is never taken for a chip's.
+                    import jax
+
+                    devs = jax.devices()
+                    v["chips"] = len(devs)
+                    v["platform"] = devs[0].platform
+                    v["value"] = round(rate / len(devs), 3)
             value = v.get("value")
             if value is None:
                 v["pass"] = None
@@ -477,15 +484,6 @@ class SLOWatchdog:
             self._snaps.clear()
         self._breached_since.clear()
         self.take_snapshot()
-
-
-def _chip_count() -> int:
-    try:
-        import jax
-
-        return max(1, len(jax.devices()))
-    except Exception:  # noqa: BLE001
-        return 1
 
 
 _watchdog: SLOWatchdog | None = None

@@ -22,6 +22,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
 
+# Upper bound on one block's f32 score matrix in ``paged_ragged_attention``
+# (its softmax output doubles it).
+SCORE_BLOCK_BYTES = 512 * 1024 * 1024
+
 
 @jax.tree_util.register_pytree_node_class
 class QuantizedPages:
@@ -116,29 +120,73 @@ def pallas_interpret() -> bool:
     bench ragged-backend sweep smoke and CI exercise the pallas /
     pallas-dma dispatch paths end-to-end off-TPU, where a compiled
     pallas_call cannot lower. Read at trace time by the ``*_auto``
-    dispatchers; never set it on real hardware (interpret mode is
-    orders of magnitude slower and skips Mosaic entirely)."""
-    return os.environ.get("OPSAGENT_PALLAS_INTERPRET", "") == "1"
+    dispatchers. On the chip it is an error, not a slow success:
+    interpret mode is orders of magnitude slower and skips Mosaic
+    entirely, so whatever it produced there would carry the kernel's
+    name without having run the kernel."""
+    on = os.environ.get("OPSAGENT_PALLAS_INTERPRET", "") == "1"
+    if on and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "OPSAGENT_PALLAS_INTERPRET=1 on the tpu backend: interpret "
+            "mode is for CPU tests only; unset it"
+        )
+    return on
+
+
+def pallas_refusal(
+    impl: str,
+    *,
+    head_dim: int,
+    kv_heads_per_shard: int,
+    page_itemsize: int,
+    mla: bool = False,
+) -> str | None:
+    """Why the chip's compiler refuses paged-attention backend ``impl``
+    at these shapes, or None when it compiles. Each rule is a refusal
+    Mosaic gave when the kernels were compiled for a described v5e
+    device (tests/test_tpu_compile.py keeps both sides of each rule);
+    the engine raises it at init and the bench sweep skips the cell by
+    it, so no such combination reaches the chip to fail — or fall back —
+    there. Interpret mode has no Mosaic and no such limits.
+
+    ``page_itemsize``: bytes per stored KV element (1 for int8 pages)."""
+    if impl == "xla":
+        return None
+    if mla:
+        return (
+            f"paged backend {impl!r} with an MLA model: the qk head dim "
+            "(nope + rope, e.g. 192) breaks the Pallas kernels' last-dim "
+            "tiling; MLA serves through the xla gather"
+        )
+    if impl == "pallas-dma":
+        if head_dim % 128:
+            return (
+                f"pallas-dma with head_dim {head_dim}: Mosaic requires "
+                "manual-DMA memref slices to be aligned to the lane "
+                "tiling on the minormost dim (\"Slice shape along "
+                "dimension 3 must be aligned to tiling (128)\")"
+            )
+        sublanes = max(1, 4 // page_itemsize)
+        if kv_heads_per_shard % sublanes:
+            return (
+                f"pallas-dma with {kv_heads_per_shard} kv head(s) per "
+                f"shard of {8 * page_itemsize}-bit pages: a page's DMA "
+                "slices the kv-head axis, and Mosaic requires \"Slice "
+                f"shape along dimension 2 must be aligned to tiling "
+                f"({sublanes})\"; use fewer tp shards, the grid kernel "
+                "('pallas') or the xla gather"
+            )
+    return None
 
 
 def _shard_map(fn, mesh: Mesh, in_specs, out_specs):
-    # check_vma/check_rep off: pallas_call does not annotate its outputs'
+    # check_vma off: pallas_call does not annotate its outputs'
     # varying-mesh-axes metadata, and the head axis is fully data-parallel
     # here (no cross-shard reduction to validate anyway).
-    try:
-        from jax import shard_map
-
-        return shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 def _pallas_kernel_fn(impl: str):
@@ -478,6 +526,64 @@ def paged_ragged_attention(
     ``paged_ragged_attention_auto``."""
     k_seq, v_seq = _gather_kv(k_pages, v_pages, page_table, layer, q.dtype)
     B, S, H, _ = q.shape
+    L = k_seq.shape[1]
+    # The f32 score matrix is [B, H, S, L]: at a 4096-token admission
+    # chunk over a 5120-slot table it alone outgrows the chip beside the
+    # weights. Walk it in (batch, query) blocks of bounded size; every
+    # query row still sees all L keys at once, so a row's softmax and
+    # its reductions are unchanged.
+    rows = max(1, SCORE_BLOCK_BYTES // (4 * H * L))
+    s_blk = _largest_divisor(S, rows)
+    b_blk = _largest_divisor(B, max(1, rows // s_blk))
+    if s_blk == S and b_blk == B:
+        return _ragged_attention_block(q, k_seq, v_seq, start, q_lens, 0)
+
+    def batch_block(xs):
+        qb, kb, vb, st, ql = xs
+        out = jax.lax.map(
+            lambda ys: _ragged_attention_block(
+                ys[0], kb, vb, st, ql, ys[1]
+            ),
+            (
+                jnp.moveaxis(
+                    qb.reshape(b_blk, S // s_blk, s_blk, *qb.shape[2:]),
+                    1, 0,
+                ),
+                jnp.arange(S // s_blk) * s_blk,
+            ),
+        )                                       # [S/s_blk, b_blk, s_blk, H, D]
+        return jnp.moveaxis(out, 0, 1).reshape(qb.shape)
+
+    def blocks(x):
+        return x.reshape(B // b_blk, b_blk, *x.shape[1:])
+
+    out = jax.lax.map(
+        batch_block,
+        (blocks(q), blocks(k_seq), blocks(v_seq), blocks(start),
+         blocks(q_lens)),
+    )
+    return out.reshape(q.shape)
+
+
+def _largest_divisor(n: int, cap: int) -> int:
+    """Largest divisor of ``n`` that is <= ``cap`` (at least 1)."""
+    for d in range(min(n, cap), 0, -1):
+        if n % d == 0:
+            return d
+    return 1
+
+
+def _ragged_attention_block(
+    q: jax.Array,        # [B, S, H, D] queries at row offsets q_off..q_off+S
+    k_seq: jax.Array,    # [B, L, K, D] gathered keys
+    v_seq: jax.Array,    # [B, L, K, D] gathered values
+    start: jax.Array,    # [B]
+    q_lens: jax.Array,   # [B]
+    q_off,               # [] offset of q's first row inside the chunk
+) -> jax.Array:
+    """Masked softmax attention of one (batch, query) block against every
+    gathered key position — the body of ``paged_ragged_attention``."""
+    B, S, H, _ = q.shape
     K, D = k_seq.shape[-2:]
     G = H // K
     L = k_seq.shape[1]
@@ -487,7 +593,9 @@ def paged_ragged_attention(
         "bskgd,btkd->bkgst", qg, k_seq, preferred_element_type=jnp.float32
     ) * scale
     pos_t = jnp.arange(L)[None, None, :]                   # [1, 1, L]
-    pos_q = (start[:, None] + jnp.arange(S)[None, :])[:, :, None]  # [B, S, 1]
+    pos_q = (
+        start[:, None] + q_off + jnp.arange(S)[None, :]
+    )[:, :, None]                                          # [B, S, 1]
     mask = (pos_t <= pos_q) & (pos_t < (start + q_lens)[:, None, None])
     scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)

@@ -56,7 +56,7 @@ def _kernel(
     base_ref,      # [1] int32 flat-page offset (layer * N; 0 without layers)
     # blocks + scratch, order depending on ``quantized``:
     #   q_ref [1, H, D]; k_ref/v_ref [1, P, K, D] (one page, all kv heads);
-    #   with quantized, k_sc_ref/v_sc_ref [1, 1, P*K] (this page's
+    #   with quantized, k_sc_ref/v_sc_ref [1, 1, 1, P*K] (this page's
     #   pre-gathered f32 scale plane); o_ref [1, H, D]; then scratch
     #   acc [H, D] f32, m/l [H, 128] f32 (running max / denominator,
     #   lane-broadcast).
@@ -113,7 +113,7 @@ def _kernel(
             # is a lane-wise multiply identical to dequantizing the page
             # (the scale is constant per column). Same math as the
             # manual-DMA kernels (_kernel_dma).
-            s_full = s_full * k_sc_ref[0, 0][None, :]
+            s_full = s_full * k_sc_ref[0, 0]
         # Column c holds (token p*P + c//K, kv head c%K). Mask columns whose
         # kv head is not this query head's group (and out-of-range tokens) to
         # -inf and run the online softmax directly in the [H, P*K] domain —
@@ -133,7 +133,7 @@ def _kernel(
         pv = probs
         if quantized:
             # V scale folds into the probs the same way (per-column).
-            pv = probs * v_sc_ref[0, 0][None, :]
+            pv = probs * v_sc_ref[0, 0]
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
             pv, vf.astype(jnp.float32),
             dimension_numbers=(((1,), (0,)), ((), ())),
@@ -161,14 +161,17 @@ def _page_index(b, p, table_ref, lengths_ref, base_ref, *, page_size):
 
 
 def _scale_index(b, p, table_ref, lengths_ref, base_ref, *, page_size):
-    """Block index into the pre-gathered ``[B, MaxP, P*K]`` scale planes
-    for grid step (b, p): the slot axis is clamped exactly like
+    """Block index into the pre-gathered ``[B, MaxP, 1, P*K]`` scale planes
+    for grid step (b, p) (the unit axis makes the (1, P*K) block span the
+    array's last two dims, which the TPU lowering requires of a block
+    whose lane dim — 64 at 4 kv heads x 16-token pages — is no multiple
+    of 128): the slot axis is clamped exactly like
     ``_page_index`` so past-the-end steps see an unchanged index and the
     pipeline skips the refetch — the scale block can therefore never come
     from a different page slot than the k/v blocks beside it."""
     num_pages = pl.cdiv(lengths_ref[b], page_size)
     last = jnp.maximum(num_pages - 1, 0)
-    return (b, jnp.minimum(p, last), 0)
+    return (b, jnp.minimum(p, last), 0, 0)
 
 
 def _kernel_dma(
@@ -322,7 +325,8 @@ def paged_decode_attention_pallas_dma(
     Requires ``head_dim % 128 == 0``: Mosaic's manual-DMA memref slices
     must be 128-aligned on the minormost dim (r04 on-chip: head_dim=64
     fails to compile). Callers with smaller heads should use the grid
-    kernel or the xla gather (engine auto-falls-back).
+    kernel or the xla gather (the engine refuses the combination at
+    init, ``ops.attention.pallas_refusal``).
 
     Accepts ``ops.attention.QuantizedPages`` (int8 values + per-token
     scales): the int8 pages stream through the manual DMAs exactly like
@@ -440,7 +444,7 @@ def _kernel_ragged(
     base_ref,      # [1] int32 flat-page offset (layer * N; 0 without layers)
     # blocks + scratch, order depending on ``quantized``:
     #   q_ref [1, S, H, D]; k_ref/v_ref [1, P, K, D] (one page, all kv
-    #   heads); with quantized, k_sc_ref/v_sc_ref [1, 1, P*K] (this
+    #   heads); with quantized, k_sc_ref/v_sc_ref [1, 1, 1, P*K] (this
     #   page's pre-gathered f32 scale plane); o_ref [1, S, H, D]; then
     #   scratch acc [S*H, D] f32, m/l [S*H, 128] f32.
     *refs,
@@ -458,7 +462,7 @@ def _kernel_ragged(
     columns) and emit garbage the host discards.
 
     ``quantized``: pages are int8 and two extra blocks carry this page
-    slot's pre-gathered, pre-flattened [1, 1, P*K] f32 scale planes,
+    slot's pre-gathered, pre-flattened [1, 1, 1, P*K] f32 scale planes,
     pipelined with the SAME clamped slot index map as the pages; scales
     apply as per-column multiplies in score/probs space exactly like the
     manual-DMA kernels (see ``_kernel_dma``)."""
@@ -502,7 +506,7 @@ def _kernel_ragged(
         )                                                  # [S*H, P*K]
         if quantized:
             # Per-column K scale in score space (see _kernel_dma).
-            s_full = s_full * k_sc_ref[0, 0][None, :]
+            s_full = s_full * k_sc_ref[0, 0]
         # Column c holds (token p*P + c//K, kv head c%K); row r holds
         # (query position start + r//H, query head r%H). Select the GQA
         # group AND the ragged causal window in one mask.
@@ -526,7 +530,7 @@ def _kernel_ragged(
         pv = probs
         if quantized:
             # V scale folds into the probs the same way (per-column).
-            pv = probs * v_sc_ref[0, 0][None, :]
+            pv = probs * v_sc_ref[0, 0]
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
             pv, vf.astype(jnp.float32),
             dimension_numbers=(((1,), (0,)), ((), ())),
@@ -564,7 +568,7 @@ def _scale_index_ragged(
     start + q_len)."""
     num_pages = pl.cdiv(start_ref[b] + qlens_ref[b], page_size)
     last = jnp.maximum(num_pages - 1, 0)
-    return (b, jnp.minimum(p, last), 0)
+    return (b, jnp.minimum(p, last), 0, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -590,7 +594,7 @@ def paged_ragged_attention_pallas(
     Accepts ``ops.attention.QuantizedPages``: int8 pages flow through the
     same per-page BlockSpec pipeline at half the bytes, while each page
     slot's f32 scale plane — XLA-gathered outside, flattened to
-    [B, MaxP, P*K], and pipelined with the SAME clamped slot index map as
+    [B, MaxP, 1, P*K], and pipelined with the SAME clamped slot index map as
     the pages — applies as per-column multiplies in score/probs space
     (see ``_kernel_ragged``). This closes the sweep gap where
     pallas + int8 KV silently resolved to xla at engine init."""
@@ -629,17 +633,17 @@ def paged_ragged_attention_pallas(
     if quantized:
         # Per-page scale planes, gathered OUTSIDE the kernel (4 bytes per
         # D int8 values) with the same max(slot, 0) + base index math as
-        # the page maps, flattened so the lane dim is 128-aligned, and
-        # pipelined one page slot at a time alongside the k/v blocks.
+        # the page maps, flattened to [B, MaxP, 1, P*K] (see _scale_index),
+        # and pipelined one page slot at a time alongside the k/v blocks.
         safe_table = jnp.maximum(page_table, 0) + base
         sc_map = functools.partial(_scale_index_ragged, page_size=P)
         sc_spec = pl.BlockSpec(
-            (1, 1, P * K), sc_map, memory_space=pltpu.VMEM
+            (1, 1, 1, P * K), sc_map, memory_space=pltpu.VMEM
         )
         in_specs += [sc_spec, sc_spec]
         operands += [
-            k_scale[safe_table].reshape(B, MaxP, P * K),
-            v_scale[safe_table].reshape(B, MaxP, P * K),
+            k_scale[safe_table].reshape(B, MaxP, 1, P * K),
+            v_scale[safe_table].reshape(B, MaxP, 1, P * K),
         ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -846,7 +850,8 @@ def paged_ragged_attention_pallas_dma(
     Requires ``head_dim % 128 == 0``: Mosaic's manual-DMA memref slices
     must be 128-aligned on the minormost dim (r04 on-chip: head_dim=64
     fails to compile). Callers with smaller heads should use the grid
-    kernel or the xla gather (engine auto-falls-back).
+    kernel or the xla gather (the engine refuses the combination at
+    init, ``ops.attention.pallas_refusal``).
 
     Accepts ``ops.attention.QuantizedPages``: int8 pages stream through
     the manual DMAs at HALF the bytes, while this sequence's scale planes
@@ -966,7 +971,7 @@ def paged_decode_attention_pallas(
     """Grid-form paged decode attention. Accepts
     ``ops.attention.QuantizedPages`` exactly like the ragged grid kernel:
     int8 pages ride the per-page BlockSpec pipeline at half the bytes,
-    per-page [1, 1, P*K] scale planes ride beside them on the same
+    per-page [1, 1, 1, P*K] scale planes ride beside them on the same
     clamped slot index map, applied in score/probs space."""
     from .attention import QuantizedPages
 
@@ -1007,12 +1012,12 @@ def paged_decode_attention_pallas(
         safe_table = jnp.maximum(page_table, 0) + base
         sc_map = functools.partial(_scale_index, page_size=P)
         sc_spec = pl.BlockSpec(
-            (1, 1, P * K), sc_map, memory_space=pltpu.VMEM
+            (1, 1, 1, P * K), sc_map, memory_space=pltpu.VMEM
         )
         in_specs += [sc_spec, sc_spec]
         operands += [
-            k_scale[safe_table].reshape(B, MaxP, P * K),
-            v_scale[safe_table].reshape(B, MaxP, P * K),
+            k_scale[safe_table].reshape(B, MaxP, 1, P * K),
+            v_scale[safe_table].reshape(B, MaxP, 1, P * K),
         ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,

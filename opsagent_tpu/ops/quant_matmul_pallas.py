@@ -37,6 +37,15 @@ from jax.experimental.pallas import tpu as pltpu
 # slots plus the x block fit VMEM with room for the accumulator.
 IN_TILE = 256
 OUT_TILE = 512
+# Activation rows resident per grid step. The x block spans the whole
+# contraction axis, so its VMEM footprint is T_TILE x In x itemsize,
+# double-buffered by the pipeline: 128 rows of an 18944-wide bf16
+# contraction are 2 x 4.6 MB, inside the 16 MB scoped-VMEM limit that a
+# whole (T, In) block overruns from T = 1024 (the chip's compiler, at the
+# engine's largest mixed bucket). Decode batches fit one tile, so the
+# weights still stream exactly once per matmul there; wider batches
+# re-stream them once per row tile.
+T_TILE = 128
 
 
 def _out_tile(out: int) -> int:
@@ -49,10 +58,10 @@ def _out_tile(out: int) -> int:
 
 
 def _kernel_int8(
-    x_ref,      # [T, In] VMEM (activations, full contraction axis)
+    x_ref,      # [T_T, In] VMEM (one row tile, full contraction axis)
     s_ref,      # [1, OUT_T] VMEM (per-output-channel scale tile)
     q_hbm,      # [In, Out] int8, HBM-resident (memory_space=ANY)
-    o_ref,      # [T, OUT_T] VMEM
+    o_ref,      # [T_T, OUT_T] VMEM
     q_buf,      # [2, IN_T, OUT_T] int8 VMEM scratch (the two DMA slots)
     sem,        # DMA semaphores (2,)
     *,
@@ -65,7 +74,7 @@ def _kernel_int8(
     kernels clamp page indices) so a ragged contraction axis re-reads a
     few rows instead of reading out of bounds; the re-read rows are zeroed
     in the x slice, so their products vanish."""
-    j = pl.program_id(0)
+    j = pl.program_id(1)
     out_t = o_ref.shape[1]
 
     def start(i):
@@ -115,10 +124,10 @@ def _kernel_int8(
 
 
 def _kernel_int4(
-    x_ref,      # [T, In] VMEM
+    x_ref,      # [T_T, In] VMEM
     s_ref,      # [G, 1, OUT_T] VMEM (group scales for this out tile)
     q_hbm,      # [In//2, Out] packed int8, HBM-resident
-    o_ref,      # [T, OUT_T] VMEM
+    o_ref,      # [T_T, OUT_T] VMEM
     q_buf,      # [2, g//2, OUT_T] int8 VMEM scratch
     sem,
     *,
@@ -130,7 +139,7 @@ def _kernel_int4(
     applies as a broadcast multiply with no cross-group bookkeeping.
     ``group`` always divides the contraction axis (quantize_weight4
     derives it as a divisor), so there is no ragged tail here."""
-    j = pl.program_id(0)
+    j = pl.program_id(1)
     out_t = o_ref.shape[1]
     half = group // 2
 
@@ -154,11 +163,14 @@ def _kernel_int4(
         packed = q_buf[slot]                                # [g/2, OUT_T]
         # Nibble unpack, exactly quant.QuantizedLinear4.dequantize:
         # arithmetic shifts sign-extend; stack on -2 interleaves
-        # (even, odd) rows back into contraction order.
+        # (even, odd) rows back into contraction order. The shifts run
+        # on int32 lanes: Mosaic does not legalize arith.shli on int8
+        # vectors.
+        p32 = packed.astype(jnp.int32)
         low = jax.lax.shift_right_arithmetic(
-            jax.lax.shift_left(packed, jnp.int8(4)), jnp.int8(4)
+            jax.lax.shift_left(p32, jnp.int32(28)), jnp.int32(28)
         )
-        high = jax.lax.shift_right_arithmetic(packed, jnp.int8(4))
+        high = jax.lax.shift_right_arithmetic(p32, jnp.int32(4))
         w = jnp.stack([low, high], axis=-2)                 # [g/2, 2, OUT_T]
         w = w.astype(jnp.float32).reshape(group, out_t)
         xs = x_ref[:, pl.ds(i * group, group)]              # [T, g]
@@ -203,8 +215,8 @@ def quant_matmul_pallas(
     """``x @ w.dequantize().astype(x.dtype)`` with the weight stream
     double-buffered HBM->VMEM instead of serialized with the dot.
 
-    Grid is one step per output tile; within a step the contraction axis
-    streams through two DMA slots (int8: IN_TILE rows per slot; int4: one
+    Grid is (row tiles of T_TILE, output tiles); within a step the
+    contraction axis streams through two DMA slots (int8: IN_TILE rows per slot; int4: one
     scale group per slot, packed two-per-byte). Returns [T, Out] in
     ``x.dtype``.
     """
@@ -218,6 +230,7 @@ def quant_matmul_pallas(
             f"(stacked/MoE leaves stay on the XLA dequant path)"
         )
     T = x.shape[0]
+    t_tile = min(T, T_TILE)
 
     if isinstance(w, QuantizedLinear4):
         half, Out = w.q.shape
@@ -231,9 +244,11 @@ def quant_matmul_pallas(
             _kernel_int4, group=group, n_groups=G
         )
         in_specs = [
-            pl.BlockSpec((T, In), lambda j: (0, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec(
-                (G, 1, out_t), lambda j: (0, 0, j),
+                (t_tile, In), lambda t, j: (t, 0), memory_space=pltpu.VMEM
+            ),
+            pl.BlockSpec(
+                (G, 1, out_t), lambda t, j: (0, 0, j),
                 memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(memory_space=pl.ANY),
@@ -254,9 +269,11 @@ def quant_matmul_pallas(
             _kernel_int8, in_tile=in_tile, n_in=n_in, In=In
         )
         in_specs = [
-            pl.BlockSpec((T, In), lambda j: (0, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec(
-                (1, out_t), lambda j: (0, j), memory_space=pltpu.VMEM
+                (t_tile, In), lambda t, j: (t, 0), memory_space=pltpu.VMEM
+            ),
+            pl.BlockSpec(
+                (1, out_t), lambda t, j: (0, j), memory_space=pltpu.VMEM
             ),
             pl.BlockSpec(memory_space=pl.ANY),
         ]
@@ -270,10 +287,14 @@ def quant_matmul_pallas(
 
     return pl.pallas_call(
         kernel,
-        grid=(Out // out_t,),
+        # Row tiles outermost: a row tile's x block stays resident while
+        # every output tile streams its weight columns past it. A ragged
+        # last row tile reads padding and drops the out-of-range rows on
+        # write; matmul rows are independent, so valid rows are exact.
+        grid=(pl.cdiv(T, t_tile), Out // out_t),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (T, out_t), lambda j: (0, j), memory_space=pltpu.VMEM
+            (t_tile, out_t), lambda t, j: (t, j), memory_space=pltpu.VMEM
         ),
         out_shape=jax.ShapeDtypeStruct((T, Out), x.dtype),
         scratch_shapes=scratch,
@@ -281,7 +302,7 @@ def quant_matmul_pallas(
         cost_estimate=pl.CostEstimate(
             flops=2 * T * In * Out,
             bytes_accessed=(
-                weight_bytes
+                weight_bytes * pl.cdiv(T, t_tile)
                 + T * (In + Out) * x.dtype.itemsize
             ),
             transcendentals=0,
@@ -298,7 +319,7 @@ def quant_matmul_pallas_tp(
     """Column-parallel TP form: ``w`` sharded on its OUTPUT axis over the
     mesh's tp axis, ``x`` replicated — each shard streams only its own
     weight columns and emits its own output columns; no collective. The
-    engine currently resolves weight_stream to xla at tp > 1 (row-parallel
+    engine refuses weight_stream=pallas-dma at tp > 1 (row-parallel
     projections would need a psum epilogue); this form exists so the
     sharded kernel stays covered ahead of that wiring."""
     from jax.sharding import PartitionSpec as Pspec

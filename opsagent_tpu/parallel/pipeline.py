@@ -280,8 +280,6 @@ def make_pipeline_loss(
         # contributed its own per-layer routing stats (vs ONE whole-batch
         # stat in the unpipelined step), and dp/sp shards each counted
         # their slice — mean over all of them.
-        # Static sizes from the enclosing mesh: jax.lax.axis_size only
-        # exists in newer jax than this container ships (0.4.37).
         aux = jax.lax.psum(aux_acc, ("pp", "dp", "sp")) / (
             M * mesh.shape["dp"] * mesh.shape["sp"]
         )

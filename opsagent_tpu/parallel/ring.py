@@ -42,12 +42,9 @@ def _local_ring_attention(
     v: jax.Array,        # [B, S_l, K, D]
     lengths: jax.Array,  # [B] valid GLOBAL lengths (right padding beyond)
     axis: str,
-    sp: int,             # static axis size (mesh.shape[axis]): the ring
-                         # step count and perm table need a Python int,
-                         # and jax.lax.axis_size only exists in newer jax
-                         # than this container ships (0.4.37)
 ) -> jax.Array:
     idx = jax.lax.axis_index(axis)
+    sp = jax.lax.axis_size(axis)  # static: ring step count + perm table
     B, S_l, H, D = q.shape
     K = k.shape[2]
     G = H // K
@@ -98,7 +95,7 @@ def _local_ring_attention(
         return k_blk, v_blk, m_new, l_new, acc_new
 
     # Derive the initial accumulators from q (not fresh constants) so they
-    # carry q's varying-manual-axes type — the new shard_map's VMA tracking
+    # carry q's varying-manual-axes type — shard_map's VMA tracking
     # rejects a scan whose carry starts unvarying but becomes varying.
     acc0 = jnp.moveaxis(qg, 1, 3).astype(jnp.float32) * 0.0  # [B,K,G,S_l,D]
     l0 = acc0[..., :1]
@@ -119,24 +116,11 @@ def make_ring_attention(
     Heads stay tensor-parallel over "tp"; batch over "dp"."""
     spec = P("dp", "sp", "tp", None)
     len_spec = P("dp")  # lengths replicated over sp/tp, batch over dp
-    local = functools.partial(
-        _local_ring_attention, axis=axis, sp=int(mesh.shape[axis])
+    mapped = jax.shard_map(
+        functools.partial(_local_ring_attention, axis=axis),
+        mesh=mesh,
+        in_specs=(spec, spec, spec, len_spec), out_specs=spec,
     )
-    try:
-        from jax import shard_map
-
-        mapped = shard_map(
-            local, mesh=mesh,
-            in_specs=(spec, spec, spec, len_spec), out_specs=spec,
-        )
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
-        mapped = shard_map(
-            local, mesh=mesh,
-            in_specs=(spec, spec, spec, len_spec), out_specs=spec,
-            check_rep=False,
-        )
 
     def ring_attn(q, k, v, lengths=None):
         if lengths is None:

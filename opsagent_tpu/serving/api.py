@@ -784,10 +784,11 @@ def build_engine_app(stack: ServingStack, membership=None):
             "prefix_hit_tokens": eng.alloc.hit_tokens,
             "prefix_miss_tokens": eng.alloc.miss_tokens,
             "prefix_evictions": eng.alloc.evictions,
-            # RESOLVED execution modes (attn impl after every fallback
-            # gate, weight + KV quant): fleet snapshots and sweep readers
-            # self-describe instead of inferring backend from env.
+            # What actually runs and on what (device, mesh, attn impl,
+            # weight + KV quant, FSM tables): fleet snapshots and sweep
+            # readers self-describe instead of inferring it from env.
             "impl": eng.impl_info(),
+            "device_memory": eng.device_memory(),
         }
         if getattr(eng, "init_stats", None):
             # Cold-start provenance: how long weights + warmup took, and
@@ -1255,16 +1256,10 @@ def run_engine_server(
     replica_id: str = "",
     replica_role: str = "decode",
     restore_snapshot: str = "",
-    compile_cache_dir: str = "",
 ) -> None:
-    import os
-
     from aiohttp import web
 
     from ..models.config import resolve_model
-
-    if compile_cache_dir:
-        os.environ["OPSAGENT_COMPILE_CACHE_DIR"] = compile_cache_dir
 
     if restore_snapshot:
         # Cold-start fast path: the snapshot IS the engine config —

@@ -150,9 +150,10 @@ def write_snapshot(engine: Any, path: str) -> dict[str, Any]:
             "digest": digest_bytes(data),
         })
 
-    # Persistent XLA compile cache -> build artifact. Entries land there
-    # at COMPILE time, so `snapshot create` warms the engine under
-    # OPSAGENT_COMPILE_CACHE_MIN_S=0 before calling this.
+    # Persistent XLA compile cache (engine.compile_cache_dir()) -> build
+    # artifact. Entries land there at COMPILE time, so `snapshot create`
+    # warms the engine under OPSAGENT_COMPILE_CACHE_MIN_S=0 before
+    # calling this.
     cache_entries = 0
     cache_bytes = 0
     src_cache = getattr(engine, "compile_cache_dir", None)
@@ -167,8 +168,8 @@ def write_snapshot(engine: Any, path: str) -> dict[str, Any]:
     else:
         log.warning(
             "engine has no active compile cache dir: snapshot carries "
-            "weights only (restore will recompile; set "
-            "OPSAGENT_COMPILE_CACHE_DIR)"
+            "weights only (restore will recompile; "
+            "jax_enable_compilation_cache is off)"
         )
 
     cfg = engine.cfg

@@ -187,7 +187,7 @@ def make_train_step(
     )
 
     def run(params, opt_state, tokens, loss_mask):
-        with mesh:
+        with jax.set_mesh(mesh):
             return jitted(params, opt_state, tokens, loss_mask)
 
     return run

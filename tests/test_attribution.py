@@ -10,6 +10,9 @@ from opsagent_tpu import obs
 from opsagent_tpu.obs import attribution
 from opsagent_tpu.obs.attribution import Attribution, prefill_attn_positions
 
+V5E = "TPU v5 lite"  # device_kind JAX reports for a v5e chip
+V5E_HBM = attribution.DEVICE_PEAKS[V5E].hbm_gbps * 1e9
+
 
 def _bench8b_int8() -> Attribution:
     # The PERF.md worked example: bench-8b (Llama-3-8B architecture)
@@ -26,6 +29,7 @@ def _bench8b_int8() -> Attribution:
         vocab_size=cfg.vocab_size,
         dtype_bytes=2,
         quantize="int8",
+        device_kind=V5E,
     )
 
 
@@ -46,9 +50,9 @@ def test_closed_form_weight_stream_matches_hand_arithmetic():
     assert a.num_params == params
     assert abs(params / 1e9 - 8.03) < 0.01           # the 8B class
     assert a.weight_stream_bytes == params * 1.02    # int8 + 2% scales
-    # At the v5e default 820 GB/s this is the ~10 ms/step weight floor
-    # PERF.md's 16.9 ms/step measurement sits on.
-    floor_ms = a.weight_stream_bytes / 820e9 * 1e3
+    # At the v5e's published 819 GB/s this is the ~10 ms/step weight
+    # floor PERF.md's 16.9 ms/step measurement sits on.
+    floor_ms = a.weight_stream_bytes / V5E_HBM * 1e3
     assert 9.5 < floor_ms < 10.5
 
 
@@ -73,7 +77,7 @@ def test_closed_form_kv_and_dispatch_totals():
     assert c["total"] == (
         c["weights"] + c["kv_read"] + c["kv_write"] + c["other"]
     )
-    assert abs(c["modeled_s"] - c["total"] / 820e9) < 1e-12
+    assert abs(c["modeled_s"] - c["total"] / V5E_HBM) < 1e-12
     # FLOPs: 2*P per processed token + the exact attention terms.
     assert c["flops"] == (
         2.0 * a.num_params * B + 4.0 * 32 * 128 * 32 * (B * ctx)
@@ -135,6 +139,63 @@ def test_dispatch_updates_metrics_and_drift():
                kv_write_tokens=32, attn_q_ctx=32 * 384)
     assert attribution.ATTR_HBM_UTIL.value() > 0.0
     assert attribution.ATTR_MFU.value() > 0.0
+
+
+def test_peaks_table_raises_on_unknown_device_kind():
+    """A device that is not in DEVICE_PEAKS is an error for everything
+    the peaks price — never a v5e default."""
+    import pytest
+
+    assert attribution.device_peaks(V5E).bf16_tflops == 197.0
+    assert attribution.DEVICE_PEAKS[V5E].source
+    with pytest.raises(KeyError, match="no published peaks.*TPU v9"):
+        attribution.device_peaks("TPU v9")
+    with pytest.raises(KeyError, match="no published peaks"):
+        Attribution(
+            num_params=10, num_layers=1, num_heads=1, num_kv_heads=1,
+            head_dim=8, vocab_size=16, device_kind="TPU v9",
+        )
+
+
+def test_no_peaks_means_counts_only():
+    """On the CPU (device_kind None) the ledger keeps its byte/FLOP
+    counts and the measured-step histogram, and emits no modeled time,
+    MFU, HBM utilization or drift."""
+    a = Attribution(
+        num_params=10_000, num_layers=2, num_heads=4, num_kv_heads=2,
+        head_dim=16, vocab_size=512, dtype_bytes=4,
+    )
+    for _ in range(2):
+        c = a.dispatch(
+            "single", q_tokens=2, kv_read_tokens=8, kv_write_tokens=2,
+            attn_q_ctx=8, measured_s=0.004,
+        )
+    assert c["modeled_s"] is None and c["total"] > 0 and c["flops"] > 0
+    assert attribution.ATTR_BYTES.value(kind="weights") == 2 * c["weights"]
+    assert attribution.ATTR_MEASURED_STEP_SECONDS.count(op="single") == 2
+    text = obs.metrics_text()
+    for family in (
+        "opsagent_attr_mfu", "opsagent_attr_hbm_utilization",
+        "opsagent_attr_model_drift_ratio",
+        "opsagent_attr_modeled_step_seconds",
+    ):
+        assert f"\n{family} " not in text and f"\n{family}{{" not in text
+    snap = a.snapshot()
+    assert snap["hbm_gbps"] is None and "mfu" not in snap
+
+
+def test_engine_on_cpu_prices_no_time():
+    """for_engine keys the peaks by the device JAX reports: the CPU has
+    none, so a CPU engine's ledger carries counts only."""
+    from opsagent_tpu.serving.engine import Engine, EngineConfig
+
+    eng = Engine(EngineConfig(
+        model="tiny-test", dtype=jnp.float32, tp=1, page_size=4,
+        num_pages=32, max_pages_per_seq=8, max_batch_size=2,
+        prefill_buckets=(8,),
+    ))
+    assert eng.attr.device_kind is None
+    assert eng.attr.cost(q_tokens=1)["modeled_s"] is None
 
 
 def test_goodput_counter_and_snapshot():

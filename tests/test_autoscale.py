@@ -195,7 +195,7 @@ class TestElasticScaleOut:
     ):
         monkeypatch.setenv("OPSAGENT_COMPILE_CACHE_MIN_S", "0")
         monkeypatch.setenv(
-            "OPSAGENT_COMPILE_CACHE_DIR", str(tmp_path / "cache")
+            "JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache")
         )
         jax.clear_caches()
         router, stacks = _router(1, shed_queue_depth=None)

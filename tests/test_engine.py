@@ -351,15 +351,88 @@ def test_warmup_compiles_without_disturbing_state():
     assert got == want
 
 
-def test_compilation_cache_dir_configured(tmp_path, monkeypatch):
-    from opsagent_tpu.serving.engine import enable_compilation_cache
+@pytest.mark.parametrize(
+    "progs,breaks",
+    [
+        ({"mixed"}, "program"),          # the sequential dispatch pass
+        ({"mixed_async", "fsm"}, "fsm"),  # async mixed + ToolPrompt tables
+        ({"ffwd"}, "fsm"),                # grammar fast-forward family
+        ({"fsm"}, "fsm"),                 # device-FSM decode blocks
+    ],
+    ids=["mixed", "mixed_async-fsm", "ffwd", "fsm"],
+)
+def test_failing_warmup_family_raises(monkeypatch, progs, breaks):
+    """A warmup family that cannot be built is fatal: on the chip it is a
+    program the compiler refuses, and start-up must not exit clean only
+    to compile — or crash — inside the first request. (Each of these
+    four used to log "non-fatal" and carry on.)"""
+    eng = Engine(EngineConfig(
+        model="tiny-test", dtype=jnp.float32, tp=1, page_size=4,
+        num_pages=64, max_pages_per_seq=8, max_batch_size=2,
+        prefill_buckets=(8,), mixed_buckets=(4,), decode_block=4,
+    ))
+    monkeypatch.setitem(Engine.WARMUP_LEVELS, "only", frozenset(progs))
 
-    monkeypatch.setenv("OPSAGENT_COMPILE_CACHE", str(tmp_path / "xla"))
-    path = enable_compilation_cache()
-    assert path == str(tmp_path / "xla")
+    class Refused(RuntimeError):
+        pass
+
+    def refuse(*a, **kw):
+        raise Refused("Mosaic failed to compile TPU kernel")
+
+    if breaks == "fsm":
+        monkeypatch.setattr(eng, "_toolprompt_fsm_tables", refuse)
+    else:
+        class Program:
+            lower = __call__ = staticmethod(refuse)
+
+        monkeypatch.setattr(eng, "_mixed_sample_jit", Program())
+    with pytest.raises(Refused):
+        eng.warmup("only")
+
+
+def test_compilation_cache_lives_in_one_place(tmp_path, monkeypatch):
+    """Where JAX_COMPILATION_CACHE_DIR is set the cache lives there;
+    where it is not, at one fixed git-ignored path inside the checkout
+    (never a temporary name, pid or time). No call ever points JAX at
+    any other directory."""
     import os
-    assert os.path.isdir(path)
+
+    from opsagent_tpu.serving import engine as engine_mod
+    from opsagent_tpu.serving.engine import (
+        compile_cache_dir, enable_compilation_cache,
+    )
+
+    pointed: list[str] = []
+    real_update = jax.config.update
+
+    def spy(name, value):
+        if name == "jax_compilation_cache_dir":
+            pointed.append(value)
+        return real_update(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
+    path = enable_compilation_cache()
+    assert path == str(tmp_path / "xla") and os.path.isdir(path)
     assert jax.config.jax_compilation_cache_dir == path
+    # Already there (as when the environment set it before jax was
+    # imported): a second call sets nothing.
+    n = len(pointed)
+    assert enable_compilation_cache() == path and len(pointed) == n
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    fixed = enable_compilation_cache()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert fixed == compile_cache_dir() == enable_compilation_cache()
+    assert os.path.dirname(fixed) == os.path.join(repo, ".jax_cache")
+    assert os.path.basename(fixed).startswith(jax.default_backend())
+    assert jax.config.jax_compilation_cache_dir == fixed
+    assert set(pointed) == {str(tmp_path / "xla"), fixed}
+    # The directory is git-ignored, and an engine uses the same one.
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+    assert engine_mod._CHECKOUT == repo
 
 
 def test_prefill_chunks_interleave_with_decode():

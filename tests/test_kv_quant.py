@@ -345,24 +345,30 @@ def test_engine_kv_quantize_close_to_fp_cache_on_pinned_context():
             )
 
 
-def test_engine_keeps_pallas_dma_with_kv_quantize_at_aligned_head_dim(
+def test_engine_keeps_pallas_dma_with_kv_quantize_at_aligned_shapes(
     monkeypatch,
 ):
-    """kv_quantize no longer forces xla when the manual-DMA kernel (which
-    has a quantized path) is selected AND the head dim satisfies its
-    alignment rule."""
+    """kv_quantize does not force xla when the manual-DMA kernel (which
+    has a quantized path) is selected AND the shapes satisfy Mosaic's
+    alignment rules: head_dim a multiple of 128, and the kv heads of one
+    shard a multiple of the page dtype's sublane packing (4 for int8).
+    Short of either, the engine refuses with the compiler's reason."""
     from dataclasses import replace
 
     from opsagent_tpu.models.config import get_config_preset
-    from opsagent_tpu.serving.engine import Engine, EngineConfig
+    from opsagent_tpu.serving.engine import (
+        BackendRefused, Engine, EngineConfig,
+    )
 
     monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", "pallas-dma")
-    cfg128 = replace(get_config_preset("tiny-test"), head_dim=128)
-    eng = Engine(
-        EngineConfig(kv_quantize="int8", warmup=False, **_engine_kwargs()),
-        model_cfg=cfg128,
+    cfg128 = replace(
+        get_config_preset("tiny-test"), head_dim=128, num_kv_heads=4
     )
+    kw = dict(kv_quantize="int8", warmup=False, **_engine_kwargs())
+    eng = Engine(EngineConfig(tp=1, **kw), model_cfg=cfg128)
     assert eng.attn_impl == "pallas-dma"
+    with pytest.raises(BackendRefused, match=r"2 kv head\(s\) per shard"):
+        Engine(EngineConfig(tp=2, **kw), model_cfg=cfg128)
 
 
 def test_engine_rejects_bad_kv_quantize_and_mla_combo():

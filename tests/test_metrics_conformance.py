@@ -75,6 +75,7 @@ def _generate_traffic():
     attr = obs.attribution.Attribution(
         num_params=10_000, num_layers=2, num_heads=4, num_kv_heads=2,
         head_dim=16, vocab_size=512, dtype_bytes=4,
+        device_kind="TPU v5 lite",
     )
     attr.dispatch(
         "single", q_tokens=2, kv_read_tokens=8, kv_write_tokens=2,

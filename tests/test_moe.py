@@ -375,7 +375,7 @@ class TestExpertParallelism:
         want = llama.forward_full(params, cfg, tokens, dtype=jnp.float32)
         mesh = make_mesh(ep=4, dp=1, tp=2)
         sharded = shard_params(params, llama.param_specs(cfg), mesh)
-        with mesh:
+        with jax.set_mesh(mesh):
             got = jax.jit(
                 lambda p, t: llama.forward_full(p, cfg, t, dtype=jnp.float32)
             )(sharded, tokens)
@@ -415,14 +415,15 @@ class TestExpertParallelism:
         assert len(out) == 2 and all(len(t) >= 1 for t in out)
 
     def test_ep_constrain_pins_layout_under_mesh(self):
-        """_ep_constrain must actually apply inside jit under `with mesh:`
-        (regression: get_abstract_mesh is empty there, which silently
-        turned the constraint into dead code)."""
+        """_ep_constrain must actually apply inside jit under
+        `jax.set_mesh` (the context the trainer's step runs in; the
+        legacy `with mesh:` leaves get_abstract_mesh empty, which would
+        silently turn the constraint into dead code)."""
         from opsagent_tpu.parallel.mesh import make_mesh
 
         mesh = make_mesh(ep=2, dp=2, tp=2)
         P = jax.sharding.PartitionSpec
-        with mesh:
+        with jax.set_mesh(mesh):
             y = jax.jit(
                 lambda x: llama._ep_constrain(x, P("ep", None))
             )(jnp.ones((4, 8)))

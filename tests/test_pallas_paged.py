@@ -292,17 +292,20 @@ def test_pallas_dma_rejects_unaligned_head_dim():
         )
 
 
-def test_engine_falls_back_from_pallas_dma_on_small_head_dim(monkeypatch):
-    """tiny-test (head_dim 16) + OPSAGENT_PAGED_BACKEND=pallas-dma must
-    resolve to the xla gather, not die in Mosaic at first prefill."""
+def test_engine_refuses_pallas_dma_on_small_head_dim(monkeypatch):
+    """tiny-test (head_dim 16) + OPSAGENT_PAGED_BACKEND=pallas-dma is
+    refused at engine init with Mosaic's reason — neither a death at
+    first prefill nor an xla run under the kernel's name."""
     monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", "pallas-dma")
-    from opsagent_tpu.serving.engine import Engine, EngineConfig
+    from opsagent_tpu.serving.engine import (
+        BackendRefused, Engine, EngineConfig,
+    )
 
-    eng = Engine(EngineConfig(
-        model="tiny-test", max_batch_size=2, num_pages=16, page_size=8,
-        max_pages_per_seq=4, prefill_buckets=(16,), decode_block=4,
-    ))
-    assert eng.attn_impl == "xla"
+    with pytest.raises(BackendRefused, match="head_dim 16.*tiling"):
+        Engine(EngineConfig(
+            model="tiny-test", max_batch_size=2, num_pages=16, page_size=8,
+            max_pages_per_seq=4, prefill_buckets=(16,), decode_block=4,
+        ))
 
 
 def test_pallas_dma_length_beyond_table_clamps():

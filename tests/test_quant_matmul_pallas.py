@@ -6,8 +6,8 @@ weights — including the ragged last contraction tile and the
 contraction-smaller-than-group edge — the column-parallel shard_map form,
 and the engine-level acceptance gates: ``weight_stream="pallas-dma"``
 must produce BYTE-IDENTICAL greedy output to the xla weight stream
-through the mixed hot path with zero post-warmup compiles, and must fall
-back to xla whenever its gates (quantized weights, tp == 1) trip.
+through the mixed hot path with zero post-warmup compiles, and must be
+refused at init whenever its gates (quantized weights, tp == 1) trip.
 """
 
 import jax
@@ -274,28 +274,32 @@ def test_engine_weight_stream_env_knob(monkeypatch):
     assert eng.weight_stream_impl == "pallas-dma"
 
 
-def test_engine_falls_back_without_quantized_weights(monkeypatch):
-    """pallas-dma weight streaming needs narrow storage to stream;
-    full-precision engines resolve to xla instead of dying."""
-    from opsagent_tpu.serving.engine import Engine, EngineConfig
+def test_engine_refuses_weight_stream_without_quantized_weights():
+    """pallas-dma weight streaming needs narrow storage to stream: an
+    explicit request on full-precision weights is refused at init with
+    the reason — it never runs as xla under the kernel's name."""
+    from opsagent_tpu.serving.engine import (
+        BackendRefused, Engine, EngineConfig,
+    )
 
-    eng = Engine(EngineConfig(weight_stream="pallas-dma", **ENGINE_BASE))
-    assert eng.weight_stream_impl == "xla"
-    assert eng.impl_info()["weight_stream"] == "xla"
+    with pytest.raises(BackendRefused, match="needs quantize=int8"):
+        Engine(EngineConfig(weight_stream="pallas-dma", **ENGINE_BASE))
 
 
-def test_engine_falls_back_on_tp(monkeypatch):
-    """Sharded engines keep the XLA weight path until the row-parallel
-    psum epilogue is wired (the resolution gate, not a crash)."""
-    from opsagent_tpu.serving.engine import Engine, EngineConfig
+def test_engine_refuses_weight_stream_on_tp():
+    """The prefetch kernel is single-shard until the row-parallel psum
+    epilogue is wired: a sharded engine asking for it is refused."""
+    from opsagent_tpu.serving.engine import (
+        BackendRefused, Engine, EngineConfig,
+    )
 
     if len(jax.devices()) < 2:
         pytest.skip("needs 2 devices")
     cfg = dict(ENGINE_BASE, tp=2)
-    eng = Engine(EngineConfig(
-        quantize="int8", weight_stream="pallas-dma", **cfg
-    ))
-    assert eng.weight_stream_impl == "xla"
+    with pytest.raises(BackendRefused, match="single-shard only"):
+        Engine(EngineConfig(
+            quantize="int8", weight_stream="pallas-dma", **cfg
+        ))
 
 
 def test_engine_rejects_unknown_weight_stream():
@@ -314,6 +318,7 @@ def test_attribution_reroutes_weight_bytes_under_prefetch():
     kw = dict(
         num_params=1_000_000, num_layers=4, num_heads=8, num_kv_heads=4,
         head_dim=64, vocab_size=1000, quantize="int8",
+        device_kind="TPU v5 lite",
     )
     serial = Attribution(**kw)
     overlap = Attribution(weight_stream="pallas-dma", **kw)

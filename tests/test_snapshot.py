@@ -14,6 +14,8 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 from aiohttp.test_utils import TestClient, TestServer
 
 from opsagent_tpu import obs
@@ -47,7 +49,7 @@ def cache_env(tmp_path, monkeypatch):
     so every warmed program lands in the snapshot's cache artifact."""
     monkeypatch.setenv("OPSAGENT_COMPILE_CACHE_MIN_S", "0")
     monkeypatch.setenv(
-        "OPSAGENT_COMPILE_CACHE_DIR", str(tmp_path / "cache-fresh")
+        "JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache-fresh")
     )
     # Earlier tests' in-process executables would otherwise let this
     # test's writer engine skip compiles entirely, leaving its isolated
@@ -73,7 +75,7 @@ def _teardown_and_restore(eng, snapdir, tmp_path, monkeypatch, warmup):
     gc.collect()
     jax.clear_caches()
     monkeypatch.setenv(
-        "OPSAGENT_COMPILE_CACHE_DIR", str(tmp_path / "cache-restore")
+        "JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache-restore")
     )
     return Engine.from_snapshot(snapdir, warmup=warmup)
 
@@ -226,22 +228,32 @@ class TestRestore:
 
 # -- env / compile-cache wiring ------------------------------------------------
 class TestCompileCacheEnv:
-    def test_dir_env_overrides(self, tmp_path, monkeypatch):
+    def test_jax_dir_env_is_the_location(self, tmp_path, monkeypatch):
         target = str(tmp_path / "cc")
-        monkeypatch.setenv("OPSAGENT_COMPILE_CACHE_DIR", target)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", target)
         assert enable_compilation_cache() == target
+        assert jax.config.jax_compilation_cache_dir == target
 
-    def test_legacy_name_still_accepted(self, tmp_path, monkeypatch):
-        target = str(tmp_path / "legacy")
-        monkeypatch.delenv("OPSAGENT_COMPILE_CACHE_DIR", raising=False)
-        monkeypatch.setenv("OPSAGENT_COMPILE_CACHE", target)
-        assert enable_compilation_cache() == target
+    def test_repo_spellings_are_gone(self, tmp_path, monkeypatch):
+        """The repo's own variables no longer move the cache: unset
+        JAX_COMPILATION_CACHE_DIR means the fixed in-checkout path."""
+        from opsagent_tpu.serving.engine import compile_cache_dir
 
-    def test_empty_disables(self, monkeypatch):
-        monkeypatch.setenv("OPSAGENT_COMPILE_CACHE_DIR", "")
-        assert enable_compilation_cache() is None
-        monkeypatch.setenv("OPSAGENT_COMPILE_CACHE_DIR", "0")
-        assert enable_compilation_cache() is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setenv("OPSAGENT_COMPILE_CACHE_DIR", str(tmp_path / "a"))
+        monkeypatch.setenv("OPSAGENT_COMPILE_CACHE", str(tmp_path / "b"))
+        path = enable_compilation_cache()
+        assert path == compile_cache_dir()
+        assert os.path.dirname(path) == os.path.join(REPO, ".jax_cache")
+        assert not (tmp_path / "a").exists() and not (tmp_path / "b").exists()
+
+    def test_jax_switch_disables(self):
+        """JAX's own switch turns the cache off; no repo spelling does."""
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            assert enable_compilation_cache() is None
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
 
 
 # -- /healthz init block -------------------------------------------------------

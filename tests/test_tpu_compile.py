@@ -1,0 +1,308 @@
+"""The chip's compiler, asked without the chip.
+
+Interpret mode runs a Pallas kernel's body on the CPU and knows nothing
+of Mosaic: block shapes the TPU lowering refuses, ops it cannot legalize,
+slices off the memory tiling and blocks that overrun VMEM all pass there.
+The TPU compiler is installed beside JAX and compiles for a device that
+is DESCRIBED (``v5e:2x2``), not attached, so every Pallas entry point is
+compiled here at the widths of the model the chip smoke serves
+(``qwen2.5-7b-instruct``: 28/4 heads of 128, hidden 3584, FFN 18944, vocab
+152064; 2048 pages of 16 tokens, 320 per sequence) — kernels only, a
+second or two each. A combination the engine refuses at init
+(``ops.attention.pallas_refusal``) is pinned from both sides: the
+compiler's refusal, and the engine's with the same reason.
+
+A compile that passes is not a chip run, and nothing here is a time.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+# Describing a topology loads libtpu, which by default is one process's at a
+# time (a lock file); nothing here touches a device, so test workers and a
+# builder's scratch compile may share it.
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from opsagent_tpu.models.config import get_config_preset
+from opsagent_tpu.models.quant import QuantizedLinear, QuantizedLinear4
+from opsagent_tpu.ops import attention, paged_attention_pallas as pap
+from opsagent_tpu.ops import quant_matmul_pallas as qmp
+from opsagent_tpu.ops.attention import QuantizedPages, pallas_refusal
+
+CFG = get_config_preset("qwen2.5-7b-instruct")
+H, K, D, L = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim_, CFG.num_layers
+N, PAGE, MAXP, B = 2048, 16, 320, 8  # EngineConfig / serve-engine defaults
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four described devices of a v5e 2x2 host. The persistent
+    compile cache is off around these compiles: an executable built for
+    a described device is written to it but cannot be read back without
+    a chip (a warning per compile, and an entry nothing can use)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - no libtpu, or it is locked
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _pages(sds, kv: str, k: int = K, d: int = D):
+    """(k_pages, v_pages) shapes in the engine's whole-cache layout."""
+    def one():
+        if kv == "int8":
+            return QuantizedPages(
+                sds((L, N, PAGE, k, d), jnp.int8),
+                sds((L, N, PAGE, k), jnp.float32),
+            )
+        return sds((L, N, PAGE, k, d), jnp.bfloat16)
+
+    return one(), one()
+
+
+def _attention(sds, kernel: str, kv: str, s: int, k: int = K, d: int = D):
+    """Compile one paged-attention kernel; s == 0 means the decode form."""
+    h = k * (H // K)
+    kp, vp = _pages(sds, kv, k, d)
+    table, rows = sds((B, MAXP), jnp.int32), sds((B,), jnp.int32)
+    layer = sds((), jnp.int32)
+    if s == 0:
+        fn = getattr(pap, f"paged_decode_attention_{kernel}")
+        return _compile(
+            lambda q, k_, v_, t, ln, ly: fn(q, k_, v_, t, ln, layer=ly),
+            sds((B, h, d), jnp.bfloat16), kp, vp, table, rows, layer,
+        )
+    fn = getattr(pap, f"paged_ragged_attention_{kernel}")
+    return _compile(
+        lambda q, k_, v_, t, st, ql, ly: fn(q, k_, v_, t, st, ql, layer=ly),
+        sds((B, s, h, d), jnp.bfloat16), kp, vp, table, rows, rows, layer,
+    )
+
+
+def _one_chip(devices):
+    one = SingleDeviceSharding(devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one
+    )
+
+
+# -- paged attention: kernel x page dtype x query rows -----------------------
+@pytest.mark.parametrize(
+    "kernel,kv,s",
+    [
+        (kernel, kv, s)
+        for kernel in ("pallas", "pallas_dma")
+        # s: 0 = the decode form; ragged at decode rows and at the
+        # smallest and the largest mixed bucket (the largest with int8
+        # pages only: the same body plus the scales, ~4 s a compile).
+        for kv, s in [
+            ("bf16", 0), ("bf16", 1), ("bf16", 16),
+            ("int8", 0), ("int8", 1), ("int8", 16), ("int8", 128),
+        ]
+    ],
+)
+def test_paged_attention_kernels_compile(v5e, kernel, kv, s):
+    """Every paged-attention entry point, bf16 and int8 pages. The grid
+    kernels with int8 pages were refused here ("last two dimensions of
+    your block shape ...": a (1, 1, P*K) scale block whose lane dim is
+    64 at 4 kv heads x 16-token pages) until the scale planes got a unit
+    sublane axis, so that the block spans the array's last two dims."""
+    assert _attention(_one_chip(v5e), kernel, kv, s) is not None
+
+
+# -- quantized matmul: weight dtype x projection x rows ----------------------
+SHAPES = {
+    "qkv": (CFG.hidden_size, (H + 2 * K) * D),
+    "o": (H * D, CFG.hidden_size),
+    "gate_up": (CFG.hidden_size, CFG.intermediate_size),
+    "down": (CFG.intermediate_size, CFG.hidden_size),
+    "lm_head": (CFG.hidden_size, CFG.vocab_size),
+}
+
+
+def _weight(sds, mode: str, n_in: int, n_out: int):
+    if mode == "int4":
+        return QuantizedLinear4(
+            sds((n_in // 2, n_out), jnp.int8),
+            sds((n_in // 128, 1, n_out), jnp.float32),
+        )
+    return QuantizedLinear(
+        sds((n_in, n_out), jnp.int8), sds((1, n_out), jnp.float32)
+    )
+
+
+@pytest.mark.parametrize("t", [32, 256])
+@pytest.mark.parametrize("name", list(SHAPES))
+@pytest.mark.parametrize("mode", ["int8", "int4"])
+def test_quant_matmul_compiles(v5e, mode, name, t):
+    """The weight-stream kernel at every projection of the model. The
+    int4 form was refused here ("failed to legalize operation
+    'arith.shli'" on int8 vectors) until its nibble unpack moved to
+    int32 lanes."""
+    sds = _one_chip(v5e)
+    n_in, n_out = SHAPES[name]
+    assert _compile(
+        qmp.quant_matmul_pallas,
+        sds((t, n_in), jnp.bfloat16), _weight(sds, mode, n_in, n_out),
+    ) is not None
+
+
+@pytest.mark.parametrize("mode", ["int8", "int4"])
+def test_quant_matmul_compiles_at_the_largest_mixed_bucket(v5e, mode):
+    """32 rows x the 128-token mixed bucket = 4096 activation rows: as
+    one resident (T, In) block this overran scoped VMEM ("Ran out of
+    memory in memory space vmem"); row tiles of T_TILE keep it inside."""
+    sds = _one_chip(v5e)
+    n_in, n_out = SHAPES["down"]  # the widest contraction
+    assert 4096 > qmp.T_TILE
+    assert _compile(
+        qmp.quant_matmul_pallas,
+        sds((4096, n_in), jnp.bfloat16), _weight(sds, mode, n_in, n_out),
+    ) is not None
+
+
+# -- tensor parallelism: the shard_map wrapper on the four devices -----------
+def _tp_mesh(devices):
+    return Mesh(np.array(devices).reshape(4), ("tp",))
+
+
+@pytest.mark.parametrize("form", ["decode", "ragged"])
+def test_grid_kernel_compiles_under_tp4(v5e, form):
+    """The (B, MaxP) grid kernel through the tp shard_map wrapper: four
+    shards of 7 query heads and ONE kv head each."""
+    mesh = _tp_mesh(v5e)
+
+    def sds(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, spec)
+        )
+
+    pages = sds((L, N, PAGE, K, D), jnp.bfloat16,
+                P(None, None, None, "tp", None))
+    table, rows = sds((B, MAXP), jnp.int32), sds((B,), jnp.int32)
+    layer = sds((), jnp.int32)
+    if form == "decode":
+        compiled = _compile(
+            lambda q, k_, v_, t, ln, ly: (
+                attention.paged_decode_attention_pallas_tp(
+                    q, k_, v_, t, ln, mesh, layer=ly, impl="pallas"
+                )
+            ),
+            sds((B, H, D), jnp.bfloat16, P(None, "tp", None)),
+            pages, pages, table, rows, layer,
+        )
+    else:
+        compiled = _compile(
+            lambda q, k_, v_, t, st, ql, ly: (
+                attention.paged_ragged_attention_pallas_tp(
+                    q, k_, v_, t, st, ql, mesh, layer=ly, impl="pallas"
+                )
+            ),
+            sds((B, 16, H, D), jnp.bfloat16, P(None, None, "tp", None)),
+            pages, pages, table, rows, rows, layer,
+        )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- what the compiler refuses, the engine refuses first ---------------------
+REFUSALS = [
+    # (id, model, tp, kv_quantize, kernel shapes (k, d, kv), Mosaic's words)
+    ("one-kv-head-per-shard", "qwen2.5-7b-instruct", 4, "",
+     (1, 128, "bf16"), r"dimension 2 must be aligned to tiling \(2\)"),
+    ("two-int8-kv-heads-per-shard", "qwen2.5-7b-instruct", 2, "int8",
+     (2, 128, "int8"), r"dimension 2 must be aligned to tiling \(4\)"),
+    # The kernel wrappers state this one themselves, ahead of Mosaic's
+    # "Slice shape along dimension 3 must be aligned to tiling (128), but
+    # is 64" (what the compiler says with the wrapper's check lifted).
+    ("head-dim-64", "bench-1b", 1, "",
+     (8, 64, "bf16"), r"needs head_dim % 128 == 0, got 64"),
+]
+
+
+@pytest.mark.parametrize(
+    "model,tp,kvq,shapes,words",
+    [r[1:] for r in REFUSALS], ids=[r[0] for r in REFUSALS],
+)
+def test_manual_dma_refusals_pinned_from_both_sides(
+    v5e, monkeypatch, model, tp, kvq, shapes, words
+):
+    """The manual-DMA kernels slice whole pages out of HBM, and Mosaic
+    wants the slice aligned to the memory tiling: head_dim to the 128
+    lanes, the kv heads of a shard to the sublane packing of the page
+    dtype (2 for bf16, 4 for int8). Short of that the chip's compiler
+    refuses the kernel — and the engine refuses the configuration at
+    init with that reason, before it builds anything; it never starts
+    as xla under the kernel's name."""
+    from opsagent_tpu.serving.engine import (
+        BackendRefused, Engine, EngineConfig,
+    )
+
+    k, d, kv = shapes
+    for s in (0, 16):
+        with pytest.raises(Exception, match=words):
+            _attention(_one_chip(v5e), "pallas_dma", kv, s, k=k, d=d)
+    cfg = get_config_preset(model)
+    assert (cfg.num_kv_heads // tp, cfg.head_dim_) == (k, d)
+    why = pallas_refusal(
+        "pallas-dma", head_dim=d, kv_heads_per_shard=k,
+        page_itemsize=1 if kvq else 2,
+    )
+    assert why is not None
+    monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", "pallas-dma")
+    monkeypatch.delenv("OPSAGENT_PALLAS_INTERPRET", raising=False)
+    with pytest.raises(BackendRefused) as refused:
+        Engine(EngineConfig(
+            model=model, tp=tp, kv_quantize=kvq, quantize="int8",
+        ))
+    assert str(refused.value) == why
+    # The aligned neighbours of each rule are the compiling cases above,
+    # and the rule says so too.
+    assert pallas_refusal(
+        "pallas-dma", head_dim=128, kv_heads_per_shard=4, page_itemsize=1
+    ) is None
+    assert pallas_refusal(
+        "pallas", head_dim=d, kv_heads_per_shard=k,
+        page_itemsize=1 if kvq else 2,
+    ) is None
+
+
+def test_mla_refuses_the_pallas_backends(monkeypatch):
+    from opsagent_tpu.serving.engine import (
+        BackendRefused, Engine, EngineConfig,
+    )
+
+    monkeypatch.delenv("OPSAGENT_PALLAS_INTERPRET", raising=False)
+    for impl in ("pallas", "pallas-dma"):
+        monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", impl)
+        with pytest.raises(BackendRefused, match="MLA"):
+            Engine(EngineConfig(model="tiny-mla"))
+
+
+def test_interpret_mode_is_an_error_on_the_chip(monkeypatch):
+    """OPSAGENT_PALLAS_INTERPRET is the CPU tests' switch; on the tpu
+    backend it would turn a kernel into a slow success that never ran
+    Mosaic, so there it raises."""
+    monkeypatch.setenv("OPSAGENT_PALLAS_INTERPRET", "1")
+    assert attention.pallas_interpret() is True  # the CPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="interpret mode is for CPU"):
+        attention.pallas_interpret()
