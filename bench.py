@@ -177,6 +177,27 @@ def metrics_snapshot() -> dict:
         return {}
 
 
+def _tick_phases(snap0: dict, snap1: dict) -> tuple[float, float]:
+    """(host work per tick in ms, share of the non-idle time spent waiting
+    for the device) between two ``metrics_snapshot()``s, from the tick
+    phase counter (opsagent_tick_phase_seconds_total, obs.phase)."""
+    def delta(key: str) -> float:
+        return float(snap1.get(key, 0.0)) - float(snap0.get(key, 0.0))
+
+    def phase(name: str) -> float:
+        return delta(f'opsagent_tick_phase_seconds_total{{phase="{name}"}}')
+
+    work = sum(
+        phase(p) for p in ("admit", "plan", "dispatch", "commit", "reap")
+    )
+    wait = phase("wait")
+    ticks = delta("opsagent_ticks_total")
+    return (
+        work / ticks * 1e3 if ticks else 0.0,
+        wait / (work + wait) if work + wait else 0.0,
+    )
+
+
 def attribution_snapshot() -> dict:
     """The goodput ledger's roofline snapshot (obs/attribution.py):
     modeled bytes by kind, MFU / HBM-utilization over the rate window,
@@ -677,10 +698,10 @@ def run_orchestrated() -> None:
     if rsessasync is not None:
         ae = rsessasync.get("extra", {})
         extra["sessions_async_tok_s_chip"] = rsessasync["value"]
-        extra["sessions_async_host_gap_p50_ms"] = ae.get("host_gap_p50_ms")
+        extra["sessions_async_host_work_ms"] = ae.get("host_work_ms")
         extra["sessions_async_sync_tok_s_chip"] = ae.get("sync_tok_s_chip")
-        extra["sessions_async_sync_host_gap_p50_ms"] = ae.get(
-            "sync_host_gap_p50_ms"
+        extra["sessions_async_sync_host_work_ms"] = ae.get(
+            "sync_host_work_ms"
         )
         extra["sessions_async_outputs_identical"] = ae.get(
             "outputs_identical"
@@ -1740,8 +1761,10 @@ def run_sessions_async(eng, model, batch, steps, prompt_len, platform,
     gets generated), and running the sync phase second hands IT the
     prefix-cache advantage — a handicap against the async phase's tok/s,
     so an async win here is conservative. Decision numbers per phase:
-    tok/s/chip, p50 TTFT, host-gap p50 (the time the device can idle
-    between mixed dispatches — the thing the overlap shrinks), and the
+    tok/s/chip, p50 TTFT, host work per tick and the share of the loop's
+    time spent blocked on the device (the tick phase counter: a sync tick
+    is work + a whole step of waiting, an async tick hides one in the
+    other, so the overlap shows as a lower wait share), and the
     overlapped-commit count proving host work actually ran while a newer
     dispatch was in flight."""
     from opsagent_tpu.serving.api import ServingStack
@@ -1765,9 +1788,10 @@ def run_sessions_async(eng, model, batch, steps, prompt_len, platform,
             float(np.median(r["ttfts"]) * 1e3) if r["ttfts"] else 0.0
         )
         r["tok_s_chip"] = r["produced"] / max(1e-9, r["wall"]) / n_chips
-        hg = get_perf_stats().get_stats().get("engine.step_host_gap", {})
-        r["host_gap_p50_ms"] = float(hg.get("p50", 0.0))
         snap1 = metrics_snapshot()
+        r["host_work_ms"], r["device_wait_share"] = _tick_phases(
+            snap0, snap1
+        )
         r["overlapped_commits"] = int(
             snap1.get("opsagent_async_overlapped_commits_total", 0)
             - snap0.get("opsagent_async_overlapped_commits_total", 0)
@@ -1779,8 +1803,9 @@ def run_sessions_async(eng, model, batch, steps, prompt_len, platform,
         log(f"bench[sessions-async/{tag}]: {batch} sessions x {rounds} "
             f"rounds, {r['produced']} tokens in {r['wall']:.2f}s -> "
             f"{r['tok_s_chip']:.0f} tok/s/chip; p50 TTFT "
-            f"{r['p50_ttft_ms']:.0f} ms; host-gap p50 "
-            f"{r['host_gap_p50_ms']:.2f} ms; overlapped commits "
+            f"{r['p50_ttft_ms']:.0f} ms; host work "
+            f"{r['host_work_ms']:.2f} ms/tick, device wait share "
+            f"{r['device_wait_share']:.2f}; overlapped commits "
             f"{r['overlapped_commits']}; errors={len(r['errors'])}")
     a, s = phases["async"], phases["sync"]
     identical = a["texts"] == s["texts"] and not a["errors"] and not s["errors"]
@@ -1794,15 +1819,14 @@ def run_sessions_async(eng, model, batch, steps, prompt_len, platform,
             "sessions": batch,
             "rounds": rounds,
             "p50_ttft_ms": round(a["p50_ttft_ms"], 1),
-            "host_gap_p50_ms": round(a["host_gap_p50_ms"], 3),
+            "host_work_ms": round(a["host_work_ms"], 3),
+            "device_wait_share": round(a["device_wait_share"], 3),
             "overlapped_commits": a["overlapped_commits"],
             "async_commits": a["async_commits"],
             "sync_tok_s_chip": round(s["tok_s_chip"], 1),
             "sync_p50_ttft_ms": round(s["p50_ttft_ms"], 1),
-            "sync_host_gap_p50_ms": round(s["host_gap_p50_ms"], 3),
-            "host_gap_delta_ms": round(
-                s["host_gap_p50_ms"] - a["host_gap_p50_ms"], 3
-            ),
+            "sync_host_work_ms": round(s["host_work_ms"], 3),
+            "sync_device_wait_share": round(s["device_wait_share"], 3),
             "tok_s_chip_delta": round(
                 a["tok_s_chip"] - s["tok_s_chip"], 1
             ),

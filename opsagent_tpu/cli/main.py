@@ -323,8 +323,7 @@ def build_parser() -> argparse.ArgumentParser:
     se.add_argument(
         "--profile-dir",
         default="",
-        help="capture jax.profiler device traces into this directory "
-             "(also enables device.* per-step timings in /api/perf/stats)",
+        help="capture jax.profiler device traces into this directory",
     )
     se.add_argument(
         "--join-fleet", default="",
@@ -713,10 +712,8 @@ def main(argv: list[str] | None = None) -> int:
 
     if args.command == "serve-engine":
         if args.profile_dir:
-            # One env var drives both the trace destination and the
-            # device.* per-step timings (utils/profiling.py reads it).
+            # The trace destination (utils/profiling.py reads it).
             os.environ["OPSAGENT_PROFILE_DIR"] = args.profile_dir
-            os.environ.setdefault("OPSAGENT_DEVICE_TIMING", "1")
         if args.platform:
             import jax
 

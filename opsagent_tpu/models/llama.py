@@ -42,9 +42,28 @@ from ..ops.attention import (
     write_pages,
 )
 from ..ops.rope import apply_rope, rope_table
+from ..utils.profiling import scoped
 from .config import ModelConfig
 
 Params = dict[str, Any]
+
+# The named scopes of a step program (``jax.named_scope`` via
+# ``utils.profiling.scoped``): every device operation of a step lies under
+# exactly one of them, innermost wins, and the device trace's reduction
+# (benchmarks/scope_reduce.py) charges its time there. The one vocabulary,
+# shared by every attention family (dense GQA, MLA, MoE blocks use the name
+# of the part they have); docs/observability.md has the same table.
+SCOPES = (
+    "embed",      # token embedding lookup
+    "attn_qkv",   # attention norm, q/k/v projections, RoPE (and its tables)
+    "kv_write",   # page-write scatter of the fresh keys and values
+    "kv_gather",  # reading pages out of the cache, and any re-tiling of it
+    "attn_core",  # scores, mask, softmax, weighted values
+    "attn_out",   # output projection and its residual
+    "ffn",        # MLP norm, dense or mixture-of-experts MLP, residual
+    "lm_head",    # final norm, last-position select, vocabulary projection
+    "sample",     # FSM mask, top-k/top-p, draw, carry (serving/decode_loop.py)
+)
 
 
 def _layer_split(cfg: ModelConfig) -> tuple[int, int]:
@@ -557,6 +576,7 @@ def _qkv(
     return (q, k, v.reshape(B, S, K, D))
 
 
+@scoped("attn_qkv")
 def _qkv_mla(
     x: jax.Array, lp: Params, cfg: ModelConfig, cos, sin,
     with_latent: bool = False,
@@ -633,6 +653,7 @@ def _dense_weight(w: Any) -> jax.Array:
     return w
 
 
+@scoped("attn_qkv")
 def _mla_latent_parts(x, lp, cfg: ModelConfig, cos, sin):
     """Weight-absorbed form for the LATENT cache: per-head latent queries
     and the per-token latent to write.
@@ -661,6 +682,7 @@ def _mla_latent_parts(x, lp, cfg: ModelConfig, cos, sin):
     return q_lat.astype(x.dtype), latent.astype(x.dtype)
 
 
+@scoped("attn_out")
 def _mla_latent_out(ctx, lp, cfg: ModelConfig):
     """Attention output over latent VALUES -> padded per-head layout.
 
@@ -695,6 +717,7 @@ def _yarn_q_scale(cfg: ModelConfig) -> float:
     return ms * ms
 
 
+@scoped("attn_qkv")
 def _qkv_rope(
     x: jax.Array, lp: Params, cfg: ModelConfig, cos, sin
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -901,15 +924,18 @@ def _run_stack(
     def make_body(moe: bool):
         def body(carry, lp):
             x, aux, kc, vc, li = carry
-            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+            with jax.named_scope("attn_qkv"):
+                h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
             attn, kc, vc = attn_fn(h, lp, kc, vc, li)
-            x = x + _mm(attn, lp["wo"])
-            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-            if moe:
-                y, layer_aux = _moe_mlp(h, lp, cfg)
-                x, aux = x + y, aux + layer_aux
-            else:
-                x = x + _mlp(h, lp)
+            with jax.named_scope("attn_out"):
+                x = x + _mm(attn, lp["wo"])
+            with jax.named_scope("ffn"):
+                h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+                if moe:
+                    y, layer_aux = _moe_mlp(h, lp, cfg)
+                    x, aux = x + y, aux + layer_aux
+                else:
+                    x = x + _mlp(h, lp)
             return (x, aux, kc, vc, li + 1), None
         return jax.checkpoint(body) if remat else body
 
@@ -957,7 +983,7 @@ def prefill(
     positions = jnp.arange(S)[None, :].repeat(B, axis=0)
     cos, sin = rope_table(positions, cfg.rope_dim_, cfg.rope_theta,
                           scaling=cfg.rope_scaling)
-    x = params["embed"][tokens].astype(dtype)
+    x = _embed(params, tokens, dtype)
     start = jnp.zeros((B,), jnp.int32)
     attn_op = prefill_attn or causal_prefill_attention
 
@@ -980,9 +1006,8 @@ def prefill(
         return attn.reshape(B, S, -1), kc, vc
 
     x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    last = jnp.clip(lengths - 1, 0, S - 1)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]  # [B, D]
+    x = _final_norm(params, cfg, x)
+    x_last = _last_valid(x, lengths)
     logits = _lm_head(params, cfg, x_last)
     return logits, cache
 
@@ -1004,7 +1029,7 @@ def prefill_with_prefix(
     positions = start[:, None] + jnp.arange(S)[None, :]
     cos, sin = rope_table(positions, cfg.rope_dim_, cfg.rope_theta,
                           scaling=cfg.rope_scaling)
-    x = params["embed"][tokens].astype(dtype)
+    x = _embed(params, tokens, dtype)
 
     def attn_fn(h, lp, kc, vc, li):
         if _latent_cache(cfg):
@@ -1026,9 +1051,8 @@ def prefill_with_prefix(
         return attn.reshape(B, S, -1), kc, vc
 
     x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    last = jnp.clip(lengths - 1, 0, S - 1)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+    x = _final_norm(params, cfg, x)
+    x_last = _last_valid(x, lengths)
     logits = _lm_head(params, cfg, x_last)
     return logits, cache
 
@@ -1061,7 +1085,7 @@ def mixed_step(
     positions = start[:, None] + jnp.arange(S)[None, :]
     cos, sin = rope_table(positions, cfg.rope_dim_, cfg.rope_theta,
                           scaling=cfg.rope_scaling)
-    x = params["embed"][tokens].astype(dtype)
+    x = _embed(params, tokens, dtype)
 
     def attn_fn(h, lp, kc, vc, li):
         if _latent_cache(cfg):
@@ -1086,9 +1110,8 @@ def mixed_step(
 
     with weight_stream_scope(weight_stream):
         x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache)
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-        last = jnp.clip(q_lens - 1, 0, S - 1)
-        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+        x = _final_norm(params, cfg, x)
+        x_last = _last_valid(x, q_lens)
         logits = _lm_head(params, cfg, x_last)
     return logits, cache
 
@@ -1117,7 +1140,7 @@ def verify_step(
     positions = start[:, None] + jnp.arange(S)[None, :]
     cos, sin = rope_table(positions, cfg.rope_dim_, cfg.rope_theta,
                           scaling=cfg.rope_scaling)
-    x = params["embed"][tokens].astype(dtype)
+    x = _embed(params, tokens, dtype)
 
     def attn_fn(h, lp, kc, vc, li):
         if _latent_cache(cfg):
@@ -1139,7 +1162,7 @@ def verify_step(
         return attn.reshape(B, S, -1), kc, vc
 
     x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    x = _final_norm(params, cfg, x)
     logits = _lm_head(params, cfg, x)
     return logits, cache
 
@@ -1163,7 +1186,7 @@ def decode_step(
     positions = lengths[:, None]                       # [B, 1]
     cos, sin = rope_table(positions, cfg.rope_dim_, cfg.rope_theta,
                           scaling=cfg.rope_scaling)
-    x = params["embed"][tokens[:, None]].astype(dtype)  # [B, 1, D]
+    x = _embed(params, tokens[:, None], dtype)          # [B, 1, D]
     valid = active.astype(jnp.int32)                   # [B] 1 new token if active
 
     def attn_fn(h, lp, kc, vc, li):
@@ -1189,11 +1212,29 @@ def decode_step(
 
     with weight_stream_scope(weight_stream):
         x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache)
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        x = _final_norm(params, cfg, x)
         logits = _lm_head(params, cfg, x[:, 0])
     return logits, cache
 
 
+@scoped("embed")
+def _embed(params: Params, tokens: jax.Array, dtype) -> jax.Array:
+    return params["embed"][tokens].astype(dtype)
+
+
+@scoped("lm_head")
+def _final_norm(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+
+
+@scoped("lm_head")
+def _last_valid(x: jax.Array, lengths: jax.Array) -> jax.Array:
+    """[B, D]: each row's hidden state at its last valid position."""
+    last = jnp.clip(lengths - 1, 0, x.shape[1] - 1)
+    return jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+
+
+@scoped("lm_head")
 def _lm_head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     if cfg.tie_embeddings:
         return (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
@@ -1219,7 +1260,7 @@ def forward_full(
     positions = jnp.arange(S)[None, :].repeat(B, axis=0)
     cos, sin = rope_table(positions, cfg.rope_dim_, cfg.rope_theta,
                           scaling=cfg.rope_scaling)
-    x = params["embed"][tokens].astype(dtype)
+    x = _embed(params, tokens, dtype)
     attn_op = prefill_attn or causal_prefill_attention
 
     def attn_fn(h, lp, kc, vc, li):
@@ -1228,6 +1269,6 @@ def forward_full(
         return attn.reshape(B, S, -1), kc, vc
 
     x, _, aux = _run_stack(params, cfg, x, attn_fn, cache=None, remat=remat)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    x = _final_norm(params, cfg, x)
     logits = _lm_head(params, cfg, x)
     return (logits, aux) if return_aux else logits

@@ -121,11 +121,55 @@ MIXED_BUDGET_UTILIZATION = _reg.histogram(
 # -- async mixed serving runtime (serving/async_runtime.py) -------------------
 STEP_HOST_GAP_SECONDS = _reg.histogram(
     "opsagent_step_host_gap_seconds",
-    "Host-side gap between consecutive mixed-tick device dispatches "
-    "(enqueue-return to next enqueue — time the device can go idle "
-    "waiting on host work), by tick mode (sync = async_depth 1, "
-    "async = one-step-lookahead pipeline)",
+    "Dispatch-to-dispatch interval of back-to-back mixed ticks (one "
+    "enqueue returning to the next enqueue starting), by tick mode "
+    "(sync = async_depth 1, async = one-step-lookahead pipeline). NOT "
+    "host work: it includes the wait for the device at the token pull; "
+    "opsagent_tick_phase_seconds_total splits it into work and wait",
     labelnames=("mode",),
+    buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+             0.025, 0.05, 0.1, 0.25, 1.0),
+)
+# -- tick phases + step clock (obs.phase, obs.StepClock below) ----------------
+TICK_PHASES = ("admit", "plan", "dispatch", "wait", "commit", "reap", "idle")
+TICK_PHASE_SECONDS = _reg.counter(
+    "opsagent_tick_phase_seconds_total",
+    "Seconds the thread that drives the engine (in serving, the "
+    "scheduler thread) spent in each tick phase: admit / plan / dispatch "
+    "/ wait (blocked on a device array) / commit / reap / idle. The "
+    "phases partition that thread's time: over any interval their "
+    "deltas sum to the interval",
+    labelnames=("phase",),
+)
+TICKS = _reg.counter(
+    "opsagent_ticks_total",
+    "Scheduler loop iterations that had work (a running or admitting "
+    "request after admission)",
+)
+STEP_DEVICE_SECONDS = _reg.histogram(
+    "opsagent_step_device_seconds",
+    "Device time of one dispatched step, by program (mixed / "
+    "decode_block / spec / ffwd / prefill_chunk) and bucket (chunk "
+    "bucket or block length), from the step clock: ready_k - "
+    "max(ready_k-1, enqueued_k), sampled only when the pull waited for "
+    "the step (no profiler, no extra sync)",
+    labelnames=("program", "bucket"),
+    buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
+             1.0, 2.5, 5.0, 10.0),
+)
+STEP_LATE_PULLS = _reg.counter(
+    "opsagent_step_late_pulls_total",
+    "Pulls that gave the step clock no sample: the result was already "
+    "ready when the host arrived (the device had been waiting for the "
+    "host), or the step's start was not known",
+    labelnames=("program",),
+)
+STREAM_EMIT_LAG_SECONDS = _reg.histogram(
+    "opsagent_stream_emit_lag_seconds",
+    "Token hand-off lag of a streamed response: from the scheduler "
+    "thread handing a token to on_token to the HTTP handler passing the "
+    "chunk that carries it to resp.write (queue + executor hop + event "
+    "loop), once per content chunk",
     buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
              0.025, 0.05, 0.1, 0.25, 1.0),
 )
@@ -614,12 +658,14 @@ def metrics_snapshot() -> dict:
 # SLO gauges join the scrape as a collector. ``attribution`` (the
 # roofline cost ledger + goodput counters) and ``timeline`` (per-request
 # phase assembly over the flight ring + trace store) complete the
-# goodput-ledger surface.
+# goodput-ledger surface. ``tick`` holds the tick-phase helper and the
+# step clock.
 from . import flight  # noqa: E402,F401
 from . import slo  # noqa: E402,F401
 from . import attribution  # noqa: E402,F401
 from . import timeline  # noqa: E402,F401
 from . import history  # noqa: E402,F401
+from .tick import StepClock, phase  # noqa: E402,F401
 
 flight.install_compile_watchdog()
 _reg.add_collector(lambda: slo.get_watchdog().collect())

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..utils.profiling import scoped
+
 NEG_INF = -1e30
 
 # Upper bound on one block's f32 score matrix in ``paged_ragged_attention``
@@ -262,6 +264,7 @@ def paged_decode_attention_pallas_tp(
     return mapped(q, k_pages, v_pages, page_table, lengths, layer)
 
 
+@scoped("attn_core")
 def paged_decode_attention_auto(
     q: jax.Array,
     k_pages: jax.Array,
@@ -295,6 +298,7 @@ def paged_decode_attention_auto(
     )
 
 
+@scoped("attn_core")
 def causal_prefill_attention(
     q: jax.Array,        # [B, S, H, D]
     k: jax.Array,        # [B, S, K, D]
@@ -330,6 +334,7 @@ def causal_prefill_attention(
     return out.reshape(B, S, H, D).astype(q.dtype)
 
 
+@scoped("kv_write")
 def write_kv_pages(
     k_pages: jax.Array,     # [N, P, K, D] — or [L, N, P, K, D] with layer
     v_pages: jax.Array,     # like k_pages
@@ -362,6 +367,7 @@ def write_kv_pages(
     return k_pages, v_pages
 
 
+@scoped("kv_write")
 def write_pages(
     pages: jax.Array,       # [N, P, K, D] — or [L, N, P, K, D] with layer
     new: jax.Array,         # [B, S, K, D]
@@ -462,6 +468,7 @@ def _write_scale_pages(
     return pf.reshape(shape)
 
 
+@scoped("kv_gather")
 def _gather_kv(
     k_pages, v_pages, page_table: jax.Array, layer, dtype
 ) -> tuple[jax.Array, jax.Array]:
@@ -501,6 +508,7 @@ def _gather_kv(
     return k_seq, v_seq
 
 
+@scoped("attn_core")
 def paged_ragged_attention(
     q: jax.Array,           # [B, S, H, D] queries (right-padded per row)
     k_pages: jax.Array,     # [N, P, K, D] — or [L, N, P, K, D] with layer
@@ -679,6 +687,7 @@ def paged_ragged_attention_pallas_tp(
     return mapped(q, k_pages, v_pages, page_table, start, q_lens, layer)
 
 
+@scoped("attn_core")
 def paged_ragged_attention_auto(
     q: jax.Array,           # [B, S, H, D]
     k_pages: jax.Array,
@@ -713,6 +722,7 @@ def paged_ragged_attention_auto(
     )
 
 
+@scoped("attn_core")
 def paged_decode_attention(
     q: jax.Array,           # [B, H, D] (one new token per sequence)
     k_pages: jax.Array,     # [N, P, K, D] — or [L, N, P, K, D] with layer

@@ -13,6 +13,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..utils.profiling import scoped
+
 
 def yarn_get_mscale(scale: float, mscale: float) -> float:
     """YaRN attention-magnitude correction (HF yarn_get_mscale)."""
@@ -82,6 +84,7 @@ def _scaled_freqs(head_dim: int, theta: float, scaling) -> tuple[jnp.ndarray, fl
     raise ValueError(f"unknown rope scaling type {scaling.rope_type!r}")
 
 
+@scoped("attn_qkv")
 def rope_table(
     positions: jax.Array, head_dim: int, theta: float, scaling=None
 ) -> tuple[jax.Array, jax.Array]:

@@ -35,6 +35,16 @@ from .scheduler import Request, RequestError, Scheduler
 log = get_logger("serving.api")
 
 
+class _StampedChunk(dict):
+    """A streamed content chunk that remembers when the scheduler thread
+    handed over the newest token it carries (``time.perf_counter()``): the
+    one stamp that travels from ``on_token`` to the HTTP handler, which
+    reads it at ``resp.write`` (``opsagent_stream_emit_lag_seconds``). It
+    serialises as the plain dict it is."""
+
+    stamp: float = 0.0
+
+
 class _SpanFinisher:
     """Duck-types ``Trace.finish()`` for a nested fleet-hop leg span:
     the completion paths call ``owned.finish()`` in their finally
@@ -510,7 +520,8 @@ class ServingStack:
             raise RequestError(f"invalid n: {e}", 400) from e
         if n != 1:
             raise RequestError("n > 1 is not supported with stream", 400)
-        token_q: "queue.Queue[int | None]" = queue.Queue()
+        # (token, when the scheduler thread handed it over) or None (end).
+        token_q: "queue.Queue[tuple[int, float] | None]" = queue.Queue()
         owned, parent, cid = self._request_trace(hop)
         self._stamp_class(parent, body)
         gen_span = (
@@ -519,7 +530,8 @@ class ServingStack:
         )
         req = Request(
             prompt_ids, sampling, mask_fn=mask_fn,
-            on_token=lambda t: token_q.put(t), trace=gen_span,
+            on_token=lambda t: token_q.put((t, time.perf_counter())),
+            trace=gen_span,
         )
         self.scheduler.submit(req)
         created = int(time.time())
@@ -563,12 +575,20 @@ class ServingStack:
         if first_tok is None and req.error:
             raise RequestError(req.error, req.error_status)
         yield chunk({"role": "assistant", "content": ""})
+        handed = 0.0    # when the newest token read so far was handed over
 
         def _tokens():
-            t = first_tok
-            while t is not None:
+            nonlocal handed
+            item = first_tok
+            while item is not None:
+                t, handed = item
                 yield t
-                t = token_q.get()
+                item = token_q.get()
+
+        def content(text: str) -> _StampedChunk:
+            out = _StampedChunk(chunk({"content": text}))
+            out.stamp = handed
+            return out
         # Incremental detokenization with a SLIDING window (vLLM-style):
         # decode only tokens[prefix_off:] and diff against the same window's
         # previous decode, so per-token cost is O(window), not O(total).
@@ -620,12 +640,12 @@ class ServingStack:
             else:
                 emit, pending = pending, ""
             if emit:
-                yield chunk({"content": emit})
+                yield content(emit)
         if req.error:
             yield {"error": {"message": req.error}}
             return
         if not stopped and pending:
-            yield chunk({"content": pending})
+            yield content(pending)
         finish = "stop" if stopped else (req.finish_reason or "length")
         yield chunk({}, finish=finish)
 
@@ -858,6 +878,10 @@ def build_engine_app(stack: ServingStack, membership=None):
             chunk = first
             try:
                 while chunk is not None:
+                    if isinstance(chunk, _StampedChunk):
+                        obs.STREAM_EMIT_LAG_SECONDS.observe(
+                            time.perf_counter() - chunk.stamp
+                        )
                     await resp.write(
                         b"data: " + json.dumps(chunk).encode("utf-8") + b"\n\n"
                     )

@@ -71,8 +71,11 @@ class _Tick:
     # dispatch appended BEFORE its sampled token (committed in order
     # ahead of the pull; see _dispatch's ffwd planning).
     ffwd: dict = field(default_factory=dict)
-    t_disp: float = 0.0
-    tick_id: int = 0
+    # The step clock's ticket (engine-wide step number, enqueue time): the
+    # number is the tick id that the flight event, the phase spans and the
+    # per-request span children carry.
+    ticket: tuple[int, float] = (0, 0.0)
+    bucket: int = 0
 
 
 class AsyncMixedRuntime:
@@ -97,7 +100,6 @@ class AsyncMixedRuntime:
         # warmup, sync-lane fallbacks — commit into this buffer so a
         # finished admission can never be lost between scheduler ticks).
         self._results: tuple[dict, dict] = ({}, {})
-        self._tick_id = 0
 
     # -- public surface (via Engine wrappers) -------------------------------
     @property
@@ -422,14 +424,15 @@ class AsyncMixedRuntime:
                     ov_fsm[lane] = _walk(fsm, seq.tokens)
 
         perf = get_perf_stats()
-        now = time.perf_counter()
+        ticket = eng.step_clock.enqueue()
+        tick_id, t_disp = ticket
         if eng._mixed_gap_stamp is not None:
-            gap = now - eng._mixed_gap_stamp
-            obs.STEP_HOST_GAP_SECONDS.observe(gap, mode="async")
-            perf.record_metric("engine.step_host_gap", gap * 1e3, "ms")
-        t_disp = time.perf_counter()
+            obs.STEP_HOST_GAP_SECONDS.observe(
+                t_disp - eng._mixed_gap_stamp, mode="async"
+            )
         try:
-            with annotate("engine.mixed_step_async"), eng.mesh_ctx():
+            with obs.phase("dispatch", tick=tick_id), \
+                    annotate("engine.mixed_step_async"), eng.mesh_ctx():
                 eng._sample_key, sub = jax.random.split(eng._sample_key)
                 carry = eng._async_carry
                 if carry is None:
@@ -538,7 +541,6 @@ class AsyncMixedRuntime:
                         attr=getattr(eng, "attr", None),
                         request_id=obs.flight.request_id_of(s.trace),
                     )
-        self._tick_id += 1
         obs.flight.record(
             "dispatch", op="mixed",
             decode_seq_ids=[s.seq_id for s, _ in dec_rows],
@@ -546,7 +548,7 @@ class AsyncMixedRuntime:
             bucket=int(S), prefill_tokens=n_prefill,
             forced_tokens=n_forced,
             budget=cfg.max_step_tokens,
-            tick=self._tick_id, pipeline_pos=len(self._pending),
+            tick=tick_id, pipeline_pos=len(self._pending),
         )
         # Book-keeping AFTER the dispatch succeeded: planned prefill
         # progress advances (the write is enqueued — deterministic), the
@@ -578,8 +580,8 @@ class AsyncMixedRuntime:
             decode=[(s.seq_id, lane) for s, lane in dec_rows],
             chunks=chk_rows,
             ffwd={sid: plan[1] for sid, plan in ffwd_plan.items()},
-            t_disp=t_disp,
-            tick_id=self._tick_id,
+            ticket=ticket,
+            bucket=int(S),
         ))
         return True
 
@@ -597,10 +599,21 @@ class AsyncMixedRuntime:
         perf = get_perf_stats()
         overlapped = bool(self._pending)
         t0 = time.perf_counter()
-        sampled = np.asarray(tick.toks_d)
+        sampled = eng._pull("mixed", tick.bucket, tick.ticket, tick.toks_d)
         perf.record_metric(
             "engine.async_pull", (time.perf_counter() - t0) * 1e3, "ms"
         )
+        with obs.phase("commit", tick=tick.ticket[0]):
+            self._commit(tick, sampled, overlapped)
+
+    def _commit(
+        self, tick: _Tick, sampled: np.ndarray, overlapped: bool
+    ) -> None:
+        """Fold one pulled tick into host state: accept, stop scan,
+        detokenize, stream, roll finished rows' bookings back."""
+        eng = self.eng
+        perf = get_perf_stats()
+        tick_id, t_disp = tick.ticket
         decode_out, prefill_out = self._results
         produced = 0
         for sid, lane in tick.decode:
@@ -641,7 +654,8 @@ class AsyncMixedRuntime:
             if dspan is not None:
                 dspan.child(
                     "ffwd_step" if pre else "mixed_step",
-                    tick.t_disp, time.perf_counter(), tokens=accepted,
+                    t_disp, time.perf_counter(), tokens=accepted,
+                    tick=tick_id,
                 )
             if s.done:
                 # Roll bookings (including any still-in-flight lookahead

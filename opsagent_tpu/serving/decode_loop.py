@@ -199,23 +199,31 @@ def mixed_step_carry(
     input (a don't-care the host never reads). The FSM state advances only
     on emitting rows, so a chunk whose sampled token is discarded cannot
     corrupt a constrained row's grammar walk."""
-    first = jnp.where(use_carry, carry_tok, tokens[:, 0]).astype(jnp.int32)
-    tokens = tokens.at[:, 0].set(first)
+    with jax.named_scope("sample"):     # the carry splice (llama.SCOPES)
+        first = jnp.where(
+            use_carry, carry_tok, tokens[:, 0]
+        ).astype(jnp.int32)
+        tokens = tokens.at[:, 0].set(first)
     logits, cache = llama.mixed_step(
         params, cfg, tokens, starts, q_lens, cache, page_table,
         dtype=dtype, attn_impl=attn_impl, mesh=mesh,
         weight_stream=weight_stream,
     )
     with_fsm = fsm_mask is not None
-    if with_fsm:
-        fstate = jnp.where(use_carry, carry_fsm, ov_fsm).astype(jnp.int32)
-        logits = jnp.where(fsm_mask[fstate], logits, NEG_INF)
-    tok = sample(logits, key, temps, top_k, top_p, None).astype(jnp.int32)
-    out = jnp.where(emits, tok, first)
-    if with_fsm:
-        fsm_out = jnp.where(emits, fsm_dest[fstate, tok], fstate)
-    else:
-        fsm_out = jnp.zeros_like(out)
+    with jax.named_scope("sample"):
+        if with_fsm:
+            fstate = jnp.where(
+                use_carry, carry_fsm, ov_fsm
+            ).astype(jnp.int32)
+            logits = jnp.where(fsm_mask[fstate], logits, NEG_INF)
+        tok = sample(
+            logits, key, temps, top_k, top_p, None
+        ).astype(jnp.int32)
+        out = jnp.where(emits, tok, first)
+        if with_fsm:
+            fsm_out = jnp.where(emits, fsm_dest[fstate, tok], fstate)
+        else:
+            fsm_out = jnp.zeros_like(out)
     return out, cache, fsm_out
 
 
@@ -333,23 +341,24 @@ def decode_block_carry(
             dtype=dtype, attn_impl=attn_impl, mesh=mesh,
             weight_stream=weight_stream,
         )
-        if with_fsm:
-            # Grammar mask from the per-row DFA state: one [B, V] gather,
-            # no host round trip. NEG_INF (not -inf): masked logits feed
-            # a softmax in the sampled path.
-            logits = jnp.where(fsm_mask[fstate], logits, -1e30)
-        if greedy:
-            nxt = jnp.argmax(logits, axis=-1)
-        else:
-            key, sub = jax.random.split(key)
-            nxt = sample(logits, sub, temps, top_k, top_p, None)
-        nxt = jnp.where(act, nxt, tok).astype(jnp.int32)
-        emitted = jnp.where(act, nxt, pad_id).astype(jnp.int32)
-        if with_fsm:
-            fstate = jnp.where(act, fsm_dest[fstate, nxt], fstate)
-        at = at + act.astype(jnp.int32)
-        eos = eos | (act & (nxt == eos_id))
-        act = act & ~eos & (step_idx + 1 < budgets)
+        with jax.named_scope("sample"):
+            if with_fsm:
+                # Grammar mask from the per-row DFA state: one [B, V]
+                # gather, no host round trip. NEG_INF (not -inf): masked
+                # logits feed a softmax in the sampled path.
+                logits = jnp.where(fsm_mask[fstate], logits, -1e30)
+            if greedy:
+                nxt = jnp.argmax(logits, axis=-1)
+            else:
+                key, sub = jax.random.split(key)
+                nxt = sample(logits, sub, temps, top_k, top_p, None)
+            nxt = jnp.where(act, nxt, tok).astype(jnp.int32)
+            emitted = jnp.where(act, nxt, pad_id).astype(jnp.int32)
+            if with_fsm:
+                fstate = jnp.where(act, fsm_dest[fstate, nxt], fstate)
+            at = at + act.astype(jnp.int32)
+            eos = eos | (act & (nxt == eos_id))
+            act = act & ~eos & (step_idx + 1 < budgets)
         return (nxt, at, eos, act, fstate, cache, key), emitted
 
     (tok, at, eos, _, fstate, cache, key), toks = jax.lax.scan(
@@ -438,49 +447,51 @@ def speculative_block_carry(
 
     def body(carry, _):
         tok, at, eos, act, emitted, cache, hist = carry
-        rem = budgets - emitted
-        draft = ngram_draft(hist, at, tok, k, ngram)
-        inputs = jnp.concatenate([tok[:, None], draft], axis=1)    # [B, k+1]
-        valid = jnp.where(act, jnp.minimum(k + 1, rem), 0)
+        with jax.named_scope("sample"):    # draft (llama.SCOPES)
+            rem = budgets - emitted
+            draft = ngram_draft(hist, at, tok, k, ngram)
+            inputs = jnp.concatenate([tok[:, None], draft], axis=1)  # [B, k+1]
+            valid = jnp.where(act, jnp.minimum(k + 1, rem), 0)
         logits, cache = llama.verify_step(
             params, cfg, inputs, at, valid, cache, page_table, dtype=dtype
         )
-        a = jnp.argmax(logits, axis=-1).astype(jnp.int32)          # [B, k+1]
-        match = (draft == a[:, :k]).astype(jnp.int32)
-        prefix_ok = jnp.cumprod(match, axis=1)                     # [B, k]
-        can = jnp.concatenate(
-            [jnp.ones((B, 1), jnp.int32), prefix_ok], axis=1
-        )                                                          # [B, k+1]
-        no_eos_before = jnp.cumprod(
-            jnp.concatenate(
-                [jnp.ones((B, 1), jnp.int32),
-                 (a[:, :k] != eos_id).astype(jnp.int32)],
+        with jax.named_scope("sample"):    # accept
+            a = jnp.argmax(logits, axis=-1).astype(jnp.int32)      # [B, k+1]
+            match = (draft == a[:, :k]).astype(jnp.int32)
+            prefix_ok = jnp.cumprod(match, axis=1)                 # [B, k]
+            can = jnp.concatenate(
+                [jnp.ones((B, 1), jnp.int32), prefix_ok], axis=1
+            )                                                      # [B, k+1]
+            no_eos_before = jnp.cumprod(
+                jnp.concatenate(
+                    [jnp.ones((B, 1), jnp.int32),
+                     (a[:, :k] != eos_id).astype(jnp.int32)],
+                    axis=1,
+                ),
                 axis=1,
-            ),
-            axis=1,
-        )
-        emit = (
-            (can * no_eos_before) > 0
-        ) & (iota < rem[:, None]) & act[:, None]
-        n_emit = jnp.sum(emit, axis=1).astype(jnp.int32)           # [B]
-        out_toks = jnp.where(emit, a, pad_id).astype(jnp.int32)
-        eos_new = eos | jnp.any(emit & (a == eos_id), axis=1)
-        # History: the ACCEPTED inputs land at positions at..at+n_emit-1.
-        wpos = at[:, None] + iota
-        H = hist.shape[1]
-        hpos = jnp.where(
-            (iota < n_emit[:, None]) & (wpos < H), wpos, H
-        )
-        hist = jax.vmap(
-            lambda h, p, v: h.at[p].set(v, mode="drop")
-        )(hist, hpos, inputs)
-        last = jnp.take_along_axis(
-            a, jnp.clip(n_emit - 1, 0, k)[:, None], axis=1
-        )[:, 0]
-        tok = jnp.where(n_emit > 0, last, tok)
-        at = at + n_emit
-        emitted = emitted + n_emit
-        act = act & ~eos_new & (emitted < budgets)
+            )
+            emit = (
+                (can * no_eos_before) > 0
+            ) & (iota < rem[:, None]) & act[:, None]
+            n_emit = jnp.sum(emit, axis=1).astype(jnp.int32)       # [B]
+            out_toks = jnp.where(emit, a, pad_id).astype(jnp.int32)
+            eos_new = eos | jnp.any(emit & (a == eos_id), axis=1)
+            # History: the ACCEPTED inputs land at positions at..at+n_emit-1.
+            wpos = at[:, None] + iota
+            H = hist.shape[1]
+            hpos = jnp.where(
+                (iota < n_emit[:, None]) & (wpos < H), wpos, H
+            )
+            hist = jax.vmap(
+                lambda h, p, v: h.at[p].set(v, mode="drop")
+            )(hist, hpos, inputs)
+            last = jnp.take_along_axis(
+                a, jnp.clip(n_emit - 1, 0, k)[:, None], axis=1
+            )[:, 0]
+            tok = jnp.where(n_emit > 0, last, tok)
+            at = at + n_emit
+            emitted = emitted + n_emit
+            act = act & ~eos_new & (emitted < budgets)
         return (tok, at, eos_new, act, emitted, cache, hist), (
             out_toks, n_emit
         )

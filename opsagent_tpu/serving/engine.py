@@ -34,7 +34,7 @@ from ..models.config import ModelConfig, get_config_preset
 from ..parallel.mesh import make_mesh, shard_params, spec_tree_shardings
 from ..utils.logger import get_logger
 from ..utils.perf import get_perf_stats
-from ..utils.profiling import annotate, device_timer
+from ..utils.profiling import annotate
 from .kvcache import InvalidRequest, PageAllocator, OutOfPages
 from .sampler import SamplingParams, sample
 from .tokenizer import Tokenizer, load_tokenizer
@@ -835,8 +835,14 @@ class Engine:
         self._ffwd_noted: set[int] = set()
         # Wall-clock stamp of the last mixed dispatch's enqueue return,
         # shared by the sync and async tick paths: the gap to the next
-        # dispatch is the opsagent_step_host_gap_seconds observable.
+        # dispatch is the opsagent_step_host_gap_seconds observable
+        # (dispatch to dispatch: the wait for the device is inside it).
         self._mixed_gap_stamp: float | None = None
+        # Device time of every dispatched step, learnt at its pull
+        # (opsagent_step_device_seconds). A step's ticket number is also
+        # its tick id: the flight "dispatch" event, the engine.dispatch /
+        # wait / commit spans and the per-request span children carry it.
+        self.step_clock = obs.StepClock()
 
         if cfg.warmup:
             self.warmup()
@@ -1651,9 +1657,9 @@ class Engine:
                     starts[i] = d
                     lens[i] = c
                     tables[i] = self.alloc.page_table_row(sid)
-                dev_out: list = []
-                with annotate("engine.prefill_chunk"), \
-                        device_timer("prefill_chunk", dev_out), self.mesh_ctx():
+                ticket = self.step_clock.enqueue()
+                with obs.phase("dispatch", tick=ticket[0]), \
+                        annotate("engine.prefill_chunk"), self.mesh_ctx():
                     logits, self.cache = self._prefill_prefix_jit(
                         self.params,
                         jnp.asarray(tokens),
@@ -1662,7 +1668,6 @@ class Engine:
                         self.cache,
                         jnp.asarray(tables),
                     )
-                    dev_out.append(logits)
                 perf = get_perf_stats()
                 perf.record_metric(
                     "engine.prefill_tokens", int(sum(chunks)), "tok"
@@ -1671,7 +1676,7 @@ class Engine:
                 obs.flight.record(
                     "dispatch", op="prefill_batch", seq_ids=list(seq_ids),
                     bucket=bucket, rows=len(seq_ids),
-                    prefill_tokens=int(sum(chunks)),
+                    prefill_tokens=int(sum(chunks)), tick=ticket[0],
                 )
                 self.attr.dispatch(
                     "prefill_batch",
@@ -1717,31 +1722,36 @@ class Engine:
                         seqs[i] if i in fset else None
                         for i in range(len(seq_ids))
                     ] + [None] * (Bp - len(seq_ids))
-                    first_toks = self._sample_one(logits, row_seqs)
-                for i, (sid, seq, d, c) in enumerate(
-                    zip(seq_ids, seqs, dones, chunks)
-                ):
-                    if i in bad:
-                        self._drop_admission(sid)
-                        out[sid] = bad[i]
-                        continue
-                    if d + c < seq.prompt_len:
-                        self._prefilling[sid] = d + c
-                        out[sid] = False
-                        continue
-                    del self._prefilling[sid]
-                    token = int(first_toks[i])
-                    seq.ttft_s = time.perf_counter() - seq.started_s
-                    perf.record_metric("engine.ttft", seq.ttft_s * 1e3, "ms")
-                    self._first_token_obs(seq)
-                    try:
-                        self._accept_token(seq, token)
-                    except Exception as e:  # noqa: BLE001 - stream callback
-                        self._drop_admission(sid)
-                        out[sid] = e
-                        continue
-                    out[sid] = True
-                self._observe_occupancy()
+                    first_toks = self._sample_one(
+                        logits, row_seqs, ("prefill_chunk", bucket, ticket)
+                    )
+                with obs.phase("commit", tick=ticket[0]):
+                    for i, (sid, seq, d, c) in enumerate(
+                        zip(seq_ids, seqs, dones, chunks)
+                    ):
+                        if i in bad:
+                            self._drop_admission(sid)
+                            out[sid] = bad[i]
+                            continue
+                        if d + c < seq.prompt_len:
+                            self._prefilling[sid] = d + c
+                            out[sid] = False
+                            continue
+                        del self._prefilling[sid]
+                        token = int(first_toks[i])
+                        seq.ttft_s = time.perf_counter() - seq.started_s
+                        perf.record_metric(
+                            "engine.ttft", seq.ttft_s * 1e3, "ms"
+                        )
+                        self._first_token_obs(seq)
+                        try:
+                            self._accept_token(seq, token)
+                        except Exception as e:  # noqa: BLE001 - stream cb
+                            self._drop_admission(sid)
+                            out[sid] = e
+                            continue
+                        out[sid] = True
+                    self._observe_occupancy()
                 return out
             except Exception:
                 for sid in seq_ids:
@@ -1780,9 +1790,9 @@ class Engine:
                 bucket = self._bucket(chunk)
                 tokens = np.full((1, bucket), self.tokenizer.pad_id, np.int32)
                 tokens[0, :chunk] = seq.prompt_ids[done:done + chunk]
-                dev_out: list = []
-                with annotate("engine.prefill_chunk"), \
-                        device_timer("prefill_chunk", dev_out), self.mesh_ctx():
+                ticket = self.step_clock.enqueue()
+                with obs.phase("dispatch", tick=ticket[0]), \
+                        annotate("engine.prefill_chunk"), self.mesh_ctx():
                     if done:
                         logits, self.cache = self._prefill_prefix_jit(
                             self.params,
@@ -1800,7 +1810,6 @@ class Engine:
                             self.cache,
                             table,
                         )
-                    dev_out.append(logits)
                 done += chunk
                 perf = get_perf_stats()
                 perf.record_metric("engine.prefill_tokens", chunk, "tok")
@@ -1808,7 +1817,7 @@ class Engine:
                 obs.flight.record(
                     "dispatch", op="prefill_chunk", seq_id=seq_id,
                     bucket=bucket, prefill_tokens=chunk,
-                    prompt_done=done, prompt_total=n,
+                    prompt_done=done, prompt_total=n, tick=ticket[0],
                 )
                 self.attr.dispatch(
                     "prefill_chunk",
@@ -1823,12 +1832,15 @@ class Engine:
                     self._prefilling[seq_id] = done
                     return False
                 del self._prefilling[seq_id]
-                token = int(self._sample_one(logits, [seq])[0])
-                seq.ttft_s = time.perf_counter() - seq.started_s
-                perf.record_metric("engine.ttft", seq.ttft_s * 1e3, "ms")
-                self._first_token_obs(seq)
-                self._accept_token(seq, token)
-                self._observe_occupancy()
+                token = int(self._sample_one(
+                    logits, [seq], ("prefill_chunk", bucket, ticket)
+                )[0])
+                with obs.phase("commit", tick=ticket[0]):
+                    seq.ttft_s = time.perf_counter() - seq.started_s
+                    perf.record_metric("engine.ttft", seq.ttft_s * 1e3, "ms")
+                    self._first_token_obs(seq)
+                    self._accept_token(seq, token)
+                    self._observe_occupancy()
                 return True
             except Exception:
                 # Failed admissions (prefill OOM, raising mask_fn, a raising
@@ -1983,19 +1995,20 @@ class Engine:
             slots += [None] * (B - len(slots))
             temps, top_k, top_p, _ = self._sampling_arrays(slots, B)
             perf = get_perf_stats()
-            t_disp = time.perf_counter()
-            # Host-gap observable (the async A/B's comparison basis): time
-            # since the previous mixed dispatch's enqueue returned — in
-            # this SYNC tick it spans the blocking token pull plus all
-            # host post-processing, the span the async runtime overlaps.
+            ticket = self.step_clock.enqueue()
+            tick_id, t_disp = ticket
+            # Dispatch-to-dispatch interval (the async A/B's comparison
+            # basis): time since the previous mixed dispatch's enqueue
+            # returned — in this SYNC tick it spans the blocking token
+            # pull (the device's whole step) plus all host
+            # post-processing, the span the async runtime overlaps.
             if self._mixed_gap_stamp is not None:
-                gap = t_disp - self._mixed_gap_stamp
-                obs.STEP_HOST_GAP_SECONDS.observe(gap, mode="sync")
-                perf.record_metric("engine.step_host_gap", gap * 1e3, "ms")
+                obs.STEP_HOST_GAP_SECONDS.observe(
+                    t_disp - self._mixed_gap_stamp, mode="sync"
+                )
             try:
-                dev_out: list = []
-                with annotate("engine.mixed_step"), \
-                        device_timer("mixed_step", dev_out), self.mesh_ctx():
+                with obs.phase("dispatch", tick=tick_id), \
+                        annotate("engine.mixed_step"), self.mesh_ctx():
                     self._sample_key, sub = jax.random.split(self._sample_key)
                     toks_d, self.cache = self._mixed_sample_jit(
                         self.params,
@@ -2009,9 +2022,8 @@ class Engine:
                         jnp.asarray(top_k),
                         jnp.asarray(top_p),
                     )
-                    dev_out.append(toks_d)
                 self._mixed_gap_stamp = time.perf_counter()
-                sampled = np.asarray(toks_d)
+                sampled = self._pull("mixed", int(S), ticket, toks_d)
             except Exception:
                 # The decode rows' +1 bookings are for tokens this failed
                 # dispatch never wrote; leaving them would put an
@@ -2064,47 +2076,49 @@ class Engine:
                 decode_seq_ids=[s.seq_id for s in decode],
                 prefill_seq_ids=[sid for sid, *_ in chunk_info],
                 bucket=int(S), prefill_tokens=n_prefill,
-                budget=self.cfg.max_step_tokens,
+                budget=self.cfg.max_step_tokens, tick=tick_id,
             )
-            for i, s in enumerate(decode):
-                tok = int(sampled[i])
-                dspan = s.decode_span
-                try:
-                    self._accept_token(s, tok)
-                except Exception:  # noqa: BLE001 - raising stream callback
-                    # Row-local isolation WITHOUT propagation: the reap
-                    # path surfaces finish_reason "error"; raising here
-                    # would lose the same dispatch's prefill results.
-                    s.done = True
-                    s.finish_reason = s.finish_reason or "error"
-                    self.alloc.truncate(s.seq_id, self._host_written(s))
-                decode_out[s.seq_id] = [tok]
-                if dspan is not None:
-                    dspan.child(
-                        "mixed_step", t_disp, time.perf_counter(), tokens=1
+            with obs.phase("commit", tick=tick_id):
+                for i, s in enumerate(decode):
+                    tok = int(sampled[i])
+                    dspan = s.decode_span
+                    try:
+                        self._accept_token(s, tok)
+                    except Exception:  # noqa: BLE001 - raising stream callback
+                        # Row-local isolation WITHOUT propagation: the reap
+                        # path surfaces finish_reason "error"; raising here
+                        # would lose the same dispatch's prefill results.
+                        s.done = True
+                        s.finish_reason = s.finish_reason or "error"
+                        self.alloc.truncate(s.seq_id, self._host_written(s))
+                    decode_out[s.seq_id] = [tok]
+                    if dspan is not None:
+                        dspan.child(
+                            "mixed_step", t_disp, time.perf_counter(),
+                            tokens=1, tick=tick_id,
+                        )
+                for j, (sid, seq, done, c) in enumerate(chunk_info):
+                    if done + c < seq.prompt_len:
+                        self._prefilling[sid] = done + c
+                        prefill_out[sid] = False
+                        continue
+                    del self._prefilling[sid]
+                    token = int(sampled[base + j])
+                    seq.ttft_s = time.perf_counter() - seq.started_s
+                    perf.record_metric("engine.ttft", seq.ttft_s * 1e3, "ms")
+                    self._first_token_obs(seq)
+                    try:
+                        self._accept_token(seq, token)
+                    except Exception as e:  # noqa: BLE001 - stream callback
+                        self._drop_admission(sid)
+                        prefill_out[sid] = e
+                        continue
+                    prefill_out[sid] = True
+                if decode:
+                    perf.record_metric(
+                        "engine.decode_tokens", len(decode), "tok"
                     )
-            for j, (sid, seq, done, c) in enumerate(chunk_info):
-                if done + c < seq.prompt_len:
-                    self._prefilling[sid] = done + c
-                    prefill_out[sid] = False
-                    continue
-                del self._prefilling[sid]
-                token = int(sampled[base + j])
-                seq.ttft_s = time.perf_counter() - seq.started_s
-                perf.record_metric("engine.ttft", seq.ttft_s * 1e3, "ms")
-                self._first_token_obs(seq)
-                try:
-                    self._accept_token(seq, token)
-                except Exception as e:  # noqa: BLE001 - stream callback
-                    self._drop_admission(sid)
-                    prefill_out[sid] = e
-                    continue
-                prefill_out[sid] = True
-            if decode:
-                perf.record_metric(
-                    "engine.decode_tokens", len(decode), "tok"
-                )
-            self._observe_occupancy()
+                self._observe_occupancy()
             return decode_out, prefill_out
 
     # -- grammar fast-forward (forced-token runs) ----------------------------
@@ -2256,11 +2270,11 @@ class Engine:
             zb = jnp.zeros((B,), bool)
             zi = jnp.zeros((B,), jnp.int32)
             perf = get_perf_stats()
-            t_disp = time.perf_counter()
+            ticket = self.step_clock.enqueue()
+            tick_id, t_disp = ticket
             try:
-                dev_out: list = []
-                with annotate("engine.ffwd_step"), \
-                        device_timer("ffwd_step", dev_out), self.mesh_ctx():
+                with obs.phase("dispatch", tick=tick_id), \
+                        annotate("engine.ffwd_step"), self.mesh_ctx():
                     self._sample_key, sub = jax.random.split(self._sample_key)
                     toks_d, self.cache, _fsm_d = self._mixed_carry_jit(
                         self.params,
@@ -2279,9 +2293,8 @@ class Engine:
                         fsm_mask=fm, fsm_dest=fd,
                         carry_fsm=zi, ov_fsm=jnp.asarray(ov_fsm),
                     )
-                    dev_out.append(toks_d)
                 self._mixed_gap_stamp = time.perf_counter()
-                sampled = np.asarray(toks_d)
+                sampled = self._pull("ffwd", int(S), ticket, toks_d)
             except Exception:
                 # Nothing was accepted: roll every booking back to
                 # written truth before surfacing the dispatch error.
@@ -2315,51 +2328,52 @@ class Engine:
             obs.flight.record(
                 "dispatch", op="ffwd",
                 decode_seq_ids=[s.seq_id for s, _ in rows],
-                bucket=int(S), forced_tokens=n_forced,
+                bucket=int(S), forced_tokens=n_forced, tick=tick_id,
             )
             from .decode_loop import record_ffwd_append
 
             decode_out: dict[int, list[int]] = {}
             produced = 0
-            for i, (s, run) in enumerate(rows):
-                accepted: list[int] = []
-                dspan = s.decode_span
-                try:
-                    for t in run:
-                        if s.done:
-                            break
-                        self._accept_token(s, t)
-                        accepted.append(t)
-                    if not s.done:
-                        tok = int(sampled[i])
-                        self._accept_token(s, tok)
-                        accepted.append(tok)
-                except Exception:  # noqa: BLE001 - raising stream callback
-                    # Row-local isolation, same contract as step_mixed:
-                    # the reap path surfaces finish_reason "error".
-                    s.done = True
-                    s.finish_reason = s.finish_reason or "error"
-                if s.done:
-                    # Stop string / EOS / max_tokens (or a raising
-                    # callback) landed mid-append: the tail of the booked
-                    # run is dead content — roll back to written truth.
-                    self.alloc.truncate(s.seq_id, self._host_written(s))
-                n_ff = min(len(accepted), len(run))
-                if n_ff:
-                    record_ffwd_append(
-                        s.seq_id, n_ff, attr=self.attr,
-                        request_id=obs.flight.request_id_of(s.trace),
-                    )
-                if dspan is not None:
-                    dspan.child(
-                        "ffwd_step", t_disp, time.perf_counter(),
-                        tokens=len(accepted),
-                    )
-                decode_out[s.seq_id] = accepted
-                produced += len(accepted)
-            if produced:
-                perf.record_metric("engine.decode_tokens", produced, "tok")
-            self._observe_occupancy()
+            with obs.phase("commit", tick=tick_id):
+                for i, (s, run) in enumerate(rows):
+                    accepted: list[int] = []
+                    dspan = s.decode_span
+                    try:
+                        for t in run:
+                            if s.done:
+                                break
+                            self._accept_token(s, t)
+                            accepted.append(t)
+                        if not s.done:
+                            tok = int(sampled[i])
+                            self._accept_token(s, tok)
+                            accepted.append(tok)
+                    except Exception:  # noqa: BLE001 - raising stream callback
+                        # Row-local isolation, same contract as step_mixed:
+                        # the reap path surfaces finish_reason "error".
+                        s.done = True
+                        s.finish_reason = s.finish_reason or "error"
+                    if s.done:
+                        # Stop string / EOS / max_tokens (or a raising
+                        # callback) landed mid-append: the tail of the booked
+                        # run is dead content — roll back to written truth.
+                        self.alloc.truncate(s.seq_id, self._host_written(s))
+                    n_ff = min(len(accepted), len(run))
+                    if n_ff:
+                        record_ffwd_append(
+                            s.seq_id, n_ff, attr=self.attr,
+                            request_id=obs.flight.request_id_of(s.trace),
+                        )
+                    if dspan is not None:
+                        dspan.child(
+                            "ffwd_step", t_disp, time.perf_counter(),
+                            tokens=len(accepted), tick=tick_id,
+                        )
+                    decode_out[s.seq_id] = accepted
+                    produced += len(accepted)
+                if produced:
+                    perf.record_metric("engine.decode_tokens", produced, "tok")
+                self._observe_occupancy()
             return decode_out
 
     def _sampling_arrays(
@@ -2452,7 +2466,28 @@ class Engine:
                         )
         return bias
 
-    def _sample_one(self, logits: jax.Array, seqs: list[Sequence]) -> np.ndarray:
+    def _pull(
+        self, program: str, bucket: int, ticket: tuple[int, float],
+        out_d: jax.Array,
+    ) -> np.ndarray:
+        """Bring a dispatched step's output to the host: the ``wait`` phase
+        (the scheduler thread blocks on the device here) and the step
+        clock's reading of when the step finished."""
+        with obs.phase("wait", tick=ticket[0]):
+            waited = not out_d.is_ready()
+            out = np.asarray(out_d)
+            self.step_clock.pulled(program, bucket, ticket, waited)
+        return out
+
+    def _sample_one(
+        self, logits: jax.Array, seqs: list[Sequence],
+        step: tuple[str, int, tuple[int, float]] | None = None,
+    ) -> np.ndarray:
+        """Sample one token per row from a prefill program's ``logits``.
+        ``step`` is (program, bucket, ticket) of the dispatch that made
+        them: the token pull below is the first time the host waits for
+        it (so its step-clock sample includes the small sample program).
+        Warm-up passes none and is not clocked."""
         B = logits.shape[0]
         temps, top_k, top_p, mask = self._sampling_arrays(seqs, B)
         bias = self._bias_array(seqs, B)
@@ -2474,7 +2509,7 @@ class Engine:
                 jnp.asarray(top_p),
                 None if mask is None else jnp.asarray(mask),
             )
-        toks = np.asarray(tok)
+        toks = np.asarray(tok) if step is None else self._pull(*step, tok)
         if any(s is not None and s.params.logprobs for s in seqs):
             # First-token logprobs (prefill's sampled token), on the host
             # in numpy: admission is not the steady-state hot loop, and an
@@ -2729,14 +2764,29 @@ class Engine:
         round trip per dispatch) and fold them into host state. Records are
         pulled FIFO, so the host always sees a row's EOS before any of its
         later pad-only blocks."""
-        toks_d, lane_seqs, budgets, counts_d, t_disp = self._inflight.popleft()
+        toks_d, lane_seqs, budgets, counts_d, ticket, program = (
+            self._inflight.popleft()
+        )
         perf = get_perf_stats()
+        tick_id, t_disp = ticket
         t0 = time.perf_counter()
-        toks = np.asarray(toks_d)
+        toks = self._pull(*program, ticket, toks_d)
         counts = None if counts_d is None else np.asarray(counts_d)
         perf.record_metric(
             "engine.block_pull", (time.perf_counter() - t0) * 1e3, "ms"
         )
+        with obs.phase("commit", tick=tick_id):
+            return self._commit_block(
+                toks, counts, lane_seqs, budgets, ticket
+            )
+
+    def _commit_block(
+        self, toks, counts, lane_seqs, budgets, ticket
+    ) -> dict[int, list[int]]:
+        """Fold one pulled decode block into host state (the commit phase:
+        accept, stop scan, detokenize, stream, roll bookings back)."""
+        perf = get_perf_stats()
+        tick_id, t_disp = ticket
         out: dict[int, list[int]] = {}
         produced = 0
         first_exc: BaseException | None = None
@@ -2799,7 +2849,7 @@ class Engine:
                     # is the point — the trace shows the pipelining.
                     dspan.child(
                         "decode_block", t_disp, time.perf_counter(),
-                        tokens=len(accepted),
+                        tokens=len(accepted), tick=tick_id,
                     )
                 if s.done:
                     # Roll pre-booked pages back to written content. Any
@@ -3237,7 +3287,6 @@ class Engine:
                     )
             c_tok, c_at, c_eos, c_fsm, c_key = self._carry
             perf = get_perf_stats()
-            t_disp = time.perf_counter()
             speculate = (
                 self.cfg.speculative_k > 0 and greedy and fsm_obj is None
             )
@@ -3270,9 +3319,10 @@ class Engine:
                     if self._ov_hist_zeros is None:
                         self._ov_hist_zeros = jnp.zeros((B, H), jnp.int32)
                     ov_hist_dev = self._ov_hist_zeros
-            dev_out: list = []
-            with annotate("engine.decode_block"), \
-                    device_timer("decode_block", dev_out), self.mesh_ctx():
+            ticket = self.step_clock.enqueue()
+            tick_id, t_disp = ticket
+            with obs.phase("dispatch", tick=tick_id), \
+                    annotate("engine.decode_block"), self.mesh_ctx():
                 if speculate:
                     toks, counts, self.cache, carry = (
                         self._spec_pipeline_jit(
@@ -3312,7 +3362,6 @@ class Engine:
                     )
                     n_tok, n_at, n_eos, n_fsm, n_key = carry
                     self._carry = (n_tok, n_at, n_eos, n_fsm, n_key)
-                dev_out.append(toks)
             perf.record_metric(
                 "engine.block_dispatch", (time.perf_counter() - t_disp) * 1e3,
                 "ms",
@@ -3355,9 +3404,13 @@ class Engine:
                 "dispatch", op="spec" if speculate else "decode_block",
                 seq_ids=[sid for sid, b in zip(lane_seqs, budgets)
                          if sid is not None and b],
-                steps=int(budgets.max()),
+                steps=int(budgets.max()), tick=tick_id,
             )
-            self._inflight.append((toks, lane_seqs, budgets, counts, t_disp))
+            self._inflight.append((
+                toks, lane_seqs, budgets, counts, ticket,
+                ("spec", self._spec_steps) if speculate
+                else ("decode_block", block),
+            ))
             for sid, b in zip(lane_seqs, budgets):
                 if sid is not None and b:
                     self._inflight_steps[sid] = (

@@ -13,6 +13,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from ..utils.profiling import scoped
+
 NEG_INF = -1e30
 
 
@@ -51,6 +53,7 @@ class SamplingParams:
 MAX_CANDIDATES = 64
 
 
+@scoped("sample")
 def sample(
     logits: jax.Array,             # [B, V] float32
     key: jax.Array,

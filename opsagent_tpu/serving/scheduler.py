@@ -810,9 +810,15 @@ class Scheduler:
         consecutive_failures = 0
         while not self._stop.is_set():
             try:
-                self._drain_queue()
-                self._try_admit()
+                # The loop is always in exactly one obs.phase (the table
+                # in docs/observability.md): admit, plan, reap and idle
+                # here; the engine carves dispatch, wait and commit out
+                # of plan where it enqueues, blocks on and folds a step.
+                with obs.phase("admit"):
+                    self._drain_queue()
+                    self._try_admit()
                 if self._running or self._prefilling:
+                    obs.TICKS.inc()
                     # Only counted with work in flight: idle ticks spin
                     # at an arbitrary rate, which would make hit-count
                     # fault selectors wall-clock-dependent.
@@ -823,32 +829,41 @@ class Scheduler:
                 # Mixed tick first: one dispatch covers decode AND a
                 # prefill chunk (one weight stream). Falls back to the
                 # split prefill-then-decode tick when it cannot run.
-                mixed = self._mixed_tick()
-                if not mixed:
-                    self._advance_prefill()
-                self._reap()
-                if not self._running:
-                    if self._prefilling:
-                        continue  # keep advancing admission chunks
-                    # Idle: the mixed-tick cadence breaks here — the wait
-                    # must not be observed as host gap.
-                    gap_break = getattr(self.engine, "mixed_gap_break", None)
-                    if gap_break is not None:
-                        gap_break()
-                    # Land pending device->host page copies (the
-                    # offload double buffer's drain side), then wait.
-                    flush = getattr(self.engine, "offload_flush", None)
-                    if flush is not None:
-                        try:
-                            flush()
-                        except Exception:  # noqa: BLE001 - best-effort
-                            pass
-                    self._wake.wait(timeout=0.05)
-                    self._wake.clear()
-                    continue
-                if not mixed:
-                    self.engine.step_block(sorted(self._running))
+                with obs.phase("plan"):
+                    mixed = self._mixed_tick()
+                    if not mixed:
+                        self._advance_prefill()
+                with obs.phase("reap"):
                     self._reap()
+                    idle = not self._running and not self._prefilling
+                    if idle:
+                        # The mixed-tick cadence breaks here — the wait
+                        # must not be observed as host gap.
+                        gap_break = getattr(
+                            self.engine, "mixed_gap_break", None
+                        )
+                        if gap_break is not None:
+                            gap_break()
+                        # Land pending device->host page copies (the
+                        # offload double buffer's drain side), then wait.
+                        flush = getattr(self.engine, "offload_flush", None)
+                        if flush is not None:
+                            try:
+                                flush()
+                            except Exception:  # noqa: BLE001 - best-effort
+                                pass
+                if idle:
+                    with obs.phase("idle"):
+                        self._wake.wait(timeout=0.05)
+                        self._wake.clear()
+                    continue
+                if not self._running:
+                    continue  # keep advancing admission chunks
+                if not mixed:
+                    with obs.phase("plan"):
+                        self.engine.step_block(sorted(self._running))
+                    with obs.phase("reap"):
+                        self._reap()
                 consecutive_failures = 0
             except Exception as e:  # noqa: BLE001 - the loop must survive
                 # A raising stream callback surfaces here after the engine
@@ -858,7 +873,8 @@ class Scheduler:
                 log.exception("scheduler step failed")
                 before = len(self._running)
                 try:
-                    self._reap()
+                    with obs.phase("reap"):
+                        self._reap()
                 except Exception:  # noqa: BLE001
                     pass
                 if len(self._running) < before:

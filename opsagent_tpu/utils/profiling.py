@@ -1,52 +1,47 @@
-"""Device-level profiling: ``jax.profiler`` traces and per-step device
-timings, layered on the request-level perf registry.
+"""Device-level profiling: ``jax.profiler`` traces, host annotations and
+kernel scopes, layered on the request-level perf registry.
 
 The reference instruments the host path only — named start/stop timers
 aggregated to min/max/avg/p95/p99 (reference pkg/utils/perf.go:168-210),
 exposed at GET /api/perf/stats (reference pkg/api/router.go:104). On TPU
 that misses where the time actually goes: host wall-clock around a dispatch
 measures the *enqueue*, not the device, because XLA execution is async.
-This module adds the two device-side views SURVEY §5 calls for:
+This module adds the device-side views SURVEY §5 calls for:
 
 1. **Traces** — ``trace()`` wraps a region in a ``jax.profiler`` capture
    (TensorBoard/xprof format: per-op device timelines, HLO, memory). Opt-in
    via ``OPSAGENT_PROFILE_DIR`` or an explicit ``logdir``; no-op otherwise,
    so production serving pays nothing.
-2. **Per-step device timings** — ``device_timer()`` blocks on the step's
-   output arrays and records the *synchronous* elapsed time into the perf
-   registry under a ``device.`` prefix, so ``/api/perf/stats`` shows device
-   step time next to the host-side dispatch/pull timers. Blocking defeats
-   the engine's dispatch pipelining, so this is opt-in via
-   ``OPSAGENT_DEVICE_TIMING=1`` — a measurement mode, not a serving mode.
+2. **Names on the trace** — ``annotate()`` names host regions inside an
+   active trace (free when no trace is running; ``obs.phase`` builds the
+   tick phases on it), and ``scoped()`` puts a function's device
+   operations under a ``jax.named_scope`` (metadata only: the compiled
+   program is the same), so the trace's reduction finds each kernel by
+   the program's own name.
 
-``annotate()`` names host regions inside an active trace (shows up on the
-trace timeline), and is free when no trace is running.
+Per-step device times with no trace at all come from the step clock
+(``obs.StepClock``, ``opsagent_step_device_seconds``).
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
-from typing import Any, Iterator
+from typing import Callable, Iterator
 
 import jax
 
 from .logger import get_logger
-from .perf import get_perf_stats
 
 log = get_logger("profiling")
 
 _ENV_DIR = "OPSAGENT_PROFILE_DIR"
-_ENV_TIMING = "OPSAGENT_DEVICE_TIMING"
 
 
 def profile_dir() -> str | None:
     """The configured trace directory, or None when tracing is off."""
     return os.environ.get(_ENV_DIR) or None
-
-
-def device_timing_enabled() -> bool:
-    return os.environ.get(_ENV_TIMING, "") not in ("", "0", "false")
 
 
 @contextlib.contextmanager
@@ -77,28 +72,19 @@ def annotate(name: str) -> contextlib.AbstractContextManager:
     return jax.profiler.TraceAnnotation(name)
 
 
-@contextlib.contextmanager
-def device_timer(name: str, outputs: list[Any]) -> Iterator[None]:
-    """Measure the device time of one dispatched step.
-
-    Appends the step's output arrays to ``outputs`` inside the body; on
-    exit (when enabled) blocks until they are ready and records the
-    synchronous wall time as ``device.<name>`` in the perf registry. When
-    ``OPSAGENT_DEVICE_TIMING`` is unset this is a plain pass-through — no
-    sync, no pipeline stall.
-    """
-    if not device_timing_enabled():
-        yield
-        return
-    import time
-
-    t0 = time.perf_counter()
-    yield
-    for out in outputs:
-        jax.block_until_ready(out)
-    get_perf_stats().record_metric(
-        f"device.{name}", (time.perf_counter() - t0) * 1e3, "ms"
-    )
+def scoped(name: str) -> Callable:
+    """Decorator: trace the function under ``jax.named_scope(name)``, so
+    every device operation it emits carries ``name`` on its ``op_name``
+    path. ``name`` is one of ``models.llama.SCOPES``. The scope is entered
+    at each call (not bound at decoration), which is what lets a test
+    compile the same program without scopes."""
+    def decorate(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return wrapper
+    return decorate
 
 
 MAX_CAPTURE_SECONDS = 120.0

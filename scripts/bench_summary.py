@@ -195,9 +195,11 @@ def terminal_summary(paths: list[str]) -> int:
         d = sasync[-1]
         e = d.get("extra", {})
         print(
-            f"async A/B: host-gap p50 {e.get('host_gap_p50_ms', 0)} ms "
-            f"(depth=2) vs {e.get('sync_host_gap_p50_ms', 0)} ms "
-            f"(depth=1); tok/s/chip {d['value']} vs "
+            f"async A/B: device wait share "
+            f"{e.get('device_wait_share', 0)} (depth=2) vs "
+            f"{e.get('sync_device_wait_share', 0)} (depth=1) at "
+            f"{e.get('host_work_ms', 0)} ms host work a tick; "
+            f"tok/s/chip {d['value']} vs "
             f"{e.get('sync_tok_s_chip', 0)}; outputs identical: "
             f"{e.get('outputs_identical')}"
         )

@@ -1,6 +1,8 @@
-"""Device-level profiling (utils/profiling.py): jax.profiler traces and
-per-step device timings — SURVEY §5's TPU additions over the reference's
-host-only timer registry (reference pkg/utils/perf.go:168-210)."""
+"""Device-level profiling (utils/profiling.py): jax.profiler traces, host
+annotations and kernel scopes — SURVEY §5's TPU additions over the
+reference's host-only timer registry (reference pkg/utils/perf.go:168-210).
+Tick phases, the step clock and the scopes of the step programs are in
+tests/test_tick_tracing.py."""
 
 import os
 
@@ -8,7 +10,6 @@ import jax
 import jax.numpy as jnp
 
 from opsagent_tpu.utils import profiling
-from opsagent_tpu.utils.perf import get_perf_stats
 
 
 def test_trace_noop_without_dir(monkeypatch):
@@ -30,24 +31,3 @@ def test_trace_writes_capture(tmp_path, monkeypatch):
 def test_annotate_is_free_outside_trace():
     with profiling.annotate("unit-test-region"):
         pass
-
-
-def test_device_timer_records_metric(monkeypatch):
-    monkeypatch.setenv("OPSAGENT_DEVICE_TIMING", "1")
-    perf = get_perf_stats()
-    perf.reset()
-    outs: list = []
-    with profiling.device_timer("unit_step", outs):
-        outs.append(jax.jit(lambda x: x + 1)(jnp.zeros((16,))))
-    stats = perf.get_stats()
-    assert "device.unit_step" in stats
-    assert stats["device.unit_step"]["count"] == 1
-
-
-def test_device_timer_noop_when_disabled(monkeypatch):
-    monkeypatch.delenv("OPSAGENT_DEVICE_TIMING", raising=False)
-    perf = get_perf_stats()
-    perf.reset()
-    with profiling.device_timer("disabled_step", []):
-        pass
-    assert "device.disabled_step" not in perf.get_stats()
