@@ -34,10 +34,13 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import (
-    paged_ragged_attention_auto,
+    PAGE_FORMS,
+    QuantizedPages,
     causal_prefill_attention,
+    page_form,
     paged_decode_attention_auto,
     paged_prefix_attention,
+    paged_ragged_attention_auto,
     write_kv_pages,
     write_pages,
 )
@@ -375,24 +378,45 @@ def _latent_cache(cfg: ModelConfig) -> bool:
     return cfg.mla is not None and cfg.mla.latent_cache
 
 
+def cache_form(
+    cfg: ModelConfig, kv_shards: int = 1, attn_impl: str = "xla"
+) -> str:
+    """The form this model's KV pages are held in on the device, "split"
+    ``[L, N, P, K, D]`` or "merged" ``[L, N, P, K*D]``, from what is known
+    where the cache is made: the kv heads one tp shard holds and the
+    attention backend (``ops.attention.page_form`` has the tile
+    arithmetic). The MLA latent is one head."""
+    if _latent_cache(cfg):
+        return "split"
+    return page_form(max(1, cfg.num_kv_heads // kv_shards), attn_impl)
+
+
 def make_cache(
     cfg: ModelConfig,
     num_pages: int,
     page_size: int,
     dtype: jnp.dtype = jnp.bfloat16,
     kv_quantize: str = "",
+    form: str | None = None,
 ) -> Params:
-    """Paged KV cache pytree: pages stacked over layers. MLA latent mode
+    """Paged KV cache pytree: pages stacked over layers, held ``[L, N, P,
+    K, D]`` or merged ``[L, N, P, K*D]`` (``form``; None asks
+    ``cache_form`` for one shard and the xla gather). Merged is for 2-7 kv
+    heads a shard: split, they get a part-empty TPU tile (``T(4,128)`` at
+    4 heads) that the page gather cannot read, and every layer re-tiled
+    all of K and of V; merged, the page slots fill the (8, 128) tile and
+    write and gather share it. 8 heads fill it split. MLA latent mode
     stores ONE (kv_lora_rank + rope)-dim latent per token in ``k`` — the
     compression that motivates MLA — with a 1-dim placeholder ``v`` (the
     pytree shape is shared with the standard layout so the engine's
     donation/restart plumbing is layout-agnostic).
 
     ``kv_quantize="int8"`` stores pages as ``ops.attention.QuantizedPages``
-    (int8 values + per-token-per-head f32 scales): halves decode KV reads,
-    the dominant non-weight HBM term at serving shapes (PERF.md). Not
-    supported for the MLA latent layout (latents feed weight-absorbed
-    matmuls, not raw attention; the engine rejects the combination)."""
+    (int8 values + per-token-per-head f32 scales ``[L, N, P, K]`` in
+    either form): halves decode KV reads, the dominant non-weight HBM
+    term at serving shapes (PERF.md). Not supported for the MLA latent
+    layout (latents feed weight-absorbed matmuls, not raw attention; the
+    engine rejects the combination)."""
     L = cfg.num_layers
     if _latent_cache(cfg):
         if kv_quantize:
@@ -403,47 +427,45 @@ def make_cache(
             "k": jnp.zeros(shape_k, dtype), "v": jnp.zeros(shape_v, dtype)
         }
     K, D = cfg.num_kv_heads, cfg.head_dim_
-    shape = (L, num_pages, page_size, K, D)
+    form = form or cache_form(cfg)
+    if form not in PAGE_FORMS:
+        raise ValueError(f"page form {form!r}: expected one of {PAGE_FORMS}")
+    scales = (L, num_pages, page_size, K)
+    shape = scales[:-1] + (K * D,) if form == "merged" else scales + (D,)
     if kv_quantize:
         if kv_quantize != "int8":
             raise ValueError(f"unsupported kv_quantize {kv_quantize!r}")
-        from ..ops.attention import QuantizedPages
-
         return {
-            "k": QuantizedPages(
-                jnp.zeros(shape, jnp.int8),
-                jnp.ones(shape[:-1], jnp.float32),
-            ),
-            "v": QuantizedPages(
-                jnp.zeros(shape, jnp.int8),
-                jnp.ones(shape[:-1], jnp.float32),
-            ),
+            name: QuantizedPages(
+                jnp.zeros(shape, jnp.int8), jnp.ones(scales, jnp.float32)
+            )
+            for name in ("k", "v")
         }
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def cache_specs(cfg: ModelConfig, kv_quantize: str = "") -> Params:
-    """KV pages are sharded over the kv-head axis (tp), like wk/wv. The
-    MLA latent cache has ONE shared 'head' — replicated over tp (it is
-    per-token global state; queries/outputs still shard over heads).
-    Quantized pages: the scale plane drops the head-dim axis but keeps
-    the kv-head axis, so it shards with its values."""
+def cache_specs(
+    cfg: ModelConfig, kv_quantize: str = "", form: str | None = None
+) -> Params:
+    """KV pages are sharded over the kv-head axis (tp), like wk/wv: the
+    K axis of split pages, the merged K*D axis of merged ones (a shard
+    of it is whole heads, K/tp of them). The MLA latent cache has ONE
+    shared 'head' — replicated over tp (it is per-token global state;
+    queries/outputs still shard over heads). Quantized pages: the scale
+    plane drops the head-dim axis but keeps the kv-head axis, so it
+    shards with its values."""
     if _latent_cache(cfg):
         return {
             "k": P(None, None, None, None, None),
             "v": P(None, None, None, None, None),
         }
+    scales = P(None, None, None, "tp")
+    values = P(None, None, None, "tp", None)
+    if (form or cache_form(cfg)) == "merged":
+        values = scales     # [L, N, P, K*D]: a shard is K/tp whole heads
     if kv_quantize:
-        from ..ops.attention import QuantizedPages
-
-        spec = QuantizedPages(
-            P(None, None, None, "tp", None), P(None, None, None, "tp")
-        )
-        return {"k": spec, "v": spec}
-    return {
-        "k": P(None, None, None, "tp", None),
-        "v": P(None, None, None, "tp", None),
-    }
+        values = QuantizedPages(values, scales)
+    return {"k": values, "v": values}
 
 
 # -- building blocks --------------------------------------------------------

@@ -5,11 +5,26 @@ ground truth for the Pallas TPU kernels in ``paged_attention_pallas.py``.
 Softmax accumulates in float32 regardless of the activation dtype (bf16 on
 TPU) for numerical parity with the fused kernels.
 
-The paged layout: KV lives in fixed-size pages ``[num_pages, page_size,
-num_kv_heads, head_dim]``; a sequence owns a row of the page table
-``[max_pages_per_seq]`` holding page indices. This is the structure the
-continuous-batching scheduler allocates against (SURVEY.md section 7 step 5 /
-the Ragged-Paged-Attention design in PAPERS.md).
+The paged layout: KV lives in fixed-size pages; a sequence owns a row of
+the page table ``[max_pages_per_seq]`` holding page indices. This is the
+structure the continuous-batching scheduler allocates against (SURVEY.md
+section 7 step 5 / the Ragged-Paged-Attention design in PAPERS.md).
+
+A page array is HELD in one of two forms (``page_form`` chooses, where the
+cache is made): *split* ``[num_pages, page_size, kv_heads, head_dim]`` or
+*merged* ``[num_pages, page_size, kv_heads * head_dim]``, the same bytes
+in the same order. The TPU tiles an array's two minor axes into (8, 128)
+tiles. Split at 8 kv heads fills a tile with (kv_heads, head_dim), and
+both the page write's scatter and the page gather run in it. Split at 4
+(or 2) gets a half-height ``T(4,128)`` tile that the scatter takes but
+the gather does not: it asks for full tiles with the page slots under the
+head dim, and the compiler re-tiled ALL of K and of V for it in every
+layer (56 copies of 1.17 GB a step at the 7B's 28 layers x 2560 pages,
+200 ms of a 511 ms step, PERF.md PR 25). Merged, the 16 page slots fill
+the tile's rows at any head count and write and gather share the tiling.
+``write_pages`` and ``_gather_kv`` take either form and tell them apart
+by the trailing axis (``pages_merged``); the Pallas kernels take split
+pages only.
 """
 
 from __future__ import annotations
@@ -70,6 +85,43 @@ class QuantizedPages:
     @property
     def dtype(self):
         return self.q.dtype
+
+
+PAGE_FORMS = ("split", "merged")
+TILE_ROWS = 8  # rows of the TPU's (rows, 128 lanes) tile, at 1, 2 and 4 bytes
+
+
+def page_form(kv_heads_per_shard: int, attn_impl: str = "xla") -> str:
+    """The form a shard's KV pages are held in on the device (module
+    header): "merged" ``[.., P, K*D]`` where split pages would get a
+    part-empty tile (K neither 1 nor a multiple of 8), "split"
+    ``[.., P, K, D]`` otherwise. By the compiler, for a described v5e
+    (tests/test_tpu_compile.py): split at K = 2 and 4 re-tiles the whole
+    cache between write and gather in every layer, bf16 and int8 alike
+    (both get 8-row tiles); at K = 8 split has no such copy and merged
+    would add one of each gathered block; at K = 1 (MLA's latent, a tp
+    shard of one head) the unit axis costs nothing. The Pallas backends
+    index ``[.., P, K, D]`` blocks and gather nothing, so they hold split
+    pages at any K."""
+    if attn_impl != "xla" or kv_heads_per_shard == 1:
+        return "split"
+    return "split" if kv_heads_per_shard % TILE_ROWS == 0 else "merged"
+
+
+def pages_merged(pages, head_dim: int) -> bool:
+    """Whether ``pages`` (an array or ``QuantizedPages``) are held merged.
+    One kv head is never merged (``page_form``), so a trailing axis wider
+    than the head dim says so."""
+    return pages.shape[-1] != head_dim
+
+
+def page_view(pages: jax.Array, page_shape: tuple[int, ...]) -> jax.Array:
+    """``[L, n, <a page in either form>]`` -> ``[L, n, *page_shape]``: how
+    code outside the step programs (the host tier, and through it
+    snapshots and the fleet's wire) reads held pages as ``[P, K, D]`` and
+    writes them back. Merging trailing axes does not reorder a page's
+    bytes, so the stored format is the split one whatever is held."""
+    return pages.reshape(*pages.shape[:2], *page_shape)
 
 
 def quantize_kv_rows(new: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -181,6 +233,15 @@ def pallas_refusal(
     return None
 
 
+def _require_split(pages, head_dim: int, impl: str) -> None:
+    if pages_merged(pages, head_dim):
+        raise ValueError(
+            f"paged backend {impl!r} was given merged pages "
+            f"{tuple(pages.shape)}: the Pallas kernels index [.., P, K, D] "
+            "blocks; make the cache with page_form(kv_heads, attn_impl)"
+        )
+
+
 def _shard_map(fn, mesh: Mesh, in_specs, out_specs):
     # check_vma off: pallas_call does not annotate its outputs'
     # varying-mesh-axes metadata, and the head axis is fully data-parallel
@@ -283,6 +344,7 @@ def paged_decode_attention_auto(
     manual-DMA kernel, and the (B, MaxP) grid kernel all carry a
     score-space scale path now."""
     if impl.startswith("pallas"):
+        _require_split(k_pages, q.shape[-1], impl)
         interpret = pallas_interpret()
         if mesh is not None and mesh.shape.get("tp", 1) > 1:
             return paged_decode_attention_pallas_tp(
@@ -336,8 +398,8 @@ def causal_prefill_attention(
 
 @scoped("kv_write")
 def write_kv_pages(
-    k_pages: jax.Array,     # [N, P, K, D] — or [L, N, P, K, D] with layer
-    v_pages: jax.Array,     # like k_pages
+    k_pages: jax.Array,     # [N, P, K, D] or merged [N, P, K*D] — with a
+    v_pages: jax.Array,     # leading L axis when ``layer`` is given
     k_new: jax.Array,       # [B, S, K, D]
     v_new: jax.Array,       # [B, S, K, D]
     page_table: jax.Array,  # [B, MaxP] int32 page indices (-1 = unassigned)
@@ -369,7 +431,7 @@ def write_kv_pages(
 
 @scoped("kv_write")
 def write_pages(
-    pages: jax.Array,       # [N, P, K, D] — or [L, N, P, K, D] with layer
+    pages: jax.Array,       # [(L,) N, P, K, D] or merged [(L,) N, P, K*D]
     new: jax.Array,         # [B, S, K, D]
     page_table: jax.Array,  # [B, MaxP] int32 page indices (-1 = unassigned)
     start: jax.Array,       # [B] int32 write offset (tokens already in cache)
@@ -378,6 +440,11 @@ def write_pages(
 ) -> jax.Array:
     """Single-array page scatter (``write_kv_pages`` for one side; the MLA
     latent cache writes only one array per token).
+
+    The scatter runs in the form the pages are held in (module header):
+    rows of ``[K, D]`` into the flat ``[slots, K, D]`` view of split pages,
+    rows of ``[K*D]`` into the ``[slots, K*D]`` view of merged ones, in
+    place in the array's own tiling either way.
 
     ``QuantizedPages`` targets quantize the fresh rows on write (absmax
     over the head dim) and scatter values and scales with the same flat
@@ -394,22 +461,21 @@ def write_pages(
                 valid_len=valid_len, layer=layer,
             ),
         )
-    if pages.ndim == 5:
-        L, N, P, K, D = pages.shape
-        total = L * N
-        base = (layer if layer is not None else 0) * N
+    B, S, K, D = new.shape
+    row = pages.shape[-1:] if pages_merged(pages, D) else (K, D)
+    lead = pages.shape[: pages.ndim - len(row) - 1]    # (L, N) or (N,)
+    P = pages.shape[len(lead)]
+    if len(lead) == 2:
+        total = lead[0] * lead[1]
+        base = (layer if layer is not None else 0) * lead[1]
     else:
-        N, P, K, D = pages.shape
-        total = N
-        base = 0
-    B, S = new.shape[:2]
+        total, base = lead[0], 0
     flat = _flat_slot_indices(
         page_table, start, S, P, base, total, valid_len
     ).reshape(B * S)
-    shape = pages.shape
-    pf = pages.reshape(total * P, K, D)
-    pf = pf.at[flat].set(new.reshape(B * S, K, D), mode="drop")
-    return pf.reshape(shape)
+    pf = pages.reshape(total * P, *row)
+    pf = pf.at[flat].set(new.reshape(B * S, *row), mode="drop")
+    return pf.reshape(pages.shape)
 
 
 def _flat_slot_indices(
@@ -470,39 +536,42 @@ def _write_scale_pages(
 
 @scoped("kv_gather")
 def _gather_kv(
-    k_pages, v_pages, page_table: jax.Array, layer, dtype
+    k_pages, v_pages, page_table: jax.Array, layer, dtype, head_dim: int
 ) -> tuple[jax.Array, jax.Array]:
     """Shared page gather for the XLA readers: [B, MaxP] table ->
     contiguous ([B, L, K, D], [B, L, K, D]) sequence views, L = MaxP * P.
-    Handles the optional leading layer axis (flatten + ``layer * N``
-    offset) and ``QuantizedPages`` (gather int8 values + scales, then
-    dequantize — XLA fuses the convert/multiply into the consuming
-    einsum's operand read)."""
+    Whole pages are gathered in the form they are held in (``head_dim``,
+    the queries', tells merged ``[.., P, K*D]`` from split) and only the
+    gathered block is given its kv-head axis, so no reader asks the
+    compiler for another tiling of the cache. Handles the optional
+    leading layer axis (flatten + ``layer * N`` offset) and
+    ``QuantizedPages`` (gather int8 values + scales, then dequantize —
+    XLA fuses the convert/multiply into the consuming einsum's operand
+    read)."""
     k_scale = v_scale = None
     if isinstance(k_pages, QuantizedPages):
         k_pages, k_scale = k_pages.q, k_pages.scale
         v_pages, v_scale = v_pages.q, v_pages.scale
-    if k_pages.ndim == 5:
-        Lr, N, P, K, D = k_pages.shape
+    lead = k_pages.ndim - (2 if pages_merged(k_pages, head_dim) else 3)
+    N, P = k_pages.shape[lead - 1 : lead + 1]
+    base, nmax = 0, N - 1
+    if lead == 2:
+        Lr = k_pages.shape[0]
         base = (layer if layer is not None else 0) * N
-        k_pages = k_pages.reshape(Lr * N, P, K, D)
-        v_pages = v_pages.reshape(Lr * N, P, K, D)
-        if k_scale is not None:
-            k_scale = k_scale.reshape(Lr * N, P, K)
-            v_scale = v_scale.reshape(Lr * N, P, K)
         nmax = Lr * N - 1
-    else:
-        N, P, K, D = k_pages.shape
-        base = 0
-        nmax = N - 1
+        k_pages = k_pages.reshape(Lr * N, *k_pages.shape[2:])
+        v_pages = v_pages.reshape(Lr * N, *v_pages.shape[2:])
+        if k_scale is not None:
+            k_scale = k_scale.reshape(Lr * N, *k_scale.shape[2:])
+            v_scale = v_scale.reshape(Lr * N, *v_scale.shape[2:])
     B = page_table.shape[0]
     L = page_table.shape[1] * P
     safe_table = jnp.clip(page_table + base, 0, nmax)
-    k_seq = k_pages[safe_table].reshape(B, L, K, D)
-    v_seq = v_pages[safe_table].reshape(B, L, K, D)
+    k_seq = k_pages[safe_table].reshape(B, L, -1, head_dim)
+    v_seq = v_pages[safe_table].reshape(B, L, k_seq.shape[2], -1)
     if k_scale is not None:
-        ks = k_scale[safe_table].reshape(B, L, K)
-        vs = v_scale[safe_table].reshape(B, L, K)
+        ks = k_scale[safe_table].reshape(B, L, -1)
+        vs = v_scale[safe_table].reshape(B, L, -1)
         k_seq = _dequantize_gathered(k_seq, ks, dtype)
         v_seq = _dequantize_gathered(v_seq, vs, dtype)
     return k_seq, v_seq
@@ -511,7 +580,7 @@ def _gather_kv(
 @scoped("attn_core")
 def paged_ragged_attention(
     q: jax.Array,           # [B, S, H, D] queries (right-padded per row)
-    k_pages: jax.Array,     # [N, P, K, D] — or [L, N, P, K, D] with layer
+    k_pages: jax.Array,     # [(L,) N, P, K, D] or merged [(L,) N, P, K*D]
     v_pages: jax.Array,     # like k_pages
     page_table: jax.Array,  # [B, MaxP]
     start: jax.Array,       # [B] tokens already in cache (queries begin here)
@@ -532,7 +601,9 @@ def paged_ragged_attention(
     discard. Gather-based XLA reference; the Pallas page-streaming variant
     is ``paged_ragged_attention_pallas`` behind
     ``paged_ragged_attention_auto``."""
-    k_seq, v_seq = _gather_kv(k_pages, v_pages, page_table, layer, q.dtype)
+    k_seq, v_seq = _gather_kv(
+        k_pages, v_pages, page_table, layer, q.dtype, q.shape[-1]
+    )
     B, S, H, _ = q.shape
     L = k_seq.shape[1]
     # The f32 score matrix is [B, H, S, L]: at a 4096-token admission
@@ -565,10 +636,14 @@ def paged_ragged_attention(
     def blocks(x):
         return x.reshape(B // b_blk, b_blk, *x.shape[1:])
 
+    # XLA folds this blocking into the gather's own reshape and moves the
+    # gathered keys once for both (kv heads above positions, for the dot):
+    # a re-tiling of what was read from the cache, so it is named as one.
+    with jax.named_scope("kv_gather"):
+        k_seq, v_seq = blocks(k_seq), blocks(v_seq)
     out = jax.lax.map(
         batch_block,
-        (blocks(q), blocks(k_seq), blocks(v_seq), blocks(start),
-         blocks(q_lens)),
+        (blocks(q), k_seq, v_seq, blocks(start), blocks(q_lens)),
     )
     return out.reshape(q.shape)
 
@@ -618,7 +693,7 @@ def _ragged_attention_block(
 
 def paged_prefix_attention(
     q: jax.Array,           # [B, S, H, D] tail queries (right-padded)
-    k_pages: jax.Array,     # [N, P, K, D] — or [L, N, P, K, D] with layer
+    k_pages: jax.Array,     # [(L,) N, P, K, D] or merged [(L,) N, P, K*D]
     v_pages: jax.Array,     # like k_pages
     page_table: jax.Array,  # [B, MaxP]
     start: jax.Array,       # [B] cached-prefix lengths (tail begins here)
@@ -707,6 +782,7 @@ def paged_ragged_attention_auto(
     so quantized pages on the mixed hot path are never materialized as a
     dequantized contiguous gather under any pallas impl."""
     if impl.startswith("pallas"):
+        _require_split(k_pages, q.shape[-1], impl)
         interpret = pallas_interpret()
         if mesh is not None and mesh.shape.get("tp", 1) > 1:
             return paged_ragged_attention_pallas_tp(
@@ -725,7 +801,7 @@ def paged_ragged_attention_auto(
 @scoped("attn_core")
 def paged_decode_attention(
     q: jax.Array,           # [B, H, D] (one new token per sequence)
-    k_pages: jax.Array,     # [N, P, K, D] — or [L, N, P, K, D] with layer
+    k_pages: jax.Array,     # [(L,) N, P, K, D] or merged [(L,) N, P, K*D]
     v_pages: jax.Array,     # like k_pages
     page_table: jax.Array,  # [B, MaxP]
     lengths: jax.Array,     # [B] total tokens in cache (incl. the new one)
@@ -737,7 +813,9 @@ def paged_decode_attention(
     masks positions >= length. The Pallas kernel avoids this materialized
     gather; results must match to ~1e-2 in bf16 / 1e-5 in f32.
     """
-    k_seq, v_seq = _gather_kv(k_pages, v_pages, page_table, layer, q.dtype)
+    k_seq, v_seq = _gather_kv(
+        k_pages, v_pages, page_table, layer, q.dtype, q.shape[-1]
+    )
     B, H, _ = q.shape
     K, D = k_seq.shape[-2:]
     G = H // K

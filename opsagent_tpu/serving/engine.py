@@ -547,19 +547,30 @@ class Engine:
             "compile_cache_entries_at_start": cache_entries,
         }
         # The page pool is created sharded as well: no leaf ever exists
-        # whole on one device.
-        self.cache = jax.jit(
-            lambda: llama.make_cache(
+        # whole on one device. Its pages are held in the form both the
+        # page write and the page gather run in at this shard's kv heads
+        # (ops.attention.page_form); outside the step programs a page is
+        # [P, K, D] whatever is held (``cache_wire``: the split cache's
+        # shapes, which the host tier and the snapshot manifest use).
+        self.page_form = llama.cache_form(self.model_cfg, tp, self.attn_impl)
+
+        def make(form: str):
+            return llama.make_cache(
                 self.model_cfg, cfg.num_pages, cfg.page_size,
-                dtype=cfg.dtype, kv_quantize=cfg.kv_quantize,
-            ),
+                dtype=cfg.dtype, kv_quantize=cfg.kv_quantize, form=form,
+            )
+
+        self.cache = jax.jit(
+            lambda: make(self.page_form),
             out_shardings=spec_tree_shardings(
                 llama.cache_specs(
-                    self.model_cfg, kv_quantize=cfg.kv_quantize
+                    self.model_cfg, kv_quantize=cfg.kv_quantize,
+                    form=self.page_form,
                 ),
                 self.mesh,
             ),
         )()
+        self.cache_wire = jax.eval_shape(lambda: make("split"))
         self.alloc = PageAllocator(
             cfg.num_pages, cfg.page_size, cfg.max_pages_per_seq,
             prefix_cache=cfg.prefix_cache,
@@ -577,6 +588,7 @@ class Engine:
                     capacity_bytes=cfg.host_pool_bytes or None,
                 ),
                 PageCopyEngine(
+                    self.cache_wire,
                     mesh_ctx=self.mesh_ctx,
                     copy_pages=cfg.offload_copy_pages,
                 ),
@@ -918,6 +930,7 @@ class Engine:
             "weight_stream": self.weight_stream_impl,
             "quantize": self.cfg.quantize or "none",
             "kv_quantize": self.cfg.kv_quantize or "none",
+            "kv_page_form": self.page_form,
             "fsm_impl": native.impl(),
         }
         if self.weight_stream_leaves:

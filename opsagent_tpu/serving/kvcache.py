@@ -1,7 +1,11 @@
 """Host-side paged KV-cache bookkeeping, with prefix caching.
 
-The device holds the pages (``models.llama.make_cache``); this module owns
-the free list, per-sequence page tables, and the **prefix trie**: finished
+The device holds the pages (``models.llama.make_cache``: ``[L, N, P, K,
+D]``, or ``[L, N, P, K*D]`` where fewer than 8 kv heads would leave the
+TPU's (8, 128) tile part empty and cost a re-tiling of the whole cache in
+every layer; the same bytes, and a page is ``[P, K, D]`` wherever it goes
+off the device, ``ops.attention.page_view``). A page index means the same
+in either form; this module owns the free list, per-sequence page tables, and the **prefix trie**: finished
 sequences donate their full pages (keyed by page-aligned token content) so a
 later request whose prompt shares the prefix skips re-prefilling it. The
 ReAct loop re-sends the whole chat history every iteration (reference

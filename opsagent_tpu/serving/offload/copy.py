@@ -19,7 +19,10 @@ keeps the restore path inside the zero-post-warmup-compiles invariant:
 
 Every cache leaf keeps its page axis at index 1 (``[L, N, P, ...]``, both
 the fp and the QuantizedPages int8+scale layouts), so one ``tree.map``
-covers all layouts.
+covers all layouts. A page leaves the device as ``[L, P, K, D]`` whichever
+form the cache holds it in (``ops.attention.page_view``: merged pages
+``[L, N, P, K*D]`` are the same bytes), so the host pool, snapshots and
+the fleet's wire have one format.
 """
 
 from __future__ import annotations
@@ -30,15 +33,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...ops.attention import page_view
+
 _PAGE_AXIS = 1  # cache leaves are [L, num_pages, page_size, ...]
 
 
 class PageCopyEngine:
-    def __init__(self, mesh_ctx=None, copy_pages: int = 8):
+    def __init__(self, wire, mesh_ctx=None, copy_pages: int = 8):
         """``mesh_ctx`` is the engine's mesh context factory
         (``Engine.mesh_ctx``): the copy programs must compile under the
         same ambient mesh as every other engine program or the jit cache
-        forks (see Engine._mesh_tls)."""
+        forks (see Engine._mesh_tls). ``wire`` is the cache's shape tree
+        in the split form (``Engine.cache_wire``), the format pages have
+        off the device."""
         import contextlib
 
         self._mesh_ctx = mesh_ctx or contextlib.nullcontext
@@ -47,12 +54,18 @@ class PageCopyEngine:
 
         def _gather(cache, ids):
             return jax.tree_util.tree_map(
-                lambda c: jnp.take(c, ids, axis=_PAGE_AXIS), cache
+                lambda c, w: page_view(
+                    jnp.take(c, ids, axis=_PAGE_AXIS), w.shape[2:]
+                ),
+                cache, wire,
             )
 
         def _scatter(cache, ids, data):
             return jax.tree_util.tree_map(
-                lambda c, d: c.at[:, ids].set(d.astype(c.dtype)), cache, data
+                lambda c, d: c.at[:, ids].set(
+                    page_view(d, c.shape[2:]).astype(c.dtype)
+                ),
+                cache, data,
             )
 
         self._gather_jit = jax.jit(_gather)

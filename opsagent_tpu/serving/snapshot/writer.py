@@ -173,7 +173,9 @@ def write_snapshot(engine: Any, path: str) -> dict[str, Any]:
         )
 
     cfg = engine.cfg
-    cache_leaves = jax.tree_util.tree_flatten_with_path(engine.cache)[0]
+    # The plan records pages in the format they have off the device
+    # ([L, N, P, K, D]), whichever form this engine holds them in.
+    cache_leaves = jax.tree_util.tree_flatten_with_path(engine.cache_wire)[0]
     kv_plan = {
         "num_pages": cfg.num_pages,
         "page_size": cfg.page_size,

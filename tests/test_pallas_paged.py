@@ -98,7 +98,11 @@ def test_decode_step_with_pallas_impl_matches_xla():
     cfg = get_config_preset("tiny-test")
     params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
     P, NP, MaxP, B = 8, 16, 4, 2
-    cache = llama.make_cache(cfg, NP, P, dtype=jnp.float32)
+    # The Pallas kernels take split pages (2 kv heads would be merged).
+    cache = llama.make_cache(
+        cfg, NP, P, dtype=jnp.float32,
+        form=llama.cache_form(cfg, attn_impl="pallas"),
+    )
 
     # Prefill two sequences to populate pages.
     lens = [5, 9]

@@ -312,11 +312,23 @@ def test_scopes_change_no_instruction_of_the_compiled_step(monkeypatch):
         return [ln for ln in text.splitlines()
                 if re.match(r"\s+(ROOT )?%n\d+ = ", ln)]
 
-    with_scopes = lower_program("mixed").compile().as_text()
+    # The persistent compile cache keys a program without its metadata:
+    # it would hand the second compile the first one's executable, names
+    # and all (PERF.md, PR 24's trap), so it is off around these two.
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with_scopes = lower_program("mixed").compile().as_text()
+        monkeypatch.setattr(
+            jax, "named_scope", lambda name: contextlib.nullcontext())
+        without = lower_program("mixed").compile().as_text()
+    finally:
+        monkeypatch.undo()
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
     assert scopes_in(with_scopes) == set(llama.SCOPES)
-    monkeypatch.setattr(
-        jax, "named_scope", lambda name: contextlib.nullcontext())
-    without = lower_program("mixed").compile().as_text()
     # (JAX's own threefry code puts a "sample" on its operations' paths)
     assert not scopes_in(without) - {"sample"}
     assert len(instructions(with_scopes)) > 100

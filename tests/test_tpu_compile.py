@@ -15,7 +15,9 @@ compiler's refusal, and the engine's with the same reason.
 A compile that passes is not a chip run, and nothing here is a time.
 """
 
+import dataclasses
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
 # Describing a topology loads libtpu, which by default is one process's at a
@@ -306,3 +308,143 @@ def test_interpret_mode_is_an_error_on_the_chip(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with pytest.raises(RuntimeError, match="interpret mode is for CPU"):
         attention.pallas_interpret()
+
+
+# -- the KV pages' held form: no step re-tiles the whole cache ---------------
+# The cells' cache geometry (benchmarks/configs): the 7B holds 2560 pages of
+# 16 tokens, 384 a sequence; the 72B widths 2048 pages, 104 a sequence.
+# Two layers and eight rows keep a gathered block (rows x pages a sequence)
+# smaller than one layer-stacked K array, so size alone tells them apart.
+STEP_ROWS, STEP_TOKENS, STEP_LAYERS = 8, 32, 2
+GEOMETRY = {
+    "qwen2.5-7b-instruct": (2560, 384),
+    "qwen2.5-72b-instruct": (2048, 104),
+}
+
+
+def _copies_of(hlo: str, elements: int) -> list[str]:
+    """Names of the ``copy`` instructions of an optimized HLO module whose
+    result has at least ``elements`` elements, wherever they sit: in the
+    layer loop's body or at the program's entry or exit."""
+    out = []
+    for name, dims in re.findall(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]+)\]\S* copy\(", hlo, re.M
+    ):
+        if np.prod([int(d) for d in dims.split(",")]) >= elements:
+            out.append(f"{name}[{dims}]")
+    return out
+
+
+def _mixed_step(sds, preset: str, kv: str):
+    """Compile the engine's ``_mixed_carry`` program (decode_loop.
+    mixed_step_carry, the cache donated) at a preset's widths cut to two
+    layers, with the cache ``llama.make_cache`` gives it."""
+    from opsagent_tpu.models import llama
+    from opsagent_tpu.serving import decode_loop
+
+    cfg = dataclasses.replace(
+        get_config_preset(preset), num_layers=STEP_LAYERS
+    )
+    n, maxp = GEOMETRY[preset]
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: sds(x.shape, x.dtype), tree
+    )
+    params = on_chip(jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)
+    ))
+    cache = on_chip(jax.eval_shape(
+        lambda: llama.make_cache(cfg, n, PAGE, jnp.bfloat16, kv_quantize=kv)
+    ))
+    key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    b = STEP_ROWS
+    i32 = lambda *s: sds(s, jnp.int32)       # noqa: E731
+    f32 = lambda *s: sds(s, jnp.float32)     # noqa: E731
+    flag = lambda *s: sds(s, jnp.bool_)      # noqa: E731
+
+    def step(params, tokens, use_carry, carry, starts, qlens, emits, cache,
+             table, key, temps, top_k, top_p):
+        return decode_loop.mixed_step_carry(
+            params, cfg, tokens, use_carry, carry, starts, qlens, emits,
+            cache, table, key, temps, top_k, top_p,
+        )
+
+    compiled = jax.jit(step, donate_argnames=("cache",)).lower(
+        params, i32(b, STEP_TOKENS), flag(b), i32(b), i32(b), i32(b),
+        flag(b), cache, i32(b, maxp), key, f32(b), i32(b), f32(b),
+    ).compile()
+    whole = STEP_LAYERS * n * PAGE * cfg.num_kv_heads * cfg.head_dim_
+    return cfg, cache, _copies_of(compiled.as_text(), whole)
+
+
+@pytest.mark.parametrize("preset,kv,form", [
+    ("qwen2.5-7b-instruct", "", "merged"),       # cell 1: 4 kv heads
+    ("qwen2.5-7b-instruct", "int8", "merged"),
+    ("qwen2.5-72b-instruct", "", "split"),       # cell 2's widths: 8
+])
+def test_no_step_copies_a_whole_k_or_v_array(v5e, preset, kv, form):
+    """The mixed step holds no copy as large as one layer-stacked K array,
+    in the layer loop or outside it: the page write's scatter and the page
+    gather run in the tiling the pages are held in. At 4 kv heads that is
+    the merged form, at 8 the split one (where merged would add a copy of
+    each gathered block)."""
+    from opsagent_tpu.models import llama
+
+    cfg, cache, copies = _mixed_step(_one_chip(v5e), preset, kv)
+    assert llama.cache_form(cfg) == form
+    n = GEOMETRY[preset][0]
+    k, d = cfg.num_kv_heads, cfg.head_dim_
+    row = (k * d,) if form == "merged" else (k, d)
+    assert cache["k"].shape == (STEP_LAYERS, n, PAGE) + row
+    assert copies == []
+
+
+def test_split_pages_at_four_kv_heads_are_copied_whole_in_every_layer(v5e):
+    """What the merged form removes, kept here as it was (the flat-slot
+    scatter and the paged gather over ``[L, N, P, 4, 128]`` pages), so the
+    test above cannot pass for want of a copy to find: the compiler
+    re-tiles all of K and all of V between the write and the gather, in
+    the loop's body."""
+    sds = _one_chip(v5e)
+    n, maxp = GEOMETRY["qwen2.5-7b-instruct"]
+    b, s, layers = STEP_ROWS, STEP_TOKENS, STEP_LAYERS
+
+    def write(pages, new, flat):
+        pf = pages.reshape(layers * n * PAGE, K, D)
+        return pf.at[flat].set(new.reshape(b * s, K, D), mode="drop").reshape(
+            pages.shape
+        )
+
+    def gather(pages, table, layer):
+        paged = pages.reshape(layers * n, PAGE, K, D)
+        return paged[table + layer * n].reshape(b, maxp * PAGE, K, D)
+
+    def step(kc, vc, q, k_new, v_new, table, flat):
+        def body(carry, _):
+            kc, vc, layer, acc = carry
+            at = flat + layer * n * PAGE
+            kc, vc = write(kc, k_new, at), write(vc, v_new, at)
+            scores = jnp.einsum(
+                "bskgd,btkd->bkgst", (q + acc).reshape(b, s, K, H // K, D),
+                gather(kc, table, layer),
+            )
+            out = jnp.einsum(
+                "bkgst,btkd->bskgd", jax.nn.softmax(scores, -1),
+                gather(vc, table, layer),
+            )
+            return (kc, vc, layer + 1, out.reshape(q.shape)), None
+
+        init = (kc, vc, jnp.int32(0), jnp.zeros_like(q))
+        (kc, vc, _, acc), _ = jax.lax.scan(body, init, None, length=layers)
+        return kc, vc, acc
+
+    pages = sds((layers, n, PAGE, K, D), jnp.bfloat16)
+    new = sds((b, s, K, D), jnp.bfloat16)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        pages, pages, sds((b, s, H, D), jnp.bfloat16), new, new,
+        sds((b, maxp), jnp.int32), sds((b * s,), jnp.int32),
+    ).compile()
+    hlo = compiled.as_text()
+    copies = _copies_of(hlo, layers * n * PAGE * K * D)
+    assert len(copies) == 2, copies     # all of K, and all of V
+    body = hlo[: hlo.index("\nENTRY ")]
+    assert all(c.split("[")[0] + " = " in body for c in copies)
