@@ -31,7 +31,7 @@ import time
 
 import numpy as np
 
-from benchmarks.loading import load_module
+from benchmarks.loading import load_family, load_module
 
 SEQ_BUCKET = 1024      # sequences pad to a multiple: few shapes compile
 ROW_BUCKET = 256       # so do a block's compared positions
@@ -74,12 +74,31 @@ def free_positions(reply: list[int]) -> tuple[list[int], list[int]]:
     return free, illegal
 
 
+SAMPLE_GROWTH = 4      # times ``max_requests``, where replies leave little free
+
+
+def checkable(sample: dict) -> int:
+    """The served tokens of a request that the comparison can judge: every
+    token of a free reply, the free positions of a constrained one."""
+    reply = sample["reply_ids"]
+    if sample.get("constrained"):
+        return len(free_positions(list(reply))[0])
+    return len(reply)
+
+
 def select(finished: list[dict], rng, min_tokens: int,
-           max_requests: int) -> list[dict]:
+           max_requests: int, min_checkable: int = 0) -> list[dict]:
     """The longest finished request, then others drawn by ``rng`` until the
     sample holds ``min_tokens`` served tokens or ``max_requests``. Where the
     requests name their ``client``, one of each client comes before a second
-    of any: a fault that sits in one client's row is then in the sample."""
+    of any: a fault that sits in one client's row is then in the sample.
+
+    Seeded weights now and then make a model that closes every string at
+    once, and its constrained replies leave three or four free positions
+    each: the sample then grows past ``max_requests``, in the same order,
+    until it holds ``min_checkable`` tokens the comparison can judge (the
+    cell's ``min_checked_tokens``), or ``SAMPLE_GROWTH`` times as many
+    requests. Every other sample is what it was."""
     if not finished:
         return []
     order = sorted(
@@ -98,11 +117,14 @@ def select(finished: list[dict], rng, min_tokens: int,
             first.append(i)
         else:
             again.append(i)
-    picked, tokens = [], 0
+    picked, tokens, judged = [], 0, 0
     for i in [order[0], *first, *again]:
         picked.append(finished[i])
         tokens += len(finished[i]["reply_ids"])
-        if tokens >= min_tokens or len(picked) >= max_requests:
+        judged += checkable(finished[i]) if min_checkable else 0
+        full = tokens >= min_tokens or len(picked) >= max_requests
+        if full and (judged >= min_checkable
+                     or len(picked) >= SAMPLE_GROWTH * max_requests):
             break
     return picked
 
@@ -119,9 +141,10 @@ def run_check(config: dict, seed: int, samples: list[dict],
     from benchmarks import weights as W
 
     t0 = time.perf_counter()
+    family = load_family(config)
     ref = load_module("reference", config["reference"])
-    sz = W.sizes(config)
-    eps, theta = config["rms_norm_eps"], config["rope_theta"]
+    sz, no = family.sizes(config), family.LEAF_NO
+    eps = config["rms_norm_eps"]
     root = W.root_key(seed)
 
     seqs, rows, served, allowed, illegal = [], [], [], [], 0
@@ -148,28 +171,30 @@ def run_check(config: dict, seed: int, samples: list[dict],
     # a multiple of ROW_BUCKET: a cell compiles a few shapes, and only one
     # block's float32 activations are live at a time.
     length = -(-max(len(ids) for ids in seqs) // SEQ_BUCKET) * SEQ_BUCKET
-    cos, sin = ref.rope_tables(length, sz["D"], theta)
+    tables = family.position_tables(ref, length, config, sz)
 
     # One jitted call makes a layer's weights from the seed and applies the
     # layer to a block's sequences, so a layer's float32 matrices live only
     # inside the call; the head is applied in blocks of the vocabulary for
-    # the same reason.
-    @functools.partial(jax.jit, static_argnames=("bits",), donate_argnums=2)
-    def layer_step(root, layer, x, bits: int):
+    # the same reason. The family says which leaves a layer of each kind
+    # has and how the reference is called on them.
+    @functools.partial(jax.jit, static_argnames=("kind", "bits"),
+                       donate_argnums=2)
+    def layer_step(root, layer, x, kind: str, bits: int):
         w = {
-            name: (W.dequantize(*leaf, weight_bits=bits)
-                   if isinstance(leaf, tuple) else leaf.astype(jnp.float32))
-            for name, leaf in W.layer_leaves(root, layer, sz).items()
+            name: W.as_float32(leaf, bits)
+            for name, leaf in family.layer_leaves(root, kind, layer, sz).items()
         }
-        return jax.vmap(lambda seq: ref.layer(
-            seq, w, cos, sin, heads=sz["H"], kv_heads=sz["K"], eps=eps))(x)
+        return jax.vmap(lambda seq: family.apply_layer(
+            ref, kind, seq, w, tables, config, sz))(x)
 
     vocab_blocks = 8 if sz["v"] % 8 == 0 else 1
 
     @functools.partial(jax.jit, static_argnames=("bits",))
     def head_logits(root, x, bits: int):
-        q, scale = W.matrix(root, "lm_head", 0, sz["d"], sz["v"])
-        final_norm = W.norm(root, "final_norm", 0, sz).astype(jnp.float32)
+        q, scale = W.matrix(root, no["lm_head"], 0, sz["d"], sz["v"])
+        final_norm = W.norm(root, no["final_norm"], 0, sz["d"])
+        final_norm = final_norm.astype(jnp.float32)
         qs = q.reshape(sz["d"], vocab_blocks, -1).transpose(1, 0, 2)
 
         def one(part):
@@ -182,12 +207,14 @@ def run_check(config: dict, seed: int, samples: list[dict],
 
     @jax.jit
     def embed_rows(root, tok):
-        return W.embedding(root, sz["v"], sz["d"])[tok].astype(jnp.float32)
+        table = W.embedding(root, no["embed"], sz["v"], sz["d"])
+        return table[tok].astype(jnp.float32)
 
     def all_logits(tok, flat_rows, bits: int):
         x = embed_rows(root, jnp.asarray(tok))
-        for l in range(sz["L"]):
-            x = layer_step(root, jnp.int32(l), x, bits=bits)
+        for _key, kind, first, count in family.stacks(sz):
+            for l in range(first, first + count):
+                x = layer_step(root, jnp.int32(l), x, kind=kind, bits=bits)
         return head_logits(root, x.reshape(-1, sz["d"])[flat_rows], bits=bits)
 
     @jax.jit
@@ -272,9 +299,8 @@ def precision_mismatches(stated: dict, impl: dict) -> list[str]:
             for key, got in served.items() if stated.get(key) != got]
 
 
-def verdict(numbers: dict, limits: dict) -> tuple[bool, list[str]]:
-    """(correct, one line for each number beside its limit)."""
-    lines, ok = [], True
+def comparisons(numbers: dict, limits: dict) -> list[tuple]:
+    """(name, number, ``<=`` or ``>=``, limit, met) for each number compared."""
     checks = [
         ("checked_tokens", numbers.get("checked_tokens", 0),
          ">=", limits["min_checked_tokens"]),
@@ -286,11 +312,19 @@ def verdict(numbers: dict, limits: dict) -> tuple[bool, list[str]]:
         if name in limits:
             checks.append(
                 (name, numbers.get(name, float("inf")), "<=", limits[name]))
-    for name, value, op, limit in checks:
-        good = value >= limit if op == ">=" else value <= limit
-        ok = ok and bool(good)
-        lines.append(
-            f"compared {name}: {value!r} (limit {op} {limit!r}) "
-            f"{'ok' if good else 'NOT MET'}"
-        )
-    return ok, lines
+    return [
+        (name, value, op, limit,
+         bool(value >= limit if op == ">=" else value <= limit))
+        for name, value, op, limit in checks
+    ]
+
+
+def verdict(numbers: dict, limits: dict) -> tuple[bool, list[str]]:
+    """(correct, one line for each number beside its limit)."""
+    rows = comparisons(numbers, limits)
+    lines = [
+        f"compared {name}: {value!r} (limit {op} {limit!r}) "
+        f"{'ok' if good else 'NOT MET'}"
+        for name, value, op, limit, good in rows
+    ]
+    return all(row[-1] for row in rows), lines

@@ -28,6 +28,7 @@ T_START = __import__("time").perf_counter()
 import argparse
 import asyncio
 import json
+import math
 import os
 import random
 import signal
@@ -255,6 +256,12 @@ async def measure(cell: dict, args, base: str) -> dict:
         if args.trace:
             lead = max(0.0, (args.seconds - TRACE_SECONDS) / 2)
             await asyncio.sleep(lead)
+            say(f"tracing: {info['setup']['compile_cache_entries_at_start']} "
+                "programs were in the compile cache at start, and the cache "
+                "keys a program without its scope names: the trace shows "
+                "the names of the build that compiled first, so a run that "
+                "must show new names compiles fresh (an empty "
+                f"{info['setup']['compile_cache_dir'] or 'cache directory'})")
             await post("/bench/trace/start")
             t_trace = time.perf_counter()
             await asyncio.sleep(min(TRACE_SECONDS, args.seconds / 2))
@@ -302,7 +309,7 @@ async def measure(cell: dict, args, base: str) -> dict:
         spec = config["check"]
         samples = check.select(
             finished, random.Random(args.seed), spec["sample_tokens"],
-            spec["max_requests"])
+            spec["max_requests"], spec["limits"]["min_checked_tokens"])
         result = await post("/bench/finish", {
             "samples": samples, "control_bits": args.control_bits})
         numbers = result["check"]
@@ -320,11 +327,18 @@ async def measure(cell: dict, args, base: str) -> dict:
         correct, lines = check.verdict(numbers, spec["limits"])
         for line in lines:
             say(line)
+        compared = {
+            name: {"value": value if math.isfinite(value) else None,
+                   "limit": f"{op} {limit!r}"}
+            for name, value, op, limit, _met in check.comparisons(
+                numbers, spec["limits"])
+        }
         ctx = {"before": before, "after": after, "trace": trace,
                "config": config, "traffic": traffic, "counts": counts,
                "client": values,
                "device": result["device"]}
         return {"values": values, "counts": counts, "correct": correct,
+                "compared": compared, "verdict_lines": lines,
                 "device": result["device"], "trace": trace, "ctx": ctx}
 
 
@@ -447,14 +461,24 @@ def main() -> int:
         }
         say("rehearsal complete: control flow only, which proves nothing "
             "about the chip")
-        print(json.dumps(result), flush=True)
+        print_result(result, got)
         return 3
     missing = [n for n in unit if n not in metrics] if not args.trace else []
     if missing:
         say(f"FAILED: the window gave no sample for {missing}")
         return 1
-    print(json.dumps(result), flush=True)
+    print_result(result, got)
     return 0
+
+
+def print_result(result: dict, got: dict) -> None:
+    """The result's line, last on standard output, with each number
+    compared beside its limit as its last key; the same, as lines, last on
+    standard error."""
+    result["compared"] = got["compared"]
+    for line in got["verdict_lines"]:
+        print(f"[bench] {line}", file=sys.stderr, flush=True)
+    print(json.dumps(result), flush=True)
 
 
 if __name__ == "__main__":

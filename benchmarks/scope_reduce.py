@@ -12,9 +12,12 @@ gives:
 
 - ``scope_s``: the device's operation time by ``jax.named_scope`` name. An
   operation's own time (``trace_reduce._self_times``: a ``while`` less its
-  body) goes to the innermost name of ``SCOPES`` on its ``tf_op`` path (the
-  first path, where XLA joined several with ``;``), else to ``unscoped``;
-  seconds per chip, which add up to ``trace_reduce``'s ``op_sum_s``.
+  body) goes to the innermost name on its ``tf_op`` path (the first path,
+  where XLA joined several with ``;``) that is in ``SCOPES`` or among the
+  names the cell's family adds (``families/<family>.py`` ``SCOPES``), else
+  to ``unscoped``; seconds per chip, which add up to ``trace_reduce``'s
+  ``op_sum_s``. A name a family adds takes its time from the scope that
+  encloses it, and the total does not change.
 - ``unscoped_ops``: the largest operations left in ``unscoped``.
 - ``module_s``: the mean device duration of each executed program on the
   ``XLA Modules`` line (``jit__mixed_carry``), and ``module_n`` their count,
@@ -36,9 +39,12 @@ import os
 import struct
 
 from benchmarks import trace_reduce
+from benchmarks.loading import load_family
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-# The program's scope vocabulary (opsagent_tpu/models/llama.py SCOPES).
+# The program's scope vocabulary (opsagent_tpu/models/llama.py SCOPES): the
+# names every family's step has. A family whose program names more (a
+# router, a latent up-projection) lists them in its module.
 SCOPES = (
     "embed", "attn_qkv", "kv_write", "kv_gather", "attn_core", "attn_out",
     "ffn", "lm_head", "sample",
@@ -177,8 +183,8 @@ def read_planes(path: str) -> list:
 
 
 # -- the reduction -------------------------------------------------------------
-def scope_of(tf_op: str) -> str:
-    """The innermost vocabulary name on an ``op_name`` path. Where XLA has
+def scope_of(tf_op: str, scopes: tuple = SCOPES) -> str:
+    """The innermost name of ``scopes`` on an ``op_name`` path. Where XLA has
     merged operations it joins their paths with ``;`` (the whole-cache copy
     between the page write's flat view and the gather's paged one reads
     ``.../kv_gather/reshape;kv_write/kv_write/reshape``): the first path
@@ -186,16 +192,18 @@ def scope_of(tf_op: str) -> str:
     vocabulary has it."""
     first = str(tf_op or "").split(";")[0]
     for part in reversed(first.split("/")):
-        if part in SCOPES:
+        if part in scopes:
             return part
     return UNSCOPED
 
 
-def reduce_planes(planes: list, chips: int = 1) -> dict:
+def reduce_planes(planes: list, chips: int = 1, extra: tuple = ()) -> dict:
+    """``extra``: the scope names the cell's family adds to ``SCOPES``."""
     device = [(n, ls) for n, ls in planes
               if n.startswith(trace_reduce.DEVICE_PLANE)]
     device = device[:chips] if chips else device
-    scope_ns = dict.fromkeys((*SCOPES, UNSCOPED), 0.0)
+    scopes = (*SCOPES, *(s for s in extra if s not in SCOPES))
+    scope_ns = dict.fromkeys((*scopes, UNSCOPED), 0.0)
     unscoped_ns: dict[str, float] = {}
     module_ns: dict[str, list] = {}
     with_tf_op = ops = 0
@@ -205,7 +213,7 @@ def reduce_planes(planes: list, chips: int = 1) -> dict:
                 for ev, _start, self_ns, stats in trace_reduce._self_times(events):
                     ops += 1
                     with_tf_op += "tf_op" in stats
-                    scope = scope_of(stats.get("tf_op", ""))
+                    scope = scope_of(stats.get("tf_op", ""), scopes)
                     scope_ns[scope] += self_ns[0]
                     if scope == UNSCOPED:
                         label = trace_reduce.short_name(ev)
@@ -234,17 +242,17 @@ def reduce_planes(planes: list, chips: int = 1) -> dict:
 
 
 @functools.lru_cache(maxsize=2)
-def _reduce_cached(path: str, mtime: float, chips: int) -> dict:
+def _reduce_cached(path: str, mtime: float, chips: int, extra: tuple) -> dict:
     """One reduction per capture, shared by the readers of a run; its
     summary goes to the run's log once (not into the result line)."""
-    got = reduce_planes(read_planes(path), chips=chips)
+    got = reduce_planes(read_planes(path), chips=chips, extra=extra)
     print(f"[bench] scope_reduce {os.path.relpath(path, ROOT)}: "
           + json.dumps(got), flush=True)
     return got
 
 
-def reduce_file(path: str, chips: int = 1) -> dict:
-    return _reduce_cached(path, os.path.getmtime(path), chips)
+def reduce_file(path: str, chips: int = 1, extra: tuple = ()) -> dict:
+    return _reduce_cached(path, os.path.getmtime(path), chips, tuple(extra))
 
 
 def newest_trace() -> str | None:
@@ -265,14 +273,17 @@ def for_run(ctx: dict) -> dict | None:
     path = newest_trace()
     if path is None:
         return None
-    got = reduce_file(path, chips=trace["devices"])
+    config = ctx.get("config") or {}
+    extra = load_family(config).SCOPES if "family" in config else ()
+    got = reduce_file(path, chips=trace["devices"], extra=extra)
     return got if got["devices"] else None
 
 
 def scope_ms_per_pass(ctx: dict, *scopes: str) -> float | None:
     """Milliseconds of device operation time a model pass spent under the
     given scopes in the traced span (``trace_reduce.model_passes``: a
-    fused decode block counts its ``decode_block`` passes)."""
+    fused decode block counts its ``decode_block`` passes). A scope is one
+    of ``SCOPES``, ``unscoped`` or a name the cell's family adds."""
     got = for_run(ctx)
     if got is None:
         return None

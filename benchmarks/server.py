@@ -31,7 +31,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 
-from benchmarks.loading import load_data  # noqa: E402
+from benchmarks.loading import load_data, load_family  # noqa: E402
 
 
 def say(msg: str) -> None:
@@ -39,73 +39,84 @@ def say(msg: str) -> None:
 
 
 def model_config(config: dict):
-    """The program's ModelConfig from the file's published keys."""
-    from opsagent_tpu.models.config import ModelConfig
+    """The program's ModelConfig from the file's published keys, as the
+    configuration's family builds it."""
+    return load_family(config).model_config(config)
 
-    return ModelConfig(
-        name=config["preset"],
-        vocab_size=config["vocab_size"],
-        hidden_size=config["hidden_size"],
-        intermediate_size=config["intermediate_size"],
-        num_layers=config["num_hidden_layers"],
-        num_heads=config["num_attention_heads"],
-        num_kv_heads=config["num_key_value_heads"],
-        rope_theta=config["rope_theta"],
-        rms_norm_eps=config["rms_norm_eps"],
-        attn_bias=True,
-        tie_embeddings=config["tie_word_embeddings"],
-        max_position=config["max_position_embeddings"],
-    )
+
+def _flat(fields: dict, prefix: str = "") -> dict:
+    """A nested dict with dotted keys: ``{"moe": {"num_experts": 8}}`` ->
+    ``{"moe.num_experts": 8}``."""
+    out = {}
+    for key, value in fields.items():
+        if isinstance(value, dict):
+            out.update(_flat(value, f"{prefix}{key}."))
+        else:
+            out[prefix + key] = value
+    return out
 
 
 def check_against_preset(config: dict, mc) -> None:
-    """Every width of the file equals the in-tree preset it names; only the
-    keys under ``reduced`` may differ."""
+    """Every field of the file's model equals the in-tree preset it names;
+    only the fields that the family's ``REDUCED`` map gives for the keys
+    under ``reduced`` may differ."""
     import dataclasses
 
     from opsagent_tpu.models.config import get_config_preset
 
     preset = get_config_preset(config["preset"])
-    ours, theirs = dataclasses.asdict(mc), dataclasses.asdict(preset)
-    allowed = {"num_hidden_layers": "num_layers"}
-    skip = {allowed[k] for k in config.get("reduced", []) if k in allowed}
-    diff = {k: (ours[k], theirs[k]) for k in ours
-            if k not in skip and ours[k] != theirs[k]}
+    ours = _flat(dataclasses.asdict(mc))
+    theirs = _flat(dataclasses.asdict(preset))
+    allowed = load_family(config).REDUCED
+    unknown = [k for k in config.get("reduced", []) if k not in allowed]
+    if unknown:
+        raise SystemExit(
+            f"reduced keys the family cannot cut: {unknown}; it allows "
+            f"{sorted(allowed)}")
+    skip = {allowed[k] for k in config.get("reduced", [])}
+    diff = {k: (ours.get(k), theirs.get(k)) for k in sorted({*ours, *theirs})
+            if k not in skip and ours.get(k) != theirs.get(k)}
     if diff:
         raise SystemExit(f"configuration differs from preset: {diff}")
 
 
 def tree_builder(config: dict):
     """``root key -> the seeded weights in the layout the program serves``,
-    to be jitted: stacked leaves filled layer by layer under ``lax.map``."""
+    to be jitted: each of the family's stacks filled layer by layer under
+    ``lax.map``, an int8 pair held as a ``QuantizedLinear`` (its scale
+    broadcast over the contraction axis), every other leaf as it is."""
     import jax
     import jax.numpy as jnp
 
     from benchmarks import weights as W
     from opsagent_tpu.models.quant import QuantizedLinear
 
-    sz = W.sizes(config)
-    layers = jnp.arange(sz["L"], dtype=jnp.int32)
+    family = load_family(config)
+    sz, no = family.sizes(config), family.LEAF_NO
+
+    def served(leaf):
+        if isinstance(leaf, tuple):
+            q, scale = leaf
+            return QuantizedLinear(q, scale[..., None, :])
+        return leaf
+
+    stacks = [(key, kind, jnp.arange(first, first + count, dtype=jnp.int32))
+              for key, kind, first, count in family.stacks(sz)]
 
     def build(root):
-        def one(layer):
-            return W.layer_leaves(root, layer, sz)
-
-        stacked = jax.lax.map(one, layers)
         tree = {}
-        for name, leaf in stacked.items():
-            if isinstance(leaf, tuple):
-                q, scale = leaf
-                tree[name] = QuantizedLinear(q, scale[:, None, :])
-            else:
-                tree[name] = leaf
-        q, scale = W.matrix(root, "lm_head", 0, sz["d"], sz["v"])
-        return {
-            "embed": W.embedding(root, sz["v"], sz["d"]),
-            "layers": tree,
-            "final_norm": W.norm(root, "final_norm", 0, sz),
+        for key, kind, layers in stacks:
+            stacked = jax.lax.map(
+                lambda layer: family.layer_leaves(root, kind, layer, sz),
+                layers)
+            tree[key] = {name: served(leaf) for name, leaf in stacked.items()}
+        q, scale = W.matrix(root, no["lm_head"], 0, sz["d"], sz["v"])
+        tree.update({
+            "embed": W.embedding(root, no["embed"], sz["v"], sz["d"]),
+            "final_norm": W.norm(root, no["final_norm"], 0, sz["d"]),
             "lm_head": QuantizedLinear(q, scale[None, :]),
-        }
+        })
+        return tree
 
     return build
 
@@ -261,6 +272,12 @@ def main() -> int:
         for old in glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                              recursive=True):
             os.remove(old)
+        # The trap of scope names: JAX's persistent compile cache keys a
+        # program without its metadata, so an executable that another build
+        # compiled first serves this one too, and the trace then shows THAT
+        # build's ``jax.named_scope`` names. A traced run that must show
+        # names this build (or a family's program) added compiles fresh;
+        # ``run.py`` prints as much beside the cache's count at start.
         # The program's annotations and the device's operations; not every
         # Python call of the host, which would slow the host it measures.
         options = jax.profiler.ProfileOptions()

@@ -6,6 +6,8 @@ import re
 
 import pytest
 
+from benchmarks.loading import FAMILY_ANSWERS, load_family
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH = os.path.join(ROOT, "benchmarks")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -122,7 +124,7 @@ def test_configuration_files_say_what_they_are(entry):
     assert config["source"] == entry["source"]
     assert config["reduced"] == entry["reduced"] and len(entry["reduced"]) <= 16
     for key in ("assumed", "deployment", "precision", "hbm_share", "engine",
-                "reference", "check"):
+                "family", "reference", "check"):
         assert config.get(key), key
     width = re.compile(r"(hidden|intermediate|latent|state|head)_(size|dim)|_dim$|_rank$")
     assert not [k for k in entry["reduced"] if width.search(k)]
@@ -140,3 +142,87 @@ def test_traffic_files_are_data_with_a_generator_and_a_sender(cell):
     assert len(traffic["who"]) > 20
     assert os.path.isfile(
         os.path.join(BENCH, "generators", traffic["generator"] + ".py"))
+
+
+# -- the model family a configuration names ----------------------------------------
+MIN_EXPERTS_HELD = 8        # the model-configs guide's floors for a cut
+MIN_VOCABULARY_SHARE = 8    # at least an eighth of the rows
+
+
+def cut_faults(config: dict, reduced_fields: dict) -> list[str]:
+    """What is wrong with a file's ``reduced`` list, given its family's map
+    from keys under ``reduced`` to fields of the program's model. Every
+    key is one the family can cut and has its published count beside it
+    (``source_<key>``); a cut in the experts held or in the rows of the
+    vocabulary also states the deployment (``chips_sharing_a_layer``) and
+    keeps to the floors."""
+    faults = []
+    for key in config["reduced"]:
+        if key not in reduced_fields:
+            faults.append(f"{key}: not a key the family can cut")
+            continue
+        published = config.get("source_" + key)
+        if not isinstance(published, int) or published < config[key]:
+            faults.append(f"{key}: no published count source_{key} beside it")
+            continue
+        field = reduced_fields[key]
+        if field not in ("moe.num_experts", "vocab_size"):
+            continue
+        chips = config.get("chips_sharing_a_layer")
+        if not isinstance(chips, int) or chips < 1:
+            faults.append(f"{key}: the file states no chips_sharing_a_layer")
+        elif field == "moe.num_experts" and config[key] * chips < published:
+            faults.append(f"{key}: {chips} chips of {config[key]} experts "
+                          f"do not hold the published {published}")
+        if field == "moe.num_experts" and config[key] < MIN_EXPERTS_HELD:
+            faults.append(f"{key}: under {MIN_EXPERTS_HELD} routed experts")
+        if (field == "vocab_size"
+                and config[key] * MIN_VOCABULARY_SHARE < published):
+            faults.append(f"{key}: under an eighth of the vocabulary")
+    return faults
+
+
+@pytest.mark.parametrize("entry", bench()["configs"], ids=lambda c: c["name"])
+def test_every_configuration_names_a_family_that_answers(entry):
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        config = json.load(f)
+    assert NAME.match(config["family"])
+    assert os.path.isfile(
+        os.path.join(BENCH, "families", config["family"] + ".py"))
+    family = load_family(config)
+    for name in FAMILY_ANSWERS:
+        answer = getattr(family, name)
+        assert callable(answer) or isinstance(answer, (dict, tuple)), name
+    assert not cut_faults(config, family.REDUCED)
+    # the rehearsal's sizes are the same family's
+    tiny = dict(config, **config.get("rehearsal", {}))
+    assert family.sizes(tiny)["L"] >= 1
+
+
+CUT = {"n_routed_experts": "moe.num_experts", "vocab_size": "vocab_size",
+       "num_hidden_layers": "num_layers"}
+SOUND = {"reduced": ["num_hidden_layers", "n_routed_experts", "vocab_size"],
+         "num_hidden_layers": 8, "source_num_hidden_layers": 61,
+         "n_routed_experts": 12, "source_n_routed_experts": 192,
+         "vocab_size": 20480, "source_vocab_size": 163840,
+         "chips_sharing_a_layer": 16}
+
+
+@pytest.mark.parametrize("change,fault", [
+    ({}, None),
+    ({"reduced": ["num_hidden_layers"], "chips_sharing_a_layer": None}, None),
+    ({"reduced": ["q_lora_rank"], "q_lora_rank": 64}, "not a key the family can cut"),
+    ({"source_n_routed_experts": None}, "no published count"),
+    ({"source_vocab_size": 1024}, "no published count"),     # under what is held
+    ({"chips_sharing_a_layer": None}, "states no chips_sharing_a_layer"),
+    ({"chips_sharing_a_layer": 8}, "do not hold the published 192"),
+    ({"n_routed_experts": 6, "chips_sharing_a_layer": 32}, "under 8 routed"),
+    ({"vocab_size": 20479}, "under an eighth"),
+])
+def test_a_cut_states_its_source_and_deployment_and_keeps_the_floors(change, fault):
+    config = {k: v for k, v in {**SOUND, **change}.items() if v is not None}
+    faults = cut_faults(config, CUT)
+    if fault is None:
+        assert not faults
+    else:
+        assert len(faults) >= 1 and any(fault in x for x in faults), faults

@@ -287,3 +287,80 @@ def test_kernel_readers_charge_a_pass_its_scopes(tmp_path, monkeypatch):
     assert ms["kernels.unscoped_ms"] == pytest.approx(0.030 / 9)
     assert ms["kernels.attn_core_ms"] == ms["kernels.proj_ms"] == 0.0
     assert sum(ms.values()) == pytest.approx(0.150 / 9)
+
+
+# -- the names a family adds -------------------------------------------------------
+def test_with_no_added_name_every_scope_reads_what_it_read():
+    """The two cells' family adds none: the vocabulary is the nine, and a
+    family's empty list changes no scope's time."""
+    base = scope_reduce.reduce_file(SCOPED)
+    assert set(base["scope_s"]) == {*scope_reduce.SCOPES, "unscoped"}
+    assert len(scope_reduce.SCOPES) == 9
+    same = scope_reduce.reduce_planes(scope_reduce.read_planes(SCOPED), extra=())
+    assert same["scope_s"] == base["scope_s"]
+    # a name of the nine given again is no tenth scope
+    again = scope_reduce.reduce_planes(
+        scope_reduce.read_planes(SCOPED), extra=("ffn",))
+    assert again["scope_s"] == base["scope_s"]
+
+
+@pytest.mark.parametrize("name,enclosers", [
+    # the draw inside the sampler: JAX's own jit name on the path
+    ("jit(_gumbel)", {"sample"}),
+    # the scores' contraction inside the attention core
+    ("bkgd,blkd->bkgl", {"attn_core"}),
+    # one that lies inside several of the nine, and outside them all
+    ("dot_general:", {"attn_qkv", "attn_core", "attn_out", "ffn", "lm_head",
+                      "unscoped"}),
+])
+def test_an_added_name_takes_exactly_what_its_enclosers_lose(name, enclosers):
+    """On the chip recording: a name that lies on operation paths inside
+    the nine is charged the own time of the operations under it, the scopes
+    that enclose it lose exactly that, the others keep theirs, and the total
+    does not change."""
+    planes = scope_reduce.read_planes(SCOPED)
+    base = scope_reduce.reduce_planes(planes)["scope_s"]
+    got = scope_reduce.reduce_planes(planes, extra=(name,))["scope_s"]
+    assert set(got) == {*base, name}
+    assert got[name] > 0
+    lost = {s: base[s] - got[s] for s in base}
+    assert {s for s, x in lost.items() if x > 1e-12} <= enclosers
+    assert all(x >= 0 for x in lost.values())
+    assert sum(lost.values()) == pytest.approx(got[name], rel=1e-9)
+    assert sum(got.values()) == pytest.approx(sum(base.values()), rel=1e-12)
+    assert scope_reduce.scope_of(
+        f"jit(f)/while/body/ffn/{name}/mul", (*scope_reduce.SCOPES, name)) == name
+    assert scope_reduce.scope_of(f"jit(f)/while/body/ffn/{name}/mul") == "ffn"
+
+
+def test_the_readers_answer_for_a_familys_name(tmp_path, monkeypatch):
+    """``scope_ms_per_pass`` over a capture, with the cell's family found by
+    the name in its configuration: the nine as before, and a name the
+    family adds (here a family made for the test, whose program names the
+    gather inside the attention core)."""
+    from benchmarks import loading
+
+    trace_dir = tmp_path / ".bench_out" / "cell" / "trace" / "plugins" / "profile" / "x"
+    trace_dir.mkdir(parents=True)
+    write_xspace(trace_dir / "host.xplane.pb", scoped_planes())
+    monkeypatch.setattr(scope_reduce, "ROOT", str(tmp_path))
+    families = tmp_path / "families"
+    families.mkdir()
+    with open(os.path.join(ROOT, "benchmarks", "families", "qwen2.py")) as f:
+        text = f.read()
+    assert "SCOPES = ()" in text
+    (families / "naming.py").write_text(
+        text.replace("SCOPES = ()", 'SCOPES = ("gather",)'))
+    (families / "qwen2.py").write_text(text)
+    monkeypatch.setattr(loading, "HERE", str(tmp_path))
+    ctx = {"trace": {"devices": 1, "annotations": {
+               "engine.mixed_step_async": 1, "engine.decode_block": 1}},
+           "config": {"engine": {"decode_block": 8}, "family": "qwen2"}}
+    assert scope_reduce.scope_ms_per_pass(ctx, "kv_gather") == pytest.approx(0.030 / 9)
+    with pytest.raises(KeyError):
+        scope_reduce.scope_ms_per_pass(ctx, "gather")
+    ctx["config"]["family"] = "naming"
+    assert scope_reduce.scope_ms_per_pass(ctx, "gather") == pytest.approx(0.030 / 9)
+    assert scope_reduce.scope_ms_per_pass(ctx, "kv_gather") == 0.0
+    assert scope_reduce.scope_ms_per_pass(ctx, "ffn", "lm_head", "unscoped") == (
+        pytest.approx(0.120 / 9))

@@ -303,3 +303,30 @@ def test_same_seed_same_requests_other_seed_same_sizes(mix):
     assert all(first["lo"] <= s["first_user"] <= first["hi"]
                for s in a["sessions"])
     assert len(a["system"]) == params["system_tokens"]
+
+
+def test_a_sample_of_replies_with_little_free_grows_until_it_can_be_judged():
+    """A seed whose model closes every string at once (cell 1, seed
+    2600003303 on the chip, PR 26): three free positions a reply. The sample
+    grows past ``max_requests`` in its own order until the comparison has
+    its least tokens; a sample that has them is what it was."""
+    closed = [ord(c) for c in '{"question":"","thought":"","action":""}']
+    wordy = [ord(c) for c in '{"question":"how many pods","thought":"count"}']
+    assert check.checkable({"reply_ids": closed, "constrained": True}) == 3
+    assert check.checkable({"reply_ids": closed, "constrained": False}) == len(closed)
+
+    def finished(reply):
+        return [{"prompt_ids": [1] * (40 + i), "reply_ids": reply,
+                 "constrained": True, "client": i % 8} for i in range(96)]
+
+    old = check.select(finished(closed), random.Random(7), 10**6, 12)
+    grown = check.select(finished(closed), random.Random(7), 10**6, 12, 100)
+    assert len(old) == 12 and grown[:12] == old
+    assert len(grown) == 34 and sum(map(check.checkable, grown)) >= 100
+    # never past four times the requests, however little is free
+    capped = check.select(finished(closed), random.Random(7), 10**6, 12, 10**6)
+    assert len(capped) == 48 and capped[:34] == grown
+    # replies with enough free positions: the sample does not change
+    same = check.select(finished(wordy), random.Random(7), 10**6, 12, 100)
+    assert same == check.select(finished(wordy), random.Random(7), 10**6, 12)
+    assert sum(map(check.checkable, same)) >= 100
