@@ -1,0 +1,21 @@
+"""Device time of one model pass spent in the router over all its published experts: float32 scores, top-k, combine weights, and the sort that plans the dispatch (``moe_router``).
+
+Layer: kernels (ops/linear_attention.py, models/llama.py and what XLA makes
+of them). Source: the device trace: own time of each operation, charged to
+the innermost ``jax.named_scope`` name on its ``tf_op`` path
+(``benchmarks/scope_reduce.py``; the name is one the cell's family adds,
+``families/solar_open2.py`` ``SCOPES``), over the model passes of the traced
+span. With the seven ``kernels.*_ms`` every cell reads, whose ``ffn``,
+``attn_qkv`` and ``attn_out`` hold what is left of a layer, the five of this
+family add up to ``step.device_ms_mean`` less the device's idle share. A
+program without the scope (the parent's) gives nothing to read.
+Moves: tpot_p50_ms.
+"""
+from benchmarks import scope_reduce
+
+
+def read(ctx: dict):
+    try:
+        return scope_reduce.scope_ms_per_pass(ctx, 'moe_router')
+    except KeyError:        # the family of this cell adds no such scope
+        return None

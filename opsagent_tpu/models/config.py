@@ -49,6 +49,19 @@ class MoEConfig:
     # not num_experts. 0 disables grouped dispatch entirely.
     grouped_dispatch_min_tokens: int = 512
     capacity_factor: float = 2.0
+    # Experts at one chip's share of an expert-parallel deployment:
+    # ``num_experts`` stays the count HELD here (the leading axis of the
+    # expert stacks), ``router_experts`` is the router's published width
+    # (0 = the router is as wide as what is held: the whole layer lives
+    # here) and ``first_expert`` the global id of the first one held. The
+    # router scores and ranks all ``router_experts``; the layer computes the
+    # chosen experts it holds and leaves out what the absent ones would add.
+    router_experts: int = 0
+    first_expert: int = 0
+
+    @property
+    def router_width(self) -> int:
+        return self.router_experts or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -82,6 +95,41 @@ class MLAConfig:
     @property
     def latent_dim(self) -> int:
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class LinearAttnConfig:
+    """Delta-rule linear attention with a per-channel decay (Kimi Delta
+    Attention): a float32 state ``[num_heads, key_head_dim, value_head_dim]``
+    per sequence instead of pages, a causal depthwise convolution of
+    ``conv_kernel`` over the q/k/v streams (its last ``conv_kernel - 1``
+    inputs are state too), low-rank decay and output gates of width
+    ``gate_rank``. ``neg_eigval`` doubles beta's range to (0, 2), which lets
+    a state transition have negative eigenvalues."""
+
+    num_heads: int = 4
+    key_head_dim: int = 16
+    value_head_dim: int = 16
+    conv_kernel: int = 4
+    gate_rank: int = 16
+    neg_eigval: bool = True
+
+    @property
+    def key_size(self) -> int:
+        return self.num_heads * self.key_head_dim
+
+    @property
+    def value_size(self) -> int:
+        return self.num_heads * self.value_head_dim
+
+    @property
+    def conv_size(self) -> int:
+        """Width of the convolved stream: q, k and v side by side."""
+        return 2 * self.key_size + self.value_size
+
+
+# Kinds of token mixer a layer may have (``ModelConfig.mixer_period``).
+MIXERS = ("attn", "linear")
 
 
 @dataclass(frozen=True)
@@ -131,6 +179,53 @@ class ModelConfig:
     # Long-context rope scaling; when set, max_position may cover the
     # scaled window (factor x original_max_position).
     rope_scaling: Optional[RopeScalingConfig] = None
+    # The layer pattern, by its period: the kind of token mixer at each
+    # position of one period ("attn" softmax attention over pages, "linear"
+    # delta-rule linear attention over a recurrent state); layer ``i`` has
+    # ``mixer_period[i % len(mixer_period)]``. Empty = every layer "attn".
+    # Equal at any depth that is a whole number of periods. The MLP's kind
+    # stays ``moe`` / ``moe_layer_start``.
+    mixer_period: tuple = ()
+    linear_attn: Optional[LinearAttnConfig] = None
+    # softmax-attention output multiplied by sigmoid(x W_gate) before wo
+    attn_output_gate: bool = False
+    use_rope: bool = True            # False: no positional embedding (NoPE)
+
+    def __post_init__(self):
+        period = tuple(self.mixer_period)
+        object.__setattr__(self, "mixer_period", period)
+        if not period:
+            return
+        bad = [m for m in period if m not in MIXERS]
+        if bad:
+            raise ValueError(f"mixer_period {period}: unknown mixers {bad}")
+        if "linear" in period and self.linear_attn is None:
+            raise ValueError("mixer_period has linear layers: linear_attn unset")
+        if self.num_layers % len(period) or (
+            self.moe is not None and self.moe_layer_start % len(period)
+        ):
+            raise ValueError(
+                f"num_layers={self.num_layers} (and moe_layer_start) must be "
+                f"whole periods of {len(period)} layers"
+            )
+
+    @property
+    def period_(self) -> tuple:
+        """The period as run: ("attn",) where none is configured."""
+        return self.mixer_period or ("attn",)
+
+    def mixer_of(self, layer: int) -> str:
+        return self.period_[layer % len(self.period_)]
+
+    def count_mixers(self, kind: str) -> int:
+        """Layers of the whole model whose mixer is ``kind``."""
+        p = self.period_
+        return self.num_layers // len(p) * sum(1 for m in p if m == kind)
+
+    @property
+    def has_state(self) -> bool:
+        """Some layer keeps a recurrent state beside (or instead of) pages."""
+        return "linear" in self.period_
 
     @property
     def head_dim_(self) -> int:
@@ -150,16 +245,52 @@ class ModelConfig:
     def kv_size(self) -> int:
         return self.num_kv_heads * self.head_dim_
 
-    def num_params(self) -> int:
-        """Approximate parameter count (dense layers)."""
+    def num_params(self, active: bool = False) -> int:
+        """Parameters of the model this configuration describes: every
+        layer by its mixer and its MLP, the router at its published width
+        and the experts HELD here (a chip's share counts its share; the
+        whole model is the preset with every expert held). ``active``
+        counts what one token uses: its ``num_experts_per_token`` routed
+        experts instead of all. MLA projections are not itemised (counted
+        as plain attention)."""
         d, f, v = self.hidden_size, self.intermediate_size, self.vocab_size
-        per_layer = (
-            d * self.q_size + 2 * d * self.kv_size + self.q_size * d  # attn
-            + 3 * d * f                                               # mlp
-            + 2 * d                                                   # norms
-        )
+        attn = d * self.q_size + 2 * d * self.kv_size + self.q_size * d
+        if self.attn_output_gate:
+            attn += d * self.q_size
+        if self.attn_bias:
+            attn += self.q_size + 2 * self.kv_size
+        if self.qk_norm:
+            attn += 2 * self.head_dim_
+        linear = 0
+        if self.linear_attn is not None:
+            la = self.linear_attn
+            linear = (
+                d * la.conv_size + la.conv_kernel * la.conv_size   # q,k,v, conv
+                + la.value_size * d                                # wo
+                + 2 * d * la.gate_rank                             # gate downs
+                + la.gate_rank * (la.key_size + la.value_size)     # gate ups
+                + d * la.num_heads                                 # beta
+                + la.num_heads + la.key_size                       # A_log, dt_bias
+                + la.value_head_dim                                # output norm
+            )
+        dense_mlp = 3 * d * f
+        moe_mlp = 0
+        if self.moe is not None:
+            m = self.moe
+            fe = m.expert_intermediate_size or f
+            routed = m.num_experts_per_token if active else m.num_experts
+            moe_mlp = (
+                d * m.router_width
+                + (m.router_width if m.scoring_func == "sigmoid" else 0)
+                + 3 * d * fe * (routed + m.num_shared_experts)
+            )
+        total = 0
+        for layer in range(self.num_layers):
+            total += attn if self.mixer_of(layer) == "attn" else linear
+            is_moe = self.moe is not None and layer >= self.moe_layer_start
+            total += (moe_mlp if is_moe else dense_mlp) + 2 * d
         embed = v * d * (1 if self.tie_embeddings else 2)
-        return self.num_layers * per_layer + embed + d
+        return total + embed + d
 
 
 PRESETS: dict[str, ModelConfig] = {}
@@ -423,6 +554,83 @@ DEEPSEEK_V3 = _register(
     )
 )
 
+# Solar-Open2-250B (upstage; HF solar_open2): 48 layers in periods of four,
+# layer i softmax GQA (64 query / 8 kv heads of 128, NO rotary embedding, a
+# sigmoid output gate) where i % 4 == 0 and delta-rule linear attention
+# (64 heads, key and value dims 128, conv 4, negative eigenvalues) otherwise;
+# every layer 320 routed experts of width 1280, top-8 by sigmoid score with
+# a selection bias, one shared expert. The low-rank gate width (the head
+# dim) is the KDA publication's choice; the config leaves it out.
+SOLAR_OPEN2_250B = _register(
+    ModelConfig(
+        name="solar-open2-250b",
+        vocab_size=196608,
+        hidden_size=4096,
+        intermediate_size=10240,
+        num_layers=48,
+        num_heads=64,
+        num_kv_heads=8,
+        head_dim=128,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-5,
+        max_position=1048576,
+        moe=MoEConfig(
+            num_experts=320,
+            num_experts_per_token=8,
+            num_shared_experts=1,
+            expert_intermediate_size=1280,
+            norm_topk_prob=True,
+            routed_scaling_factor=1.0,
+            scoring_func="sigmoid",
+            router_experts=320,
+            first_expert=0,
+        ),
+        moe_layer_start=0,
+        mixer_period=("attn", "linear", "linear", "linear"),
+        linear_attn=LinearAttnConfig(
+            num_heads=64, key_head_dim=128, value_head_dim=128,
+            conv_kernel=4, gate_rank=128, neg_eigval=True,
+        ),
+        attn_output_gate=True,
+        use_rope=False,
+    )
+)
+
+# One period of the same pattern at toy widths (CPU tests): 8 experts of
+# which 4 are held here, so the share and the router differ.
+TINY_HYBRID = _register(
+    ModelConfig(
+        name="tiny-hybrid",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        rope_theta=10000.0,
+        max_position=4096,
+        moe=MoEConfig(
+            num_experts=4,
+            num_experts_per_token=2,
+            num_shared_experts=1,
+            expert_intermediate_size=32,
+            norm_topk_prob=True,
+            scoring_func="sigmoid",
+            router_experts=8,
+            first_expert=0,
+        ),
+        moe_layer_start=0,
+        mixer_period=("attn", "linear", "linear", "linear"),
+        linear_attn=LinearAttnConfig(
+            num_heads=4, key_head_dim=16, value_head_dim=16,
+            conv_kernel=4, gate_rank=16, neg_eigval=True,
+        ),
+        attn_output_gate=True,
+        use_rope=False,
+    )
+)
+
 TINY_MLA = _register(
     ModelConfig(
         name="tiny-mla",
@@ -493,11 +701,17 @@ def config_from_hf(path: str, name: str = "") -> ModelConfig:
         hf = json.load(f)
     mt = hf.get("model_type", "llama")
     if mt not in ("llama", "mistral", "qwen2", "qwen3", "qwen3_moe",
-                  "deepseek", "deepseek_v2", "deepseek_v3"):
+                  "deepseek", "deepseek_v2", "deepseek_v3", "solar_open2"):
         raise ValueError(
             f"config_from_hf supports model_type llama/mistral/qwen2/"
-            f"qwen3/qwen3_moe/deepseek/deepseek_v2/deepseek_v3, got {mt!r}"
+            f"qwen3/qwen3_moe/deepseek/deepseek_v2/deepseek_v3/solar_open2, "
+            f"got {mt!r}"
         )
+    name = name or os.path.basename(os.path.normpath(
+        path if os.path.isdir(path) else os.path.dirname(cfg_path)
+    )) or mt
+    if mt == "solar_open2":
+        return _solar_open2_from_hf(hf, name)
     # Sliding-window attention is not implemented; a config that would
     # ACTIVELY use it must be rejected loudly, never silently served
     # with full attention. Mistral (llama-shaped otherwise: same weight
@@ -620,9 +834,7 @@ def config_from_hf(path: str, name: str = "") -> ModelConfig:
             raise ValueError(f"unsupported rope_scaling type {rt!r}")
     heads = int(hf["num_attention_heads"])
     return ModelConfig(
-        name=name or os.path.basename(os.path.normpath(
-            path if os.path.isdir(path) else os.path.dirname(cfg_path)
-        )) or mt,
+        name=name,
         vocab_size=int(hf["vocab_size"]),
         hidden_size=int(hf["hidden_size"]),
         intermediate_size=int(hf["intermediate_size"]),
@@ -646,6 +858,125 @@ def config_from_hf(path: str, name: str = "") -> ModelConfig:
         mla=mla,
         rope_scaling=rs,
     )
+
+
+def _solar_open2_from_hf(hf: dict, name: str) -> ModelConfig:
+    """``model_type: solar_open2``: softmax GQA layers (no rotary embedding,
+    a sigmoid output gate) every ``gqa_interval + 1`` layers, delta-rule
+    linear attention between them, DeepSeek-V3-style experts in every layer.
+    What the config leaves out follows the KDA publication: low-rank decay
+    and output gates as wide as a head, sigmoid scores with a selection
+    bias. ``experts_held`` / ``first_expert_held`` (this engine's own keys,
+    written by ``hf_config_dict``) say which share of the experts is here."""
+    period = int(hf.get("gqa_interval", 3)) + 1
+    layers = int(hf["num_hidden_layers"])
+    gqa = [i for i in hf.get("gqa_layers", range(0, layers, period))
+           if i < layers]
+    if gqa != list(range(0, layers, period)):
+        raise ValueError(
+            f"solar_open2: gqa_layers {gqa} are not every {period}th layer "
+            f"from 0: only a periodic pattern is supported"
+        )
+    if int(hf.get("first_k_dense_replace", 0)) % period:
+        raise ValueError("solar_open2: first_k_dense_replace splits a period")
+    if hf.get("kda_use_full_proj", False):
+        raise ValueError("solar_open2: kda_use_full_proj=true is not supported")
+    if float(hf.get("partial_rotary_factor", 1)) != 1 and hf.get("use_rope"):
+        raise ValueError("solar_open2: partial rotary embedding is not supported")
+    la = hf["linear_attn_config"]
+    la_heads = int(la["num_heads"])
+    if la.get("num_kv_heads") not in (None, la_heads):
+        raise ValueError("solar_open2: grouped linear-attention heads")
+    la_dim = int(la["head_dim"])
+    routed = int(hf["n_routed_experts"])
+    return ModelConfig(
+        name=name,
+        vocab_size=int(hf["vocab_size"]),
+        hidden_size=int(hf["hidden_size"]),
+        intermediate_size=int(hf["intermediate_size"]),
+        num_layers=layers,
+        num_heads=int(hf["num_attention_heads"]),
+        num_kv_heads=int(hf["num_key_value_heads"]),
+        head_dim=int(hf.get("head_dim") or 0),
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rms_norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        max_position=int(hf.get("max_position_embeddings", 8192)),
+        moe=MoEConfig(
+            num_experts=int(hf.get("experts_held", routed)),
+            num_experts_per_token=int(hf["num_experts_per_tok"]),
+            num_shared_experts=int(hf.get("n_shared_experts", 0) or 0),
+            expert_intermediate_size=int(hf["moe_intermediate_size"]),
+            norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            scoring_func="sigmoid",
+            router_experts=routed,
+            first_expert=int(hf.get("first_expert_held", 0)),
+        ),
+        moe_layer_start=int(hf.get("first_k_dense_replace", 0)),
+        mixer_period=("attn",) + ("linear",) * (period - 1),
+        linear_attn=LinearAttnConfig(
+            num_heads=la_heads, key_head_dim=la_dim, value_head_dim=la_dim,
+            conv_kernel=int(la.get("short_conv_kernel_size", 4)),
+            gate_rank=la_dim,
+            neg_eigval=bool(hf.get("kda_allow_neg_eigval", False)),
+        ),
+        attn_output_gate=bool(hf.get("use_gqa_gate", False)),
+        use_rope=bool(hf.get("use_rope", True)),
+    )
+
+
+def _solar_open2_dict(cfg: ModelConfig) -> dict:
+    """The inverse of ``_solar_open2_from_hf``."""
+    la, m = cfg.linear_attn, cfg.moe
+    period = len(cfg.mixer_period)
+    if (cfg.mixer_period != ("attn",) + ("linear",) * (period - 1)
+            or m is None or m.scoring_func != "sigmoid" or cfg.mla
+            or la.key_head_dim != la.value_head_dim
+            or la.gate_rank != la.key_head_dim):
+        raise ValueError(
+            "hf_config_dict: this layer pattern is not expressible as "
+            "model_type solar_open2"
+        )
+    hf = {
+        "model_type": "solar_open2",
+        "architectures": ["SolarOpen2ForCausalLM"],
+        "vocab_size": cfg.vocab_size,
+        "hidden_size": cfg.hidden_size,
+        "intermediate_size": cfg.intermediate_size,
+        "moe_intermediate_size": m.expert_intermediate_size,
+        "num_hidden_layers": cfg.num_layers,
+        "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.num_kv_heads,
+        "head_dim": cfg.head_dim_,
+        "rope_theta": cfg.rope_theta,
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "tie_word_embeddings": cfg.tie_embeddings,
+        "max_position_embeddings": cfg.max_position,
+        "partial_rotary_factor": 1,
+        "use_rope": cfg.use_rope,
+        "use_gqa_gate": cfg.attn_output_gate,
+        "gqa_interval": period - 1,
+        "gqa_layers": list(range(0, cfg.num_layers, period)),
+        "linear_attn_config": {
+            "short_conv_kernel_size": la.conv_kernel,
+            "head_dim": la.key_head_dim,
+            "num_heads": la.num_heads,
+            "num_kv_heads": None,
+        },
+        "kda_use_full_proj": False,
+        "kda_allow_neg_eigval": la.neg_eigval,
+        "first_k_dense_replace": cfg.moe_layer_start,
+        "n_routed_experts": m.router_width,
+        "n_shared_experts": m.num_shared_experts,
+        "num_experts_per_tok": m.num_experts_per_token,
+        "norm_topk_prob": m.norm_topk_prob,
+        "routed_scaling_factor": m.routed_scaling_factor,
+    }
+    if m.num_experts != m.router_width or m.first_expert:
+        hf["experts_held"] = m.num_experts
+        hf["first_expert_held"] = m.first_expert
+    return hf
 
 
 def resolve_model(
@@ -676,6 +1007,13 @@ def hf_config_dict(cfg: ModelConfig) -> dict:
     with a plain softmax MoE); other MoE and/or MLA configs emit the
     deepseek family (deepseek_v2/v3 when MLA is present, deepseek
     otherwise)."""
+    if cfg.has_state:
+        return _solar_open2_dict(cfg)
+    if cfg.mixer_period or cfg.attn_output_gate or not cfg.use_rope:
+        raise ValueError(
+            "hf_config_dict: a layer pattern, an attention output gate or "
+            "NoPE attention is only expressible as solar_open2"
+        )
     qwen3_moe = (
         cfg.qk_norm and cfg.moe is not None and cfg.mla is None
         and cfg.moe.scoring_func == "softmax"
@@ -787,6 +1125,14 @@ def hf_config_dict(cfg: ModelConfig) -> dict:
     return hf
 
 
-def scaled_for_test(cfg: ModelConfig, vocab_size: int = 512) -> ModelConfig:
-    """Shrink a preset's vocab for fast CPU tests, keeping its shape ratios."""
-    return replace(cfg, vocab_size=vocab_size)
+def scaled_for_test(
+    cfg: ModelConfig, vocab_size: int = 512, periods: int = 0
+) -> ModelConfig:
+    """Shrink a preset's vocab for fast CPU tests, keeping its shape ratios;
+    ``periods`` also cuts the depth to that many WHOLE periods of the layer
+    pattern (after any leading dense layers), never through one."""
+    out = replace(cfg, vocab_size=vocab_size)
+    if periods:
+        depth = cfg.moe_layer_start if cfg.moe is not None else 0
+        out = replace(out, num_layers=depth + periods * len(cfg.period_))
+    return out

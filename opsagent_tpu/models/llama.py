@@ -27,7 +27,7 @@ from __future__ import annotations
 import contextlib
 import math
 import threading
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +43,11 @@ from ..ops.attention import (
     paged_ragged_attention_auto,
     write_kv_pages,
     write_pages,
+)
+from ..ops.linear_attention import (
+    conv_with_tail,
+    delta_rule_chunk,
+    delta_rule_step,
 )
 from ..ops.rope import apply_rope, rope_table
 from ..utils.profiling import scoped
@@ -67,6 +72,24 @@ SCOPES = (
     "lm_head",    # final norm, last-position select, vocabulary projection
     "sample",     # FSM mask, top-k/top-p, draw, carry (serving/decode_loop.py)
 )
+# Names a model with linear-attention layers or an expert share adds inside
+# the nine (innermost wins, so a name here takes its time from the scope
+# that encloses it): the benchmark's family lists the ones it reads.
+EXTRA_SCOPES = (
+    "lin_proj",     # a linear layer's projections, conv, norms and gates
+    "lin_scan",     # decay, delta update, read-out
+    "state_io",     # state-slot gather / scatter, snapshot copies in a step
+    "attn_gate",    # sigmoid output gate of a gated attention layer
+    "moe_router",   # router scores, top-k, weights, the dispatch plan
+    "moe_experts",  # the routed experts' matmuls
+    "moe_shared",   # the always-on shared expert
+)
+# Accumulators a model with an expert share keeps in ``cache["stats"]``, one
+# uint32 each, added to by every MoE layer of every pass and never reset:
+# they wrap, and the engine reads their deltas modulo 2**32 into Prometheus
+# counters (``Engine.sync_device_counters``).
+MOE_STATS = ("moe_layer_passes", "landed", "absent", "experts_touched",
+             "max_load")
 
 
 def _layer_split(cfg: ModelConfig) -> tuple[int, int]:
@@ -74,6 +97,57 @@ def _layer_split(cfg: ModelConfig) -> tuple[int, int]:
     if cfg.moe is None:
         return cfg.num_layers, 0
     return cfg.moe_layer_start, cfg.num_layers - cfg.moe_layer_start
+
+
+def period_runs(cfg: ModelConfig) -> tuple[tuple[str, str, int], ...]:
+    """One period of the layer pattern as runs of like mixers, in order:
+    ``((tree key, mixer, layers), ...)``, e.g. ``(("r0_attn", "attn", 1),
+    ("r1_linear", "linear", 3))``. A model whose period is one attention
+    layer has no runs: its stacks keep the flat ``[L, ...]`` leaves."""
+    period = cfg.period_
+    if period == ("attn",):
+        return ()
+    runs: list[list] = []
+    for mixer in period:
+        if runs and runs[-1][0] == mixer:
+            runs[-1][1] += 1
+        else:
+            runs.append([mixer, 1])
+    return tuple(
+        (f"r{i}_{mixer}", mixer, n) for i, (mixer, n) in enumerate(runs)
+    )
+
+
+def stack_layer_runs(cfg: ModelConfig, params: Params) -> Params:
+    """The period layout from runs given one by one. A source that makes a
+    patterned model's layers in order (a seeded builder, a loader) holds
+    each contiguous run of like layers under ``"<stack>:<period>:<run
+    key>"`` with leaves ``[layers of the run, ...]``; the layer scan wants
+    ``params[stack][run key]`` with leaves ``[periods, layers of the run,
+    ...]``. Stacks leaf by leaf, dropping the inputs as it goes, so the
+    transient is one leaf. A tree with no such key is returned as it is."""
+    keys = sorted(k for k in params if ":" in k)
+    if not keys:
+        return params
+    out = {k: v for k, v in params.items() if ":" not in k}
+    for stack in sorted({k.split(":")[0] for k in keys}):
+        periods = 1 + max(
+            int(k.split(":")[1]) for k in keys if k.startswith(stack + ":")
+        )
+        out[stack] = {}
+        for run_key, _mixer, _n in period_runs(cfg):
+            parts = [params[f"{stack}:{p}:{run_key}"] for p in range(periods)]
+            names = list(parts[0])
+            stacked = {}
+            for name in names:
+                stacked[name] = jax.tree.map(
+                    lambda *xs: jnp.stack(xs), *(part[name] for part in parts)
+                )
+                for part in parts:
+                    for leaf in jax.tree.leaves(part.pop(name)):
+                        leaf.delete()
+            out[stack][run_key] = stacked
+    return out
 
 
 # -- init / specs -----------------------------------------------------------
@@ -128,56 +202,105 @@ def _build_tree(cfg: ModelConfig, ks, dtype, big, dense) -> Params:
     q, kv = cfg.q_size, cfg.kv_size
     Ld, Lm = _layer_split(cfg)
 
-    def attn_block(L: int) -> Params:
+    def attn_block(L: tuple) -> Params:
         if cfg.mla is not None:
-            return _mla_attn_block(cfg, L, ks, dtype, big)
+            return _mla_attn_block(cfg, L[0], ks, dtype, big)
         block: Params = {
-            "attn_norm": jnp.ones((L, d), dtype),
-            "wq": big(next(ks), (L, d, q), d),
-            "wk": big(next(ks), (L, d, kv), d),
-            "wv": big(next(ks), (L, d, kv), d),
-            "wo": big(next(ks), (L, q, d), q),
-            "mlp_norm": jnp.ones((L, d), dtype),
+            "attn_norm": jnp.ones((*L, d), dtype),
+            "wq": big(next(ks), (*L, d, q), d),
+            "wk": big(next(ks), (*L, d, kv), d),
+            "wv": big(next(ks), (*L, d, kv), d),
+            "wo": big(next(ks), (*L, q, d), q),
+            "mlp_norm": jnp.ones((*L, d), dtype),
         }
         if cfg.attn_bias:
-            block["bq"] = jnp.zeros((L, q), dtype)
-            block["bk"] = jnp.zeros((L, kv), dtype)
-            block["bv"] = jnp.zeros((L, kv), dtype)
+            block["bq"] = jnp.zeros((*L, q), dtype)
+            block["bk"] = jnp.zeros((*L, kv), dtype)
+            block["bv"] = jnp.zeros((*L, kv), dtype)
         if cfg.qk_norm:
             # Qwen3 per-head q/k RMSNorm weights (over head_dim).
-            block["qn"] = jnp.ones((L, cfg.head_dim_), dtype)
-            block["kn"] = jnp.ones((L, cfg.head_dim_), dtype)
+            block["qn"] = jnp.ones((*L, cfg.head_dim_), dtype)
+            block["kn"] = jnp.ones((*L, cfg.head_dim_), dtype)
+        if cfg.attn_output_gate:
+            block["wgate"] = big(next(ks), (*L, d, q), d)
         return block
 
-    layers = attn_block(Ld)
-    layers["wg"] = big(next(ks), (Ld, d, f), d)
-    layers["wu"] = big(next(ks), (Ld, d, f), d)
-    layers["wd"] = big(next(ks), (Ld, f, d), f)
-    params: Params = {
-        "embed": dense(next(ks), (v, d), d, dtype),
-        "layers": layers,
-        "final_norm": jnp.ones((d,), dtype),
-    }
-    if Lm:
+    def linear_block(L: tuple) -> Params:
+        la = cfg.linear_attn
+        kd, vd, r = la.key_size, la.value_size, la.gate_rank
+        return {
+            "attn_norm": jnp.ones((*L, d), dtype),
+            "lq": big(next(ks), (*L, d, kd), d),
+            "lk": big(next(ks), (*L, d, kd), d),
+            "lv": big(next(ks), (*L, d, vd), d),
+            "lo": big(next(ks), (*L, vd, d), vd),
+            "f_down": big(next(ks), (*L, d, r), d),
+            "f_up": big(next(ks), (*L, r, kd), r),
+            "g_down": big(next(ks), (*L, d, r), d),
+            "g_up": big(next(ks), (*L, r, vd), r),
+            "wb": big(next(ks), (*L, d, la.num_heads), d),
+            "conv": dense(
+                next(ks), (*L, la.conv_kernel, la.conv_size),
+                la.conv_kernel, dtype),
+            # decay rate exp(a_log) and the softplus offset: float32, as
+            # the state they drive (zero-init; checkpoints carry them)
+            "a_log": jnp.zeros((*L, la.num_heads), jnp.float32),
+            "dt_bias": jnp.zeros((*L, kd), jnp.float32),
+            "o_norm": jnp.ones((*L, la.value_head_dim), dtype),
+            "mlp_norm": jnp.ones((*L, d), dtype),
+        }
+
+    def dense_mlp(block: Params, L: tuple) -> Params:
+        block["wg"] = big(next(ks), (*L, d, f), d)
+        block["wu"] = big(next(ks), (*L, d, f), d)
+        block["wd"] = big(next(ks), (*L, f, d), f)
+        return block
+
+    def moe_mlp(block: Params, L: tuple) -> Params:
         m = cfg.moe
         fe = m.expert_intermediate_size or f
         E = m.num_experts
-        moe_layers = attn_block(Lm)
-        # Router stays f32: tiny, and top-k is precision-sensitive.
-        moe_layers["router"] = dense(next(ks), (Lm, d, E), d, jnp.float32)
+        # Router stays f32: tiny, and top-k is precision-sensitive. It
+        # keeps its published width where only a share of the experts
+        # is held here.
+        block["router"] = dense(
+            next(ks), (*L, d, m.router_width), d, jnp.float32)
         if m.scoring_func == "sigmoid":
             # noaux_tc selection bias (zero-init; loaded from real
             # checkpoints' e_score_correction_bias).
-            moe_layers["router_bias"] = jnp.zeros((Lm, E), jnp.float32)
-        moe_layers["eg"] = big(next(ks), (Lm, E, d, fe), d)
-        moe_layers["eu"] = big(next(ks), (Lm, E, d, fe), d)
-        moe_layers["ed"] = big(next(ks), (Lm, E, fe, d), fe)
+            block["router_bias"] = jnp.zeros(
+                (*L, m.router_width), jnp.float32)
+        block["eg"] = big(next(ks), (*L, E, d, fe), d)
+        block["eu"] = big(next(ks), (*L, E, d, fe), d)
+        block["ed"] = big(next(ks), (*L, E, fe, d), fe)
         if m.num_shared_experts:
             fs = fe * m.num_shared_experts
-            moe_layers["sg"] = big(next(ks), (Lm, d, fs), d)
-            moe_layers["su"] = big(next(ks), (Lm, d, fs), d)
-            moe_layers["sd"] = big(next(ks), (Lm, fs, d), fs)
-        params["moe_layers"] = moe_layers
+            block["sg"] = big(next(ks), (*L, d, fs), d)
+            block["su"] = big(next(ks), (*L, d, fs), d)
+            block["sd"] = big(next(ks), (*L, fs, d), fs)
+        return block
+
+    runs = period_runs(cfg)
+
+    def stack(n_layers: int, mlp) -> Params:
+        """One stack: flat ``[L, ...]`` leaves, or where the period has
+        runs, ``{run key: leaves [periods, layers of the run, ...]}``."""
+        if not runs:
+            return mlp(attn_block((n_layers,)), (n_layers,))
+        periods = n_layers // len(cfg.period_)
+        return {
+            key: mlp(
+                (attn_block if mixer == "attn" else linear_block)(
+                    (periods, n)), (periods, n))
+            for key, mixer, n in runs
+        }
+
+    # (a patterned model with no dense layer has no "layers" stack at all)
+    params: Params = {} if runs and not Ld else {"layers": stack(Ld, dense_mlp)}
+    params["embed"] = dense(next(ks), (v, d), d, dtype)
+    params["final_norm"] = jnp.ones((d,), dtype)
+    if Lm:
+        params["moe_layers"] = stack(Lm, moe_mlp)
     if not cfg.tie_embeddings:
         params["lm_head"] = big(next(ks), (d, v), d)
     return params
@@ -323,7 +446,21 @@ def _attn_block_specs(cfg: ModelConfig) -> Params:
         # does not — replicated.
         block["qn"] = P(None, None)
         block["kn"] = P(None, None)
+    if cfg.attn_output_gate:
+        block["wgate"] = P(None, None, "tp")
     return block
+
+
+def _linear_block_specs() -> Params:
+    """A linear-attention layer's leaves, replicated: the engine refuses
+    tp > 1 for a model that has them (its state is not sharded yet)."""
+    two, three = P(None, None), P(None, None, None)
+    return {
+        "attn_norm": two, "lq": three, "lk": three, "lv": three, "lo": three,
+        "f_down": three, "f_up": three, "g_down": three, "g_up": three,
+        "wb": three, "conv": three, "a_log": two, "dt_bias": two,
+        "o_norm": two, "mlp_norm": two,
+    }
 
 
 def param_specs(cfg: ModelConfig) -> Params:
@@ -338,25 +475,20 @@ def param_specs(cfg: ModelConfig) -> Params:
     for ed) — every expert runs tensor-parallel, composing ep x tp.
     Embedding sharded over vocab; lm_head over vocab columns.
     """
-    layers = _attn_block_specs(cfg)
-    layers.update(
-        {
-            "wg": P(None, None, "tp"),
-            "wu": P(None, None, "tp"),
-            "wd": P(None, "tp", None),
-        }
-    )
-    specs: Params = {
-        "embed": P("tp", None),
-        "layers": layers,
-        "final_norm": P(None),
-    }
-    Ld, Lm = _layer_split(cfg)
-    if Lm:
-        moe_layers = _attn_block_specs(cfg)
+    def dense_mlp(block: Params) -> Params:
+        block.update(
+            {
+                "wg": P(None, None, "tp"),
+                "wu": P(None, None, "tp"),
+                "wd": P(None, "tp", None),
+            }
+        )
+        return block
+
+    def moe_mlp(block: Params) -> Params:
         if cfg.moe.scoring_func == "sigmoid":
-            moe_layers["router_bias"] = P(None, None)
-        moe_layers.update(
+            block["router_bias"] = P(None, None)
+        block.update(
             {
                 "router": P(None, None, None),
                 "eg": P(None, "ep", None, "tp"),
@@ -365,10 +497,32 @@ def param_specs(cfg: ModelConfig) -> Params:
             }
         )
         if cfg.moe.num_shared_experts:
-            moe_layers["sg"] = P(None, None, "tp")
-            moe_layers["su"] = P(None, None, "tp")
-            moe_layers["sd"] = P(None, "tp", None)
-        specs["moe_layers"] = moe_layers
+            block["sg"] = P(None, None, "tp")
+            block["su"] = P(None, None, "tp")
+            block["sd"] = P(None, "tp", None)
+        return block
+
+    runs = period_runs(cfg)
+
+    def stack(mlp) -> Params:
+        if not runs:
+            return mlp(_attn_block_specs(cfg))
+        # a run's leaves lead with [periods, layers of the run]
+        return {
+            key: {
+                name: P(None, *spec) for name, spec in mlp(
+                    _attn_block_specs(cfg) if mixer == "attn"
+                    else _linear_block_specs()).items()
+            }
+            for key, mixer, _n in runs
+        }
+
+    Ld, Lm = _layer_split(cfg)
+    specs: Params = {} if runs and not Ld else {"layers": stack(dense_mlp)}
+    specs["embed"] = P("tp", None)
+    specs["final_norm"] = P(None)
+    if Lm:
+        specs["moe_layers"] = stack(moe_mlp)
     if not cfg.tie_embeddings:
         specs["lm_head"] = P(None, "tp")
     return specs
@@ -398,6 +552,7 @@ def make_cache(
     dtype: jnp.dtype = jnp.bfloat16,
     kv_quantize: str = "",
     form: str | None = None,
+    state_slots: int = 0,
 ) -> Params:
     """Paged KV cache pytree: pages stacked over layers, held ``[L, N, P,
     K, D]`` or merged ``[L, N, P, K*D]`` (``form``; None asks
@@ -416,11 +571,21 @@ def make_cache(
     either form): halves decode KV reads, the dominant non-weight HBM
     term at serving shapes (PERF.md). Not supported for the MLA latent
     layout (latents feed weight-absorbed matmuls, not raw attention; the
-    engine rejects the combination)."""
-    L = cfg.num_layers
+    engine rejects the combination).
+
+    A model with linear-attention layers holds pages for its attention
+    layers only (``L`` counts those) and, beside them under the same tree
+    so that they are donated through every step alike, ``state_slots``
+    slots of recurrent state (``make_state``)."""
+    # pages only for the layers that attend over them; the recurrent
+    # state of the others goes beside them
+    L = cfg.count_mixers("attn")
+    state = make_state(cfg, state_slots, dtype) if cfg.has_state else {}
     if _latent_cache(cfg):
-        if kv_quantize:
-            raise ValueError("kv_quantize is not supported with MLA latent cache")
+        if kv_quantize or state:
+            raise ValueError(
+                "kv_quantize and linear-attention layers are not supported "
+                "with the MLA latent cache")
         shape_k = (L, num_pages, page_size, 1, cfg.mla.latent_dim)
         shape_v = (L, num_pages, page_size, 1, 1)
         return {
@@ -435,13 +600,65 @@ def make_cache(
     if kv_quantize:
         if kv_quantize != "int8":
             raise ValueError(f"unsupported kv_quantize {kv_quantize!r}")
-        return {
+        return {**state, **{
             name: QuantizedPages(
                 jnp.zeros(shape, jnp.int8), jnp.ones(scales, jnp.float32)
             )
             for name in ("k", "v")
-        }
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        }}
+    return {**state, "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def make_state(cfg: ModelConfig, slots: int, dtype=jnp.bfloat16) -> Params:
+    """The recurrent-state part of the cache: for every linear-attention
+    layer and slot a float32 state ``[heads, key dim, value dim]`` and the
+    conv tail (the last ``conv_kernel - 1`` inputs of the convolved q/k/v
+    stream, in the compute type). A slot belongs to a running sequence or
+    holds a snapshot the prefix trie can restore; the slot a row uses rides
+    in its table row beside its pages (``split_table``). ``stats`` are the
+    ``MOE_STATS`` accumulators of a model with an expert share."""
+    la = cfg.linear_attn
+    n = cfg.count_mixers("linear")
+    return {
+        "state": jnp.zeros(
+            (n, slots, la.num_heads, la.key_head_dim, la.value_head_dim),
+            jnp.float32),
+        # flat [..., (kernel - 1) * width]: a minor pair of (3, width) would
+        # pad the 3 to a whole tile on the TPU, five times the bytes
+        "conv": jnp.zeros(
+            (n, slots, (la.conv_kernel - 1) * la.conv_size), dtype),
+        "stats": jnp.zeros((len(MOE_STATS),), jnp.uint32),
+    }
+
+
+# A row of the table that goes into every step program holds the row's
+# pages and, for a model with recurrent state, two more columns: the slot
+# of the row's state and the slot its state is copied to when the pass
+# leaves the row on a page boundary (-1: none).
+STATE_COLUMNS = 2
+
+
+def split_table(cfg: ModelConfig, table: jax.Array):
+    """(page table [B, MaxP], state slots [B] or None, snapshot slots)."""
+    if not cfg.has_state:
+        return table, None, None
+    return (table[:, :-STATE_COLUMNS], table[:, -STATE_COLUMNS],
+            table[:, -STATE_COLUMNS + 1])
+
+
+def copy_state_slots(cache: Params, src: jax.Array, dst: jax.Array) -> Params:
+    """Copy every linear layer's state and conv tail from slots ``src`` to
+    slots ``dst`` ([n] each; a negative ``dst`` copies nothing): a snapshot
+    taken or restored by a dispatch of its own."""
+    with jax.named_scope("state_io"):
+        n = cache["state"].shape[1]
+        to = jnp.where(dst >= 0, dst, n)
+        out = dict(cache)
+        for name in ("state", "conv"):
+            a = cache[name]
+            out[name] = a.at[:, to].set(a[:, jnp.clip(src, 0, n - 1)],
+                                        mode="drop")
+        return out
 
 
 def cache_specs(
@@ -465,6 +682,10 @@ def cache_specs(
         values = scales     # [L, N, P, K*D]: a shard is K/tp whole heads
     if kv_quantize:
         values = QuantizedPages(values, scales)
+    if cfg.has_state:
+        return {"k": values, "v": values,
+                "state": P(None, None, None, None, None),
+                "conv": P(None, None, None), "stats": P(None)}
     return {"k": values, "v": values}
 
 
@@ -749,6 +970,8 @@ def _qkv_rope(
     if cfg.mla is not None:
         return _qkv_mla(x, lp, cfg, cos, sin)
     q, k, v = _qkv(x, lp, cfg)
+    if cos is None:     # no positional embedding (cfg.use_rope false)
+        return q, k, v
     return (
         apply_rope(q, cos, sin) * _yarn_q_scale(cfg),
         apply_rope(k, cos, sin),
@@ -760,38 +983,139 @@ def _mlp(x: jax.Array, lp: Params) -> jax.Array:
     return _mm(jax.nn.silu(_mm(x, lp["wg"])) * _mm(x, lp["wu"]), lp["wd"])
 
 
-def _moe_mlp(
-    h: jax.Array, lp: Params, cfg: ModelConfig
-) -> tuple[jax.Array, jax.Array]:
-    """DeepSeek-style MoE MLP: softmax router, top-k combine weights
-    (renormalized/scaled per the checkpoint's norm_topk_prob /
-    routed_scaling_factor), always-on shared experts, plus routed experts.
-    Returns (output, load-balance aux loss).
+class _Indexed:
+    """Stacked leaves left whole, with the index of the layer in them: the
+    expert stacks of a patterned model, which ``_moe_share`` reads one
+    expert at a time (``stack[period, layer, expert]``) instead of taking a
+    layer's forty out first (a 600 MB copy a layer, by the chip's
+    compiler)."""
 
-    Two dispatch strategies, picked by token count (MoEConfig):
+    def __init__(self, tree, idx: tuple):
+        self.tree, self.idx = tree, idx
 
-    - **all-experts scan** (decode / tiny batches): every expert computes
-      every token, masked by the combine weight. E× the active FLOPs, but
-      with T·k >= E each expert's weights stream from HBM once either way,
-      so decode — which is bandwidth-bound, not FLOPs-bound — loses
-      nothing, and there is no capacity/drop risk.
-    - **grouped capacity dispatch** (prefill / training): tokens scatter
-      into per-expert buckets of C = ceil(T·k/E · capacity_factor) slots,
-      experts run as ONE batched einsum over [E, C, d], results gather
-      back weighted. Expert FLOPs scale with top-k·capacity_factor, not
-      num_experts (VERDICT round-1 weak #5). Assignments overflowing an
-      expert's bucket fall back to the shared-experts-only path for that
-      slot (standard Switch-style capacity semantics; capacity_factor
-      sizes the safety margin).
 
-    Aux = Switch-Transformer balance loss E·Σ_e f_e·P_e (f_e = fraction of
-    token-slots routed to expert e, P_e = mean router probability): minimized
-    at uniform routing, it counteracts the router's winner-take-all dynamic
-    during fine-tuning (weighted into the loss by TrainConfig.moe_aux_weight;
-    serving paths discard it)."""
+_EXPERT_STACKS = ("eg", "eu", "ed")
+
+
+def _layer_view(stack: Params, idx: tuple, whole_experts: bool) -> Params:
+    """One layer's leaves out of ``stack`` (leaves ``[periods, layers of
+    the run, ...]``) at ``idx``; the expert stacks stay whole where the
+    expert share indexes them itself."""
+    return {
+        name: _Indexed(leaf, idx)
+        if whole_experts and name in _EXPERT_STACKS
+        else jax.tree.map(lambda a: a[idx], leaf)
+        for name, leaf in stack.items()
+    }
+
+
+def _one_expert(stack, e):
+    """Expert ``e`` of a layer's stack ``[E, ...]``, or of a whole stack
+    with the layer's index (``_Indexed``)."""
+    if isinstance(stack, _Indexed):
+        return jax.tree.map(lambda a: a[(*stack.idx, e)], stack.tree)
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, e, 0, False), stack)
+
+
+class StateCtx(NamedTuple):
+    """What a step program knows of its rows' recurrent state."""
+
+    slots: jax.Array    # [B] the slot of each row's state (-1: none)
+    start: jax.Array    # [B] tokens the row had before this pass (0: the
+                        # state starts from zero whatever the slot holds)
+    valid: jax.Array    # [B] tokens it gets in it (0: the slot is left)
+    snap: jax.Array     # [B] slot the new state is also copied to when the
+                        # pass leaves the row on a page boundary (-1: none)
+    page_size: int
+
+
+def _state_read(flat: jax.Array, idx: jax.Array, fresh: jax.Array):
+    """Rows ``idx`` of ``flat`` [slots, ...]; zeros where ``fresh``."""
+    got = flat[jnp.clip(idx, 0, flat.shape[0] - 1)]
+    return jnp.where(fresh.reshape(-1, *([1] * (got.ndim - 1))), 0, got)
+
+
+def _linear_mixer(h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None):
+    """Delta-rule linear attention on the normed input h [B, S, d]: the
+    chunk form for S > 1, the one-token recurrence for S == 1, from and to
+    the rows' state slots (``ctx`` None: from zero, kept nowhere). Returns
+    (mixer output before the residual [B, S, d], cache)."""
+    la = cfg.linear_attn
+    B, S, _ = h.shape
+    H, dk, dv = la.num_heads, la.key_head_dim, la.value_head_dim
+    if ctx is None:
+        valid = jnp.full((B,), S, jnp.int32)
+        S0 = jnp.zeros((B, H, dk, dv), jnp.float32)
+        tail = jnp.zeros((B, la.conv_kernel - 1, la.conv_size), h.dtype)
+    else:
+        valid = ctx.valid
+        with jax.named_scope("state_io"):
+            n_slots = cache["state"].shape[1]
+            idx = si * n_slots + ctx.slots
+            fresh = (ctx.start == 0) | (ctx.slots < 0)
+            state_flat = cache["state"].reshape(-1, H, dk, dv)
+            conv_flat = cache["conv"].reshape(-1, cache["conv"].shape[-1])
+            S0 = _state_read(state_flat, idx, fresh)
+            tail = _state_read(conv_flat, idx, fresh).reshape(
+                B, la.conv_kernel - 1, la.conv_size)
+    with jax.named_scope("lin_proj"):
+        x = jnp.concatenate(
+            [_mm(h, lp["lq"]), _mm(h, lp["lk"]), _mm(h, lp["lv"])], axis=-1)
+        x, tail = conv_with_tail(x, tail, lp["conv"].astype(x.dtype), valid)
+        x = jax.nn.silu(x).astype(jnp.float32)
+        q = x[..., :la.key_size].reshape(B, S, H, dk)
+        k = x[..., la.key_size:2 * la.key_size].reshape(B, S, H, dk)
+        v = x[..., 2 * la.key_size:].reshape(B, S, H, dv)
+        q, k = (a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+                for a in (q, k))        # L2-normalised per head
+        q = q * (dk ** -0.5)
+        decay = jax.nn.softplus(
+            _mm(_mm(h, lp["f_down"]), lp["f_up"]).astype(jnp.float32)
+            + lp["dt_bias"])
+        g = -jnp.exp(lp["a_log"])[:, None] * decay.reshape(B, S, H, dk)
+        beta = jax.nn.sigmoid(_mm(h, lp["wb"]).astype(jnp.float32))
+        if la.neg_eigval:
+            beta = beta * 2.0
+    with jax.named_scope("lin_scan"):
+        if S == 1:
+            live = (valid > 0)[:, None]
+            o, S1 = delta_rule_step(
+                q[:, 0], k[:, 0], v[:, 0],
+                jnp.where(live[..., None], g[:, 0], 0.0),
+                jnp.where(live, beta[:, 0], 0.0), S0)
+            o = o[:, None]
+        else:
+            o, S1 = delta_rule_chunk(q, k, v, g, beta, S0, valid)
+    if ctx is not None:
+        with jax.named_scope("state_io"):
+            oob = state_flat.shape[0]
+            wrote = (valid > 0) & (ctx.slots >= 0)
+            on_page = (ctx.start + valid) % ctx.page_size == 0
+            for to in (
+                jnp.where(wrote, idx, oob),
+                jnp.where(wrote & on_page & (ctx.snap >= 0),
+                          si * n_slots + ctx.snap, oob),
+            ):
+                state_flat = state_flat.at[to].set(S1, mode="drop")
+                conv_flat = conv_flat.at[to].set(
+                    tail.reshape(B, -1).astype(conv_flat.dtype), mode="drop")
+            cache = dict(
+                cache, state=state_flat.reshape(cache["state"].shape),
+                conv=conv_flat.reshape(cache["conv"].shape))
+    with jax.named_scope("lin_proj"):
+        o = rms_norm(o, lp["o_norm"].astype(jnp.float32), cfg.rms_norm_eps)
+        gate = jax.nn.sigmoid(_mm(_mm(h, lp["g_down"]), lp["g_up"]))
+        out = o.reshape(B, S, H * dv).astype(h.dtype) * gate
+    return out, cache
+
+
+def _route(h, lp, cfg: ModelConfig):
+    """Router scores over the router's whole width, the top-k choice and
+    the combine weights, per the checkpoint's HF config. Returns (probs
+    [B,S,E], idx [B,S,k], vals [B,S,k])."""
     m = cfg.moe
-    E, k = m.num_experts, m.num_experts_per_token
-    T = h.shape[0] * h.shape[1]
+    E, k = m.router_width, m.num_experts_per_token
     router_logits = (h.astype(jnp.float32) @ lp["router"])          # [B,S,E]
     # Router scoring per the checkpoint's HF config: softmax (DeepSeek-
     # MoE/V2) or sigmoid with the noaux_tc selection bias (V3). The bias
@@ -831,6 +1155,123 @@ def _moe_mlp(
         vals = vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-20)
     if m.routed_scaling_factor != 1.0:
         vals = vals * m.routed_scaling_factor
+    return probs, idx, vals
+
+
+def _shared_experts(h, lp) -> jax.Array:
+    return _mm(jax.nn.silu(_mm(h, lp["sg"])) * _mm(h, lp["su"]), lp["sd"])
+
+
+def _moe_share(h, lp, cfg: ModelConfig, token_valid):
+    """The expert layer of a model whose ``MoEConfig`` names a router width
+    (``router_experts``): the router scores and ranks ALL its experts, this
+    chip computes the chosen ones among the ``num_experts`` it holds (from
+    ``first_expert``) and the shared expert; what absent experts would add
+    is left out (one chip of an expert-parallel deployment, without the
+    exchange). The whole layer is the same function with every expert held.
+
+    Dropless at any token count, with work in proportion to the
+    assignments that land here: assignments are sorted by expert into one
+    buffer in which each expert's rows are padded to whole blocks of
+    ``bm`` rows, and a loop over the blocks IN USE runs one expert's three
+    matmuls a block (a ``while`` of data-dependent length: no capacity, no
+    all-experts pass). ``token_valid`` [B, S] (or None) keeps the padding
+    positions of ragged rows out of the experts and the counts.
+
+    Returns (output [B, S, d], the MOE_STATS increments [5])."""
+    m = cfg.moe
+    E, k = m.num_experts, m.num_experts_per_token
+    B, S, d = h.shape
+    T = B * S
+    with jax.named_scope("moe_router"):
+        _, idx, vals = _route(h, lp, cfg)
+        local = idx.reshape(T * k) - m.first_expert
+        here = (local >= 0) & (local < E)
+        real = jnp.ones((T * k,), bool) if token_valid is None else (
+            jnp.repeat(token_valid.reshape(T), k))
+        group = jnp.where(here & real, local, E)        # E: not computed here
+        # rows of a block: the assignments an expert expects at this
+        # token count, up to a power of two, between 8 and 128
+        expected = max(1, T * k // m.router_width)
+        bm = min(128, max(8, 1 << (expected - 1).bit_length()))
+        rows = T * min(k, E) + E * bm                   # every case fits
+        rows = -(-rows // bm) * bm
+        order = jnp.argsort(group, stable=True)
+        sorted_group = group[order]
+        sizes = jnp.zeros((E + 1,), jnp.int32).at[group].add(1)
+        padded = -(-sizes[:E] // bm) * bm
+        ends = jnp.cumsum(padded)
+        first_row = ends - padded                       # in the buffer
+        first_sorted = jnp.cumsum(sizes) - sizes        # in sorted order
+        rank = jnp.arange(T * k) - first_sorted[sorted_group]
+        dest_sorted = jnp.where(
+            sorted_group < E,
+            first_row[jnp.minimum(sorted_group, E - 1)] + rank, rows)
+        x = h.reshape(T, d)
+        xs = jnp.zeros((rows, d), h.dtype).at[dest_sorted].set(
+            x[order // k], mode="drop")
+        blocks_used = ends[-1] // bm
+        block_ends = ends // bm
+        stats = jnp.stack([
+            jnp.int32(1),
+            jnp.sum(here & real, dtype=jnp.int32),
+            jnp.sum(real & ~here, dtype=jnp.int32),
+            jnp.sum(sizes[:E] > 0, dtype=jnp.int32),
+            jnp.max(sizes[:E]),
+        ]).astype(jnp.uint32)
+    with jax.named_scope("moe_experts"):
+        def one_block(b, ys):
+            e = jnp.searchsorted(block_ends, b, side="right")
+            w = [_one_expert(lp[name], e) for name in _EXPERT_STACKS]
+            xb = jax.lax.dynamic_slice_in_dim(xs, b * bm, bm)
+            y = _mm(jax.nn.silu(_mm(xb, w[0])) * _mm(xb, w[1]), w[2])
+            return jax.lax.dynamic_update_slice_in_dim(ys, y, b * bm, 0)
+
+        ys = jax.lax.fori_loop(0, blocks_used, one_block, jnp.zeros_like(xs))
+        dest = jnp.zeros((T * k,), jnp.int32).at[order].set(dest_sorted)
+        per = ys.at[dest].get(mode="fill", fill_value=0)
+        out = jnp.sum(
+            (per * vals.reshape(T * k, 1).astype(h.dtype)).reshape(T, k, d),
+            axis=1).reshape(B, S, d)
+    if m.num_shared_experts:
+        with jax.named_scope("moe_shared"):
+            out = out + _shared_experts(h, lp)
+    return out, stats
+
+
+def _moe_mlp(
+    h: jax.Array, lp: Params, cfg: ModelConfig
+) -> tuple[jax.Array, jax.Array]:
+    """DeepSeek-style MoE MLP: softmax router, top-k combine weights
+    (renormalized/scaled per the checkpoint's norm_topk_prob /
+    routed_scaling_factor), always-on shared experts, plus routed experts.
+    Returns (output, load-balance aux loss).
+
+    Two dispatch strategies, picked by token count (MoEConfig):
+
+    - **all-experts scan** (decode / tiny batches): every expert computes
+      every token, masked by the combine weight. E× the active FLOPs, but
+      with T·k >= E each expert's weights stream from HBM once either way,
+      so decode — which is bandwidth-bound, not FLOPs-bound — loses
+      nothing, and there is no capacity/drop risk.
+    - **grouped capacity dispatch** (prefill / training): tokens scatter
+      into per-expert buckets of C = ceil(T·k/E · capacity_factor) slots,
+      experts run as ONE batched einsum over [E, C, d], results gather
+      back weighted. Expert FLOPs scale with top-k·capacity_factor, not
+      num_experts (VERDICT round-1 weak #5). Assignments overflowing an
+      expert's bucket fall back to the shared-experts-only path for that
+      slot (standard Switch-style capacity semantics; capacity_factor
+      sizes the safety margin).
+
+    Aux = Switch-Transformer balance loss E·Σ_e f_e·P_e (f_e = fraction of
+    token-slots routed to expert e, P_e = mean router probability): minimized
+    at uniform routing, it counteracts the router's winner-take-all dynamic
+    during fine-tuning (weighted into the loss by TrainConfig.moe_aux_weight;
+    serving paths discard it)."""
+    m = cfg.moe
+    E, k = m.num_experts, m.num_experts_per_token
+    T = h.shape[0] * h.shape[1]
+    probs, idx, vals = _route(h, lp, cfg)
     sel = jnp.sum(jax.nn.one_hot(idx, E, dtype=probs.dtype), axis=-2)  # [B,S,E]
     f_e = jnp.mean(sel / k, axis=(0, 1))                            # [E]
     p_e = jnp.mean(probs, axis=(0, 1))                              # [E]
@@ -860,9 +1301,7 @@ def _moe_mlp(
             (lp["eg"], lp["eu"], lp["ed"], combine),
         )
     if m.num_shared_experts:
-        out = out + _mm(
-            jax.nn.silu(_mm(h, lp["sg"])) * _mm(h, lp["su"]), lp["sd"]
-        )
+        out = out + _shared_experts(h, lp)
     return out, aux
 
 
@@ -932,57 +1371,114 @@ def _run_stack(
     cache: Params | None,
     remat: bool = False,
     stacks: tuple[str, ...] | None = None,
+    state_ctx: StateCtx | None = None,
+    token_valid: jax.Array | None = None,
 ) -> tuple[jax.Array, Params | None, jax.Array]:
-    """Run the dense stack then (if configured) the MoE stack; returns
-    (final hidden states, updated cache or None, summed MoE aux loss).
+    """Scan the model's stacks of WHOLE PERIODS: the dense-MLP stack then
+    (if configured) the MoE stack, each a ``lax.scan`` over its periods; a
+    period's layers may differ in mixer (softmax attention over pages,
+    linear attention over a recurrent state: ``cfg.period_``) and a stack's
+    in MLP. A model of like layers is the period of one. Returns (final
+    hidden states, updated cache or None, summed MoE aux loss).
 
-    The KV cache travels through the layer scan as part of the CARRY (one
-    whole-cache array, layer-indexed by the scanned step counter), not as
-    per-layer slices with stacked outputs: stacked scan outputs semantically
-    copy the full cache every call (~GBs per decode step at serving
-    shapes), while scatters into a loop carry update it in place."""
+    The cache travels through the layer scan as part of the CARRY (one
+    whole-cache pytree, layer-indexed by the scanned step counters: ``ai``
+    counts attention layers for the pages, ``si`` linear layers for the
+    state), not as per-layer slices with stacked outputs: stacked scan
+    outputs semantically copy the full cache every call (~GBs per decode
+    step at serving shapes), while scatters into a loop carry update it in
+    place. ``state_ctx`` tells a linear layer its rows' slots;
+    ``token_valid`` [B, S] keeps the padding of ragged rows out of an
+    expert share."""
     Ld, Lm = _layer_split(cfg)
+    runs = period_runs(cfg)
+    share = cfg.moe is not None and cfg.moe.router_experts > 0
 
-    def make_body(moe: bool):
-        def body(carry, lp):
-            x, aux, kc, vc, li = carry
-            with jax.named_scope("attn_qkv"):
-                h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-            attn, kc, vc = attn_fn(h, lp, kc, vc, li)
+    def layer(carry, lp, mixer: str, moe: bool):
+        x, aux, cache, (ai, *rest) = carry
+        si = rest[0] if rest else None
+        with jax.named_scope("attn_qkv"):
+            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        if mixer == "attn":
+            attn, kc, vc = attn_fn(h, lp, cache["k"], cache["v"], ai)
+            cache = dict(cache, k=kc, v=vc)
             with jax.named_scope("attn_out"):
+                if cfg.attn_output_gate:
+                    with jax.named_scope("attn_gate"):
+                        attn = attn * jax.nn.sigmoid(_mm(h, lp["wgate"]))
                 x = x + _mm(attn, lp["wo"])
-            with jax.named_scope("ffn"):
-                h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-                if moe:
-                    y, layer_aux = _moe_mlp(h, lp, cfg)
-                    x, aux = x + y, aux + layer_aux
+        else:
+            mixed, cache = _linear_mixer(h, lp, cfg, cache, si, state_ctx)
+            with jax.named_scope("attn_out"):
+                x = x + _mm(mixed, lp["lo"])
+        with jax.named_scope("ffn"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+            if moe and share:
+                y, stats = _moe_share(h, lp, cfg, token_valid)
+                x = x + y
+                if "stats" in cache:
+                    cache = dict(cache, stats=cache["stats"] + stats)
+            elif moe:
+                y, layer_aux = _moe_mlp(h, lp, cfg)
+                x, aux = x + y, aux + layer_aux
+            else:
+                x = x + _mlp(h, lp)
+        if mixer == "attn":
+            return (x, aux, cache, (ai + 1, *rest))
+        return (x, aux, cache, (ai, si + 1))
+
+    def make_body(moe: bool, stack: Params):
+        def body(carry, lp):
+            return layer(carry, lp, "attn", moe), None
+
+        def period(carry, p):
+            # A period's runs, each layer taken out of the WHOLE stack by
+            # (period, layer of the run): scanning the period's slice and
+            # then the run's inside it made the compiler copy every run's
+            # leaves once a period (the chip's compiler, 1.8 GB of them).
+            for key, mixer, n in runs:
+                def one(c, j, key=key, mixer=mixer):
+                    lp = _layer_view(stack[key], (p, j), moe and share)
+                    return layer(c, lp, mixer, moe), None
+                if n == 1:
+                    carry, _ = one(carry, 0)
                 else:
-                    x = x + _mlp(h, lp)
-            return (x, aux, kc, vc, li + 1), None
+                    carry, _ = jax.lax.scan(one, carry, jnp.arange(n))
+            return carry, None
+
+        body = period if runs else body
         return jax.checkpoint(body) if remat else body
 
-    if cache is None:
-        kc = vc = jnp.zeros((0,), x.dtype)  # pytree placeholder
-    else:
-        kc, vc = cache["k"], cache["v"]
+    def xs(stack: Params):
+        if not runs:
+            return stack
+        return jnp.arange(jax.tree.leaves(stack)[0].shape[0])
+
+    placeholder = cache is None
+    if placeholder:
+        zero = jnp.zeros((0,), x.dtype)  # pytree placeholder
+        cache = {"k": zero, "v": zero}
     # The aux init inherits x's varying-manual-axes type via an O(1)
     # numeric no-op (one element, not a reduction — XLA cannot fold float
     # 0*x): under shard_map manual (parallel/pipeline.py) the scan body's
     # aux output is varying over the manual axes, and scan requires the
     # initial carry to match; outside manual contexts this is plain zero.
     aux0 = x.reshape(-1)[0].astype(jnp.float32) * 0.0
-    carry = (x, aux0, kc, vc, jnp.int32(0))
+    # layer counters: attention layers (pages), and linear layers (state)
+    # where the model has them
+    counters = (jnp.int32(0),) * (2 if cfg.has_state else 1)
+    carry = (x, aux0, cache, counters)
     # stacks=None runs the full config-implied stack (missing keys raise
     # loudly); pipeline stages (parallel/pipeline.py) pass the subset they
     # own explicitly rather than relying on silent key-presence dispatch.
     if Ld and (stacks is None or "layers" in stacks):
-        carry, _ = jax.lax.scan(make_body(False), carry, params["layers"])
+        stack = params["layers"]
+        carry, _ = jax.lax.scan(make_body(False, stack), carry, xs(stack))
     if Lm and (stacks is None or "moe_layers" in stacks):
-        carry, _ = jax.lax.scan(make_body(True), carry, params["moe_layers"])
-    x, aux, kc, vc, _ = carry
-    if cache is None:
-        return x, None, aux
-    return x, {"k": kc, "v": vc}, aux
+        stack = params["moe_layers"]
+        carry, _ = jax.lax.scan(make_body(True, stack), carry, xs(stack))
+    x, aux, cache, _ = carry
+    return x, (None if placeholder else cache), aux
 
 
 # -- forward passes ---------------------------------------------------------
@@ -1003,11 +1499,12 @@ def prefill(
     stay on the pjit-partitioned scatter either way."""
     B, S = tokens.shape
     positions = jnp.arange(S)[None, :].repeat(B, axis=0)
-    cos, sin = rope_table(positions, cfg.rope_dim_, cfg.rope_theta,
-                          scaling=cfg.rope_scaling)
+    cos, sin = _rope_tables(cfg, positions)
     x = _embed(params, tokens, dtype)
     start = jnp.zeros((B,), jnp.int32)
     attn_op = prefill_attn or causal_prefill_attention
+    page_table, ctx, token_valid = _row_state(
+        cfg, cache, page_table, start, lengths, S)
 
     def attn_fn(h, lp, kc, vc, li):
         if _latent_cache(cfg):
@@ -1027,7 +1524,8 @@ def prefill(
         attn = attn_op(q, k, v, lengths=lengths)
         return attn.reshape(B, S, -1), kc, vc
 
-    x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache)
+    x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache,
+                             state_ctx=ctx, token_valid=token_valid)
     x = _final_norm(params, cfg, x)
     x_last = _last_valid(x, lengths)
     logits = _lm_head(params, cfg, x_last)
@@ -1049,9 +1547,10 @@ def prefill_with_prefix(
     (last-tail-position logits [B, V], updated cache)."""
     B, S = tokens.shape
     positions = start[:, None] + jnp.arange(S)[None, :]
-    cos, sin = rope_table(positions, cfg.rope_dim_, cfg.rope_theta,
-                          scaling=cfg.rope_scaling)
+    cos, sin = _rope_tables(cfg, positions)
     x = _embed(params, tokens, dtype)
+    page_table, sctx, token_valid = _row_state(
+        cfg, cache, page_table, start, lengths, S)
 
     def attn_fn(h, lp, kc, vc, li):
         if _latent_cache(cfg):
@@ -1072,7 +1571,8 @@ def prefill_with_prefix(
         )
         return attn.reshape(B, S, -1), kc, vc
 
-    x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache)
+    x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache,
+                             state_ctx=sctx, token_valid=token_valid)
     x = _final_norm(params, cfg, x)
     x_last = _last_valid(x, lengths)
     logits = _lm_head(params, cfg, x_last)
@@ -1105,9 +1605,10 @@ def mixed_step(
     updated cache)."""
     B, S = tokens.shape
     positions = start[:, None] + jnp.arange(S)[None, :]
-    cos, sin = rope_table(positions, cfg.rope_dim_, cfg.rope_theta,
-                          scaling=cfg.rope_scaling)
+    cos, sin = _rope_tables(cfg, positions)
     x = _embed(params, tokens, dtype)
+    page_table, sctx, token_valid = _row_state(
+        cfg, cache, page_table, start, q_lens, S)
 
     def attn_fn(h, lp, kc, vc, li):
         if _latent_cache(cfg):
@@ -1131,7 +1632,8 @@ def mixed_step(
         return attn.reshape(B, S, -1), kc, vc
 
     with weight_stream_scope(weight_stream):
-        x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache)
+        x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache,
+                                 state_ctx=sctx, token_valid=token_valid)
         x = _final_norm(params, cfg, x)
         x_last = _last_valid(x, q_lens)
         logits = _lm_head(params, cfg, x_last)
@@ -1158,10 +1660,14 @@ def verify_step(
     tokens, because the write offset only advances by the accepted count.
     Costs ~one decode step of HBM traffic (weights stream once per
     forward, the whole point of speculation)."""
+    if cfg.has_state:
+        raise ValueError(
+            "verify_step: a rejected draft cannot be taken back out of a "
+            "recurrent state; speculative decoding is not supported for a "
+            "model with linear-attention layers")
     B, S = tokens.shape
     positions = start[:, None] + jnp.arange(S)[None, :]
-    cos, sin = rope_table(positions, cfg.rope_dim_, cfg.rope_theta,
-                          scaling=cfg.rope_scaling)
+    cos, sin = _rope_tables(cfg, positions)
     x = _embed(params, tokens, dtype)
 
     def attn_fn(h, lp, kc, vc, li):
@@ -1206,10 +1712,11 @@ def decode_step(
     updated cache)."""
     B = tokens.shape[0]
     positions = lengths[:, None]                       # [B, 1]
-    cos, sin = rope_table(positions, cfg.rope_dim_, cfg.rope_theta,
-                          scaling=cfg.rope_scaling)
+    cos, sin = _rope_tables(cfg, positions)
     x = _embed(params, tokens[:, None], dtype)          # [B, 1, D]
     valid = active.astype(jnp.int32)                   # [B] 1 new token if active
+    page_table, sctx, token_valid = _row_state(
+        cfg, cache, page_table, lengths, valid, 1)
 
     def attn_fn(h, lp, kc, vc, li):
         if _latent_cache(cfg):
@@ -1233,10 +1740,35 @@ def decode_step(
         return attn.reshape(B, 1, -1), kc, vc
 
     with weight_stream_scope(weight_stream):
-        x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache)
+        x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache,
+                                 state_ctx=sctx, token_valid=token_valid)
         x = _final_norm(params, cfg, x)
         logits = _lm_head(params, cfg, x[:, 0])
     return logits, cache
+
+
+def _rope_tables(cfg: ModelConfig, positions: jax.Array):
+    """(cos, sin) of the rotary embedding; (None, None) for a model
+    without one (``use_rope`` false: ``_qkv_rope`` then rotates nothing)."""
+    if not cfg.use_rope:
+        return None, None
+    return rope_table(positions, cfg.rope_dim_, cfg.rope_theta,
+                      scaling=cfg.rope_scaling)
+
+
+def _row_state(cfg: ModelConfig, cache, table, start, valid, S: int):
+    """What a step program derives from its rows' table for a model with
+    recurrent state or an expert share: (page table, StateCtx or None, the
+    mask [B, S] of real positions or None). Any other model gets its
+    table back and nothing else."""
+    share = cfg.moe is not None and cfg.moe.router_experts > 0
+    token_valid = (
+        jnp.arange(S)[None, :] < valid[:, None] if share else None)
+    if not cfg.has_state:
+        return table, None, token_valid
+    pages, slots, snap = split_table(cfg, table)
+    page_size = jax.tree.leaves(cache["k"])[0].shape[2]
+    return pages, StateCtx(slots, start, valid, snap, page_size), token_valid
 
 
 @scoped("embed")
@@ -1280,8 +1812,7 @@ def forward_full(
     (zero for dense models)."""
     B, S = tokens.shape
     positions = jnp.arange(S)[None, :].repeat(B, axis=0)
-    cos, sin = rope_table(positions, cfg.rope_dim_, cfg.rope_theta,
-                          scaling=cfg.rope_scaling)
+    cos, sin = _rope_tables(cfg, positions)
     x = _embed(params, tokens, dtype)
     attn_op = prefill_attn or causal_prefill_attention
 

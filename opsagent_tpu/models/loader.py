@@ -256,6 +256,13 @@ def load_checkpoint(
     restack of every tensor. ``Engine.from_snapshot`` bypasses it
     entirely — snapshot restore (serving/snapshot/restore.py) memory-maps
     leaves already in this stacked device layout."""
+    if cfg.mixer_period or cfg.attn_output_gate or not cfg.use_rope:
+        raise NotImplementedError(
+            "load_checkpoint: no loader for a solar_open2 checkpoint (a "
+            "layer pattern, gated NoPE attention, linear-attention layers): "
+            "its checkpoint's tensor names are not public here; "
+            "config_from_hf reads its config.json, and weights come seeded"
+        )
     t0 = time.perf_counter()
     tensors = _open_shards(path)
     L = cfg.num_layers

@@ -185,7 +185,11 @@ _QUANT_KEYS = frozenset(
      "eg", "eu", "ed", "sg", "su", "sd", "lm_head",
      # MLA projections (models/llama._mla_attn_block); the low-rank
      # norms stay exact like other norm vectors.
-     "wdq", "wuq", "wdkv", "wkr", "wukv"}
+     "wdq", "wuq", "wdkv", "wkr", "wukv",
+     # gated attention and linear-attention projections (models/llama
+     # linear_block): the conv, decay vectors and norms stay exact.
+     "wgate", "lq", "lk", "lv", "lo", "f_down", "f_up", "g_down", "g_up",
+     "wb"}
 )
 
 

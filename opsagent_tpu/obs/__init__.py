@@ -164,6 +164,45 @@ STEP_LATE_PULLS = _reg.counter(
     "host), or the step's start was not known",
     labelnames=("program",),
 )
+# -- recurrent state and expert shares (models with linear-attention layers,
+# or experts at one chip's share: serving/kvcache.py, models/llama.py) -------
+STATE_SLOTS_IN_USE = _reg.gauge(
+    "opsagent_state_slots_in_use",
+    "Recurrent-state slots held: live (one a running sequence) and "
+    "snapshot (held by a trie node or being written by a sequence)",
+    labelnames=("kind",),
+)
+STATE_SNAPSHOTS = _reg.counter(
+    "opsagent_state_snapshots_total",
+    "State snapshots by event: taken (put on the trie node that ends a "
+    "donated page chain), restored (copied into an admitted sequence's "
+    "slot), evicted (dropped, alone or with their node)",
+    labelnames=("event",),
+)
+STATE_RESTORED_TOKENS = _reg.counter(
+    "opsagent_state_restored_tokens_total",
+    "Prompt tokens served from a restored state snapshot (and its pages) "
+    "instead of prefill",
+)
+STATE_UNMATCHED_TOKENS = _reg.counter(
+    "opsagent_state_unmatched_tokens_total",
+    "Prompt tokens whose pages matched in the trie and were given up for "
+    "want of a state snapshot at or after them",
+)
+STATE_PROMPT_TOKENS = _reg.counter(
+    "opsagent_state_prompt_tokens_total",
+    "Prompt tokens admitted by an engine whose model keeps recurrent state",
+)
+MOE_SHARE = _reg.counter(
+    "opsagent_moe_share_total",
+    "An expert share's work, summed over MoE layers and passes, read from "
+    "the device's accumulators at scrape: layer_passes (one a layer a "
+    "pass), landed (assignments to experts held here), absent "
+    "(assignments to experts on other chips, left out), experts_touched "
+    "(distinct held experts with work), max_load (the fullest expert's "
+    "assignments)",
+    labelnames=("what",),
+)
 STREAM_EMIT_LAG_SECONDS = _reg.histogram(
     "opsagent_stream_emit_lag_seconds",
     "Token hand-off lag of a streamed response: from the scheduler "

@@ -954,6 +954,7 @@ def build_engine_app(stack: ServingStack, membership=None):
         try:
             with eng.lock:
                 eng._observe_occupancy()
+            eng.sync_device_counters()
         except AttributeError:
             pass  # test fakes without the full engine surface
         return web.Response(

@@ -158,7 +158,7 @@ class AsyncMixedRuntime:
         eng = self.eng
         cfg = eng.cfg
         B = cfg.max_batch_size
-        MaxP = cfg.max_pages_per_seq
+        MaxP = eng.alloc.table_width
         decode = [
             eng.sequences[s] for s in decode_ids
             if s in eng.sequences and not eng.sequences[s].done
@@ -301,7 +301,8 @@ class AsyncMixedRuntime:
             if seq is None or sid not in eng._prefilling:
                 continue
             done = eng._prefilling[sid]
-            c = min(want, cfg.mixed_buckets[-1], seq.prompt_len - done)
+            c = eng.alloc.clamp_chunk(sid, done, seq.prompt_len, min(
+                want, cfg.mixed_buckets[-1], seq.prompt_len - done))
             if c <= 0:
                 continue
             chunk_info.append((sid, seq, done, c))
@@ -383,7 +384,7 @@ class AsyncMixedRuntime:
             # The bookings above made alloc.length = written + inflight
             # + q; the row writes its q inputs from the slots before it.
             starts[lane] = eng.alloc.length(s.seq_id) - q
-            tables[lane] = eng.alloc.page_table_row(s.seq_id)
+            tables[lane] = eng._pass_row(s.seq_id, int(starts[lane]), q)
             temps[lane] = s.params.temperature
             top_k[lane] = s.params.top_k
             top_p[lane] = s.params.top_p
@@ -412,7 +413,7 @@ class AsyncMixedRuntime:
             tokens[lane, :c] = seq.prompt_ids[done:done + c]
             starts[lane] = done
             qlens[lane] = c
-            tables[lane] = eng.alloc.page_table_row(sid)
+            tables[lane] = eng._pass_row(sid, done, c)
             temps[lane] = seq.params.temperature
             top_k[lane] = seq.params.top_k
             top_p[lane] = seq.params.top_p

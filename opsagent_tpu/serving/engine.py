@@ -285,6 +285,13 @@ class EngineConfig:
     # device FSM tables (hosted masks, budget-exceeded schemas), with
     # logprobs, or with logit bias are ineligible and decode normally.
     grammar_ffwd: bool = True
+    # Recurrent state (a model with linear-attention layers; nothing is
+    # allocated otherwise). Every row has a live slot (``max_batch_size``
+    # of them). ``state_snapshots``: the pool of snapshot slots the prefix
+    # trie restores from (0 = twice the live slots): each running sequence
+    # writes one, and a donated chain keeps it on the node that ends it,
+    # LRU-evicted when a new sequence needs a slot.
+    state_snapshots: int = 0
     # Compile every serving program (all prefill buckets + decode) at
     # construction time so the first real request never pays XLA compile
     # (the TTFT budget is 500 ms; a cold bucket compile is tens of seconds).
@@ -455,6 +462,26 @@ class Engine:
             else "",
         )
 
+        if self.model_cfg.has_state:
+            # What carries a sequence between steps, tiers or replicas as a
+            # page chain alone would serve this model without its
+            # recurrent state: refuse it here, by name, instead.
+            why = "a model with linear-attention layers (recurrent state)"
+            refused = {
+                f"tp={tp}": tp > 1,
+                f"the {self.attn_impl} attention backend":
+                    self.attn_impl != "xla",
+                "weight_stream=pallas-dma": ws == "pallas-dma",
+                f"speculative_k={cfg.speculative_k} (verify_step cannot "
+                "take a rejected draft back out of the state)":
+                    cfg.speculative_k > 0,
+                "offload=True (the host tier, fleet page transfer and "
+                "peer fault-in carry page chains only)": cfg.offload,
+                "sp > 1 ring prefill": cfg.sp > 1,
+            }
+            for what, hit in refused.items():
+                if hit:
+                    raise BackendRefused(f"{what} is not supported for {why}")
         if cfg.kv_quantize and cfg.kv_quantize != "int8":
             raise ValueError(
                 f"kv_quantize={cfg.kv_quantize!r}: only 'int8' is supported"
@@ -529,6 +556,9 @@ class Engine:
                         "per-output-channel" if cfg.quantize == "int8"
                         else "group-wise",
                     )
+            # A source that made a patterned model's layers in order
+            # holds them run by run: the layer scan wants them by period.
+            params = llama.stack_layer_runs(self.model_cfg, params)
             self.params = shard_params(params, specs, self.mesh)
         # Block on the transfers so weights_load_s measures the actual
         # host->HBM move, not just the device_put enqueue.
@@ -553,11 +583,19 @@ class Engine:
         # [P, K, D] whatever is held (``cache_wire``: the split cache's
         # shapes, which the host tier and the snapshot manifest use).
         self.page_form = llama.cache_form(self.model_cfg, tp, self.attn_impl)
+        # Recurrent-state slots of a model with linear-attention layers:
+        # live ones and the snapshot pool, one device array (a restore is
+        # one copy between slots).
+        live = snaps = 0
+        if self.model_cfg.has_state:
+            live = cfg.max_batch_size
+            snaps = cfg.state_snapshots or 2 * live
 
         def make(form: str):
             return llama.make_cache(
                 self.model_cfg, cfg.num_pages, cfg.page_size,
                 dtype=cfg.dtype, kv_quantize=cfg.kv_quantize, form=form,
+                state_slots=live + snaps,
             )
 
         self.cache = jax.jit(
@@ -574,7 +612,13 @@ class Engine:
         self.alloc = PageAllocator(
             cfg.num_pages, cfg.page_size, cfg.max_pages_per_seq,
             prefix_cache=cfg.prefix_cache,
+            state_slots=live, state_snapshots=snaps,
         )
+        self._state_copy_jit = jax.jit(
+            llama.copy_state_slots, donate_argnames=("cache",)
+        )
+        self._moe_stats_seen = np.zeros((len(llama.MOE_STATS),), np.uint32)
+        self._snapshots_seen = [0, 0]    # taken, evicted: obs delta bases
         # Host-RAM offload tier: spills ride every trie eviction, restores
         # ride admission (begin_request). Parking APIs: park_chain (tool
         # windows), park_sequence (admission-pressure LRU).
@@ -600,7 +644,7 @@ class Engine:
         # the router (in-process) or run_engine_server (--join-fleet);
         # None = the peer-fetch tier is off and admission behaves as
         # before (trie -> host pool -> re-prefill).
-        self.pagestore = None
+        self._pagestore = None
         self._digests_truncated = False
         self.sequences: dict[int, Sequence] = {}
         self._evictions_seen = 0  # delta-sync base for the obs counter
@@ -886,12 +930,35 @@ class Engine:
             "decode_greedy", "mixed", "mixed_async", "fsm", "ffwd",
             "offload",
         }),
+        # "sessions" for traffic that never sends a response_format: no
+        # grammar table is ever built, so neither the FSM variants of the
+        # carry and decode-block programs nor the fast-forward appends can
+        # be dispatched. At a model whose step program compiles in most of
+        # a minute these are three whole compiles, one after the other.
+        "sessions-free": frozenset({
+            "prefill", "prefill_prefix", "prefill_batched", "sample",
+            "decode_greedy", "mixed", "mixed_async", "offload",
+        }),
         "full": frozenset({
             "prefill", "prefill_prefix", "prefill_batched", "sample",
             "decode_single", "logprobs", "decode_greedy", "decode_sampled",
             "fsm", "spec", "mixed", "mixed_async", "ffwd", "offload",
         }),
     }
+
+    @property
+    def pagestore(self):
+        return self._pagestore
+
+    @pagestore.setter
+    def pagestore(self, client) -> None:
+        if client is not None and self.model_cfg.has_state:
+            raise BackendRefused(
+                "the fleet page store (peer fault-in of page chains) is not "
+                "supported for a model with linear-attention layers: a "
+                "page chain does not carry its recurrent state"
+            )
+        self._pagestore = client
 
     @contextlib.contextmanager
     def mesh_ctx(self):
@@ -935,6 +1002,10 @@ class Engine:
         }
         if self.weight_stream_leaves:
             info["weight_stream_leaves"] = dict(self.weight_stream_leaves)
+        if self.model_cfg.has_state:
+            info["state_dtype"] = self.cache["state"].dtype.name
+            info["state_slots"] = self.alloc.state_slots
+            info["state_snapshots"] = self.alloc.state_snapshots
         return info
 
     def device_memory(self) -> list[dict[str, Any]]:
@@ -983,7 +1054,7 @@ class Engine:
         only exist after the first dispatch — so they stay sequential.
         """
         B = self.cfg.max_batch_size
-        MaxP = self.cfg.max_pages_per_seq
+        MaxP = self.alloc.table_width
         jobs: list[tuple[str, Any]] = []
 
         def add(group: str, fn, *args, **kw):
@@ -1078,7 +1149,8 @@ class Engine:
 
         ``level`` picks the program subset (WARMUP_LEVELS): "full" for
         serving, "sessions" for the concurrent-sessions path (batched
-        admission + prefix prefill + greedy decode), "bench" for the
+        admission + prefix prefill + greedy decode), "sessions-free" for
+        the same path under free-text traffic only, "bench" for the
         minimal throughput-bench path (plain prefill + greedy decode).
 
         Combined with ``enable_compilation_cache`` this is one-time cost
@@ -1087,7 +1159,7 @@ class Engine:
         progs = self.WARMUP_LEVELS[level]
         t0 = time.perf_counter()
         B = self.cfg.max_batch_size
-        MaxP = self.cfg.max_pages_per_seq
+        MaxP = self.alloc.table_width
         # Compile-watchdog phase bracket: compiles inside count as
         # "warmup"; once any warmup completes, a compile during serving is
         # an anomaly (ring dump + opsagent_post_warmup_compiles).
@@ -1369,6 +1441,14 @@ class Engine:
             # its own content — state-preserving like every warmup call.
             if "offload" in progs and self.offload is not None:
                 self.cache = self.offload.copier.warm(self.cache)
+            # The state-slot copy (a snapshot restored at admission): one
+            # slot to nowhere, which copies nothing.
+            if self.alloc.state_slots:
+                one = jnp.zeros((1,), jnp.int32)
+                self.cache = self._state_copy_jit(self.cache, one, one - 1)
+            # ... and the copy a /metrics scrape reads the expert share's
+            # accumulators from.
+            self.sync_device_counters()
             if "spec" in progs and self.cfg.speculative_k > 0:
                 H = self.cfg.max_pages_per_seq * self.cfg.page_size
                 ov_hist = jnp.zeros((B, H), jnp.int32)
@@ -1414,6 +1494,12 @@ class Engine:
         lowered are not in the artifact. Returns the manifest dict."""
         from .snapshot.writer import write_snapshot
 
+        if self.model_cfg.has_state:
+            raise BackendRefused(
+                "Engine.snapshot (snapshot/writer.py) is not supported for "
+                "a model with linear-attention layers: its paged-KV plan "
+                "has no place for the recurrent state"
+            )
         with self.lock:
             return write_snapshot(self, path)
 
@@ -1523,9 +1609,26 @@ class Engine:
             # Prefix cache: reuse full pages of the prompt MINUS its last
             # token (at least one tail token must be prefilled to produce
             # the next-token logits).
-            prefix_pages = self.alloc.match_prefix(prompt_ids[: n - 1])
+            snapshot = -1
+            if self.alloc.state_slots:
+                # Recurrent state: a page chain is reusable only up to a
+                # node that holds a state snapshot; restoring is one
+                # device copy into the sequence's slot, enqueued here,
+                # before any step that could write either slot.
+                prefix_pages, snapshot, full = self.alloc.match_prefix_state(
+                    prompt_ids[: n - 1])
+                given_up = (full - len(prefix_pages)) * self.cfg.page_size
+                obs.STATE_PROMPT_TOKENS.inc(n)
+                if given_up:
+                    obs.STATE_UNMATCHED_TOKENS.inc(given_up)
+            else:
+                prefix_pages = self.alloc.match_prefix(prompt_ids[: n - 1])
             matched = len(prefix_pages) * self.cfg.page_size
             seq_id = self.alloc.allocate(n, prefix_pages=prefix_pages)
+            if snapshot >= 0:
+                self._copy_state([snapshot], [self.alloc.state_slot(seq_id)])
+                obs.STATE_SNAPSHOTS.inc(event="restored")
+                obs.STATE_RESTORED_TOKENS.inc(matched)
             restored = self._restore_from_host(
                 seq_id, prompt_ids, n, len(prefix_pages), matched
             )
@@ -1566,6 +1669,41 @@ class Engine:
             )
             self._observe_occupancy()
             return seq_id
+
+    def _copy_state(self, src: list[int], dst: list[int]) -> None:
+        """Copy recurrent-state slots ``src`` to ``dst`` on the device: a
+        dispatch of its own on the step clock (program ``state_copy``),
+        ordered with the steps like any other. Under the engine lock."""
+        ticket = self.step_clock.enqueue()
+        with obs.phase("dispatch", tick=ticket[0]), \
+                annotate("engine.state_copy"), self.mesh_ctx():
+            self.cache = self._state_copy_jit(
+                self.cache, jnp.asarray(src, jnp.int32),
+                jnp.asarray(dst, jnp.int32),
+            )
+        obs.flight.record(
+            "dispatch", op="state_copy", slots=len(src), tick=ticket[0])
+
+    def sync_device_counters(self) -> None:
+        """Read the expert share's accumulators off the device into
+        ``opsagent_moe_share_total``, at a scrape. Under the engine lock
+        only a copy is enqueued behind the steps in flight (a step donates
+        the cache itself); the wait for it is outside the lock. The
+        accumulators are uint32 and wrap, so a delta is taken modulo 2**32:
+        right as long as scrapes are less than 2**32 assignments apart
+        (days of serving) and one at a time (the ``/metrics`` handler, on
+        the event loop)."""
+        with self.lock, self.mesh_ctx():
+            if "stats" not in self.cache:
+                return
+            stats = jnp.copy(self.cache["stats"])
+        now = np.asarray(stats)
+        with self.lock:
+            delta = now - self._moe_stats_seen      # uint32: modulo 2**32
+            self._moe_stats_seen = now
+        for what, d in zip(llama.MOE_STATS, delta):
+            if d:
+                obs.MOE_SHARE.inc(float(d), what=what)
 
     def _restore_from_host(
         self, seq_id: int, prompt_ids: list[int], n: int,
@@ -1618,9 +1756,10 @@ class Engine:
         with self.lock:
             seq = self.sequences[seq_id]
             done = self._prefilling[seq_id]
-            return self._bucket(
+            return self._bucket(self.alloc.clamp_chunk(
+                seq_id, done, seq.prompt_len,
                 min(seq.prompt_len - done, self.cfg.prefill_buckets[-1])
-            )
+            ))
 
     def prefill_batch(self, seq_ids: list[int]) -> dict[int, bool]:
         """Run ONE prefill chunk for EACH given admitting sequence in a
@@ -1648,8 +1787,10 @@ class Engine:
                 seqs = [self.sequences[s] for s in seq_ids]
                 dones = [self._prefilling[s] for s in seq_ids]
                 chunks = [
-                    min(seq.prompt_len - d, self.cfg.prefill_buckets[-1])
-                    for seq, d in zip(seqs, dones)
+                    self.alloc.clamp_chunk(
+                        sid, d, seq.prompt_len,
+                        min(seq.prompt_len - d, self.cfg.prefill_buckets[-1]))
+                    for sid, seq, d in zip(seq_ids, seqs, dones)
                 ]
                 bucket = self._bucket(max(chunks))
                 Bp = 1
@@ -1661,7 +1802,7 @@ class Engine:
                 starts = np.zeros((Bp,), np.int32)
                 lens = np.zeros((Bp,), np.int32)
                 tables = np.full(
-                    (Bp, self.cfg.max_pages_per_seq), -1, np.int32
+                    (Bp, self.alloc.table_width), -1, np.int32
                 )
                 for i, (sid, seq, d, c) in enumerate(
                     zip(seq_ids, seqs, dones, chunks)
@@ -1669,7 +1810,7 @@ class Engine:
                     tokens[i, :c] = seq.prompt_ids[d : d + c]
                     starts[i] = d
                     lens[i] = c
-                    tables[i] = self.alloc.page_table_row(sid)
+                    tables[i] = self._pass_row(sid, d, c)
                 ticket = self.step_clock.enqueue()
                 with obs.phase("dispatch", tick=ticket[0]), \
                         annotate("engine.prefill_chunk"), self.mesh_ctx():
@@ -1771,6 +1912,17 @@ class Engine:
                     self._drop_admission(sid)
                 raise
 
+    def _pass_row(
+        self, seq_id: int, start: int, q: int, each_token: bool = False
+    ) -> np.ndarray:
+        """The sequence's row of a step program's table for a pass (or,
+        with ``each_token``, ``q`` one-token passes) that takes it from
+        ``start`` tokens to ``start + q``: its pages and, for a model with
+        recurrent state, its slots, with the pass noted for the snapshot's
+        bookkeeping (``PageAllocator.note_pass``)."""
+        self.alloc.note_pass(seq_id, start, start + q, each_token)
+        return self.alloc.page_table_row(seq_id)
+
     def _drop_admission(self, seq_id: int) -> None:
         """Clean one failed admission: pages freed, host state dropped."""
         self.sequences.pop(seq_id, None)
@@ -1796,10 +1948,12 @@ class Engine:
             done = self._prefilling[seq_id]
             n = seq.prompt_len
             try:
+                chunk = self.alloc.clamp_chunk(
+                    seq_id, done, n,
+                    min(n - done, self.cfg.prefill_buckets[-1]))
                 table = jnp.asarray(
-                    self.alloc.page_table_row(seq_id)[None, :]
+                    self._pass_row(seq_id, done, chunk)[None, :]
                 )
-                chunk = min(n - done, self.cfg.prefill_buckets[-1])
                 bucket = self._bucket(chunk)
                 tokens = np.full((1, bucket), self.tokenizer.pad_id, np.int32)
                 tokens[0, :chunk] = seq.prompt_ids[done:done + chunk]
@@ -1977,16 +2131,16 @@ class Engine:
             for sid, want in prefill_chunks.items():
                 seq = self.sequences[sid]
                 done = self._prefilling[sid]
-                c = min(
+                c = self.alloc.clamp_chunk(sid, done, seq.prompt_len, min(
                     want, self.cfg.mixed_buckets[-1], seq.prompt_len - done
-                )
+                ))
                 chunk_info.append((sid, seq, done, c))
                 smax = max(smax, c)
             S = self._mixed_bucket(smax)
             tokens = np.full((B, S), self.tokenizer.pad_id, np.int32)
             starts = np.zeros((B,), np.int32)
             qlens = np.zeros((B,), np.int32)
-            tables = np.full((B, self.cfg.max_pages_per_seq), -1, np.int32)
+            tables = np.full((B, self.alloc.table_width), -1, np.int32)
             for i, s in enumerate(decode):
                 tokens[i, 0] = (
                     s.tokens[-1] if s.tokens else self.tokenizer.bos_id
@@ -1995,13 +2149,13 @@ class Engine:
                 # writes (and attends from) the written offset.
                 starts[i] = self.alloc.length(s.seq_id) - 1
                 qlens[i] = 1
-                tables[i] = self.alloc.page_table_row(s.seq_id)
+                tables[i] = self._pass_row(s.seq_id, int(starts[i]), 1)
             base = len(decode)
             for j, (sid, seq, done, c) in enumerate(chunk_info):
                 tokens[base + j, :c] = seq.prompt_ids[done:done + c]
                 starts[base + j] = done
                 qlens[base + j] = c
-                tables[base + j] = self.alloc.page_table_row(sid)
+                tables[base + j] = self._pass_row(sid, done, c)
             slots: list[Sequence | None] = (
                 decode + [seq for _, seq, _, _ in chunk_info]
             )
@@ -2260,7 +2414,7 @@ class Engine:
             qlens = np.zeros((B,), np.int32)
             emits = np.zeros((B,), bool)
             ov_fsm = np.zeros((B,), np.int32)  # 0 = FREE sentinel row
-            tables = np.full((B, self.cfg.max_pages_per_seq), -1, np.int32)
+            tables = np.full((B, self.alloc.table_width), -1, np.int32)
             temps = np.zeros((B,), np.float32)
             top_k = np.zeros((B,), np.int32)
             top_p = np.ones((B,), np.float32)
@@ -2272,7 +2426,7 @@ class Engine:
                 starts[i] = self.alloc.length(s.seq_id) - q
                 qlens[i] = q
                 emits[i] = True
-                tables[i] = self.alloc.page_table_row(s.seq_id)
+                tables[i] = self._pass_row(s.seq_id, int(starts[i]), q)
                 # The masked sample applies the state AFTER the appended
                 # run (+1: device-table row 0 is the FREE sentinel).
                 ov_fsm[i] = s.mask_fn.dfa_state(s.tokens + run) + 1
@@ -2558,6 +2712,18 @@ class Engine:
         running = sum(1 for s in self.sequences.values() if not s.done)
         obs.BATCH_OCCUPANCY.set(running / max(1, self.cfg.max_batch_size))
         obs.RUNNING_SEQUENCES.set(len(self.sequences))
+        if self.alloc.state_slots:
+            live, snaps = self.alloc.state_slots_in_use()
+            obs.STATE_SLOTS_IN_USE.set(live, kind="live")
+            obs.STATE_SLOTS_IN_USE.set(snaps, kind="snapshot")
+            for i, (event, total) in enumerate((
+                ("taken", self.alloc.snapshots_taken),
+                ("evicted", self.alloc.snapshots_evicted),
+            )):
+                if total > self._snapshots_seen[i]:
+                    obs.STATE_SNAPSHOTS.inc(
+                        total - self._snapshots_seen[i], event=event)
+                    self._snapshots_seen[i] = total
         ev = self.alloc.evictions
         if ev > self._evictions_seen:
             obs.PREFIX_EVICTIONS.inc(ev - self._evictions_seen)
@@ -2962,6 +3128,9 @@ class Engine:
                 return {}
             ids: list[int | None] = [s.seq_id for s in running]
             ids += [None] * (B - len(ids))
+            for s in running:
+                at = self.alloc.length(s.seq_id)
+                self.alloc.note_pass(s.seq_id, at - 1, at)
             table, lengths, active = self.alloc.batch_views(ids, B)
             # lengths now include the new token; decode wants the write
             # offset (tokens already present before this step).
@@ -3254,6 +3423,8 @@ class Engine:
                 alive[lane] = True
                 budgets[lane] = got
                 lane_seqs[lane] = sid
+                at = self.alloc.length(sid)
+                self.alloc.note_pass(sid, at - got, at, each_token=True)
             if not budgets.any():
                 # Nothing to dispatch; a pull still guarantees progress.
                 if self._inflight:

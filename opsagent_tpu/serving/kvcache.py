@@ -21,6 +21,21 @@ States of a page:
 
 Allocation is O(pages) against a free list plus O(prompt/page_size) trie
 walks; page counts are small (thousands).
+
+**Recurrent state** (``state_slots`` > 0: a model with linear-attention
+layers, ``models.llama.make_state``). Such a layer keeps a fixed-size state
+per sequence, whatever its length, and a page chain alone cannot stand in
+for it: a prefix is reusable only up to a position for which every such
+layer's state was kept. Slots of one device pool are either **live** (one
+per running sequence, taken at admission and freed with it) or
+**snapshots**: each running sequence also holds one snapshot slot that the
+step programs overwrite whenever a pass leaves the sequence on a page
+boundary (the slots ride in the sequence's table row, ``page_table_row``);
+when the sequence's pages are donated to the trie, the snapshot goes on the
+node that ends the chain at the snapshot's position. ``match_prefix_state``
+returns the longest chain that ends in a node with a snapshot. Snapshots
+are evicted with their node, or alone (LRU by their node's stamp) when a
+new sequence needs a slot; evicting one never touches a page.
 """
 
 from __future__ import annotations
@@ -51,6 +66,11 @@ class SeqAlloc:
     pages: list[int] = field(default_factory=list)
     length: int = 0          # tokens currently in cache
     num_shared: int = 0      # leading pages borrowed from the prefix trie
+    # recurrent state (allocators with state slots; -1 = none)
+    state_slot: int = -1     # the live slot the sequence's state is in
+    snap_slot: int = -1      # the snapshot slot its step programs write
+    snap_pos: int = 0        # position the newest pass may have left in it
+    state_from: int = 0      # position its state started from (0 or restored)
 
 
 @dataclass
@@ -63,6 +83,7 @@ class TrieNode:
     refcount: int = 0                # live sequences sharing this page
     children: int = 0                # child nodes (only leaves are evictable)
     last_use: int = 0                # LRU stamp
+    snapshot: int = -1               # state-snapshot slot at this page's end
 
 
 class PageAllocator:
@@ -72,11 +93,24 @@ class PageAllocator:
         page_size: int,
         max_pages_per_seq: int,
         prefix_cache: bool = True,
+        state_slots: int = 0,
+        state_snapshots: int = 0,
     ):
         self.num_pages = num_pages
         self.page_size = page_size
         self.max_pages_per_seq = max_pages_per_seq
         self.prefix_cache = prefix_cache
+        # Recurrent-state slots: [0, state_slots) live, the rest snapshots.
+        self.state_slots = state_slots
+        self.state_snapshots = state_snapshots if state_slots else 0
+        self._free_live: list[int] = list(range(state_slots - 1, -1, -1))
+        self._free_snaps: list[int] = list(range(
+            state_slots + self.state_snapshots - 1, state_slots - 1, -1))
+        self.snapshots_taken = 0     # attached to a trie node
+        self.snapshots_evicted = 0   # dropped, alone or with their node
+        # a row of the step programs' table: the pages, then (with state)
+        # the live slot and the snapshot slot
+        self.table_width = max_pages_per_seq + (2 if state_slots else 0)
         self._free: list[int] = list(range(num_pages - 1, -1, -1))
         self._seqs: dict[int, SeqAlloc] = {}
         self._next_id = 0
@@ -195,6 +229,94 @@ class PageAllocator:
             parent = node.page
         return pages
 
+    def match_prefix_state(
+        self, tokens: list[int]
+    ) -> tuple[list[int], int, int]:
+        """``match_prefix`` for a model with recurrent state: the longest
+        cached chain that ENDS in a node holding a state snapshot, that
+        snapshot's slot (-1: none, and no page is reused), and how many
+        pages the full page match had (what was given up for want of a
+        snapshot is the difference)."""
+        pages = self.match_prefix(tokens)
+        keep = 0
+        for i, page in enumerate(pages):
+            if self._by_page[page].snapshot >= 0:
+                keep = i + 1
+        slot = self._by_page[pages[keep - 1]].snapshot if keep else -1
+        return pages[:keep], slot, len(pages)
+
+    # -- recurrent-state slots ----------------------------------------------
+    def _take_snapshot_slot(self, keep: int = -1) -> int:
+        """A free snapshot slot, else the least recently used one that a
+        trie node holds (never ``keep``: the one being restored from);
+        -1 when running sequences hold them all."""
+        if self._free_snaps:
+            return self._free_snaps.pop()
+        victim: TrieNode | None = None
+        for node in self._by_page.values():
+            if node.snapshot >= 0 and node.snapshot != keep and (
+                victim is None or node.last_use < victim.last_use
+            ):
+                victim = node
+        if victim is None:
+            return -1
+        slot, victim.snapshot = victim.snapshot, -1
+        self.snapshots_evicted += 1
+        return slot
+
+    def _drop_snapshot(self, node: TrieNode) -> None:
+        if node.snapshot >= 0:
+            self._free_snaps.append(node.snapshot)
+            node.snapshot = -1
+            self.snapshots_evicted += 1
+
+    def _release_state(self, seq: SeqAlloc) -> None:
+        if seq.state_slot >= 0:
+            self._free_live.append(seq.state_slot)
+        if seq.snap_slot >= 0:
+            self._free_snaps.append(seq.snap_slot)
+        seq.state_slot = seq.snap_slot = -1
+
+    def state_slots_in_use(self) -> tuple[int, int]:
+        """(live slots held by sequences, snapshot slots not free)."""
+        return (self.state_slots - len(self._free_live),
+                self.state_snapshots - len(self._free_snaps))
+
+    def state_slot(self, seq_id: int) -> int:
+        return self._seqs[seq_id].state_slot
+
+    def note_pass(self, seq_id: int, start: int, end: int,
+                  each_token: bool = False) -> None:
+        """A step program is about to take the sequence from ``start`` to
+        ``end`` tokens: in one pass (its snapshot slot is written if ``end``
+        is a page boundary), or with ``each_token`` a token a pass (every
+        boundary on the way is written, the last one stays)."""
+        if not self.state_slots:
+            return
+        seq = self._seqs[seq_id]
+        P = self.page_size
+        last = end // P * P
+        if seq.snap_slot >= 0 and last > start and (
+            each_token or last == end
+        ):
+            seq.snap_pos = last
+
+    def snapshot_boundary(self, seq_id: int, done: int, n: int) -> int:
+        """Where a prefill of a prompt of ``n`` tokens that stands at
+        ``done`` must end a chunk so that the sequence's snapshot is taken:
+        the last page boundary before the prompt's final token (a later
+        turn re-sends the prompt and more, and can restore there), or 0
+        when there is none ahead."""
+        if not self.state_slots or self._seqs[seq_id].snap_slot < 0:
+            return 0
+        at = (n - 1) // self.page_size * self.page_size
+        return at if at > done else 0
+
+    def clamp_chunk(self, seq_id: int, done: int, n: int, chunk: int) -> int:
+        """``chunk`` cut so that it does not pass ``snapshot_boundary``."""
+        at = self.snapshot_boundary(seq_id, done, n)
+        return min(chunk, at - done) if at else chunk
+
     def _take_free_page(self) -> int:
         """Pop a free page, evicting the LRU unreferenced trie leaf if the
         free list is dry. Raises OutOfPages when nothing is evictable."""
@@ -221,6 +343,7 @@ class PageAllocator:
             except Exception:  # noqa: BLE001 - offload is best-effort
                 pass
         self.evictions += 1
+        self._drop_snapshot(node)
         del self._trie[(node.parent, node.key)]
         del self._by_page[node.page]
         if node.parent >= 0 and node.parent in self._by_page:
@@ -331,6 +454,15 @@ class PageAllocator:
         full_pages = min(len(tokens) // P, len(seq.pages))
         absorbed: set[int] = set()
         parent = -1
+        # The sequence's snapshot is good for the trie when the newest pass
+        # that could have written it left it at the last page boundary of
+        # what the cache really holds (a pass that ran past it, and was
+        # rolled back, has overwritten it with a later state), and some
+        # pass wrote it at all.
+        at = len(tokens) // P * P
+        snap_page = at // P - 1 if (
+            seq.snap_slot >= 0 and seq.snap_pos == at > seq.state_from
+        ) else -1
         for i in range(full_pages):
             key = tuple(tokens[i * P:(i + 1) * P])
             page = seq.pages[i]
@@ -339,21 +471,23 @@ class PageAllocator:
                 node.refcount -= 1
                 node.last_use = stamp
                 parent = page
-                continue
-            node = self._trie.get((parent, key))
-            if node is not None:
+            elif (node := self._trie.get((parent, key))) is not None:
                 # Same content already cached by someone else: our page is a
                 # duplicate — follow the canonical chain, free ours.
                 node.last_use = stamp
                 parent = node.page
-                continue
-            node = TrieNode(page=page, parent=parent, key=key, last_use=stamp)
-            self._trie[(parent, key)] = node
-            self._by_page[page] = node
-            if parent >= 0 and parent in self._by_page:
-                self._by_page[parent].children += 1
-            absorbed.add(page)
-            parent = page
+            else:
+                node = TrieNode(
+                    page=page, parent=parent, key=key, last_use=stamp)
+                self._trie[(parent, key)] = node
+                self._by_page[page] = node
+                if parent >= 0 and parent in self._by_page:
+                    self._by_page[parent].children += 1
+                absorbed.add(page)
+                parent = page
+            if i == snap_page and node.snapshot < 0:
+                node.snapshot, seq.snap_slot = seq.snap_slot, -1
+                self.snapshots_taken += 1
         # Shared pages past the registered walk (can happen only if tokens
         # shrank, which callers never do — defensive deref).
         for i in range(full_pages, seq.num_shared):
@@ -402,6 +536,17 @@ class PageAllocator:
                 self._by_page[p].refcount -= 1
             raise
         seq = SeqAlloc(self._next_id)
+        if self.state_slots:
+            if not self._free_live:
+                self._free.extend(fresh)
+                for p in shared:
+                    self._by_page[p].refcount -= 1
+                raise OutOfPages("no free recurrent-state slot")
+            seq.state_slot = self._free_live.pop()
+            restored_from = (
+                self._by_page[shared[-1]].snapshot if shared else -1)
+            seq.snap_slot = self._take_snapshot_slot(keep=restored_from)
+            seq.state_from = seq.snap_pos = len(shared) * self.page_size
         self._next_id += 1
         seq.pages = shared + fresh
         seq.num_shared = len(shared)
@@ -479,13 +624,17 @@ class PageAllocator:
                         node.refcount -= 1
                 else:
                     self._free.append(p)
+        self._release_state(seq)
 
     # -- device views ------------------------------------------------------
     def page_table_row(self, seq_id: int) -> np.ndarray:
-        """This sequence's page table padded to max_pages_per_seq with -1."""
-        row = np.full((self.max_pages_per_seq,), -1, np.int32)
-        pages = self._seqs[seq_id].pages
-        row[: len(pages)] = pages
+        """This sequence's page table padded to max_pages_per_seq with -1;
+        with recurrent state, then its live slot and its snapshot slot."""
+        row = np.full((self.table_width,), -1, np.int32)
+        seq = self._seqs[seq_id]
+        row[: len(seq.pages)] = seq.pages
+        if self.state_slots:
+            row[-2:] = seq.state_slot, seq.snap_slot
         return row
 
     def batch_views(
@@ -493,7 +642,7 @@ class PageAllocator:
     ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         """(page_table [batch, MaxP], lengths [batch], active [batch]) for a
         decode batch; unused slots are inactive with empty tables."""
-        table = np.full((batch_size, self.max_pages_per_seq), -1, np.int32)
+        table = np.full((batch_size, self.table_width), -1, np.int32)
         lengths = np.zeros((batch_size,), np.int32)
         active = np.zeros((batch_size,), bool)
         for i, sid in enumerate(seq_ids):

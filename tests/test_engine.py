@@ -390,6 +390,25 @@ def test_failing_warmup_family_raises(monkeypatch, progs, breaks):
         eng.warmup("only")
 
 
+def test_sessions_free_warmup_builds_no_grammar_table(monkeypatch):
+    """"sessions-free" is "sessions" less the grammar families: under
+    free-text traffic no FSM table exists, so none is built and no FSM
+    variant of a step program is compiled."""
+    levels = Engine.WARMUP_LEVELS
+    assert levels["sessions-free"] == levels["sessions"] - {"fsm", "ffwd"}
+    eng = Engine(EngineConfig(
+        model="tiny-test", dtype=jnp.float32, tp=1, page_size=4,
+        num_pages=64, max_pages_per_seq=8, max_batch_size=2,
+        prefill_buckets=(8,), mixed_buckets=(4,), decode_block=4,
+    ))
+
+    def refuse(*a, **kw):
+        raise AssertionError("a grammar table was asked for")
+
+    monkeypatch.setattr(eng, "_toolprompt_fsm_tables", refuse)
+    assert eng.warmup("sessions-free") > 0
+
+
 def test_compilation_cache_lives_in_one_place(tmp_path, monkeypatch):
     """Where JAX_COMPILATION_CACHE_DIR is set the cache lives there;
     where it is not, at one fixed git-ignored path inside the checkout
