@@ -1541,6 +1541,8 @@ def prefill_with_prefix(
     cache: Params,
     page_table: jax.Array,   # [B, MaxP] (prefix pages + fresh tail pages)
     dtype: jnp.dtype = jnp.bfloat16,
+    attn_impl: str = "xla",  # ops.paged_attention_backend choice
+    mesh=None,               # Mesh for the shard_mapped pallas-under-tp path
 ) -> tuple[jax.Array, Params]:
     """Prefix-cache admission: forward only the tail, attending over the
     sequence's cached prefix pages + the tail KV written this call. Returns
@@ -1559,7 +1561,8 @@ def prefill_with_prefix(
                 kc, latent, page_table, start, valid_len=lengths, layer=li
             )
             ctx = paged_prefix_attention(
-                q_lat, kc, kc, page_table, start, lengths, layer=li
+                q_lat, kc, kc, page_table, start, lengths, layer=li,
+                impl=attn_impl, mesh=mesh,
             )
             return _mla_latent_out(ctx, lp, cfg), kc, vc
         q, k, v = _qkv_rope(h, lp, cfg, cos, sin)
@@ -1567,7 +1570,8 @@ def prefill_with_prefix(
             kc, vc, k, v, page_table, start, valid_len=lengths, layer=li
         )
         attn = paged_prefix_attention(
-            q, kc, vc, page_table, start, lengths, layer=li
+            q, kc, vc, page_table, start, lengths, layer=li,
+            impl=attn_impl, mesh=mesh,
         )
         return attn.reshape(B, S, -1), kc, vc
 
@@ -1649,6 +1653,8 @@ def verify_step(
     cache: Params,
     page_table: jax.Array,   # [B, MaxP]
     dtype: jnp.dtype = jnp.bfloat16,
+    attn_impl: str = "xla",  # ops.paged_attention_backend choice
+    mesh=None,               # Mesh for the shard_mapped pallas-under-tp path
 ) -> tuple[jax.Array, Params]:
     """Speculative-decoding verify forward: process an S-token draft chunk
     per row in ONE pass, returning logits for EVERY chunk position
@@ -1677,7 +1683,8 @@ def verify_step(
                 kc, latent, page_table, start, valid_len=valid, layer=li
             )
             ctx = paged_prefix_attention(
-                q_lat, kc, kc, page_table, start, valid, layer=li
+                q_lat, kc, kc, page_table, start, valid, layer=li,
+                impl=attn_impl, mesh=mesh,
             )
             return _mla_latent_out(ctx, lp, cfg), kc, vc
         q, k, v = _qkv_rope(h, lp, cfg, cos, sin)
@@ -1685,7 +1692,8 @@ def verify_step(
             kc, vc, k, v, page_table, start, valid_len=valid, layer=li
         )
         attn = paged_prefix_attention(
-            q, kc, vc, page_table, start, valid, layer=li
+            q, kc, vc, page_table, start, valid, layer=li,
+            impl=attn_impl, mesh=mesh,
         )
         return attn.reshape(B, S, -1), kc, vc
 
@@ -1704,7 +1712,7 @@ def decode_step(
     page_table: jax.Array,   # [B, MaxP]
     active: jax.Array,       # [B] bool; inactive slots skip the page write
     dtype: jnp.dtype = jnp.bfloat16,
-    attn_impl: str = "xla",  # xla | pallas | pallas-dma (paged_attention_backend)
+    attn_impl: str = "xla",  # ops.paged_attention_backend choice
     mesh=None,               # Mesh for the shard_mapped pallas-under-tp path
     weight_stream: str = "xla",  # xla | pallas-dma (quant_matmul_pallas)
 ) -> tuple[jax.Array, Params]:

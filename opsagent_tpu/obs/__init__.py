@@ -103,6 +103,17 @@ DECODE_DISPATCHES = _reg.counter(
     "Device decode dispatches by kind (block, single, speculative, mixed)",
     labelnames=("kind",),
 )
+ATTN_PAGES_STREAMED = _reg.counter(
+    "opsagent_attn_pages_streamed_total",
+    "KV pages the rows of attention dispatches own below their last query "
+    "(sum over rows of ceil((start + q_len) / page_size)), counted at "
+    "plan time: what a streaming reader reads a layer",
+)
+ATTN_PAGES_CAPACITY = _reg.counter(
+    "opsagent_attn_pages_capacity_total",
+    "Page-table capacity of the same dispatches (rows x max_pages_per_seq "
+    "a pass): what a gathering reader reads a layer",
+)
 MIXED_DECODE_LANES = _reg.histogram(
     "opsagent_mixed_dispatch_decode_lanes",
     "Decode lanes advanced per mixed prefill+decode dispatch",

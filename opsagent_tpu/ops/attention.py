@@ -23,8 +23,10 @@ layer (56 copies of 1.17 GB a step at the 7B's 28 layers x 2560 pages,
 200 ms of a 511 ms step, PERF.md PR 25). Merged, the 16 page slots fill
 the tile's rows at any head count and write and gather share the tiling.
 ``write_pages`` and ``_gather_kv`` take either form and tell them apart
-by the trailing axis (``pages_merged``); the Pallas kernels take split
-pages only.
+by the trailing axis (``pages_merged``). The streaming kernel
+(``paged_attention_stream``, "pallas-stream") reads merged pages at any
+head count, a kv head being a 128-lane slice of the page row; the two
+older Pallas kernels take split pages only.
 """
 
 from __future__ import annotations
@@ -93,17 +95,23 @@ TILE_ROWS = 8  # rows of the TPU's (rows, 128 lanes) tile, at 1, 2 and 4 bytes
 
 def page_form(kv_heads_per_shard: int, attn_impl: str = "xla") -> str:
     """The form a shard's KV pages are held in on the device (module
-    header): "merged" ``[.., P, K*D]`` where split pages would get a
-    part-empty tile (K neither 1 nor a multiple of 8), "split"
-    ``[.., P, K, D]`` otherwise. By the compiler, for a described v5e
-    (tests/test_tpu_compile.py): split at K = 2 and 4 re-tiles the whole
-    cache between write and gather in every layer, bf16 and int8 alike
-    (both get 8-row tiles); at K = 8 split has no such copy and merged
-    would add one of each gathered block; at K = 1 (MLA's latent, a tp
-    shard of one head) the unit axis costs nothing. The Pallas backends
-    index ``[.., P, K, D]`` blocks and gather nothing, so they hold split
-    pages at any K."""
-    if attn_impl != "xla" or kv_heads_per_shard == 1:
+    header): "merged" ``[.., P, K*D]`` or "split" ``[.., P, K, D]``, by
+    the reader. Under the xla gather: merged where split pages would get
+    a part-empty tile (K neither 1 nor a multiple of 8). By the compiler,
+    for a described v5e (tests/test_tpu_compile.py): split at K = 2 and 4
+    re-tiles the whole cache between write and gather in every layer,
+    bf16 and int8 alike (both get 8-row tiles); at K = 8 split has no
+    such copy and merged would add one of each gathered block. The
+    streaming kernel gathers nothing and slices a kv head out of the
+    merged row's lanes, so under it every K above 1 is merged. The grid
+    and manual-DMA kernels index ``[.., P, K, D]`` blocks and hold split
+    pages at any K. One kv head (MLA's latent, a tp shard of one head) is
+    the same bytes either way and keeps its unit axis."""
+    if kv_heads_per_shard == 1:
+        return "split"
+    if attn_impl == "pallas-stream":
+        return "merged"
+    if attn_impl != "xla":
         return "split"
     return "split" if kv_heads_per_shard % TILE_ROWS == 0 else "merged"
 
@@ -139,33 +147,53 @@ def _dequantize_gathered(seq: jax.Array, scale: jax.Array, dtype) -> jax.Array:
     return (seq.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
-def paged_attention_backend() -> str:
-    """Which decode-attention implementation to use: "xla" (gather-based),
-    "pallas" ((B, MaxP) grid kernel), or "pallas-dma" (manual
-    double-buffered page streaming). Env OPSAGENT_PAGED_BACKEND overrides.
+PAGED_BACKENDS = ("xla", "pallas", "pallas-dma", "pallas-stream")
 
-    Default is "xla" EVERYWHERE — by measurement, not preference: the
-    r01 on-chip comparison had the gather beating the grid kernel at
-    decode shapes (per-page pipeline-step overhead), and the committed
-    headline numbers are xla numbers. "pallas-dma" now covers BOTH hot
-    paths — decode (``paged_decode_attention_pallas_dma``) and the
-    mixed ragged step (``paged_ragged_attention_pallas_dma``), each
-    streaming int8 ``QuantizedPages`` at half the bytes — and the bench
-    ragged-backend sweep (xla vs pallas vs pallas-dma × KV dtype ×
-    weight quant) promotes it into the headline the moment an on-chip
-    run shows it winning; the default flips only on that evidence.
-    Interpret-mode tests cover semantics, not Mosaic lowering or speed,
-    and head_dim % 128 != 0 still rejects (r04 on-chip: Mosaic
-    manual-DMA alignment)."""
+
+def paged_attention_backend(
+    *,
+    platform: str,
+    head_dim: int,
+    kv_heads_per_shard: int,
+    page_itemsize: int,
+    mla: bool = False,
+) -> str:
+    """Which reader of paged keys and values an engine runs: "xla" (the
+    gather, and the oracle of every test), "pallas-stream" (the streaming
+    ragged kernel, ``paged_attention_stream``), or one of the two older
+    kernels, "pallas" ((B, MaxP) grid) and "pallas-dma" (a page a step).
+
+    The choice is the code's, from what it can observe where the engine
+    is built: the platform of the mesh's devices and the shapes
+    ``pallas_refusal`` takes. On a TPU it is the streaming kernel
+    wherever the chip's compiler takes it (head dim on the 128-lane
+    tiling, bf16 pages, no MLA); everywhere else (the CPU, int8 pages,
+    MLA's 192-wide qk heads, head dims off the tiling) the gather. By
+    measurement (PERF.md section 6, PR 29, ``scripts/attn_microbench.py``
+    at the benchmark cells' shapes on a v5e): the kernel is ahead of the
+    gather at every shape the cells run, decode blocks over short rows
+    included, so no shape is sent back to the gather on speed.
+
+    OPSAGENT_PAGED_BACKEND names a backend outright (the tests' and the
+    bench sweep's handle on the older kernels and on interpret mode); a
+    named backend the shapes refuse is an error at engine init, never a
+    quiet gather under the kernel's name."""
     choice = os.environ.get("OPSAGENT_PAGED_BACKEND", "auto")
-    if choice in ("pallas", "pallas-dma", "xla"):
+    if choice in PAGED_BACKENDS:
         return choice
     if choice != "auto":
         raise ValueError(
-            f"OPSAGENT_PAGED_BACKEND={choice!r}: expected pallas, "
-            f"pallas-dma, xla, or auto"
+            f"OPSAGENT_PAGED_BACKEND={choice!r}: expected one of "
+            f"{', '.join(PAGED_BACKENDS)}, or auto"
         )
-    return "xla"
+    if platform != "tpu":
+        return "xla"
+    refused = pallas_refusal(
+        "pallas-stream", head_dim=head_dim,
+        kv_heads_per_shard=kv_heads_per_shard,
+        page_itemsize=page_itemsize, mla=mla,
+    )
+    return "xla" if refused else "pallas-stream"
 
 
 def pallas_interpret() -> bool:
@@ -212,6 +240,22 @@ def pallas_refusal(
             "(nope + rope, e.g. 192) breaks the Pallas kernels' last-dim "
             "tiling; MLA serves through the xla gather"
         )
+    if impl == "pallas-stream":
+        if page_itemsize == 1:
+            return (
+                "pallas-stream with int8 pages: a 16-token page is half "
+                "of an int8 tile's 32 rows, so a key block cannot be "
+                "read out of the page buffers without a re-tiling, and "
+                "the per-token scales would need a lane-to-sublane move "
+                "a kv head; QuantizedPages serve through the xla gather"
+            )
+        if head_dim % 128:
+            return (
+                f"pallas-stream with head_dim {head_dim}: a kv head is a "
+                "slice of the merged page row's lanes, and Mosaic wants "
+                "it on the 128-lane tiling; such heads serve through the "
+                "xla gather"
+            )
     if impl == "pallas-dma":
         if head_dim % 128:
             return (
@@ -233,12 +277,30 @@ def pallas_refusal(
     return None
 
 
-def _require_split(pages, head_dim: int, impl: str) -> None:
-    if pages_merged(pages, head_dim):
+def _tp(mesh: Mesh | None) -> int:
+    return 1 if mesh is None else mesh.shape.get("tp", 1)
+
+
+def _require_form(pages, head_dim: int, impl: str, tp: int = 1) -> None:
+    """The held form must be the one ``page_form`` gives ``impl`` at the
+    kv heads a shard holds (``tp`` shards of the pages' kv-head axis)."""
+    merged = pages_merged(pages, head_dim)
+    one_head = not merged and pages.shape[-2] == tp
+    if impl == "pallas-stream":
+        if isinstance(pages, QuantizedPages):
+            raise ValueError(pallas_refusal(
+                impl, head_dim=head_dim, kv_heads_per_shard=1,
+                page_itemsize=1,
+            ))
+        wrong = not (merged or one_head)
+    else:
+        wrong = merged
+    if wrong:
         raise ValueError(
-            f"paged backend {impl!r} was given merged pages "
-            f"{tuple(pages.shape)}: the Pallas kernels index [.., P, K, D] "
-            "blocks; make the cache with page_form(kv_heads, attn_impl)"
+            f"paged backend {impl!r} was given "
+            f"{'merged' if merged else 'split'} pages "
+            f"{tuple(pages.shape)}: make the cache with "
+            "page_form(kv_heads, attn_impl)"
         )
 
 
@@ -253,6 +315,10 @@ def _shard_map(fn, mesh: Mesh, in_specs, out_specs):
 
 
 def _pallas_kernel_fn(impl: str):
+    if impl == "pallas-stream":
+        from .paged_attention_stream import paged_decode_attention_stream
+
+        return paged_decode_attention_stream
     from .paged_attention_pallas import (
         paged_decode_attention_pallas,
         paged_decode_attention_pallas_dma,
@@ -265,6 +331,10 @@ def _pallas_kernel_fn(impl: str):
 
 
 def _ragged_pallas_kernel_fn(impl: str):
+    if impl == "pallas-stream":
+        from .paged_attention_stream import paged_ragged_attention_stream
+
+        return paged_ragged_attention_stream
     from .paged_attention_pallas import (
         paged_ragged_attention_pallas,
         paged_ragged_attention_pallas_dma,
@@ -274,6 +344,26 @@ def _ragged_pallas_kernel_fn(impl: str):
         paged_ragged_attention_pallas_dma if impl == "pallas-dma"
         else paged_ragged_attention_pallas
     )
+
+
+def _tp_page_spec(pages, head_dim: int):
+    """PartitionSpec (a ``QuantizedPages`` of them for int8 pages) that
+    shards held pages by kv head over ``tp``: the kv-head axis of split
+    pages, the trailing ``K*D`` axis of merged ones (a shard's heads are
+    contiguous lanes)."""
+    values = pages.q if isinstance(pages, QuantizedPages) else pages
+    lead = (None,) * (
+        values.ndim - (1 if pages_merged(values, head_dim) else 2)
+    )
+    spec = (
+        P(*lead, "tp") if pages_merged(values, head_dim)
+        else P(*lead, "tp", None)
+    )
+    if isinstance(pages, QuantizedPages):
+        # Scale planes shard with their values' kv-head axis (one fewer
+        # trailing dim); the spec pytree mirrors the QuantizedPages leaf.
+        return QuantizedPages(spec, P(*lead, "tp"))
+    return spec
 
 
 def paged_decode_attention_pallas_tp(
@@ -299,18 +389,7 @@ def paged_decode_attention_pallas_tp(
     kernel = _pallas_kernel_fn(impl)
 
     spec_q = P(None, "tp", None)
-    five_d = k_pages.ndim == 5
-    spec_kv = (
-        P(None, None, None, "tp", None) if five_d
-        else P(None, None, "tp", None)
-    )
-    if isinstance(k_pages, QuantizedPages):
-        # Scale planes shard with their values' kv-head axis (one fewer
-        # trailing dim); the spec pytree mirrors the QuantizedPages leaf.
-        spec_sc = (
-            P(None, None, None, "tp") if five_d else P(None, None, "tp")
-        )
-        spec_kv = QuantizedPages(spec_kv, spec_sc)
+    spec_kv = _tp_page_spec(k_pages, q.shape[-1])
     if layer is None:
         layer = jnp.int32(0)
 
@@ -340,13 +419,13 @@ def paged_decode_attention_auto(
     ``paged_attention_backend``, resolved at trace time by the caller).
     With a mesh whose tp axis is >1, the Pallas path runs shard_mapped
     over tp (see ``paged_decode_attention_pallas_tp``). int8+scale
-    ``QuantizedPages`` flow through EVERY impl: the XLA gather, the
-    manual-DMA kernel, and the (B, MaxP) grid kernel all carry a
-    score-space scale path now."""
+    ``QuantizedPages`` flow through the XLA gather, the manual-DMA kernel
+    and the (B, MaxP) grid kernel (a score-space scale path each); the
+    streaming kernel refuses them by name (``pallas_refusal``)."""
     if impl.startswith("pallas"):
-        _require_split(k_pages, q.shape[-1], impl)
+        _require_form(k_pages, q.shape[-1], impl, _tp(mesh))
         interpret = pallas_interpret()
-        if mesh is not None and mesh.shape.get("tp", 1) > 1:
+        if _tp(mesh) > 1:
             return paged_decode_attention_pallas_tp(
                 q, k_pages, v_pages, page_table, lengths, mesh, layer=layer,
                 impl=impl, interpret=interpret,
@@ -699,13 +778,17 @@ def paged_prefix_attention(
     start: jax.Array,       # [B] cached-prefix lengths (tail begins here)
     lengths: jax.Array,     # [B] valid TAIL lengths
     layer: jax.Array | None = None,  # [] int32 with the layer-axis form
+    impl: str = "xla",
+    mesh: Mesh | None = None,
 ) -> jax.Array:
     """Tail-prefill attention over paged KV holding [prefix + tail] — the
     prefix-cache admission path. Prefix attention IS ragged paged
     attention (per-row write offset + per-row valid tail length), so this
-    is the same op under its admission-era name."""
-    return paged_ragged_attention(
-        q, k_pages, v_pages, page_table, start, lengths, layer=layer
+    is the same op under its admission-era name, through the same
+    dispatch."""
+    return paged_ragged_attention_auto(
+        q, k_pages, v_pages, page_table, start, lengths, impl=impl,
+        layer=layer, mesh=mesh,
     )
 
 
@@ -732,18 +815,7 @@ def paged_ragged_attention_pallas_tp(
     kernel = _ragged_pallas_kernel_fn(impl)
 
     spec_q = P(None, None, "tp", None)
-    five_d = k_pages.ndim == 5
-    spec_kv = (
-        P(None, None, None, "tp", None) if five_d
-        else P(None, None, "tp", None)
-    )
-    if isinstance(k_pages, QuantizedPages):
-        # Scale planes shard with their values' kv-head axis (one fewer
-        # trailing dim); the spec pytree mirrors the QuantizedPages leaf.
-        spec_sc = (
-            P(None, None, None, "tp") if five_d else P(None, None, "tp")
-        )
-        spec_kv = QuantizedPages(spec_kv, spec_sc)
+    spec_kv = _tp_page_spec(k_pages, q.shape[-1])
     if layer is None:
         layer = jnp.int32(0)
 
@@ -775,16 +847,17 @@ def paged_ragged_attention_auto(
     mesh: Mesh | None = None,
 ) -> jax.Array:
     """Impl-dispatched ragged paged attention (the mixed-step analogue of
-    ``paged_decode_attention_auto``). "pallas-dma" dispatches to the
-    ragged manual-DMA streamer (``paged_ragged_attention_pallas_dma``)
-    and "pallas" to the (B, MaxP) grid kernel — BOTH natively stream
-    int8 ``QuantizedPages`` at half the bytes with score-space scales,
-    so quantized pages on the mixed hot path are never materialized as a
-    dequantized contiguous gather under any pallas impl."""
+    ``paged_decode_attention_auto``): "pallas-stream" is the streaming
+    kernel over merged pages (``paged_attention_stream``), "pallas-dma"
+    the older manual-DMA streamer (a page a step) and "pallas" the
+    (B, MaxP) grid kernel. The two older ones stream int8
+    ``QuantizedPages`` at half the bytes with score-space scales; the
+    streaming kernel refuses them by name and an engine with int8 pages
+    resolves to the gather."""
     if impl.startswith("pallas"):
-        _require_split(k_pages, q.shape[-1], impl)
+        _require_form(k_pages, q.shape[-1], impl, _tp(mesh))
         interpret = pallas_interpret()
-        if mesh is not None and mesh.shape.get("tp", 1) > 1:
+        if _tp(mesh) > 1:
             return paged_ragged_attention_pallas_tp(
                 q, k_pages, v_pages, page_table, start, q_lens, mesh,
                 layer=layer, impl=impl, interpret=interpret,

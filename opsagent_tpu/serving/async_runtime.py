@@ -425,6 +425,7 @@ class AsyncMixedRuntime:
                     ov_fsm[lane] = _walk(fsm, seq.tokens)
 
         perf = get_perf_stats()
+        eng._record_attn_pages(starts, qlens)
         ticket = eng.step_clock.enqueue()
         tick_id, t_disp = ticket
         if eng._mixed_gap_stamp is not None:

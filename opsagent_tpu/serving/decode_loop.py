@@ -26,6 +26,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..models import llama
 from ..models.config import ModelConfig
@@ -66,6 +67,23 @@ def record_dispatch(
             buckets=(1, 2, 4, 8, 16, 32, 64, 128),
         ).observe(rows)
     _record_attr(kind, attr, attr_kw)
+
+
+def record_attn_pages(
+    starts, q_lens, page_size: int, table_width: int
+) -> None:
+    """The live share of the page tables' capacity, a scrape away: what
+    an attention pass over these rows streams (``ceil((start + q_len) /
+    page_size)`` pages a row with a query, none for a row without)
+    against what the table could hold (every row x ``table_width``).
+    From the plan's own arrays, ``[rows]`` or, for the passes of a fused
+    block, ``[passes, rows]``."""
+    from .. import obs
+
+    starts, q_lens = np.asarray(starts), np.asarray(q_lens)
+    live = np.where(q_lens > 0, -(-(starts + q_lens) // page_size), 0)
+    obs.ATTN_PAGES_STREAMED.inc(int(live.sum()))
+    obs.ATTN_PAGES_CAPACITY.inc(starts.size * table_width)
 
 
 def record_mixed_dispatch(
@@ -424,6 +442,8 @@ def speculative_block_carry(
     k: int,                 # draft tokens per iteration
     ngram: int = 2,
     dtype: jnp.dtype = jnp.bfloat16,
+    attn_impl: str = "xla",
+    mesh=None,
 ) -> tuple[jax.Array, jax.Array, Any, tuple]:
     """GREEDY decode with prompt-lookup speculation, device-resident like
     ``decode_block_carry``: each scan step drafts k tokens from the row's
@@ -453,7 +473,8 @@ def speculative_block_carry(
             inputs = jnp.concatenate([tok[:, None], draft], axis=1)  # [B, k+1]
             valid = jnp.where(act, jnp.minimum(k + 1, rem), 0)
         logits, cache = llama.verify_step(
-            params, cfg, inputs, at, valid, cache, page_table, dtype=dtype
+            params, cfg, inputs, at, valid, cache, page_table, dtype=dtype,
+            attn_impl=attn_impl, mesh=mesh,
         )
         with jax.named_scope("sample"):    # accept
             a = jnp.argmax(logits, axis=-1).astype(jnp.int32)      # [B, k+1]

@@ -411,8 +411,11 @@ class Engine:
         self._mesh_tls = threading.local()
 
         # Execution backends, resolved ONCE here, before anything is built
-        # on the device. Both are explicit requests (config field / env
-        # knob, default xla): one that cannot be honoured is an error
+        # on the device. The attention reader is the code's own choice
+        # from the platform and the model's shapes
+        # (ops.attention.paged_attention_backend); the weight stream is an
+        # explicit request (config field / env knob, default xla). A
+        # backend asked for BY NAME that cannot be honoured is an error
         # with the reason (BackendRefused), never a quiet xla run under
         # the kernel's name. impl_info() reports what runs.
         from ..ops.attention import (
@@ -440,18 +443,23 @@ class Engine:
                 f"weight_stream=pallas-dma is single-shard only (tp={tp})"
             )
         self.weight_stream_impl = ws
-        self.attn_impl = paged_attention_backend()
-        if self.attn_impl != "xla" and not pallas_interpret():
-            why = pallas_refusal(
-                self.attn_impl,
-                head_dim=self.model_cfg.head_dim_,
-                kv_heads_per_shard=self.model_cfg.num_kv_heads // tp,
-                page_itemsize=(
-                    1 if cfg.kv_quantize
-                    else jnp.dtype(cfg.dtype).itemsize
-                ),
-                mla=self.model_cfg.mla is not None,
-            )
+        shapes = dict(
+            head_dim=self.model_cfg.head_dim_,
+            kv_heads_per_shard=max(1, self.model_cfg.num_kv_heads // tp),
+            page_itemsize=(
+                1 if cfg.kv_quantize else jnp.dtype(cfg.dtype).itemsize
+            ),
+            mla=self.model_cfg.mla is not None,
+        )
+        self.attn_impl = paged_attention_backend(
+            platform=self.mesh.devices.flat[0].platform, **shapes
+        )
+        # Interpret mode (the CPU tests) has no Mosaic and none of its
+        # tiling limits; pages the kernel has no reader for (int8 under
+        # pallas-stream) stay refused there too.
+        stream_int8 = self.attn_impl == "pallas-stream" and cfg.kv_quantize
+        if self.attn_impl != "xla" and (stream_int8 or not pallas_interpret()):
+            why = pallas_refusal(self.attn_impl, **shapes)
             if why:
                 raise BackendRefused(why)
         log.info(
@@ -469,8 +477,6 @@ class Engine:
             why = "a model with linear-attention layers (recurrent state)"
             refused = {
                 f"tp={tp}": tp > 1,
-                f"the {self.attn_impl} attention backend":
-                    self.attn_impl != "xla",
                 "weight_stream=pallas-dma": ws == "pallas-dma",
                 f"speculative_k={cfg.speculative_k} (verify_step cannot "
                 "take a rejected draft back out of the state)":
@@ -690,7 +696,8 @@ class Engine:
 
         def _prefill_prefix(params, tokens, start, lengths, cache, table):
             return llama.prefill_with_prefix(
-                params, mc, tokens, start, lengths, cache, table, dtype=dt
+                params, mc, tokens, start, lengths, cache, table, dtype=dt,
+                attn_impl=self.attn_impl, mesh=self.mesh,
             )
 
         def _decode_sample(
@@ -856,6 +863,8 @@ class Engine:
                 k=cfg.speculative_k,
                 ngram=cfg.speculative_ngram,
                 dtype=dt,
+                attn_impl=self.attn_impl,
+                mesh=self.mesh,
             )
 
         self._spec_pipeline_jit = jax.jit(
@@ -976,6 +985,13 @@ class Engine:
                 yield
         finally:
             self._mesh_tls.active = False
+
+    def _record_attn_pages(self, starts, q_lens) -> None:
+        from .decode_loop import record_attn_pages
+
+        record_attn_pages(
+            starts, q_lens, self.cfg.page_size, self.alloc.table_width
+        )
 
     def impl_info(self) -> dict[str, Any]:
         """What actually runs: the device JAX put this engine on, the
@@ -1811,6 +1827,7 @@ class Engine:
                     starts[i] = d
                     lens[i] = c
                     tables[i] = self._pass_row(sid, d, c)
+                self._record_attn_pages(starts, lens)
                 ticket = self.step_clock.enqueue()
                 with obs.phase("dispatch", tick=ticket[0]), \
                         annotate("engine.prefill_chunk"), self.mesh_ctx():
@@ -1961,6 +1978,7 @@ class Engine:
                 with obs.phase("dispatch", tick=ticket[0]), \
                         annotate("engine.prefill_chunk"), self.mesh_ctx():
                     if done:
+                        self._record_attn_pages([done], [chunk])
                         logits, self.cache = self._prefill_prefix_jit(
                             self.params,
                             jnp.asarray(tokens),
@@ -2162,6 +2180,7 @@ class Engine:
             slots += [None] * (B - len(slots))
             temps, top_k, top_p, _ = self._sampling_arrays(slots, B)
             perf = get_perf_stats()
+            self._record_attn_pages(starts, qlens)
             ticket = self.step_clock.enqueue()
             tick_id, t_disp = ticket
             # Dispatch-to-dispatch interval (the async A/B's comparison
@@ -2437,6 +2456,7 @@ class Engine:
             zb = jnp.zeros((B,), bool)
             zi = jnp.zeros((B,), jnp.int32)
             perf = get_perf_stats()
+            self._record_attn_pages(starts, qlens)
             ticket = self.step_clock.enqueue()
             tick_id, t_disp = ticket
             try:
@@ -3145,6 +3165,7 @@ class Engine:
             bias = self._bias_array(slots, B)
             want_lp = any(s.params.logprobs for s in running)
             chosen_lp = top_ids = top_lps = None
+            self._record_attn_pages(write_at, active)
             t_step = time.perf_counter()
             with self.mesh_ctx():
                 # split under the mesh like warmup's, or its eager helper
@@ -3503,6 +3524,15 @@ class Engine:
                     if self._ov_hist_zeros is None:
                         self._ov_hist_zeros = jnp.zeros((B, H), jnp.int32)
                     ov_hist_dev = self._ov_hist_zeros
+            if not speculate:
+                # Pass j of the fused block: a lane with budget above j
+                # has one query at its length before the block + j.
+                steps = np.arange(self.cfg.decode_block)[:, None]
+                before = np.array([
+                    0 if sid is None else self.alloc.length(sid)
+                    for sid in lane_seqs
+                ]) - budgets
+                self._record_attn_pages(before + steps, budgets > steps)
             ticket = self.step_clock.enqueue()
             tick_id, t_disp = ticket
             with obs.phase("dispatch", tick=tick_id), \

@@ -602,3 +602,114 @@ def test_batched_prefill_isolates_bad_row():
         eng.step_block([good])
     eng.finish(good)
     assert eng.alloc.free_pages == free0
+
+
+# -- which reader of paged keys and values an engine runs ---------------------
+QWEN_7B = dict(head_dim=128, kv_heads_per_shard=4, page_itemsize=2)
+
+
+@pytest.mark.parametrize("platform,shapes,backend", [
+    ("tpu", QWEN_7B, "pallas-stream"),                       # cell 1
+    ("tpu", dict(QWEN_7B, kv_heads_per_shard=8), "pallas-stream"),  # 2 and 3
+    ("tpu", dict(QWEN_7B, kv_heads_per_shard=1), "pallas-stream"),  # 7B, tp=4
+    ("tpu", dict(QWEN_7B, page_itemsize=1), "xla"),          # int8 pages
+    ("tpu", dict(QWEN_7B, head_dim=64), "xla"),              # off the lanes
+    ("tpu", dict(QWEN_7B, head_dim=192, mla=True), "xla"),   # MLA's qk heads
+    ("tpu", dict(QWEN_7B, mla=True), "xla"),
+    ("cpu", QWEN_7B, "xla"),                                 # the tests' oracle
+    ("gpu", QWEN_7B, "xla"),
+])
+def test_the_attention_backend_is_chosen_from_platform_and_shapes(
+    monkeypatch, platform, shapes, backend
+):
+    """No knob: ``auto`` resolves from what the engine can observe where
+    it is built. The streaming kernel on a TPU wherever the chip's
+    compiler takes it, the gather everywhere else."""
+    from opsagent_tpu.ops.attention import (
+        paged_attention_backend, pallas_refusal,
+    )
+
+    monkeypatch.delenv("OPSAGENT_PAGED_BACKEND", raising=False)
+    assert paged_attention_backend(platform=platform, **shapes) == backend
+    if platform == "tpu":
+        refused = pallas_refusal("pallas-stream", **shapes)
+        assert (refused is None) == (backend == "pallas-stream")
+
+
+def test_a_backend_asked_for_by_name_wins_and_a_wrong_name_raises(monkeypatch):
+    from opsagent_tpu.ops.attention import paged_attention_backend
+
+    monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", "pallas-dma")
+    assert paged_attention_backend(platform="cpu", **QWEN_7B) == "pallas-dma"
+    monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", "auto")
+    assert paged_attention_backend(platform="cpu", **QWEN_7B) == "xla"
+    monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", "cuda")
+    with pytest.raises(ValueError, match="pallas-stream"):
+        paged_attention_backend(platform="tpu", **QWEN_7B)
+
+
+def test_an_engine_on_the_cpu_runs_the_gather_and_counts_its_pages(engine):
+    """The default on this platform, what ``impl_info`` says of it, and
+    the two counters that put the live share of the page tables'
+    capacity a scrape away."""
+    from opsagent_tpu import obs
+
+    info = engine.impl_info()
+    assert (info["platform"], info["attn_impl"]) == ("cpu", "xla")
+
+    def read():
+        return (
+            obs.ATTN_PAGES_STREAMED.value(), obs.ATTN_PAGES_CAPACITY.value()
+        )
+
+    s0, c0 = read()
+    engine.generate([[257, 5, 6, 7, 8, 9]], SamplingParams(max_tokens=6))
+    s1, c1 = read()
+    assert 0 < s1 - s0 < c1 - c0
+    # every pass counts its rows' whole tables: rows x max_pages_per_seq
+    assert (c1 - c0) % 16 == 0
+
+
+def test_a_model_with_recurrent_state_takes_the_streaming_kernel(monkeypatch):
+    """``_row_state`` strips the state-slot columns before attention sees
+    the table and the family's GQA layers are plain GQA to this op, so
+    the engine no longer refuses such a model an attention backend; the
+    kernel (interpreted here) serves what the gather serves."""
+    monkeypatch.setenv("OPSAGENT_PALLAS_INTERPRET", "1")
+    outs = {}
+    for backend in ("xla", "pallas-stream"):
+        monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", backend)
+        eng = Engine(EngineConfig(
+            model="tiny-hybrid", dtype=jnp.float32, tp=1, max_batch_size=2,
+            num_pages=16, max_pages_per_seq=8, prefill_buckets=(32,),
+            mixed_buckets=(16,), mixed_batching=True,
+        ))
+        assert eng.impl_info()["attn_impl"] == backend
+        outs[backend] = eng.generate(
+            [[257] + list(range(1, 20)), [257, 4, 4, 2]],
+            SamplingParams(max_tokens=6),
+        )
+    assert outs["xla"] == outs["pallas-stream"]
+
+
+def test_the_streaming_kernel_serves_one_kv_head_a_shard_under_tp(monkeypatch):
+    """tiny-test's two kv heads over tp=2 leave ONE a shard: the pages
+    are held split with the heads' axis sharded, and the dispatch's form
+    check counts the heads a shard holds, not the array's (the 7B over
+    tp=4). The kernel, interpreted, serves what the gather serves."""
+    monkeypatch.setenv("OPSAGENT_PALLAS_INTERPRET", "1")
+    outs = {}
+    for backend in ("xla", "pallas-stream"):
+        monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", backend)
+        eng = Engine(EngineConfig(
+            model="tiny-test", dtype=jnp.float32, tp=2, max_batch_size=2,
+            num_pages=16, max_pages_per_seq=8, prefill_buckets=(32,),
+            mixed_buckets=(16,), mixed_batching=True,
+        ))
+        info = eng.impl_info()
+        assert (info["attn_impl"], info["kv_page_form"]) == (backend, "split")
+        outs[backend] = eng.generate(
+            [[257] + list(range(1, 20)), [257, 4, 4, 2]],
+            SamplingParams(max_tokens=6),
+        )
+    assert outs["xla"] == outs["pallas-stream"]

@@ -448,3 +448,70 @@ def test_two_turns_through_the_engine_restore_the_replys_snapshot():
     eng.cache = dict(eng.cache, stats=jnp.full_like(eng.cache["stats"], 2))
     eng.sync_device_counters()
     assert obs.metrics_snapshot()[passes] - before == 5
+
+
+# -- the streaming attention kernel inside the hybrid stack (PR 29) ----------
+def _solar_attention_widths():
+    """The ``solar-open2-250b`` preset with its attention as published
+    (64 query and 8 kv heads of 128, no rope, a gated output, one GQA
+    layer a period of four) and everything attention never sees cut to
+    what a CPU test holds: one period, a toy vocabulary and hidden size,
+    4 of 8 experts, 4 linear heads."""
+    full = PRESETS["solar-open2-250b"]
+    return dataclasses.replace(
+        full, name="solar-open2-attn", num_layers=4, vocab_size=512,
+        hidden_size=128, intermediate_size=128, max_position=4096,
+        moe=dataclasses.replace(
+            full.moe, num_experts=4, router_experts=8,
+            num_experts_per_token=2, expert_intermediate_size=32),
+        linear_attn=dataclasses.replace(
+            full.linear_attn, num_heads=4, key_head_dim=32,
+            value_head_dim=32, gate_rank=16),
+    )
+
+
+def test_the_streaming_kernel_equals_the_gather_inside_the_hybrid_stack(
+        monkeypatch):
+    """A mixed step (a chunk row, a decode row, an idle row) and a decode
+    step of the solar-open2 preset's attention, the kernel interpreted:
+    logits, pages and recurrent state equal the gather path's at this
+    file's tolerance. The kernel reads merged pages, the gather at 8 kv
+    heads split ones, so the two caches hold the same bytes in two forms."""
+    monkeypatch.setenv("OPSAGENT_PALLAS_INTERPRET", "1")
+    cfg = _solar_attention_widths()
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_) == (64, 8, 128)
+    p = _randomised(
+        llama.init_params(cfg, jax.random.PRNGKey(2), jnp.float32),
+        jax.random.PRNGKey(3))
+    toks = jax.random.randint(jax.random.PRNGKey(4), (2, 64), 0, 512)
+    table = table_rows([(range(8), 1, -1), (range(8, 16), 3, -1),
+                        (range(16, 24), 5, -1)])
+    first = np.zeros((3, 32), np.int32)
+    first[0, :32] = np.asarray(toks[0, :32])
+    first[1, :20] = np.asarray(toks[1, :20])
+    second = np.zeros((3, 16), np.int32)
+    second[0, 0] = int(toks[0, 32])
+    second[1, :7] = np.asarray(toks[1, 20:27])
+    got = {}
+    for impl in ("xla", "pallas-stream"):
+        form = llama.cache_form(cfg, 1, impl)
+        assert form == ("merged" if impl == "pallas-stream" else "split")
+        cache = llama.make_cache(
+            cfg, 64, PAGE, dtype=jnp.float32, state_slots=8, form=form)
+        _, cache = llama.mixed_step(
+            p, cfg, jnp.asarray(first), jnp.zeros((3,), jnp.int32),
+            jnp.asarray([32, 20, 0]), cache, table, dtype=jnp.float32,
+            attn_impl=impl)
+        mixed, cache = llama.mixed_step(
+            p, cfg, jnp.asarray(second), jnp.asarray([32, 20, 0]),
+            jnp.asarray([1, 7, 0]), cache, table, dtype=jnp.float32,
+            attn_impl=impl)
+        decoded, cache = llama.decode_step(
+            p, cfg, jnp.asarray([int(toks[0, 33]), int(toks[1, 27]), 0]),
+            jnp.asarray([33, 27, 0]), cache, table,
+            jnp.asarray([True, True, False]), dtype=jnp.float32,
+            attn_impl=impl)
+        got[impl] = (mixed[:2], decoded[:2], cache["state"],
+                     cache["k"].reshape(-1), cache["v"].reshape(-1))
+    for a, b in zip(got["xla"], got["pallas-stream"]):
+        assert float(jnp.max(jnp.abs(a - b))) < TOL

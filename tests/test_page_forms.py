@@ -48,6 +48,9 @@ LAYERS, LAYER = 3, 1       # the layer-stacked form, and the layer written
     (16, "xla", "split"),
     (4, "pallas", "split"),     # the kernels index [.., P, K, D] blocks
     (4, "pallas-dma", "split"),
+    (4, "pallas-stream", "merged"),   # a kv head is a lane slice of the row
+    (8, "pallas-stream", "merged"),   # at any head count: nothing is gathered
+    (1, "pallas-stream", "split"),    # one head: the same bytes, a unit axis
 ])
 def test_page_form_by_kv_heads_and_backend(kv_heads, impl, form):
     assert page_form(kv_heads, impl) == form

@@ -792,3 +792,235 @@ def test_ragged_dma_at_bench_8b_mixed_shape():
                 np.asarray(ref, np.float32)[b, :n],
                 rtol=3e-2, atol=3e-2,
             )
+
+
+# -- the streaming kernel ("pallas-stream", ops/paged_attention_stream.py) ---
+# Interpreted on the CPU against the gather, at the page size and head dim
+# the chip runs (16 slots, 128 lanes) and key blocks of 2-4 pages, so that
+# a row crosses several blocks and the unmasked and the masked loops run.
+SP, SD, SMAXP = 16, 128, 12
+
+
+def _stream_case(rng, S, K, G, start, q_lens, dtype=jnp.float32, layers=0):
+    """Pages in the form ``page_form(K, "pallas-stream")`` holds them in:
+    merged ``[N, P, K*D]`` above one kv head, ``[N, P, 1, D]`` at one."""
+    B = len(start)
+    n = B * SMAXP + 3
+    q = jnp.asarray(rng.standard_normal((B, S, K * G, SD)), dtype)
+    shape = (n, SP, K * SD) if K > 1 else (n, SP, 1, SD)
+    if layers:
+        shape = (layers, *shape)
+    k_pages = jnp.asarray(rng.standard_normal(shape), dtype)
+    v_pages = jnp.asarray(rng.standard_normal(shape), dtype)
+    table = np.full((B, SMAXP), -1, np.int32)
+    free = list(range(n))
+    rng.shuffle(free)
+    for b in range(B):
+        for i in range(-(-(start[b] + q_lens[b]) // SP)):
+            table[b, i] = free.pop()
+    return (
+        q, k_pages, v_pages, jnp.asarray(table),
+        jnp.asarray(start, jnp.int32), jnp.asarray(q_lens, jnp.int32),
+    )
+
+
+def _assert_live_rows_match(got, ref, q_lens, tol=2e-5):
+    got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+    assert not np.isnan(got).any()
+    for b, n in enumerate(q_lens):
+        np.testing.assert_allclose(got[b, :n], ref[b, :n], rtol=tol, atol=tol)
+        # Query slots past a row's q_len in a block the kernel skipped or
+        # cut short come back as zeros, never as what VMEM held.
+        assert np.isfinite(got[b, n:]).all()
+
+
+@pytest.mark.parametrize("S", [1, 16, 32, 64])
+@pytest.mark.parametrize("K,G", [(1, 7), (4, 7), (8, 8)])
+def test_stream_matches_oracle_with_rows_of_every_kind(K, G, S):
+    """One batch holds an inactive row, a decode row, a whole chunk and a
+    part chunk, at 1, 4 (merged, the 7B's) and 8 kv heads."""
+    from opsagent_tpu.ops.attention import paged_ragged_attention
+    from opsagent_tpu.ops.paged_attention_stream import (
+        paged_ragged_attention_stream,
+    )
+
+    rng = np.random.default_rng(100 + S + K)
+    part = max(1, S // 2 - 1)
+    start, q_lens = [40, 37, 64, 5], [0, 1, S, part]
+    args = _stream_case(rng, S, K, G, start, q_lens)
+    ref = paged_ragged_attention(*args)
+    got = paged_ragged_attention_stream(*args, interpret=True, block_pages=2)
+    _assert_live_rows_match(got, ref, q_lens)
+    np.testing.assert_array_equal(np.asarray(got)[0], 0.0)
+
+
+@pytest.mark.parametrize(
+    "total", [15, 16, 17, 63, 64, 65, 127, 128, SMAXP * SP],
+    ids=lambda t: f"len{t}",
+)
+def test_stream_lengths_on_and_off_page_and_key_block_boundaries(total):
+    """A decode row and a chunk row whose last key sits just below, on and
+    just above a page (16) and a key-block (64) boundary, and one that
+    fills the page table to its last slot."""
+    from opsagent_tpu.ops.attention import paged_ragged_attention
+    from opsagent_tpu.ops.paged_attention_stream import (
+        paged_ragged_attention_stream,
+    )
+
+    rng = np.random.default_rng(total)
+    S = 8
+    chunk = min(S, total)
+    start, q_lens = [total - 1, total - chunk], [1, chunk]
+    args = _stream_case(rng, S, 4, 2, start, q_lens)
+    ref = paged_ragged_attention(*args)
+    got = paged_ragged_attention_stream(*args, interpret=True, block_pages=4)
+    _assert_live_rows_match(got, ref, q_lens)
+
+
+@pytest.mark.parametrize("S", [128, 160])
+def test_stream_walks_query_blocks_of_an_admission_chunk(S):
+    """``paged_prefix_attention`` at a prefill bucket: more query slots
+    than a block holds (160: and not a whole number of blocks), so the
+    grid walks query blocks, each streaming the keys below its own last
+    query."""
+    from opsagent_tpu.ops.attention import paged_ragged_attention
+    from opsagent_tpu.ops.paged_attention_stream import (
+        QUERY_BLOCK_TOKENS, paged_ragged_attention_stream,
+    )
+
+    assert S > QUERY_BLOCK_TOKENS
+    rng = np.random.default_rng(S)
+    room = SMAXP * SP - S
+    start, q_lens = [room, 0, 3], [S, S - 70, 1]
+    args = _stream_case(rng, S, 4, 2, start, q_lens)
+    ref = paged_ragged_attention(*args)
+    got = paged_ragged_attention_stream(*args, interpret=True)
+    _assert_live_rows_match(got, ref, q_lens)
+
+
+def test_stream_layer_axis_form():
+    """The layer-stacked cache the engine threads through its scan: the
+    scalar-prefetched base offsets every page lookup into the layer."""
+    from opsagent_tpu.ops.attention import paged_ragged_attention
+    from opsagent_tpu.ops.paged_attention_stream import (
+        paged_ragged_attention_stream,
+    )
+
+    rng = np.random.default_rng(7)
+    start, q_lens = [33, 0], [1, 16]
+    args = _stream_case(rng, 16, 4, 2, start, q_lens, layers=3)
+    for layer in (0, 2):
+        ref = paged_ragged_attention(*args, layer=jnp.int32(layer))
+        got = paged_ragged_attention_stream(
+            *args, layer=jnp.int32(layer), interpret=True, block_pages=2
+        )
+        _assert_live_rows_match(got, ref, q_lens)
+
+
+def test_stream_bf16_is_the_oracles_arithmetic():
+    """bf16 operands with f32 accumulation and an f32 softmax, the
+    probabilities cast to bf16 before the second dot: what
+    ``_ragged_attention_block`` does, so the two agree to bf16's last
+    place over a few hundred keys, not to a looser f32-dot tolerance."""
+    from opsagent_tpu.ops.attention import paged_ragged_attention
+    from opsagent_tpu.ops.paged_attention_stream import (
+        paged_ragged_attention_stream,
+    )
+
+    rng = np.random.default_rng(11)
+    start, q_lens = [150, 100], [1, 32]
+    args = _stream_case(rng, 32, 4, 7, start, q_lens, dtype=jnp.bfloat16)
+    ref = paged_ragged_attention(*args)
+    got = paged_ragged_attention_stream(*args, interpret=True, block_pages=4)
+    assert got.dtype == jnp.bfloat16
+    _assert_live_rows_match(got, ref, q_lens, tol=1.6e-2)
+
+
+def test_stream_decode_form_matches_the_decode_oracle():
+    from opsagent_tpu.ops.paged_attention_stream import (
+        paged_decode_attention_stream,
+    )
+
+    rng = np.random.default_rng(13)
+    lengths = [1, 16, 77, 0, SMAXP * SP]
+    q, kp, vp, table, _, _ = _stream_case(
+        rng, 1, 8, 8, [n - 1 if n else 0 for n in lengths],
+        [1 if n else 0 for n in lengths],
+    )
+    lens = jnp.asarray(lengths, jnp.int32)
+    ref = paged_decode_attention(q[:, 0], kp, vp, table, lens)
+    got = paged_decode_attention_stream(
+        q[:, 0], kp, vp, table, lens, interpret=True
+    )
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(
+        np.asarray(got)[live], np.asarray(ref)[live], rtol=2e-5, atol=2e-5
+    )
+    np.testing.assert_array_equal(np.asarray(got)[~live], 0.0)
+
+
+def test_stream_refuses_int8_pages_by_name():
+    """No cell holds int8 pages; the kernel has no reader for them (a
+    16-token page is half an int8 tile) and says so, and the choice
+    function sends such an engine to the gather."""
+    from opsagent_tpu.ops.attention import (
+        QuantizedPages, paged_attention_backend, paged_ragged_attention_auto,
+        pallas_refusal,
+    )
+
+    q = jnp.zeros((1, 1, 8, SD), jnp.float32)
+    pages = QuantizedPages(
+        jnp.zeros((4, SP, 4 * SD), jnp.int8),
+        jnp.ones((4, SP, 4), jnp.float32),
+    )
+    table = jnp.zeros((1, 2), jnp.int32)
+    one = jnp.ones((1,), jnp.int32)
+    with pytest.raises(ValueError, match="int8 pages"):
+        paged_ragged_attention_auto(
+            q, pages, pages, table, one, one, impl="pallas-stream"
+        )
+    shapes = dict(head_dim=128, kv_heads_per_shard=4)
+    assert "int8 pages" in pallas_refusal(
+        "pallas-stream", page_itemsize=1, **shapes
+    )
+    assert pallas_refusal("pallas-stream", page_itemsize=2, **shapes) is None
+    assert paged_attention_backend(
+        platform="tpu", page_itemsize=1, **shapes
+    ) == "xla"
+
+
+def test_stream_refuses_split_pages():
+    from opsagent_tpu.ops.attention import paged_ragged_attention_auto
+
+    q = jnp.zeros((1, 1, 8, SD), jnp.float32)
+    pages = jnp.zeros((4, SP, 4, SD), jnp.float32)
+    table = jnp.zeros((1, 2), jnp.int32)
+    one = jnp.ones((1,), jnp.int32)
+    with pytest.raises(ValueError, match="split pages"):
+        paged_ragged_attention_auto(
+            q, pages, pages, table, one, one, impl="pallas-stream"
+        )
+
+
+@pytest.mark.parametrize("tp,K", [(2, 4), (4, 4)])
+def test_stream_under_tp_matches_oracle(tp, K):
+    """Through the shard_map wrapper: a shard of merged pages is whole
+    heads (a contiguous run of lanes), and at one head a shard the held
+    form is split with a unit axis, which the kernel reads as it is."""
+    from opsagent_tpu.ops.attention import (
+        page_form, paged_ragged_attention, paged_ragged_attention_pallas_tp,
+    )
+    from opsagent_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(tp=tp, dp=1, sp=1, devices=jax.devices()[:tp])
+    rng = np.random.default_rng(50 + tp)
+    start, q_lens = [70, 0], [1, 16]
+    q, kp, vp, table, st, ql = _stream_case(rng, 16, K, 2, start, q_lens)
+    if page_form(K // tp, "pallas-stream") == "split":
+        kp = kp.reshape(*kp.shape[:-1], K, SD)
+        vp = vp.reshape(*vp.shape[:-1], K, SD)
+    ref = paged_ragged_attention(q, kp, vp, table, st, ql)
+    got = paged_ragged_attention_pallas_tp(
+        q, kp, vp, table, st, ql, mesh, interpret=True, impl="pallas-stream",
+    )
+    _assert_live_rows_match(got, ref, q_lens)

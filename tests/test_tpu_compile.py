@@ -131,6 +131,57 @@ def test_paged_attention_kernels_compile(v5e, kernel, kv, s):
     assert _attention(_one_chip(v5e), kernel, kv, s) is not None
 
 
+# -- the streaming kernel at the benchmark cells' own engine shapes -----------
+# (benchmarks/configs/*.json: rows, mixed and prefill buckets, heads, pages a
+# sequence, pages; layers as many as the cell's cache stacks.)
+STREAM_CELLS = {
+    "qwen25-7b.agent-turns": dict(
+        b=32, h=28, k=4, maxp=384, n=2560, layers=28, s=(1, 16, 32, 256)),
+    "qwen25-72b-l8.long-generate": dict(
+        b=16, h=64, k=8, maxp=104, n=2048, layers=8, s=(1, 16, 32, 64, 256)),
+    "solar-open2-ep8-l8.doc-turns": dict(
+        b=32, h=64, k=8, maxp=512, n=12288, layers=2, s=(1, 16, 256)),
+}
+
+
+def _stream(sds, *, b, s, h, k, maxp, n, layers, d=D):
+    """Compile ``paged_ragged_attention_auto`` under "pallas-stream" over a
+    layer-stacked cache in the form ``page_form`` holds for it."""
+    merged = attention.page_form(k, "pallas-stream") == "merged"
+    pages = sds(
+        (layers, n, PAGE, k * d) if merged else (layers, n, PAGE, k, d),
+        jnp.bfloat16,
+    )
+    return _compile(
+        lambda q, k_, v_, t, st, ql, ly: attention.paged_ragged_attention_auto(
+            q, k_, v_, t, st, ql, impl="pallas-stream", layer=ly),
+        sds((b, s, h, d), jnp.bfloat16), pages, pages,
+        sds((b, maxp), jnp.int32), sds((b,), jnp.int32),
+        sds((b,), jnp.int32), sds((), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize(
+    "cell,s",
+    [(cell, s) for cell, shape in STREAM_CELLS.items() for s in shape["s"]],
+)
+def test_stream_kernel_compiles_at_the_cells_shapes(v5e, cell, s):
+    """Decode rows (the fused blocks' S = 1), every mixed bucket and the
+    prefill bucket of each cell, at 4 kv heads (merged pages) and at 8."""
+    shape = {k: v for k, v in STREAM_CELLS[cell].items() if k != "s"}
+    compiled = _stream(_one_chip(v5e), s=s, **shape)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_stream_kernel_compiles_at_one_kv_head(v5e):
+    """One kv head unsharded (split pages with a unit axis, which the
+    wrapper drops): the kernel compiles, and dropping the axis copies no
+    cache."""
+    compiled = _stream(
+        _one_chip(v5e), b=8, s=16, h=7, k=1, maxp=MAXP, n=N, layers=L)
+    assert _copies_of(compiled.as_text(), N * PAGE * D) == []
+
+
 # -- quantized matmul: weight dtype x projection x rows ----------------------
 SHAPES = {
     "qkv": (CFG.hidden_size, (H + 2 * K) * D),
@@ -225,6 +276,42 @@ def test_grid_kernel_compiles_under_tp4(v5e, form):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("tp", [2, 4])
+def test_stream_kernel_compiles_under_tp(v5e, tp):
+    """The streaming kernel through the tp shard_map wrapper at the 7B's
+    heads, by the engine's own dispatch (the form check counts the kv
+    heads a SHARD holds): two shards of two kv heads each read merged
+    pages (a shard's heads are contiguous lanes), four shards of ONE hold
+    split pages with a unit axis; no shard copies its cache."""
+    mesh = Mesh(np.array(v5e[:tp]).reshape(tp), ("tp",))
+
+    def sds(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, spec)
+        )
+
+    merged = attention.page_form(K // tp, "pallas-stream") == "merged"
+    pages = (
+        sds((L, N, PAGE, K * D), jnp.bfloat16, P(None, None, None, "tp"))
+        if merged else
+        sds((L, N, PAGE, K, D), jnp.bfloat16, P(None, None, None, "tp", None))
+    )
+    table, rows = sds((B, MAXP), jnp.int32), sds((B,), jnp.int32)
+    compiled = _compile(
+        lambda q, k_, v_, t, st, ql, ly: (
+            attention.paged_ragged_attention_auto(
+                q, k_, v_, t, st, ql, impl="pallas-stream", layer=ly,
+                mesh=mesh,
+            )
+        ),
+        sds((B, 32, H, D), jnp.bfloat16, P(None, None, "tp", None)),
+        pages, pages, table, rows, rows, sds((), jnp.int32),
+    )
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert _copies_of(hlo, L * N * PAGE * K * D // tp) == []
+
+
 # -- what the compiler refuses, the engine refuses first ---------------------
 REFUSALS = [
     # (id, model, tp, kv_quantize, kernel shapes (k, d, kv), Mosaic's words)
@@ -287,13 +374,71 @@ def test_manual_dma_refusals_pinned_from_both_sides(
     ) is None
 
 
+STREAM_REFUSALS = [
+    # (id, model, kv_quantize, kernel shapes (k, d, kv), the words)
+    ("head-dim-64", "bench-1b", "", (8, 64, "bf16"), "128-lane tiling"),
+    ("int8-pages", "qwen2.5-7b-instruct", "int8", (4, 128, "int8"),
+     "int8 pages"),
+]
+
+
+@pytest.mark.parametrize(
+    "model,kvq,shapes,words",
+    [r[1:] for r in STREAM_REFUSALS], ids=[r[0] for r in STREAM_REFUSALS],
+)
+def test_stream_refusals_pinned_from_both_sides(
+    v5e, monkeypatch, model, kvq, shapes, words
+):
+    """The two rules ``pallas_refusal`` has for "pallas-stream": a head
+    dim off the 128 lanes (a kv head is a lane slice of the merged row)
+    and int8 pages (no reader). The dispatcher refuses each with the
+    words of the rule, ``auto`` sends such an engine to the gather on a
+    TPU, and an engine asked for the kernel BY NAME refuses at init; the
+    aligned bf16 neighbours are the compiling cases above."""
+    from opsagent_tpu.ops.attention import paged_attention_backend
+    from opsagent_tpu.serving.engine import (
+        BackendRefused, Engine, EngineConfig,
+    )
+
+    k, d, kv = shapes
+    sds = _one_chip(v5e)
+    pages = sds((L, N, PAGE, k * d), jnp.int8 if kv == "int8" else jnp.bfloat16)
+    if kv == "int8":
+        pages = QuantizedPages(pages, sds((L, N, PAGE, k), jnp.float32))
+    table, rows = sds((B, MAXP), jnp.int32), sds((B,), jnp.int32)
+    with pytest.raises(ValueError, match=words):
+        _compile(
+            lambda q, k_, v_, t, st, ql: attention.paged_ragged_attention_auto(
+                q, k_, v_, t, st, ql, impl="pallas-stream"),
+            sds((B, 16, k * 7, d), jnp.bfloat16), pages, pages, table, rows,
+            rows,
+        )
+    cfg = get_config_preset(model)
+    assert (cfg.num_kv_heads, cfg.head_dim_) == (k, d)
+    rule = dict(
+        head_dim=d, kv_heads_per_shard=k, page_itemsize=1 if kvq else 2
+    )
+    why = pallas_refusal("pallas-stream", **rule)
+    assert why is not None and words in why
+    monkeypatch.delenv("OPSAGENT_PAGED_BACKEND", raising=False)
+    assert paged_attention_backend(platform="tpu", **rule) == "xla"
+    monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", "pallas-stream")
+    monkeypatch.delenv("OPSAGENT_PALLAS_INTERPRET", raising=False)
+    with pytest.raises(BackendRefused) as refused:
+        Engine(EngineConfig(model=model, kv_quantize=kvq, quantize="int8"))
+    assert str(refused.value) == why
+    assert pallas_refusal(
+        "pallas-stream", head_dim=128, kv_heads_per_shard=k, page_itemsize=2
+    ) is None
+
+
 def test_mla_refuses_the_pallas_backends(monkeypatch):
     from opsagent_tpu.serving.engine import (
         BackendRefused, Engine, EngineConfig,
     )
 
     monkeypatch.delenv("OPSAGENT_PALLAS_INTERPRET", raising=False)
-    for impl in ("pallas", "pallas-dma"):
+    for impl in ("pallas", "pallas-dma", "pallas-stream"):
         monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", impl)
         with pytest.raises(BackendRefused, match="MLA"):
             Engine(EngineConfig(model="tiny-mla"))
@@ -335,10 +480,11 @@ def _copies_of(hlo: str, elements: int) -> list[str]:
     return out
 
 
-def _mixed_step(sds, preset: str, kv: str):
+def _mixed_step(sds, preset: str, kv: str, impl: str = "xla"):
     """Compile the engine's ``_mixed_carry`` program (decode_loop.
     mixed_step_carry, the cache donated) at a preset's widths cut to two
-    layers, with the cache ``llama.make_cache`` gives it."""
+    layers, with the cache ``llama.make_cache`` gives it for the
+    attention backend ``impl``."""
     from opsagent_tpu.models import llama
     from opsagent_tpu.serving import decode_loop
 
@@ -353,7 +499,10 @@ def _mixed_step(sds, preset: str, kv: str):
         lambda: llama.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)
     ))
     cache = on_chip(jax.eval_shape(
-        lambda: llama.make_cache(cfg, n, PAGE, jnp.bfloat16, kv_quantize=kv)
+        lambda: llama.make_cache(
+            cfg, n, PAGE, jnp.bfloat16, kv_quantize=kv,
+            form=llama.cache_form(cfg, 1, impl),
+        )
     ))
     key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     b = STEP_ROWS
@@ -365,7 +514,7 @@ def _mixed_step(sds, preset: str, kv: str):
              table, key, temps, top_k, top_p):
         return decode_loop.mixed_step_carry(
             params, cfg, tokens, use_carry, carry, starts, qlens, emits,
-            cache, table, key, temps, top_k, top_p,
+            cache, table, key, temps, top_k, top_p, attn_impl=impl,
         )
 
     compiled = jax.jit(step, donate_argnames=("cache",)).lower(
@@ -373,24 +522,31 @@ def _mixed_step(sds, preset: str, kv: str):
         flag(b), cache, i32(b, maxp), key, f32(b), i32(b), f32(b),
     ).compile()
     whole = STEP_LAYERS * n * PAGE * cfg.num_kv_heads * cfg.head_dim_
-    return cfg, cache, _copies_of(compiled.as_text(), whole)
+    hlo = compiled.as_text()
+    assert ("tpu_custom_call" in hlo) == (impl != "xla")
+    return cfg, cache, _copies_of(hlo, whole)
 
 
-@pytest.mark.parametrize("preset,kv,form", [
-    ("qwen2.5-7b-instruct", "", "merged"),       # cell 1: 4 kv heads
-    ("qwen2.5-7b-instruct", "int8", "merged"),
-    ("qwen2.5-72b-instruct", "", "split"),       # cell 2's widths: 8
+@pytest.mark.parametrize("preset,kv,impl,form", [
+    ("qwen2.5-7b-instruct", "", "xla", "merged"),    # cell 1: 4 kv heads
+    ("qwen2.5-7b-instruct", "int8", "xla", "merged"),
+    ("qwen2.5-72b-instruct", "", "xla", "split"),    # cell 2's widths: 8
+    # What the chip runs since PR 29: the kernel reads merged pages at any
+    # head count, and the page write's scatter runs in the same tiling.
+    ("qwen2.5-7b-instruct", "", "pallas-stream", "merged"),
+    ("qwen2.5-72b-instruct", "", "pallas-stream", "merged"),
 ])
-def test_no_step_copies_a_whole_k_or_v_array(v5e, preset, kv, form):
+def test_no_step_copies_a_whole_k_or_v_array(v5e, preset, kv, impl, form):
     """The mixed step holds no copy as large as one layer-stacked K array,
     in the layer loop or outside it: the page write's scatter and the page
-    gather run in the tiling the pages are held in. At 4 kv heads that is
-    the merged form, at 8 the split one (where merged would add a copy of
-    each gathered block)."""
+    reader run in the tiling the pages are held in. Under the gather that
+    is the merged form at 4 kv heads and the split one at 8 (where merged
+    would add a copy of each gathered block); the streaming kernel gathers
+    nothing and holds merged pages at both."""
     from opsagent_tpu.models import llama
 
-    cfg, cache, copies = _mixed_step(_one_chip(v5e), preset, kv)
-    assert llama.cache_form(cfg) == form
+    cfg, cache, copies = _mixed_step(_one_chip(v5e), preset, kv, impl)
+    assert llama.cache_form(cfg, 1, impl) == form
     n = GEOMETRY[preset][0]
     k, d = cfg.num_kv_heads, cfg.head_dim_
     row = (k * d,) if form == "merged" else (k, d)
@@ -448,3 +604,46 @@ def test_split_pages_at_four_kv_heads_are_copied_whole_in_every_layer(v5e):
     assert len(copies) == 2, copies     # all of K, and all of V
     body = hlo[: hlo.index("\nENTRY ")]
     assert all(c.split("[")[0] + " = " in body for c in copies)
+
+
+def test_stream_kernel_is_exported_once_a_shape(v5e, tmp_path, monkeypatch):
+    """A second program holding the kernel at the same shape inlines the
+    exported bytes (no second trace of the kernel's body); the bytes lie
+    beside JAX's compile cache, and a new process (here: every in-process
+    cache dropped) reads them back instead of tracing, for as long as the
+    file is there."""
+    from opsagent_tpu.ops import paged_attention_stream as stream
+
+    before = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    traced = []
+    kernel = stream._kernel
+    monkeypatch.setattr(
+        stream, "_kernel", lambda *a, **kw: traced.append(1) or kernel(*a, **kw)
+    )
+
+    def new_process():
+        stream._kernel_call.cache_clear()
+        stream.paged_ragged_attention_stream.clear_cache()
+
+    def compiled():
+        return _stream(
+            _one_chip(v5e), b=4, s=16, h=14, k=2, maxp=MAXP, n=N, layers=L
+        ).as_text()
+
+    try:
+        new_process()
+        assert "tpu_custom_call" in compiled() and len(traced) == 1
+        files = [f for f in os.listdir(tmp_path) if f.endswith(".export")]
+        assert len(files) == 1
+        compiled()                      # another program, the same shape
+        assert len(traced) == 1
+        new_process()
+        assert "tpu_custom_call" in compiled() and len(traced) == 1
+        os.remove(tmp_path / files[0])
+        new_process()
+        compiled()
+        assert len(traced) == 2
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        new_process()
