@@ -25,9 +25,8 @@ both phases plus an identical-output check), the sessions-offload A/B
 the fleet-affinity A/B (two engine replicas behind the fleet router:
 prefix-affinity + sticky placement vs stateless least-loaded, reporting
 re-prefill-avoided tokens and p50 TTFT per phase),
-the agent-turns stage (north-star p50 TTFT per tool-call turn), the
-pallas-dma kernel comparison (plain and kv-int8), a cold-restart TTFT
-probe against the stage-1-primed compilation cache, and last a
+the agent-turns stage (north-star p50 TTFT per tool-call turn), a
+cold-restart TTFT probe against the stage-1-primed compilation cache, and last a
 speculative-decoding overhead run (its question is already
 measurement-closed).
 EVERY result line is printed
@@ -384,8 +383,8 @@ def run_orchestrated() -> None:
     it the whole run), then the bench-8b int8 headline and its int4,
     int8-KV, and combined int4+int8-KV variants, the BASELINE config-5
     concurrent-sessions run, the sessions-mixed A/B, the agent-turns
-    stage, the pallas-dma kernel comparisons, the cold-restart TTFT
-    probe, and the speculative-decoding overhead run
+    stage, the ragged sweep, the cold-restart TTFT probe, and the
+    speculative-decoding overhead run
     last; the later stages only start if the
     remaining budget plausibly covers them. Mode/spec env vars are
     stripped from stages
@@ -398,13 +397,11 @@ def run_orchestrated() -> None:
         return budget - (time.perf_counter() - t_start)
 
     # None-valued entries REMOVE inherited vars (see _run_child): an
-    # operator-exported spec/mode/backend var must not contaminate the
-    # stages it doesn't belong to (the pallas-dma stage is compared
-    # against stage 1's xla default).
+    # operator-exported spec/mode var must not contaminate the stages it
+    # doesn't belong to.
     base = {
         "OPSAGENT_BENCH_SPEC": None,
         "OPSAGENT_BENCH_MODE": None,
-        "OPSAGENT_PAGED_BACKEND": None,
         "OPSAGENT_BENCH_QUANT": None,
         "OPSAGENT_BENCH_KV": None,
         "OPSAGENT_BENCH_MIXED": None,
@@ -599,37 +596,11 @@ def run_orchestrated() -> None:
         {"OPSAGENT_BENCH_MODE": "agent-conveyor"},
         200, "agent-conveyor", cap=300.0,
     )
-    # Kernel comparison (PERF.md plan item 2): the manual-DMA Pallas
-    # paged-attention backend on the 8B int8 preset — the headline shape,
-    # and the one whose head_dim (128) satisfies the kernel's Mosaic
-    # alignment requirement (bench-1b's head_dim=64 cannot compile it;
-    # r04 on-chip). Value vs the r8b stage (xla gather) decides the
-    # default (ops/attention.py).
-    rdma = stage(
-        {"OPSAGENT_BENCH_MODEL": "bench-8b",
-         "OPSAGENT_PAGED_BACKEND": "pallas-dma"},
-        330, "pallas-dma",
-    ) if r8b is not None else None
-    if rdma is not None and rdma["value"] > headline["value"]:
-        headline = rdma
-    # The dma kernel also has a quantized path (int8 pages streamed, VMEM
-    # dequantize): if both parents produced numbers, measure the
-    # composition — the strongest candidate configuration when the kernel
-    # beats the gather.
-    rdmakv = stage(
-        {"OPSAGENT_BENCH_MODEL": "bench-8b",
-         "OPSAGENT_PAGED_BACKEND": "pallas-dma",
-         "OPSAGENT_BENCH_KV": "int8"},
-        330, "pallas-dma-kv",
-    ) if rdma is not None and r8bkv is not None else None
-    if rdmakv is not None and rdmakv["value"] > headline["value"]:
-        headline = rdmakv
-    # Ragged-backend sweep (ISSUE 15): the MIXED hot path (step_mixed →
-    # paged_ragged_attention_auto) timed per backend × KV dtype × weight
-    # quant on the bench-8b shape, one tok/s/chip row per cell with
-    # self-describing resolved-impl extras. The dma stages above time
-    # the legacy block-decode path; this stage times what serving
-    # actually runs. Last row is the child's best-cell summary —
+    # Ragged sweep (ISSUE 15): the MIXED hot path (step_mixed →
+    # paged_ragged_attention_auto) timed per KV dtype × weight quant ×
+    # weight stream on the bench-8b shape under the attention reader the
+    # engine chooses, one tok/s/chip row per cell with self-describing
+    # resolved-impl extras. Last row is the child's best-cell summary —
     # promote-if-faster like the int4 stage.
     sweep_rows = stage_rows(
         {"OPSAGENT_BENCH_MODE": "ragged-sweep",
@@ -807,10 +778,6 @@ def run_orchestrated() -> None:
         )
     if rspec is not None:
         extra[f"spec{SPEC_K}_overhead_tok_s_chip"] = rspec["value"]
-    if rdma is not None and headline is not rdma:
-        extra["pallas_dma_tok_s_chip"] = rdma["value"]
-    if rdmakv is not None and headline is not rdmakv:
-        extra["pallas_dma_kv_int8_tok_s_chip"] = rdmakv["value"]
     if rsweep is not None:
         se = rsweep.get("extra", {})
         if headline is not rsweep:
@@ -849,7 +816,7 @@ def run_orchestrated() -> None:
     # printed, so the verdict can never eat a result line.
     exit_if_perf_regression([
         r1, r8b, r8b4, r8bkv, r8b4kv, rsess, rsessmix, rsessasync,
-        rsessoff, rfleet, rchaos, rfgkv, ragent, rconvey, rdma, rdmakv,
+        rsessoff, rfleet, rchaos, rfgkv, ragent, rconvey,
         rcold, rcoldstart, rspec, robsh, *sweep_rows,
     ])
 
@@ -885,11 +852,6 @@ def run_single() -> None:
     # (every row carries ``extra.dtype`` via impl_info).
     dtype = jnp.bfloat16 if on_tpu else jnp.float32
 
-    # Measured on v5e: the XLA gather attention currently beats the Pallas
-    # kernel at decode shapes (the kernel's (B, MaxP) grid is overhead-bound
-    # at one page per step); pin the faster impl unless the caller chose.
-    os.environ.setdefault("OPSAGENT_PAGED_BACKEND", "xla")
-
     from opsagent_tpu.serving.engine import Engine, EngineConfig
     from opsagent_tpu.serving.sampler import SamplingParams
 
@@ -913,8 +875,8 @@ def run_single() -> None:
         run_agent_conveyor(platform, n_chips)
         return
     if mode == "ragged-sweep":
-        # Builds one engine per (backend x KV dtype x weight quant) cell
-        # with its own geometry — intercept before the shared
+        # Builds one engine per (KV dtype x weight quant x weight
+        # stream) cell with its own geometry — intercept before the shared
         # construction below.
         run_ragged_sweep(platform, n_chips, model, batch, steps,
                          prompt_len)
@@ -1283,27 +1245,25 @@ def run_cold_start(cfg, model, batch, steps, prompt_len, platform,
 
 def run_ragged_sweep(platform, n_chips, model, batch, steps,
                      prompt_len) -> None:
-    """Ragged-backend sweep (ROADMAP item 1): time the MIXED hot path —
-    sync ``step_mixed`` ticks, the program serving actually runs — across
-    attention backend x KV page dtype x weight quant x weight-stream
-    cells on one model shape, one self-describing tok/s/chip row per
-    cell. The weight-stream axis rides the xla attention backend only
-    (the double-buffered quant-matmul prefetch is orthogonal to the
-    attention kernel under test) and needs quantized weights, so it adds
-    one pallas-dma cell per quantized weight mode — plus, off-chip, the
-    int8 oracle cell that anchors its byte-identity check.
+    """Ragged sweep (ROADMAP item 1): time the MIXED hot path — sync
+    ``step_mixed`` ticks, the program serving actually runs — across
+    KV page dtype x weight quant x weight-stream cells on one model
+    shape, one self-describing tok/s/chip row per cell. Every cell runs
+    the attention reader its engine chooses
+    (``ops.attention.paged_attention_backend``; the row names it). The
+    weight-stream axis needs quantized weights, so it adds one
+    pallas-dma prefetch cell per quantized weight mode, each beside the
+    xla weight-stream cell that anchors its byte-identity check.
 
-    Each cell builds its own engine (the backend env var and quant modes
-    are engine-construction inputs), warms exactly the mixed program
-    family ("bench-mixed" level), admits ``batch`` identical greedy
-    prompts through chunked mixed admission, then times ``steps``
-    decode-only mixed ticks. Within a (weight, KV) group the xla cell is
-    the oracle: every other backend's full greedy token streams must be
+    Each cell builds its own engine (the quant modes are
+    engine-construction inputs), warms exactly the mixed program family
+    ("bench-mixed" level), admits ``batch`` identical greedy prompts
+    through chunked mixed admission, then times ``steps`` decode-only
+    mixed ticks. Within a (weight, KV) group the xla weight-stream cell
+    is the oracle: the prefetch cell's full greedy token streams must be
     byte-identical, and that verdict rides each row's extra. Off-chip
-    the Pallas cells run in interpret mode (no Mosaic on CPU), which is
-    exactly what the CI smoke exercises; on chip the rows answer the
-    r04 open question — whether streaming int8 pages through the ragged
-    DMA kernel tracks the attribution model's halved bytes floor.
+    the weight-stream kernel runs in interpret mode (no Mosaic on CPU),
+    which is exactly what the CI smoke exercises.
 
     Rows are flushed the moment they exist (driver-kill contract), and
     the LAST line is a copy of the best cell with the per-cell values
@@ -1324,15 +1284,14 @@ def run_ragged_sweep(platform, n_chips, model, batch, steps,
     ))
     t_start = time.perf_counter()
     if not on_tpu:
-        # No Mosaic off-chip: run the Pallas cells in interpret mode so
-        # the full chain (engine impl gate -> auto dispatcher -> ragged
-        # DMA kernel) still executes end to end on CPU.
+        # No Mosaic off-chip: run the weight-stream cell in interpret
+        # mode so the full chain (engine gate -> weight_stream_scope ->
+        # quant-matmul kernel) still executes end to end on CPU.
         os.environ["OPSAGENT_PALLAS_INTERPRET"] = "1"
-    backends = ("xla", "pallas", "pallas-dma")
     kv_modes = ("", "int8")
     # Off-chip cells keep fp32 weights: the question CPU answers is
     # dispatch-equivalence, not throughput, and weight quant doubles the
-    # cell count without touching the attention path under test.
+    # cell count without touching the mixed path under test.
     weight_modes = ("int8", "int4") if on_tpu else ("",)
     dtype = jnp.bfloat16 if on_tpu else jnp.float32
     steps = min(steps, 256)
@@ -1347,25 +1306,25 @@ def run_ragged_sweep(platform, n_chips, model, batch, steps,
     sampling = SamplingParams(temperature=0.0, max_tokens=10**9)
 
     cells = [
-        (wq, kv, backend, "xla", False)
-        for wq in weight_modes for kv in kv_modes for backend in backends
+        (wq, kv, "xla", False)
+        for wq in weight_modes for kv in kv_modes
     ]
     # Weight-stream axis: one pallas-dma prefetch cell per quantized
-    # weight mode (xla attention, plain KV — the weight path is the axis
-    # under test). The prefetch kernel is single-shard for now, so these
-    # cells pin tp=1 and bring their OWN tp=1 xla oracle: greedy byte
+    # weight mode (plain KV — the weight path is the axis under test).
+    # The prefetch kernel is single-shard for now, so these cells pin
+    # tp=1 and bring their OWN tp=1 xla oracle: greedy byte
     # identity is only meaningful against the same reduction layout, and
     # the baseline grid above runs on every chip.
     ws_weights = ("int8", "int4") if on_tpu else ("int8",)
     for wq in ws_weights:
-        cells.append((wq, "", "xla", "xla", True))
-        cells.append((wq, "", "xla", "pallas-dma", True))
+        cells.append((wq, "", "xla", True))
+        cells.append((wq, "", "pallas-dma", True))
     rows: list[dict] = []
     skipped: dict[str, str] = {}
     oracle: dict[tuple, list[list[int]]] = {}
     groups_ok: dict[tuple, bool] = {}
-    for wq, kv, backend, ws, single in cells:
-        label = f"{backend}/{wq or 'bf16'}/kv-{kv or 'bf16'}"
+    for wq, kv, ws, single in cells:
+        label = f"{wq or 'bf16'}/kv-{kv or 'bf16'}"
         if single:
             label += f"/ws-{ws}"
         elapsed = time.perf_counter() - t_start
@@ -1373,7 +1332,6 @@ def run_ragged_sweep(platform, n_chips, model, batch, steps,
             log(f"bench[ragged-sweep]: {elapsed:.0f}s > {budget:.0f}s "
                 f"budget; dropping {label} and later cells")
             break
-        os.environ["OPSAGENT_PAGED_BACKEND"] = backend
         cfg = EngineConfig(
             model=model,
             dtype=dtype,
@@ -1393,9 +1351,9 @@ def run_ragged_sweep(platform, n_chips, model, batch, steps,
         try:
             eng = Engine(cfg)
         except BackendRefused as e:
-            # A cell the chip's compiler refuses at this model's shapes
-            # is skipped BY NAME with the compiler's reason — it never
-            # runs as xla under the kernel's label.
+            # A weight stream the engine refuses at this model's shapes
+            # is skipped BY NAME with the reason — it never runs as xla
+            # under the kernel's label.
             skipped[label] = str(e)
             log(f"bench[ragged-sweep]: skipping cell {label}: {e}")
             continue
@@ -1433,7 +1391,7 @@ def run_ragged_sweep(platform, n_chips, model, batch, steps,
         # tp=1 weight-stream cells form their own oracle group: greedy
         # byte identity only holds within one reduction layout.
         group = (wq, kv, single)
-        if backend == "xla" and ws == "xla":
+        if ws == "xla":
             oracle[group] = outputs
             identical = True
         else:
@@ -1447,15 +1405,14 @@ def run_ragged_sweep(platform, n_chips, model, batch, steps,
         row = {
             "metric": (
                 f"mixed_ragged_throughput[{model},{wq or 'bf16'},"
-                f"kv-{kv or 'bf16'},{backend}{ws_tag},B={batch},"
-                f"{platform}]"
+                f"kv-{kv or 'bf16'},{info['attn_impl']}{ws_tag},"
+                f"B={batch},{platform}]"
             ),
             "value": round(tok_s_chip, 1),
             "unit": "tok/s/chip",
             "vs_baseline": None,
             "extra": {
                 "total_tok_s": round(tok_s, 1),
-                "requested_backend": backend,
                 "requested_weight_stream": ws,
                 **info,
                 "outputs_identical": identical,

@@ -39,7 +39,6 @@ from ..ops.attention import (
     causal_prefill_attention,
     page_form,
     paged_decode_attention_auto,
-    paged_prefix_attention,
     paged_ragged_attention_auto,
     write_kv_pages,
     write_pages,
@@ -1560,7 +1559,7 @@ def prefill_with_prefix(
             kc = write_pages(
                 kc, latent, page_table, start, valid_len=lengths, layer=li
             )
-            ctx = paged_prefix_attention(
+            ctx = paged_ragged_attention_auto(
                 q_lat, kc, kc, page_table, start, lengths, layer=li,
                 impl=attn_impl, mesh=mesh,
             )
@@ -1569,7 +1568,7 @@ def prefill_with_prefix(
         kc, vc = write_kv_pages(
             kc, vc, k, v, page_table, start, valid_len=lengths, layer=li
         )
-        attn = paged_prefix_attention(
+        attn = paged_ragged_attention_auto(
             q, kc, vc, page_table, start, lengths, layer=li,
             impl=attn_impl, mesh=mesh,
         )
@@ -1682,7 +1681,7 @@ def verify_step(
             kc = write_pages(
                 kc, latent, page_table, start, valid_len=valid, layer=li
             )
-            ctx = paged_prefix_attention(
+            ctx = paged_ragged_attention_auto(
                 q_lat, kc, kc, page_table, start, valid, layer=li,
                 impl=attn_impl, mesh=mesh,
             )
@@ -1691,7 +1690,7 @@ def verify_step(
         kc, vc = write_kv_pages(
             kc, vc, k, v, page_table, start, valid_len=valid, layer=li
         )
-        attn = paged_prefix_attention(
+        attn = paged_ragged_attention_auto(
             q, kc, vc, page_table, start, valid, layer=li,
             impl=attn_impl, mesh=mesh,
         )

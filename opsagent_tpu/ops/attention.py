@@ -1,9 +1,9 @@
 """Attention ops: prefill (causal GQA) and paged-KV decode.
 
 These are the XLA reference implementations — correct on any backend and the
-ground truth for the Pallas TPU kernels in ``paged_attention_pallas.py``.
+ground truth for the Pallas TPU kernel in ``paged_attention_stream.py``.
 Softmax accumulates in float32 regardless of the activation dtype (bf16 on
-TPU) for numerical parity with the fused kernels.
+TPU) for numerical parity with the fused kernel.
 
 The paged layout: KV lives in fixed-size pages; a sequence owns a row of
 the page table ``[max_pages_per_seq]`` holding page indices. This is the
@@ -25,8 +25,7 @@ the tile's rows at any head count and write and gather share the tiling.
 ``write_pages`` and ``_gather_kv`` take either form and tell them apart
 by the trailing axis (``pages_merged``). The streaming kernel
 (``paged_attention_stream``, "pallas-stream") reads merged pages at any
-head count, a kv head being a 128-lane slice of the page row; the two
-older Pallas kernels take split pages only.
+head count, a kv head being a 128-lane slice of the page row.
 """
 
 from __future__ import annotations
@@ -38,6 +37,10 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..utils.profiling import scoped
+from .paged_attention_stream import (
+    paged_decode_attention_stream,
+    paged_ragged_attention_stream,
+)
 
 NEG_INF = -1e30
 
@@ -103,16 +106,13 @@ def page_form(kv_heads_per_shard: int, attn_impl: str = "xla") -> str:
     bf16 and int8 alike (both get 8-row tiles); at K = 8 split has no
     such copy and merged would add one of each gathered block. The
     streaming kernel gathers nothing and slices a kv head out of the
-    merged row's lanes, so under it every K above 1 is merged. The grid
-    and manual-DMA kernels index ``[.., P, K, D]`` blocks and hold split
-    pages at any K. One kv head (MLA's latent, a tp shard of one head) is
-    the same bytes either way and keeps its unit axis."""
+    merged row's lanes, so under it every K above 1 is merged. One kv
+    head (MLA's latent, a tp shard of one head) is the same bytes either
+    way and keeps its unit axis."""
     if kv_heads_per_shard == 1:
         return "split"
     if attn_impl == "pallas-stream":
         return "merged"
-    if attn_impl != "xla":
-        return "split"
     return "split" if kv_heads_per_shard % TILE_ROWS == 0 else "merged"
 
 
@@ -147,7 +147,7 @@ def _dequantize_gathered(seq: jax.Array, scale: jax.Array, dtype) -> jax.Array:
     return (seq.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
-PAGED_BACKENDS = ("xla", "pallas", "pallas-dma", "pallas-stream")
+PAGED_BACKENDS = ("xla", "pallas-stream")
 
 
 def paged_attention_backend(
@@ -159,33 +159,20 @@ def paged_attention_backend(
     mla: bool = False,
 ) -> str:
     """Which reader of paged keys and values an engine runs: "xla" (the
-    gather, and the oracle of every test), "pallas-stream" (the streaming
-    ragged kernel, ``paged_attention_stream``), or one of the two older
-    kernels, "pallas" ((B, MaxP) grid) and "pallas-dma" (a page a step).
+    gather, and the oracle of every test) or "pallas-stream" (the
+    streaming ragged kernel, ``paged_attention_stream``).
 
-    The choice is the code's, from what it can observe where the engine
-    is built: the platform of the mesh's devices and the shapes
-    ``pallas_refusal`` takes. On a TPU it is the streaming kernel
+    The choice is the code's, a pure function of what it can observe
+    where the engine is built: the platform of the mesh's devices and the
+    shapes ``pallas_refusal`` takes; nothing outside the code names a
+    reader. On a TPU it is the streaming kernel
     wherever the chip's compiler takes it (head dim on the 128-lane
     tiling, bf16 pages, no MLA); everywhere else (the CPU, int8 pages,
     MLA's 192-wide qk heads, head dims off the tiling) the gather. By
     measurement (PERF.md section 6, PR 29, ``scripts/attn_microbench.py``
     at the benchmark cells' shapes on a v5e): the kernel is ahead of the
     gather at every shape the cells run, decode blocks over short rows
-    included, so no shape is sent back to the gather on speed.
-
-    OPSAGENT_PAGED_BACKEND names a backend outright (the tests' and the
-    bench sweep's handle on the older kernels and on interpret mode); a
-    named backend the shapes refuse is an error at engine init, never a
-    quiet gather under the kernel's name."""
-    choice = os.environ.get("OPSAGENT_PAGED_BACKEND", "auto")
-    if choice in PAGED_BACKENDS:
-        return choice
-    if choice != "auto":
-        raise ValueError(
-            f"OPSAGENT_PAGED_BACKEND={choice!r}: expected one of "
-            f"{', '.join(PAGED_BACKENDS)}, or auto"
-        )
+    included, so no shape is sent back to the gather on speed."""
     if platform != "tpu":
         return "xla"
     refused = pallas_refusal(
@@ -198,10 +185,9 @@ def paged_attention_backend(
 
 def pallas_interpret() -> bool:
     """Whether the Pallas kernels should run in interpret mode
-    (OPSAGENT_PALLAS_INTERPRET=1): the CPU escape hatch that lets the
-    bench ragged-backend sweep smoke and CI exercise the pallas /
-    pallas-dma dispatch paths end-to-end off-TPU, where a compiled
-    pallas_call cannot lower. Read at trace time by the ``*_auto``
+    (OPSAGENT_PALLAS_INTERPRET=1): how the CPU tests run the attention
+    and weight-stream kernels' dispatch paths end-to-end off-TPU, where a
+    compiled pallas_call cannot lower. Read at trace time by the ``*_auto``
     dispatchers. On the chip it is an error, not a slow success:
     interpret mode is orders of magnitude slower and skips Mosaic
     entirely, so whatever it produced there would carry the kernel's
@@ -223,57 +209,41 @@ def pallas_refusal(
     page_itemsize: int,
     mla: bool = False,
 ) -> str | None:
-    """Why the chip's compiler refuses paged-attention backend ``impl``
-    at these shapes, or None when it compiles. Each rule is a refusal
-    Mosaic gave when the kernels were compiled for a described v5e
-    device (tests/test_tpu_compile.py keeps both sides of each rule);
-    the engine raises it at init and the bench sweep skips the cell by
-    it, so no such combination reaches the chip to fail — or fall back —
-    there. Interpret mode has no Mosaic and no such limits.
+    """Why paged-attention backend ``impl`` cannot serve these shapes, or
+    None when it can: MLA, int8 pages (the streaming kernel has no reader
+    for either) and a head dim off the 128 lanes (Mosaic's refusal when
+    the kernel was compiled for a described v5e device;
+    tests/test_tpu_compile.py keeps both sides of each rule).
+    ``paged_attention_backend`` sends such an engine to the gather, so no
+    such combination reaches the chip to fail there. Interpret mode has
+    no Mosaic and not its tiling limit.
 
-    ``page_itemsize``: bytes per stored KV element (1 for int8 pages)."""
+    ``page_itemsize``: bytes per stored KV element (1 for int8 pages).
+    ``kv_heads_per_shard``: one of the shapes an engine describes itself
+    by; no rule reads it, the kernel takes any head count."""
     if impl == "xla":
         return None
     if mla:
         return (
             f"paged backend {impl!r} with an MLA model: the qk head dim "
-            "(nope + rope, e.g. 192) breaks the Pallas kernels' last-dim "
+            "(nope + rope, e.g. 192) breaks the Pallas kernel's last-dim "
             "tiling; MLA serves through the xla gather"
         )
-    if impl == "pallas-stream":
-        if page_itemsize == 1:
-            return (
-                "pallas-stream with int8 pages: a 16-token page is half "
-                "of an int8 tile's 32 rows, so a key block cannot be "
-                "read out of the page buffers without a re-tiling, and "
-                "the per-token scales would need a lane-to-sublane move "
-                "a kv head; QuantizedPages serve through the xla gather"
-            )
-        if head_dim % 128:
-            return (
-                f"pallas-stream with head_dim {head_dim}: a kv head is a "
-                "slice of the merged page row's lanes, and Mosaic wants "
-                "it on the 128-lane tiling; such heads serve through the "
-                "xla gather"
-            )
-    if impl == "pallas-dma":
-        if head_dim % 128:
-            return (
-                f"pallas-dma with head_dim {head_dim}: Mosaic requires "
-                "manual-DMA memref slices to be aligned to the lane "
-                "tiling on the minormost dim (\"Slice shape along "
-                "dimension 3 must be aligned to tiling (128)\")"
-            )
-        sublanes = max(1, 4 // page_itemsize)
-        if kv_heads_per_shard % sublanes:
-            return (
-                f"pallas-dma with {kv_heads_per_shard} kv head(s) per "
-                f"shard of {8 * page_itemsize}-bit pages: a page's DMA "
-                "slices the kv-head axis, and Mosaic requires \"Slice "
-                f"shape along dimension 2 must be aligned to tiling "
-                f"({sublanes})\"; use fewer tp shards, the grid kernel "
-                "('pallas') or the xla gather"
-            )
+    if page_itemsize == 1:
+        return (
+            "pallas-stream with int8 pages: a 16-token page is half "
+            "of an int8 tile's 32 rows, so a key block cannot be "
+            "read out of the page buffers without a re-tiling, and "
+            "the per-token scales would need a lane-to-sublane move "
+            "a kv head; QuantizedPages serve through the xla gather"
+        )
+    if head_dim % 128:
+        return (
+            f"pallas-stream with head_dim {head_dim}: a kv head is a "
+            "slice of the merged page row's lanes, and Mosaic wants "
+            "it on the 128-lane tiling; such heads serve through the "
+            "xla gather"
+        )
     return None
 
 
@@ -281,24 +251,28 @@ def _tp(mesh: Mesh | None) -> int:
     return 1 if mesh is None else mesh.shape.get("tp", 1)
 
 
-def _require_form(pages, head_dim: int, impl: str, tp: int = 1) -> None:
-    """The held form must be the one ``page_form`` gives ``impl`` at the
-    kv heads a shard holds (``tp`` shards of the pages' kv-head axis)."""
-    merged = pages_merged(pages, head_dim)
-    one_head = not merged and pages.shape[-2] == tp
-    if impl == "pallas-stream":
-        if isinstance(pages, QuantizedPages):
-            raise ValueError(pallas_refusal(
-                impl, head_dim=head_dim, kv_heads_per_shard=1,
-                page_itemsize=1,
-            ))
-        wrong = not (merged or one_head)
-    else:
-        wrong = merged
-    if wrong:
+def _require_reader(impl: str) -> None:
+    """A name that is no reader's (a deleted kernel's, a typo) is an
+    error where the dispatch would otherwise run the gather under it."""
+    if impl not in PAGED_BACKENDS:
         raise ValueError(
-            f"paged backend {impl!r} was given "
-            f"{'merged' if merged else 'split'} pages "
+            f"paged backend {impl!r}: expected one of {PAGED_BACKENDS}"
+        )
+
+
+def _require_form(pages, head_dim: int, tp: int = 1) -> None:
+    """The streaming kernel takes the form ``page_form`` gives it at the
+    kv heads a shard holds (``tp`` shards of the pages' kv-head axis):
+    merged float pages, or one head a shard with its unit axis."""
+    if isinstance(pages, QuantizedPages):
+        raise ValueError(pallas_refusal(
+            "pallas-stream", head_dim=head_dim, kv_heads_per_shard=1,
+            page_itemsize=1,
+        ))
+    merged = pages_merged(pages, head_dim)
+    if not merged and pages.shape[-2] != tp:
+        raise ValueError(
+            f"paged backend 'pallas-stream' was given split pages "
             f"{tuple(pages.shape)}: make the cache with "
             "page_form(kv_heads, attn_impl)"
         )
@@ -314,56 +288,13 @@ def _shard_map(fn, mesh: Mesh, in_specs, out_specs):
     )
 
 
-def _pallas_kernel_fn(impl: str):
-    if impl == "pallas-stream":
-        from .paged_attention_stream import paged_decode_attention_stream
-
-        return paged_decode_attention_stream
-    from .paged_attention_pallas import (
-        paged_decode_attention_pallas,
-        paged_decode_attention_pallas_dma,
-    )
-
-    return (
-        paged_decode_attention_pallas_dma if impl == "pallas-dma"
-        else paged_decode_attention_pallas
-    )
-
-
-def _ragged_pallas_kernel_fn(impl: str):
-    if impl == "pallas-stream":
-        from .paged_attention_stream import paged_ragged_attention_stream
-
-        return paged_ragged_attention_stream
-    from .paged_attention_pallas import (
-        paged_ragged_attention_pallas,
-        paged_ragged_attention_pallas_dma,
-    )
-
-    return (
-        paged_ragged_attention_pallas_dma if impl == "pallas-dma"
-        else paged_ragged_attention_pallas
-    )
-
-
-def _tp_page_spec(pages, head_dim: int):
-    """PartitionSpec (a ``QuantizedPages`` of them for int8 pages) that
-    shards held pages by kv head over ``tp``: the kv-head axis of split
-    pages, the trailing ``K*D`` axis of merged ones (a shard's heads are
-    contiguous lanes)."""
-    values = pages.q if isinstance(pages, QuantizedPages) else pages
-    lead = (None,) * (
-        values.ndim - (1 if pages_merged(values, head_dim) else 2)
-    )
-    spec = (
-        P(*lead, "tp") if pages_merged(values, head_dim)
-        else P(*lead, "tp", None)
-    )
-    if isinstance(pages, QuantizedPages):
-        # Scale planes shard with their values' kv-head axis (one fewer
-        # trailing dim); the spec pytree mirrors the QuantizedPages leaf.
-        return QuantizedPages(spec, P(*lead, "tp"))
-    return spec
+def _tp_page_spec(pages: jax.Array, head_dim: int) -> P:
+    """PartitionSpec that shards held pages by kv head over ``tp``: the
+    kv-head axis of split pages, the trailing ``K*D`` axis of merged ones
+    (a shard's heads are contiguous lanes)."""
+    if pages_merged(pages, head_dim):
+        return P(*(None,) * (pages.ndim - 1), "tp")
+    return P(*(None,) * (pages.ndim - 2), "tp", None)
 
 
 def paged_decode_attention_pallas_tp(
@@ -375,9 +306,8 @@ def paged_decode_attention_pallas_tp(
     mesh: Mesh,
     layer: jax.Array | None = None,
     interpret: bool = False,
-    impl: str = "pallas",
 ) -> jax.Array:
-    """The Pallas decode kernel under tensor parallelism.
+    """The streaming kernel's decode form under tensor parallelism.
 
     A bare pallas_call is opaque to the pjit partitioner, so it is wrapped
     in shard_map over the ``tp`` mesh axis: q's heads and the KV pages' kv
@@ -386,15 +316,15 @@ def paged_decode_attention_pallas_tp(
     K/tp kv heads — the GQA group structure is preserved per shard and NO
     collective is needed (the head axis is fully data-parallel here; the
     all-reduce happens later at the wo row-parallel matmul)."""
-    kernel = _pallas_kernel_fn(impl)
-
     spec_q = P(None, "tp", None)
     spec_kv = _tp_page_spec(k_pages, q.shape[-1])
     if layer is None:
         layer = jnp.int32(0)
 
     def local(q, kp, vp, table, ln, ly):
-        return kernel(q, kp, vp, table, ln, interpret=interpret, layer=ly)
+        return paged_decode_attention_stream(
+            q, kp, vp, table, ln, interpret=interpret, layer=ly
+        )
 
     mapped = _shard_map(
         local, mesh,
@@ -417,23 +347,23 @@ def paged_decode_attention_auto(
 ) -> jax.Array:
     """Impl-dispatched paged decode attention (impl from
     ``paged_attention_backend``, resolved at trace time by the caller).
-    With a mesh whose tp axis is >1, the Pallas path runs shard_mapped
-    over tp (see ``paged_decode_attention_pallas_tp``). int8+scale
-    ``QuantizedPages`` flow through the XLA gather, the manual-DMA kernel
-    and the (B, MaxP) grid kernel (a score-space scale path each); the
-    streaming kernel refuses them by name (``pallas_refusal``)."""
-    if impl.startswith("pallas"):
-        _require_form(k_pages, q.shape[-1], impl, _tp(mesh))
+    With a mesh whose tp axis is >1, the kernel runs shard_mapped over
+    tp (see ``paged_decode_attention_pallas_tp``). int8+scale
+    ``QuantizedPages`` flow through the XLA gather; the streaming kernel
+    refuses them by name (``pallas_refusal``)."""
+    if impl == "pallas-stream":
+        _require_form(k_pages, q.shape[-1], _tp(mesh))
         interpret = pallas_interpret()
         if _tp(mesh) > 1:
             return paged_decode_attention_pallas_tp(
                 q, k_pages, v_pages, page_table, lengths, mesh, layer=layer,
-                impl=impl, interpret=interpret,
+                interpret=interpret,
             )
-        return _pallas_kernel_fn(impl)(
+        return paged_decode_attention_stream(
             q, k_pages, v_pages, page_table, lengths, layer=layer,
             interpret=interpret,
         )
+    _require_reader(impl)
     return paged_decode_attention(
         q, k_pages, v_pages, page_table, lengths, layer=layer
     )
@@ -678,7 +608,7 @@ def paged_ragged_attention(
     ``start[b] + q_lens[b]``. Rows with q_lens == 0 produce garbage output
     (finite — all-masked softmax degrades to uniform) that callers
     discard. Gather-based XLA reference; the Pallas page-streaming variant
-    is ``paged_ragged_attention_pallas`` behind
+    is ``paged_ragged_attention_stream`` behind
     ``paged_ragged_attention_auto``."""
     k_seq, v_seq = _gather_kv(
         k_pages, v_pages, page_table, layer, q.dtype, q.shape[-1]
@@ -770,28 +700,6 @@ def _ragged_attention_block(
     return out.reshape(B, S, H, D).astype(q.dtype)
 
 
-def paged_prefix_attention(
-    q: jax.Array,           # [B, S, H, D] tail queries (right-padded)
-    k_pages: jax.Array,     # [(L,) N, P, K, D] or merged [(L,) N, P, K*D]
-    v_pages: jax.Array,     # like k_pages
-    page_table: jax.Array,  # [B, MaxP]
-    start: jax.Array,       # [B] cached-prefix lengths (tail begins here)
-    lengths: jax.Array,     # [B] valid TAIL lengths
-    layer: jax.Array | None = None,  # [] int32 with the layer-axis form
-    impl: str = "xla",
-    mesh: Mesh | None = None,
-) -> jax.Array:
-    """Tail-prefill attention over paged KV holding [prefix + tail] — the
-    prefix-cache admission path. Prefix attention IS ragged paged
-    attention (per-row write offset + per-row valid tail length), so this
-    is the same op under its admission-era name, through the same
-    dispatch."""
-    return paged_ragged_attention_auto(
-        q, k_pages, v_pages, page_table, start, lengths, impl=impl,
-        layer=layer, mesh=mesh,
-    )
-
-
 def paged_ragged_attention_pallas_tp(
     q: jax.Array,           # [B, S, H, D] — H sharded over tp
     k_pages: jax.Array,     # [N, P, K, D] or [L, N, P, K, D] — K over tp
@@ -802,25 +710,19 @@ def paged_ragged_attention_pallas_tp(
     mesh: Mesh,
     layer: jax.Array | None = None,
     interpret: bool = False,
-    impl: str = "pallas",
 ) -> jax.Array:
-    """The ragged Pallas kernels under tensor parallelism: shard_mapped
-    over ``tp`` exactly like ``paged_decode_attention_pallas_tp`` — query
+    """The streaming kernel under tensor parallelism: shard_mapped over
+    ``tp`` exactly like ``paged_decode_attention_pallas_tp`` — query
     heads and kv heads are both tp-sharded, the GQA group structure is
     preserved per shard, and no collective is needed (the all-reduce
-    happens later at the wo row-parallel matmul). ``impl`` picks the grid
-    kernel ("pallas") or the manual-DMA streamer ("pallas-dma"); with
-    ``QuantizedPages`` the scale planes shard with their values' kv-head
-    axis, mirroring the decode TP wrapper."""
-    kernel = _ragged_pallas_kernel_fn(impl)
-
+    happens later at the wo row-parallel matmul)."""
     spec_q = P(None, None, "tp", None)
     spec_kv = _tp_page_spec(k_pages, q.shape[-1])
     if layer is None:
         layer = jnp.int32(0)
 
     def local(q, kp, vp, table, st, ql, ly):
-        return kernel(
+        return paged_ragged_attention_stream(
             q, kp, vp, table, st, ql, interpret=interpret, layer=ly
         )
 
@@ -847,25 +749,24 @@ def paged_ragged_attention_auto(
     mesh: Mesh | None = None,
 ) -> jax.Array:
     """Impl-dispatched ragged paged attention (the mixed-step analogue of
-    ``paged_decode_attention_auto``): "pallas-stream" is the streaming
-    kernel over merged pages (``paged_attention_stream``), "pallas-dma"
-    the older manual-DMA streamer (a page a step) and "pallas" the
-    (B, MaxP) grid kernel. The two older ones stream int8
-    ``QuantizedPages`` at half the bytes with score-space scales; the
-    streaming kernel refuses them by name and an engine with int8 pages
-    resolves to the gather."""
-    if impl.startswith("pallas"):
-        _require_form(k_pages, q.shape[-1], impl, _tp(mesh))
+    ``paged_decode_attention_auto``, and the tail prefill's op over a
+    cached prefix: per-row write offset + per-row valid tail length):
+    "pallas-stream" is the streaming kernel over merged pages
+    (``paged_attention_stream``), which refuses int8 ``QuantizedPages``
+    by name; an engine with int8 pages resolves to the gather."""
+    if impl == "pallas-stream":
+        _require_form(k_pages, q.shape[-1], _tp(mesh))
         interpret = pallas_interpret()
         if _tp(mesh) > 1:
             return paged_ragged_attention_pallas_tp(
                 q, k_pages, v_pages, page_table, start, q_lens, mesh,
-                layer=layer, impl=impl, interpret=interpret,
+                layer=layer, interpret=interpret,
             )
-        return _ragged_pallas_kernel_fn(impl)(
+        return paged_ragged_attention_stream(
             q, k_pages, v_pages, page_table, start, q_lens, layer=layer,
             interpret=interpret,
         )
+    _require_reader(impl)
     return paged_ragged_attention(
         q, k_pages, v_pages, page_table, start, q_lens, layer=layer
     )

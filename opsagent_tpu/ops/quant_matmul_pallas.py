@@ -4,8 +4,8 @@ The decode/mixed hot path is weight-streaming-bound (PERF.md roofline:
 ~9.8 ms/step of weight bytes at 8B int8) and the XLA path serializes that
 stream with compute: every ``x @ w.dequantize()`` waits for its operand
 tiles. This kernel applies the same manual ``make_async_copy`` DMA
-discipline the paged-attention kernels (ops/paged_attention_pallas.py)
-use for KV pages to the WEIGHTS: int8 / self-packed-int4 tiles stream
+discipline the paged-attention kernel (ops/paged_attention_stream.py)
+uses for KV pages to the WEIGHTS: int8 / self-packed-int4 tiles stream
 HBM->VMEM through two double-buffered slots, so tile i+1's DMA runs under
 tile i's MXU dot and the stream hides behind compute instead of adding to
 it. Group-wise scales (models/quant.py layouts) are applied in-register

@@ -412,12 +412,14 @@ class Engine:
 
         # Execution backends, resolved ONCE here, before anything is built
         # on the device. The attention reader is the code's own choice
-        # from the platform and the model's shapes
-        # (ops.attention.paged_attention_backend); the weight stream is an
-        # explicit request (config field / env knob, default xla). A
-        # backend asked for BY NAME that cannot be honoured is an error
-        # with the reason (BackendRefused), never a quiet xla run under
-        # the kernel's name. impl_info() reports what runs.
+        # from the platform and the model's shapes, and nothing outside
+        # the code can name one (ops.attention.paged_attention_backend:
+        # the streaming kernel on a TPU where it has a reader, else the
+        # gather); the weight stream is an explicit request (config field
+        # / env knob, default xla), and a weight stream asked for that
+        # cannot be honoured is an error with the reason (BackendRefused),
+        # never a quiet xla run under the kernel's name. impl_info()
+        # reports what runs.
         from ..ops.attention import (
             pallas_interpret, pallas_refusal, paged_attention_backend,
         )
@@ -451,22 +453,25 @@ class Engine:
             ),
             mla=self.model_cfg.mla is not None,
         )
-        self.attn_impl = paged_attention_backend(
-            platform=self.mesh.devices.flat[0].platform, **shapes
-        )
-        # Interpret mode (the CPU tests) has no Mosaic and none of its
-        # tiling limits; pages the kernel has no reader for (int8 under
-        # pallas-stream) stay refused there too.
-        stream_int8 = self.attn_impl == "pallas-stream" and cfg.kv_quantize
-        if self.attn_impl != "xla" and (stream_int8 or not pallas_interpret()):
-            why = pallas_refusal(self.attn_impl, **shapes)
-            if why:
-                raise BackendRefused(why)
+        platform = self.mesh.devices.flat[0].platform
+        self.attn_impl = paged_attention_backend(platform=platform, **shapes)
+        refused = pallas_refusal("pallas-stream", **shapes)
+        # The choice never answers the kernel where pallas_refusal has a
+        # reason, so this raises only where a test has put the kernel in
+        # the choice's place. Interpret mode (the CPU tests) has no Mosaic
+        # and none of its tiling limits; pages the kernel has no reader
+        # for (int8 under pallas-stream) stay refused there too.
+        if self.attn_impl == "pallas-stream" and refused and (
+            cfg.kv_quantize or not pallas_interpret()
+        ):
+            raise BackendRefused(refused)
         log.info(
-            "paged attention impl: %s, weight stream: %s (tp=%d%s)",
-            self.attn_impl, ws, tp,
+            "paged attention reader: %s on %s%s, weight stream: %s (tp=%d%s)",
+            self.attn_impl, platform,
+            f" ({refused})" if self.attn_impl == "xla" and refused else "",
+            ws, tp,
             ", shard_map over tp"
-            if self.attn_impl.startswith("pallas") and tp > 1
+            if self.attn_impl == "pallas-stream" and tp > 1
             else "",
         )
 

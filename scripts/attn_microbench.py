@@ -28,9 +28,6 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from opsagent_tpu.ops import attention  # noqa: E402
-from opsagent_tpu.ops.paged_attention_pallas import (  # noqa: E402
-    paged_ragged_attention_pallas_dma,
-)
 from opsagent_tpu.ops.paged_attention_stream import (  # noqa: E402
     paged_ragged_attention_stream,
 )
@@ -152,7 +149,6 @@ def main() -> int:
             rng.standard_normal((LAYERS, N, PAGE, K * D), np.float32),
             jnp.bfloat16,
         )
-        split = (LAYERS, N, PAGE, K, D)
         for S in widths:
             table, start, q_lens = make_rows(
                 rng, B, S, N, MaxP, span, chunk_rows
@@ -186,20 +182,9 @@ def main() -> int:
                 )
                 for bp in blocks
             }
-            candidates["pallas_dma_split_pages"] = (
-                lambda x, k_, v_, t, st, ql, ly: (
-                    paged_ragged_attention_pallas_dma(
-                        x, k_, v_, t, st, ql, layer=ly, interpret=REHEARSE))
-            )
             for cand, op in candidates.items():
-                call = args
-                if cand == "pallas_dma_split_pages":
-                    if S > 16 or name == "cell3":
-                        continue    # a page a step in f32: small shapes tell
-                    # Its pages are split; re-laid here, outside the timing.
-                    call = (q, kc.reshape(split), vc.reshape(split), *args[3:])
                 try:
-                    ms, got = timed(over_layers(op), call)
+                    ms, got = timed(over_layers(op), args)
                 except Exception as e:  # noqa: BLE001 - a refusal is a finding
                     line["ms_per_layer"][cand] = f"refused: {str(e)[:200]}"
                     continue

@@ -153,17 +153,8 @@ def terminal_summary(paths: list[str]) -> int:
         print(f"\nfastest 8B variant: {best['metric']} "
               f"at {best['value']:.0f} tok/s/chip "
               f"({'>=' if best['value'] >= 2000 else '<'} 2000 target)")
-        dma = [d for d in eight_b
-               if d.get("extra", {}).get("paged_backend") == "pallas-dma"]
-        xla = [d for d in eight_b
-               if d.get("extra", {}).get("paged_backend") in ("", "xla")]
-        if dma and xla:
-            print(f"kernel verdict: pallas-dma best "
-                  f"{max(d['value'] for d in dma):.0f} vs xla best "
-                  f"{max(d['value'] for d in xla):.0f}")
-    # Ragged-backend sweep (the MIXED hot path): best cell per RESOLVED
-    # impl, with the byte-identical verdict — the decision input for
-    # flipping paged_attention_backend()'s default.
+    # Ragged sweep (the MIXED hot path): best cell per RESOLVED attention
+    # reader, with the byte-identical verdict.
     sweep = [d for d in rows
              if d["metric"].startswith("mixed_ragged_throughput")
              and "best_cell" not in d.get("extra", {})]

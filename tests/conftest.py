@@ -96,6 +96,34 @@ def fake_tools():
     tools_pkg.copilot_tools.update(saved)
 
 
+@pytest.fixture
+def stream_kernel():
+    """The tests' handle on the streaming attention kernel off the chip:
+    an engine built AND run inside ``with stream_kernel():`` runs
+    "pallas-stream", interpreted, wherever the kernel has a reader (no
+    int8 pages, no MLA; interpret mode has none of Mosaic's tiling limits,
+    so the tiny presets' head dims of 16 pass); outside it the same test
+    builds the gather's engine to compare with. The engine imports the
+    choice function inside ``__init__``, so patching the module's
+    attribute reaches it, and interpret mode is read when a step program
+    is traced. Nothing in the program can name a reader."""
+    import contextlib
+
+    from opsagent_tpu.ops import attention
+
+    def choice(*, page_itemsize, mla=False, **_):
+        return "xla" if mla or page_itemsize == 1 else "pallas-stream"
+
+    @contextlib.contextmanager
+    def under():
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(attention, "paged_attention_backend", choice)
+            mp.setenv("OPSAGENT_PALLAS_INTERPRET", "1")
+            yield
+
+    return under
+
+
 def _reset_obs():
     # Observability isolation: clear the metric SAMPLES (instruments stay
     # registered), the trace ring, the flight-recorder ring, the SLO

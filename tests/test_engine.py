@@ -620,32 +620,58 @@ QWEN_7B = dict(head_dim=128, kv_heads_per_shard=4, page_itemsize=2)
     ("gpu", QWEN_7B, "xla"),
 ])
 def test_the_attention_backend_is_chosen_from_platform_and_shapes(
-    monkeypatch, platform, shapes, backend
+    platform, shapes, backend
 ):
-    """No knob: ``auto`` resolves from what the engine can observe where
-    it is built. The streaming kernel on a TPU wherever the chip's
+    """No knob: the choice resolves from what the engine can observe
+    where it is built. The streaming kernel on a TPU wherever the chip's
     compiler takes it, the gather everywhere else."""
     from opsagent_tpu.ops.attention import (
         paged_attention_backend, pallas_refusal,
     )
 
-    monkeypatch.delenv("OPSAGENT_PAGED_BACKEND", raising=False)
     assert paged_attention_backend(platform=platform, **shapes) == backend
     if platform == "tpu":
         refused = pallas_refusal("pallas-stream", **shapes)
         assert (refused is None) == (backend == "pallas-stream")
 
 
-def test_a_backend_asked_for_by_name_wins_and_a_wrong_name_raises(monkeypatch):
-    from opsagent_tpu.ops.attention import paged_attention_backend
+def test_the_choice_is_a_pure_function_whatever_the_environment_names(
+    monkeypatch
+):
+    """The variable that once named a backend outright is read by nothing:
+    set to a reader, to a kernel that is gone or to nonsense, the choice
+    is what it is without it, and nothing is raised."""
+    from opsagent_tpu.ops.attention import (
+        PAGED_BACKENDS, paged_attention_backend,
+    )
 
-    monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", "pallas-dma")
-    assert paged_attention_backend(platform="cpu", **QWEN_7B) == "pallas-dma"
-    monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", "auto")
-    assert paged_attention_backend(platform="cpu", **QWEN_7B) == "xla"
-    monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", "cuda")
-    with pytest.raises(ValueError, match="pallas-stream"):
-        paged_attention_backend(platform="tpu", **QWEN_7B)
+    assert PAGED_BACKENDS == ("xla", "pallas-stream")
+    int8 = dict(QWEN_7B, page_itemsize=1)
+    for named in ("xla", "pallas-stream", "pallas-dma", "auto", "cuda"):
+        monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", named)
+        assert paged_attention_backend(platform="tpu", **QWEN_7B) == (
+            "pallas-stream")
+        assert paged_attention_backend(platform="tpu", **int8) == "xla"
+        assert paged_attention_backend(platform="cpu", **QWEN_7B) == "xla"
+
+
+def test_nothing_in_the_package_reads_a_variable_that_names_a_reader():
+    """A grep over the package: no ``OPSAGENT_*BACKEND`` variable and no
+    ``OPSAGENT_ATTN*`` / ``OPSAGENT_PAGED*`` one, which also guards the
+    next knob. (``OPSAGENT_PALLAS_INTERPRET`` says how a ``pallas_call`` is
+    lowered, not which reader runs, and is an error on the chip.)"""
+    import pathlib
+    import re
+
+    import opsagent_tpu
+
+    knob = re.compile(r"OPSAGENT_\w*(BACKEND|ATTN|ATTENTION|PAGED)\w*")
+    found = [
+        f"{path}: {m.group(0)}"
+        for path in pathlib.Path(opsagent_tpu.__file__).parent.rglob("*.py")
+        for m in knob.finditer(path.read_text())
+    ]
+    assert found == []
 
 
 def test_an_engine_on_the_cpu_runs_the_gather_and_counts_its_pages(engine):
@@ -670,46 +696,211 @@ def test_an_engine_on_the_cpu_runs_the_gather_and_counts_its_pages(engine):
     assert (c1 - c0) % 16 == 0
 
 
-def test_a_model_with_recurrent_state_takes_the_streaming_kernel(monkeypatch):
+def _gather_and_kernel(stream_kernel, run, model_cfg=None, **cfg):
+    """``run(engine)`` on the gather's engine and on the kernel's (the
+    conftest fixture: the choice patched, the kernel interpreted), and
+    what the kernel's engine says of itself."""
+    want = run(Engine(EngineConfig(**cfg), model_cfg=model_cfg))
+    with stream_kernel():
+        eng = Engine(EngineConfig(**cfg), model_cfg=model_cfg)
+        return want, run(eng), eng.impl_info()
+
+
+SMALL = dict(
+    dtype=jnp.float32, max_batch_size=2, num_pages=16, max_pages_per_seq=8,
+    prefill_buckets=(32,), mixed_buckets=(16,), mixed_batching=True,
+)
+PROMPTS = [[257] + list(range(1, 20)), [257, 4, 4, 2]]
+
+
+def test_a_model_with_recurrent_state_takes_the_streaming_kernel(stream_kernel):
     """``_row_state`` strips the state-slot columns before attention sees
     the table and the family's GQA layers are plain GQA to this op, so
     the engine no longer refuses such a model an attention backend; the
     kernel (interpreted here) serves what the gather serves."""
-    monkeypatch.setenv("OPSAGENT_PALLAS_INTERPRET", "1")
-    outs = {}
-    for backend in ("xla", "pallas-stream"):
-        monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", backend)
-        eng = Engine(EngineConfig(
-            model="tiny-hybrid", dtype=jnp.float32, tp=1, max_batch_size=2,
-            num_pages=16, max_pages_per_seq=8, prefill_buckets=(32,),
-            mixed_buckets=(16,), mixed_batching=True,
-        ))
-        assert eng.impl_info()["attn_impl"] == backend
-        outs[backend] = eng.generate(
-            [[257] + list(range(1, 20)), [257, 4, 4, 2]],
-            SamplingParams(max_tokens=6),
-        )
-    assert outs["xla"] == outs["pallas-stream"]
+    want, got, info = _gather_and_kernel(
+        stream_kernel,
+        lambda eng: eng.generate(PROMPTS, SamplingParams(max_tokens=6)),
+        model="tiny-hybrid", tp=1, **SMALL,
+    )
+    assert info["attn_impl"] == "pallas-stream"
+    assert got == want
 
 
-def test_the_streaming_kernel_serves_one_kv_head_a_shard_under_tp(monkeypatch):
+def test_the_streaming_kernel_serves_one_kv_head_a_shard_under_tp(stream_kernel):
     """tiny-test's two kv heads over tp=2 leave ONE a shard: the pages
     are held split with the heads' axis sharded, and the dispatch's form
     check counts the heads a shard holds, not the array's (the 7B over
     tp=4). The kernel, interpreted, serves what the gather serves."""
-    monkeypatch.setenv("OPSAGENT_PALLAS_INTERPRET", "1")
-    outs = {}
-    for backend in ("xla", "pallas-stream"):
-        monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", backend)
-        eng = Engine(EngineConfig(
-            model="tiny-test", dtype=jnp.float32, tp=2, max_batch_size=2,
-            num_pages=16, max_pages_per_seq=8, prefill_buckets=(32,),
-            mixed_buckets=(16,), mixed_batching=True,
-        ))
-        info = eng.impl_info()
-        assert (info["attn_impl"], info["kv_page_form"]) == (backend, "split")
-        outs[backend] = eng.generate(
-            [[257] + list(range(1, 20)), [257, 4, 4, 2]],
-            SamplingParams(max_tokens=6),
-        )
-    assert outs["xla"] == outs["pallas-stream"]
+    want, got, info = _gather_and_kernel(
+        stream_kernel,
+        lambda eng: eng.generate(PROMPTS, SamplingParams(max_tokens=6)),
+        model="tiny-test", tp=2, **SMALL,
+    )
+    assert (info["attn_impl"], info["kv_page_form"]) == (
+        "pallas-stream", "split")
+    assert got == want
+
+
+# Each program that reaches attention by a door of its own, on tiny-test
+# (two kv heads: merged pages under the kernel), pages of 4 slots.
+DOORS = dict(
+    model="tiny-test", dtype=jnp.float32, tp=1, page_size=4, num_pages=64,
+    max_pages_per_seq=16, max_batch_size=4, prefill_buckets=(8, 16),
+    decode_block=4, mixed_buckets=(4, 8, 16), max_step_tokens=32, seed=0,
+)
+LONG = [257] + list(range(1, 40))
+SHORT = [257, 9, 8, 7]
+
+
+def _run_mixed_async(eng):
+    """``step_mixed_async`` at depth 2: a decode lane rides while a longer
+    prompt is admitted in chunks, then both decode to their end."""
+    a = eng.add_request(SHORT, SamplingParams(max_tokens=10))
+    b = eng.begin_request(LONG, SamplingParams(max_tokens=6))
+    ticks = 0
+    while not (eng.sequences[a].done and eng.sequences[b].done):
+        chunks = {}
+        if b in eng._prefilling:
+            done, total = eng.prefill_progress(b)
+            if total > done:
+                chunks = {b: min(total - done, 16)}
+        lanes = [
+            s for s in (a, b)
+            if s not in eng._prefilling and not eng.sequences[s].done
+        ]
+        eng.step_mixed_async(lanes, chunks)
+        ticks += 1
+        assert ticks < 200, "async driving made no progress"
+    eng.async_drain()
+    return [eng.finish(a), eng.finish(b)]
+
+
+def _run_blocks(eng):
+    """Split prefill (``prefill_step``: the prompt is longer than the
+    largest bucket, so its tail attends over pages), then fused decode
+    blocks (``step_block``, the decode form: what cell 2 runs between
+    admissions)."""
+    return eng.generate([LONG, SHORT], SamplingParams(max_tokens=9))
+
+
+def _run_single_steps(eng):
+    """The single decode ``step``, a token a dispatch."""
+    sid = eng.add_request(SHORT, SamplingParams(max_tokens=7))
+    while not eng.sequences[sid].done:
+        eng.step([sid])
+    return eng.finish(sid)
+
+
+def _run_cached_prefix(eng):
+    """Admission over a cached prefix: the second prompt shares the
+    first's 24 tokens, the trie hands their pages over, and
+    ``prefill_step`` computes only the tail against them."""
+    sp = SamplingParams(max_tokens=5)
+    first = eng.generate([LONG[:28]], sp)
+    before = eng.alloc.hit_tokens
+    second = eng.generate([LONG[:24] + [7, 7, 3, 1, 2]], sp)
+    assert eng.alloc.hit_tokens - before >= 16
+    return first + second
+
+
+def _run_speculative(eng):
+    """Prompt-lookup speculation: ``verify_step`` scores k drafts and the
+    token before them in one ragged pass (k + 1 query slots a row); exact
+    for greedy, so it generates what plain decoding does."""
+    return eng.generate(
+        [[7, 8, 9, 7, 8, 9, 7, 8]], SamplingParams(max_tokens=10)
+    )
+
+
+@pytest.mark.parametrize("run,cfg", [
+    pytest.param(_run_mixed_async, dict(async_depth=2), id="mixed-async"),
+    pytest.param(_run_blocks, {}, id="blocks"),
+    pytest.param(_run_single_steps, {}, id="single-steps"),
+    pytest.param(_run_cached_prefix, {}, id="cached-prefix"),
+    pytest.param(_run_speculative, dict(speculative_k=3), id="speculative"),
+])
+def test_the_kernels_engine_generates_what_the_gathers_does(
+    stream_kernel, run, cfg
+):
+    want, got, _ = _gather_and_kernel(stream_kernel, run, **DOORS, **cfg)
+    assert got == want and all(got)
+
+
+def test_the_streaming_kernel_serves_merged_pages_sharded_by_lanes(
+    stream_kernel
+):
+    """Four kv heads over tp=2 leave two a shard: held merged, the ``K*D``
+    axis sharded over tp, each shard's kernel reading its own heads'
+    lanes (the 72B's eight heads over tp=4)."""
+    import dataclasses
+
+    want, got, info = _gather_and_kernel(
+        stream_kernel,
+        lambda eng: eng.generate(PROMPTS, SamplingParams(max_tokens=6)),
+        model_cfg=dataclasses.replace(TINY_TEST, num_heads=4, num_kv_heads=4),
+        model="tiny-test", tp=2, **SMALL,
+    )
+    assert (info["attn_impl"], info["kv_page_form"]) == (
+        "pallas-stream", "merged")
+    assert got == want
+
+
+def test_impl_info_names_the_kernel_and_the_form_it_reads(stream_kernel):
+    """Under the fixture every engine with a reader says ``pallas-stream``
+    with the form ``page_form`` gives it: merged at tiny-test's two kv
+    heads, split (a unit axis) at one head a shard; without a reader (int8
+    pages, MLA) it says ``xla`` with the gather's form."""
+    rows = [
+        (dict(model="tiny-test", tp=1), "pallas-stream", "merged"),
+        (dict(model="tiny-test", tp=2), "pallas-stream", "split"),
+        (dict(model="tiny-test", tp=1, kv_quantize="int8"), "xla", "merged"),
+        (dict(model="tiny-mla", tp=1), "xla", None),
+    ]
+    with stream_kernel():
+        for cfg, impl, form in rows:
+            eng = Engine(EngineConfig(**cfg, **SMALL))
+            info = eng.impl_info()
+            assert info["attn_impl"] == impl, cfg
+            assert info["kv_page_form"] == llama.cache_form(
+                eng.model_cfg, cfg["tp"], impl), cfg
+            assert form in (None, info["kv_page_form"]), cfg
+
+
+@pytest.mark.parametrize("cfg,words", [
+    (dict(model="tiny-test", kv_quantize="int8"), "int8 pages"),
+    (dict(model="tiny-test"), "head_dim 16"),
+    (dict(model="tiny-mla"), "MLA"),
+], ids=["int8-pages", "head-dim-off-the-lanes", "mla"])
+def test_what_the_kernel_cannot_read_on_a_tpu_goes_to_the_gather_and_the_log_says_why(
+    monkeypatch, cfg, words
+):
+    """An engine whose choice is asked as on a TPU, at each of the three
+    things the kernel has no reader for: it runs the gather, and the line
+    that names the reader carries the refusal's reason."""
+    import logging
+
+    from opsagent_tpu.ops import attention
+
+    choice = attention.paged_attention_backend
+    asked, lines = [], []
+    # The program's loggers do not propagate to the root that caplog reads.
+    handler = logging.Handler(logging.INFO)
+    handler.emit = lambda record: lines.append(record.getMessage())
+    logger = logging.getLogger("opsagent.engine")
+
+    def on_a_tpu(*, platform, **shapes):
+        asked.append(shapes)
+        return choice(platform="tpu", **shapes)
+
+    monkeypatch.setattr(attention, "paged_attention_backend", on_a_tpu)
+    logger.addHandler(handler)
+    try:
+        eng = Engine(EngineConfig(**cfg, **dict(SMALL, dtype=jnp.bfloat16)))
+    finally:
+        logger.removeHandler(handler)
+    assert eng.impl_info()["attn_impl"] == "xla"
+    why = attention.pallas_refusal("pallas-stream", **asked[0])
+    assert words in why
+    line = next(x for x in lines if "paged attention reader" in x)
+    assert "reader: xla" in line and why in line

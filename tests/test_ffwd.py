@@ -333,22 +333,20 @@ def test_hosted_mask_rows_counted_ffwd_ineligible():
     ) > f0
 
 
-def test_ffwd_backends_byte_identical_with_int8_kv(monkeypatch):
+def test_ffwd_readers_byte_identical_and_int8_kv_compiles_nothing(
+    stream_kernel
+):
     """The grammar fast-forward's q_len>1 forced-run appends ride the
-    ragged DMA kernel too: async depth-2 + int8 KV pages +
-    OPSAGENT_PAGED_BACKEND=pallas-dma (interpret off-chip) must produce
-    byte-identical constrained greedy output vs the xla gather, with
-    ZERO post-warmup compiles under the warmed mixed/ffwd groups."""
-    monkeypatch.setenv("OPSAGENT_PALLAS_INTERPRET", "1")
-    outs = {}
-    for backend in ("xla", "pallas-dma"):
-        monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", backend)
+    streaming kernel too: async depth-2 under the kernel (interpreted
+    off-chip) must produce byte-identical constrained greedy output vs
+    the xla gather, with ZERO post-warmup compiles under the warmed
+    mixed/ffwd groups, under either reader and with int8 KV pages, which
+    only the gather reads."""
+    def run(impl, **kw):
         eng = Engine(EngineConfig(
-            grammar_ffwd=True, async_depth=2, kv_quantize="int8", **BASE
+            grammar_ffwd=True, async_depth=2, **kw, **BASE
         ))
-        # The resolved impl must be the requested backend — the old
-        # kv_quantize gate would silently force xla here.
-        assert eng.attn_impl == backend
+        assert eng.attn_impl == impl
         eng.warmup("full")
         con = json_constraint(eng.tokenizer, TOOLPROMPT_SCHEMA)
         sid = eng.begin_request(
@@ -362,8 +360,12 @@ def test_ffwd_backends_byte_identical_with_int8_kv(monkeypatch):
         # earlier tests in file order but not in standalone runs.)
         n0 = len(_COMPILES)
         _drive_async(eng, sid)
-        outs[backend] = eng.finish(sid)
         assert len(_COMPILES) == n0, (
-            f"{len(_COMPILES) - n0} post-warmup compiles on {backend}"
+            f"{len(_COMPILES) - n0} post-warmup compiles on {impl} {kw}"
         )
-    assert outs["xla"] == outs["pallas-dma"], outs
+        return eng.finish(sid)
+
+    want = run("xla")
+    with stream_kernel():
+        assert run("pallas-stream") == want
+        run("xla", kv_quantize="int8")      # the kernel has no int8 reader

@@ -136,19 +136,17 @@ def test_an_engine_refuses_what_cannot_carry_the_state(kw, said):
     assert said in str(e.value)
 
 
-@pytest.mark.parametrize("backend", ["pallas", "pallas-dma", "pallas-stream"])
-def test_an_engine_takes_a_pallas_attention_backend(backend, monkeypatch):
+def test_an_engine_takes_the_streaming_kernel(stream_kernel):
     """Refused until PR 29 out of caution, not by a finding: the state-slot
     columns never reach attention (``_row_state``) and the GQA layers are
     plain GQA to the op (NoPE and the gate sit outside it)."""
     from opsagent_tpu.serving.sampler import SamplingParams
 
-    monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", backend)
-    monkeypatch.setenv("OPSAGENT_PALLAS_INTERPRET", "1")
-    eng = Engine(engine_cfg())
-    assert eng.impl_info()["attn_impl"] == backend
-    out = eng.generate([[257, 3, 1, 4, 1, 5, 9, 2, 6]],
-                       SamplingParams(max_tokens=3))
+    with stream_kernel():
+        eng = Engine(engine_cfg())
+        assert eng.impl_info()["attn_impl"] == "pallas-stream"
+        out = eng.generate([[257, 3, 1, 4, 1, 5, 9, 2, 6]],
+                           SamplingParams(max_tokens=3))
     assert len(out[0]) == 3
 
 

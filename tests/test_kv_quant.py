@@ -3,8 +3,8 @@
 Decode-step KV reads are the dominant non-weight HBM term at serving
 shapes (PERF.md roofline); int8 pages + per-token-per-head scales halve
 them. These tests pin the write/read roundtrip against the bf16 page
-path and the engine-level wiring (config validation, backend forcing,
-end-to-end generation).
+path and the engine-level wiring (config validation, the gather as the
+only int8 reader, end-to-end generation).
 """
 
 import jax.numpy as jnp
@@ -14,7 +14,7 @@ import pytest
 from opsagent_tpu.ops.attention import (
     QuantizedPages,
     paged_decode_attention,
-    paged_prefix_attention,
+    paged_ragged_attention_auto,
     quantize_kv_rows,
     write_kv_pages,
 )
@@ -76,8 +76,8 @@ def test_quantized_pages_attention_matches_fp(reader):
         ref = paged_decode_attention(q1, kf, vf, table, lens)
         got = paged_decode_attention(q1, kq, vq, table, lens)
     else:
-        ref = paged_prefix_attention(q, kf, vf, table, start, lens)
-        got = paged_prefix_attention(q, kq, vq, table, start, lens)
+        ref = paged_ragged_attention_auto(q, kf, vf, table, start, lens)
+        got = paged_ragged_attention_auto(q, kq, vq, table, start, lens)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), rtol=5e-2, atol=5e-2
     )
@@ -127,148 +127,57 @@ def test_quantized_pages_layer_form_and_chunked_writes():
         )
 
 
-# -- pallas-dma quantized kernel ---------------------------------------------
+# -- under a tp mesh: split pages and their scale planes shard by kv head -----
 
-def test_pallas_dma_quantized_matches_xla_reader():
-    """The manual-DMA kernel fed QuantizedPages (interpret mode) must
-    match the XLA gather reader on the same quantized cache — same
-    dequantize math, different data path."""
-    from opsagent_tpu.ops.paged_attention_pallas import (
-        paged_decode_attention_pallas_dma,
-    )
-
-    rng = np.random.default_rng(5)
-    B, S, K, D, P, MaxP, N = 2, 20, 2, 32, 4, 8, 16
-    q, k, v, table = _rand_case(rng, B, S, K, D, P, MaxP, N)
-    start = jnp.zeros((B,), jnp.int32)
-    lens = jnp.full((B,), S, jnp.int32)
-    kq, vq = write_kv_pages(
-        _pages(N, P, K, D, True), _pages(N, P, K, D, True),
-        k, v, table, start, valid_len=lens,
-    )
-    q1 = q[:, -1]
-    ref = paged_decode_attention(q1, kq, vq, table, lens)
-    got = paged_decode_attention_pallas_dma(
-        q1, kq, vq, table, lens, interpret=True
-    )
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5
-    )
-
-
-def test_pallas_dma_quantized_layer_form():
-    """Whole-cache [L, N, ...] QuantizedPages with a layer offset through
-    the dma kernel (interpret) vs the XLA reader."""
-    from opsagent_tpu.ops.paged_attention_pallas import (
-        paged_decode_attention_pallas_dma,
-    )
-    from opsagent_tpu.ops.attention import QuantizedPages
-
-    rng = np.random.default_rng(6)
-    B, S, K, D, P, MaxP, N, L = 1, 10, 2, 16, 4, 4, 8, 3
-    q, k, v, table = _rand_case(rng, B, S, K, D, P, MaxP, N)
-    start = jnp.zeros((B,), jnp.int32)
-    lens = jnp.full((B,), S, jnp.int32)
-    pages = QuantizedPages(
-        jnp.zeros((L, N, P, K, D), jnp.int8),
-        jnp.ones((L, N, P, K), jnp.float32),
-    )
-    kq = write_kv_pages(
-        pages, pages, k, v, table, start,
-        valid_len=lens, layer=jnp.int32(2),
-    )[0]
-    q1 = q[:, -1]
-    ref = paged_decode_attention(q1, kq, kq, table, lens, layer=jnp.int32(2))
-    got = paged_decode_attention_pallas_dma(
-        q1, kq, kq, table, lens, interpret=True, layer=jnp.int32(2)
-    )
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5
-    )
-
-
-@pytest.mark.slow
-def test_pallas_dma_quantized_at_bench_8b_decode_shape():
-    """Interpret parity at the EXACT pallas-dma-kv bench stage shape
-    (B=32, K=8, D=128, P=64, MaxP=12, int8 pages, ragged + one full row)
-    — validated before the stage burns chip time, like the bf16 twin in
-    test_pallas_paged."""
-    from opsagent_tpu.ops.attention import QuantizedPages
-    from opsagent_tpu.ops.paged_attention_pallas import (
-        paged_decode_attention_pallas_dma,
-    )
-
-    rng = np.random.default_rng(43)
-    B, K, D, P, MaxP, N = 32, 8, 128, 64, 12, 32 * 12 + 2
-    H = 32
-    lengths = np.asarray(
-        [MaxP * P] + [int(rng.integers(1, MaxP * P + 1)) for _ in range(B - 1)],
-        np.int32,
-    )
-    table = np.full((B, MaxP), -1, np.int32)
-    free = list(range(N))
-    for b in range(B):
-        for i in range(-(-int(lengths[b]) // P)):
-            table[b, i] = free.pop()
-    # f32 queries: both paths then compute in f32 and must agree tightly
-    # (the kernel applies scales in score space, the reader dequantizes —
-    # algebraically identical). bf16 rounding-order differences between
-    # the two paths are covered by the bf16 twin in test_pallas_paged;
-    # THIS test de-risks grid/scratch/indexing at the exact stage shape.
-    q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
-    kq = QuantizedPages(
-        jnp.asarray(rng.integers(-127, 128, size=(N, P, K, D)), jnp.int8),
-        jnp.asarray(rng.uniform(0.01, 0.2, size=(N, P, K)), jnp.float32),
-    )
-    vq = QuantizedPages(
-        jnp.asarray(rng.integers(-127, 128, size=(N, P, K, D)), jnp.int8),
-        jnp.asarray(rng.uniform(0.01, 0.2, size=(N, P, K)), jnp.float32),
-    )
-    tbl = jnp.asarray(table)
-    lens = jnp.asarray(lengths)
-    ref = paged_decode_attention(q, kq, vq, tbl, lens)
-    got = paged_decode_attention_pallas_dma(
-        q, kq, vq, tbl, lens, interpret=True
-    )
-    # atol 1e-3: f32 blockwise online softmax vs the reference's full
-    # softmax reorder accumulation over up to 768 tokens; observed worst
-    # deviation ~3e-4 on near-zero outputs.
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(ref, np.float32),
-        rtol=1e-3, atol=1e-3,
-    )
-
-
-def test_pallas_dma_quantized_under_tp_matches_oracle():
-    """QuantizedPages through the tp shard_map wrapper: the scale-plane
-    PartitionSpec pytree must mirror the leaf structure and put tp on the
-    kv-head axis (one fewer trailing dim than the values)."""
+@pytest.mark.parametrize("reader", ["decode", "ragged"])
+def test_split_int8_pages_under_tp_match_the_float_oracle(reader):
+    """16 kv heads over tp=2 leave 8 a shard, which the gather holds split
+    (``page_form``): values shard on the kv-head axis and the scale planes
+    on theirs, one fewer trailing dim. The gather over them on the mesh
+    must match the unsharded float pages to int8-rounding tolerance."""
     import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from opsagent_tpu.ops.attention import paged_decode_attention_pallas_tp
+    from opsagent_tpu.ops.attention import page_form
     from opsagent_tpu.parallel.mesh import make_mesh
 
-    if len(jax.devices()) < 2:
-        import pytest
-
-        pytest.skip("needs >= 2 devices")
     mesh = make_mesh(tp=2, dp=1, sp=1, devices=jax.devices()[:2])
     rng = np.random.default_rng(7)
-    B, S, K, D, P, MaxP, N = 2, 17, 2, 32, 8, 4, 10
-    q, k, v, table = _rand_case(rng, B, S, K, D, P, MaxP, N)
+    B, S, K, D, PG, MaxP, N = 2, 17, 16, 32, 8, 4, 10
+    assert page_form(K // 2, "xla") == "split"
+    q, k, v, table = _rand_case(rng, B, S, K, D, PG, MaxP, N)
     start = jnp.zeros((B,), jnp.int32)
     lens = jnp.full((B,), S, jnp.int32)
-    kq, vq = write_kv_pages(
-        _pages(N, P, K, D, True), _pages(N, P, K, D, True),
+    kf, vf = write_kv_pages(
+        _pages(N, PG, K, D, False), _pages(N, PG, K, D, False),
         k, v, table, start, valid_len=lens,
     )
-    q1 = q[:, -1]
-    ref = paged_decode_attention(q1, kq, vq, table, lens)
-    got = paged_decode_attention_pallas_tp(
-        q1, kq, vq, table, lens, mesh, interpret=True, impl="pallas-dma",
+    kq, vq = write_kv_pages(
+        _pages(N, PG, K, D, True), _pages(N, PG, K, D, True),
+        k, v, table, start, valid_len=lens,
     )
+
+    def on(x, *spec):
+        return jax.device_put(x, NamedSharding(mesh, P(*spec)))
+
+    kq, vq = (
+        QuantizedPages(
+            on(p.q, None, None, "tp", None), on(p.scale, None, None, "tp")
+        )
+        for p in (kq, vq)
+    )
+    if reader == "decode":
+        ref = paged_decode_attention(q[:, -1], kf, vf, table, lens)
+        got = jax.jit(paged_decode_attention)(
+            on(q[:, -1], None, "tp", None), kq, vq, table, lens
+        )
+    else:
+        ref = paged_ragged_attention_auto(q, kf, vf, table, start, lens)
+        got = jax.jit(paged_ragged_attention_auto)(
+            on(q, None, None, "tp", None), kq, vq, table, start, lens
+        )
     np.testing.assert_allclose(
-        np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5
+        np.asarray(got), np.asarray(ref), rtol=5e-2, atol=5e-2
     )
 
 
@@ -343,32 +252,6 @@ def test_engine_kv_quantize_close_to_fp_cache_on_pinned_context():
             assert abs(lp - q_by_id[tid]) < 0.25, (
                 f"token {tid}: fp {lp} vs int8 {q_by_id[tid]}"
             )
-
-
-def test_engine_keeps_pallas_dma_with_kv_quantize_at_aligned_shapes(
-    monkeypatch,
-):
-    """kv_quantize does not force xla when the manual-DMA kernel (which
-    has a quantized path) is selected AND the shapes satisfy Mosaic's
-    alignment rules: head_dim a multiple of 128, and the kv heads of one
-    shard a multiple of the page dtype's sublane packing (4 for int8).
-    Short of either, the engine refuses with the compiler's reason."""
-    from dataclasses import replace
-
-    from opsagent_tpu.models.config import get_config_preset
-    from opsagent_tpu.serving.engine import (
-        BackendRefused, Engine, EngineConfig,
-    )
-
-    monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", "pallas-dma")
-    cfg128 = replace(
-        get_config_preset("tiny-test"), head_dim=128, num_kv_heads=4
-    )
-    kw = dict(kv_quantize="int8", warmup=False, **_engine_kwargs())
-    eng = Engine(EngineConfig(tp=1, **kw), model_cfg=cfg128)
-    assert eng.attn_impl == "pallas-dma"
-    with pytest.raises(BackendRefused, match=r"2 kv head\(s\) per shard"):
-        Engine(EngineConfig(tp=2, **kw), model_cfg=cfg128)
 
 
 def test_engine_rejects_bad_kv_quantize_and_mla_combo():

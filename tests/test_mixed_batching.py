@@ -280,27 +280,23 @@ def test_mixed_dispatch_composition_metrics_recorded():
     eng.finish(a), eng.finish(b)
 
 
-def test_mixed_backends_byte_identical_with_int8_kv(monkeypatch):
-    """step_mixed with kv_quantize="int8" across attention backends
-    (xla gather vs the ragged manual-DMA kernel, interpret off-chip):
-    chunked admission + interleaved decode lanes must produce
-    byte-identical greedy output, the resolved impl must be the
-    requested backend (the old QuantizedPages fallback forced xla), and
-    no mixed composition may compile post-warmup."""
+def test_mixed_readers_byte_identical_and_int8_kv_compiles_nothing(
+    stream_kernel
+):
+    """step_mixed across the two attention readers (the xla gather vs the
+    streaming kernel, interpreted off-chip): chunked admission +
+    interleaved decode lanes must produce byte-identical greedy output,
+    and no mixed composition may compile post-warmup, under either reader
+    nor with kv_quantize="int8", which only the gather reads."""
     prompts = [
         [257] + list(range(1, 12)),
         [257] + [5, 9, 2, 8, 1, 7, 3, 3, 4, 6, 2, 9, 8, 1, 5, 5, 2],
         [257, 4, 4, 2],
     ]
-    monkeypatch.setenv("OPSAGENT_PALLAS_INTERPRET", "1")
-    outs = {}
-    for backend in ("xla", "pallas-dma"):
-        monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", backend)
-        cfg = EngineConfig(
-            mixed_batching=True, kv_quantize="int8", **BASE
-        )
-        eng = Engine(cfg)
-        assert eng.attn_impl == backend
+
+    def run(impl, **kw):
+        eng = Engine(EngineConfig(mixed_batching=True, **kw, **BASE))
+        assert eng.attn_impl == impl
         eng.warmup("sessions")
         sampling = SamplingParams(max_tokens=8)
         n0 = len(_COMPILES)
@@ -316,8 +312,12 @@ def test_mixed_backends_byte_identical_with_int8_kv(monkeypatch):
         while live:
             eng.step_mixed(live, {})
             live = [s for s in live if not eng.sequences[s].done]
-        outs[backend] = [eng.finish(s) for s in sids]
         assert len(_COMPILES) == n0, (
-            f"{len(_COMPILES) - n0} post-warmup compiles on {backend}"
+            f"{len(_COMPILES) - n0} post-warmup compiles on {impl} {kw}"
         )
-    assert outs["xla"] == outs["pallas-dma"], outs
+        return [eng.finish(s) for s in sids]
+
+    want = run("xla")
+    with stream_kernel():
+        assert run("pallas-stream") == want
+        run("xla", kv_quantize="int8")      # the kernel has no int8 reader

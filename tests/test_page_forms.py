@@ -42,18 +42,43 @@ LAYERS, LAYER = 3, 1       # the layer-stacked form, and the layer written
 @pytest.mark.parametrize("kv_heads,impl,form", [
     (1, "xla", "split"),        # MLA's latent; one head of a tp shard
     (2, "xla", "merged"),       # the 72B's 8 heads over tp=4
+    (3, "xla", "merged"),
     (4, "xla", "merged"),       # the 7B: a T(4,128) tile if split
     (6, "xla", "merged"),
+    (7, "xla", "merged"),
     (8, "xla", "split"),        # the 72B on one chip: the tile is full
     (16, "xla", "split"),
-    (4, "pallas", "split"),     # the kernels index [.., P, K, D] blocks
-    (4, "pallas-dma", "split"),
-    (4, "pallas-stream", "merged"),   # a kv head is a lane slice of the row
-    (8, "pallas-stream", "merged"),   # at any head count: nothing is gathered
     (1, "pallas-stream", "split"),    # one head: the same bytes, a unit axis
+    (2, "pallas-stream", "merged"),   # a kv head is a lane slice of the row
+    (3, "pallas-stream", "merged"),
+    (4, "pallas-stream", "merged"),
+    (6, "pallas-stream", "merged"),
+    (7, "pallas-stream", "merged"),
+    (8, "pallas-stream", "merged"),   # at any head count: nothing is gathered
+    (16, "pallas-stream", "merged"),
 ])
 def test_page_form_by_kv_heads_and_backend(kv_heads, impl, form):
+    """The form a reader's pages are held in, and what the kernel's
+    dispatch makes of each form at that head count: it takes the one
+    ``page_form`` gives it and refuses the other by name (the gather reads
+    either, by the trailing axis)."""
+    from opsagent_tpu.ops.attention import _require_form
+
     assert page_form(kv_heads, impl) == form
+    D = 8
+    held = {
+        "split": jnp.zeros((N, P, kv_heads, D)),
+        "merged": jnp.zeros((N, P, kv_heads * D)),
+    }
+    if impl == "xla":
+        assert pages_merged(held[form], D) == (form == "merged")
+        return
+    _require_form(held[form], D)
+    if kv_heads > 1:    # one head is the same bytes either way
+        with pytest.raises(ValueError, match="split pages"):
+            _require_form(held["split"], D)
+        # ... unless the array's heads are tp shards of one head each.
+        _require_form(held["split"], D, tp=kv_heads)
 
 
 @pytest.mark.parametrize("kv_shards,form", [(1, "merged"), (2, "split")])
@@ -188,25 +213,6 @@ def test_the_mla_latent_cache_is_one_split_head():
     cache = llama.make_cache(TINY_MLA_LATENT, 8, 4, jnp.float32)
     assert cache["k"].shape[-2:] == (1, TINY_MLA_LATENT.mla.latent_dim)
     assert cache["v"].shape[-2:] == (1, 1)
-
-
-def test_the_pallas_backends_refuse_merged_pages():
-    q = jnp.zeros((1, 4, 8))
-    pages = jnp.zeros((4, 4, 16))     # [N, P, K*D] at 2 heads of 8
-    table, lengths = jnp.zeros((1, 2), jnp.int32), jnp.ones((1,), jnp.int32)
-    from opsagent_tpu.ops.attention import (
-        paged_decode_attention_auto, paged_ragged_attention_auto,
-    )
-
-    with pytest.raises(ValueError, match="merged pages"):
-        paged_decode_attention_auto(
-            q, pages, pages, table, lengths, impl="pallas"
-        )
-    with pytest.raises(ValueError, match="merged pages"):
-        paged_ragged_attention_auto(
-            q[:, None], pages, pages, table, lengths, lengths,
-            impl="pallas-dma",
-        )
 
 
 # -- tensor parallel: a shard of merged pages is whole heads -----------------

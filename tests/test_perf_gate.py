@@ -227,7 +227,7 @@ def test_ragged_sweep_rows_gate_higher_better(tmp_path):
     from opsagent_tpu.cli.perfcheck import _higher_better
 
     assert _higher_better("tok/s/chip") is True
-    cell = ("mixed_ragged_throughput[bench-8b,int8,kv-int8,pallas-dma,"
+    cell = ("mixed_ragged_throughput[bench-8b,int8,kv-int8,xla,"
             "B=32,tpu]")
     base = _jsonl(tmp_path / "base.jsonl", BASELINE + [_row(cell, 2400.0)])
     slower = _jsonl(tmp_path / "cur.jsonl", [_row(cell, 2400.0 * 0.7)])
@@ -235,7 +235,7 @@ def test_ragged_sweep_rows_gate_higher_better(tmp_path):
     faster = _jsonl(tmp_path / "cur2.jsonl", [_row(cell, 2400.0 * 1.3)])
     assert run_perf_check(faster, baseline=base) == 0
     fresh = _jsonl(tmp_path / "cur3.jsonl", [
-        _row("mixed_ragged_throughput[bench-8b,int4,kv-int8,pallas-dma,"
+        _row("mixed_ragged_throughput[bench-8b,int4,kv-int8,xla,"
              "B=32,tpu]", 2800.0),
         _row("paged_decode_throughput[bench-8b,int8,B=32,tpu]", 1899.0),
     ])

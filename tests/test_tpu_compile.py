@@ -7,15 +7,17 @@ The TPU compiler is installed beside JAX and compiles for a device that
 is DESCRIBED (``v5e:2x2``), not attached, so every Pallas entry point is
 compiled here at the widths of the model the chip smoke serves
 (``qwen2.5-7b-instruct``: 28/4 heads of 128, hidden 3584, FFN 18944, vocab
-152064; 2048 pages of 16 tokens, 320 per sequence) — kernels only, a
-second or two each. A combination the engine refuses at init
-(``ops.attention.pallas_refusal``) is pinned from both sides: the
-compiler's refusal, and the engine's with the same reason.
+152064; 2048 pages of 16 tokens, 320 per sequence) and at the benchmark
+cells' shapes — kernels only, a second or two each; and so is the gather
+where it is the only reader (int8 pages, MLA). A combination the choice
+function sends to the gather (``ops.attention.pallas_refusal``) is pinned
+from both sides: the kernel's or the compiler's refusal, and the choice.
 
 A compile that passes is not a chip run, and nothing here is a time.
 """
 
 import dataclasses
+import json
 import os
 import re
 
@@ -33,7 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from opsagent_tpu.models.config import get_config_preset
 from opsagent_tpu.models.quant import QuantizedLinear, QuantizedLinear4
-from opsagent_tpu.ops import attention, paged_attention_pallas as pap
+from opsagent_tpu.ops import attention
 from opsagent_tpu.ops import quant_matmul_pallas as qmp
 from opsagent_tpu.ops.attention import QuantizedPages, pallas_refusal
 
@@ -68,67 +70,11 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _pages(sds, kv: str, k: int = K, d: int = D):
-    """(k_pages, v_pages) shapes in the engine's whole-cache layout."""
-    def one():
-        if kv == "int8":
-            return QuantizedPages(
-                sds((L, N, PAGE, k, d), jnp.int8),
-                sds((L, N, PAGE, k), jnp.float32),
-            )
-        return sds((L, N, PAGE, k, d), jnp.bfloat16)
-
-    return one(), one()
-
-
-def _attention(sds, kernel: str, kv: str, s: int, k: int = K, d: int = D):
-    """Compile one paged-attention kernel; s == 0 means the decode form."""
-    h = k * (H // K)
-    kp, vp = _pages(sds, kv, k, d)
-    table, rows = sds((B, MAXP), jnp.int32), sds((B,), jnp.int32)
-    layer = sds((), jnp.int32)
-    if s == 0:
-        fn = getattr(pap, f"paged_decode_attention_{kernel}")
-        return _compile(
-            lambda q, k_, v_, t, ln, ly: fn(q, k_, v_, t, ln, layer=ly),
-            sds((B, h, d), jnp.bfloat16), kp, vp, table, rows, layer,
-        )
-    fn = getattr(pap, f"paged_ragged_attention_{kernel}")
-    return _compile(
-        lambda q, k_, v_, t, st, ql, ly: fn(q, k_, v_, t, st, ql, layer=ly),
-        sds((B, s, h, d), jnp.bfloat16), kp, vp, table, rows, rows, layer,
-    )
-
-
 def _one_chip(devices):
     one = SingleDeviceSharding(devices[0])
     return lambda shape, dtype: jax.ShapeDtypeStruct(
         shape, dtype, sharding=one
     )
-
-
-# -- paged attention: kernel x page dtype x query rows -----------------------
-@pytest.mark.parametrize(
-    "kernel,kv,s",
-    [
-        (kernel, kv, s)
-        for kernel in ("pallas", "pallas_dma")
-        # s: 0 = the decode form; ragged at decode rows and at the
-        # smallest and the largest mixed bucket (the largest with int8
-        # pages only: the same body plus the scales, ~4 s a compile).
-        for kv, s in [
-            ("bf16", 0), ("bf16", 1), ("bf16", 16),
-            ("int8", 0), ("int8", 1), ("int8", 16), ("int8", 128),
-        ]
-    ],
-)
-def test_paged_attention_kernels_compile(v5e, kernel, kv, s):
-    """Every paged-attention entry point, bf16 and int8 pages. The grid
-    kernels with int8 pages were refused here ("last two dimensions of
-    your block shape ...": a (1, 1, P*K) scale block whose lane dim is
-    64 at 4 kv heads x 16-token pages) until the scale planes got a unit
-    sublane axis, so that the block spans the array's last two dims."""
-    assert _attention(_one_chip(v5e), kernel, kv, s) is not None
 
 
 # -- the streaming kernel at the benchmark cells' own engine shapes -----------
@@ -180,6 +126,160 @@ def test_stream_kernel_compiles_at_one_kv_head(v5e):
     compiled = _stream(
         _one_chip(v5e), b=8, s=16, h=7, k=1, maxp=MAXP, n=N, layers=L)
     assert _copies_of(compiled.as_text(), N * PAGE * D) == []
+
+
+@pytest.mark.parametrize("s", [0, 1, 16, 128])
+def test_stream_kernel_compiles_at_serve_engines_default_shapes(v5e, s):
+    """What ``serve-engine --model-name qwen2.5-7b-instruct`` runs with no
+    option set (this file's N, PAGE, MAXP, B), which no cell has: the
+    decode form (s == 0) and ragged at decode rows and at the smallest and
+    the largest default mixed bucket."""
+    sds = _one_chip(v5e)
+    if s:
+        compiled = _stream(sds, b=B, s=s, h=H, k=K, maxp=MAXP, n=N, layers=L)
+    else:
+        pages = sds((L, N, PAGE, K * D), jnp.bfloat16)
+        compiled = _compile(
+            lambda q, k_, v_, t, ln, ly: attention.paged_decode_attention_auto(
+                q, k_, v_, t, ln, impl="pallas-stream", layer=ly),
+            sds((B, H, D), jnp.bfloat16), pages, pages,
+            sds((B, MAXP), jnp.int32), sds((B,), jnp.int32),
+            sds((), jnp.int32),
+        )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("s", [4, 64])
+def test_stream_kernel_compiles_at_pages_of_64_slots(v5e, s):
+    """What ``bench.py``'s stages run on a chip now that nothing pins them
+    to the gather: ``bench-8b`` (32/8 heads of 128) at its own page size
+    of 64 slots, 8 pages a sequence, 32 rows, at the ragged sweep's two
+    mixed buckets. Every cell holds pages of 16."""
+    sds = _one_chip(v5e)
+    cfg = get_config_preset("bench-8b")
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_) == (32, 8, 128)
+    pages = sds((cfg.num_layers, 256, 64, 8 * 128), jnp.bfloat16)
+    compiled = _compile(
+        lambda q, k_, v_, t, st, ql, ly: attention.paged_ragged_attention_auto(
+            q, k_, v_, t, st, ql, impl="pallas-stream", layer=ly),
+        sds((32, s, 32, 128), jnp.bfloat16), pages, pages,
+        sds((32, 8), jnp.int32), sds((32,), jnp.int32),
+        sds((32,), jnp.int32), sds((), jnp.int32),
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the gather where it is the only reader: int8 pages, MLA ------------------
+def _gather(sds, pages, *, b, s, h, d, maxp):
+    """Compile the xla reader over a layer-stacked cache; s == 0 means the
+    decode form (what a fused decode block runs)."""
+    table, rows = sds((b, maxp), jnp.int32), sds((b,), jnp.int32)
+    if s == 0:
+        return _compile(
+            lambda q, k_, v_, t, ln, ly: attention.paged_decode_attention_auto(
+                q, k_, v_, t, ln, impl="xla", layer=ly),
+            sds((b, h, d), jnp.bfloat16), pages, pages, table, rows,
+            sds((), jnp.int32),
+        )
+    return _compile(
+        lambda q, k_, v_, t, st, ql, ly: attention.paged_ragged_attention_auto(
+            q, k_, v_, t, st, ql, impl="xla", layer=ly),
+        sds((b, s, h, d), jnp.bfloat16), pages, pages, table, rows, rows,
+        sds((), jnp.int32),
+    )
+
+
+# The decode form (0), the smallest mixed bucket and the cell's largest
+# one; cell 3 has the one mixed bucket, so its prefill bucket, where the
+# gather walks the score matrix in blocks.
+INT8_ROWS = {
+    "qwen25-7b.agent-turns": (0, 16, 32),
+    "qwen25-72b-l8.long-generate": (0, 16, 64),
+    "solar-open2-ep8-l8.doc-turns": (0, 16, 256),
+}
+
+
+@pytest.mark.parametrize(
+    "cell,s", [(cell, s) for cell, rows in INT8_ROWS.items() for s in rows]
+)
+def test_int8_pages_gather_compiles_at_the_cells_shapes(v5e, cell, s):
+    """``kv_quantize="int8"`` on the chip: the choice sends it to the
+    gather (the kernel has no int8 reader), over ``QuantizedPages`` in the
+    form ``page_form`` gives the gather at the cell's kv heads (merged at
+    4, split at 8) with the scale planes beside them. The compiler takes
+    it and the program fits the chip."""
+    sds = _one_chip(v5e)
+    c = STREAM_CELLS[cell]
+    k, n, layers = c["k"], c["n"], c["layers"]
+    merged = attention.page_form(k, "xla") == "merged"
+    assert merged == (k == 4)
+    pages = QuantizedPages(
+        sds((layers, n, PAGE, k * D) if merged else (layers, n, PAGE, k, D),
+            jnp.int8),
+        sds((layers, n, PAGE, k), jnp.float32),
+    )
+    compiled = _gather(
+        sds, pages, b=c["b"], s=s, h=c["h"], d=D, maxp=c["maxp"])
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_mla_latent_gather_compiles_and_copies_the_latent_cache(v5e):
+    """MLA serves through the gather on the chip too: deepseek-v2-lite's
+    absorbed queries (16 heads of the 576-wide latent: 512 + 64 rope,
+    off the 128 lanes) over its one-head latent pages, which keys and
+    values share, at ``serve-engine``'s default geometry. The compiler
+    takes it, and copies the latent cache WHOLE to move the unit
+    kv-head axis out from between a page's 16 slots and the latent (one
+    copy a reader of the array; the whole mixed step has the same two at
+    its entry and its exit, not in the layer loop: ROADMAP S3). Pinned as
+    it is: no cell serves an MLA model, and the cure is a page form."""
+    cfg = get_config_preset("deepseek-v2-lite")
+    assert cfg.mla.latent_cache and cfg.mla.latent_dim % 128
+    sds = _one_chip(v5e)
+    layers = 2
+    pages = sds((layers, N, PAGE, 1, cfg.mla.latent_dim), jnp.bfloat16)
+    assert attention.paged_attention_backend(
+        platform="tpu", head_dim=cfg.head_dim_, kv_heads_per_shard=1,
+        page_itemsize=2, mla=True,
+    ) == "xla"
+    compiled = _gather(
+        sds, pages, b=B, s=16, h=cfg.num_heads, d=cfg.mla.latent_dim,
+        maxp=MAXP,
+    )
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" not in hlo
+    copies = _copies_of(hlo, layers * N * PAGE * cfg.mla.latent_dim)
+    assert len(copies) == 2, copies     # the keys' read and the values'
+
+
+@pytest.mark.parametrize("cell", list(STREAM_CELLS))
+def test_the_choice_for_each_cells_configuration(cell):
+    """``paged_attention_backend`` for the configuration each cell serves,
+    read from its file under benchmarks/configs: the streaming kernel on a
+    TPU, the gather on the CPU; a pure function of what it is given."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    name = {w["name"]: w["config"] for w in bench["workloads"]}[cell]
+    path = {c["name"]: c["file"] for c in bench["configs"]}[name]
+    with open(os.path.join(root, path)) as f:
+        config = json.load(f)
+    engine = config["engine"]
+    assert engine["dtype"] == "bfloat16" and "kv_quantize" not in engine
+    shapes = dict(
+        head_dim=config["head_dim"],
+        kv_heads_per_shard=config["num_key_value_heads"] // engine["tp"],
+        page_itemsize=2,
+    )
+    c = STREAM_CELLS[cell]
+    assert (c["k"], c["h"]) == (
+        config["num_key_value_heads"], config["num_attention_heads"])
+    assert (c["b"], c["maxp"], c["n"]) == (
+        engine["max_batch_size"], engine["max_pages_per_seq"],
+        engine["num_pages"])
+    assert attention.paged_attention_backend(
+        platform="tpu", **shapes) == "pallas-stream"
+    assert attention.paged_attention_backend(platform="cpu", **shapes) == "xla"
 
 
 # -- quantized matmul: weight dtype x projection x rows ----------------------
@@ -234,48 +334,6 @@ def test_quant_matmul_compiles_at_the_largest_mixed_bucket(v5e, mode):
 
 
 # -- tensor parallelism: the shard_map wrapper on the four devices -----------
-def _tp_mesh(devices):
-    return Mesh(np.array(devices).reshape(4), ("tp",))
-
-
-@pytest.mark.parametrize("form", ["decode", "ragged"])
-def test_grid_kernel_compiles_under_tp4(v5e, form):
-    """The (B, MaxP) grid kernel through the tp shard_map wrapper: four
-    shards of 7 query heads and ONE kv head each."""
-    mesh = _tp_mesh(v5e)
-
-    def sds(shape, dtype, spec=P()):
-        return jax.ShapeDtypeStruct(
-            shape, dtype, sharding=NamedSharding(mesh, spec)
-        )
-
-    pages = sds((L, N, PAGE, K, D), jnp.bfloat16,
-                P(None, None, None, "tp", None))
-    table, rows = sds((B, MAXP), jnp.int32), sds((B,), jnp.int32)
-    layer = sds((), jnp.int32)
-    if form == "decode":
-        compiled = _compile(
-            lambda q, k_, v_, t, ln, ly: (
-                attention.paged_decode_attention_pallas_tp(
-                    q, k_, v_, t, ln, mesh, layer=ly, impl="pallas"
-                )
-            ),
-            sds((B, H, D), jnp.bfloat16, P(None, "tp", None)),
-            pages, pages, table, rows, layer,
-        )
-    else:
-        compiled = _compile(
-            lambda q, k_, v_, t, st, ql, ly: (
-                attention.paged_ragged_attention_pallas_tp(
-                    q, k_, v_, t, st, ql, mesh, layer=ly, impl="pallas"
-                )
-            ),
-            sds((B, 16, H, D), jnp.bfloat16, P(None, None, "tp", None)),
-            pages, pages, table, rows, rows, layer,
-        )
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 @pytest.mark.parametrize("tp", [2, 4])
 def test_stream_kernel_compiles_under_tp(v5e, tp):
     """The streaming kernel through the tp shard_map wrapper at the 7B's
@@ -312,68 +370,7 @@ def test_stream_kernel_compiles_under_tp(v5e, tp):
     assert _copies_of(hlo, L * N * PAGE * K * D // tp) == []
 
 
-# -- what the compiler refuses, the engine refuses first ---------------------
-REFUSALS = [
-    # (id, model, tp, kv_quantize, kernel shapes (k, d, kv), Mosaic's words)
-    ("one-kv-head-per-shard", "qwen2.5-7b-instruct", 4, "",
-     (1, 128, "bf16"), r"dimension 2 must be aligned to tiling \(2\)"),
-    ("two-int8-kv-heads-per-shard", "qwen2.5-7b-instruct", 2, "int8",
-     (2, 128, "int8"), r"dimension 2 must be aligned to tiling \(4\)"),
-    # The kernel wrappers state this one themselves, ahead of Mosaic's
-    # "Slice shape along dimension 3 must be aligned to tiling (128), but
-    # is 64" (what the compiler says with the wrapper's check lifted).
-    ("head-dim-64", "bench-1b", 1, "",
-     (8, 64, "bf16"), r"needs head_dim % 128 == 0, got 64"),
-]
-
-
-@pytest.mark.parametrize(
-    "model,tp,kvq,shapes,words",
-    [r[1:] for r in REFUSALS], ids=[r[0] for r in REFUSALS],
-)
-def test_manual_dma_refusals_pinned_from_both_sides(
-    v5e, monkeypatch, model, tp, kvq, shapes, words
-):
-    """The manual-DMA kernels slice whole pages out of HBM, and Mosaic
-    wants the slice aligned to the memory tiling: head_dim to the 128
-    lanes, the kv heads of a shard to the sublane packing of the page
-    dtype (2 for bf16, 4 for int8). Short of that the chip's compiler
-    refuses the kernel — and the engine refuses the configuration at
-    init with that reason, before it builds anything; it never starts
-    as xla under the kernel's name."""
-    from opsagent_tpu.serving.engine import (
-        BackendRefused, Engine, EngineConfig,
-    )
-
-    k, d, kv = shapes
-    for s in (0, 16):
-        with pytest.raises(Exception, match=words):
-            _attention(_one_chip(v5e), "pallas_dma", kv, s, k=k, d=d)
-    cfg = get_config_preset(model)
-    assert (cfg.num_kv_heads // tp, cfg.head_dim_) == (k, d)
-    why = pallas_refusal(
-        "pallas-dma", head_dim=d, kv_heads_per_shard=k,
-        page_itemsize=1 if kvq else 2,
-    )
-    assert why is not None
-    monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", "pallas-dma")
-    monkeypatch.delenv("OPSAGENT_PALLAS_INTERPRET", raising=False)
-    with pytest.raises(BackendRefused) as refused:
-        Engine(EngineConfig(
-            model=model, tp=tp, kv_quantize=kvq, quantize="int8",
-        ))
-    assert str(refused.value) == why
-    # The aligned neighbours of each rule are the compiling cases above,
-    # and the rule says so too.
-    assert pallas_refusal(
-        "pallas-dma", head_dim=128, kv_heads_per_shard=4, page_itemsize=1
-    ) is None
-    assert pallas_refusal(
-        "pallas", head_dim=d, kv_heads_per_shard=k,
-        page_itemsize=1 if kvq else 2,
-    ) is None
-
-
+# -- what the kernel cannot read goes to the gather -------------------------
 STREAM_REFUSALS = [
     # (id, model, kv_quantize, kernel shapes (k, d, kv), the words)
     ("head-dim-64", "bench-1b", "", (8, 64, "bf16"), "128-lane tiling"),
@@ -389,13 +386,13 @@ STREAM_REFUSALS = [
 def test_stream_refusals_pinned_from_both_sides(
     v5e, monkeypatch, model, kvq, shapes, words
 ):
-    """The two rules ``pallas_refusal`` has for "pallas-stream": a head
-    dim off the 128 lanes (a kv head is a lane slice of the merged row)
-    and int8 pages (no reader). The dispatcher refuses each with the
-    words of the rule, ``auto`` sends such an engine to the gather on a
-    TPU, and an engine asked for the kernel BY NAME refuses at init; the
-    aligned bf16 neighbours are the compiling cases above."""
-    from opsagent_tpu.ops.attention import paged_attention_backend
+    """The two shape rules ``pallas_refusal`` has: a head dim off the 128
+    lanes (a kv head is a lane slice of the merged row) and int8 pages (no
+    reader). The dispatcher refuses each with the words of the rule, the
+    choice sends such an engine to the gather on a TPU, and an engine
+    whose choice is made to answer the kernel all the same refuses at
+    init with the rule's reason; the aligned bf16 neighbours are the
+    compiling cases above."""
     from opsagent_tpu.serving.engine import (
         BackendRefused, Engine, EngineConfig,
     )
@@ -420,9 +417,10 @@ def test_stream_refusals_pinned_from_both_sides(
     )
     why = pallas_refusal("pallas-stream", **rule)
     assert why is not None and words in why
-    monkeypatch.delenv("OPSAGENT_PAGED_BACKEND", raising=False)
-    assert paged_attention_backend(platform="tpu", **rule) == "xla"
-    monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", "pallas-stream")
+    assert attention.paged_attention_backend(platform="tpu", **rule) == "xla"
+    monkeypatch.setattr(
+        attention, "paged_attention_backend", lambda **_: "pallas-stream"
+    )
     monkeypatch.delenv("OPSAGENT_PALLAS_INTERPRET", raising=False)
     with pytest.raises(BackendRefused) as refused:
         Engine(EngineConfig(model=model, kv_quantize=kvq, quantize="int8"))
@@ -432,16 +430,21 @@ def test_stream_refusals_pinned_from_both_sides(
     ) is None
 
 
-def test_mla_refuses_the_pallas_backends(monkeypatch):
+def test_mla_refuses_the_kernel(monkeypatch):
     from opsagent_tpu.serving.engine import (
         BackendRefused, Engine, EngineConfig,
     )
 
+    assert attention.paged_attention_backend(
+        platform="tpu", head_dim=128, kv_heads_per_shard=1, page_itemsize=2,
+        mla=True,
+    ) == "xla"
+    monkeypatch.setattr(
+        attention, "paged_attention_backend", lambda **_: "pallas-stream"
+    )
     monkeypatch.delenv("OPSAGENT_PALLAS_INTERPRET", raising=False)
-    for impl in ("pallas", "pallas-dma", "pallas-stream"):
-        monkeypatch.setenv("OPSAGENT_PAGED_BACKEND", impl)
-        with pytest.raises(BackendRefused, match="MLA"):
-            Engine(EngineConfig(model="tiny-mla"))
+    with pytest.raises(BackendRefused, match="MLA"):
+        Engine(EngineConfig(model="tiny-mla"))
 
 
 def test_interpret_mode_is_an_error_on_the_chip(monkeypatch):
@@ -467,31 +470,33 @@ GEOMETRY = {
 }
 
 
-def _copies_of(hlo: str, elements: int) -> list[str]:
+def _copies_of(hlo: str, elements: int, axes=None) -> list[str]:
     """Names of the ``copy`` instructions of an optimized HLO module whose
-    result has at least ``elements`` elements, wherever they sit: in the
-    layer loop's body or at the program's entry or exit."""
+    result has at least ``elements`` elements (and, given ``axes``, those
+    axes in any order), wherever they sit: in the layer loop's body or at
+    the program's entry or exit."""
     out = []
     for name, dims in re.findall(
         r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]+)\]\S* copy\(", hlo, re.M
     ):
-        if np.prod([int(d) for d in dims.split(",")]) >= elements:
+        sizes = [int(d) for d in dims.split(",")]
+        if np.prod(sizes) >= elements and (
+            axes is None or sorted(sizes) == sorted(axes)
+        ):
             out.append(f"{name}[{dims}]")
     return out
 
 
-def _mixed_step(sds, preset: str, kv: str, impl: str = "xla"):
-    """Compile the engine's ``_mixed_carry`` program (decode_loop.
-    mixed_step_carry, the cache donated) at a preset's widths cut to two
-    layers, with the cache ``llama.make_cache`` gives it for the
-    attention backend ``impl``."""
+def _step_shapes(sds, preset: str, kv: str, impl: str):
+    """A preset's widths cut to two layers, with parameters, the cache
+    ``llama.make_cache`` gives it for the attention backend ``impl``, a
+    key, and makers of row-shaped arguments, all as shapes on the chip."""
     from opsagent_tpu.models import llama
-    from opsagent_tpu.serving import decode_loop
 
     cfg = dataclasses.replace(
         get_config_preset(preset), num_layers=STEP_LAYERS
     )
-    n, maxp = GEOMETRY[preset]
+    n, _ = GEOMETRY[preset]
     on_chip = lambda tree: jax.tree.map(  # noqa: E731
         lambda x: sds(x.shape, x.dtype), tree
     )
@@ -505,6 +510,28 @@ def _mixed_step(sds, preset: str, kv: str, impl: str = "xla"):
         )
     ))
     key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    return cfg, params, cache, key
+
+
+def _whole_cache_copies(compiled, cfg, preset: str, impl: str, axes=None):
+    whole = (
+        STEP_LAYERS * GEOMETRY[preset][0] * PAGE
+        * cfg.num_kv_heads * cfg.head_dim_
+    )
+    hlo = compiled.as_text()
+    assert ("tpu_custom_call" in hlo) == (impl != "xla")
+    return _copies_of(hlo, whole, axes)
+
+
+def _mixed_step(sds, preset: str, kv: str, impl: str = "xla"):
+    """Compile the engine's ``_mixed_carry`` program (decode_loop.
+    mixed_step_carry, the cache donated) at a preset's widths cut to two
+    layers, with the cache ``llama.make_cache`` gives it for the
+    attention backend ``impl``."""
+    from opsagent_tpu.serving import decode_loop
+
+    cfg, params, cache, key = _step_shapes(sds, preset, kv, impl)
+    maxp = GEOMETRY[preset][1]
     b = STEP_ROWS
     i32 = lambda *s: sds(s, jnp.int32)       # noqa: E731
     f32 = lambda *s: sds(s, jnp.float32)     # noqa: E731
@@ -521,16 +548,45 @@ def _mixed_step(sds, preset: str, kv: str, impl: str = "xla"):
         params, i32(b, STEP_TOKENS), flag(b), i32(b), i32(b), i32(b),
         flag(b), cache, i32(b, maxp), key, f32(b), i32(b), f32(b),
     ).compile()
-    whole = STEP_LAYERS * n * PAGE * cfg.num_kv_heads * cfg.head_dim_
-    hlo = compiled.as_text()
-    assert ("tpu_custom_call" in hlo) == (impl != "xla")
-    return cfg, cache, _copies_of(hlo, whole)
+    return cfg, cache, _whole_cache_copies(compiled, cfg, preset, impl)
+
+
+def _decode_block(sds, preset: str, impl: str, steps: int = 8):
+    """Compile the fused decode block (decode_loop.decode_block: ``steps``
+    greedy passes under one scan, the cache its carry and donated), what
+    cell 2 runs between admissions."""
+    from opsagent_tpu.serving import decode_loop
+
+    cfg, params, cache, key = _step_shapes(sds, preset, "", impl)
+    maxp = GEOMETRY[preset][1]
+    b = STEP_ROWS
+    i32 = lambda *s: sds(s, jnp.int32)       # noqa: E731
+    f32 = lambda *s: sds(s, jnp.float32)     # noqa: E731
+
+    def block(params, tokens, write_at, active, budgets, cache, table, key,
+              temps, top_k, top_p, eos, pad):
+        return decode_loop.decode_block(
+            params, cfg, tokens, write_at, active, budgets, cache, table,
+            key, temps, top_k, top_p, eos, pad, n_steps=steps, greedy=True,
+            attn_impl=impl,
+        )
+
+    compiled = jax.jit(block, donate_argnames=("cache",)).lower(
+        params, i32(b), i32(b), sds((b,), jnp.bool_), i32(b), cache,
+        i32(b, maxp), key, f32(b), i32(b), f32(b), i32(), i32(),
+    ).compile()
+    # By shape, not by size alone: at the 72B's widths one bf16 projection
+    # stack [2, 8192, 8192] is larger than a K array, and this program
+    # copies one at its entry (the cells' weights are int8: not compiled
+    # here, ROADMAP S2).
+    return _whole_cache_copies(compiled, cfg, preset, impl, cache["k"].shape)
 
 
 @pytest.mark.parametrize("preset,kv,impl,form", [
     ("qwen2.5-7b-instruct", "", "xla", "merged"),    # cell 1: 4 kv heads
     ("qwen2.5-7b-instruct", "int8", "xla", "merged"),
     ("qwen2.5-72b-instruct", "", "xla", "split"),    # cell 2's widths: 8
+    ("qwen2.5-72b-instruct", "int8", "xla", "split"),
     # What the chip runs since PR 29: the kernel reads merged pages at any
     # head count, and the page write's scatter runs in the same tiling.
     ("qwen2.5-7b-instruct", "", "pallas-stream", "merged"),
@@ -552,6 +608,18 @@ def test_no_step_copies_a_whole_k_or_v_array(v5e, preset, kv, impl, form):
     row = (k * d,) if form == "merged" else (k, d)
     assert cache["k"].shape == (STEP_LAYERS, n, PAGE) + row
     assert copies == []
+
+
+@pytest.mark.parametrize("preset,impl", [
+    ("qwen2.5-7b-instruct", "pallas-stream"),
+    ("qwen2.5-72b-instruct", "pallas-stream"),    # cell 2's widths
+    ("qwen2.5-72b-instruct", "xla"),
+])
+def test_no_decode_block_copies_a_whole_k_or_v_array(v5e, preset, impl):
+    """The fused decode block carries the cache through a scan over its
+    steps as well as over the layers; neither loop, nor the program's
+    entry or exit, holds a copy as large as one layer-stacked K array."""
+    assert _decode_block(_one_chip(v5e), preset, impl) == []
 
 
 def test_split_pages_at_four_kv_heads_are_copied_whole_in_every_layer(v5e):
