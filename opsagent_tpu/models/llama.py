@@ -961,14 +961,18 @@ def _yarn_q_scale(cfg: ModelConfig) -> float:
 
 @scoped("attn_qkv")
 def _qkv_rope(
-    x: jax.Array, lp: Params, cfg: ModelConfig, cos, sin
+    x: jax.Array, lp: Params, cfg: ModelConfig, cos, sin, rows=None
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """q/k/v with RoPE applied, dispatched on the attention family. The
     rope tables must be built with ``cfg.rope_dim_`` (the decoupled rope
-    part under MLA, the full head otherwise)."""
+    part under MLA, the full head otherwise). ``rows`` un-packs a packed
+    stream (``Pack.rows``) where the tables and the caller want rows: the
+    projections run over the packed tokens, RoPE over rows."""
     if cfg.mla is not None:
-        return _qkv_mla(x, lp, cfg, cos, sin)
+        return _qkv_mla(x if rows is None else rows(x), lp, cfg, cos, sin)
     q, k, v = _qkv(x, lp, cfg)
+    if rows is not None:
+        q, k, v = rows(q), rows(k), rows(v)
     if cos is None:     # no positional embedding (cfg.use_rope false)
         return q, k, v
     return (
@@ -1027,6 +1031,49 @@ class StateCtx(NamedTuple):
     snap: jax.Array     # [B] slot the new state is also copied to when the
                         # pass leaves the row on a page boundary (-1: none)
     page_size: int
+
+
+class Pack(NamedTuple):
+    """The tokens of ragged rows ``[B, S]`` packed row after row into one
+    row ``[1, T]``: what a mixed step's norms, projections and MLP run
+    over, so that their cost follows the tokens a tick carries and not the
+    slots its rows are padded to. A mixer that needs rows gets them with
+    ``rows`` and hands its output back with ``tokens``; padding reads zero
+    on either side."""
+
+    dst: jax.Array      # [B, S] the token of each slot (T: padding)
+    src: jax.Array      # [T] the slot b * S + s of each token (B * S: none)
+    valid: jax.Array    # [1, T] real tokens
+    last: jax.Array     # [B] the token at each row's last valid position
+
+    @classmethod
+    def of(cls, q_lens: jax.Array, S: int, T: int) -> "Pack":
+        """From the rows' lengths alone, on the device; rows past ``T``
+        tokens in all are the caller's to refuse (``Engine`` does)."""
+        B = q_lens.shape[0]
+        ends = jnp.cumsum(q_lens)
+        offsets = ends - q_lens
+        s = jnp.arange(S, dtype=jnp.int32)[None, :]
+        dst = jnp.where(s < q_lens[:, None], offsets[:, None] + s, T)
+        t = jnp.arange(T, dtype=jnp.int32)
+        row = jnp.sum(t[:, None] >= ends[None, :], axis=1)      # B: none
+        at = t - offsets[jnp.minimum(row, B - 1)]
+        src = jnp.where(row < B, row * S + at, B * S)
+        return cls(dst, src, (t < ends[-1])[None, :],
+                   jnp.clip(ends - 1, 0, T - 1))
+
+    def rows(self, a: jax.Array) -> jax.Array:
+        """``[1, T, ...]`` -> ``[B, S, ...]``."""
+        flat = a.reshape(a.shape[1], -1)       # one row of features a token
+        got = jnp.take(
+            flat, self.dst.reshape(-1), axis=0, mode="fill", fill_value=0)
+        return got.reshape(*self.dst.shape, *a.shape[2:])
+
+    def tokens(self, a: jax.Array) -> jax.Array:
+        """``[B, S, ...]`` -> ``[1, T, ...]``."""
+        flat = a.reshape(a.shape[0] * a.shape[1], -1)
+        got = jnp.take(flat, self.src, axis=0, mode="fill", fill_value=0)
+        return got.reshape(1, -1, *a.shape[2:])
 
 
 def _state_read(flat: jax.Array, idx: jax.Array, fresh: jax.Array):
@@ -1372,6 +1419,7 @@ def _run_stack(
     stacks: tuple[str, ...] | None = None,
     state_ctx: StateCtx | None = None,
     token_valid: jax.Array | None = None,
+    pack: Pack | None = None,
 ) -> tuple[jax.Array, Params | None, jax.Array]:
     """Scan the model's stacks of WHOLE PERIODS: the dense-MLP stack then
     (if configured) the MoE stack, each a ``lax.scan`` over its periods; a
@@ -1388,7 +1436,9 @@ def _run_stack(
     step at serving shapes), while scatters into a loop carry update it in
     place. ``state_ctx`` tells a linear layer its rows' slots;
     ``token_valid`` [B, S] keeps the padding of ragged rows out of an
-    expert share."""
+    expert share. With ``pack`` the stream ``x`` is the rows' tokens packed
+    ``[1, T, d]`` (``token_valid`` [1, T]): ``attn_fn`` takes and returns
+    packed tokens, and a linear layer gets rows and hands rows back."""
     Ld, Lm = _layer_split(cfg)
     runs = period_runs(cfg)
     share = cfg.moe is not None and cfg.moe.router_experts > 0
@@ -1407,8 +1457,13 @@ def _run_stack(
                         attn = attn * jax.nn.sigmoid(_mm(h, lp["wgate"]))
                 x = x + _mm(attn, lp["wo"])
         else:
+            if pack is not None:
+                with jax.named_scope("lin_proj"):
+                    h = pack.rows(h)
             mixed, cache = _linear_mixer(h, lp, cfg, cache, si, state_ctx)
             with jax.named_scope("attn_out"):
+                if pack is not None:
+                    mixed = pack.tokens(mixed)
                 x = x + _mm(mixed, lp["lo"])
         with jax.named_scope("ffn"):
             h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
@@ -1594,6 +1649,7 @@ def mixed_step(
     attn_impl: str = "xla",  # ops.paged_attention_backend choice
     mesh=None,               # Mesh for the shard_mapped pallas-under-tp path
     weight_stream: str = "xla",  # xla | pallas-dma (quant_matmul_pallas)
+    step_tokens: int = 0,    # the most tokens a step carries (0: B * S)
 ) -> tuple[jax.Array, Params]:
     """The unified mixed prefill+decode forward: one program advances
     q_len=1 decode rows AND q_len=chunk prefill rows in the same batch, so
@@ -1605,16 +1661,40 @@ def mixed_step(
     impl-dispatched ragged op (Pallas page streaming on TPU when enabled)
     and rows with q_lens == 0 are inert (no KV writes; garbage logits the
     caller discards). Returns (last-valid-position logits [B, V],
-    updated cache)."""
+    updated cache).
+
+    Where the rows' slots outnumber ``step_tokens`` the residual stream is
+    the tick's tokens packed ``[1, step_tokens, d]`` (``Pack``): embedding,
+    norms, projections and the MLP run over tokens; RoPE, the page write
+    and attention see ``[B, S, H, D]`` rows as before, and a
+    linear-attention or MLA mixer sees rows of the normed stream. The
+    caller holds ``sum(q_lens)`` to ``step_tokens``."""
     B, S = tokens.shape
     positions = start[:, None] + jnp.arange(S)[None, :]
     cos, sin = _rope_tables(cfg, positions)
-    x = _embed(params, tokens, dtype)
     page_table, sctx, token_valid = _row_state(
         cfg, cache, page_table, start, q_lens, S)
+    pack = None
+    if 0 < step_tokens < B * S:
+        with jax.named_scope("embed"):
+            pack = Pack.of(q_lens, S, step_tokens)
+            tokens = pack.tokens(tokens)
+        if token_valid is not None:
+            token_valid = pack.valid
+    rows = None if pack is None else pack.rows
+    x = _embed(params, tokens, dtype)
+
+    def packed(a):
+        if pack is None:
+            return a
+        with jax.named_scope("attn_out"):
+            return pack.tokens(a)
 
     def attn_fn(h, lp, kc, vc, li):
         if _latent_cache(cfg):
+            if pack is not None:
+                with jax.named_scope("attn_qkv"):
+                    h = pack.rows(h)
             q_lat, latent = _mla_latent_parts(h, lp, cfg, cos, sin)
             kc = write_pages(
                 kc, latent, page_table, start, valid_len=q_lens, layer=li
@@ -1623,8 +1703,8 @@ def mixed_step(
                 q_lat, kc, kc, page_table, start, q_lens,
                 impl=attn_impl, layer=li, mesh=mesh,
             )
-            return _mla_latent_out(ctx, lp, cfg), kc, vc
-        q, k, v = _qkv_rope(h, lp, cfg, cos, sin)
+            return _mla_latent_out(packed(ctx), lp, cfg), kc, vc
+        q, k, v = _qkv_rope(h, lp, cfg, cos, sin, rows)
         kc, vc = write_kv_pages(
             kc, vc, k, v, page_table, start, valid_len=q_lens, layer=li
         )
@@ -1632,13 +1712,14 @@ def mixed_step(
             q, kc, vc, page_table, start, q_lens,
             impl=attn_impl, layer=li, mesh=mesh,
         )
-        return attn.reshape(B, S, -1), kc, vc
+        return packed(attn.reshape(B, S, -1)), kc, vc
 
     with weight_stream_scope(weight_stream):
         x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache,
-                                 state_ctx=sctx, token_valid=token_valid)
+                                 state_ctx=sctx, token_valid=token_valid,
+                                 pack=pack)
         x = _final_norm(params, cfg, x)
-        x_last = _last_valid(x, q_lens)
+        x_last = _last_valid(x, q_lens, pack)
         logits = _lm_head(params, cfg, x_last)
     return logits, cache
 
@@ -1789,8 +1870,12 @@ def _final_norm(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
 
 @scoped("lm_head")
-def _last_valid(x: jax.Array, lengths: jax.Array) -> jax.Array:
+def _last_valid(
+    x: jax.Array, lengths: jax.Array, pack: Pack | None = None
+) -> jax.Array:
     """[B, D]: each row's hidden state at its last valid position."""
+    if pack is not None:
+        return x[0][pack.last]
     last = jnp.clip(lengths - 1, 0, x.shape[1] - 1)
     return jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
 

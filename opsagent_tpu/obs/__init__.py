@@ -129,6 +129,15 @@ MIXED_BUDGET_UTILIZATION = _reg.histogram(
     "Fraction of max_step_tokens used per mixed dispatch (0..1)",
     buckets=(0.1, 0.25, 0.5, 0.75, 0.9, 1.0),
 )
+STEP_TOKENS = _reg.counter(
+    "opsagent_step_tokens_total",
+    "Tokens of mixed dispatches, counted at dispatch: kind=real the tokens "
+    "the dispatch carried (decode lanes, forced runs, chunk tokens), "
+    "kind=computed the rows its norms, projections and MLP ran over (the "
+    "packed width where the program packs, else rows x chunk bucket); "
+    "real / computed is the step's fill share",
+    labelnames=("kind",),
+)
 # -- async mixed serving runtime (serving/async_runtime.py) -------------------
 STEP_HOST_GAP_SECONDS = _reg.histogram(
     "opsagent_step_host_gap_seconds",

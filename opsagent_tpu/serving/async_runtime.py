@@ -224,6 +224,32 @@ class AsyncMixedRuntime:
                     "seq %d truncated: KV page budget exhausted", s.seq_id
                 )
         decode = [s for s in grown if not s.done]
+        # What one dispatch carries is held to the step's width
+        # (Engine.step_tokens: a packed program has no room beyond it):
+        # the decode lanes first, the lookahead lanes the scheduler did
+        # not count among them, then the chunks, then forced runs from
+        # what is left. A chunk or a run cut short costs its row a later
+        # step and changes no token.
+        room = eng.step_tokens - len(decode)
+        chunk_info: list[tuple[int, Any, int, int]] = []
+        smax = 1
+        for sid, want in prefill_chunks.items():
+            seq = eng.sequences.get(sid)
+            if seq is None or sid not in eng._prefilling:
+                continue
+            done = eng._prefilling[sid]
+            c = eng.alloc.clamp_chunk(sid, done, seq.prompt_len, min(
+                want, cfg.mixed_buckets[-1], seq.prompt_len - done, room))
+            if c <= 0:
+                continue
+            chunk_info.append((sid, seq, done, c))
+            smax = max(smax, c)
+            room -= c
+        if len(decode) + len(chunk_info) > B:
+            raise ValueError(
+                f"async mixed batch of {len(decode)} decode + "
+                f"{len(chunk_info)} prefill rows exceeds max_batch_size={B}"
+            )
         # Grammar fast-forward plans: a constrained decode row whose
         # current FSM state forces a run of singleton-mask tokens appends
         # the whole run in THIS dispatch (the q_len>1 path chunk rows
@@ -268,10 +294,12 @@ class AsyncMixedRuntime:
                     )
                     pre = run
                 # Never overshoot max_tokens (pre + one sampled token on
-                # top of what is already in flight) or the largest bucket.
+                # top of what is already in flight), the largest bucket,
+                # or what the step still carries.
                 cap = min(
                     s.params.max_tokens - len(s.tokens) - inflight - 1,
                     cfg.mixed_buckets[-1] - 1,
+                    room,
                 )
                 pre = pre[: max(0, cap)]
                 if not pre:
@@ -292,28 +320,11 @@ class AsyncMixedRuntime:
                     eng.alloc.truncate(sid, eng.alloc.length(sid))
                     continue
                 ffwd_plan[sid] = (int(anchor), pre, st + 1)
-        chunk_info: list[tuple[int, Any, int, int]] = []
-        smax = 1
+                room -= len(pre)
         for _sid, (_a, _pre, _st) in ffwd_plan.items():
             smax = max(smax, 1 + len(_pre))
-        for sid, want in prefill_chunks.items():
-            seq = eng.sequences.get(sid)
-            if seq is None or sid not in eng._prefilling:
-                continue
-            done = eng._prefilling[sid]
-            c = eng.alloc.clamp_chunk(sid, done, seq.prompt_len, min(
-                want, cfg.mixed_buckets[-1], seq.prompt_len - done))
-            if c <= 0:
-                continue
-            chunk_info.append((sid, seq, done, c))
-            smax = max(smax, c)
         if not decode and not chunk_info:
             return False
-        if len(decode) + len(chunk_info) > B:
-            raise ValueError(
-                f"async mixed batch of {len(decode)} decode + "
-                f"{len(chunk_info)} prefill rows exceeds max_batch_size={B}"
-            )
         S = eng._mixed_bucket(smax)
 
         # Lane assignment: continuing decode rows keep their lane (the
@@ -426,6 +437,7 @@ class AsyncMixedRuntime:
 
         perf = get_perf_stats()
         eng._record_attn_pages(starts, qlens)
+        eng._count_step_tokens(S, int(qlens.sum()))
         ticket = eng.step_clock.enqueue()
         tick_id, t_disp = ticket
         if eng._mixed_gap_stamp is not None:

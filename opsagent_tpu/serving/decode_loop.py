@@ -203,6 +203,7 @@ def mixed_step_carry(
     fsm_dest: jax.Array | None = None,
     carry_fsm: jax.Array | None = None,   # [B] int32
     ov_fsm: jax.Array | None = None,      # [B] int32
+    step_tokens: int = 0,                 # llama.mixed_step's packed width
 ) -> tuple[jax.Array, Any, jax.Array]:
     """``llama.mixed_step`` with the sampled-token feedback DEVICE-RESIDENT:
     each decode lane's input token is spliced from ``carry_tok`` — the
@@ -225,7 +226,7 @@ def mixed_step_carry(
     logits, cache = llama.mixed_step(
         params, cfg, tokens, starts, q_lens, cache, page_table,
         dtype=dtype, attn_impl=attn_impl, mesh=mesh,
-        weight_stream=weight_stream,
+        weight_stream=weight_stream, step_tokens=step_tokens,
     )
     with_fsm = fsm_mask is not None
     with jax.named_scope("sample"):

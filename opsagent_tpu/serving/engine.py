@@ -682,6 +682,14 @@ class Engine:
         obs.attribution.set_current(self.attr)
 
         mc, dt = self.model_cfg, cfg.dtype
+        # The most tokens one mixed dispatch carries (decode lanes, forced
+        # runs and chunks together), up to whole MXU passes of 128 rows:
+        # where a mixed program's rows have more slots than this, its
+        # matmuls run over the tokens packed to this width instead
+        # (llama.mixed_step). Derived from the budget the scheduler plans
+        # to; the planners below hold every dispatch to it.
+        self.step_tokens = -(
+            -max(cfg.max_step_tokens, cfg.max_batch_size) // 128) * 128
 
         # sp > 1: shard long-context prefill attention over the sp axis as
         # a ragged ring (each sequence masks by its own length inside every
@@ -768,6 +776,7 @@ class Engine:
                 params, mc, tokens, starts, qlens, cache, table, dtype=dt,
                 attn_impl=self.attn_impl, mesh=self.mesh,
                 weight_stream=self.weight_stream_impl,
+                step_tokens=self.step_tokens,
             )
             tok = sample(logits, key, temps, top_k, top_p, None)
             return tok.astype(jnp.int32), cache
@@ -829,6 +838,7 @@ class Engine:
                 weight_stream=self.weight_stream_impl,
                 fsm_mask=fsm_mask, fsm_dest=fsm_dest,
                 carry_fsm=carry_fsm, ov_fsm=ov_fsm,
+                step_tokens=self.step_tokens,
             )
 
         self._mixed_sample_jit = jax.jit(
@@ -1020,6 +1030,12 @@ class Engine:
             "kv_quantize": self.cfg.kv_quantize or "none",
             "kv_page_form": self.page_form,
             "fsm_impl": native.impl(),
+            "step_rows": (
+                f"packed:{self.step_tokens}"
+                if self.cfg.mixed_batching and self.step_tokens
+                < self.cfg.max_batch_size * self.cfg.mixed_buckets[-1]
+                else "rows"
+            ),
         }
         if self.weight_stream_leaves:
             info["weight_stream_leaves"] = dict(self.weight_stream_leaves)
@@ -2049,6 +2065,15 @@ class Engine:
                 return b
         return self.cfg.mixed_buckets[-1]
 
+    def _step_rows(self, S: int) -> int:
+        """Rows the matmuls of the mixed program of bucket ``S`` run over:
+        the packed width where its slots outnumber it, else its slots."""
+        return min(self.cfg.max_batch_size * S, self.step_tokens)
+
+    def _count_step_tokens(self, S: int, real: int) -> None:
+        obs.STEP_TOKENS.inc(real, kind="real")
+        obs.STEP_TOKENS.inc(self._step_rows(S), kind="computed")
+
     def mixed_hosted(self, seq_id: int) -> bool:
         """True when this sequence needs host-side per-token work — a
         constrained-decoding mask, logprobs, or a logit bias/penalty —
@@ -2127,6 +2152,25 @@ class Engine:
                     f"{len(prefill_chunks)} prefill rows exceeds "
                     f"max_batch_size={B}"
                 )
+            chunk_info: list[tuple[int, Sequence, int, int]] = []
+            smax = 1
+            for sid, want in prefill_chunks.items():
+                seq = self.sequences[sid]
+                done = self._prefilling[sid]
+                c = self.alloc.clamp_chunk(sid, done, seq.prompt_len, min(
+                    want, self.cfg.mixed_buckets[-1], seq.prompt_len - done
+                ))
+                chunk_info.append((sid, seq, done, c))
+                smax = max(smax, c)
+            carried = len(decode) + sum(c for *_, c in chunk_info)
+            if carried > self.step_tokens:
+                # No program is warmed for it: the scheduler plans inside
+                # max_step_tokens, which the packed width covers.
+                raise ValueError(
+                    f"mixed batch of {carried} tokens exceeds the step's "
+                    f"{self.step_tokens} (max_step_tokens="
+                    f"{self.cfg.max_step_tokens})"
+                )
             # Book the token each decode row is about to write (the
             # step() contract: a row that cannot grow finishes as
             # truncated instead of killing the dispatch).
@@ -2149,16 +2193,6 @@ class Engine:
             prefill_out: dict[int, Any] = {}
             if not decode and not prefill_chunks:
                 return decode_out, prefill_out
-            chunk_info: list[tuple[int, Sequence, int, int]] = []
-            smax = 1
-            for sid, want in prefill_chunks.items():
-                seq = self.sequences[sid]
-                done = self._prefilling[sid]
-                c = self.alloc.clamp_chunk(sid, done, seq.prompt_len, min(
-                    want, self.cfg.mixed_buckets[-1], seq.prompt_len - done
-                ))
-                chunk_info.append((sid, seq, done, c))
-                smax = max(smax, c)
             S = self._mixed_bucket(smax)
             tokens = np.full((B, S), self.tokenizer.pad_id, np.int32)
             starts = np.zeros((B,), np.int32)
@@ -2186,6 +2220,7 @@ class Engine:
             temps, top_k, top_p, _ = self._sampling_arrays(slots, B)
             perf = get_perf_stats()
             self._record_attn_pages(starts, qlens)
+            self._count_step_tokens(S, int(qlens.sum()))
             ticket = self.step_clock.enqueue()
             tick_id, t_disp = ticket
             # Dispatch-to-dispatch interval (the async A/B's comparison
@@ -2417,7 +2452,14 @@ class Engine:
                 c for c in cands if c[1] is fsm0
             ][: self.cfg.max_batch_size]
             rows: list[tuple[Sequence, list[int]]] = []
+            room = self.step_tokens
             for s, _fsm, run in cands:
+                # A run cut to what the step still carries (its row's last
+                # token rides too) costs the row a later step and changes
+                # no token: what follows a forced token is forced as well.
+                run = run[: max(0, room - 1)]
+                if not run:
+                    break
                 try:
                     self.alloc.extend(s.seq_id, 1 + len(run))
                 except OutOfPages:
@@ -2429,6 +2471,7 @@ class Engine:
                     )
                     continue
                 rows.append((s, run))
+                room -= 1 + len(run)
             if not rows:
                 return {}
             B = self.cfg.max_batch_size
@@ -2458,16 +2501,19 @@ class Engine:
                 top_k[i] = s.params.top_k
                 top_p[i] = s.params.top_p
             fm, fd = self._fsm_device_tables(fsm0)
-            zb = jnp.zeros((B,), bool)
-            zi = jnp.zeros((B,), jnp.int32)
             perf = get_perf_stats()
             self._record_attn_pages(starts, qlens)
+            self._count_step_tokens(S, int(qlens.sum()))
             ticket = self.step_clock.enqueue()
             tick_id, t_disp = ticket
             try:
                 with obs.phase("dispatch", tick=tick_id), \
                         annotate("engine.ffwd_step"), self.mesh_ctx():
                     self._sample_key, sub = jax.random.split(self._sample_key)
+                    # under the mesh context, as warm-up made them: the
+                    # jit cache keys even these on it
+                    zb = jnp.zeros((B,), bool)
+                    zi = jnp.zeros((B,), jnp.int32)
                     toks_d, self.cache, _fsm_d = self._mixed_carry_jit(
                         self.params,
                         jnp.asarray(tokens),

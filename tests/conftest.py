@@ -124,6 +124,74 @@ def stream_kernel():
     return under
 
 
+# Six rows of a 16-slot bucket (one case: 32) packed to 32 tokens: the
+# ragged shapes a packed mixed step must serve as the rows program does.
+_RAGGED = {
+    "rows_of_0_1_and_S": ((1, 16, 0, 7, 1, 3), 16),
+    "one_token_in_all": ((0, 0, 1, 0, 0, 0), 16),
+    "T_minus_1_tokens": ((16, 15, 0, 0, 0, 0), 16),
+    "T_tokens": ((16, 0, 1, 0, 15, 0), 16),
+    "all_rows_decoding": ((1, 1, 1, 1, 1, 1), 16),
+    "one_row_holds_all_of_T": ((0, 0, 32, 0, 0, 0), 32),
+}
+
+
+@pytest.fixture(params=list(_RAGGED), scope="module")
+def ragged_case(request):
+    """(q_lens of six rows, the bucket S); the packed width is 32."""
+    return _RAGGED[request.param]
+
+
+@pytest.fixture(scope="module")
+def packed_against_rows():
+    """``check(cfg, params, q_lens, S, tol, table=None)``: one
+    ``llama.mixed_step`` over six rows that already hold 32 tokens each,
+    packed to 32 tokens and over rows, float32: logits at the last
+    position of every live row and every leaf of the cache tree agree
+    within ``tol``. One jit a (cfg, S, width), whatever the lengths."""
+    import functools
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from opsagent_tpu.models import llama
+
+    B, T, PAGE, MAXP = 6, 32, 16, 8
+
+    @functools.lru_cache(maxsize=None)
+    def step(cfg, width):
+        return jax.jit(functools.partial(
+            llama.mixed_step, cfg=cfg, dtype=jnp.float32, step_tokens=width))
+
+    def check(cfg, params, q_lens, S, tol, table=None):
+        if table is None:
+            table = jnp.arange(B * MAXP, dtype=jnp.int32).reshape(B, MAXP)
+        rng = np.random.default_rng(S)
+        held = jnp.asarray(rng.integers(1, cfg.vocab_size, (B, 32)), jnp.int32)
+        new = jnp.asarray(rng.integers(1, cfg.vocab_size, (B, S)), jnp.int32)
+        start = jnp.asarray([5, 0, 0, 9, 20, 2], jnp.int32)
+        q_lens = jnp.asarray(q_lens, jnp.int32)
+        got = []
+        for width in (0, T):
+            kw = {"state_slots": 8} if cfg.has_state else {}
+            cache = llama.make_cache(
+                cfg, B * MAXP, PAGE, dtype=jnp.float32, **kw)
+            _, cache = step(cfg, 0)(
+                params, tokens=held, start=jnp.zeros((B,), jnp.int32),
+                q_lens=start, cache=cache, page_table=table)
+            got.append(step(cfg, width)(
+                params, tokens=new, start=start, q_lens=q_lens, cache=cache,
+                page_table=table))
+        (rows, rows_cache), (packed, packed_cache) = got
+        live = np.asarray(q_lens) > 0
+        assert float(jnp.max(jnp.abs(rows[live] - packed[live]))) < tol
+        for a, b in zip(jax.tree.leaves(rows_cache),
+                        jax.tree.leaves(packed_cache)):
+            assert float(jnp.max(jnp.abs(a - b))) < tol
+
+    return check
+
+
 def _reset_obs():
     # Observability isolation: clear the metric SAMPLES (instruments stay
     # registered), the trace ring, the flight-recorder ring, the SLO

@@ -149,6 +149,51 @@ def test_async_direct_matches_sync_and_overlaps():
     assert _metric("opsagent_async_overlapped_commits_total") > ov0
 
 
+def test_async_dispatch_is_held_to_the_steps_width():
+    """Eight decode lanes beside eight prompts offered 16 tokens each:
+    136 against a step of 128 tokens (``Engine.step_tokens``: 16 rows of
+    the 16-slot bucket pack to it). The planner cuts the last chunk, no
+    dispatch carries more than the step, the cut prompt catches up in the
+    next one, and every sequence serves its synchronous generation."""
+    cfg = dict(BASE, max_batch_size=16, num_pages=256, mixed_buckets=(16,),
+               max_step_tokens=128)
+    shorts = [[257, 9, 8, 7 + i] for i in range(8)]
+    longs = [[257] + list(range(1 + i, 40 + i)) for i in range(8)]
+    sync = Engine(EngineConfig(async_depth=1, **cfg))
+    want = [
+        sync.generate([p], SamplingParams(max_tokens=10))[0]
+        for p in shorts + longs
+    ]
+
+    eng = Engine(EngineConfig(async_depth=2, **cfg))
+    assert eng.impl_info()["step_rows"] == "packed:128"
+    carried: list[int] = []
+    count = eng._count_step_tokens
+    eng._count_step_tokens = lambda S, real: (
+        carried.append(real), count(S, real))
+    lanes = [eng.add_request(p, SamplingParams(max_tokens=10)) for p in shorts]
+    admits = [
+        eng.begin_request(p, SamplingParams(max_tokens=10)) for p in longs
+    ]
+    for n in range(200):
+        chunks = {}
+        for sid in admits:
+            if sid in eng._prefilling:
+                done, total = eng.prefill_progress(sid)
+                if total > done:
+                    chunks[sid] = min(total - done, 16)
+        if not chunks and not eng.async_pending():
+            break
+        live = [s for s in lanes if not eng.sequences[s].done]
+        _, p_out = eng.step_mixed_async(live, chunks)
+        assert not any(isinstance(r, Exception) for r in p_out.values())
+    assert carried[0] == 128 and max(carried) == 128, carried
+    _drain_all(eng, lanes + admits)
+    assert [eng.finish(s) for s in lanes + admits] == want
+    acc = eng.alloc.accounting()
+    assert acc["owned"] == 0 and acc["free"] + acc["trie"] == acc["total"]
+
+
 def test_async_stop_string_overshoot_discarded_no_page_leak():
     """Stop-string detection lags one tick under the lookahead: the
     finished row's overshoot token must be DISCARDED (tokens identical

@@ -22,6 +22,7 @@ covered on the tiny CPU engine:
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from opsagent_tpu import obs
 from opsagent_tpu.serving.constrained import (
@@ -275,6 +276,58 @@ def test_page_conservation_around_multi_token_appends():
     assert acc1["total"] == acc0["total"] == BASE["num_pages"]
     assert acc1["owned"] == 0, acc1
     assert acc1["free"] + acc1["trie"] == acc1["total"]
+
+
+@pytest.mark.parametrize("depth", [1, 2], ids=["sync", "async"])
+def test_forced_runs_are_trimmed_to_the_steps_width(depth):
+    """Sixteen constrained rows reach the schema's forced key names in
+    the same tick: 16 x (1 + up to 15) tokens against a step of 128
+    (``Engine.step_tokens``; the 16-slot bucket's program packs to it). The
+    planner cuts the runs so that no dispatch carries more, the cut rows
+    catch up in later steps, every row serves the tokens of the
+    fast-forward-off oracle, nothing compiles after warm-up whichever
+    bucket a tick lands in, and no page stays booked."""
+    over = dict(max_batch_size=16, num_pages=256, mixed_buckets=(8, 16))
+    sampling = SamplingParams(max_tokens=40)
+    want, _ = _oracle(sampling, **over)
+    eng = Engine(EngineConfig(
+        grammar_ffwd=True, async_depth=depth, **dict(BASE, **over)))
+    assert eng.step_tokens == 128
+    eng.warmup("full")
+    carried: list[int] = []
+    count = eng._count_step_tokens
+    eng._count_step_tokens = lambda S, real: (
+        carried.append(real), count(S, real))
+    sids = []
+    for _ in range(16):
+        sid = eng.begin_request(
+            PROMPT, sampling,
+            mask_fn=json_constraint(eng.tokenizer, TOOLPROMPT_SCHEMA))
+        while not eng.prefill_step(sid):
+            pass
+        sids.append(sid)
+    n0 = len(_COMPILES)
+    t0 = _metric("opsagent_ffwd_tokens_total")
+    for _ in range(300):
+        live = [s for s in sids if not eng.sequences[s].done]
+        if not live:
+            break
+        if depth > 1:
+            eng.step_mixed_async(live, {})
+            continue
+        eng.drain()
+        out = eng.ffwd_step(live)
+        rest = [s for s in live
+                if not out.get(s) and not eng.sequences[s].done]
+        if rest:
+            eng.step_block(rest)
+    eng.drain()
+    assert max(carried) == eng.step_tokens, carried   # the cut engaged
+    assert _metric("opsagent_ffwd_tokens_total") - t0 > 16 * 8
+    assert len(_COMPILES) == n0
+    assert [eng.finish(s) for s in sids] == [want] * 16
+    acc = eng.alloc.accounting()
+    assert acc["owned"] == 0 and acc["free"] + acc["trie"] == acc["total"]
 
 
 def test_zero_compiles_after_warmup_with_ffwd():

@@ -233,6 +233,16 @@ def test_a_mixed_step_leaves_a_padded_rows_state_untouched(params, tokens, truth
     assert float(jnp.min(cache["state"][:, 5])) == 7.0
 
 
+def test_the_packed_mixed_step_is_the_rows_step(
+        params, packed_against_rows, ragged_case):
+    """Tokens packed for the norms, projections and experts, rows for the
+    page write, attention, the conv tail and the scan: pages, state, conv
+    tails, expert counts and logits are those of the step over rows."""
+    q_lens, S = ragged_case
+    table = table_rows([(range(8 * i, 8 * i + 8), i, -1) for i in range(6)])
+    packed_against_rows(CFG, params, q_lens, S, TOL, table=table)
+
+
 def test_a_restored_snapshot_and_the_rest_equal_prefilling_it_all(
         params, tokens, truth):
     """Row 0 prefills 48 tokens (three pages) with a snapshot slot armed:

@@ -196,6 +196,17 @@ def test_goodput_ledger_families_on_the_scrape():
         assert f'opsagent_attr_step_bytes{{kind="{k}"}}' in text
 
 
+def test_step_tokens_family_on_the_scrape():
+    """The mixed step's fill share (ISSUE 32): one counter, two kinds,
+    real / computed; pinned so a rename is a visible contract break."""
+    obs.STEP_TOKENS.inc(160, kind="real")
+    obs.STEP_TOKENS.inc(256, kind="computed")
+    text = obs.metrics_text()
+    assert "# TYPE opsagent_step_tokens_total counter" in text
+    for kind in ("real", "computed"):
+        assert f'opsagent_step_tokens_total{{kind="{kind}"}}' in text
+
+
 def test_fleet_journey_families_on_the_scrape():
     """The fleet-journey families (ISSUE 16's contract with dashboards):
     hop latency histogram, journey shape counter, per-replica clock-skew

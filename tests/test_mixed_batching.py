@@ -53,23 +53,34 @@ def _drain_all(eng, sids):
     eng.drain()
 
 
-def test_mixed_scheduler_matches_split_greedy():
+# 16 rows x a 16-slot bucket is 256 slots against a step of 128 tokens
+# (Engine.step_tokens): the mixed program packs. BASE's 4 x 16 never does.
+PACKED = dict(max_batch_size=16, num_pages=256, mixed_buckets=(16,))
+
+
+@pytest.mark.parametrize(
+    "over", [{}, PACKED, dict(PACKED, tp=2)],
+    ids=["rows", "packed", "packed_tp2"])
+def test_mixed_scheduler_matches_split_greedy(over):
     """(a) End-to-end through the scheduler: concurrent short + long
     prompts decoded under the mixed tick must be token-identical to the
     split-path oracle."""
+    cfg = dict(BASE, **over)
     prompts = [
         [257, 9, 8, 7],
         [257] + list(range(1, 40)),     # multiple chunks
         [257, 5, 5, 5, 5, 5],
     ]
     budgets = [12, 6, 9]
-    split = Engine(EngineConfig(mixed_batching=False, **BASE))
+    split = Engine(EngineConfig(mixed_batching=False, **cfg))
     want = [
         split.generate([p], SamplingParams(max_tokens=n))[0]
         for p, n in zip(prompts, budgets)
     ]
 
-    eng = Engine(EngineConfig(mixed_batching=True, **BASE))
+    eng = Engine(EngineConfig(mixed_batching=True, **cfg))
+    assert eng.impl_info()["step_rows"] == (
+        "packed:128" if over else "rows")
     sched = Scheduler(eng)
     sched.start()
     try:
@@ -117,6 +128,28 @@ def test_step_mixed_direct_matches_split_greedy():
     assert got_b == want_long
     # The decode lane advanced DURING admission (mixed piggybacking).
     assert len(collected) > 1
+
+
+def test_step_mixed_refuses_more_tokens_than_the_step_carries():
+    """A direct caller that plans outside the scheduler's budget gets a
+    ValueError before anything is booked, not a program nobody warmed:
+    nine chunks of 16 are 144 tokens against a packed width of 128."""
+    eng = Engine(EngineConfig(mixed_batching=True, **dict(BASE, **PACKED)))
+    assert eng.step_tokens == 128
+    prompt = [257] + list(range(1, 40))
+    sids = [
+        eng.begin_request(prompt, SamplingParams(max_tokens=4))
+        for _ in range(9)
+    ]
+    owned = eng.alloc.accounting()["owned"]
+    with pytest.raises(ValueError, match="exceeds the step's 128"):
+        eng.step_mixed([], {sid: 16 for sid in sids})
+    assert eng.alloc.accounting()["owned"] == owned
+    assert all(eng.prefill_progress(sid)[0] == 0 for sid in sids)
+    # eight of them are 128 tokens: the widest dispatch there is
+    _, out = eng.step_mixed([], {sid: 16 for sid in sids[:8]})
+    assert all(v is False for v in out.values())
+    assert all(eng.prefill_progress(sid)[0] == 16 for sid in sids[:8])
 
 
 def test_budget_policy_honors_max_step_tokens_and_decode_priority():
@@ -321,3 +354,31 @@ def test_mixed_readers_byte_identical_and_int8_kv_compiles_nothing(
     with stream_kernel():
         assert run("pallas-stream") == want
         run("xla", kv_quantize="int8")      # the kernel has no int8 reader
+
+
+# -- the packed mixed step (llama.Pack): tokens, not slots ---------------------
+@pytest.mark.parametrize("preset,over", [
+    ("tiny-test", {"attn_bias": True}),     # dense GQA with QKV bias
+    ("tiny-mla", {}),                       # latent pages: the gather path
+], ids=["gqa_qkv_bias", "mla_latent"])
+def test_packed_mixed_step_is_the_rows_step(
+        packed_against_rows, ragged_case, preset, over):
+    """The residual stream packed to the tick's tokens gives the logits and
+    the cache of the stream laid out rows x bucket, at every ragged shape:
+    the same float32 mathematics, the matmuls' rows in another order."""
+    import dataclasses
+
+    from opsagent_tpu.models import llama
+    from opsagent_tpu.models.config import PRESETS
+
+    cfg = dataclasses.replace(PRESETS[preset], **over)
+    params = llama.init_params(cfg, jax.random.PRNGKey(3), jnp.float32)
+    if over:
+        # init_params leaves the biases at zero; a dropped one must show
+        params["layers"] = {
+            name: jax.random.normal(jax.random.PRNGKey(9), leaf.shape) * 0.1
+            if name in ("bq", "bk", "bv") else leaf
+            for name, leaf in params["layers"].items()}
+    q_lens, S = ragged_case
+    with jax.default_matmul_precision("highest"):
+        packed_against_rows(cfg, params, q_lens, S, tol=2e-5)

@@ -193,3 +193,39 @@ def test_perf_timer_paths_unified():
     ps.start_timer("op")
     assert ps.stop_timer("op") == 0.0
     assert ps.get_stats()["op"]["count"] == 2
+
+
+@pytest.mark.parametrize("depth", [1, 2], ids=["sync", "async"])
+@pytest.mark.parametrize("rows,computed", [(4, 64), (16, 128)],
+                         ids=["rows", "packed"])
+def test_step_tokens_counts_what_a_mixed_dispatch_carried_and_computed(
+        depth, rows, computed):
+    """opsagent_step_tokens_total, at dispatch on both mixed paths:
+    kind=real the tokens carried (one decode lane and a chunk of 16),
+    kind=computed the rows the matmuls ran over: rows x bucket where
+    that fits the step's 128 tokens, the packed 128 where it does not."""
+    import jax.numpy as jnp
+
+    from opsagent_tpu.serving.engine import Engine, EngineConfig
+    from opsagent_tpu.serving.sampler import SamplingParams
+
+    eng = Engine(EngineConfig(
+        model="tiny-test", dtype=jnp.float32, tp=1, page_size=4,
+        num_pages=64 * rows, max_pages_per_seq=24, max_batch_size=rows,
+        prefill_buckets=(8, 16), decode_block=4, mixed_buckets=(16,),
+        max_step_tokens=32, async_depth=depth,
+    ))
+    lane = eng.add_request([257, 9, 8, 7], SamplingParams(max_tokens=8))
+    admit = eng.begin_request(
+        [257] + list(range(1, 40)), SamplingParams(max_tokens=4))
+
+    def read(kind):
+        return obs.metrics_snapshot().get(
+            f'opsagent_step_tokens_total{{kind="{kind}"}}', 0.0)
+
+    before = read("real"), read("computed")
+    step = eng.step_mixed if depth == 1 else eng.step_mixed_async
+    step([lane], {admit: 16})
+    eng.drain()
+    assert read("real") - before[0] == 1 + 16
+    assert read("computed") - before[1] == computed

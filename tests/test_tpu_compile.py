@@ -523,16 +523,19 @@ def _whole_cache_copies(compiled, cfg, preset: str, impl: str, axes=None):
     return _copies_of(hlo, whole, axes)
 
 
-def _mixed_step(sds, preset: str, kv: str, impl: str = "xla"):
+def _mixed_step(sds, preset: str, kv: str, impl: str = "xla", *,
+                rows: int = STEP_ROWS, tokens: int = STEP_TOKENS,
+                step_tokens: int = 0):
     """Compile the engine's ``_mixed_carry`` program (decode_loop.
     mixed_step_carry, the cache donated) at a preset's widths cut to two
     layers, with the cache ``llama.make_cache`` gives it for the
-    attention backend ``impl``."""
+    attention backend ``impl``; ``rows`` x ``tokens`` slots, packed to
+    ``step_tokens`` where that is fewer."""
     from opsagent_tpu.serving import decode_loop
 
     cfg, params, cache, key = _step_shapes(sds, preset, kv, impl)
     maxp = GEOMETRY[preset][1]
-    b = STEP_ROWS
+    b = rows
     i32 = lambda *s: sds(s, jnp.int32)       # noqa: E731
     f32 = lambda *s: sds(s, jnp.float32)     # noqa: E731
     flag = lambda *s: sds(s, jnp.bool_)      # noqa: E731
@@ -542,13 +545,14 @@ def _mixed_step(sds, preset: str, kv: str, impl: str = "xla"):
         return decode_loop.mixed_step_carry(
             params, cfg, tokens, use_carry, carry, starts, qlens, emits,
             cache, table, key, temps, top_k, top_p, attn_impl=impl,
+            step_tokens=step_tokens,
         )
 
     compiled = jax.jit(step, donate_argnames=("cache",)).lower(
-        params, i32(b, STEP_TOKENS), flag(b), i32(b), i32(b), i32(b),
+        params, i32(b, tokens), flag(b), i32(b), i32(b), i32(b),
         flag(b), cache, i32(b, maxp), key, f32(b), i32(b), f32(b),
     ).compile()
-    return cfg, cache, _whole_cache_copies(compiled, cfg, preset, impl)
+    return cfg, cache, _whole_cache_copies(compiled, cfg, preset, impl), compiled
 
 
 def _decode_block(sds, preset: str, impl: str, steps: int = 8):
@@ -601,13 +605,40 @@ def test_no_step_copies_a_whole_k_or_v_array(v5e, preset, kv, impl, form):
     nothing and holds merged pages at both."""
     from opsagent_tpu.models import llama
 
-    cfg, cache, copies = _mixed_step(_one_chip(v5e), preset, kv, impl)
+    cfg, cache, copies, _ = _mixed_step(_one_chip(v5e), preset, kv, impl)
     assert llama.cache_form(cfg, 1, impl) == form
     n = GEOMETRY[preset][0]
     k, d = cfg.num_kv_heads, cfg.head_dim_
     row = (k * d,) if form == "merged" else (k, d)
     assert cache["k"].shape == (STEP_LAYERS, n, PAGE) + row
     assert copies == []
+
+
+def test_cell_1s_mixed_step_runs_its_matmuls_over_the_steps_tokens(v5e):
+    """Cell 1's widest mixed program, 32 rows of the 32-slot bucket under
+    the kernel, packed to the step's 256 tokens (``Engine.step_tokens``):
+    the FFN's matmuls take ``[256, 3584]`` and give ``[256, 18944]``, no
+    array of 32 x 32 x 18944 elements is left anywhere, attention still
+    sees q un-packed to its 32 x 32 rows, no dequantized weight or whole
+    K array is written out, and the program's scratch HBM stays a few MB
+    (the compiler's ``temp_size_in_bytes``: 1.53 MB over rows, 2.16 MB
+    packed, compile, PR 32: in both the activations live in on-chip
+    memory and the cache is updated in place, so the FFN's
+    ``[32, 32, 18944]`` arrays, 38.8 MB each, never were HBM temporaries;
+    at the 72B's widths with int8 weights it reads 62.2 MB over
+    ``[16, 64]`` rows and 2.35 MB packed)."""
+    cfg, _, copies, compiled = _mixed_step(
+        _one_chip(v5e), "qwen2.5-7b-instruct", "", "pallas-stream",
+        rows=32, tokens=32, step_tokens=256)
+    hlo = compiled.as_text()
+    d, f = cfg.hidden_size, cfg.intermediate_size
+    assert re.search(rf"bf16\[256,{f}\]\S* convolution\(", hlo)
+    assert re.search(rf"bf16\[256,{d}\]\S* convolution\(", hlo)
+    assert not re.search(rf"\[32,32,{f}\]", hlo)
+    assert re.search(rf"bf16\[1024,{d}\]\S* gather\(", hlo)   # q, un-packed
+    assert copies == []
+    assert not re.search(rf"bf16\[1,{d},{d}\]\S* fusion\(", hlo)  # a weight whole
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
 
 
 @pytest.mark.parametrize("preset,impl", [
