@@ -99,13 +99,23 @@ class MLAConfig:
 
 @dataclass(frozen=True)
 class LinearAttnConfig:
-    """Delta-rule linear attention with a per-channel decay (Kimi Delta
-    Attention): a float32 state ``[num_heads, key_head_dim, value_head_dim]``
-    per sequence instead of pages, a causal depthwise convolution of
-    ``conv_kernel`` over the q/k/v streams (its last ``conv_kernel - 1``
-    inputs are state too), low-rank decay and output gates of width
-    ``gate_rank``. ``neg_eigval`` doubles beta's range to (0, 2), which lets
-    a state transition have negative eigenvalues."""
+    """Gated delta-rule linear attention: a float32 state ``[num_heads,
+    key_head_dim, value_head_dim]`` per sequence instead of pages, and a
+    causal depthwise convolution of ``conv_kernel`` over the q/k/v streams
+    (its last ``conv_kernel - 1`` inputs are state too). ``neg_eigval``
+    doubles beta's range to (0, 2), which lets a state transition have
+    negative eigenvalues. Two published layers differ in two ways:
+
+    - ``decay``: how fine the decay is. ``"channel"``: one for every key
+      channel of every head (Kimi Delta Attention; ``dt_bias`` is as wide
+      as the keys). ``"head"``: one number a head (Gated DeltaNet;
+      ``dt_bias`` is ``[num_heads]``).
+    - ``gates``: ``"low_rank"``: the decay and the output gate are two
+      low-rank projections of width ``gate_rank`` each, the output gate a
+      sigmoid beside the per-head RMSNorm of the read-out (KDA).
+      ``"full"``: the decay is one projection ``wa`` as wide as the decay,
+      the output gate one full-rank projection ``wog``, and the gate is the
+      silu of it (Gated DeltaNet's gated norm); ``gate_rank`` is unused."""
 
     num_heads: int = 4
     key_head_dim: int = 16
@@ -113,6 +123,19 @@ class LinearAttnConfig:
     conv_kernel: int = 4
     gate_rank: int = 16
     neg_eigval: bool = True
+    decay: str = "channel"
+    gates: str = "low_rank"
+
+    def __post_init__(self):
+        if self.decay not in ("channel", "head"):
+            raise ValueError(f"linear_attn.decay {self.decay!r}")
+        if self.gates not in ("low_rank", "full"):
+            raise ValueError(f"linear_attn.gates {self.gates!r}")
+
+    @property
+    def decay_size(self) -> int:
+        """Decays of one token: a channel's each, or a head's."""
+        return self.key_size if self.decay == "channel" else self.num_heads
 
     @property
     def key_size(self) -> int:
@@ -167,6 +190,10 @@ class ModelConfig:
     # Qwen3-style per-head RMSNorm on q and k (over head_dim, learned
     # [head_dim] weights, applied before RoPE).
     qk_norm: bool = False
+    # With ``qk_norm``: the norm runs over the whole projection width
+    # (Olmo2 / Olmo3: weights [q_size] and [kv_size]) before the heads are
+    # split, instead of over each head.
+    qk_norm_whole: bool = False
     tie_embeddings: bool = False
     max_position: int = 131072
     moe: Optional[MoEConfig] = None
@@ -190,6 +217,10 @@ class ModelConfig:
     # softmax-attention output multiplied by sigmoid(x W_gate) before wo
     attn_output_gate: bool = False
     use_rope: bool = True            # False: no positional embedding (NoPE)
+    # The Olmo2 block: ``h = x + norm(mixer(x))``, ``out = h + norm(mlp(h))``
+    # (``attn_norm`` and ``mlp_norm`` follow the sublayer they are named
+    # for, and nothing is normed before it). False: pre-norm.
+    post_norm: bool = False
 
     def __post_init__(self):
         period = tuple(self.mixer_period)
@@ -260,17 +291,22 @@ class ModelConfig:
         if self.attn_bias:
             attn += self.q_size + 2 * self.kv_size
         if self.qk_norm:
-            attn += 2 * self.head_dim_
+            attn += (self.q_size + self.kv_size if self.qk_norm_whole
+                     else 2 * self.head_dim_)
         linear = 0
         if self.linear_attn is not None:
             la = self.linear_attn
+            if la.gates == "low_rank":      # downs and ups of both gates
+                gates = (2 * d * la.gate_rank
+                         + la.gate_rank * (la.decay_size + la.value_size))
+            else:
+                gates = d * (la.decay_size + la.value_size)
             linear = (
                 d * la.conv_size + la.conv_kernel * la.conv_size   # q,k,v, conv
                 + la.value_size * d                                # wo
-                + 2 * d * la.gate_rank                             # gate downs
-                + la.gate_rank * (la.key_size + la.value_size)     # gate ups
+                + gates
                 + d * la.num_heads                                 # beta
-                + la.num_heads + la.key_size                       # A_log, dt_bias
+                + la.num_heads + la.decay_size                     # A_log, dt_bias
                 + la.value_head_dim                                # output norm
             )
         dense_mlp = 3 * d * f
@@ -631,6 +667,62 @@ TINY_HYBRID = _register(
     )
 )
 
+# Olmo-Hybrid-7B (allenai; HF olmo_hybrid): 32 dense layers in periods of
+# three gated delta-rule layers (FLA's Gated DeltaNet: 30 heads, keys 96 and
+# values 192 a head, one decay a head, full-rank gates, conv 4, negative
+# eigenvalues) and one full-attention layer LAST (30 heads and 30 kv heads
+# of 128, RMSNorm over the whole q and k, no rotary embedding), in the Olmo2
+# block order: the norm follows the sublayer.
+OLMO_HYBRID_7B = _register(
+    ModelConfig(
+        name="olmo-hybrid-7b",
+        vocab_size=100352,
+        hidden_size=3840,
+        intermediate_size=11008,
+        num_layers=32,
+        num_heads=30,
+        num_kv_heads=30,
+        rms_norm_eps=1e-6,
+        qk_norm=True,
+        qk_norm_whole=True,
+        max_position=65536,
+        mixer_period=("linear", "linear", "linear", "attn"),
+        linear_attn=LinearAttnConfig(
+            num_heads=30, key_head_dim=96, value_head_dim=192,
+            conv_kernel=4, gate_rank=0, neg_eigval=True,
+            decay="head", gates="full",
+        ),
+        use_rope=False,
+        post_norm=True,
+    )
+)
+
+# Two periods of the same pattern at toy widths (CPU tests), key and value
+# dims unequal as in the model.
+TINY_OLMO_HYBRID = _register(
+    ModelConfig(
+        name="tiny-olmo-hybrid",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=8,
+        num_heads=4,
+        num_kv_heads=4,
+        rms_norm_eps=1e-6,
+        qk_norm=True,
+        qk_norm_whole=True,
+        max_position=4096,
+        mixer_period=("linear", "linear", "linear", "attn"),
+        linear_attn=LinearAttnConfig(
+            num_heads=4, key_head_dim=12, value_head_dim=24,
+            conv_kernel=4, gate_rank=0, neg_eigval=True,
+            decay="head", gates="full",
+        ),
+        use_rope=False,
+        post_norm=True,
+    )
+)
+
 TINY_MLA = _register(
     ModelConfig(
         name="tiny-mla",
@@ -701,17 +793,20 @@ def config_from_hf(path: str, name: str = "") -> ModelConfig:
         hf = json.load(f)
     mt = hf.get("model_type", "llama")
     if mt not in ("llama", "mistral", "qwen2", "qwen3", "qwen3_moe",
-                  "deepseek", "deepseek_v2", "deepseek_v3", "solar_open2"):
+                  "deepseek", "deepseek_v2", "deepseek_v3", "solar_open2",
+                  "olmo_hybrid"):
         raise ValueError(
             f"config_from_hf supports model_type llama/mistral/qwen2/"
-            f"qwen3/qwen3_moe/deepseek/deepseek_v2/deepseek_v3/solar_open2, "
-            f"got {mt!r}"
+            f"qwen3/qwen3_moe/deepseek/deepseek_v2/deepseek_v3/solar_open2/"
+            f"olmo_hybrid, got {mt!r}"
         )
     name = name or os.path.basename(os.path.normpath(
         path if os.path.isdir(path) else os.path.dirname(cfg_path)
     )) or mt
     if mt == "solar_open2":
         return _solar_open2_from_hf(hf, name)
+    if mt == "olmo_hybrid":
+        return _olmo_hybrid_from_hf(hf, name)
     # Sliding-window attention is not implemented; a config that would
     # ACTIVELY use it must be rejected loudly, never silently served
     # with full attention. Mistral (llama-shaped otherwise: same weight
@@ -933,7 +1028,8 @@ def _solar_open2_dict(cfg: ModelConfig) -> dict:
     if (cfg.mixer_period != ("attn",) + ("linear",) * (period - 1)
             or m is None or m.scoring_func != "sigmoid" or cfg.mla
             or la.key_head_dim != la.value_head_dim
-            or la.gate_rank != la.key_head_dim):
+            or la.gate_rank != la.key_head_dim
+            or (la.decay, la.gates) != ("channel", "low_rank")):
         raise ValueError(
             "hf_config_dict: this layer pattern is not expressible as "
             "model_type solar_open2"
@@ -979,6 +1075,105 @@ def _solar_open2_dict(cfg: ModelConfig) -> dict:
     return hf
 
 
+_OLMO_LAYER_TYPES = {"linear_attention": "linear", "full_attention": "attn"}
+
+
+def _olmo_hybrid_from_hf(hf: dict, name: str) -> ModelConfig:
+    """``model_type: olmo_hybrid``: dense layers whose ``layer_types`` repeat
+    a period of gated delta-rule layers and full-attention layers (the
+    ``linear_*`` keys are FLA's Gated DeltaNet: one decay a head, full-rank
+    gates). What the config has no key for follows the family (Olmo2 /
+    Olmo3): the norm after each sublayer, an RMSNorm over the whole q and
+    k; ``rope_parameters.rope_theta`` null means no rotary embedding."""
+    layers = int(hf["num_hidden_layers"])
+    types = list(hf.get("layer_types") or ["full_attention"] * layers)
+    unknown = sorted(set(types) - set(_OLMO_LAYER_TYPES))
+    if unknown or len(types) != layers:
+        raise ValueError(
+            f"olmo_hybrid: layer_types {unknown or len(types)} not supported "
+            f"(have {sorted(_OLMO_LAYER_TYPES)}, one for each layer)"
+        )
+    period = next(
+        p for p in range(1, layers + 1)
+        if layers % p == 0 and types == types[:p] * (layers // p)
+    )
+    heads = int(hf["linear_num_value_heads"])
+    if int(hf.get("linear_num_key_heads", heads)) != heads:
+        raise ValueError("olmo_hybrid: grouped linear-attention heads")
+    theta = (hf.get("rope_parameters") or {}).get(
+        "rope_theta", hf.get("rope_theta"))
+    return ModelConfig(
+        name=name,
+        vocab_size=int(hf["vocab_size"]),
+        hidden_size=int(hf["hidden_size"]),
+        intermediate_size=int(hf["intermediate_size"]),
+        num_layers=layers,
+        num_heads=int(hf["num_attention_heads"]),
+        num_kv_heads=int(hf["num_key_value_heads"]),
+        head_dim=int(hf.get("head_dim") or 0),
+        rope_theta=float(theta) if theta is not None else ModelConfig.rope_theta,
+        rms_norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        attn_bias=bool(hf.get("attention_bias", False)),
+        qk_norm=True,
+        qk_norm_whole=True,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        max_position=int(hf.get("max_position_embeddings", 8192)),
+        mixer_period=tuple(_OLMO_LAYER_TYPES[t] for t in types[:period]),
+        linear_attn=LinearAttnConfig(
+            num_heads=heads,
+            key_head_dim=int(hf["linear_key_head_dim"]),
+            value_head_dim=int(hf["linear_value_head_dim"]),
+            conv_kernel=int(hf.get("linear_conv_kernel_dim", 4)),
+            gate_rank=0,
+            neg_eigval=bool(hf.get("linear_allow_neg_eigval", False)),
+            decay="head", gates="full",
+        ),
+        use_rope=theta is not None,
+        post_norm=True,
+    )
+
+
+def _olmo_hybrid_dict(cfg: ModelConfig) -> dict:
+    """The inverse of ``_olmo_hybrid_from_hf``."""
+    la = cfg.linear_attn
+    if (la is None or cfg.moe or cfg.mla
+            or not (cfg.qk_norm and cfg.qk_norm_whole)
+            or cfg.attn_output_gate or cfg.rope_scaling
+            or (la.decay, la.gates) != ("head", "full")):
+        raise ValueError(
+            "hf_config_dict: this model is not expressible as model_type "
+            "olmo_hybrid"
+        )
+    names = {v: k for k, v in _OLMO_LAYER_TYPES.items()}
+    hf = {
+        "model_type": "olmo_hybrid",
+        "architectures": ["OlmoHybridForCausalLM"],
+        "vocab_size": cfg.vocab_size,
+        "hidden_size": cfg.hidden_size,
+        "intermediate_size": cfg.intermediate_size,
+        "num_hidden_layers": cfg.num_layers,
+        "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.num_kv_heads,
+        "hidden_act": "silu",
+        "max_position_embeddings": cfg.max_position,
+        "attention_bias": cfg.attn_bias,
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "tie_word_embeddings": cfg.tie_embeddings,
+        "layer_types": [names[cfg.mixer_of(i)] for i in range(cfg.num_layers)],
+        "linear_num_key_heads": la.num_heads,
+        "linear_num_value_heads": la.num_heads,
+        "linear_key_head_dim": la.key_head_dim,
+        "linear_value_head_dim": la.value_head_dim,
+        "linear_conv_kernel_dim": la.conv_kernel,
+        "linear_allow_neg_eigval": la.neg_eigval,
+        "rope_parameters": {
+            "rope_theta": cfg.rope_theta if cfg.use_rope else None},
+    }
+    if cfg.head_dim:
+        hf["head_dim"] = cfg.head_dim
+    return hf
+
+
 def resolve_model(
     model_name: str, checkpoint: str = ""
 ) -> tuple[str, Optional[ModelConfig]]:
@@ -1007,12 +1202,16 @@ def hf_config_dict(cfg: ModelConfig) -> dict:
     with a plain softmax MoE); other MoE and/or MLA configs emit the
     deepseek family (deepseek_v2/v3 when MLA is present, deepseek
     otherwise)."""
+    if cfg.post_norm:
+        return _olmo_hybrid_dict(cfg)
     if cfg.has_state:
         return _solar_open2_dict(cfg)
-    if cfg.mixer_period or cfg.attn_output_gate or not cfg.use_rope:
+    if (cfg.mixer_period or cfg.attn_output_gate or not cfg.use_rope
+            or cfg.qk_norm_whole):
         raise ValueError(
-            "hf_config_dict: a layer pattern, an attention output gate or "
-            "NoPE attention is only expressible as solar_open2"
+            "hf_config_dict: a layer pattern, an attention output gate, "
+            "NoPE attention or a whole-width q/k norm is only expressible "
+            "as solar_open2 or olmo_hybrid"
         )
     qwen3_moe = (
         cfg.qk_norm and cfg.moe is not None and cfg.mla is None

@@ -217,9 +217,11 @@ def _build_tree(cfg: ModelConfig, ks, dtype, big, dense) -> Params:
             block["bk"] = jnp.zeros((*L, kv), dtype)
             block["bv"] = jnp.zeros((*L, kv), dtype)
         if cfg.qk_norm:
-            # Qwen3 per-head q/k RMSNorm weights (over head_dim).
-            block["qn"] = jnp.ones((*L, cfg.head_dim_), dtype)
-            block["kn"] = jnp.ones((*L, cfg.head_dim_), dtype)
+            # q/k RMSNorm weights: Qwen3's over each head, Olmo2's over
+            # the whole projection.
+            qn, kn = (q, kv) if cfg.qk_norm_whole else (cfg.head_dim_,) * 2
+            block["qn"] = jnp.ones((*L, qn), dtype)
+            block["kn"] = jnp.ones((*L, kn), dtype)
         if cfg.attn_output_gate:
             block["wgate"] = big(next(ks), (*L, d, q), d)
         return block
@@ -227,16 +229,23 @@ def _build_tree(cfg: ModelConfig, ks, dtype, big, dense) -> Params:
     def linear_block(L: tuple) -> Params:
         la = cfg.linear_attn
         kd, vd, r = la.key_size, la.value_size, la.gate_rank
-        return {
+        block = {
             "attn_norm": jnp.ones((*L, d), dtype),
             "lq": big(next(ks), (*L, d, kd), d),
             "lk": big(next(ks), (*L, d, kd), d),
             "lv": big(next(ks), (*L, d, vd), d),
             "lo": big(next(ks), (*L, vd, d), vd),
-            "f_down": big(next(ks), (*L, d, r), d),
-            "f_up": big(next(ks), (*L, r, kd), r),
-            "g_down": big(next(ks), (*L, d, r), d),
-            "g_up": big(next(ks), (*L, r, vd), r),
+        }
+        if la.gates == "low_rank":
+            block["f_down"] = big(next(ks), (*L, d, r), d)
+            block["f_up"] = big(next(ks), (*L, r, la.decay_size), r)
+            block["g_down"] = big(next(ks), (*L, d, r), d)
+            block["g_up"] = big(next(ks), (*L, r, vd), r)
+        else:
+            block["wa"] = big(next(ks), (*L, d, la.decay_size), d)
+            block["wog"] = big(next(ks), (*L, d, vd), d)
+        return {
+            **block,
             "wb": big(next(ks), (*L, d, la.num_heads), d),
             "conv": dense(
                 next(ks), (*L, la.conv_kernel, la.conv_size),
@@ -244,7 +253,7 @@ def _build_tree(cfg: ModelConfig, ks, dtype, big, dense) -> Params:
             # decay rate exp(a_log) and the softplus offset: float32, as
             # the state they drive (zero-init; checkpoints carry them)
             "a_log": jnp.zeros((*L, la.num_heads), jnp.float32),
-            "dt_bias": jnp.zeros((*L, kd), jnp.float32),
+            "dt_bias": jnp.zeros((*L, la.decay_size), jnp.float32),
             "o_norm": jnp.ones((*L, la.value_head_dim), dtype),
             "mlp_norm": jnp.ones((*L, d), dtype),
         }
@@ -442,7 +451,8 @@ def _attn_block_specs(cfg: ModelConfig) -> Params:
         block["bv"] = P(None, "tp")
     if cfg.qk_norm:
         # Per-head-dim vectors: the head axis shards over tp, head_dim
-        # does not — replicated.
+        # does not — replicated (as the whole-width ones are: their mean
+        # is over every head).
         block["qn"] = P(None, None)
         block["kn"] = P(None, None)
     if cfg.attn_output_gate:
@@ -450,13 +460,15 @@ def _attn_block_specs(cfg: ModelConfig) -> Params:
     return block
 
 
-def _linear_block_specs() -> Params:
+def _linear_block_specs(cfg: ModelConfig) -> Params:
     """A linear-attention layer's leaves, replicated: the engine refuses
     tp > 1 for a model that has them (its state is not sharded yet)."""
     two, three = P(None, None), P(None, None, None)
+    gates = (("f_down", "f_up", "g_down", "g_up")
+             if cfg.linear_attn.gates == "low_rank" else ("wa", "wog"))
     return {
         "attn_norm": two, "lq": three, "lk": three, "lv": three, "lo": three,
-        "f_down": three, "f_up": three, "g_down": three, "g_up": three,
+        **{name: three for name in gates},
         "wb": three, "conv": three, "a_log": two, "dt_bias": two,
         "o_norm": two, "mlp_norm": two,
     }
@@ -511,7 +523,7 @@ def param_specs(cfg: ModelConfig) -> Params:
             key: {
                 name: P(None, *spec) for name, spec in mlp(
                     _attn_block_specs(cfg) if mixer == "attn"
-                    else _linear_block_specs()).items()
+                    else _linear_block_specs(cfg)).items()
             }
             for key, mixer, _n in runs
         }
@@ -608,26 +620,45 @@ def make_cache(
     return {**state, "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+def state_slot_shape(la) -> tuple[int, ...]:
+    """One linear layer's state of one slot as the cache holds it: ``[heads,
+    key dim, value dim]`` where the value dim fills whole 128-lane tiles of
+    the TPU, else the same numbers in the same order as rows of 128: a
+    minor dim of 192 pads to 256 lanes (a third more bytes held and moved
+    by every step), rows of 128 pad nothing. A step reshapes the rows it
+    read to ``[heads, key dim, value dim]`` and back (``_linear_mixer``)."""
+    H, dk, dv = la.num_heads, la.key_head_dim, la.value_head_dim
+    if dv % 128 == 0 or (H * dk * dv) % 128:
+        return (H, dk, dv)
+    return (H * dk * dv // 128, 128)
+
+
 def make_state(cfg: ModelConfig, slots: int, dtype=jnp.bfloat16) -> Params:
     """The recurrent-state part of the cache: for every linear-attention
-    layer and slot a float32 state ``[heads, key dim, value dim]`` and the
-    conv tail (the last ``conv_kernel - 1`` inputs of the convolved q/k/v
-    stream, in the compute type). A slot belongs to a running sequence or
-    holds a snapshot the prefix trie can restore; the slot a row uses rides
-    in its table row beside its pages (``split_table``). ``stats`` are the
-    ``MOE_STATS`` accumulators of a model with an expert share."""
+    layer and slot a float32 state (``state_slot_shape``) and the conv tail
+    (the last ``conv_kernel - 1`` inputs of the convolved q/k/v stream, in
+    the compute type). A slot belongs to a running sequence or holds a
+    snapshot the prefix trie can restore; the slot a row uses rides in its
+    table row beside its pages (``split_table``). A model with an expert
+    share also keeps its ``MOE_STATS`` accumulators here (``stats``)."""
     la = cfg.linear_attn
     n = cfg.count_mixers("linear")
-    return {
-        "state": jnp.zeros(
-            (n, slots, la.num_heads, la.key_head_dim, la.value_head_dim),
-            jnp.float32),
+    state = {
+        "state": jnp.zeros((n, slots, *state_slot_shape(la)), jnp.float32),
         # flat [..., (kernel - 1) * width]: a minor pair of (3, width) would
         # pad the 3 to a whole tile on the TPU, five times the bytes
         "conv": jnp.zeros(
             (n, slots, (la.conv_kernel - 1) * la.conv_size), dtype),
-        "stats": jnp.zeros((len(MOE_STATS),), jnp.uint32),
     }
+    if _expert_share(cfg):
+        state["stats"] = jnp.zeros((len(MOE_STATS),), jnp.uint32)
+    return state
+
+
+def _expert_share(cfg: ModelConfig) -> bool:
+    """The MLP of the model's MoE layers is ``_moe_share``: the router names
+    its own width, of which this chip holds a share."""
+    return cfg.moe is not None and cfg.moe.router_experts > 0
 
 
 # A row of the table that goes into every step program holds the row's
@@ -682,9 +713,10 @@ def cache_specs(
     if kv_quantize:
         values = QuantizedPages(values, scales)
     if cfg.has_state:
-        return {"k": values, "v": values,
-                "state": P(None, None, None, None, None),
-                "conv": P(None, None, None), "stats": P(None)}
+        rank = 2 + len(state_slot_shape(cfg.linear_attn))
+        stats = {"stats": P(None)} if _expert_share(cfg) else {}
+        return {"k": values, "v": values, "state": P(*[None] * rank),
+                "conv": P(None, None, None), **stats}
     return {"k": values, "v": values}
 
 
@@ -808,9 +840,13 @@ def _qkv(
         q = q + lp["bq"]
         k = k + lp["bk"]
         v = v + lp["bv"]
+    if cfg.qk_norm and cfg.qk_norm_whole:
+        # Olmo2: RMSNorm over the whole projection, before the heads split
+        q = rms_norm(q, lp["qn"], cfg.rms_norm_eps)
+        k = rms_norm(k, lp["kn"], cfg.rms_norm_eps)
     q = q.reshape(B, S, cfg.num_heads, D)
     k = k.reshape(B, S, K, D)
-    if cfg.qk_norm:
+    if cfg.qk_norm and not cfg.qk_norm_whole:
         # Qwen3: per-head RMSNorm over head_dim, BEFORE RoPE (the caller
         # applies rope to this function's outputs).
         q = rms_norm(q, lp["qn"], cfg.rms_norm_eps)
@@ -1077,16 +1113,25 @@ class Pack(NamedTuple):
 
 
 def _state_read(flat: jax.Array, idx: jax.Array, fresh: jax.Array):
-    """Rows ``idx`` of ``flat`` [slots, ...]; zeros where ``fresh``."""
-    got = flat[jnp.clip(idx, 0, flat.shape[0] - 1)]
+    """Rows ``idx`` of ``flat`` [slots, ...]; zeros where ``fresh``. One
+    dynamic slice a row: as one gather, the TPU compiler slices the WHOLE
+    array into pieces a row of which is under a megabyte before it gathers
+    (every slot of every layer copied in every layer: 2.4 GB of temporaries
+    at 24 layers x 48 slots x 2.2 MB, compile, PR 33), and walks the rows
+    itself only where a row is 4 MB or more."""
+    got = jax.lax.map(
+        lambda i: jax.lax.dynamic_index_in_dim(flat, i, keepdims=False),
+        jnp.clip(idx, 0, flat.shape[0] - 1))
     return jnp.where(fresh.reshape(-1, *([1] * (got.ndim - 1))), 0, got)
 
 
 def _linear_mixer(h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None):
-    """Delta-rule linear attention on the normed input h [B, S, d]: the
-    chunk form for S > 1, the one-token recurrence for S == 1, from and to
-    the rows' state slots (``ctx`` None: from zero, kept nowhere). Returns
-    (mixer output before the residual [B, S, d], cache)."""
+    """Gated delta-rule linear attention on the layer's input h [B, S, d]
+    (normed, in a pre-norm block): the chunk form for S > 1, the one-token
+    recurrence for S == 1, from and to the rows' state slots (``ctx`` None:
+    from zero, kept nowhere). The decay's width and the gates' kind are the
+    config's (``LinearAttnConfig``). Returns (mixer output before the
+    output projection [B, S, H * dv], cache)."""
     la = cfg.linear_attn
     B, S, _ = h.shape
     H, dk, dv = la.num_heads, la.key_head_dim, la.value_head_dim
@@ -1100,9 +1145,10 @@ def _linear_mixer(h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None):
             n_slots = cache["state"].shape[1]
             idx = si * n_slots + ctx.slots
             fresh = (ctx.start == 0) | (ctx.slots < 0)
-            state_flat = cache["state"].reshape(-1, H, dk, dv)
+            state_flat = cache["state"].reshape(-1, *cache["state"].shape[2:])
             conv_flat = cache["conv"].reshape(-1, cache["conv"].shape[-1])
-            S0 = _state_read(state_flat, idx, fresh)
+            # as held (``state_slot_shape``) -> what the delta rule takes
+            S0 = _state_read(state_flat, idx, fresh).reshape(B, H, dk, dv)
             tail = _state_read(conv_flat, idx, fresh).reshape(
                 B, la.conv_kernel - 1, la.conv_size)
     with jax.named_scope("lin_proj"):
@@ -1116,10 +1162,13 @@ def _linear_mixer(h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None):
         q, k = (a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
                 for a in (q, k))        # L2-normalised per head
         q = q * (dk ** -0.5)
-        decay = jax.nn.softplus(
-            _mm(_mm(h, lp["f_down"]), lp["f_up"]).astype(jnp.float32)
-            + lp["dt_bias"])
-        g = -jnp.exp(lp["a_log"])[:, None] * decay.reshape(B, S, H, dk)
+        raw = (_mm(_mm(h, lp["f_down"]), lp["f_up"])
+               if la.gates == "low_rank" else _mm(h, lp["wa"]))
+        decay = jax.nn.softplus(raw.astype(jnp.float32) + lp["dt_bias"])
+        if la.decay == "channel":
+            g = -jnp.exp(lp["a_log"])[:, None] * decay.reshape(B, S, H, dk)
+        else:
+            g = -jnp.exp(lp["a_log"]) * decay                  # [B, S, H]
         beta = jax.nn.sigmoid(_mm(h, lp["wb"]).astype(jnp.float32))
         if la.neg_eigval:
             beta = beta * 2.0
@@ -1128,7 +1177,7 @@ def _linear_mixer(h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None):
             live = (valid > 0)[:, None]
             o, S1 = delta_rule_step(
                 q[:, 0], k[:, 0], v[:, 0],
-                jnp.where(live[..., None], g[:, 0], 0.0),
+                jnp.where(live.reshape(B, *([1] * (g.ndim - 2))), g[:, 0], 0.0),
                 jnp.where(live, beta[:, 0], 0.0), S0)
             o = o[:, None]
         else:
@@ -1138,6 +1187,7 @@ def _linear_mixer(h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None):
             oob = state_flat.shape[0]
             wrote = (valid > 0) & (ctx.slots >= 0)
             on_page = (ctx.start + valid) % ctx.page_size == 0
+            S1 = S1.reshape(B, *state_flat.shape[1:])
             for to in (
                 jnp.where(wrote, idx, oob),
                 jnp.where(wrote & on_page & (ctx.snap >= 0),
@@ -1151,7 +1201,10 @@ def _linear_mixer(h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None):
                 conv=conv_flat.reshape(cache["conv"].shape))
     with jax.named_scope("lin_proj"):
         o = rms_norm(o, lp["o_norm"].astype(jnp.float32), cfg.rms_norm_eps)
-        gate = jax.nn.sigmoid(_mm(_mm(h, lp["g_down"]), lp["g_up"]))
+        if la.gates == "low_rank":
+            gate = jax.nn.sigmoid(_mm(_mm(h, lp["g_down"]), lp["g_up"]))
+        else:
+            gate = jax.nn.silu(_mm(h, lp["wog"]))
         out = o.reshape(B, S, H * dv).astype(h.dtype) * gate
     return out, cache
 
@@ -1441,13 +1494,24 @@ def _run_stack(
     packed tokens, and a linear layer gets rows and hands rows back."""
     Ld, Lm = _layer_split(cfg)
     runs = period_runs(cfg)
-    share = cfg.moe is not None and cfg.moe.router_experts > 0
+    share = _expert_share(cfg)
 
     def layer(carry, lp, mixer: str, moe: bool):
         x, aux, cache, (ai, *rest) = carry
         si = rest[0] if rest else None
+
+        # pre-norm: a sublayer's input is normed; post-norm (Olmo2): its
+        # output is, before the residual
+        def pre(a, name):
+            return a if cfg.post_norm else rms_norm(
+                a, lp[name], cfg.rms_norm_eps)
+
+        def post(a, name):
+            return rms_norm(
+                a, lp[name], cfg.rms_norm_eps) if cfg.post_norm else a
+
         with jax.named_scope("attn_qkv"):
-            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+            h = pre(x, "attn_norm")
         if mixer == "attn":
             attn, kc, vc = attn_fn(h, lp, cache["k"], cache["v"], ai)
             cache = dict(cache, k=kc, v=vc)
@@ -1455,7 +1519,7 @@ def _run_stack(
                 if cfg.attn_output_gate:
                     with jax.named_scope("attn_gate"):
                         attn = attn * jax.nn.sigmoid(_mm(h, lp["wgate"]))
-                x = x + _mm(attn, lp["wo"])
+                x = x + post(_mm(attn, lp["wo"]), "attn_norm")
         else:
             if pack is not None:
                 with jax.named_scope("lin_proj"):
@@ -1464,19 +1528,19 @@ def _run_stack(
             with jax.named_scope("attn_out"):
                 if pack is not None:
                     mixed = pack.tokens(mixed)
-                x = x + _mm(mixed, lp["lo"])
+                x = x + post(_mm(mixed, lp["lo"]), "attn_norm")
         with jax.named_scope("ffn"):
-            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+            h = pre(x, "mlp_norm")
             if moe and share:
                 y, stats = _moe_share(h, lp, cfg, token_valid)
-                x = x + y
+                x = x + post(y, "mlp_norm")
                 if "stats" in cache:
                     cache = dict(cache, stats=cache["stats"] + stats)
             elif moe:
                 y, layer_aux = _moe_mlp(h, lp, cfg)
-                x, aux = x + y, aux + layer_aux
+                x, aux = x + post(y, "mlp_norm"), aux + layer_aux
             else:
-                x = x + _mlp(h, lp)
+                x = x + post(_mlp(h, lp), "mlp_norm")
         if mixer == "attn":
             return (x, aux, cache, (ai + 1, *rest))
         return (x, aux, cache, (ai, si + 1))
@@ -1849,7 +1913,7 @@ def _row_state(cfg: ModelConfig, cache, table, start, valid, S: int):
     recurrent state or an expert share: (page table, StateCtx or None, the
     mask [B, S] of real positions or None). Any other model gets its
     table back and nothing else."""
-    share = cfg.moe is not None and cfg.moe.router_experts > 0
+    share = _expert_share(cfg)
     token_valid = (
         jnp.arange(S)[None, :] < valid[:, None] if share else None)
     if not cfg.has_state:

@@ -256,11 +256,13 @@ def load_checkpoint(
     restack of every tensor. ``Engine.from_snapshot`` bypasses it
     entirely — snapshot restore (serving/snapshot/restore.py) memory-maps
     leaves already in this stacked device layout."""
-    if cfg.mixer_period or cfg.attn_output_gate or not cfg.use_rope:
+    if (cfg.mixer_period or cfg.attn_output_gate or not cfg.use_rope
+            or cfg.post_norm or cfg.qk_norm_whole):
         raise NotImplementedError(
-            "load_checkpoint: no loader for a solar_open2 checkpoint (a "
-            "layer pattern, gated NoPE attention, linear-attention layers): "
-            "its checkpoint's tensor names are not public here; "
+            "load_checkpoint: no loader for a solar_open2 or olmo_hybrid "
+            "checkpoint (a layer pattern, gated or NoPE attention, "
+            "linear-attention layers, the post-norm block): its "
+            "checkpoint's tensor names are not public here; "
             "config_from_hf reads its config.json, and weights come seeded"
         )
     t0 = time.perf_counter()

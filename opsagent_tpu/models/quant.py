@@ -189,7 +189,7 @@ _QUANT_KEYS = frozenset(
      # gated attention and linear-attention projections (models/llama
      # linear_block): the conv, decay vectors and norms stay exact.
      "wgate", "lq", "lk", "lv", "lo", "f_down", "f_up", "g_down", "g_up",
-     "wb"}
+     "wb", "wa", "wog"}
 )
 
 

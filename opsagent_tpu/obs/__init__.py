@@ -192,6 +192,13 @@ STATE_SLOTS_IN_USE = _reg.gauge(
     "snapshot (held by a trie node or being written by a sequence)",
     labelnames=("kind",),
 )
+STATE_SLOT_BYTES = _reg.gauge(
+    "opsagent_state_slot_bytes",
+    "Bytes one recurrent-state slot holds over all linear-attention layers, "
+    "as the arrays are shaped (a device's tile padding not counted): the "
+    "float32 state, and the conv tail",
+    labelnames=("part",),
+)
 STATE_SNAPSHOTS = _reg.counter(
     "opsagent_state_snapshots_total",
     "State snapshots by event: taken (put on the trie node that ends a "

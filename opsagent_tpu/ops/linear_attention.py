@@ -1,11 +1,17 @@
-"""Delta-rule linear attention with a per-channel decay (Kimi Delta
-Attention), in plain ``jax.numpy`` under XLA.
+"""Gated delta-rule linear attention in plain ``jax.numpy`` under XLA: the
+mixer of Kimi Delta Attention (a decay for every key channel) and of Gated
+DeltaNet (one decay a head).
 
-Per head, with a float32 state ``S`` [key dim, value dim]:
+Per head, with a float32 state ``S`` [key dim, value dim] (the two dims
+need not be equal):
 
     S <- diag(exp(g_t)) S
     S <- S + beta_t k_t (v_t - S^T k_t)^T
     o_t = S^T q_t
+
+``g_t`` is ``[.., H, dk]`` (a decay a channel) or ``[.., H]`` (a decay a
+head: ``diag(exp(g_t))`` is then ``exp(g_t) I``). Which one is read from
+the shape of ``g``; nothing else tells the two apart.
 
 Two forms over the same mathematics:
 
@@ -18,9 +24,13 @@ Two forms over the same mathematics:
   = beta (v - K~ S_0)`` with ``A_ij = k_i . (exp(G_i - G_j) k_j)``, ``G`` the
   running sum of ``g`` inside the block. The decays ``exp(G_i - G_j)`` are
   never split into ``exp(G_i) exp(-G_j)`` across a whole block (the second
-  overflows under a strong decay): across sub-blocks of ``SUB`` tokens they
-  are split at the later sub-block's start, where both factors are at most
-  one, and inside a sub-block they are taken directly. The system is solved
+  overflows under a strong decay). With a decay a channel, across
+  sub-blocks of ``SUB`` tokens they are split at the later sub-block's
+  start, where both factors are at most one, and inside a sub-block they
+  are taken directly (``[SUB, SUB, dk]`` elementwise sums). With a decay a
+  head they are one ``[C, C]`` matrix ``Gamma`` a head, taken directly, and
+  ``A = (K K^T) * Gamma`` and ``Bq = (Q K^T) * Gamma`` go through the matrix
+  unit. Either way every decay factor is at most one. The system is solved
   by forward substitution in sub-blocks. A scan over the blocks carries the
   state.
 
@@ -61,10 +71,13 @@ def conv_with_tail(x, tail, w, valid):
 
 
 def delta_rule_step(q, k, v, g, beta, state):
-    """One token a row. q, k, g: [B, H, dk]; v: [B, H, dv]; beta: [B, H];
-    state: [B, H, dk, dv]. Returns (o [B, H, dv], new state). Elementwise
-    float32 throughout: nothing here goes through the matrix unit."""
-    state = state * jnp.exp(g)[..., None]
+    """One token a row. q, k: [B, H, dk]; g: [B, H, dk] or [B, H]; v: [B, H,
+    dv]; beta: [B, H]; state: [B, H, dk, dv]. Returns (o [B, H, dv], new
+    state). Elementwise float32 throughout: nothing here goes through the
+    matrix unit."""
+    decay = jnp.exp(g)
+    state = state * (decay[..., None] if g.ndim == k.ndim
+                     else decay[..., None, None])
     read = jnp.sum(state * k[..., None], axis=-2)              # S^T k
     state = state + (beta[..., None] * k)[..., None] * (v - read)[..., None, :]
     return jnp.sum(state * q[..., None], axis=-2), state
@@ -81,17 +94,11 @@ def _unit_lower_inverse(n_mat):
     return inv
 
 
-def _block_terms(q, k, v, g, beta):
-    """What a block needs that does not depend on the incoming state.
-
-    q, k, g: [..., C, dk]; v: [..., C, dv]; beta: [..., C] (leading axes:
-    batch, block, head). Returns (W [..., C, dk], U [..., C, dv], Bq [..., C,
-    C], q~ [..., C, dk], k^ [..., C, dk], decay of the whole block [..., dk]):
-    ``u = U - W S_0``, ``o = q~ S_0 + Bq u``, ``S_C = decay S_0 + k^T u``."""
-    C, dk = k.shape[-2:]
-    sub = SUB if C % SUB == 0 else C
-    n = C // sub
-    G = jnp.cumsum(g, axis=-2)                                 # inclusive
+def _pairs_by_channel(q, k, G, n: int, sub: int):
+    """``A_ij = k_i . (exp(G_i - G_j) k_j)`` (strictly lower) and ``Bq_ij =
+    q_i . (exp(G_i - G_j) k_j)`` (lower), [..., n, sub, n, sub], with a
+    decay a channel. q, k, G: [..., C, dk]."""
+    dk = k.shape[-1]
     lead = G.shape[:-2]
     Gs = G.reshape(*lead, n, sub, dk)
     ks = k.reshape(*lead, n, sub, dk)
@@ -121,6 +128,44 @@ def _block_terms(q, k, v, g, beta):
     same = jnp.eye(n, dtype=bool)[:, None, :, None]
     A = A + jnp.where(same, A_in[..., :, :, None, :], 0.0)
     Bq = Bq + jnp.where(same, Bq_in[..., :, :, None, :], 0.0)
+    return A, Bq
+
+
+def _pairs_by_head(q, k, G, n: int, sub: int):
+    """The same two with a decay a head, G: [..., C]: ``Gamma_ij = exp(G_i -
+    G_j)`` for ``i >= j`` is one ``[C, C]`` matrix a head, taken directly
+    (``G`` never rises, so every entry is at most one), and multiplies
+    ``K K^T`` and ``Q K^T`` from the matrix unit."""
+    lead, C = G.shape[:-1], G.shape[-1]
+    i = jnp.arange(C)
+    gamma = jnp.exp(jnp.where(
+        i[:, None] >= i[None, :], G[..., :, None] - G[..., None, :], -jnp.inf))
+    A = jnp.einsum("...id,...jd->...ij", k, k, precision=_HI) * gamma
+    Bq = jnp.einsum("...id,...jd->...ij", q, k, precision=_HI) * gamma
+    A = A * (i[:, None] > i[None, :])                          # strict
+    return (A.reshape(*lead, n, sub, n, sub),
+            Bq.reshape(*lead, n, sub, n, sub))
+
+
+def _block_terms(q, k, v, g, beta):
+    """What a block needs that does not depend on the incoming state.
+
+    q, k: [..., C, dk]; g: [..., C, dk] or [..., C]; v: [..., C, dv]; beta:
+    [..., C] (leading axes: batch, block, head). Returns (W [..., C, dk], U
+    [..., C, dv], Bq [..., C, C], q~ [..., C, dk], k^ [..., C, dk], decay of
+    the whole block [..., dk] or [..., 1]): ``u = U - W S_0``, ``o = q~ S_0
+    + Bq u``, ``S_C = decay S_0 + k^T u``."""
+    C, dk = k.shape[-2:]
+    sub = SUB if C % SUB == 0 else C
+    n = C // sub
+    lead = k.shape[:-2]
+    if g.ndim == k.ndim:
+        G = jnp.cumsum(g, axis=-2)                             # inclusive
+        A, Bq = _pairs_by_channel(q, k, G, n, sub)
+    else:
+        G = jnp.cumsum(g, axis=-1)
+        A, Bq = _pairs_by_head(q, k, G, n, sub)
+        G = G[..., None]                    # one decay for every channel
     # (I + diag(beta) A) [W | U] = beta [k~ | v], by sub-blocks
     bs = beta.reshape(*lead, n, sub)
     M = bs[..., :, :, None, None] * A                          # [...,a,i,b,j]
@@ -146,28 +191,32 @@ def _block_terms(q, k, v, g, beta):
 
 
 def delta_rule_chunk(q, k, v, g, beta, state, valid):
-    """``S`` tokens a row from the row's state. q, k, g: [B, S, H, dk]; v:
-    [B, S, H, dv]; beta: [B, S, H]; state: [B, H, dk, dv]; valid: [B] real
-    positions of each row (the rest leave the state untouched). Returns
-    (o [B, S, H, dv], new state)."""
+    """``S`` tokens a row from the row's state. q, k: [B, S, H, dk]; g: [B,
+    S, H, dk] or [B, S, H]; v: [B, S, H, dv]; beta: [B, S, H]; state: [B, H,
+    dk, dv]; valid: [B] real positions of each row (the rest leave the state
+    untouched). Returns (o [B, S, H, dv], new state)."""
     B, S, H, dk = k.shape
     real = (jnp.arange(S)[None, :] < valid[:, None])
-    g = jnp.where(real[:, :, None, None], g, 0.0)
+    g = jnp.where(real.reshape(B, S, *([1] * (g.ndim - 2))), g, 0.0)
     beta = jnp.where(real[:, :, None], beta, 0.0)
     C = BLOCK if S > BLOCK else (S if S <= SUB else -(-S // SUB) * SUB)
     pad = -S % C
     if pad:
-        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                      for a in (q, k, v, g))
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+        q, k, v, g, beta = (
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            for a in (q, k, v, g, beta))
     nb = (S + pad) // C
 
     def blocks(a):          # [B, S, H, x] -> [B, nb, H, C, x]
         return a.reshape(B, nb, C, H, -1).transpose(0, 1, 3, 2, 4)
 
+    def blocks_of_scalars(a):   # [B, S, H] -> [B, nb, H, C]
+        return blocks(a[..., None])[..., 0]
+
     W, U, Bq, q_fwd, k_end, decay = _block_terms(
-        blocks(q), blocks(k), blocks(v), blocks(g),
-        blocks(beta[..., None])[..., 0])
+        blocks(q), blocks(k), blocks(v),
+        blocks(g) if g.ndim == k.ndim else blocks_of_scalars(g),
+        blocks_of_scalars(beta))
 
     def block(S0, xs):
         W_, U_, Bq_, q_, k_, d_ = xs

@@ -628,6 +628,10 @@ class Engine:
         self._state_copy_jit = jax.jit(
             llama.copy_state_slots, donate_argnames=("cache",)
         )
+        if self.model_cfg.has_state:
+            for part in ("state", "conv"):
+                a = self.cache[part]
+                obs.STATE_SLOT_BYTES.set(a.nbytes // a.shape[1], part=part)
         self._moe_stats_seen = np.zeros((len(llama.MOE_STATS),), np.uint32)
         self._snapshots_seen = [0, 0]    # taken, evicted: obs delta bases
         # Host-RAM offload tier: spills ride every trie eviction, restores
@@ -1040,9 +1044,15 @@ class Engine:
         if self.weight_stream_leaves:
             info["weight_stream_leaves"] = dict(self.weight_stream_leaves)
         if self.model_cfg.has_state:
-            info["state_dtype"] = self.cache["state"].dtype.name
+            state = self.cache["state"]
+            info["state_dtype"] = state.dtype.name
             info["state_slots"] = self.alloc.state_slots
             info["state_snapshots"] = self.alloc.state_snapshots
+            # [linear layers, a slot's dims as held]: models.llama
+            # state_slot_shape says which of its two layouts and why
+            info["state_layout"] = [state.shape[0], *state.shape[2:]]
+            info["state_slot_bytes"] = state.nbytes // state.shape[1]
+            info["lin_decay"] = self.model_cfg.linear_attn.decay
         return info
 
     def device_memory(self) -> list[dict[str, Any]]:

@@ -185,3 +185,118 @@ def test_the_loader_refuses_a_solar_open2_checkpoint(tmp_path):
         dataclasses.replace(PRESETS["tiny-hybrid"]))))
     with pytest.raises(NotImplementedError, match="tensor names"):
         load_checkpoint(str(tmp_path), PRESETS["tiny-hybrid"], jnp.float32)
+
+
+# -- Olmo-Hybrid-7B: dense, attention LAST in the period, post-norm (PR 33) ------
+OLMO = PRESETS["olmo-hybrid-7b"]
+TINY_OLMO = PRESETS["tiny-olmo-hybrid"]
+
+
+def test_the_olmo_hybrid_preset_counts_7_43b():
+    assert 7.425e9 < OLMO.num_params() < 7.435e9
+    assert OLMO.num_params(active=True) == OLMO.num_params()     # dense
+    d, la = 3840, OLMO.linear_attn
+    assert (la.key_size, la.value_size, la.decay_size) == (2880, 5760, 30)
+    mixer = d * (2 * 2880 + 3 * 5760) + 4 * 11520       # q k v o gate, conv
+    gates = 2 * d * 30 + 30 + 30 + 192                  # a, b, A_log, dt, norm
+    ffn = 3 * d * 11008
+    linear, full = mixer + gates + ffn + 2 * d, 4 * d * d + 2 * d + ffn + 2 * d
+    assert OLMO.num_params() == (
+        24 * linear + 8 * full + 2 * 100352 * d + d)
+    assert (OLMO.count_mixers("linear"), OLMO.count_mixers("attn")) == (24, 8)
+    assert OLMO.head_dim_ == 128 and not OLMO.use_rope and OLMO.post_norm
+
+
+@pytest.mark.parametrize("name", ["tiny-olmo-hybrid"])
+def test_num_params_is_the_trees_size_with_full_rank_gates(name):
+    import jax
+
+    cfg = PRESETS[name]
+    tree = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0), jnp.float32))
+    assert cfg.num_params() == sum(x.size for x in jax.tree.leaves(tree))
+    linear = tree["layers"]["r0_linear"]
+    assert linear["wa"].shape == (2, 3, 64, 4)          # one decay a head
+    assert linear["dt_bias"].shape == (2, 3, 4)
+    assert linear["wog"].shape == (2, 3, 64, 96)        # full rank
+    assert not {"f_down", "f_up", "g_down", "g_up"} & set(linear)
+    assert tree["layers"]["r1_attn"]["qn"].shape == (2, 1, 64)   # whole width
+    specs = llama.param_specs(cfg)
+    assert jax.tree.structure(specs) == jax.tree.structure(
+        jax.tree.map(lambda x: 0, tree))
+
+
+@pytest.mark.parametrize("cfg", [OLMO, TINY_OLMO], ids=lambda c: c.name)
+def test_olmo_hybrid_round_trips_through_its_hf_config(cfg, tmp_path):
+    hf = hf_config_dict(cfg)
+    assert hf["model_type"] == "olmo_hybrid"
+    assert hf["layer_types"][:4] == ["linear_attention"] * 3 + ["full_attention"]
+    assert hf["rope_parameters"] == {"rope_theta": None}
+    (tmp_path / "config.json").write_text(json.dumps(hf))
+    assert config_from_hf(str(tmp_path), name=cfg.name) == cfg
+
+
+@pytest.mark.skipif(not os.path.exists(CATALOG), reason="no catalog here")
+def test_the_catalogs_olmo_hybrid_keys_give_the_preset(tmp_path):
+    with open(CATALOG) as f:
+        row = next(json.loads(line) for line in f
+                   if '"Olmo-Hybrid-7B"' in line)
+    (tmp_path / "config.json").write_text(json.dumps(row["config"]))
+    got = config_from_hf(str(tmp_path), name=OLMO.name)
+    assert got == OLMO
+    assert got.mixer_period == ("linear", "linear", "linear", "attn")
+    back = hf_config_dict(got)
+    for key, value in row["config"].items():
+        assert back[key] == value, key
+
+
+@pytest.mark.parametrize("change,said", [
+    ({"layer_types": ["sliding_attention"] * 8}, "layer_types"),
+    ({"layer_types": ["full_attention"] * 3}, "layer_types"),
+    ({"linear_num_key_heads": 2}, "grouped"),
+])
+def test_an_olmo_hybrid_config_the_engine_cannot_run_is_refused(
+        change, said, tmp_path):
+    hf = dict(hf_config_dict(TINY_OLMO), **change)
+    (tmp_path / "config.json").write_text(json.dumps(hf))
+    with pytest.raises(ValueError, match=said):
+        config_from_hf(str(tmp_path))
+
+
+def test_a_period_that_ends_in_attention_runs_linear_first():
+    assert llama.period_runs(OLMO) == (
+        ("r0_linear", "linear", 3), ("r1_attn", "attn", 1))
+    # a rope_theta in the file turns the rotary embedding on
+    hf = dict(hf_config_dict(TINY_OLMO), rope_parameters={"rope_theta": 5e5})
+    assert _from(hf).use_rope and _from(hf).rope_theta == 5e5
+    # all-attention layer_types are the period of one
+    hf = dict(hf_config_dict(TINY_OLMO), layer_types=["full_attention"] * 8)
+    assert _from(hf).mixer_period == ("attn",) and not _from(hf).has_state
+    with pytest.raises(ValueError, match="decay"):
+        dataclasses.replace(TINY_OLMO.linear_attn, decay="row")
+    with pytest.raises(ValueError, match="solar_open2"):
+        hf_config_dict(dataclasses.replace(
+            PRESETS["tiny-hybrid"], linear_attn=dataclasses.replace(
+                PRESETS["tiny-hybrid"].linear_attn, decay="head")))
+
+
+def _from(hf: dict) -> ModelConfig:
+    import tempfile
+
+    with tempfile.TemporaryDirectory() as d:
+        with open(os.path.join(d, "config.json"), "w") as f:
+            json.dump(hf, f)
+        return config_from_hf(d, name="x")
+
+
+def test_the_loader_refuses_an_olmo_hybrid_checkpoint(tmp_path):
+    from opsagent_tpu.models.loader import load_checkpoint
+
+    (tmp_path / "config.json").write_text(
+        json.dumps(hf_config_dict(TINY_OLMO)))
+    with pytest.raises(NotImplementedError, match="olmo_hybrid"):
+        load_checkpoint(str(tmp_path), TINY_OLMO, jnp.float32)
+    # ... and the post-norm block alone, whatever its mixers
+    with pytest.raises(NotImplementedError, match="tensor names"):
+        load_checkpoint(str(tmp_path), dataclasses.replace(
+            PRESETS["tiny-test"], post_norm=True), jnp.float32)

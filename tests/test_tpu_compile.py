@@ -705,6 +705,51 @@ def test_split_pages_at_four_kv_heads_are_copied_whole_in_every_layer(v5e):
     assert all(c.split("[")[0] + " = " in body for c in copies)
 
 
+def test_a_linear_layer_reads_its_rows_state_without_copying_every_slot(v5e):
+    """Olmo-Hybrid-7B's widths at one period (three linear layers and one
+    attention layer), the cell's 16 rows x 32 slots packed to 256 tokens,
+    2048 pages and 48 state slots: the mixed step holds no operation as
+    large as the whole state. Read as ONE gather of 2.2 MB rows the chip's
+    compiler first slices all of ``[layers x slots, ...]`` into pieces a
+    row of which is under a megabyte (``mini-gather-slice``), in every
+    layer: 2.4 GB of temporaries at the model's 24 linear layers, which did
+    not fit the chip (compile, PR 33). ``llama._state_read`` takes a row at
+    a time, and the program's scratch HBM is under 256 MB."""
+    from opsagent_tpu.models import llama
+    from opsagent_tpu.serving import decode_loop
+
+    sds = _one_chip(v5e)
+    cfg = dataclasses.replace(get_config_preset("olmo-hybrid-7b"), num_layers=4)
+    b, s, n, maxp, slots = 16, 32, 2048, 336, 48
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: sds(x.shape, x.dtype), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
+    cache = on_chip(jax.eval_shape(lambda: llama.make_cache(
+        cfg, n, PAGE, jnp.bfloat16, state_slots=slots,
+        form=llama.cache_form(cfg, 1, "pallas-stream"))))
+    assert cache["state"].shape == (3, slots, 4320, 128), "nothing padded"
+    key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    i32 = lambda *d: sds(d, jnp.int32)       # noqa: E731
+    f32 = lambda *d: sds(d, jnp.float32)     # noqa: E731
+    flag = lambda *d: sds(d, jnp.bool_)      # noqa: E731
+
+    def step(params, tokens, use_carry, carry, starts, qlens, emits, cache,
+             table, key, temps, top_k, top_p):
+        return decode_loop.mixed_step_carry(
+            params, cfg, tokens, use_carry, carry, starts, qlens, emits,
+            cache, table, key, temps, top_k, top_p,
+            attn_impl="pallas-stream", step_tokens=256)
+
+    compiled = jax.jit(step, donate_argnames=("cache",)).lower(
+        params, i32(b, s), flag(b), i32(b), i32(b), i32(b), flag(b), cache,
+        i32(b, maxp + llama.STATE_COLUMNS), key, f32(b), i32(b), f32(b),
+    ).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "mini-gather" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
 def test_stream_kernel_is_exported_once_a_shape(v5e, tmp_path, monkeypatch):
     """A second program holding the kernel at the same shape inlines the
     exported bytes (no second trace of the kernel's body); the bytes lie
