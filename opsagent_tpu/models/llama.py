@@ -828,11 +828,10 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return (xf * scale).astype(x.dtype) * w
 
 
-def _qkv(
+def _qkv_flat(
     x: jax.Array, lp: Params, cfg: ModelConfig
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    B, S, _ = x.shape
-    K, D = cfg.num_kv_heads, cfg.head_dim_
+    """The q/k/v projections ``[B, S, heads * D]``, heads not yet split."""
     q = _mm(x, lp["wq"])
     k = _mm(x, lp["wk"])
     v = _mm(x, lp["wv"])
@@ -844,6 +843,16 @@ def _qkv(
         # Olmo2: RMSNorm over the whole projection, before the heads split
         q = rms_norm(q, lp["qn"], cfg.rms_norm_eps)
         k = rms_norm(k, lp["kn"], cfg.rms_norm_eps)
+    return q, k, v
+
+
+def _heads(q, k, v, lp: Params, cfg: ModelConfig):
+    """``_qkv_flat``'s projections split into heads ``[B, S, heads, D]``.
+    Apart from the projections: inside a branch of ``Pack.dense`` the
+    reshape is a layout the chip's compiler chooses for the branch alone
+    (``docs/ARCHITECTURE.md``, "Two widths in the one program")."""
+    B, S, _ = q.shape
+    K, D = cfg.num_kv_heads, cfg.head_dim_
     q = q.reshape(B, S, cfg.num_heads, D)
     k = k.reshape(B, S, K, D)
     if cfg.qk_norm and not cfg.qk_norm_whole:
@@ -997,18 +1006,20 @@ def _yarn_q_scale(cfg: ModelConfig) -> float:
 
 @scoped("attn_qkv")
 def _qkv_rope(
-    x: jax.Array, lp: Params, cfg: ModelConfig, cos, sin, rows=None
+    x: jax.Array, lp: Params, cfg: ModelConfig, cos, sin,
+    pack: "Pack | None" = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """q/k/v with RoPE applied, dispatched on the attention family. The
     rope tables must be built with ``cfg.rope_dim_`` (the decoupled rope
-    part under MLA, the full head otherwise). ``rows`` un-packs a packed
-    stream (``Pack.rows``) where the tables and the caller want rows: the
-    projections run over the packed tokens, RoPE over rows."""
+    part under MLA, the full head otherwise). With ``pack`` the stream is
+    packed tokens and the tables and the caller want rows: the projections
+    run over the packed tokens (``Pack.dense``), RoPE over rows."""
     if cfg.mla is not None:
-        return _qkv_mla(x if rows is None else rows(x), lp, cfg, cos, sin)
-    q, k, v = _qkv(x, lp, cfg)
-    if rows is not None:
-        q, k, v = rows(q), rows(k), rows(v)
+        return _qkv_mla(x if pack is None else pack.rows(x), lp, cfg, cos, sin)
+    q, k, v = _dense(pack, lambda a: _qkv_flat(a, lp, cfg), x)
+    if pack is not None:
+        q, k, v = pack.rows(q), pack.rows(k), pack.rows(v)
+    q, k, v = _heads(q, k, v, lp, cfg)
     if cos is None:     # no positional embedding (cfg.use_rope false)
         return q, k, v
     return (
@@ -1036,16 +1047,27 @@ class _Indexed:
 _EXPERT_STACKS = ("eg", "eu", "ed")
 
 
-def _layer_view(stack: Params, idx: tuple, whole_experts: bool) -> Params:
+class _LayerView:
     """One layer's leaves out of ``stack`` (leaves ``[periods, layers of
-    the run, ...]``) at ``idx``; the expert stacks stay whole where the
-    expert share indexes them itself."""
-    return {
-        name: _Indexed(leaf, idx)
-        if whole_experts and name in _EXPERT_STACKS
-        else jax.tree.map(lambda a: a[idx], leaf)
-        for name, leaf in stack.items()
-    }
+    the run, ...]``, or ``[layers, ...]``) at ``idx``, each taken where it
+    is ASKED for: a weight read inside a branch of a conditional
+    (``Pack.dense``) is then sliced out of the stack inside that branch,
+    under its matmul. Sliced before the conditional it is an operand of
+    its own, which the chip's compiler writes out whole (68 MB of int8 a
+    matrix a layer at the 7B's widths; compile, PR 34). The expert stacks
+    stay whole where the expert share indexes them itself."""
+
+    def __init__(self, stack: Params, idx: tuple, whole_experts: bool = False):
+        self.stack, self.idx, self.whole_experts = stack, idx, whole_experts
+
+    def __contains__(self, name: str) -> bool:
+        return name in self.stack
+
+    def __getitem__(self, name: str):
+        leaf = self.stack[name]
+        if self.whole_experts and name in _EXPERT_STACKS:
+            return _Indexed(leaf, self.idx)
+        return jax.tree.map(lambda a: a[self.idx], leaf)
 
 
 def _one_expert(stack, e):
@@ -1075,15 +1097,20 @@ class Pack(NamedTuple):
     over, so that their cost follows the tokens a tick carries and not the
     slots its rows are padded to. A mixer that needs rows gets them with
     ``rows`` and hands its output back with ``tokens``; padding reads zero
-    on either side."""
+    on either side. Real tokens are the first ``n`` of the ``T``, so what
+    lies between mixers (``dense``) runs over the first ``narrow`` tokens
+    alone in a tick that carries no more: one program, two widths, chosen
+    on the device."""
 
     dst: jax.Array      # [B, S] the token of each slot (T: padding)
     src: jax.Array      # [T] the slot b * S + s of each token (B * S: none)
     valid: jax.Array    # [1, T] real tokens
     last: jax.Array     # [B] the token at each row's last valid position
+    n: jax.Array        # [] the tokens the tick carries
+    narrow: int         # the width a tick of ``narrow`` tokens or fewer runs
 
     @classmethod
-    def of(cls, q_lens: jax.Array, S: int, T: int) -> "Pack":
+    def of(cls, q_lens: jax.Array, S: int, T: int, narrow: int) -> "Pack":
         """From the rows' lengths alone, on the device; rows past ``T``
         tokens in all are the caller's to refuse (``Engine`` does)."""
         B = q_lens.shape[0]
@@ -1096,7 +1123,7 @@ class Pack(NamedTuple):
         at = t - offsets[jnp.minimum(row, B - 1)]
         src = jnp.where(row < B, row * S + at, B * S)
         return cls(dst, src, (t < ends[-1])[None, :],
-                   jnp.clip(ends - 1, 0, T - 1))
+                   jnp.clip(ends - 1, 0, T - 1), ends[-1], narrow)
 
     def rows(self, a: jax.Array) -> jax.Array:
         """``[1, T, ...]`` -> ``[B, S, ...]``."""
@@ -1110,6 +1137,43 @@ class Pack(NamedTuple):
         flat = a.reshape(a.shape[0] * a.shape[1], -1)
         got = jnp.take(flat, self.src, axis=0, mode="fill", fill_value=0)
         return got.reshape(1, -1, *a.shape[2:])
+
+    def dense(self, fn, *xs: jax.Array):
+        """``fn(*xs)`` for a ``fn`` that treats each token alone (norms,
+        projections, an MLP, a residual sum: ``[1, T, ...]`` in and out):
+        over the first ``narrow`` tokens where the tick carries no more,
+        the rest of the ``T`` given back as zeros, which is padding that
+        nothing reads. Both widths are branches of one conditional inside
+        the one program; a row of a matmul does not depend on how many
+        rows ride with it, so a real token reads the same either way."""
+        T, w = xs[0].shape[1], self.narrow
+
+        def few(*xs):
+            return jax.tree.map(
+                lambda y: jnp.pad(
+                    y, ((0, 0), (0, T - w)) + ((0, 0),) * (y.ndim - 2)),
+                fn(*(x[:, :w] for x in xs)))
+
+        return jax.lax.cond(self.n <= w, few, fn, *xs)
+
+
+def pack_widths(slots: int, step_tokens: int) -> tuple[int, int] | None:
+    """``(T, narrow)`` of the mixed program whose rows have ``slots`` slots
+    in all, at ``step_tokens`` tokens a step at most: the width its stream
+    is packed to and the width a tick of ``narrow`` tokens or fewer runs
+    its dense segments at; None where the program does not pack (its slots
+    are no more than ``narrow``, or no step width is given). The one
+    statement of the rule: ``mixed_step`` builds its ``Pack`` from it and
+    ``Engine`` counts and reports by it."""
+    narrow = step_tokens // 2
+    if not 0 < narrow < slots:
+        return None
+    return min(step_tokens, slots), narrow
+
+
+def _dense(pack: Pack | None, fn, *xs: jax.Array):
+    """``fn(*xs)``, at the width the tick needs where the stream is packed."""
+    return fn(*xs) if pack is None else pack.dense(fn, *xs)
 
 
 def _state_read(flat: jax.Array, idx: jax.Array, fresh: jax.Array):
@@ -1516,10 +1580,13 @@ def _run_stack(
             attn, kc, vc = attn_fn(h, lp, cache["k"], cache["v"], ai)
             cache = dict(cache, k=kc, v=vc)
             with jax.named_scope("attn_out"):
-                if cfg.attn_output_gate:
-                    with jax.named_scope("attn_gate"):
-                        attn = attn * jax.nn.sigmoid(_mm(h, lp["wgate"]))
-                x = x + post(_mm(attn, lp["wo"]), "attn_norm")
+                def out(x, attn, h):
+                    if cfg.attn_output_gate:
+                        with jax.named_scope("attn_gate"):
+                            attn = attn * jax.nn.sigmoid(_mm(h, lp["wgate"]))
+                    return x + post(_mm(attn, lp["wo"]), "attn_norm")
+
+                x = _dense(pack, out, x, attn, h)
         else:
             if pack is not None:
                 with jax.named_scope("lin_proj"):
@@ -1528,25 +1595,33 @@ def _run_stack(
             with jax.named_scope("attn_out"):
                 if pack is not None:
                     mixed = pack.tokens(mixed)
-                x = x + post(_mm(mixed, lp["lo"]), "attn_norm")
+                x = _dense(
+                    pack,
+                    lambda x, mixed: x + post(_mm(mixed, lp["lo"]), "attn_norm"),
+                    x, mixed)
         with jax.named_scope("ffn"):
-            h = pre(x, "mlp_norm")
             if moe and share:
-                y, stats = _moe_share(h, lp, cfg, token_valid)
+                y, stats = _moe_share(pre(x, "mlp_norm"), lp, cfg, token_valid)
                 x = x + post(y, "mlp_norm")
                 if "stats" in cache:
                     cache = dict(cache, stats=cache["stats"] + stats)
             elif moe:
-                y, layer_aux = _moe_mlp(h, lp, cfg)
+                y, layer_aux = _moe_mlp(pre(x, "mlp_norm"), lp, cfg)
                 x, aux = x + post(y, "mlp_norm"), aux + layer_aux
             else:
-                x = x + post(_mlp(h, lp), "mlp_norm")
+                x = _dense(
+                    pack,
+                    lambda x: x + post(
+                        _mlp(pre(x, "mlp_norm"), lp), "mlp_norm"),
+                    x)
         if mixer == "attn":
             return (x, aux, cache, (ai + 1, *rest))
         return (x, aux, cache, (ai, si + 1))
 
     def make_body(moe: bool, stack: Params):
-        def body(carry, lp):
+        def body(carry, lp):    # a layer's leaves; its index where packed
+            if pack is not None:
+                lp = _LayerView(stack, (lp,))
             return layer(carry, lp, "attn", moe), None
 
         def period(carry, p):
@@ -1556,7 +1631,7 @@ def _run_stack(
             # leaves once a period (the chip's compiler, 1.8 GB of them).
             for key, mixer, n in runs:
                 def one(c, j, key=key, mixer=mixer):
-                    lp = _layer_view(stack[key], (p, j), moe and share)
+                    lp = _LayerView(stack[key], (p, j), moe and share)
                     return layer(c, lp, mixer, moe), None
                 if n == 1:
                     carry, _ = one(carry, 0)
@@ -1568,7 +1643,11 @@ def _run_stack(
         return jax.checkpoint(body) if remat else body
 
     def xs(stack: Params):
-        if not runs:
+        # A stack of like layers is scanned by its slices where nothing
+        # branches: scanned by index the fused decode block compiles to the
+        # same scratch, copies and bytes but runs 0.7% slower on the chip
+        # (docs/ARCHITECTURE.md, "Two widths in the one program").
+        if not runs and pack is None:
             return stack
         return jnp.arange(jax.tree.leaves(stack)[0].shape[0])
 
@@ -1727,25 +1806,30 @@ def mixed_step(
     caller discards). Returns (last-valid-position logits [B, V],
     updated cache).
 
-    Where the rows' slots outnumber ``step_tokens`` the residual stream is
-    the tick's tokens packed ``[1, step_tokens, d]`` (``Pack``): embedding,
-    norms, projections and the MLP run over tokens; RoPE, the page write
-    and attention see ``[B, S, H, D]`` rows as before, and a
-    linear-attention or MLA mixer sees rows of the normed stream. The
-    caller holds ``sum(q_lens)`` to ``step_tokens``."""
+    Where the rows' slots outnumber HALF of ``step_tokens`` the residual
+    stream is the tick's tokens packed ``[1, T, d]`` (``Pack``; ``T`` is
+    ``step_tokens``, or the slots where those are fewer): embedding, norms,
+    projections and the MLP run over tokens; RoPE, the page write and
+    attention see ``[B, S, H, D]`` rows as before, and a linear-attention
+    or MLA mixer sees rows of the normed stream. The caller holds
+    ``sum(q_lens)`` to ``step_tokens``. What lies between two mixers (q/k/v,
+    the output projection and its residual, the dense MLP with its norm and
+    residual) runs over the first ``step_tokens // 2`` packed tokens alone
+    in a tick that carries no more (``Pack.dense``): the width is chosen on
+    the device from ``sum(q_lens)``, inside this one program. An expert
+    layer, a mixer's own projections and the head keep their shapes."""
     B, S = tokens.shape
     positions = start[:, None] + jnp.arange(S)[None, :]
     cos, sin = _rope_tables(cfg, positions)
     page_table, sctx, token_valid = _row_state(
         cfg, cache, page_table, start, q_lens, S)
-    pack = None
-    if 0 < step_tokens < B * S:
+    pack, widths = None, pack_widths(B * S, step_tokens)
+    if widths is not None:
         with jax.named_scope("embed"):
-            pack = Pack.of(q_lens, S, step_tokens)
+            pack = Pack.of(q_lens, S, *widths)
             tokens = pack.tokens(tokens)
         if token_valid is not None:
             token_valid = pack.valid
-    rows = None if pack is None else pack.rows
     x = _embed(params, tokens, dtype)
 
     def packed(a):
@@ -1768,7 +1852,7 @@ def mixed_step(
                 impl=attn_impl, layer=li, mesh=mesh,
             )
             return _mla_latent_out(packed(ctx), lp, cfg), kc, vc
-        q, k, v = _qkv_rope(h, lp, cfg, cos, sin, rows)
+        q, k, v = _qkv_rope(h, lp, cfg, cos, sin, pack)
         kc, vc = write_kv_pages(
             kc, vc, k, v, page_table, start, valid_len=q_lens, layer=li
         )

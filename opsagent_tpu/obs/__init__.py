@@ -133,10 +133,16 @@ STEP_TOKENS = _reg.counter(
     "opsagent_step_tokens_total",
     "Tokens of mixed dispatches, counted at dispatch: kind=real the tokens "
     "the dispatch carried (decode lanes, forced runs, chunk tokens), "
-    "kind=computed the rows its norms, projections and MLP ran over (the "
-    "packed width where the program packs, else rows x chunk bucket); "
-    "real / computed is the step's fill share",
+    "kind=computed the rows its projections and MLP ran over (rows x chunk "
+    "bucket, or the packed width where the program packs, or half of it in "
+    "a tick that carries no more); real / computed is the step's fill share",
     labelnames=("kind",),
+)
+MIXED_DISPATCH_WIDTH = _reg.counter(
+    "opsagent_mixed_dispatch_width_total",
+    "Mixed dispatches by the rows their projections and MLP ran over "
+    "(what kind=computed of opsagent_step_tokens_total adds up)",
+    labelnames=("width",),
 )
 # -- async mixed serving runtime (serving/async_runtime.py) -------------------
 STEP_HOST_GAP_SECONDS = _reg.histogram(

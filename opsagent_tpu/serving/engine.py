@@ -688,10 +688,11 @@ class Engine:
         mc, dt = self.model_cfg, cfg.dtype
         # The most tokens one mixed dispatch carries (decode lanes, forced
         # runs and chunks together), up to whole MXU passes of 128 rows:
-        # where a mixed program's rows have more slots than this, its
-        # matmuls run over the tokens packed to this width instead
-        # (llama.mixed_step). Derived from the budget the scheduler plans
-        # to; the planners below hold every dispatch to it.
+        # where a mixed program's rows have more slots than HALF of this,
+        # its matmuls run over the tokens packed to this width instead,
+        # and over half of it in a tick that carries no more
+        # (llama.mixed_step, llama.Pack.dense). Derived from the budget the
+        # scheduler plans to; the planners below hold every dispatch to it.
         self.step_tokens = -(
             -max(cfg.max_step_tokens, cfg.max_batch_size) // 128) * 128
 
@@ -1035,10 +1036,10 @@ class Engine:
             "kv_page_form": self.page_form,
             "fsm_impl": native.impl(),
             "step_rows": (
-                f"packed:{self.step_tokens}"
-                if self.cfg.mixed_batching and self.step_tokens
-                < self.cfg.max_batch_size * self.cfg.mixed_buckets[-1]
-                else "rows"
+                f"packed:{self._step_rows(self.cfg.mixed_buckets[-1])}"
+                if self.cfg.mixed_batching and llama.pack_widths(
+                    self.cfg.max_batch_size * self.cfg.mixed_buckets[-1],
+                    self.step_tokens) else "rows"
             ),
         }
         if self.weight_stream_leaves:
@@ -2075,14 +2076,23 @@ class Engine:
                 return b
         return self.cfg.mixed_buckets[-1]
 
-    def _step_rows(self, S: int) -> int:
-        """Rows the matmuls of the mixed program of bucket ``S`` run over:
-        the packed width where its slots outnumber it, else its slots."""
-        return min(self.cfg.max_batch_size * S, self.step_tokens)
+    def _step_rows(self, S: int, real: int | None = None) -> int:
+        """Rows the matmuls of the mixed program of bucket ``S`` run over
+        in a tick of ``real`` tokens (None: at most): its slots, or the
+        packed width where it packs, or the narrow width where it packs
+        and the tick carries no more (``llama.pack_widths``)."""
+        slots = self.cfg.max_batch_size * S
+        widths = llama.pack_widths(slots, self.step_tokens)
+        if widths is None:
+            return slots
+        T, narrow = widths
+        return narrow if real is not None and real <= narrow else T
 
     def _count_step_tokens(self, S: int, real: int) -> None:
+        width = self._step_rows(S, real)
         obs.STEP_TOKENS.inc(real, kind="real")
-        obs.STEP_TOKENS.inc(self._step_rows(S), kind="computed")
+        obs.STEP_TOKENS.inc(width, kind="computed")
+        obs.MIXED_DISPATCH_WIDTH.inc(width=str(width))
 
     def mixed_hosted(self, seq_id: int) -> bool:
         """True when this sequence needs host-side per-token work — a

@@ -125,7 +125,8 @@ def stream_kernel():
 
 
 # Six rows of a 16-slot bucket (one case: 32) packed to 32 tokens: the
-# ragged shapes a packed mixed step must serve as the rows program does.
+# ragged shapes a packed mixed step must serve as the rows program does. A
+# tick of 16 tokens or fewer runs its dense segments 16 wide (Pack.dense).
 _RAGGED = {
     "rows_of_0_1_and_S": ((1, 16, 0, 7, 1, 3), 16),
     "one_token_in_all": ((0, 0, 1, 0, 0, 0), 16),
@@ -133,6 +134,9 @@ _RAGGED = {
     "T_tokens": ((16, 0, 1, 0, 15, 0), 16),
     "all_rows_decoding": ((1, 1, 1, 1, 1, 1), 16),
     "one_row_holds_all_of_T": ((0, 0, 32, 0, 0, 0), 32),
+    "no_token_at_all": ((0, 0, 0, 0, 0, 0), 16),
+    "half_of_T": ((1, 7, 0, 8, 0, 0), 16),          # the widest narrow tick
+    "half_of_T_and_one": ((1, 7, 0, 8, 0, 1), 16),  # the emptiest wide one
 }
 
 
@@ -146,9 +150,11 @@ def ragged_case(request):
 def packed_against_rows():
     """``check(cfg, params, q_lens, S, tol, table=None)``: one
     ``llama.mixed_step`` over six rows that already hold 32 tokens each,
-    packed to 32 tokens and over rows, float32: logits at the last
-    position of every live row and every leaf of the cache tree agree
-    within ``tol``. One jit a (cfg, S, width), whatever the lengths."""
+    over rows, packed to 32 tokens (its dense segments 16 wide in a tick
+    of 16 tokens or fewer) and packed with every segment forced to the
+    whole 32, float32: logits at the last position of every live row and
+    every leaf of the cache tree agree within ``tol``. One jit a (cfg, S,
+    width, forced), whatever the lengths."""
     import functools
 
     import jax.numpy as jnp
@@ -159,9 +165,17 @@ def packed_against_rows():
     B, T, PAGE, MAXP = 6, 32, 16, 8
 
     @functools.lru_cache(maxsize=None)
-    def step(cfg, width):
-        return jax.jit(functools.partial(
-            llama.mixed_step, cfg=cfg, dtype=jnp.float32, step_tokens=width))
+    def step(cfg, width, wide=False):
+        fn = functools.partial(
+            llama.mixed_step, cfg=cfg, dtype=jnp.float32, step_tokens=width)
+
+        def forced(*args, **kw):    # traced once, under the patch
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(
+                    llama.Pack, "dense", lambda self, f, *xs: f(*xs))
+                return fn(*args, **kw)
+
+        return jax.jit(forced if wide else fn)
 
     def check(cfg, params, q_lens, S, tol, table=None):
         if table is None:
@@ -172,22 +186,24 @@ def packed_against_rows():
         start = jnp.asarray([5, 0, 0, 9, 20, 2], jnp.int32)
         q_lens = jnp.asarray(q_lens, jnp.int32)
         got = []
-        for width in (0, T):
+        for width, wide in ((0, False), (T, False), (T, True)):
             kw = {"state_slots": 8} if cfg.has_state else {}
             cache = llama.make_cache(
                 cfg, B * MAXP, PAGE, dtype=jnp.float32, **kw)
             _, cache = step(cfg, 0)(
                 params, tokens=held, start=jnp.zeros((B,), jnp.int32),
                 q_lens=start, cache=cache, page_table=table)
-            got.append(step(cfg, width)(
+            got.append(step(cfg, width, wide)(
                 params, tokens=new, start=start, q_lens=q_lens, cache=cache,
                 page_table=table))
-        (rows, rows_cache), (packed, packed_cache) = got
+        (rows, rows_cache), *packed = got
         live = np.asarray(q_lens) > 0
-        assert float(jnp.max(jnp.abs(rows[live] - packed[live]))) < tol
-        for a, b in zip(jax.tree.leaves(rows_cache),
-                        jax.tree.leaves(packed_cache)):
-            assert float(jnp.max(jnp.abs(a - b))) < tol
+        for logits, cache in packed:
+            assert float(jnp.max(
+                jnp.abs(rows[live] - logits[live]), initial=0.0)) < tol
+            for a, b in zip(jax.tree.leaves(rows_cache),
+                            jax.tree.leaves(cache), strict=True):
+                assert float(jnp.max(jnp.abs(a - b))) < tol
 
     return check
 

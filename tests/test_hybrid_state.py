@@ -40,6 +40,35 @@ def highest():
         yield
 
 
+@pytest.fixture(autouse=True)
+def release_compiled_programs():
+    """After each test of this file, drop JAX's in-process caches of
+    compiled programs once the process holds more than two fifths of the
+    memory mappings it may have. On the CPU every compiled program, each
+    eager operation's too, holds several mappings of its own, a process
+    may hold ``vm.max_map_count`` of them (65,530 here), and past that the
+    next compile or the next write to the persistent compile cache dies
+    with a segmentation fault or an abort: this file alone reached 59,574
+    with PR 34's cases in it and died in its last test, where the parent's
+    stopped some thousands short (one of its tests alone adds 28,000).
+    ``jax.clear_caches()`` gives them back (30,843 -> 711 after that test);
+    what is needed again is read back from the persistent cache or
+    compiled again. No test here counts compiles across tests."""
+    yield
+    try:
+        with open("/proc/sys/vm/max_map_count") as f:
+            limit = int(f.read())
+        with open("/proc/self/maps") as f:
+            held = sum(1 for _ in f)
+    except (OSError, ValueError):
+        return      # no such files: not Linux, nothing known to guard
+    if held > 0.4 * limit:
+        import gc
+
+        jax.clear_caches()
+        gc.collect()
+
+
 def _randomised(tree, key):
     """``init_params`` leaves decay rates, offsets, biases and norms at
     zero or one; give them values, so that a dropped one shows."""

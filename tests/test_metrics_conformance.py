@@ -207,6 +207,18 @@ def test_step_tokens_family_on_the_scrape():
         assert f'opsagent_step_tokens_total{{kind="{kind}"}}' in text
 
 
+def test_mixed_dispatch_width_family_on_the_scrape():
+    """How often the narrow branch of a packed mixed step ran (ISSUE 34):
+    one counter labelled by the rows the dense segments ran over; pinned
+    so a rename is a visible contract break."""
+    obs.MIXED_DISPATCH_WIDTH.inc(width="128")
+    obs.MIXED_DISPATCH_WIDTH.inc(3, width="256")
+    text = obs.metrics_text()
+    assert "# TYPE opsagent_mixed_dispatch_width_total counter" in text
+    assert 'opsagent_mixed_dispatch_width_total{width="128"} 1' in text
+    assert 'opsagent_mixed_dispatch_width_total{width="256"} 3' in text
+
+
 def test_fleet_journey_families_on_the_scrape():
     """The fleet-journey families (ISSUE 16's contract with dashboards):
     hop latency histogram, journey shape counter, per-replica clock-skew

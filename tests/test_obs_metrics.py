@@ -196,14 +196,24 @@ def test_perf_timer_paths_unified():
 
 
 @pytest.mark.parametrize("depth", [1, 2], ids=["sync", "async"])
-@pytest.mark.parametrize("rows,computed", [(4, 64), (16, 128)],
-                         ids=["rows", "packed"])
+@pytest.mark.parametrize("rows,budget,chunks,computed,step_rows", [
+    (4, 128, 1, 64, "rows"),        # 4 x 16 slots are half the step's 128
+    (16, 128, 1, 64, "packed:128"),     # 17 tokens: the narrow width
+    (16, 128, 4, 128, "packed:128"),    # 65 tokens: the whole packed width
+    (8, 128, 1, 64, "packed:128"),  # 8 x 16 slots ARE the step's 128 tokens
+    (16, 256, 1, 128, "packed:256"),    # cell 4's [16, 16] at 256
+], ids=["rows", "packed_narrow", "packed_wide", "slots_equal_step_tokens",
+        "16_rows_of_16_at_256"])
 def test_step_tokens_counts_what_a_mixed_dispatch_carried_and_computed(
-        depth, rows, computed):
+        depth, rows, budget, chunks, computed, step_rows):
     """opsagent_step_tokens_total, at dispatch on both mixed paths:
-    kind=real the tokens carried (one decode lane and a chunk of 16),
-    kind=computed the rows the matmuls ran over: rows x bucket where
-    that fits the step's 128 tokens, the packed 128 where it does not."""
+    kind=real the tokens carried (one decode lane and chunks of 16),
+    kind=computed the rows the dense segments ran over: rows x bucket where
+    that is no more than half the step's tokens (``max_step_tokens``);
+    where the program packs, half the step's tokens in a tick that carries
+    no more and the packed width in any other.
+    opsagent_mixed_dispatch_width_total counts the dispatch under that
+    width, and ``impl.step_rows`` says whether the widest program packs."""
     import jax.numpy as jnp
 
     from opsagent_tpu.serving.engine import Engine, EngineConfig
@@ -213,19 +223,28 @@ def test_step_tokens_counts_what_a_mixed_dispatch_carried_and_computed(
         model="tiny-test", dtype=jnp.float32, tp=1, page_size=4,
         num_pages=64 * rows, max_pages_per_seq=24, max_batch_size=rows,
         prefill_buckets=(8, 16), decode_block=4, mixed_buckets=(16,),
-        max_step_tokens=32, async_depth=depth,
+        max_step_tokens=budget, async_depth=depth,
     ))
+    assert eng.impl_info()["step_rows"] == step_rows
     lane = eng.add_request([257, 9, 8, 7], SamplingParams(max_tokens=8))
-    admit = eng.begin_request(
-        [257] + list(range(1, 40)), SamplingParams(max_tokens=4))
+    admits = [
+        eng.begin_request(
+            [257] + list(range(1 + i, 40 + i)), SamplingParams(max_tokens=4))
+        for i in range(chunks)]
 
-    def read(kind):
-        return obs.metrics_snapshot().get(
-            f'opsagent_step_tokens_total{{kind="{kind}"}}', 0.0)
+    def read(name, **labels):
+        key = ",".join(f'{k}="{v}"' for k, v in labels.items())
+        return obs.metrics_snapshot().get(f"{name}{{{key}}}", 0.0)
 
-    before = read("real"), read("computed")
+    before = (read("opsagent_step_tokens_total", kind="real"),
+              read("opsagent_step_tokens_total", kind="computed"),
+              read("opsagent_mixed_dispatch_width_total", width=computed))
     step = eng.step_mixed if depth == 1 else eng.step_mixed_async
-    step([lane], {admit: 16})
+    step([lane], {a: 16 for a in admits})
     eng.drain()
-    assert read("real") - before[0] == 1 + 16
-    assert read("computed") - before[1] == computed
+    assert read("opsagent_step_tokens_total", kind="real") - before[0] == (
+        1 + 16 * chunks)
+    assert read(
+        "opsagent_step_tokens_total", kind="computed") - before[1] == computed
+    assert read(
+        "opsagent_mixed_dispatch_width_total", width=computed) - before[2] == 1

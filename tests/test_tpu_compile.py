@@ -487,21 +487,22 @@ def _copies_of(hlo: str, elements: int, axes=None) -> list[str]:
     return out
 
 
-def _step_shapes(sds, preset: str, kv: str, impl: str):
-    """A preset's widths cut to two layers, with parameters, the cache
-    ``llama.make_cache`` gives it for the attention backend ``impl``, a
-    key, and makers of row-shaped arguments, all as shapes on the chip."""
+def _step_shapes(sds, preset: str, kv: str, impl: str,
+                 layers: int = STEP_LAYERS, int8: bool = False):
+    """A preset's widths cut to ``layers`` layers, with parameters (bf16,
+    or the int8 leaves the cells serve), the cache ``llama.make_cache``
+    gives it for the attention backend ``impl``, a key, and makers of
+    row-shaped arguments, all as shapes on the chip."""
     from opsagent_tpu.models import llama
 
-    cfg = dataclasses.replace(
-        get_config_preset(preset), num_layers=STEP_LAYERS
-    )
+    cfg = dataclasses.replace(get_config_preset(preset), num_layers=layers)
     n, _ = GEOMETRY[preset]
     on_chip = lambda tree: jax.tree.map(  # noqa: E731
         lambda x: sds(x.shape, x.dtype), tree
     )
     params = on_chip(jax.eval_shape(
-        lambda: llama.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)
+        (lambda: llama.init_params_random_quantized(cfg, 0)) if int8 else
+        (lambda: llama.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
     ))
     cache = on_chip(jax.eval_shape(
         lambda: llama.make_cache(
@@ -515,7 +516,7 @@ def _step_shapes(sds, preset: str, kv: str, impl: str):
 
 def _whole_cache_copies(compiled, cfg, preset: str, impl: str, axes=None):
     whole = (
-        STEP_LAYERS * GEOMETRY[preset][0] * PAGE
+        cfg.num_layers * GEOMETRY[preset][0] * PAGE
         * cfg.num_kv_heads * cfg.head_dim_
     )
     hlo = compiled.as_text()
@@ -525,15 +526,16 @@ def _whole_cache_copies(compiled, cfg, preset: str, impl: str, axes=None):
 
 def _mixed_step(sds, preset: str, kv: str, impl: str = "xla", *,
                 rows: int = STEP_ROWS, tokens: int = STEP_TOKENS,
-                step_tokens: int = 0):
+                step_tokens: int = 0, layers: int = STEP_LAYERS,
+                int8: bool = False):
     """Compile the engine's ``_mixed_carry`` program (decode_loop.
-    mixed_step_carry, the cache donated) at a preset's widths cut to two
-    layers, with the cache ``llama.make_cache`` gives it for the
-    attention backend ``impl``; ``rows`` x ``tokens`` slots, packed to
-    ``step_tokens`` where that is fewer."""
+    mixed_step_carry, the cache donated) at a preset's widths cut to
+    ``layers`` layers, with the cache ``llama.make_cache`` gives it for
+    the attention backend ``impl``; ``rows`` x ``tokens`` slots, packed
+    to ``step_tokens`` where half of that is fewer."""
     from opsagent_tpu.serving import decode_loop
 
-    cfg, params, cache, key = _step_shapes(sds, preset, kv, impl)
+    cfg, params, cache, key = _step_shapes(sds, preset, kv, impl, layers, int8)
     maxp = GEOMETRY[preset][1]
     b = rows
     i32 = lambda *s: sds(s, jnp.int32)       # noqa: E731
@@ -626,7 +628,8 @@ def test_cell_1s_mixed_step_runs_its_matmuls_over_the_steps_tokens(v5e):
     memory and the cache is updated in place, so the FFN's
     ``[32, 32, 18944]`` arrays, 38.8 MB each, never were HBM temporaries;
     at the 72B's widths with int8 weights it reads 62.2 MB over
-    ``[16, 64]`` rows and 2.35 MB packed)."""
+    ``[16, 64]`` rows and 2.35 MB packed; since PR 34 the results of the
+    three conditionals a layer are HBM buffers, 9.1 MB here)."""
     cfg, _, copies, compiled = _mixed_step(
         _one_chip(v5e), "qwen2.5-7b-instruct", "", "pallas-stream",
         rows=32, tokens=32, step_tokens=256)
@@ -638,7 +641,71 @@ def test_cell_1s_mixed_step_runs_its_matmuls_over_the_steps_tokens(v5e):
     assert re.search(rf"bf16\[1024,{d}\]\S* gather\(", hlo)   # q, un-packed
     assert copies == []
     assert not re.search(rf"bf16\[1,{d},{d}\]\S* fusion\(", hlo)  # a weight whole
-    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+    scratch = compiled.memory_analysis().temp_size_in_bytes
+    assert scratch < 10 << 20, (
+        f"{scratch / 1e6:.1f} MB of scratch: 1.9 MB with one width, 9.1 MB "
+        "with Pack.dense's three conditionals a layer, whose results are "
+        "HBM buffers; more than that is an array per row slot, or a weight")
+
+
+def _results_outside_fusions(hlo: str):
+    """(computation, name, element type, dims, operation) of every
+    instruction that is not inside a fused computation: what an optimized
+    module writes to memory, as far as its text says."""
+    for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", hlo):
+        head, _, body = comp.partition("\n")
+        name = head.removeprefix("ENTRY ").split(" ")[0]
+        if "fused_computation" in name:
+            continue
+        for m in re.finditer(
+                r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+)\[([\d,]+)\]\S* ([\w\-]+)\(",
+                body, re.M):
+            dims = tuple(int(x) for x in m.group(3).split(","))
+            yield name, m.group(1), m.group(2), dims, m.group(4)
+
+
+@pytest.mark.parametrize("preset,rows,tokens,layers", [
+    ("qwen2.5-7b-instruct", 32, 32, 28),     # cell 1's widest mixed program
+    ("qwen2.5-72b-instruct", 16, 64, 8),     # cell 2's
+], ids=["cell_1", "cell_2"])
+def test_a_packed_mixed_step_holds_both_widths_in_one_program(
+        v5e, preset, rows, tokens, layers):
+    """The cells' widest mixed programs with the int8 leaves they serve,
+    at the cells' depth (a stack of two layers is small enough for the
+    compiler to prefetch whole, which reads as a copy): three conditionals
+    a layer (q/k/v; the output projection and its residual; norm, MLP and
+    residual), each with a 128-row and a 256-row branch
+    (``llama.Pack.dense``), in the ONE program of the bucket. What the
+    conditionals must not cost (compile, PR 34): no whole weight is an
+    operation's result outside a fusion, neither dequantized (both
+    branches dequantize the same leaf, which invites hoisting the convert
+    above the conditional: ROADMAP S2 (i) again) nor as an int8 slice of
+    its stack (a leaf sliced BEFORE the conditional is an operand of its
+    own, 68 MB written a matrix a layer at the 7B: ``llama._LayerView``
+    slices inside the branch) nor as a re-laid-out stack (with q split
+    into heads inside the branch, the 128-row branch wanted ``wq``
+    transposed: ``s8[28,3584,3584]`` copied at the entry and back in the
+    256-row branch, every layer, 830 MB of scratch: ``llama._heads`` runs
+    after the conditional); no K or V array is copied; the scratch HBM
+    stays in megabytes (8.3 and 15.9 MB here; 1.9 and 2.4 before)."""
+    cfg, _, copies, compiled = _mixed_step(
+        _one_chip(v5e), preset, "", "pallas-stream", rows=rows,
+        tokens=tokens, step_tokens=256, layers=layers, int8=True)
+    hlo = compiled.as_text()
+    d, f = cfg.hidden_size, cfg.intermediate_size
+    kv = cfg.num_kv_heads * cfg.head_dim_
+    for width in (128, 256):
+        assert re.search(rf"bf16\[{width},{f}\]\S* convolution\(", hlo)
+    assert len(re.findall(r" conditional\(", hlo)) == 3
+    weights = {(d, f), (f, d), (d, d), (d, kv)}
+    written = [
+        f"{comp}: {name} {kind}{list(dims)} {op}"
+        for comp, name, kind, dims, op in _results_outside_fusions(hlo)
+        if kind in ("bf16", "s8") and dims[-2:] in weights
+        and op not in ("parameter", "get-tuple-element", "bitcast")]
+    assert written == []
+    assert copies == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
 
 
 @pytest.mark.parametrize("preset,impl", [
