@@ -40,6 +40,7 @@ from ..ops.attention import (
     page_form,
     paged_decode_attention_auto,
     paged_ragged_attention_auto,
+    pallas_interpret,
     write_kv_pages,
     write_pages,
 )
@@ -47,6 +48,11 @@ from ..ops.linear_attention import (
     conv_with_tail,
     delta_rule_chunk,
     delta_rule_step,
+)
+from ..ops.linear_state_pallas import (
+    conv_slot_shape,
+    delta_rule_slots,
+    heads_packed,
 )
 from ..ops.rope import apply_rope, rope_table
 from ..utils.profiling import scoped
@@ -564,6 +570,7 @@ def make_cache(
     kv_quantize: str = "",
     form: str | None = None,
     state_slots: int = 0,
+    state_impl: str = "xla",
 ) -> Params:
     """Paged KV cache pytree: pages stacked over layers, held ``[L, N, P,
     K, D]`` or merged ``[L, N, P, K*D]`` (``form``; None asks
@@ -587,11 +594,12 @@ def make_cache(
     A model with linear-attention layers holds pages for its attention
     layers only (``L`` counts those) and, beside them under the same tree
     so that they are donated through every step alike, ``state_slots``
-    slots of recurrent state (``make_state``)."""
+    slots of recurrent state (``make_state``, held for ``state_impl``)."""
     # pages only for the layers that attend over them; the recurrent
     # state of the others goes beside them
     L = cfg.count_mixers("attn")
-    state = make_state(cfg, state_slots, dtype) if cfg.has_state else {}
+    state = make_state(
+        cfg, state_slots, dtype, state_impl) if cfg.has_state else {}
     if _latent_cache(cfg):
         if kv_quantize or state:
             raise ValueError(
@@ -620,35 +628,59 @@ def make_cache(
     return {**state, "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def state_slot_shape(la) -> tuple[int, ...]:
-    """One linear layer's state of one slot as the cache holds it: ``[heads,
+# The recurrent state is the sequence's memory, and an error in it never
+# decays away: float32 wherever it is held.
+STATE_DTYPE = jnp.float32
+
+
+def state_slot_shape(la, impl: str = "xla") -> tuple[int, ...]:
+    """One linear layer's state of one slot as the cache holds it, by who
+    updates it (``ops.attention.linear_state_backend``). Under XLA: ``[heads,
     key dim, value dim]`` where the value dim fills whole 128-lane tiles of
     the TPU, else the same numbers in the same order as rows of 128: a
     minor dim of 192 pads to 256 lanes (a third more bytes held and moved
-    by every step), rows of 128 pad nothing. A step reshapes the rows it
-    read to ``[heads, key dim, value dim]`` and back (``_linear_mixer``)."""
+    by every step), rows of 128 pad nothing, and a step reshapes the rows it
+    read to ``[heads, key dim, value dim]`` and back (``_linear_mixer``).
+    Under the state kernel, which reads a slot as it is held: ``[heads / p,
+    key dim, p * value dim]`` with ``p`` heads side by side, one where a
+    head's value dim fills whole lane tiles and two where two heads' do
+    (``[15, 96, 384]`` for 30 heads of 96 x 192): nothing padded and nothing
+    re-tiled."""
     H, dk, dv = la.num_heads, la.key_head_dim, la.value_head_dim
+    if impl == "pallas-state":
+        p = heads_packed(dv)
+        return (H // p, dk, p * dv)
     if dv % 128 == 0 or (H * dk * dv) % 128:
         return (H, dk, dv)
     return (H * dk * dv // 128, 128)
 
 
-def make_state(cfg: ModelConfig, slots: int, dtype=jnp.bfloat16) -> Params:
+def make_state(
+    cfg: ModelConfig, slots: int, dtype=jnp.bfloat16, impl: str = "xla"
+) -> Params:
     """The recurrent-state part of the cache: for every linear-attention
     layer and slot a float32 state (``state_slot_shape``) and the conv tail
     (the last ``conv_kernel - 1`` inputs of the convolved q/k/v stream, in
     the compute type). A slot belongs to a running sequence or holds a
     snapshot the prefix trie can restore; the slot a row uses rides in its
     table row beside its pages (``split_table``). A model with an expert
-    share also keeps its ``MOE_STATS`` accumulators here (``stats``)."""
+    share also keeps its ``MOE_STATS`` accumulators here (``stats``).
+
+    ``impl`` is who updates the slots. The state kernel copies a slot's
+    tail by its leading index, so under it a tail is held as whole tiles
+    of rows of 128 (``conv_slot_shape``); a step program reads which of the
+    two it was given from the rank of ``conv`` (``_linear_mixer``)."""
     la = cfg.linear_attn
     n = cfg.count_mixers("linear")
+    width = (la.conv_kernel - 1) * la.conv_size
     state = {
-        "state": jnp.zeros((n, slots, *state_slot_shape(la)), jnp.float32),
+        "state": jnp.zeros(
+            (n, slots, *state_slot_shape(la, impl)), STATE_DTYPE),
         # flat [..., (kernel - 1) * width]: a minor pair of (3, width) would
         # pad the 3 to a whole tile on the TPU, five times the bytes
         "conv": jnp.zeros(
-            (n, slots, (la.conv_kernel - 1) * la.conv_size), dtype),
+            (n, slots, *(conv_slot_shape(width) if impl == "pallas-state"
+                         else (width,))), dtype),
     }
     if _expert_share(cfg):
         state["stats"] = jnp.zeros((len(MOE_STATS),), jnp.uint32)
@@ -692,7 +724,8 @@ def copy_state_slots(cache: Params, src: jax.Array, dst: jax.Array) -> Params:
 
 
 def cache_specs(
-    cfg: ModelConfig, kv_quantize: str = "", form: str | None = None
+    cfg: ModelConfig, kv_quantize: str = "", form: str | None = None,
+    state_impl: str = "xla",
 ) -> Params:
     """KV pages are sharded over the kv-head axis (tp), like wk/wv: the
     K axis of split pages, the merged K*D axis of merged ones (a shard
@@ -713,10 +746,11 @@ def cache_specs(
     if kv_quantize:
         values = QuantizedPages(values, scales)
     if cfg.has_state:
-        rank = 2 + len(state_slot_shape(cfg.linear_attn))
+        rank = 2 + len(state_slot_shape(cfg.linear_attn, state_impl))
         stats = {"stats": P(None)} if _expert_share(cfg) else {}
         return {"k": values, "v": values, "state": P(*[None] * rank),
-                "conv": P(None, None, None), **stats}
+                "conv": P(*[None] * (
+                    4 if state_impl == "pallas-state" else 3)), **stats}
     return {"k": values, "v": values}
 
 
@@ -1191,14 +1225,25 @@ def _state_read(flat: jax.Array, idx: jax.Array, fresh: jax.Array):
 
 def _linear_mixer(h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None):
     """Gated delta-rule linear attention on the layer's input h [B, S, d]
-    (normed, in a pre-norm block): the chunk form for S > 1, the one-token
-    recurrence for S == 1, from and to the rows' state slots (``ctx`` None:
-    from zero, kept nowhere). The decay's width and the gates' kind are the
-    config's (``LinearAttnConfig``). Returns (mixer output before the
-    output projection [B, S, H * dv], cache)."""
+    (normed, in a pre-norm block), from and to the rows' state slots
+    (``ctx`` None: from zero, kept nowhere: ``forward_full``, the training
+    path). The decay's width and the gates' kind are the config's
+    (``LinearAttnConfig``). Returns (mixer output before the output
+    projection [B, S, H * dv], cache).
+
+    Who updates the slots is read from how the cache holds them
+    (``make_state``). Held for the state kernel, one call a layer takes
+    each row's state from its slot through its tokens to the live and the
+    snapshot slot in place, and writes the conv tail by row
+    (``ops.linear_state_pallas``). Held for XLA, or without slots: the
+    chunk form for S > 1 and the one-token recurrence for S == 1 in plain
+    ``jax.numpy``, the oracle of the kernel's tests, with a slot gathered a
+    row at a time and scattered twice."""
     la = cfg.linear_attn
     B, S, _ = h.shape
     H, dk, dv = la.num_heads, la.key_head_dim, la.value_head_dim
+    width = (la.conv_kernel - 1) * la.conv_size
+    kernel = ctx is not None and cache["conv"].ndim == 4
     if ctx is None:
         valid = jnp.full((B,), S, jnp.int32)
         S0 = jnp.zeros((B, H, dk, dv), jnp.float32)
@@ -1210,11 +1255,14 @@ def _linear_mixer(h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None):
             idx = si * n_slots + ctx.slots
             fresh = (ctx.start == 0) | (ctx.slots < 0)
             state_flat = cache["state"].reshape(-1, *cache["state"].shape[2:])
-            conv_flat = cache["conv"].reshape(-1, cache["conv"].shape[-1])
-            # as held (``state_slot_shape``) -> what the delta rule takes
-            S0 = _state_read(state_flat, idx, fresh).reshape(B, H, dk, dv)
-            tail = _state_read(conv_flat, idx, fresh).reshape(
-                B, la.conv_kernel - 1, la.conv_size)
+            conv_flat = cache["conv"].reshape(-1, *cache["conv"].shape[2:])
+            if not kernel:
+                # as held (``state_slot_shape``) -> what the delta rule takes
+                S0 = _state_read(state_flat, idx, fresh).reshape(B, H, dk, dv)
+            tail = _state_read(conv_flat, idx, fresh)
+            if kernel:      # held as rows of 128, padded to whole tiles
+                tail = tail.reshape(B, -1)[:, :width]
+            tail = tail.reshape(B, la.conv_kernel - 1, la.conv_size)
     with jax.named_scope("lin_proj"):
         x = jnp.concatenate(
             [_mm(h, lp["lq"]), _mm(h, lp["lk"]), _mm(h, lp["lv"])], axis=-1)
@@ -1236,33 +1284,47 @@ def _linear_mixer(h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None):
         beta = jax.nn.sigmoid(_mm(h, lp["wb"]).astype(jnp.float32))
         if la.neg_eigval:
             beta = beta * 2.0
-    with jax.named_scope("lin_scan"):
-        if S == 1:
-            live = (valid > 0)[:, None]
-            o, S1 = delta_rule_step(
-                q[:, 0], k[:, 0], v[:, 0],
-                jnp.where(live.reshape(B, *([1] * (g.ndim - 2))), g[:, 0], 0.0),
-                jnp.where(live, beta[:, 0], 0.0), S0)
-            o = o[:, None]
-        else:
-            o, S1 = delta_rule_chunk(q, k, v, g, beta, S0, valid)
     if ctx is not None:
-        with jax.named_scope("state_io"):
-            oob = state_flat.shape[0]
-            wrote = (valid > 0) & (ctx.slots >= 0)
-            on_page = (ctx.start + valid) % ctx.page_size == 0
-            S1 = S1.reshape(B, *state_flat.shape[1:])
-            for to in (
-                jnp.where(wrote, idx, oob),
-                jnp.where(wrote & on_page & (ctx.snap >= 0),
-                          si * n_slots + ctx.snap, oob),
-            ):
-                state_flat = state_flat.at[to].set(S1, mode="drop")
-                conv_flat = conv_flat.at[to].set(
-                    tail.reshape(B, -1).astype(conv_flat.dtype), mode="drop")
-            cache = dict(
-                cache, state=state_flat.reshape(cache["state"].shape),
-                conv=conv_flat.reshape(cache["conv"].shape))
+        # where a pass writes: the row's live slot, and its snapshot slot
+        # where the pass leaves the row on a page boundary
+        wrote = (valid > 0) & (ctx.slots >= 0)
+        snaps = wrote & ((ctx.start + valid) % ctx.page_size == 0) & (
+            ctx.snap >= 0)
+    if kernel:
+        with jax.named_scope("lin_scan"):
+            o, state_flat, conv_flat = delta_rule_slots(
+                q, k, v, g, beta, state_flat, conv_flat,
+                tail.reshape(B, -1), jnp.where(ctx.slots >= 0, idx, -1),
+                jnp.where(snaps, si * n_slots + ctx.snap, -1),
+                fresh, valid, interpret=pallas_interpret())
+    else:
+        with jax.named_scope("lin_scan"):
+            if S == 1:
+                live = (valid > 0)[:, None]
+                o, S1 = delta_rule_step(
+                    q[:, 0], k[:, 0], v[:, 0],
+                    jnp.where(live.reshape(B, *([1] * (g.ndim - 2))),
+                              g[:, 0], 0.0),
+                    jnp.where(live, beta[:, 0], 0.0), S0)
+                o = o[:, None]
+            else:
+                o, S1 = delta_rule_chunk(q, k, v, g, beta, S0, valid)
+        if ctx is not None:
+            with jax.named_scope("state_io"):
+                oob = state_flat.shape[0]
+                S1 = S1.reshape(B, *state_flat.shape[1:])
+                for to in (
+                    jnp.where(wrote, idx, oob),
+                    jnp.where(snaps, si * n_slots + ctx.snap, oob),
+                ):
+                    state_flat = state_flat.at[to].set(S1, mode="drop")
+                    conv_flat = conv_flat.at[to].set(
+                        tail.reshape(B, -1).astype(conv_flat.dtype),
+                        mode="drop")
+    if ctx is not None:
+        cache = dict(
+            cache, state=state_flat.reshape(cache["state"].shape),
+            conv=conv_flat.reshape(cache["conv"].shape))
     with jax.named_scope("lin_proj"):
         o = rms_norm(o, lp["o_norm"].astype(jnp.float32), cfg.rms_norm_eps)
         if la.gates == "low_rank":

@@ -183,6 +183,40 @@ def paged_attention_backend(
     return "xla" if refused else "pallas-stream"
 
 
+STATE_BACKENDS = ("xla", "pallas-state")
+
+
+def linear_state_backend(
+    *,
+    platform: str,
+    state_dtype: str,
+    key_dim: int,
+    value_dim: int,
+    heads: int,
+) -> str:
+    """Who updates a linear-attention layer's recurrent state in an
+    engine's step programs: "xla" (a slot gathered a row at a time, the
+    chunk form or the one-token recurrence in plain ``jax.numpy``, two
+    scatters; the oracle of every test) or "pallas-state" (one kernel a
+    layer from read through update to both writes,
+    ``linear_state_pallas``).
+
+    The code's own choice, as ``paged_attention_backend`` is, from what it
+    can observe where the engine is built; the cache is then held in the
+    form the answer reads (``llama.state_slot_shape``) and every step
+    program reads the answer back from that form. On a TPU it is the
+    kernel wherever a float32 state tile lies on whole (8, 128) tiles as
+    held: the key dim a multiple of 8, and the value dim of one head, or
+    of two heads side by side where the heads pair up, a multiple of 128
+    (Mosaic takes no other; tests/test_tpu_compile.py compiles both of the
+    benchmark's shapes). Everywhere else XLA."""
+    if platform != "tpu" or state_dtype != "float32" or key_dim % 8:
+        return "xla"
+    if value_dim % 128 and (heads % 2 or (2 * value_dim) % 128):
+        return "xla"
+    return "pallas-state"
+
+
 def pallas_interpret() -> bool:
     """Whether the Pallas kernels should run in interpret mode
     (OPSAGENT_PALLAS_INTERPRET=1): how the CPU tests run the attention

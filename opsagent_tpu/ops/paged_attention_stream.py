@@ -45,14 +45,13 @@ at the benchmark cells' shapes in tests/test_tpu_compile.py.
 from __future__ import annotations
 
 import functools
-import hashlib
-import os
-import threading
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .pallas_export import exported_call
 
 NEG_INF = -1e30
 
@@ -313,20 +312,11 @@ def _pallas_call(
 
 @functools.lru_cache(maxsize=None)
 def _kernel_call(*, inline: bool, **shape):
-    """The kernel of one shape as something to call inside a step program.
-
-    Tracing the kernel's body and lowering it to Mosaic is Python work
-    that every step program holding the kernel would repeat: 1.7-3 s of
-    tracing a distinct shape and 0.2 s of lowering a program on the chip's
-    host, 48 s of a 68 s warm-up at the 72B cell's 8 shapes and some 50
-    programs, with every executable already in the compile cache
-    (PERF.md section 6, PR 29). So each shape is traced and lowered ONCE,
-    exported (``jax.export``: the lowered module as bytes), and every
-    program after that inlines the bytes; beside JAX's compile cache,
-    where one is set, the bytes also outlive the process, keyed by this
-    file's own text, JAX's version and the shape. Interpreted (the CPU
-    tests) and inside a shard_map (``inline``) the kernel is called as it
-    is."""
+    """The kernel of one shape as something to call inside a step program:
+    traced and lowered once, exported, and inlined as bytes by every
+    program after that (``pallas_export``: what that saves, and where the
+    bytes live). Interpreted (the CPU tests) and inside a shard_map
+    (``inline``) the kernel is called as it is."""
     call = _pallas_call(**shape)
     if inline:
         return call
@@ -340,43 +330,10 @@ def _kernel_call(*, inline: bool, **shape):
         jax.ShapeDtypeStruct((B, K, nQ * TM, D), shape["q_dtype"]),
         i32((TM, 1)), pages, pages,
     )
-    path = _export_path(shape)
-    if path and os.path.exists(path):
-        try:
-            with open(path, "rb") as f:
-                return jax.export.deserialize(bytearray(f.read())).call
-        except Exception:  # noqa: BLE001 - a torn file is a cold start
-            pass
-
-    def scoped(*a):
-        # The scope name the traces' readers know the kernel's time by.
-        with jax.named_scope("attn_core"):
-            return call(*a)
-
-    exported = jax.export.export(jax.jit(scoped), platforms=("tpu",))(*args)
-    if path:
-        tmp = f"{path}.{os.getpid()}.{threading.get_ident()}.tmp"
-        try:
-            with open(tmp, "wb") as f:
-                f.write(exported.serialize())
-            os.replace(tmp, path)
-        except OSError:
-            pass
-    return exported.call
-
-
-def _export_path(shape: dict) -> str | None:
-    """Where a shape's exported kernel lives: in JAX's persistent compile
-    cache directory, if the process has one."""
-    cache_dir = jax.config.jax_compilation_cache_dir
-    if not cache_dir:
-        return None
-    with open(__file__, "rb") as f:
-        text = f.read()
-    key = hashlib.sha256(
-        text + repr((jax.__version__, sorted(shape.items()))).encode()
-    ).hexdigest()[:32]
-    return os.path.join(cache_dir, f"paged_attention_stream-{key}.export")
+    return exported_call(
+        call, args, name="paged_attention_stream", source=__file__,
+        shape=shape, scope="attn_core",
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_pages"))

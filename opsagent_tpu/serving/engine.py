@@ -421,7 +421,8 @@ class Engine:
         # never a quiet xla run under the kernel's name. impl_info()
         # reports what runs.
         from ..ops.attention import (
-            pallas_interpret, pallas_refusal, paged_attention_backend,
+            linear_state_backend, pallas_interpret, pallas_refusal,
+            paged_attention_backend,
         )
 
         ws = cfg.weight_stream or os.environ.get(
@@ -475,7 +476,18 @@ class Engine:
             else "",
         )
 
+        # Who updates a linear layer's recurrent state: the code's choice
+        # too, made here so that the cache is held in the form it reads.
+        self.state_impl = "xla"
         if self.model_cfg.has_state:
+            la = self.model_cfg.linear_attn
+            self.state_impl = linear_state_backend(
+                platform=platform,
+                state_dtype=jnp.dtype(llama.STATE_DTYPE).name,
+                key_dim=la.key_head_dim, value_dim=la.value_head_dim,
+                heads=la.num_heads,
+            )
+            log.info("linear-attention state: %s", self.state_impl)
             # What carries a sequence between steps, tiers or replicas as a
             # page chain alone would serve this model without its
             # recurrent state: refuse it here, by name, instead.
@@ -606,7 +618,7 @@ class Engine:
             return llama.make_cache(
                 self.model_cfg, cfg.num_pages, cfg.page_size,
                 dtype=cfg.dtype, kv_quantize=cfg.kv_quantize, form=form,
-                state_slots=live + snaps,
+                state_slots=live + snaps, state_impl=self.state_impl,
             )
 
         self.cache = jax.jit(
@@ -614,7 +626,7 @@ class Engine:
             out_shardings=spec_tree_shardings(
                 llama.cache_specs(
                     self.model_cfg, kv_quantize=cfg.kv_quantize,
-                    form=self.page_form,
+                    form=self.page_form, state_impl=self.state_impl,
                 ),
                 self.mesh,
             ),
@@ -1046,6 +1058,7 @@ class Engine:
             info["weight_stream_leaves"] = dict(self.weight_stream_leaves)
         if self.model_cfg.has_state:
             state = self.cache["state"]
+            info["state_impl"] = self.state_impl
             info["state_dtype"] = state.dtype.name
             info["state_slots"] = self.alloc.state_slots
             info["state_snapshots"] = self.alloc.state_snapshots

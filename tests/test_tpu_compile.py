@@ -33,9 +33,11 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from opsagent_tpu.models import llama
 from opsagent_tpu.models.config import get_config_preset
 from opsagent_tpu.models.quant import QuantizedLinear, QuantizedLinear4
 from opsagent_tpu.ops import attention
+from opsagent_tpu.ops import linear_state_pallas as lsp
 from opsagent_tpu.ops import quant_matmul_pallas as qmp
 from opsagent_tpu.ops.attention import QuantizedPages, pallas_refusal
 
@@ -87,6 +89,15 @@ STREAM_CELLS = {
         b=16, h=64, k=8, maxp=104, n=2048, layers=8, s=(1, 16, 32, 64, 256)),
     "solar-open2-ep8-l8.doc-turns": dict(
         b=32, h=64, k=8, maxp=512, n=12288, layers=2, s=(1, 16, 256)),
+    "olmo-hybrid-7b.log-turns": dict(
+        b=16, h=30, k=30, maxp=336, n=2048, layers=8, s=(1, 16, 256)),
+}
+# The cells whose models keep a recurrent state: (linear heads, key dim,
+# value dim, a decay a channel, linear layers, state slots a layer: a live
+# one a row and the snapshots, conv tail width).
+STATE_CELLS = {
+    "solar-open2-ep8-l8.doc-turns": (64, 128, 128, True, 6, 32 + 96, 73728),
+    "olmo-hybrid-7b.log-turns": (30, 96, 192, False, 24, 16 + 32, 34560),
 }
 
 
@@ -267,7 +278,9 @@ def test_the_choice_for_each_cells_configuration(cell):
     engine = config["engine"]
     assert engine["dtype"] == "bfloat16" and "kv_quantize" not in engine
     shapes = dict(
-        head_dim=config["head_dim"],
+        head_dim=config.get(
+            "head_dim",
+            config["hidden_size"] // config["num_attention_heads"]),
         kv_heads_per_shard=config["num_key_value_heads"] // engine["tp"],
         page_itemsize=2,
     )
@@ -280,6 +293,18 @@ def test_the_choice_for_each_cells_configuration(cell):
     assert attention.paged_attention_backend(
         platform="tpu", **shapes) == "pallas-stream"
     assert attention.paged_attention_backend(platform="cpu", **shapes) == "xla"
+    # who updates the recurrent state, where the cell's model has one
+    if cell in STATE_CELLS:
+        la = get_config_preset(config["preset"]).linear_attn
+        state = dict(
+            state_dtype=jnp.dtype(llama.STATE_DTYPE).name,
+            key_dim=la.key_head_dim, value_dim=la.value_head_dim,
+            heads=la.num_heads)
+        assert (la.num_heads, la.key_head_dim, la.value_head_dim,
+                la.decay == "channel") == STATE_CELLS[cell][:4]
+        assert attention.linear_state_backend(
+            platform="tpu", **state) == "pallas-state"
+        assert attention.linear_state_backend(platform="cpu", **state) == "xla"
 
 
 # -- quantized matmul: weight dtype x projection x rows ----------------------
@@ -848,6 +873,174 @@ def test_stream_kernel_is_exported_once_a_shape(v5e, tmp_path, monkeypatch):
         files = [f for f in os.listdir(tmp_path) if f.endswith(".export")]
         assert len(files) == 1
         compiled()                      # another program, the same shape
+        assert len(traced) == 1
+        new_process()
+        assert "tpu_custom_call" in compiled() and len(traced) == 1
+        os.remove(tmp_path / files[0])
+        new_process()
+        compiled()
+        assert len(traced) == 2
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        new_process()
+
+
+# -- the state kernel at the state cells' own shapes ---------------------------
+def _state_kernel(sds, cell: str, b: int, s: int):
+    """Compile ``delta_rule_slots`` over one cell's whole state and conv
+    arrays as ``llama.make_state`` holds them for the kernel, both donated."""
+    h, dk, dv, by_channel, layers, slots, width = STATE_CELLS[cell]
+    p = lsp.heads_packed(dv)
+    f32 = lambda *d: sds(d, jnp.float32)     # noqa: E731
+    i32 = lambda *d: sds(d, jnp.int32)       # noqa: E731
+    n = layers * slots
+    return jax.jit(lsp.delta_rule_slots, donate_argnums=(5, 6)).lower(
+        f32(b, s, h, dk), f32(b, s, h, dk), f32(b, s, h, dv),
+        f32(b, s, h, dk) if by_channel else f32(b, s, h), f32(b, s, h),
+        f32(n, h // p, dk, p * dv),
+        sds((n, *lsp.conv_slot_shape(width)), jnp.bfloat16),
+        sds((b, width), jnp.bfloat16), i32(b), i32(b), sds((b,), jnp.bool_),
+        i32(b),
+    ).compile()
+
+
+@pytest.mark.parametrize(
+    "cell,s", [(cell, s) for cell in STATE_CELLS for s in (1, 16, 256)])
+def test_state_kernel_compiles_at_the_cells_shapes(v5e, cell, s):
+    """The fused block's ``[B, 1]``, the mixed bucket ``[B, 16]`` and the
+    prefill bucket (``EngineConfig.prefill_batch`` rows of 256) of both
+    state cells: a decay a channel at 128 x 128 and a decay a head at 96 x
+    192 with two heads side by side. The state and the conv tails go
+    through the call in place: no operation but the call gives an array of
+    their shapes, and the program's scratch HBM is the re-layout of q, k,
+    v and the decay."""
+    h, dk, dv, _, layers, slots, width = STATE_CELLS[cell]
+    b = STREAM_CELLS[cell]["b"] if s <= 16 else 4
+    compiled = _state_kernel(_one_chip(v5e), cell, b, s)
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    whole = {layers * slots * h * dk * dv,
+             layers * slots * int(np.prod(lsp.conv_slot_shape(width)))}
+    made = [
+        f"{name} {kind}{list(dims)} {op}"
+        for _, name, kind, dims, op in _results_outside_fusions(hlo)
+        if int(np.prod(dims)) in whole
+        and op not in ("parameter", "get-tuple-element", "bitcast")]
+    assert made == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 192 << 20
+
+
+def _state_cell_mixed_step(sds, cell: str, state_impl: str, layers: int = 4):
+    """A state cell's mixed program (all rows of its one bucket of 16,
+    packed to 256 tokens, int8 leaves, the streaming kernel) at one period
+    of its layers, the slots held for ``state_impl``."""
+    from opsagent_tpu.serving import decode_loop
+
+    if cell.startswith("solar"):        # as benchmarks/configs cuts it
+        full = get_config_preset("solar-open2-250b")
+        cfg = dataclasses.replace(
+            full, num_layers=layers, vocab_size=24576,
+            moe=dataclasses.replace(full.moe, num_experts=40))
+    else:
+        cfg = dataclasses.replace(
+            get_config_preset("olmo-hybrid-7b"), num_layers=layers)
+    c = STREAM_CELLS[cell]
+    b, s = c["b"], 16
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: sds(x.shape, x.dtype), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: llama.init_params_random_quantized(cfg, 0)))
+    cache = on_chip(jax.eval_shape(lambda: llama.make_cache(
+        cfg, c["n"], PAGE, jnp.bfloat16, state_slots=STATE_CELLS[cell][5],
+        form=llama.cache_form(cfg, 1, "pallas-stream"),
+        state_impl=state_impl)))
+    key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    i32 = lambda *d: sds(d, jnp.int32)       # noqa: E731
+    f32 = lambda *d: sds(d, jnp.float32)     # noqa: E731
+    flag = lambda *d: sds(d, jnp.bool_)      # noqa: E731
+
+    def step(params, tokens, use_carry, carry, starts, qlens, emits, cache,
+             table, key, temps, top_k, top_p):
+        return decode_loop.mixed_step_carry(
+            params, cfg, tokens, use_carry, carry, starts, qlens, emits,
+            cache, table, key, temps, top_k, top_p,
+            attn_impl="pallas-stream", step_tokens=256)
+
+    compiled = jax.jit(step, donate_argnames=("cache",)).lower(
+        params, i32(b, s), flag(b), i32(b), i32(b), i32(b), flag(b), cache,
+        i32(b, c["maxp"] + llama.STATE_COLUMNS), key, f32(b), i32(b), f32(b),
+    ).compile()
+    return cache, compiled
+
+
+def _state_sized(hlo: str, cache, rows: int):
+    """What an optimized module says of state-sized arrays: the operations
+    outside fusions whose result has the shape of the whole ``state`` or
+    ``conv`` array (stacked by layer, or flat over layers x slots), and
+    whether any array anywhere, a fusion's inside included, has the shape
+    of every row's state at once."""
+    whole = set()
+    for leaf in (cache["state"], cache["conv"]):
+        whole |= {leaf.shape, (leaf.shape[0] * leaf.shape[1], *leaf.shape[2:])}
+    passes = sorted({
+        op for _, _, _, dims, op in _results_outside_fusions(hlo)
+        if dims in whole} - {"parameter", "get-tuple-element", "bitcast"})
+    per_row = ",".join(str(d) for d in (rows, *cache["state"].shape[2:]))
+    return passes, f"f32[{per_row}]" in hlo
+
+
+def test_cell_3s_mixed_step_moves_state_only_inside_the_kernel(v5e):
+    """Cell 3's mixed program with its slots held for the state kernel:
+    two custom calls a period body (attention, state), no array shaped like
+    all 32 rows' state (``f32[32,64,128,128]``: under XLA the gathered S0,
+    the chunk form's products and S1, four to five passes a layer) and no
+    operation shaped like the whole ``state`` or ``conv`` array: the call
+    updates both in place (``input_output_aliases``), so neither the state's
+    two scatters nor the tail's two whole-array ``dynamic-update-slice``
+    passes are left. Held for XLA, the same program shows all of them: the
+    test cannot pass for want of something to find."""
+    sds = _one_chip(v5e)
+    cell = "solar-open2-ep8-l8.doc-turns"
+    cache, compiled = _state_cell_mixed_step(sds, cell, "pallas-state")
+    assert cache["state"].shape == (3, 128, 64, 128, 128)
+    assert cache["conv"].shape == (3, 128, 576, 128)
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 2
+    passes, per_row = _state_sized(hlo, cache, 32)
+    assert passes == [] and not per_row
+    # the parent's program, the slots held for XLA
+    cache, compiled = _state_cell_mixed_step(sds, cell, "xla")
+    assert cache["conv"].shape == (3, 128, 73728)
+    passes, per_row = _state_sized(compiled.as_text(), cache, 32)
+    assert per_row and "dynamic-update-slice" in passes, passes
+
+
+def test_state_kernel_is_exported_once_a_shape(v5e, tmp_path, monkeypatch):
+    """As the streaming kernel: a second program holding the state kernel
+    at the same shape inlines the exported bytes, and a new process reads
+    them back from beside the compile cache."""
+    before = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    traced = []
+    kernel = lsp._kernel
+    monkeypatch.setattr(
+        lsp, "_kernel", lambda *a, **kw: traced.append(1) or kernel(*a, **kw))
+    cell = "olmo-hybrid-7b.log-turns"
+
+    def new_process():
+        lsp._kernel_call.cache_clear()
+        jax.clear_caches()
+
+    def compiled():
+        return _state_kernel(_one_chip(v5e), cell, 16, 1).as_text()
+
+    try:
+        new_process()
+        assert "tpu_custom_call" in compiled() and len(traced) == 1
+        files = [f for f in os.listdir(tmp_path) if f.endswith(".export")]
+        assert len(files) == 1 and files[0].startswith("linear_state-")
+        jax.clear_caches()              # another program, the same shape
+        compiled()
         assert len(traced) == 1
         new_process()
         assert "tpu_custom_call" in compiled() and len(traced) == 1
