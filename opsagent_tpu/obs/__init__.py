@@ -103,17 +103,6 @@ DECODE_DISPATCHES = _reg.counter(
     "Device decode dispatches by kind (block, single, speculative, mixed)",
     labelnames=("kind",),
 )
-ATTN_PAGES_STREAMED = _reg.counter(
-    "opsagent_attn_pages_streamed_total",
-    "KV pages the rows of attention dispatches own below their last query "
-    "(sum over rows of ceil((start + q_len) / page_size)), counted at "
-    "plan time: what a streaming reader reads a layer",
-)
-ATTN_PAGES_CAPACITY = _reg.counter(
-    "opsagent_attn_pages_capacity_total",
-    "Page-table capacity of the same dispatches (rows x max_pages_per_seq "
-    "a pass): what a gathering reader reads a layer",
-)
 MIXED_DECODE_LANES = _reg.histogram(
     "opsagent_mixed_dispatch_decode_lanes",
     "Decode lanes advanced per mixed prefill+decode dispatch",
@@ -123,11 +112,6 @@ MIXED_PREFILL_TOKENS = _reg.histogram(
     "opsagent_mixed_dispatch_prefill_tokens",
     "Prefill chunk tokens piggybacked per mixed dispatch's weight stream",
     buckets=(0, 8, 16, 32, 64, 128, 256, 512),
-)
-MIXED_BUDGET_UTILIZATION = _reg.histogram(
-    "opsagent_mixed_step_budget_utilization",
-    "Fraction of max_step_tokens used per mixed dispatch (0..1)",
-    buckets=(0.1, 0.25, 0.5, 0.75, 0.9, 1.0),
 )
 STEP_TOKENS = _reg.counter(
     "opsagent_step_tokens_total",
@@ -167,6 +151,43 @@ TICK_PHASE_SECONDS = _reg.counter(
     "deltas sum to the interval",
     labelnames=("phase",),
 )
+TICK_PART_SECONDS = _reg.counter(
+    "opsagent_tick_part_seconds_total",
+    "Seconds of a tick phase by the named part of it the thread was in "
+    "(obs.phase(name, part=...), obs.add_part): the parts of a phase never "
+    "overlap, and what the phase's own counter holds beyond their sum was "
+    "spent under no part (its `other`)",
+    labelnames=("phase", "part"),
+)
+TICK_HOST_WORK_SECONDS = _reg.histogram(
+    "opsagent_tick_host_work_seconds",
+    "Seconds the scheduler thread spent in the work phases (admit, plan, "
+    "dispatch, commit, reap) over ONE tick, observed where "
+    "opsagent_ticks_total is counted: the tail is the ticks that leave the "
+    "device idle",
+    buckets=(*(0.005 * i for i in range(1, 21)), 0.15, 0.25, 0.5, 1.0),
+)
+ADMISSION_SECONDS = _reg.histogram(
+    "opsagent_admission_seconds",
+    "Scheduler-thread seconds of one begin_request attempt, by outcome "
+    "(admitted / out_of_pages / rejected): an admission that is made again "
+    "on a later tick shows as attempts, not as one long wait",
+    labelnames=("outcome",),
+    buckets=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.02, 0.03, 0.05, 0.075,
+             0.1, 0.25, 0.5, 1.0),
+)
+REQUEST_DECODE_TICKS = _reg.counter(
+    "opsagent_request_decode_ticks_total",
+    "Scheduler ticks between a finished request's first and last token, "
+    "added when it is reaped; over opsagent_request_decode_tokens_total it "
+    "is the ticks a token takes (under 1 where a dispatch carries more "
+    "than one token of a row: fast-forward appends, fused decode blocks)",
+)
+REQUEST_DECODE_TOKENS = _reg.counter(
+    "opsagent_request_decode_tokens_total",
+    "Tokens of finished requests less one each (the intervals the ticks "
+    "above span)",
+)
 TICKS = _reg.counter(
     "opsagent_ticks_total",
     "Scheduler loop iterations that had work (a running or admitting "
@@ -175,11 +196,13 @@ TICKS = _reg.counter(
 STEP_DEVICE_SECONDS = _reg.histogram(
     "opsagent_step_device_seconds",
     "Device time of one dispatched step, by program (mixed / "
-    "decode_block / spec / ffwd / prefill_chunk) and bucket (chunk "
-    "bucket or block length), from the step clock: ready_k - "
-    "max(ready_k-1, enqueued_k), sampled only when the pull waited for "
+    "decode_block / spec / ffwd / prefill_chunk), bucket (chunk "
+    "bucket or block length) and width (the rows a mixed program's dense "
+    "segments ran over in that tick, as opsagent_mixed_dispatch_width_total "
+    "counts them; empty for other programs), from the step clock: ready_k "
+    "- max(ready_k-1, enqueued_k), sampled only when the pull waited for "
     "the step (no profiler, no extra sync)",
-    labelnames=("program", "bucket"),
+    labelnames=("program", "bucket", "width"),
     buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
              1.0, 2.5, 5.0, 10.0),
 )
@@ -737,7 +760,9 @@ from . import slo  # noqa: E402,F401
 from . import attribution  # noqa: E402,F401
 from . import timeline  # noqa: E402,F401
 from . import history  # noqa: E402,F401
-from .tick import StepClock, phase  # noqa: E402,F401
+from .tick import (  # noqa: E402,F401
+    StepClock, add_part, phase, take_host_work,
+)
 
 flight.install_compile_watchdog()
 _reg.add_collector(lambda: slo.get_watchdog().collect())

@@ -84,13 +84,17 @@ class _Metric:
         self._children: dict[tuple[str, ...], Any] = {}
 
     def _key(self, labels: dict[str, str] | None) -> tuple[str, ...]:
+        # On every tick's path, several times: no sets, one pass.
+        names = self.labelnames
         labels = labels or {}
-        if set(labels) != set(self.labelnames):
-            raise ValueError(
-                f"{self.name}: expected labels {self.labelnames}, "
-                f"got {tuple(labels)}"
-            )
-        return tuple(str(labels[n]) for n in self.labelnames)
+        if len(labels) == len(names):
+            try:
+                return tuple([str(labels[n]) for n in names])
+            except KeyError:
+                pass
+        raise ValueError(
+            f"{self.name}: expected labels {names}, got {tuple(labels)}"
+        )
 
     def collect(self) -> list[str]:
         raise NotImplementedError
@@ -181,15 +185,27 @@ class Histogram(_Metric):
             child[1] += 1
             child[2] += float(value)
 
+    def _matching(self, labels: dict[str, str]) -> list:
+        """The children whose labels include ``labels``: all the label
+        names give one child, fewer sum over the rest. Under the lock."""
+        if not set(labels) <= set(self.labelnames):
+            raise ValueError(
+                f"{self.name}: expected labels among {self.labelnames}, "
+                f"got {tuple(labels)}"
+            )
+        want = [(self.labelnames.index(n), str(v)) for n, v in labels.items()]
+        return [
+            child for key, child in self._children.items()
+            if all(key[i] == v for i, v in want)
+        ]
+
     def count(self, **labels: str) -> int:
         with self._lock:
-            child = self._children.get(self._key(labels))
-            return 0 if child is None else child[1]
+            return sum(child[1] for child in self._matching(labels))
 
     def sum(self, **labels: str) -> float:
         with self._lock:
-            child = self._children.get(self._key(labels))
-            return 0.0 if child is None else child[2]
+            return sum((child[2] for child in self._matching(labels)), 0.0)
 
     def collect(self) -> list[str]:
         with self._lock:

@@ -71,10 +71,10 @@ class _Tick:
     # dispatch appended BEFORE its sampled token (committed in order
     # ahead of the pull; see _dispatch's ffwd planning).
     ffwd: dict = field(default_factory=dict)
-    # The step clock's ticket (engine-wide step number, enqueue time): the
-    # number is the tick id that the flight event, the phase spans and the
-    # per-request span children carry.
-    ticket: tuple[int, float] = (0, 0.0)
+    # The step clock's ticket (engine-wide step number, enqueue time, the
+    # width its dense segments ran): the number is the tick id that the
+    # flight event, the phase spans and the per-request span children carry.
+    ticket: tuple[int, float, str] = (0, 0.0, "")
     bucket: int = 0
 
 
@@ -155,10 +155,35 @@ class AsyncMixedRuntime:
     def _dispatch(
         self, decode_ids: list[int], prefill_chunks: dict[int, int]
     ) -> bool:
+        """One tick's plan and its enqueue. The parts of ``plan`` here
+        (docs/observability.md "Tick phases"): ``rows`` (which decode rows
+        ride, their one-token bookings), ``chunks``, ``ffwd``, ``lanes``,
+        ``arrays`` (with the allocator's share summed into ``pages``),
+        ``account`` (what only observes) and, after the call, ``book``."""
         eng = self.eng
-        cfg = eng.cfg
-        B = cfg.max_batch_size
-        MaxP = eng.alloc.table_width
+        with obs.phase("plan", part="rows"):
+            decode = self._rows(decode_ids)
+        with obs.phase("plan", part="chunks"):
+            chunk_info, smax, room = self._chunks(prefill_chunks, len(decode))
+        with obs.phase("plan", part="ffwd"):
+            ffwd_plan, room = self._ffwd_plans(decode, room)
+        for _sid, (_a, _pre, _st) in ffwd_plan.items():
+            smax = max(smax, 1 + len(_pre))
+        if not decode and not chunk_info:
+            return False
+        S = eng._mixed_bucket(smax)
+        with obs.phase("plan", part="lanes"):
+            lane_of, continuing = self._assign_lanes(decode, chunk_info)
+        with eng._building_arrays():
+            host, fsm_obj, dec_rows, chk_rows = self._arrays(
+                S, decode, chunk_info, ffwd_plan, lane_of, continuing)
+        return self._enqueue(
+            S, host, fsm_obj, dec_rows, chk_rows, ffwd_plan)
+
+    def _rows(self, decode_ids: list[int]) -> list:
+        """The decode rows of this dispatch, each with the token it is
+        about to write booked."""
+        eng = self.eng
         decode = [
             eng.sequences[s] for s in decode_ids
             if s in eng.sequences and not eng.sequences[s].done
@@ -223,14 +248,21 @@ class AsyncMixedRuntime:
                 log.warning(
                     "seq %d truncated: KV page budget exhausted", s.seq_id
                 )
-        decode = [s for s in grown if not s.done]
+        return [s for s in grown if not s.done]
+
+    def _chunks(
+        self, prefill_chunks: dict[int, int], n_decode: int
+    ) -> tuple[list, int, int]:
+        """(chunk rows, the longest row, the room the step has left)."""
+        eng = self.eng
+        cfg = eng.cfg
         # What one dispatch carries is held to the step's width
         # (Engine.step_tokens: a packed program has no room beyond it):
         # the decode lanes first, the lookahead lanes the scheduler did
         # not count among them, then the chunks, then forced runs from
         # what is left. A chunk or a run cut short costs its row a later
         # step and changes no token.
-        room = eng.step_tokens - len(decode)
+        room = eng.step_tokens - n_decode
         chunk_info: list[tuple[int, Any, int, int]] = []
         smax = 1
         for sid, want in prefill_chunks.items():
@@ -245,11 +277,18 @@ class AsyncMixedRuntime:
             chunk_info.append((sid, seq, done, c))
             smax = max(smax, c)
             room -= c
-        if len(decode) + len(chunk_info) > B:
+        B = cfg.max_batch_size
+        if n_decode + len(chunk_info) > B:
             raise ValueError(
-                f"async mixed batch of {len(decode)} decode + "
+                f"async mixed batch of {n_decode} decode + "
                 f"{len(chunk_info)} prefill rows exceeds max_batch_size={B}"
             )
+        return chunk_info, smax, room
+
+    def _ffwd_plans(self, decode: list, room: int) -> tuple[dict, int]:
+        """The forced runs this dispatch appends, and the room left."""
+        eng = self.eng
+        cfg = eng.cfg
         # Grammar fast-forward plans: a constrained decode row whose
         # current FSM state forces a run of singleton-mask tokens appends
         # the whole run in THIS dispatch (the q_len>1 path chunk rows
@@ -321,12 +360,12 @@ class AsyncMixedRuntime:
                     continue
                 ffwd_plan[sid] = (int(anchor), pre, st + 1)
                 room -= len(pre)
-        for _sid, (_a, _pre, _st) in ffwd_plan.items():
-            smax = max(smax, 1 + len(_pre))
-        if not decode and not chunk_info:
-            return False
-        S = eng._mixed_bucket(smax)
+        return ffwd_plan, room
 
+    def _assign_lanes(
+        self, decode: list, chunk_info: list
+    ) -> tuple[dict[int, int], set[int]]:
+        B = self.eng.cfg.max_batch_size
         # Lane assignment: continuing decode rows keep their lane (the
         # carry is indexed by lane); everyone else takes a free one.
         taken: set[int] = set()
@@ -353,7 +392,18 @@ class AsyncMixedRuntime:
         for sid, *_ in chunk_info:
             if sid not in lane_of:
                 lane_of[sid] = free.pop(0)
+        return lane_of, continuing
 
+    def _arrays(
+        self, S: int, decode: list, chunk_info: list, ffwd_plan: dict,
+        lane_of: dict[int, int], continuing: set[int],
+    ) -> tuple[dict[str, np.ndarray], Any, list, list]:
+        """The step program's host arguments by name, the FSM whose tables
+        it takes, and the rows seated. The allocator's share (a
+        ``_pass_row`` a row) is summed into ``pages`` by the engine."""
+        eng = self.eng
+        B = eng.cfg.max_batch_size
+        MaxP = eng.alloc.table_width
         tokens = np.full((B, S), eng.tokenizer.pad_id, np.int32)
         use_carry = np.zeros((B,), bool)
         starts = np.zeros((B,), np.int32)
@@ -434,49 +484,65 @@ class AsyncMixedRuntime:
                 if fsm is not None:
                     _seat_fsm(fsm)
                     ov_fsm[lane] = _walk(fsm, seq.tokens)
+        host = dict(
+            tokens=tokens, use_carry=use_carry, starts=starts, qlens=qlens,
+            emits=emits, tables=tables, temps=temps, top_k=top_k,
+            top_p=top_p, ov_fsm=ov_fsm,
+        )
+        return host, fsm_obj, dec_rows, chk_rows
 
-        perf = get_perf_stats()
-        eng._record_attn_pages(starts, qlens)
-        eng._count_step_tokens(S, int(qlens.sum()))
-        ticket = eng.step_clock.enqueue()
-        tick_id, t_disp = ticket
-        if eng._mixed_gap_stamp is not None:
-            obs.STEP_HOST_GAP_SECONDS.observe(
-                t_disp - eng._mixed_gap_stamp, mode="async"
-            )
-        try:
-            with obs.phase("dispatch", tick=tick_id), \
-                    annotate("engine.mixed_step_async"), eng.mesh_ctx():
-                eng._sample_key, sub = jax.random.split(eng._sample_key)
-                carry = eng._async_carry
-                if carry is None:
-                    carry = jnp.zeros((B,), jnp.int32)
-                fsmc = eng._async_fsm_carry
-                if fsmc is None:
-                    fsmc = jnp.zeros((B,), jnp.int32)
-                if fsm_obj is not None:
-                    fm, fd = eng._fsm_device_tables(fsm_obj)
-                else:
-                    fm = fd = None
-                toks_d, eng.cache, fsm_d = eng._mixed_carry_jit(
-                    eng.params,
-                    jnp.asarray(tokens),
-                    jnp.asarray(use_carry),
-                    carry,
-                    jnp.asarray(starts),
-                    jnp.asarray(qlens),
-                    jnp.asarray(emits),
-                    eng.cache,
-                    jnp.asarray(tables),
-                    sub,
-                    jnp.asarray(temps),
-                    jnp.asarray(top_k),
-                    jnp.asarray(top_p),
-                    fsm_mask=fm,
-                    fsm_dest=fd,
-                    carry_fsm=fsmc,
-                    ov_fsm=jnp.asarray(ov_fsm),
+    def _enqueue(
+        self, S: int, host: dict[str, np.ndarray], fsm_obj, dec_rows: list,
+        chk_rows: list, ffwd_plan: dict,
+    ) -> bool:
+        """Place the arrays, call the step program, account and book."""
+        eng = self.eng
+        B = eng.cfg.max_batch_size
+        starts, qlens = host["starts"], host["qlens"]
+        with obs.phase("plan", part="account"):
+            width = eng._count_step_tokens(S, int(qlens.sum()))
+            ticket = eng.step_clock.enqueue(width)
+            tick_id, t_disp, _ = ticket
+            if eng._mixed_gap_stamp is not None:
+                obs.STEP_HOST_GAP_SECONDS.observe(
+                    t_disp - eng._mixed_gap_stamp, mode="async"
                 )
+        try:
+            with obs.phase("dispatch", tick=tick_id), eng.mesh_ctx():
+                with obs.phase("dispatch", part="place"):
+                    eng._sample_key, sub = jax.random.split(eng._sample_key)
+                    carry = eng._async_carry
+                    if carry is None:
+                        carry = jnp.zeros((B,), jnp.int32)
+                    fsmc = eng._async_fsm_carry
+                    if fsmc is None:
+                        fsmc = jnp.zeros((B,), jnp.int32)
+                    if fsm_obj is not None:
+                        fm, fd = eng._fsm_device_tables(fsm_obj)
+                    else:
+                        fm = fd = None
+                    dev = {k: jnp.asarray(a) for k, a in host.items()}
+                with obs.phase("dispatch", part="call"), \
+                        annotate("engine.mixed_step_async"):
+                    toks_d, eng.cache, fsm_d = eng._mixed_carry_jit(
+                        eng.params,
+                        dev["tokens"],
+                        dev["use_carry"],
+                        carry,
+                        dev["starts"],
+                        dev["qlens"],
+                        dev["emits"],
+                        eng.cache,
+                        dev["tables"],
+                        sub,
+                        dev["temps"],
+                        dev["top_k"],
+                        dev["top_p"],
+                        fsm_mask=fm,
+                        fsm_dest=fd,
+                        carry_fsm=fsmc,
+                        ov_fsm=dev["ov_fsm"],
+                    )
             eng._async_carry = toks_d
             eng._async_fsm_carry = fsm_d
         except Exception:
@@ -501,13 +567,56 @@ class AsyncMixedRuntime:
             self._prev_emitted = set()
             raise
         eng._mixed_gap_stamp = time.perf_counter()
-        perf.record_metric(
-            "engine.mixed_dispatch", (eng._mixed_gap_stamp - t_disp) * 1e3,
-            "ms",
-        )
+        with obs.phase("plan", part="account"):
+            self._account_dispatch(
+                S, starts, qlens, dec_rows, chk_rows, ffwd_plan, tick_id)
+        with obs.phase("plan", part="book"):
+            # Book-keeping AFTER the dispatch succeeded: planned prefill
+            # progress advances (the write is enqueued — deterministic),
+            # the finishing set gains this tick's completing prompts, and
+            # every emitting row carries one more uncommitted token.
+            for sid, _lane, done, c, finishing in chk_rows:
+                eng._prefilling[sid] = done + c
+                if finishing:
+                    self._finishing.add(sid)
+                    self._inflight_toks[sid] = (
+                        self._inflight_toks.get(sid, 0) + 1
+                    )
+            self._prev_lane = {}
+            self._prev_emitted = set()
+            for s, lane in dec_rows:
+                self._prev_lane[s.seq_id] = lane
+                self._prev_emitted.add(s.seq_id)
+                plan = ffwd_plan.get(s.seq_id)
+                self._inflight_toks[s.seq_id] = (
+                    self._inflight_toks.get(s.seq_id, 0) + 1
+                    + (0 if plan is None else len(plan[1]))
+                )
+            for sid, lane, _done, _c, finishing in chk_rows:
+                self._prev_lane[sid] = lane
+                if finishing:
+                    self._prev_emitted.add(sid)
+            self._pending.append(_Tick(
+                toks_d=toks_d,
+                decode=[(s.seq_id, lane) for s, lane in dec_rows],
+                chunks=chk_rows,
+                ffwd={sid: plan[1] for sid, plan in ffwd_plan.items()},
+                ticket=ticket,
+                bucket=int(S),
+            ))
+        return True
+
+    def _account_dispatch(
+        self, S, starts, qlens, dec_rows, chk_rows, ffwd_plan, tick_id
+    ) -> None:
+        """What only observes the dispatch just enqueued: the token
+        counters, the attribution ledger's composition, the flight event."""
+        eng = self.eng
+        cfg = eng.cfg
         n_prefill = int(sum(c for _s, _l, _d, c, _f in chk_rows))
         if n_prefill:
-            perf.record_metric("engine.prefill_tokens", n_prefill, "tok")
+            get_perf_stats().record_metric(
+                "engine.prefill_tokens", n_prefill, "tok")
             obs.PREFILL_TOKENS.inc(n_prefill)
         from .decode_loop import record_async_dispatch
 
@@ -523,7 +632,6 @@ class AsyncMixedRuntime:
         record_async_dispatch(
             decode_rows=len(dec_rows),
             prefill_tokens=n_prefill,
-            budget=cfg.max_step_tokens,
             depth=len(self._pending) + 1,
             attr=getattr(eng, "attr", None),
             attr_kw=dict(
@@ -564,40 +672,6 @@ class AsyncMixedRuntime:
             budget=cfg.max_step_tokens,
             tick=tick_id, pipeline_pos=len(self._pending),
         )
-        # Book-keeping AFTER the dispatch succeeded: planned prefill
-        # progress advances (the write is enqueued — deterministic), the
-        # finishing set gains this tick's completing prompts, and every
-        # emitting row carries one more uncommitted token.
-        for sid, _lane, done, c, finishing in chk_rows:
-            eng._prefilling[sid] = done + c
-            if finishing:
-                self._finishing.add(sid)
-                self._inflight_toks[sid] = (
-                    self._inflight_toks.get(sid, 0) + 1
-                )
-        self._prev_lane = {}
-        self._prev_emitted = set()
-        for s, lane in dec_rows:
-            self._prev_lane[s.seq_id] = lane
-            self._prev_emitted.add(s.seq_id)
-            plan = ffwd_plan.get(s.seq_id)
-            self._inflight_toks[s.seq_id] = (
-                self._inflight_toks.get(s.seq_id, 0) + 1
-                + (0 if plan is None else len(plan[1]))
-            )
-        for sid, lane, _done, _c, finishing in chk_rows:
-            self._prev_lane[sid] = lane
-            if finishing:
-                self._prev_emitted.add(sid)
-        self._pending.append(_Tick(
-            toks_d=toks_d,
-            decode=[(s.seq_id, lane) for s, lane in dec_rows],
-            chunks=chk_rows,
-            ffwd={sid: plan[1] for sid, plan in ffwd_plan.items()},
-            ticket=ticket,
-            bucket=int(S),
-        ))
-        return True
 
     # -- commit phase --------------------------------------------------------
     def _dec_inflight(self, sid: int) -> None:
@@ -610,12 +684,12 @@ class AsyncMixedRuntime:
     def _commit_oldest(self) -> None:
         eng = self.eng
         tick = self._pending.popleft()
-        perf = get_perf_stats()
+        # Nothing enqueued behind the step pulled: the device idles from
+        # the moment it is ready until the next dispatch (wait's ``alone``).
         overlapped = bool(self._pending)
-        t0 = time.perf_counter()
-        sampled = eng._pull("mixed", tick.bucket, tick.ticket, tick.toks_d)
-        perf.record_metric(
-            "engine.async_pull", (time.perf_counter() - t0) * 1e3, "ms"
+        sampled = eng._pull(
+            "mixed", tick.bucket, tick.ticket, tick.toks_d,
+            alone=not overlapped,
         )
         with obs.phase("commit", tick=tick.ticket[0]):
             self._commit(tick, sampled, overlapped)
@@ -624,89 +698,97 @@ class AsyncMixedRuntime:
         self, tick: _Tick, sampled: np.ndarray, overlapped: bool
     ) -> None:
         """Fold one pulled tick into host state: accept, stop scan,
-        detokenize, stream, roll finished rows' bookings back."""
+        detokenize, stream, roll finished rows' bookings back. The parts
+        of ``commit``: ``accept`` (with ``stream`` and ``stop_scan`` summed
+        out of it by ``Engine._accept_token``) and ``account``."""
         eng = self.eng
         perf = get_perf_stats()
-        tick_id, t_disp = tick.ticket
+        tick_id, t_disp, _ = tick.ticket
         decode_out, prefill_out = self._results
         produced = 0
-        for sid, lane in tick.decode:
-            pre = tick.ffwd.get(sid, [])
-            n_toks = 1 + len(pre)
-            for _ in range(n_toks):
-                self._dec_inflight(sid)
-            s = eng.sequences.get(sid)
-            if s is None or s.done:
-                # Stop/EOS detection lagged a tick: this row finished at
-                # an earlier commit (or was dropped) while this dispatch
-                # was in flight. Its tokens are discarded; the page
-                # booking was already rolled back by the done-path
-                # truncate.
-                obs.ASYNC_OVERSHOOT_TOKENS.inc(n_toks)
-                continue
-            dspan = s.decode_span
-            accepted = 0
-            # Fast-forward pre-accepts land first (they precede the
-            # sampled token in the append), so the stop-string/EOS scan
-            # runs over the run in order and a mid-run stop discards the
-            # tail as overshoot.
-            for tok in list(pre) + [int(sampled[lane])]:
-                if s.done:
-                    obs.ASYNC_OVERSHOOT_TOKENS.inc()
-                    continue
-                try:
-                    eng._accept_token(s, tok)
-                except Exception:  # noqa: BLE001 - raising stream callback
-                    # Row-local isolation without propagation, exactly
-                    # like step_mixed: the reap path surfaces "error";
-                    # raising here would lose the same tick's other rows.
-                    s.done = True
-                    s.finish_reason = s.finish_reason or "error"
-                decode_out.setdefault(sid, []).append(tok)
-                accepted += 1
-            produced += accepted
-            if dspan is not None:
-                dspan.child(
-                    "ffwd_step" if pre else "mixed_step",
-                    t_disp, time.perf_counter(), tokens=accepted,
-                    tick=tick_id,
-                )
-            if s.done:
-                # Roll bookings (including any still-in-flight lookahead
-                # tokens') back to written content; later stale writes
-                # land harmlessly before any new owner's (dispatch order).
-                eng.alloc.truncate(sid, eng._host_written(s))
-        for sid, lane, done, c, finishing in tick.chunks:
-            seq = eng.sequences.get(sid)
-            if seq is None:
-                # Dropped by a failure path while this tick was in flight.
-                if finishing:
-                    self._finishing.discard(sid)
+        with eng._accepting():
+            for sid, lane in tick.decode:
+                pre = tick.ffwd.get(sid, [])
+                n_toks = 1 + len(pre)
+                for _ in range(n_toks):
                     self._dec_inflight(sid)
-                continue
-            if not finishing:
-                prefill_out[sid] = False
-                continue
-            # Finishing chunk: the prompt's first sampled token.
-            self._finishing.discard(sid)
-            self._dec_inflight(sid)
-            eng._prefilling.pop(sid, None)
-            token = int(sampled[lane])
-            seq.ttft_s = time.perf_counter() - seq.started_s
-            perf.record_metric("engine.ttft", seq.ttft_s * 1e3, "ms")
-            eng._first_token_obs(seq)
-            try:
-                eng._accept_token(seq, token)
-            except Exception as e:  # noqa: BLE001 - stream callback
-                eng._drop_admission(sid)
-                prefill_out[sid] = e
-                continue
-            prefill_out[sid] = True
-            if seq.done:
-                eng.alloc.truncate(sid, eng._host_written(seq))
-        if produced:
-            perf.record_metric("engine.decode_tokens", produced, "tok")
-        from .decode_loop import record_async_commit
+                s = eng.sequences.get(sid)
+                if s is None or s.done:
+                    # Stop/EOS detection lagged a tick: this row finished
+                    # at an earlier commit (or was dropped) while this
+                    # dispatch was in flight. Its tokens are discarded; the
+                    # page booking was already rolled back by the done-path
+                    # truncate.
+                    obs.ASYNC_OVERSHOOT_TOKENS.inc(n_toks)
+                    continue
+                dspan = s.decode_span
+                accepted = 0
+                # Fast-forward pre-accepts land first (they precede the
+                # sampled token in the append), so the stop-string/EOS scan
+                # runs over the run in order and a mid-run stop discards
+                # the tail as overshoot.
+                for tok in list(pre) + [int(sampled[lane])]:
+                    if s.done:
+                        obs.ASYNC_OVERSHOOT_TOKENS.inc()
+                        continue
+                    try:
+                        eng._accept_token(s, tok)
+                    except Exception:  # noqa: BLE001 - raising stream callback
+                        # Row-local isolation without propagation, exactly
+                        # like step_mixed: the reap path surfaces "error";
+                        # raising here would lose the same tick's other
+                        # rows.
+                        s.done = True
+                        s.finish_reason = s.finish_reason or "error"
+                    decode_out.setdefault(sid, []).append(tok)
+                    accepted += 1
+                produced += accepted
+                if dspan is not None:
+                    t0 = time.perf_counter()
+                    dspan.child(
+                        "ffwd_step" if pre else "mixed_step",
+                        t_disp, t0, tokens=accepted, tick=tick_id,
+                    )
+                    eng._summed("account", t0)
+                if s.done:
+                    # Roll bookings (including any still-in-flight
+                    # lookahead tokens') back to written content; later
+                    # stale writes land harmlessly before any new owner's
+                    # (dispatch order).
+                    eng.alloc.truncate(sid, eng._host_written(s))
+            for sid, lane, done, c, finishing in tick.chunks:
+                seq = eng.sequences.get(sid)
+                if seq is None:
+                    # Dropped by a failure path while this tick was in
+                    # flight.
+                    if finishing:
+                        self._finishing.discard(sid)
+                        self._dec_inflight(sid)
+                    continue
+                if not finishing:
+                    prefill_out[sid] = False
+                    continue
+                # Finishing chunk: the prompt's first sampled token.
+                self._finishing.discard(sid)
+                self._dec_inflight(sid)
+                eng._prefilling.pop(sid, None)
+                token = int(sampled[lane])
+                seq.ttft_s = time.perf_counter() - seq.started_s
+                perf.record_metric("engine.ttft", seq.ttft_s * 1e3, "ms")
+                eng._first_token_obs(seq)
+                try:
+                    eng._accept_token(seq, token)
+                except Exception as e:  # noqa: BLE001 - stream callback
+                    eng._drop_admission(sid)
+                    prefill_out[sid] = e
+                    continue
+                prefill_out[sid] = True
+                if seq.done:
+                    eng.alloc.truncate(sid, eng._host_written(seq))
+        with obs.phase("commit", part="account"):
+            if produced:
+                perf.record_metric("engine.decode_tokens", produced, "tok")
+            from .decode_loop import record_async_commit
 
-        record_async_commit(overlapped, len(self._pending))
-        eng._observe_occupancy()
+            record_async_commit(overlapped, len(self._pending))
+            eng._observe_occupancy()

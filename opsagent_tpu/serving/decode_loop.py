@@ -26,7 +26,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..models import llama
 from ..models.config import ModelConfig
@@ -69,47 +68,26 @@ def record_dispatch(
     _record_attr(kind, attr, attr_kw)
 
 
-def record_attn_pages(
-    starts, q_lens, page_size: int, table_width: int
-) -> None:
-    """The live share of the page tables' capacity, a scrape away: what
-    an attention pass over these rows streams (``ceil((start + q_len) /
-    page_size)`` pages a row with a query, none for a row without)
-    against what the table could hold (every row x ``table_width``).
-    From the plan's own arrays, ``[rows]`` or, for the passes of a fused
-    block, ``[passes, rows]``."""
-    from .. import obs
-
-    starts, q_lens = np.asarray(starts), np.asarray(q_lens)
-    live = np.where(q_lens > 0, -(-(starts + q_lens) // page_size), 0)
-    obs.ATTN_PAGES_STREAMED.inc(int(live.sum()))
-    obs.ATTN_PAGES_CAPACITY.inc(starts.size * table_width)
-
-
 def record_mixed_dispatch(
-    decode_rows: int, prefill_tokens: int, budget: int,
+    decode_rows: int, prefill_tokens: int,
     attr=None, attr_kw: dict | None = None,
 ) -> None:
     """Composition telemetry for one MIXED prefill+decode dispatch
-    (engine.step_mixed): how many decode lanes rode the dispatch, how many
-    prefill chunk tokens piggybacked on its weight stream, and what
-    fraction of the per-dispatch token budget (max_step_tokens) the two
-    together used. These are the series the sessions-mixed bench stage
-    uses to attribute the one-weight-stream-per-tick win."""
+    (engine.step_mixed): how many decode lanes rode the dispatch and how
+    many prefill chunk tokens piggybacked on its weight stream. These are
+    the series the sessions-mixed bench stage uses to attribute the
+    one-weight-stream-per-tick win (how full the step was is
+    ``opsagent_step_tokens_total``, real over computed)."""
     from .. import obs
 
     obs.DECODE_DISPATCHES.inc(kind="mixed")
     obs.MIXED_DECODE_LANES.observe(max(0, decode_rows))
     obs.MIXED_PREFILL_TOKENS.observe(max(0, prefill_tokens))
-    if budget > 0:
-        obs.MIXED_BUDGET_UTILIZATION.observe(
-            min(1.0, (decode_rows + prefill_tokens) / budget)
-        )
     _record_attr("mixed", attr, attr_kw)
 
 
 def record_async_dispatch(
-    decode_rows: int, prefill_tokens: int, budget: int, depth: int,
+    decode_rows: int, prefill_tokens: int, depth: int,
     attr=None, attr_kw: dict | None = None,
 ) -> None:
     """Telemetry for one ASYNC mixed dispatch (engine step_mixed_async /
@@ -124,10 +102,6 @@ def record_async_dispatch(
     obs.DECODE_DISPATCHES.inc(kind="mixed_async")
     obs.MIXED_DECODE_LANES.observe(max(0, decode_rows))
     obs.MIXED_PREFILL_TOKENS.observe(max(0, prefill_tokens))
-    if budget > 0:
-        obs.MIXED_BUDGET_UTILIZATION.observe(
-            min(1.0, (decode_rows + prefill_tokens) / budget)
-        )
     obs.ASYNC_INFLIGHT_DEPTH.set(depth)
     _record_attr("mixed_async", attr, attr_kw)
 

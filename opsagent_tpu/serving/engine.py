@@ -329,6 +329,11 @@ class Sequence:
     trace: Any = None
     decode_span: Any = None
     last_tok_s: float = 0.0
+    # The scheduler's tick count (Engine.sched_tick) at the first and at
+    # the newest accepted token: their difference over the tokens less one
+    # is the ticks a token took (opsagent_request_decode_ticks_total).
+    first_tok_tick: int = 0
+    last_tok_tick: int = 0
 
 
 class Engine:
@@ -940,6 +945,16 @@ class Engine:
         # its tick id: the flight "dispatch" event, the engine.dispatch /
         # wait / commit spans and the per-request span children carry it.
         self.step_clock = obs.StepClock()
+        # Ticks the scheduler that drives this engine has counted (it bumps
+        # this where it counts opsagent_ticks_total); _accept_token stamps
+        # a sequence's first and newest token with it.
+        self.sched_tick = 0
+        # Seconds of what recurs a token (commit: account / stream /
+        # stop_scan) or a row (plan: pages), summed here by the code that
+        # times it and handed to obs.add_part once a stretch, by
+        # _accepting and _building_arrays.
+        self._token_sums: dict[str, float] = {}
+        self._pages_s = 0.0
 
         if cfg.warmup:
             self.warmup()
@@ -1017,13 +1032,6 @@ class Engine:
                 yield
         finally:
             self._mesh_tls.active = False
-
-    def _record_attn_pages(self, starts, q_lens) -> None:
-        from .decode_loop import record_attn_pages
-
-        record_attn_pages(
-            starts, q_lens, self.cfg.page_size, self.alloc.table_width
-        )
 
     def impl_info(self) -> dict[str, Any]:
         """What actually runs: the device JAX put this engine on, the
@@ -1627,9 +1635,16 @@ class Engine:
         expect_restore: bool = False,
     ) -> int:
         """Stage 1 of admission: allocate pages (reusing any cached prefix)
-        and register the sequence in the 'prefilling' state. Cheap — no
-        device work. Follow with ``prefill_step`` calls until it returns
-        True; only then does the sequence decode."""
+        and register the sequence in the 'prefilling' state. No model pass
+        runs here, but it is not free: the trie match hashes every page of
+        the prompt, a model with recurrent state enqueues one ``state_copy``
+        dispatch to restore a snapshot, and the offload tier copies pages
+        back from the host. Follow with ``prefill_step`` calls until it
+        returns True; only then does the sequence decode.
+
+        The parts of ``admit`` here (docs/observability.md "Tick phases"):
+        ``fault_in``, ``match``, ``alloc``, ``host_restore``, ``register``
+        and ``account``; the ``state_copy`` is a ``dispatch`` of its own."""
         sampling = sampling or SamplingParams()
         n = len(prompt_ids)
         if n == 0:
@@ -1658,45 +1673,52 @@ class Engine:
         # the same chain keys, so the locked restore below picks them up
         # through the unchanged _restore_from_host path. Never raises;
         # a miss/failure just means the prefill loop covers the tokens.
-        faulted_pages = self.fault_in_prefix(
-            prompt_ids,
-            request_id=obs.flight.request_id_of(trace) or "",
-        )
+        with obs.phase("admit", part="fault_in"):
+            faulted_pages = self.fault_in_prefix(
+                prompt_ids,
+                request_id=obs.flight.request_id_of(trace) or "",
+            )
         with self.lock:
             if self.offload is not None:
                 # Land pending spills first: a page parked during the
                 # previous tick must be matchable by THIS admission.
-                self.offload.flush()
+                with obs.phase("admit", part="host_restore"):
+                    self.offload.flush()
             # Prefix cache: reuse full pages of the prompt MINUS its last
             # token (at least one tail token must be prefilled to produce
             # the next-token logits).
             snapshot = -1
-            if self.alloc.state_slots:
-                # Recurrent state: a page chain is reusable only up to a
-                # node that holds a state snapshot; restoring is one
-                # device copy into the sequence's slot, enqueued here,
-                # before any step that could write either slot.
-                prefix_pages, snapshot, full = self.alloc.match_prefix_state(
-                    prompt_ids[: n - 1])
-                given_up = (full - len(prefix_pages)) * self.cfg.page_size
-                obs.STATE_PROMPT_TOKENS.inc(n)
-                if given_up:
-                    obs.STATE_UNMATCHED_TOKENS.inc(given_up)
-            else:
-                prefix_pages = self.alloc.match_prefix(prompt_ids[: n - 1])
-            matched = len(prefix_pages) * self.cfg.page_size
-            seq_id = self.alloc.allocate(n, prefix_pages=prefix_pages)
+            with obs.phase("admit", part="match"):
+                if self.alloc.state_slots:
+                    # Recurrent state: a page chain is reusable only up to
+                    # a node that holds a state snapshot; restoring is one
+                    # device copy into the sequence's slot, enqueued here,
+                    # before any step that could write either slot.
+                    prefix_pages, snapshot, full = (
+                        self.alloc.match_prefix_state(prompt_ids[: n - 1]))
+                    given_up = (
+                        (full - len(prefix_pages)) * self.cfg.page_size)
+                    obs.STATE_PROMPT_TOKENS.inc(n)
+                    if given_up:
+                        obs.STATE_UNMATCHED_TOKENS.inc(given_up)
+                else:
+                    prefix_pages = self.alloc.match_prefix(
+                        prompt_ids[: n - 1])
+                matched = len(prefix_pages) * self.cfg.page_size
+            with obs.phase("admit", part="alloc"):
+                seq_id = self.alloc.allocate(n, prefix_pages=prefix_pages)
             if snapshot >= 0:
                 self._copy_state([snapshot], [self.alloc.state_slot(seq_id)])
                 obs.STATE_SNAPSHOTS.inc(event="restored")
                 obs.STATE_RESTORED_TOKENS.inc(matched)
-            restored = self._restore_from_host(
-                seq_id, prompt_ids, n, len(prefix_pages), matched
-            )
-            if faulted_pages and restored and self.offload is not None:
-                self.offload.note_remote_hit(
-                    min(restored, faulted_pages * self.cfg.page_size)
+            with obs.phase("admit", part="host_restore"):
+                restored = self._restore_from_host(
+                    seq_id, prompt_ids, n, len(prefix_pages), matched
                 )
+                if faulted_pages and restored and self.offload is not None:
+                    self.offload.note_remote_hit(
+                        min(restored, faulted_pages * self.cfg.page_size)
+                    )
             if expect_restore and matched + restored < (
                 (n - 1) // self.cfg.page_size
             ) * self.cfg.page_size:
@@ -1711,24 +1733,26 @@ class Engine:
                     prefix_hit_tokens=matched, restored_tokens=restored,
                     request_id=obs.flight.request_id_of(trace),
                 )
-            seq = Sequence(
-                seq_id, n, prompt_ids=list(prompt_ids),
-                params=sampling, mask_fn=mask_fn, stream=stream,
-                trace=trace,
-            )
-            self.sequences[seq_id] = seq
-            self._prefilling[seq_id] = matched + restored
-            if matched:
-                get_perf_stats().record_metric(
-                    "engine.prefix_hit_tokens", matched, "tok"
+            with obs.phase("admit", part="register"):
+                seq = Sequence(
+                    seq_id, n, prompt_ids=list(prompt_ids),
+                    params=sampling, mask_fn=mask_fn, stream=stream,
+                    trace=trace,
                 )
-                obs.PREFIX_HIT_TOKENS.inc(matched)
-            obs.flight.record(
-                "admission", seq_id=seq_id, prompt_tokens=n,
-                prefix_hit_tokens=matched, restored_tokens=restored,
-                request_id=obs.flight.request_id_of(trace),
-            )
-            self._observe_occupancy()
+                self.sequences[seq_id] = seq
+                self._prefilling[seq_id] = matched + restored
+            with obs.phase("admit", part="account"):
+                if matched:
+                    get_perf_stats().record_metric(
+                        "engine.prefix_hit_tokens", matched, "tok"
+                    )
+                    obs.PREFIX_HIT_TOKENS.inc(matched)
+                obs.flight.record(
+                    "admission", seq_id=seq_id, prompt_tokens=n,
+                    prefix_hit_tokens=matched, restored_tokens=restored,
+                    request_id=obs.flight.request_id_of(trace),
+                )
+                self._observe_occupancy()
             return seq_id
 
     def _copy_state(self, src: list[int], dst: list[int]) -> None:
@@ -1736,12 +1760,13 @@ class Engine:
         dispatch of its own on the step clock (program ``state_copy``),
         ordered with the steps like any other. Under the engine lock."""
         ticket = self.step_clock.enqueue()
-        with obs.phase("dispatch", tick=ticket[0]), \
-                annotate("engine.state_copy"), self.mesh_ctx():
-            self.cache = self._state_copy_jit(
-                self.cache, jnp.asarray(src, jnp.int32),
-                jnp.asarray(dst, jnp.int32),
-            )
+        with obs.phase("dispatch", tick=ticket[0]), self.mesh_ctx():
+            with obs.phase("dispatch", part="place"):
+                src_d = jnp.asarray(src, jnp.int32)
+                dst_d = jnp.asarray(dst, jnp.int32)
+            with obs.phase("dispatch", part="call"), \
+                    annotate("engine.state_copy"):
+                self.cache = self._state_copy_jit(self.cache, src_d, dst_d)
         obs.flight.record(
             "dispatch", op="state_copy", slots=len(src), tick=ticket[0])
 
@@ -1845,67 +1870,72 @@ class Engine:
             self._async_settle()
             self._mixed_gap_stamp = None  # see step(): gap continuity ends
             try:
-                seqs = [self.sequences[s] for s in seq_ids]
-                dones = [self._prefilling[s] for s in seq_ids]
-                chunks = [
-                    self.alloc.clamp_chunk(
-                        sid, d, seq.prompt_len,
-                        min(seq.prompt_len - d, self.cfg.prefill_buckets[-1]))
-                    for sid, seq, d in zip(seq_ids, seqs, dones)
-                ]
-                bucket = self._bucket(max(chunks))
-                Bp = 1
-                while Bp < len(seq_ids):
-                    Bp *= 2
-                tokens = np.full(
-                    (Bp, bucket), self.tokenizer.pad_id, np.int32
-                )
-                starts = np.zeros((Bp,), np.int32)
-                lens = np.zeros((Bp,), np.int32)
-                tables = np.full(
-                    (Bp, self.alloc.table_width), -1, np.int32
-                )
-                for i, (sid, seq, d, c) in enumerate(
-                    zip(seq_ids, seqs, dones, chunks)
-                ):
-                    tokens[i, :c] = seq.prompt_ids[d : d + c]
-                    starts[i] = d
-                    lens[i] = c
-                    tables[i] = self._pass_row(sid, d, c)
-                self._record_attn_pages(starts, lens)
-                ticket = self.step_clock.enqueue()
-                with obs.phase("dispatch", tick=ticket[0]), \
-                        annotate("engine.prefill_chunk"), self.mesh_ctx():
-                    logits, self.cache = self._prefill_prefix_jit(
-                        self.params,
-                        jnp.asarray(tokens),
-                        jnp.asarray(starts),
-                        jnp.asarray(lens),
-                        self.cache,
-                        jnp.asarray(tables),
+                with obs.phase("plan", part="chunks"):
+                    seqs = [self.sequences[s] for s in seq_ids]
+                    dones = [self._prefilling[s] for s in seq_ids]
+                    chunks = [
+                        self.alloc.clamp_chunk(
+                            sid, d, seq.prompt_len,
+                            min(seq.prompt_len - d,
+                                self.cfg.prefill_buckets[-1]))
+                        for sid, seq, d in zip(seq_ids, seqs, dones)
+                    ]
+                    bucket = self._bucket(max(chunks))
+                with self._building_arrays():
+                    Bp = 1
+                    while Bp < len(seq_ids):
+                        Bp *= 2
+                    tokens = np.full(
+                        (Bp, bucket), self.tokenizer.pad_id, np.int32
                     )
+                    starts = np.zeros((Bp,), np.int32)
+                    lens = np.zeros((Bp,), np.int32)
+                    tables = np.full(
+                        (Bp, self.alloc.table_width), -1, np.int32
+                    )
+                    for i, (sid, seq, d, c) in enumerate(
+                        zip(seq_ids, seqs, dones, chunks)
+                    ):
+                        tokens[i, :c] = seq.prompt_ids[d : d + c]
+                        starts[i] = d
+                        lens[i] = c
+                        tables[i] = self._pass_row(sid, d, c)
+                ticket = self.step_clock.enqueue()
+                with obs.phase("dispatch", tick=ticket[0]), self.mesh_ctx():
+                    with obs.phase("dispatch", part="place"):
+                        tokens_d, starts_d, lens_d, tables_d = (
+                            jnp.asarray(a)
+                            for a in (tokens, starts, lens, tables))
+                    with obs.phase("dispatch", part="call"), \
+                            annotate("engine.prefill_chunk"):
+                        logits, self.cache = self._prefill_prefix_jit(
+                            self.params, tokens_d, starts_d, lens_d,
+                            self.cache, tables_d,
+                        )
                 perf = get_perf_stats()
-                perf.record_metric(
-                    "engine.prefill_tokens", int(sum(chunks)), "tok"
-                )
-                obs.PREFILL_TOKENS.inc(int(sum(chunks)))
-                obs.flight.record(
-                    "dispatch", op="prefill_batch", seq_ids=list(seq_ids),
-                    bucket=bucket, rows=len(seq_ids),
-                    prefill_tokens=int(sum(chunks)), tick=ticket[0],
-                )
-                self.attr.dispatch(
-                    "prefill_batch",
-                    q_tokens=int(sum(chunks)),
-                    kv_read_tokens=int(
-                        sum(d + c for d, c in zip(dones, chunks))
-                    ),
-                    kv_write_tokens=int(sum(chunks)),
-                    attn_q_ctx=int(sum(
-                        obs.attribution.prefill_attn_positions(d, c)
-                        for d, c in zip(dones, chunks)
-                    )),
-                )
+                with obs.phase("plan", part="account"):
+                    perf.record_metric(
+                        "engine.prefill_tokens", int(sum(chunks)), "tok"
+                    )
+                    obs.PREFILL_TOKENS.inc(int(sum(chunks)))
+                    obs.flight.record(
+                        "dispatch", op="prefill_batch",
+                        seq_ids=list(seq_ids),
+                        bucket=bucket, rows=len(seq_ids),
+                        prefill_tokens=int(sum(chunks)), tick=ticket[0],
+                    )
+                    self.attr.dispatch(
+                        "prefill_batch",
+                        q_tokens=int(sum(chunks)),
+                        kv_read_tokens=int(
+                            sum(d + c for d, c in zip(dones, chunks))
+                        ),
+                        kv_write_tokens=int(sum(chunks)),
+                        attn_q_ctx=int(sum(
+                            obs.attribution.prefill_attn_positions(d, c)
+                            for d, c in zip(dones, chunks)
+                        )),
+                    )
                 out: dict[int, Any] = {}
                 finished_rows = [
                     i for i, (seq, d, c) in enumerate(zip(seqs, dones, chunks))
@@ -1941,7 +1971,7 @@ class Engine:
                     first_toks = self._sample_one(
                         logits, row_seqs, ("prefill_chunk", bucket, ticket)
                     )
-                with obs.phase("commit", tick=ticket[0]):
+                with self._accepting(tick=ticket[0]):
                     for i, (sid, seq, d, c) in enumerate(
                         zip(seq_ids, seqs, dones, chunks)
                     ):
@@ -1967,12 +1997,43 @@ class Engine:
                             out[sid] = e
                             continue
                         out[sid] = True
+                with obs.phase("commit", part="account"):
                     self._observe_occupancy()
                 return out
             except Exception:
                 for sid in seq_ids:
                     self._drop_admission(sid)
                 raise
+
+    def _summed(self, part: str, t0: float) -> None:
+        """``perf_counter() - t0`` more of ``commit``'s summed ``part``."""
+        sums = self._token_sums
+        sums[part] = sums.get(part, 0.0) + time.perf_counter() - t0
+
+    @contextlib.contextmanager
+    def _accepting(self, **ids):
+        """The ``accept`` part of ``commit``. What ``_accept_token`` and
+        the span children summed a token leaves it as parts of their own
+        (``account``, ``stream``, ``stop_scan``): one ``obs.add_part``
+        each, not one a token."""
+        with obs.phase("commit", part="accept", **ids):
+            try:
+                yield
+            finally:
+                for part, seconds in self._token_sums.items():
+                    obs.add_part("commit", part, seconds)
+                self._token_sums.clear()
+
+    @contextlib.contextmanager
+    def _building_arrays(self):
+        """The ``arrays`` part of ``plan``; the allocator's share that
+        ``_pass_row`` summed a row leaves it as ``pages``."""
+        with obs.phase("plan", part="arrays"):
+            try:
+                yield
+            finally:
+                obs.add_part("plan", "pages", self._pages_s)
+                self._pages_s = 0.0
 
     def _pass_row(
         self, seq_id: int, start: int, q: int, each_token: bool = False
@@ -1981,9 +2042,13 @@ class Engine:
         with ``each_token``, ``q`` one-token passes) that takes it from
         ``start`` tokens to ``start + q``: its pages and, for a model with
         recurrent state, its slots, with the pass noted for the snapshot's
-        bookkeeping (``PageAllocator.note_pass``)."""
+        bookkeeping (``PageAllocator.note_pass``). Once a row of every
+        plan: summed for ``plan``'s ``pages`` part (``_building_arrays``)."""
+        t0 = time.perf_counter()
         self.alloc.note_pass(seq_id, start, start + q, each_token)
-        return self.alloc.page_table_row(seq_id)
+        row = self.alloc.page_table_row(seq_id)
+        self._pages_s += time.perf_counter() - t0
+        return row
 
     def _drop_admission(self, seq_id: int) -> None:
         """Clean one failed admission: pages freed, host state dropped."""
@@ -2020,44 +2085,42 @@ class Engine:
                 tokens = np.full((1, bucket), self.tokenizer.pad_id, np.int32)
                 tokens[0, :chunk] = seq.prompt_ids[done:done + chunk]
                 ticket = self.step_clock.enqueue()
-                with obs.phase("dispatch", tick=ticket[0]), \
-                        annotate("engine.prefill_chunk"), self.mesh_ctx():
-                    if done:
-                        self._record_attn_pages([done], [chunk])
-                        logits, self.cache = self._prefill_prefix_jit(
-                            self.params,
-                            jnp.asarray(tokens),
-                            jnp.asarray([done], jnp.int32),
-                            jnp.asarray([chunk], jnp.int32),
-                            self.cache,
-                            table,
-                        )
-                    else:
-                        logits, self.cache = self._prefill_jit(
-                            self.params,
-                            jnp.asarray(tokens),
-                            jnp.asarray([chunk], jnp.int32),
-                            self.cache,
-                            table,
-                        )
+                with obs.phase("dispatch", tick=ticket[0]), self.mesh_ctx():
+                    with obs.phase("dispatch", part="place"):
+                        tokens_d = jnp.asarray(tokens)
+                        done_d = jnp.asarray([done], jnp.int32)
+                        chunk_d = jnp.asarray([chunk], jnp.int32)
+                    with obs.phase("dispatch", part="call"), \
+                            annotate("engine.prefill_chunk"):
+                        if done:
+                            logits, self.cache = self._prefill_prefix_jit(
+                                self.params, tokens_d, done_d, chunk_d,
+                                self.cache, table,
+                            )
+                        else:
+                            logits, self.cache = self._prefill_jit(
+                                self.params, tokens_d, chunk_d, self.cache,
+                                table,
+                            )
                 done += chunk
                 perf = get_perf_stats()
-                perf.record_metric("engine.prefill_tokens", chunk, "tok")
-                obs.PREFILL_TOKENS.inc(chunk)
-                obs.flight.record(
-                    "dispatch", op="prefill_chunk", seq_id=seq_id,
-                    bucket=bucket, prefill_tokens=chunk,
-                    prompt_done=done, prompt_total=n, tick=ticket[0],
-                )
-                self.attr.dispatch(
-                    "prefill_chunk",
-                    q_tokens=chunk,
-                    kv_read_tokens=done,  # done already includes chunk
-                    kv_write_tokens=chunk,
-                    attn_q_ctx=obs.attribution.prefill_attn_positions(
-                        done - chunk, chunk
-                    ),
-                )
+                with obs.phase("plan", part="account"):
+                    perf.record_metric("engine.prefill_tokens", chunk, "tok")
+                    obs.PREFILL_TOKENS.inc(chunk)
+                    obs.flight.record(
+                        "dispatch", op="prefill_chunk", seq_id=seq_id,
+                        bucket=bucket, prefill_tokens=chunk,
+                        prompt_done=done, prompt_total=n, tick=ticket[0],
+                    )
+                    self.attr.dispatch(
+                        "prefill_chunk",
+                        q_tokens=chunk,
+                        kv_read_tokens=done,  # done already includes chunk
+                        kv_write_tokens=chunk,
+                        attn_q_ctx=obs.attribution.prefill_attn_positions(
+                            done - chunk, chunk
+                        ),
+                    )
                 if done < n:
                     self._prefilling[seq_id] = done
                     return False
@@ -2065,11 +2128,12 @@ class Engine:
                 token = int(self._sample_one(
                     logits, [seq], ("prefill_chunk", bucket, ticket)
                 )[0])
-                with obs.phase("commit", tick=ticket[0]):
+                with self._accepting(tick=ticket[0]):
                     seq.ttft_s = time.perf_counter() - seq.started_s
                     perf.record_metric("engine.ttft", seq.ttft_s * 1e3, "ms")
                     self._first_token_obs(seq)
                     self._accept_token(seq, token)
+                with obs.phase("commit", part="account"):
                     self._observe_occupancy()
                 return True
             except Exception:
@@ -2101,11 +2165,15 @@ class Engine:
         T, narrow = widths
         return narrow if real is not None and real <= narrow else T
 
-    def _count_step_tokens(self, S: int, real: int) -> None:
+    def _count_step_tokens(self, S: int, real: int) -> str:
+        """Count a mixed dispatch of ``real`` tokens; returns the width its
+        dense segments run over, as the counter's label has it (the step
+        clock's ticket carries it to the pull)."""
         width = self._step_rows(S, real)
         obs.STEP_TOKENS.inc(real, kind="real")
         obs.STEP_TOKENS.inc(width, kind="computed")
         obs.MIXED_DISPATCH_WIDTH.inc(width=str(width))
+        return str(width)
 
     def mixed_hosted(self, seq_id: int) -> bool:
         """True when this sequence needs host-side per-token work — a
@@ -2227,60 +2295,62 @@ class Engine:
             if not decode and not prefill_chunks:
                 return decode_out, prefill_out
             S = self._mixed_bucket(smax)
-            tokens = np.full((B, S), self.tokenizer.pad_id, np.int32)
-            starts = np.zeros((B,), np.int32)
-            qlens = np.zeros((B,), np.int32)
-            tables = np.full((B, self.alloc.table_width), -1, np.int32)
-            for i, s in enumerate(decode):
-                tokens[i, 0] = (
-                    s.tokens[-1] if s.tokens else self.tokenizer.bos_id
-                )
-                # extend(1) above made alloc.length = written + 1; the row
-                # writes (and attends from) the written offset.
-                starts[i] = self.alloc.length(s.seq_id) - 1
-                qlens[i] = 1
-                tables[i] = self._pass_row(s.seq_id, int(starts[i]), 1)
-            base = len(decode)
-            for j, (sid, seq, done, c) in enumerate(chunk_info):
-                tokens[base + j, :c] = seq.prompt_ids[done:done + c]
-                starts[base + j] = done
-                qlens[base + j] = c
-                tables[base + j] = self._pass_row(sid, done, c)
-            slots: list[Sequence | None] = (
-                decode + [seq for _, seq, _, _ in chunk_info]
-            )
-            slots += [None] * (B - len(slots))
-            temps, top_k, top_p, _ = self._sampling_arrays(slots, B)
-            perf = get_perf_stats()
-            self._record_attn_pages(starts, qlens)
-            self._count_step_tokens(S, int(qlens.sum()))
-            ticket = self.step_clock.enqueue()
-            tick_id, t_disp = ticket
-            # Dispatch-to-dispatch interval (the async A/B's comparison
-            # basis): time since the previous mixed dispatch's enqueue
-            # returned — in this SYNC tick it spans the blocking token
-            # pull (the device's whole step) plus all host
-            # post-processing, the span the async runtime overlaps.
-            if self._mixed_gap_stamp is not None:
-                obs.STEP_HOST_GAP_SECONDS.observe(
-                    t_disp - self._mixed_gap_stamp, mode="sync"
-                )
-            try:
-                with obs.phase("dispatch", tick=tick_id), \
-                        annotate("engine.mixed_step"), self.mesh_ctx():
-                    self._sample_key, sub = jax.random.split(self._sample_key)
-                    toks_d, self.cache = self._mixed_sample_jit(
-                        self.params,
-                        jnp.asarray(tokens),
-                        jnp.asarray(starts),
-                        jnp.asarray(qlens),
-                        self.cache,
-                        jnp.asarray(tables),
-                        sub,
-                        jnp.asarray(temps),
-                        jnp.asarray(top_k),
-                        jnp.asarray(top_p),
+            with self._building_arrays():
+                tokens = np.full((B, S), self.tokenizer.pad_id, np.int32)
+                starts = np.zeros((B,), np.int32)
+                qlens = np.zeros((B,), np.int32)
+                tables = np.full((B, self.alloc.table_width), -1, np.int32)
+                for i, s in enumerate(decode):
+                    tokens[i, 0] = (
+                        s.tokens[-1] if s.tokens else self.tokenizer.bos_id
                     )
+                    # extend(1) above made alloc.length = written + 1; the
+                    # row writes (and attends from) the written offset.
+                    starts[i] = self.alloc.length(s.seq_id) - 1
+                    qlens[i] = 1
+                    tables[i] = self._pass_row(s.seq_id, int(starts[i]), 1)
+                base = len(decode)
+                for j, (sid, seq, done, c) in enumerate(chunk_info):
+                    tokens[base + j, :c] = seq.prompt_ids[done:done + c]
+                    starts[base + j] = done
+                    qlens[base + j] = c
+                    tables[base + j] = self._pass_row(sid, done, c)
+                slots: list[Sequence | None] = (
+                    decode + [seq for _, seq, _, _ in chunk_info]
+                )
+                slots += [None] * (B - len(slots))
+                temps, top_k, top_p, _ = self._sampling_arrays(slots, B)
+            perf = get_perf_stats()
+            with obs.phase("plan", part="account"):
+                width = self._count_step_tokens(S, int(qlens.sum()))
+                ticket = self.step_clock.enqueue(width)
+                tick_id, t_disp, _ = ticket
+                # Dispatch-to-dispatch interval (the async A/B's comparison
+                # basis): time since the previous mixed dispatch's enqueue
+                # returned — in this SYNC tick it spans the blocking token
+                # pull (the device's whole step) plus all host
+                # post-processing, the span the async runtime overlaps.
+                if self._mixed_gap_stamp is not None:
+                    obs.STEP_HOST_GAP_SECONDS.observe(
+                        t_disp - self._mixed_gap_stamp, mode="sync"
+                    )
+            try:
+                with obs.phase("dispatch", tick=tick_id), self.mesh_ctx():
+                    with obs.phase("dispatch", part="place"):
+                        self._sample_key, sub = jax.random.split(
+                            self._sample_key)
+                        (tokens_d, starts_d, qlens_d, tables_d, temps_d,
+                         top_k_d, top_p_d) = (
+                            jnp.asarray(a) for a in (
+                                tokens, starts, qlens, tables, temps, top_k,
+                                top_p))
+                    with obs.phase("dispatch", part="call"), \
+                            annotate("engine.mixed_step"):
+                        toks_d, self.cache = self._mixed_sample_jit(
+                            self.params, tokens_d, starts_d, qlens_d,
+                            self.cache, tables_d, sub, temps_d, top_k_d,
+                            top_p_d,
+                        )
                 self._mixed_gap_stamp = time.perf_counter()
                 sampled = self._pull("mixed", int(S), ticket, toks_d)
             except Exception:
@@ -2296,48 +2366,46 @@ class Engine:
                     self._drop_admission(sid)
                 raise
             measured_s = time.perf_counter() - t_disp
-            perf.record_metric(
-                "engine.mixed_dispatch", measured_s * 1e3, "ms",
-            )
-            n_prefill = int(sum(c for *_, c in chunk_info))
-            if n_prefill:
-                perf.record_metric(
-                    "engine.prefill_tokens", n_prefill, "tok"
-                )
-                obs.PREFILL_TOKENS.inc(n_prefill)
-            from .decode_loop import record_mixed_dispatch
+            with obs.phase("plan", part="account"):
+                n_prefill = int(sum(c for *_, c in chunk_info))
+                if n_prefill:
+                    perf.record_metric(
+                        "engine.prefill_tokens", n_prefill, "tok"
+                    )
+                    obs.PREFILL_TOKENS.inc(n_prefill)
+                from .decode_loop import record_mixed_dispatch
 
-            # Attribution composition: decode lanes attend their whole
-            # written context; chunk rows read their prefix + chunk. The
-            # sync tick's dispatch+pull wall time is a real synchronous
-            # measurement, so it also feeds the drift gauge.
-            dec_ctx = int(sum(int(starts[i]) + 1 for i in range(len(decode))))
-            record_mixed_dispatch(
-                decode_rows=len(decode),
-                prefill_tokens=n_prefill,
-                budget=self.cfg.max_step_tokens,
-                attr=self.attr,
-                attr_kw=dict(
-                    q_tokens=len(decode) + n_prefill,
-                    kv_read_tokens=dec_ctx + int(
-                        sum(d + c for _sid, _seq, d, c in chunk_info)
+                # Attribution composition: decode lanes attend their whole
+                # written context; chunk rows read their prefix + chunk.
+                # The sync tick's dispatch+pull wall time is a real
+                # synchronous measurement, so it also feeds the drift gauge.
+                dec_ctx = int(
+                    sum(int(starts[i]) + 1 for i in range(len(decode))))
+                record_mixed_dispatch(
+                    decode_rows=len(decode),
+                    prefill_tokens=n_prefill,
+                    attr=self.attr,
+                    attr_kw=dict(
+                        q_tokens=len(decode) + n_prefill,
+                        kv_read_tokens=dec_ctx + int(
+                            sum(d + c for _sid, _seq, d, c in chunk_info)
+                        ),
+                        kv_write_tokens=len(decode) + n_prefill,
+                        attn_q_ctx=dec_ctx + int(sum(
+                            obs.attribution.prefill_attn_positions(d, c)
+                            for _sid, _seq, d, c in chunk_info
+                        )),
+                        measured_s=measured_s,
                     ),
-                    kv_write_tokens=len(decode) + n_prefill,
-                    attn_q_ctx=dec_ctx + int(sum(
-                        obs.attribution.prefill_attn_positions(d, c)
-                        for _sid, _seq, d, c in chunk_info
-                    )),
-                    measured_s=measured_s,
-                ),
-            )
-            obs.flight.record(
-                "dispatch", op="mixed",
-                decode_seq_ids=[s.seq_id for s in decode],
-                prefill_seq_ids=[sid for sid, *_ in chunk_info],
-                bucket=int(S), prefill_tokens=n_prefill,
-                budget=self.cfg.max_step_tokens, tick=tick_id,
-            )
-            with obs.phase("commit", tick=tick_id):
+                )
+                obs.flight.record(
+                    "dispatch", op="mixed",
+                    decode_seq_ids=[s.seq_id for s in decode],
+                    prefill_seq_ids=[sid for sid, *_ in chunk_info],
+                    bucket=int(S), prefill_tokens=n_prefill,
+                    budget=self.cfg.max_step_tokens, tick=tick_id,
+                )
+            with self._accepting(tick=tick_id):
                 for i, s in enumerate(decode):
                     tok = int(sampled[i])
                     dspan = s.decode_span
@@ -2352,10 +2420,11 @@ class Engine:
                         self.alloc.truncate(s.seq_id, self._host_written(s))
                     decode_out[s.seq_id] = [tok]
                     if dspan is not None:
+                        t0 = time.perf_counter()
                         dspan.child(
-                            "mixed_step", t_disp, time.perf_counter(),
-                            tokens=1, tick=tick_id,
+                            "mixed_step", t_disp, t0, tokens=1, tick=tick_id,
                         )
+                        self._summed("account", t0)
                 for j, (sid, seq, done, c) in enumerate(chunk_info):
                     if done + c < seq.prompt_len:
                         self._prefilling[sid] = done + c
@@ -2373,6 +2442,7 @@ class Engine:
                         prefill_out[sid] = e
                         continue
                     prefill_out[sid] = True
+            with obs.phase("commit", part="account"):
                 if decode:
                     perf.record_metric(
                         "engine.decode_tokens", len(decode), "tok"
@@ -2533,37 +2603,44 @@ class Engine:
                 temps[i] = s.params.temperature
                 top_k[i] = s.params.top_k
                 top_p[i] = s.params.top_p
-            fm, fd = self._fsm_device_tables(fsm0)
             perf = get_perf_stats()
-            self._record_attn_pages(starts, qlens)
-            self._count_step_tokens(S, int(qlens.sum()))
-            ticket = self.step_clock.enqueue()
-            tick_id, t_disp = ticket
+            width = self._count_step_tokens(S, int(qlens.sum()))
+            ticket = self.step_clock.enqueue(width)
+            tick_id, t_disp, _ = ticket
             try:
-                with obs.phase("dispatch", tick=tick_id), \
-                        annotate("engine.ffwd_step"), self.mesh_ctx():
-                    self._sample_key, sub = jax.random.split(self._sample_key)
-                    # under the mesh context, as warm-up made them: the
-                    # jit cache keys even these on it
-                    zb = jnp.zeros((B,), bool)
-                    zi = jnp.zeros((B,), jnp.int32)
-                    toks_d, self.cache, _fsm_d = self._mixed_carry_jit(
-                        self.params,
-                        jnp.asarray(tokens),
-                        zb,  # use_carry: all rows override from host
-                        zi,  # carry tokens (unused at use_carry=False)
-                        jnp.asarray(starts),
-                        jnp.asarray(qlens),
-                        jnp.asarray(emits),
-                        self.cache,
-                        jnp.asarray(tables),
-                        sub,
-                        jnp.asarray(temps),
-                        jnp.asarray(top_k),
-                        jnp.asarray(top_p),
-                        fsm_mask=fm, fsm_dest=fd,
-                        carry_fsm=zi, ov_fsm=jnp.asarray(ov_fsm),
-                    )
+                with obs.phase("dispatch", tick=tick_id), self.mesh_ctx():
+                    with obs.phase("dispatch", part="place"):
+                        fm, fd = self._fsm_device_tables(fsm0)
+                        self._sample_key, sub = jax.random.split(
+                            self._sample_key)
+                        # under the mesh context, as warm-up made them:
+                        # the jit cache keys even these on it
+                        zb = jnp.zeros((B,), bool)
+                        zi = jnp.zeros((B,), jnp.int32)
+                        (tokens_d, starts_d, qlens_d, emits_d, tables_d,
+                         temps_d, top_k_d, top_p_d, ov_fsm_d) = (
+                            jnp.asarray(a) for a in (
+                                tokens, starts, qlens, emits, tables, temps,
+                                top_k, top_p, ov_fsm))
+                    with obs.phase("dispatch", part="call"), \
+                            annotate("engine.ffwd_step"):
+                        toks_d, self.cache, _fsm_d = self._mixed_carry_jit(
+                            self.params,
+                            tokens_d,
+                            zb,  # use_carry: all rows override from host
+                            zi,  # carry tokens (unused at use_carry=False)
+                            starts_d,
+                            qlens_d,
+                            emits_d,
+                            self.cache,
+                            tables_d,
+                            sub,
+                            temps_d,
+                            top_k_d,
+                            top_p_d,
+                            fsm_mask=fm, fsm_dest=fd,
+                            carry_fsm=zi, ov_fsm=ov_fsm_d,
+                        )
                 self._mixed_gap_stamp = time.perf_counter()
                 sampled = self._pull("ffwd", int(S), ticket, toks_d)
             except Exception:
@@ -2605,7 +2682,7 @@ class Engine:
 
             decode_out: dict[int, list[int]] = {}
             produced = 0
-            with obs.phase("commit", tick=tick_id):
+            with self._accepting(tick=tick_id):
                 for i, (s, run) in enumerate(rows):
                     accepted: list[int] = []
                     dspan = s.decode_span
@@ -2642,6 +2719,7 @@ class Engine:
                         )
                     decode_out[s.seq_id] = accepted
                     produced += len(accepted)
+            with obs.phase("commit", part="account"):
                 if produced:
                     perf.record_metric("engine.decode_tokens", produced, "tok")
                 self._observe_occupancy()
@@ -2738,13 +2816,19 @@ class Engine:
         return bias
 
     def _pull(
-        self, program: str, bucket: int, ticket: tuple[int, float],
-        out_d: jax.Array,
+        self, program: str, bucket: int, ticket: tuple[int, float, str],
+        out_d: jax.Array, alone: bool = True,
     ) -> np.ndarray:
         """Bring a dispatched step's output to the host: the ``wait`` phase
         (the scheduler thread blocks on the device here) and the step
-        clock's reading of when the step finished."""
-        with obs.phase("wait", tick=ticket[0]):
+        clock's reading of when the step finished. ``alone`` says that
+        nothing is enqueued behind the step pulled (the caller knows its
+        pipeline): the device then idles from the moment the step ends
+        until the next dispatch, and the wait is the part ``alone``, else
+        ``pipelined``."""
+        with obs.phase(
+            "wait", part="alone" if alone else "pipelined", tick=ticket[0]
+        ):
             waited = not out_d.is_ready()
             out = np.asarray(out_d)
             self.step_clock.pulled(program, bucket, ticket, waited)
@@ -2842,7 +2926,9 @@ class Engine:
         dispatch block spans attach under it; closed when the sequence
         finishes). A TTFT past the SLO threshold is a flight-recorder
         anomaly: the ring dump holds the admissions and dispatch
-        compositions of the seconds leading up to the slow first token."""
+        compositions of the seconds leading up to the slow first token.
+        All of it observes: summed into ``commit``'s ``account`` part."""
+        t0 = time.perf_counter()
         obs.TTFT_SECONDS.observe(seq.ttft_s)
         cls = obs.trace.class_of(seq.trace)
         if cls:
@@ -2866,11 +2952,17 @@ class Engine:
                 prompt_tokens=seq.prompt_len,
             )
             seq.decode_span = seq.trace.start_child("decode")
+        self._summed("account", t0)
 
     def _accept_token(self, seq: Sequence, token: int) -> None:
+        """Fold one token into its sequence, in the ``commit`` phase. What
+        recurs a token and is not the accept itself is timed here and
+        summed into parts of its own (``obs.add_part``): the counters and
+        the span's close into ``account``, the stream callback into
+        ``stream``, the stop strings' decode into ``stop_scan``."""
         seq.tokens.append(token)
-        obs.DECODE_TOKENS.inc()
         now = time.perf_counter()
+        obs.DECODE_TOKENS.inc()
         if seq.last_tok_s:
             obs.ITL_SECONDS.observe(now - seq.last_tok_s)
             cls = obs.trace.class_of(seq.trace)
@@ -2878,7 +2970,11 @@ class Engine:
                 obs.CLASS_ITL_SECONDS.observe(
                     now - seq.last_tok_s, **{"class": cls}
                 )
+        else:
+            seq.first_tok_tick = self.sched_tick
         seq.last_tok_s = now
+        seq.last_tok_tick = self.sched_tick
+        self._summed("account", now)
         p = seq.params
         if p.presence_penalty or p.frequency_penalty:
             if seq.penalty_counts is None:
@@ -2887,17 +2983,26 @@ class Engine:
                     seq.penalty_counts[t] = seq.penalty_counts.get(t, 0) + 1
             seq.penalty_counts[token] = seq.penalty_counts.get(token, 0) + 1
         if seq.stream is not None:
-            seq.stream(token)
+            t0 = time.perf_counter()
+            try:
+                seq.stream(token)
+            finally:
+                self._summed("stream", t0)
         if token == self.tokenizer.eos_id:
             seq.done = True
             seq.finish_reason = "stop"
         elif len(seq.tokens) >= seq.params.max_tokens:
             seq.done = True
             seq.finish_reason = "length"
-        elif seq.params.stop and self._hit_stop_string(seq):
-            seq.done = True
-            seq.finish_reason = "stop"
+        elif seq.params.stop:
+            t0 = time.perf_counter()
+            hit = self._hit_stop_string(seq)
+            self._summed("stop_scan", t0)
+            if hit:
+                seq.done = True
+                seq.finish_reason = "stop"
         if seq.done and seq.decode_span is not None:
+            t0 = time.perf_counter()
             obs.attribution.record_goodput(
                 seq.decode_span.duration_s(), "decode_active",
                 slo_class=obs.trace.class_of(seq.trace),
@@ -2906,6 +3011,7 @@ class Engine:
                 tokens=len(seq.tokens), finish_reason=seq.finish_reason
             )
             seq.decode_span = None
+            self._summed("account", t0)
 
     def _hit_stop_string(self, seq: Sequence) -> bool:
         """Check the decoded tail for any stop string, so generation halts at
@@ -3050,15 +3156,11 @@ class Engine:
         toks_d, lane_seqs, budgets, counts_d, ticket, program = (
             self._inflight.popleft()
         )
-        perf = get_perf_stats()
-        tick_id, t_disp = ticket
-        t0 = time.perf_counter()
-        toks = self._pull(*program, ticket, toks_d)
+        # alone: no younger block is enqueued behind the one pulled
+        toks = self._pull(
+            *program, ticket, toks_d, alone=not self._inflight)
         counts = None if counts_d is None else np.asarray(counts_d)
-        perf.record_metric(
-            "engine.block_pull", (time.perf_counter() - t0) * 1e3, "ms"
-        )
-        with obs.phase("commit", tick=tick_id):
+        with self._accepting(tick=ticket[0]):
             return self._commit_block(
                 toks, counts, lane_seqs, budgets, ticket
             )
@@ -3069,7 +3171,7 @@ class Engine:
         """Fold one pulled decode block into host state (the commit phase:
         accept, stop scan, detokenize, stream, roll bookings back)."""
         perf = get_perf_stats()
-        tick_id, t_disp = ticket
+        tick_id, t_disp, _ = ticket
         out: dict[int, list[int]] = {}
         produced = 0
         first_exc: BaseException | None = None
@@ -3130,10 +3232,12 @@ class Engine:
                     # Span per pulled block: dispatch -> pull. Blocks of
                     # one sequence overlap under pipeline_depth > 0, which
                     # is the point — the trace shows the pipelining.
+                    t0 = time.perf_counter()
                     dspan.child(
-                        "decode_block", t_disp, time.perf_counter(),
+                        "decode_block", t_disp, t0,
                         tokens=len(accepted), tick=tick_id,
                     )
+                    self._summed("account", t0)
                 if s.done:
                     # Roll pre-booked pages back to written content. Any
                     # still-in-flight dispatch may keep writing to the freed
@@ -3157,8 +3261,10 @@ class Engine:
                     )
                     if self.alloc.length(sid) > keep:
                         self.alloc.truncate(sid, keep)
+        t0 = time.perf_counter()
         perf.record_metric("engine.decode_tokens", produced, "tok")
         self._observe_occupancy()
+        obs.add_part("commit", "account", time.perf_counter() - t0)
         if first_exc is not None:
             raise first_exc
         return out
@@ -3249,7 +3355,6 @@ class Engine:
             bias = self._bias_array(slots, B)
             want_lp = any(s.params.logprobs for s in running)
             chosen_lp = top_ids = top_lps = None
-            self._record_attn_pages(write_at, active)
             t_step = time.perf_counter()
             with self.mesh_ctx():
                 # split under the mesh like warmup's, or its eager helper
@@ -3301,37 +3406,40 @@ class Engine:
             )
             out: dict[int, int] = {}
             first_exc: BaseException | None = None
-            for i, s in enumerate(running):
-                tok = int(sampled[i])
-                dspan = s.decode_span
-                if s.params.logprobs:
-                    n = s.params.top_logprobs
-                    s.logprob_data.append({
-                        "logprob": float(chosen_lp[i]),
-                        "top": [
-                            (int(top_ids[i, j]), float(top_lps[i, j]))
-                            for j in range(min(n, top_ids.shape[1]))
-                        ],
-                    })
-                try:
-                    self._accept_token(s, tok)
-                except Exception as e:  # noqa: BLE001 - raising stream cb
-                    # Isolate the disconnected client: only ITS sequence
-                    # errors (same contract as _pull_oldest); the rest of
-                    # the batch keeps its tokens.
-                    if first_exc is None:
-                        first_exc = e
-                    s.done = True
-                    s.finish_reason = s.finish_reason or "error"
-                    self.alloc.truncate(s.seq_id, self._host_written(s))
-                # _accept_token appends before the callback runs, so even
-                # an errored sequence's token is in seq.tokens (and in what
-                # finish() returns) — report it, matching _pull_oldest.
-                out[s.seq_id] = tok
-                if dspan is not None:
-                    dspan.child(
-                        "decode_step", t_step, time.perf_counter(), tokens=1
-                    )
+            with self._accepting():
+                for i, s in enumerate(running):
+                    tok = int(sampled[i])
+                    dspan = s.decode_span
+                    if s.params.logprobs:
+                        n = s.params.top_logprobs
+                        s.logprob_data.append({
+                            "logprob": float(chosen_lp[i]),
+                            "top": [
+                                (int(top_ids[i, j]), float(top_lps[i, j]))
+                                for j in range(min(n, top_ids.shape[1]))
+                            ],
+                        })
+                    try:
+                        self._accept_token(s, tok)
+                    except Exception as e:  # noqa: BLE001 - raising stream cb
+                        # Isolate the disconnected client: only ITS sequence
+                        # errors (same contract as _pull_oldest); the rest of
+                        # the batch keeps its tokens.
+                        if first_exc is None:
+                            first_exc = e
+                        s.done = True
+                        s.finish_reason = s.finish_reason or "error"
+                        self.alloc.truncate(s.seq_id, self._host_written(s))
+                    # _accept_token appends before the callback runs, so
+                    # even an errored sequence's token is in seq.tokens (and
+                    # in what finish() returns) — report it, matching
+                    # _pull_oldest.
+                    out[s.seq_id] = tok
+                    if dspan is not None:
+                        dspan.child(
+                            "decode_step", t_step, time.perf_counter(),
+                            tokens=1,
+                        )
             get_perf_stats().record_metric("engine.decode_tokens", len(running), "tok")
             self._observe_occupancy()
             if first_exc is not None:
@@ -3354,58 +3462,59 @@ class Engine:
         with self.lock:
             self._async_settle()
             self._mixed_gap_stamp = None  # see step(): gap continuity ends
-            running = [
-                s for s in self.sequences.values() if not s.done
-            ] if seq_ids is None else [
-                self.sequences[i] for i in seq_ids if not self.sequences[i].done
-            ]
-            running = running[: self.cfg.max_batch_size]
-            block = self.cfg.decode_block
-            # Constrained rows whose FSM fits the device-table budget ride
-            # the PIPELINED block: the grammar mask is a [B, V] table
-            # gather per step and the DFA state advances on device — no
-            # host sync per token (SURVEY §7's hard part). One shared
-            # table set per dispatch; seated fsm lanes pin the choice, and
-            # rows with a different schema fall back to host stepping.
-            from .constrained import JsonConstraint
+            with obs.phase("plan", part="rows"):
+                running = [
+                    s for s in self.sequences.values() if not s.done
+                ] if seq_ids is None else [
+                    self.sequences[i] for i in seq_ids if not self.sequences[i].done
+                ]
+                running = running[: self.cfg.max_batch_size]
+                block = self.cfg.decode_block
+                # Constrained rows whose FSM fits the device-table budget ride
+                # the PIPELINED block: the grammar mask is a [B, V] table
+                # gather per step and the DFA state advances on device — no
+                # host sync per token (SURVEY §7's hard part). One shared
+                # table set per dispatch; seated fsm lanes pin the choice, and
+                # rows with a different schema fall back to host stepping.
+                from .constrained import JsonConstraint
 
-            fsm_obj = None
-            for sid in self._lanes:
-                s = self.sequences.get(sid) if sid is not None else None
-                if (
-                    s is not None and not s.done
-                    and isinstance(s.mask_fn, JsonConstraint)
-                ):
-                    fsm_obj = s.mask_fn.fsm
-                    break
+                fsm_obj = None
+                for sid in self._lanes:
+                    s = self.sequences.get(sid) if sid is not None else None
+                    if (
+                        s is not None and not s.done
+                        and isinstance(s.mask_fn, JsonConstraint)
+                    ):
+                        fsm_obj = s.mask_fn.fsm
+                        break
 
-            def fsm_ok(s):
-                nonlocal fsm_obj
-                if (
-                    not isinstance(s.mask_fn, JsonConstraint)
-                    or s.params.logprobs
-                    or self._needs_bias(s)
-                    or s.mask_fn.fsm.dense_tables() is None
-                ):
-                    return False
-                if fsm_obj is None:
-                    fsm_obj = s.mask_fn.fsm
-                    return True
-                return s.mask_fn.fsm is fsm_obj
+                def fsm_ok(s):
+                    nonlocal fsm_obj
+                    if (
+                        not isinstance(s.mask_fn, JsonConstraint)
+                        or s.params.logprobs
+                        or self._needs_bias(s)
+                        or s.mask_fn.fsm.dense_tables() is None
+                    ):
+                        return False
+                    if fsm_obj is None:
+                        fsm_obj = s.mask_fn.fsm
+                        return True
+                    return s.mask_fn.fsm is fsm_obj
 
-            # Host-stepped rows: non-FSM constrained masks need a
-            # host-computed logits mask per token; logprob rows need
-            # per-token device pulls the pipelined block does not surface;
-            # biased rows need the bias rebuilt per token.
-            def hosted(s):
-                return (
-                    (s.mask_fn is not None and not fsm_ok(s))
-                    or s.params.logprobs
-                    or self._needs_bias(s)
-                )
+                # Host-stepped rows: non-FSM constrained masks need a
+                # host-computed logits mask per token; logprob rows need
+                # per-token device pulls the pipelined block does not surface;
+                # biased rows need the bias rebuilt per token.
+                def hosted(s):
+                    return (
+                        (s.mask_fn is not None and not fsm_ok(s))
+                        or s.params.logprobs
+                        or self._needs_bias(s)
+                    )
 
-            masked = [s for s in running if hosted(s)]
-            plain = [s for s in running if not hosted(s)]
+                masked = [s for s in running if hosted(s)]
+                plain = [s for s in running if not hosted(s)]
             if running and (block <= 1 or (masked and not plain)):
                 return {
                     sid: [tok]
@@ -3427,293 +3536,303 @@ class Engine:
                 })
                 plain = [s for s in plain if not s.done]
             B = self.cfg.max_batch_size
-            # Lane sync: free lanes of finished sequences, then seat newly
-            # running ones. A lane holds its sequence for its whole life, so
-            # the device carry stays valid across dispatches.
-            for lane, sid in enumerate(self._lanes):
-                if sid is None:
-                    continue
-                s = self.sequences.get(sid)
-                if s is None or s.done:
-                    self._lanes[lane] = None
-                    self._lane_of.pop(sid, None)
-            override = np.zeros((B,), bool)
-            ov_tok = np.zeros((B,), np.int32)
-            ov_at = np.zeros((B,), np.int32)
-            ov_fsm = np.zeros((B,), np.int32)  # 0 = FREE sentinel row
-            for s in plain:
-                if s.seq_id in self._lane_of:
-                    continue
-                try:
-                    lane = self._lanes.index(None)
-                except ValueError:
-                    break  # more running sequences than lanes: they wait
-                self._lanes[lane] = s.seq_id
-                self._lane_of[s.seq_id] = lane
-                override[lane] = True
-                ov_tok[lane] = s.tokens[-1] if s.tokens else self.tokenizer.bos_id
-                # Invariant at (re)seating: alloc.length == written tokens.
-                ov_at[lane] = self.alloc.length(s.seq_id)
-                if isinstance(s.mask_fn, JsonConstraint):
-                    # Walk the DFA over what this row generated so far;
-                    # +1 because device-table row 0 is the FREE sentinel.
-                    fsm = s.mask_fn.fsm
-                    st = fsm.dfa.start
-                    for t in s.tokens:
-                        if t != fsm.eos_id:
-                            st = fsm.advance(st, t)
-                    ov_fsm[lane] = st + 1
-            # Book pages for up to one block per lane; budgets account for
-            # still-in-flight dispatches so max_tokens is never overshot.
-            # Seated lanes OUTSIDE the caller's seq_ids filter keep their
-            # device carry but get no budget — they do not advance.
-            requested = {s.seq_id for s in plain}
-            alive = np.zeros((B,), bool)
-            budgets = np.zeros((B,), np.int32)
-            lane_seqs: list[int | None] = [None] * B
-            for lane, sid in enumerate(self._lanes):
-                if sid is None:
-                    continue
-                if sid not in requested:
-                    alive[lane] = True
-                    lane_seqs[lane] = sid
-                    continue
-                s = self.sequences[sid]
-                want = min(
-                    block,
-                    s.params.max_tokens - len(s.tokens)
-                    - self._inflight_steps.get(sid, 0),
-                )
-                if want <= 0:
-                    # Budget fully covered by in-flight blocks: keep the
-                    # lane seated, dispatch nothing for it.
-                    alive[lane] = True
-                    lane_seqs[lane] = sid
-                    continue
-                got = self.alloc.extend_upto(sid, want)
-                if got == 0:
-                    # Page pool dry. Before killing the row, drain the
-                    # pipeline: its in-flight blocks may hold legitimately
-                    # generated tokens for this sequence (discarding them
-                    # would truncate the response early), and their pulls
-                    # roll back other finished rows' pages — which can make
-                    # this extend succeed after all.
-                    while self._inflight:
-                        _merge_pulls(out, self._pull_oldest())
-                    # The drain may have finished sequences whose lanes were
-                    # already budgeted earlier in this loop — zero them so
-                    # the dispatch does not resurrect dead rows.
-                    for lx, sx in enumerate(lane_seqs):
-                        if sx is not None and (
-                            sx not in self.sequences or self.sequences[sx].done
-                        ):
-                            alive[lx] = False
-                            budgets[lx] = 0
-                            lane_seqs[lx] = None
-                    if s.done:
-                        continue  # drained blocks finished it (EOS/stop)
-                    got = self.alloc.extend_upto(sid, want)
-                if got == 0:
-                    s.done = True
-                    s.finish_reason = "length"
-                    obs.PREEMPTIONS.inc()
-                    obs.flight.record("preemption", seq_id=sid)
-                    self.alloc.truncate(sid, self._host_written(s))
-                    self._free_lane(sid)
-                    override[lane] = False
-                    log.warning(
-                        "seq %d truncated: KV page budget exhausted", sid
+            with obs.phase("plan", part="lanes"):
+                # Lane sync: free lanes of finished sequences, then seat
+                # newly running ones. A lane holds its sequence for its
+                # whole life, so the device carry stays valid across
+                # dispatches.
+                for lane, sid in enumerate(self._lanes):
+                    if sid is None:
+                        continue
+                    s = self.sequences.get(sid)
+                    if s is None or s.done:
+                        self._lanes[lane] = None
+                        self._lane_of.pop(sid, None)
+                override = np.zeros((B,), bool)
+                ov_tok = np.zeros((B,), np.int32)
+                ov_at = np.zeros((B,), np.int32)
+                ov_fsm = np.zeros((B,), np.int32)  # 0 = FREE sentinel row
+                for s in plain:
+                    if s.seq_id in self._lane_of:
+                        continue
+                    try:
+                        lane = self._lanes.index(None)
+                    except ValueError:
+                        break  # more running sequences than lanes: they wait
+                    self._lanes[lane] = s.seq_id
+                    self._lane_of[s.seq_id] = lane
+                    override[lane] = True
+                    ov_tok[lane] = (
+                        s.tokens[-1] if s.tokens else self.tokenizer.bos_id)
+                    # Invariant at (re)seating: alloc.length == written tokens.
+                    ov_at[lane] = self.alloc.length(s.seq_id)
+                    if isinstance(s.mask_fn, JsonConstraint):
+                        # Walk the DFA over what this row generated so far;
+                        # +1 because device-table row 0 is the FREE sentinel.
+                        fsm = s.mask_fn.fsm
+                        st = fsm.dfa.start
+                        for t in s.tokens:
+                            if t != fsm.eos_id:
+                                st = fsm.advance(st, t)
+                        ov_fsm[lane] = st + 1
+                # Book pages for up to one block per lane; budgets account for
+                # still-in-flight dispatches so max_tokens is never overshot.
+                # Seated lanes OUTSIDE the caller's seq_ids filter keep their
+                # device carry but get no budget — they do not advance.
+                requested = {s.seq_id for s in plain}
+                alive = np.zeros((B,), bool)
+                budgets = np.zeros((B,), np.int32)
+                lane_seqs: list[int | None] = [None] * B
+                for lane, sid in enumerate(self._lanes):
+                    if sid is None:
+                        continue
+                    if sid not in requested:
+                        alive[lane] = True
+                        lane_seqs[lane] = sid
+                        continue
+                    s = self.sequences[sid]
+                    want = min(
+                        block,
+                        s.params.max_tokens - len(s.tokens)
+                        - self._inflight_steps.get(sid, 0),
                     )
-                    continue
-                alive[lane] = True
-                budgets[lane] = got
-                lane_seqs[lane] = sid
-                at = self.alloc.length(sid)
-                self.alloc.note_pass(sid, at - got, at, each_token=True)
+                    if want <= 0:
+                        # Budget fully covered by in-flight blocks: keep the
+                        # lane seated, dispatch nothing for it.
+                        alive[lane] = True
+                        lane_seqs[lane] = sid
+                        continue
+                    got = self.alloc.extend_upto(sid, want)
+                    if got == 0:
+                        # Page pool dry. Before killing the row, drain the
+                        # pipeline: its in-flight blocks may hold legitimately
+                        # generated tokens for this sequence (discarding them
+                        # would truncate the response early), and their pulls
+                        # roll back other finished rows' pages — which can make
+                        # this extend succeed after all.
+                        while self._inflight:
+                            _merge_pulls(out, self._pull_oldest())
+                        # The drain may have finished sequences whose lanes
+                        # were already budgeted earlier in this loop — zero
+                        # them so the dispatch does not resurrect dead rows.
+                        for lx, sx in enumerate(lane_seqs):
+                            if sx is not None and (
+                                sx not in self.sequences
+                                or self.sequences[sx].done
+                            ):
+                                alive[lx] = False
+                                budgets[lx] = 0
+                                lane_seqs[lx] = None
+                        if s.done:
+                            continue  # drained blocks finished it (EOS/stop)
+                        got = self.alloc.extend_upto(sid, want)
+                    if got == 0:
+                        s.done = True
+                        s.finish_reason = "length"
+                        obs.PREEMPTIONS.inc()
+                        obs.flight.record("preemption", seq_id=sid)
+                        self.alloc.truncate(sid, self._host_written(s))
+                        self._free_lane(sid)
+                        override[lane] = False
+                        log.warning(
+                            "seq %d truncated: KV page budget exhausted", sid
+                        )
+                        continue
+                    alive[lane] = True
+                    budgets[lane] = got
+                    lane_seqs[lane] = sid
+                    at = self.alloc.length(sid)
+                    self.alloc.note_pass(sid, at - got, at, each_token=True)
             if not budgets.any():
                 # Nothing to dispatch; a pull still guarantees progress.
                 if self._inflight:
                     _merge_pulls(out, self._pull_oldest())
                 return out
-            table, _, _ = self.alloc.batch_views(lane_seqs, B)
-            slots = [
-                self.sequences.get(sid) if sid is not None else None
-                for sid in lane_seqs
-            ]
-            temps, top_k, top_p, _ = self._sampling_arrays(slots, B)
-            greedy = bool(np.all(temps <= 0.0))
-            # A constrained row that failed to get a lane (batch full) must
-            # not force FSM tables (and disable speculation) on a dispatch
-            # where no SEATED row is constrained — it isn't advancing
-            # anyway. Re-derive from what actually seated.
-            if fsm_obj is not None and not any(
-                isinstance(
-                    getattr(self.sequences.get(sid), "mask_fn", None),
-                    JsonConstraint,
-                )
-                for sid in self._lanes
-                if sid is not None
-            ):
-                fsm_obj = None
-            if self._carry is None:
-                # Under mesh_ctx like every other eager helper: the zeros/
-                # split programs recompile per mesh-context depth otherwise.
-                with self.mesh_ctx():
-                    # Fork the decode-loop PRNG stream off the admission
-                    # stream so per-step sampling never reuses an
-                    # admission key.
-                    self._sample_key, carry_key = jax.random.split(
-                        self._sample_key
-                    )
-                    # Distinct arrays: the donated args must be distinct
-                    # buffers (donating the same one twice is an error).
-                    self._carry = (
-                        jnp.zeros((B,), jnp.int32),
-                        jnp.zeros((B,), jnp.int32),
-                        jnp.zeros((B,), bool),
-                        jnp.zeros((B,), jnp.int32),  # device FSM (0=free)
-                        carry_key,
-                    )
-            c_tok, c_at, c_eos, c_fsm, c_key = self._carry
-            perf = get_perf_stats()
-            speculate = (
-                self.cfg.speculative_k > 0 and greedy and fsm_obj is None
-            )
-            counts = None
-            if fsm_obj is not None:
-                fsm_mask_d, fsm_dest_d = self._fsm_device_tables(fsm_obj)
-            else:
-                fsm_mask_d = fsm_dest_d = None
-            if speculate:
-                # Host history for newly seated lanes, prepared OUTSIDE the
-                # dispatch timing block. Drafting is advisory (a stale row
-                # only costs draft quality), and the common all-False
-                # override case reuses one cached device-resident zeros
-                # array instead of transferring B x H zeros per block.
-                H = self.cfg.max_pages_per_seq * self.cfg.page_size
-                if self._hist is None:
-                    self._hist = jnp.zeros((B, H), jnp.int32)
-                if override.any():
-                    ov_hist = np.zeros((B, H), np.int32)
-                    for lane, flag in enumerate(override):
-                        if not flag:
-                            continue
-                        s = self.sequences.get(self._lanes[lane])
-                        if s is None:
-                            continue
-                        ids_h = (s.prompt_ids + s.tokens)[:H]
-                        ov_hist[lane, : len(ids_h)] = ids_h
-                    ov_hist_dev = jnp.asarray(ov_hist)
-                else:
-                    if self._ov_hist_zeros is None:
-                        self._ov_hist_zeros = jnp.zeros((B, H), jnp.int32)
-                    ov_hist_dev = self._ov_hist_zeros
-            if not speculate:
-                # Pass j of the fused block: a lane with budget above j
-                # has one query at its length before the block + j.
-                steps = np.arange(self.cfg.decode_block)[:, None]
-                before = np.array([
-                    0 if sid is None else self.alloc.length(sid)
+            with self._building_arrays():
+                table, _, _ = self.alloc.batch_views(lane_seqs, B)
+                slots = [
+                    self.sequences.get(sid) if sid is not None else None
                     for sid in lane_seqs
-                ]) - budgets
-                self._record_attn_pages(before + steps, budgets > steps)
-            ticket = self.step_clock.enqueue()
-            tick_id, t_disp = ticket
-            with obs.phase("dispatch", tick=tick_id), \
-                    annotate("engine.decode_block"), self.mesh_ctx():
-                if speculate:
-                    toks, counts, self.cache, carry = (
-                        self._spec_pipeline_jit(
-                            self.params,
-                            c_tok, c_at, c_eos, self._hist,
-                            jnp.asarray(override),
-                            jnp.asarray(ov_tok),
-                            jnp.asarray(ov_at),
-                            ov_hist_dev,
-                            jnp.asarray(alive),
-                            jnp.asarray(budgets),
-                            self.cache,
-                            jnp.asarray(table),
+                ]
+                temps, top_k, top_p, _ = self._sampling_arrays(slots, B)
+                greedy = bool(np.all(temps <= 0.0))
+                # A constrained row that failed to get a lane (batch full) must
+                # not force FSM tables (and disable speculation) on a dispatch
+                # where no SEATED row is constrained — it isn't advancing
+                # anyway. Re-derive from what actually seated.
+                if fsm_obj is not None and not any(
+                    isinstance(
+                        getattr(self.sequences.get(sid), "mask_fn", None),
+                        JsonConstraint,
+                    )
+                    for sid in self._lanes
+                    if sid is not None
+                ):
+                    fsm_obj = None
+                if self._carry is None:
+                    # Under mesh_ctx like every other eager helper: the zeros/
+                    # split programs recompile per mesh-context depth
+                    # otherwise.
+                    with self.mesh_ctx():
+                        # Fork the decode-loop PRNG stream off the admission
+                        # stream so per-step sampling never reuses an
+                        # admission key.
+                        self._sample_key, carry_key = jax.random.split(
+                            self._sample_key
                         )
-                    )
-                    n_tok, n_at, n_eos, self._hist = carry
-                    self._carry = (n_tok, n_at, n_eos, c_fsm, c_key)
+                        # Distinct arrays: the donated args must be distinct
+                        # buffers (donating the same one twice is an error).
+                        self._carry = (
+                            jnp.zeros((B,), jnp.int32),
+                            jnp.zeros((B,), jnp.int32),
+                            jnp.zeros((B,), bool),
+                            jnp.zeros((B,), jnp.int32),  # device FSM (0=free)
+                            carry_key,
+                        )
+                c_tok, c_at, c_eos, c_fsm, c_key = self._carry
+                perf = get_perf_stats()
+                speculate = (
+                    self.cfg.speculative_k > 0 and greedy and fsm_obj is None
+                )
+                counts = None
+                if fsm_obj is not None:
+                    fsm_mask_d, fsm_dest_d = self._fsm_device_tables(fsm_obj)
                 else:
-                    toks, self.cache, carry = self._decode_pipeline_jit(
-                        self.params,
-                        c_tok, c_at, c_eos, c_key,
-                        jnp.asarray(override),
-                        jnp.asarray(ov_tok),
-                        jnp.asarray(ov_at),
-                        jnp.asarray(alive),
-                        jnp.asarray(budgets),
-                        self.cache,
-                        jnp.asarray(table),
-                        jnp.asarray(temps),
-                        jnp.asarray(top_k),
-                        jnp.asarray(top_p),
-                        greedy=greedy,
-                        fsm_mask=fsm_mask_d,
-                        fsm_dest=fsm_dest_d,
-                        carry_fsm=c_fsm,
-                        ov_fsm=jnp.asarray(ov_fsm),
-                    )
-                    n_tok, n_at, n_eos, n_fsm, n_key = carry
-                    self._carry = (n_tok, n_at, n_eos, n_fsm, n_key)
-            perf.record_metric(
-                "engine.block_dispatch", (time.perf_counter() - t_disp) * 1e3,
-                "ms",
-            )
-            if speculate:
-                # Observability for the speculative path (also the signal
-                # tests use to prove speculation actually engaged).
-                perf.record_metric("engine.spec_blocks", 1, "blk")
-            from .decode_loop import record_dispatch
+                    fsm_mask_d = fsm_dest_d = None
+                if speculate:
+                    # Host history for newly seated lanes, prepared OUTSIDE the
+                    # dispatch timing block. Drafting is advisory (a stale row
+                    # only costs draft quality), and the common all-False
+                    # override case reuses one cached device-resident zeros
+                    # array instead of transferring B x H zeros per block.
+                    H = self.cfg.max_pages_per_seq * self.cfg.page_size
+                    if self._hist is None:
+                        self._hist = jnp.zeros((B, H), jnp.int32)
+                    if override.any():
+                        ov_hist = np.zeros((B, H), np.int32)
+                        for lane, flag in enumerate(override):
+                            if not flag:
+                                continue
+                            s = self.sequences.get(self._lanes[lane])
+                            if s is None:
+                                continue
+                            ids_h = (s.prompt_ids + s.tokens)[:H]
+                            ov_hist[lane, : len(ids_h)] = ids_h
+                        ov_hist_dev = jnp.asarray(ov_hist)
+                    else:
+                        if self._ov_hist_zeros is None:
+                            self._ov_hist_zeros = jnp.zeros((B, H), jnp.int32)
+                        ov_hist_dev = self._ov_hist_zeros
+            ticket = self.step_clock.enqueue()
+            tick_id, t_disp, _ = ticket
+            with obs.phase("dispatch", tick=tick_id), self.mesh_ctx():
+                with obs.phase("dispatch", part="place"):
+                    (override_d, ov_tok_d, ov_at_d, alive_d, budgets_d,
+                     table_d) = (
+                        jnp.asarray(a) for a in (
+                            override, ov_tok, ov_at, alive, budgets, table))
+                    if not speculate:
+                        temps_d, top_k_d, top_p_d, ov_fsm_d = (
+                            jnp.asarray(a)
+                            for a in (temps, top_k, top_p, ov_fsm))
+                with obs.phase("dispatch", part="call"), \
+                        annotate("engine.decode_block"):
+                    if speculate:
+                        toks, counts, self.cache, carry = (
+                            self._spec_pipeline_jit(
+                                self.params,
+                                c_tok, c_at, c_eos, self._hist,
+                                override_d,
+                                ov_tok_d,
+                                ov_at_d,
+                                ov_hist_dev,
+                                alive_d,
+                                budgets_d,
+                                self.cache,
+                                table_d,
+                            )
+                        )
+                        n_tok, n_at, n_eos, self._hist = carry
+                        self._carry = (n_tok, n_at, n_eos, c_fsm, c_key)
+                    else:
+                        toks, self.cache, carry = self._decode_pipeline_jit(
+                            self.params,
+                            c_tok, c_at, c_eos, c_key,
+                            override_d,
+                            ov_tok_d,
+                            ov_at_d,
+                            alive_d,
+                            budgets_d,
+                            self.cache,
+                            table_d,
+                            temps_d,
+                            top_k_d,
+                            top_p_d,
+                            greedy=greedy,
+                            fsm_mask=fsm_mask_d,
+                            fsm_dest=fsm_dest_d,
+                            carry_fsm=c_fsm,
+                            ov_fsm=ov_fsm_d,
+                        )
+                        n_tok, n_at, n_eos, n_fsm, n_key = carry
+                        self._carry = (n_tok, n_at, n_eos, n_fsm, n_key)
+            with obs.phase("plan", part="account"):
+                perf.record_metric(
+                    "engine.block_dispatch",
+                    (time.perf_counter() - t_disp) * 1e3, "ms",
+                )
+                if speculate:
+                    # Observability for the speculative path (also the
+                    # signal tests use to prove speculation engaged).
+                    perf.record_metric("engine.spec_blocks", 1, "blk")
+                from .decode_loop import record_dispatch
 
-            # Attribution: each budgeted lane writes `b` tokens, step j
-            # attending start+j+1 positions (exact causal sum); the scan
-            # streams the weights once per SCAN STEP regardless of how
-            # few lanes carry budget (inactive lanes ride the stream).
-            attr_q = attr_read = 0
-            for lane, sid in enumerate(lane_seqs):
-                b = int(budgets[lane])
-                if sid is None or b == 0:
-                    continue
-                s0 = max(0, self.alloc.length(sid) - b)
-                attr_q += b
-                attr_read += b * s0 + b * (b + 1) // 2
-            record_dispatch(
-                "spec" if speculate else "block",
-                rows=int(np.count_nonzero(budgets)),
-                steps=int(budgets.max()),
-                attr=self.attr,
-                attr_kw=dict(
-                    weight_streams=(
-                        self._spec_steps if speculate
-                        else self.cfg.decode_block
+                # Attribution: each budgeted lane writes `b` tokens, step
+                # j attending start+j+1 positions (exact causal sum); the
+                # scan streams the weights once per SCAN STEP regardless
+                # of how few lanes carry budget (inactive lanes ride the
+                # stream).
+                attr_q = attr_read = 0
+                for lane, sid in enumerate(lane_seqs):
+                    b = int(budgets[lane])
+                    if sid is None or b == 0:
+                        continue
+                    s0 = max(0, self.alloc.length(sid) - b)
+                    attr_q += b
+                    attr_read += b * s0 + b * (b + 1) // 2
+                record_dispatch(
+                    "spec" if speculate else "block",
+                    rows=int(np.count_nonzero(budgets)),
+                    steps=int(budgets.max()),
+                    attr=self.attr,
+                    attr_kw=dict(
+                        weight_streams=(
+                            self._spec_steps if speculate
+                            else self.cfg.decode_block
+                        ),
+                        q_tokens=attr_q,
+                        kv_read_tokens=attr_read,
+                        kv_write_tokens=attr_q,
+                        attn_q_ctx=attr_read,
                     ),
-                    q_tokens=attr_q,
-                    kv_read_tokens=attr_read,
-                    kv_write_tokens=attr_q,
-                    attn_q_ctx=attr_read,
-                ),
-            )
-            obs.flight.record(
-                "dispatch", op="spec" if speculate else "decode_block",
-                seq_ids=[sid for sid, b in zip(lane_seqs, budgets)
-                         if sid is not None and b],
-                steps=int(budgets.max()), tick=tick_id,
-            )
-            self._inflight.append((
-                toks, lane_seqs, budgets, counts, ticket,
-                ("spec", self._spec_steps) if speculate
-                else ("decode_block", block),
-            ))
-            for sid, b in zip(lane_seqs, budgets):
-                if sid is not None and b:
-                    self._inflight_steps[sid] = (
-                        self._inflight_steps.get(sid, 0) + int(b)
-                    )
+                )
+                obs.flight.record(
+                    "dispatch", op="spec" if speculate else "decode_block",
+                    seq_ids=[sid for sid, b in zip(lane_seqs, budgets)
+                             if sid is not None and b],
+                    steps=int(budgets.max()), tick=tick_id,
+                )
+            with obs.phase("plan", part="book"):
+                self._inflight.append((
+                    toks, lane_seqs, budgets, counts, ticket,
+                    ("spec", self._spec_steps) if speculate
+                    else ("decode_block", block),
+                ))
+                for sid, b in zip(lane_seqs, budgets):
+                    if sid is not None and b:
+                        self._inflight_steps[sid] = (
+                            self._inflight_steps.get(sid, 0) + int(b)
+                        )
             while len(self._inflight) > self.cfg.pipeline_depth:
                 _merge_pulls(out, self._pull_oldest())
             return out
@@ -3955,6 +4074,7 @@ class Engine:
             seq = self.sequences.pop(seq_id)
             self._ffwd_noted.discard(seq_id)
             self.alloc.free(seq_id, tokens=seq.prompt_ids + seq.tokens[:-1])
+            t0 = time.perf_counter()    # the rest observes: reap's account
             obs.flight.record(
                 "finish", seq_id=seq_id, tokens=len(seq.tokens),
                 finish_reason=seq.finish_reason,
@@ -3971,6 +4091,7 @@ class Engine:
                 )
                 seq.decode_span = None
             self._observe_occupancy()
+            obs.add_part("reap", "account", time.perf_counter() - t0)
             return seq.tokens
 
     # -- convenience (tests / bench) ----------------------------------------

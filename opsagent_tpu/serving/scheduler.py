@@ -283,18 +283,32 @@ class Scheduler:
                 req.done.set()
                 continue
             def _begin(r: Request) -> int:
-                faults.maybe_raise(
-                    "sched.out_of_pages", OutOfPages,
-                    "injected OutOfPages storm",
-                )
-                return self.engine.begin_request(
-                    r.prompt_ids,
-                    r.sampling,
-                    mask_fn=r.mask_fn,
-                    stream=r.on_token,
-                    trace=r.trace,
-                    expect_restore=r.parked,
-                )
+                # One opsagent_admission_seconds observation an ATTEMPT: an
+                # admission that ends in OutOfPages is made again on a
+                # later tick, the whole match with it.
+                t0 = time.perf_counter()
+                outcome = "rejected"
+                try:
+                    faults.maybe_raise(
+                        "sched.out_of_pages", OutOfPages,
+                        "injected OutOfPages storm",
+                    )
+                    seq_id = self.engine.begin_request(
+                        r.prompt_ids,
+                        r.sampling,
+                        mask_fn=r.mask_fn,
+                        stream=r.on_token,
+                        trace=r.trace,
+                        expect_restore=r.parked,
+                    )
+                    outcome = "admitted"
+                    return seq_id
+                except OutOfPages:
+                    outcome = "out_of_pages"
+                    raise
+                finally:
+                    obs.ADMISSION_SECONDS.observe(
+                        time.perf_counter() - t0, outcome=outcome)
 
             try:
                 try:
@@ -331,17 +345,18 @@ class Scheduler:
                 continue
             req.seq_id = seq_id
             self._prefilling[seq_id] = req
-            wait_s = now - req.enqueued_s
-            get_perf_stats().record_metric(
-                "scheduler.queue_wait", wait_s * 1e3, "ms"
-            )
-            obs.QUEUE_WAIT_SECONDS.observe(wait_s)
-            obs.attribution.record_goodput(
-                wait_s, "queued",
-                slo_class=obs.trace.class_of(req.trace),
-            )
-            if req.trace is not None:
-                req.trace.child("queue_wait", req.enqueued_s, now)
+            with obs.phase("admit", part="account"):
+                wait_s = now - req.enqueued_s
+                get_perf_stats().record_metric(
+                    "scheduler.queue_wait", wait_s * 1e3, "ms"
+                )
+                obs.QUEUE_WAIT_SECONDS.observe(wait_s)
+                obs.attribution.record_goodput(
+                    wait_s, "queued",
+                    slo_class=obs.trace.class_of(req.trace),
+                )
+                if req.trace is not None:
+                    req.trace.child("queue_wait", req.enqueued_s, now)
         self._waiting = still
 
     def _advance_prefill(self) -> None:
@@ -357,14 +372,15 @@ class Scheduler:
         first = next(iter(self._prefilling))
         batch = [first]
         try:
-            bucket = self.engine.next_prefill_bucket(first)
-            for sid in self._prefilling:
-                if len(batch) >= self.engine.cfg.prefill_batch:
-                    break
-                if sid != first and (
-                    self.engine.next_prefill_bucket(sid) == bucket
-                ):
-                    batch.append(sid)
+            with obs.phase("plan", part="chunks"):
+                bucket = self.engine.next_prefill_bucket(first)
+                for sid in self._prefilling:
+                    if len(batch) >= self.engine.cfg.prefill_batch:
+                        break
+                    if sid != first and (
+                        self.engine.next_prefill_bucket(sid) == bucket
+                    ):
+                        batch.append(sid)
             results = self.engine.prefill_batch(batch)
         except Exception as e:  # noqa: BLE001 - engine cleaned up already
             for sid in batch:
@@ -412,7 +428,8 @@ class Scheduler:
             # pipeline still get the grammar fast-forward below — the
             # async planner only covers rows IT dispatches.
             if getattr(eng.cfg, "grammar_ffwd", False) and self._running:
-                eng.ffwd_step(sorted(self._running))
+                with obs.phase("plan", part="ffwd"):
+                    eng.ffwd_step(sorted(self._running))
             return False
         # Grammar fast-forward (depth-1 sync lane): splice forced-token
         # runs for constrained rows BEFORE the hosted-row bail below routes
@@ -424,12 +441,14 @@ class Scheduler:
         # settle/admission boundaries; the async lane (depth > 1) engages
         # at every plan point it dispatches.
         if getattr(eng.cfg, "grammar_ffwd", False) and self._running:
-            eng.ffwd_step(sorted(self._running))
+            with obs.phase("plan", part="ffwd"):
+                eng.ffwd_step(sorted(self._running))
         if not self._prefilling:
             return False
-        for sid in list(self._running) + list(self._prefilling):
-            if eng.mixed_hosted(sid):
-                return False
+        with obs.phase("plan", part="route"):
+            for sid in list(self._running) + list(self._prefilling):
+                if eng.mixed_hosted(sid):
+                    return False
         decode_ids = sorted(
             sid for sid in self._running
             if sid in eng.sequences and not eng.sequences[sid].done
@@ -498,77 +517,79 @@ class Scheduler:
         no admitting work and nothing in flight (pure decode belongs to
         the block pipeline), or an involved row needs a hosted lane."""
         eng = self.engine
-        # Pick up results committed by internal pipeline settles
-        # (parking, warmup, sync-lane entry points) since the last tick.
-        _, p_out = eng.async_take_results()
-        self._fold_async_prefill(p_out)
-        # Grammar fast-forward keeps dense-table constrained rows in the
-        # async lane even for PURE decode: the planner splices forced
-        # runs at every dispatch point, which the block pipeline (host
-        # token lists stale behind in-flight blocks) cannot do. Per-tick
-        # host overhead loses to block batching only when forced states
-        # are rare — a schema-constrained row is exactly where they are
-        # not.
-        ffwd_decode = (
-            getattr(eng.cfg, "grammar_ffwd", False)
-            and any(
-                sid in eng.sequences and not eng.sequences[sid].done
-                and eng.async_row_fsm(sid) is not None
-                for sid in self._running
-            )
-        )
-        if not self._prefilling and not eng.async_pending() \
-                and not ffwd_decode:
-            return False
-        # Hosted rows (and mixed-schema constrained batches) route the
-        # tick to the sync lanes — settle the pipeline first so the split
-        # path sees current host state.
-        fsm_seen = None
-        for sid in list(self._running) + list(self._prefilling):
-            hosted = eng.mixed_async_hosted(sid)
-            mismatch = False
-            if not hosted:
-                f = eng.async_row_fsm(sid)
-                if f is not None:
-                    if fsm_seen is not None and f is not fsm_seen:
-                        mismatch = True
-                    fsm_seen = f
-            if hosted or mismatch:
-                obs.ASYNC_FALLBACKS.inc(
-                    reason="hosted" if hosted else "fsm_mismatch"
+        with obs.phase("plan", part="route"):
+            # Pick up results committed by internal pipeline settles
+            # (parking, warmup, sync-lane entry points) since the last tick.
+            _, p_out = eng.async_take_results()
+            self._fold_async_prefill(p_out)
+            # Grammar fast-forward keeps dense-table constrained rows in the
+            # async lane even for PURE decode: the planner splices forced
+            # runs at every dispatch point, which the block pipeline (host
+            # token lists stale behind in-flight blocks) cannot do. Per-tick
+            # host overhead loses to block batching only when forced states
+            # are rare — a schema-constrained row is exactly where they are
+            # not.
+            ffwd_decode = (
+                getattr(eng.cfg, "grammar_ffwd", False)
+                and any(
+                    sid in eng.sequences and not eng.sequences[sid].done
+                    and eng.async_row_fsm(sid) is not None
+                    for sid in self._running
                 )
-                if hosted and getattr(eng.cfg, "grammar_ffwd", False):
-                    # A hosted row (host mask / no dense tables /
-                    # logprobs / bias) also cannot fast-forward; the
-                    # distinct reason label separates "can't ffwd" from
-                    # "can't async" (counted once per sequence).
-                    eng.note_ffwd_ineligible(sid)
-                _, p_out = eng.async_drain()
-                self._fold_async_prefill(p_out)
+            )
+            if not self._prefilling and not eng.async_pending() \
+                    and not ffwd_decode:
                 return False
-        decode_ids = sorted(
-            sid for sid in self._running
-            if sid in eng.sequences and not eng.sequences[sid].done
-        )
-        budget = eng.cfg.max_step_tokens - len(decode_ids)
-        rows_left = eng.cfg.max_batch_size - len(decode_ids)
-        cap = eng.cfg.mixed_buckets[-1]
-        chunks: dict[int, int] = {}
-        for sid in self._prefilling:
-            if budget <= 0 or rows_left <= 0:
-                break
-            try:
-                # Progress here is PLAN progress: chunks already in
-                # flight count as done, so a prompt is never re-offered.
-                done, total = eng.prefill_progress(sid)
-            except KeyError:
-                continue  # completion still in flight, or a failure path
-            c = min(total - done, budget, cap)
-            if c <= 0:
-                continue
-            chunks[sid] = c
-            budget -= c
-            rows_left -= 1
+            # Hosted rows (and mixed-schema constrained batches) route the
+            # tick to the sync lanes — settle the pipeline first so the split
+            # path sees current host state.
+            fsm_seen = None
+            for sid in list(self._running) + list(self._prefilling):
+                hosted = eng.mixed_async_hosted(sid)
+                mismatch = False
+                if not hosted:
+                    f = eng.async_row_fsm(sid)
+                    if f is not None:
+                        if fsm_seen is not None and f is not fsm_seen:
+                            mismatch = True
+                        fsm_seen = f
+                if hosted or mismatch:
+                    obs.ASYNC_FALLBACKS.inc(
+                        reason="hosted" if hosted else "fsm_mismatch"
+                    )
+                    if hosted and getattr(eng.cfg, "grammar_ffwd", False):
+                        # A hosted row (host mask / no dense tables /
+                        # logprobs / bias) also cannot fast-forward; the
+                        # distinct reason label separates "can't ffwd" from
+                        # "can't async" (counted once per sequence).
+                        eng.note_ffwd_ineligible(sid)
+                    _, p_out = eng.async_drain()
+                    self._fold_async_prefill(p_out)
+                    return False
+        with obs.phase("plan", part="chunks"):
+            decode_ids = sorted(
+                sid for sid in self._running
+                if sid in eng.sequences and not eng.sequences[sid].done
+            )
+            budget = eng.cfg.max_step_tokens - len(decode_ids)
+            rows_left = eng.cfg.max_batch_size - len(decode_ids)
+            cap = eng.cfg.mixed_buckets[-1]
+            chunks: dict[int, int] = {}
+            for sid in self._prefilling:
+                if budget <= 0 or rows_left <= 0:
+                    break
+                try:
+                    # Progress here is PLAN progress: chunks already in
+                    # flight count as done, so a prompt is never re-offered.
+                    done, total = eng.prefill_progress(sid)
+                except KeyError:
+                    continue  # completion still in flight, or a failure path
+                c = min(total - done, budget, cap)
+                if c <= 0:
+                    continue
+                chunks[sid] = c
+                budget -= c
+                rows_left -= 1
         if not chunks:
             if not ffwd_decode:
                 if not eng.async_pending():
@@ -711,24 +732,31 @@ class Scheduler:
             seq = self.engine.sequences[sid]
             req.finish_reason = seq.finish_reason
             req.logprob_data = req.logprob_data + seq.logprob_data
-            req.tokens = req.generated_prefix + self.engine.finish(sid)
+            with obs.phase("reap", part="finish"):
+                req.tokens = req.generated_prefix + self.engine.finish(sid)
             if req.finish_reason == "error":
                 # The engine terminated this sequence on a raising stream
                 # callback (client went away mid-stream). Only THIS request
                 # fails; the rest of the batch keeps decoding.
                 req.error = "stream callback failed"
-            obs.ENGINE_REQUESTS.inc(
-                outcome="error" if req.error else "completed"
-            )
-            obs.CLASS_REQUESTS.inc(**{
-                "class": obs.trace.class_of(req.trace, "interactive"),
-                "outcome": "error" if req.error else "completed",
-            })
-            if req.error:
-                obs.flight.anomaly(
-                    "request_error", seq_id=sid, error=req.error,
-                    request_id=obs.flight.request_id_of(req.trace),
+            with obs.phase("reap", part="account"):
+                obs.ENGINE_REQUESTS.inc(
+                    outcome="error" if req.error else "completed"
                 )
+                obs.CLASS_REQUESTS.inc(**{
+                    "class": obs.trace.class_of(req.trace, "interactive"),
+                    "outcome": "error" if req.error else "completed",
+                })
+                if len(seq.tokens) > 1:
+                    # the ticks a token takes, summed over requests
+                    obs.REQUEST_DECODE_TICKS.inc(
+                        max(0, seq.last_tok_tick - seq.first_tok_tick))
+                    obs.REQUEST_DECODE_TOKENS.inc(len(seq.tokens) - 1)
+                if req.error:
+                    obs.flight.anomaly(
+                        "request_error", seq_id=sid, error=req.error,
+                        request_id=obs.flight.request_id_of(req.trace),
+                    )
             req.done.set()
 
     def _recover(self) -> None:
@@ -813,12 +841,22 @@ class Scheduler:
                 # The loop is always in exactly one obs.phase (the table
                 # in docs/observability.md): admit, plan, reap and idle
                 # here; the engine carves dispatch, wait and commit out
-                # of plan where it enqueues, blocks on and folds a step.
-                with obs.phase("admit"):
+                # of plan where it enqueues, blocks on and folds a step,
+                # and a dispatch out of admit where an admission restores
+                # a state snapshot (Engine._copy_state). The parts of a
+                # phase are named where the work is (the same table).
+                with obs.phase("admit", part="drain"):
                     self._drain_queue()
+                with obs.phase("admit"):
                     self._try_admit()
                 if self._running or self._prefilling:
                     obs.TICKS.inc()
+                    # One tick's host work: the work phases' seconds since
+                    # the last tick was counted, and the tick number that
+                    # the engine stamps a sequence's tokens with.
+                    obs.TICK_HOST_WORK_SECONDS.observe(obs.take_host_work())
+                    self.engine.sched_tick = (
+                        getattr(self.engine, "sched_tick", 0) + 1)
                     # Only counted with work in flight: idle ticks spin
                     # at an arbitrary rate, which would make hit-count
                     # fault selectors wall-clock-dependent.

@@ -674,26 +674,15 @@ def test_nothing_in_the_package_reads_a_variable_that_names_a_reader():
     assert found == []
 
 
-def test_an_engine_on_the_cpu_runs_the_gather_and_counts_its_pages(engine):
-    """The default on this platform, what ``impl_info`` says of it, and
-    the two counters that put the live share of the page tables'
-    capacity a scrape away."""
-    from opsagent_tpu import obs
-
+def test_an_engine_on_the_cpu_runs_the_gather(engine):
+    """The default on this platform, and what ``impl_info`` says of it.
+    (The two page counters this test also read, made for the gather over
+    the padded capacity, went with PR 38: nothing read them.)"""
     info = engine.impl_info()
     assert (info["platform"], info["attn_impl"]) == ("cpu", "xla")
-
-    def read():
-        return (
-            obs.ATTN_PAGES_STREAMED.value(), obs.ATTN_PAGES_CAPACITY.value()
-        )
-
-    s0, c0 = read()
-    engine.generate([[257, 5, 6, 7, 8, 9]], SamplingParams(max_tokens=6))
-    s1, c1 = read()
-    assert 0 < s1 - s0 < c1 - c0
-    # every pass counts its rows' whole tables: rows x max_pages_per_seq
-    assert (c1 - c0) % 16 == 0
+    out = engine.generate(
+        [[257, 5, 6, 7, 8, 9]], SamplingParams(max_tokens=6))
+    assert len(out[0]) == 6
 
 
 def _gather_and_kernel(stream_kernel, run, model_cfg=None, **cfg):

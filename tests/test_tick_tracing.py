@@ -397,3 +397,301 @@ def test_one_tick_id_joins_the_flight_event_and_the_request_spans(engine):
     dispatched = {e["tick"] for e in obs.flight.get_recorder().snapshot()
                   if e.get("kind") == "dispatch" and "tick" in e}
     assert ticks <= dispatched
+
+
+# -- parts of a phase (PR 38) ---------------------------------------------------
+from opsagent_tpu.obs import tick as tick_mod  # noqa: E402
+from opsagent_tpu.serving import faults  # noqa: E402
+
+
+def part_seconds() -> dict[tuple[str, str], float]:
+    """{(phase, part): seconds} of opsagent_tick_part_seconds_total."""
+    out = {}
+    for key, v in obs.metrics_snapshot().items():
+        m = re.match(
+            r'opsagent_tick_part_seconds_total\{phase="(\w+)",part="(\w+)"\}',
+            key)
+        if m:
+            out[m.groups()] = v
+    return out
+
+
+@pytest.fixture
+def scripted(monkeypatch):
+    """obs/tick.py on a clock the test moves, with annotations that record
+    their opening and closing instead of reaching the profiler."""
+    clock = FakeClock()
+    events: list[tuple[str, str]] = []
+
+    class Annotation:
+        def __init__(self, label, **ids):
+            self.label = label
+
+        def __enter__(self):
+            events.append(("open", self.label))
+
+        def __exit__(self, *exc):
+            events.append(("close", self.label))
+
+    class Time:
+        perf_counter = staticmethod(clock)
+
+    monkeypatch.setattr(tick_mod, "_annotation", Annotation)
+    monkeypatch.setattr(tick_mod, "time", Time)
+    return clock, events
+
+
+def admit_script(clock, parts: bool) -> None:
+    """1 s of ``admit``, 2 s of its ``match`` (with the parts stubbed: of
+    ``admit`` itself), 0.5 s of ``admit`` again."""
+    match = (obs.phase("admit", part="match") if parts
+             else contextlib.nullcontext())
+    with obs.phase("admit"):
+        clock.now += 1.0
+        with match:
+            clock.now += 2.0
+        clock.now += 0.5
+
+
+def test_a_part_suspends_its_phase_and_both_counters_get_its_seconds(scripted):
+    clock, events = scripted
+    totals = []
+    for parts in (False, True):
+        del events[:]
+        p0, m0 = phase_seconds()["admit"], obs.TICK_PART_SECONDS.value(
+            phase="admit", part="match")
+        admit_script(clock, parts)
+        totals.append(phase_seconds()["admit"] - p0)
+        got_part = obs.TICK_PART_SECONDS.value(
+            phase="admit", part="match") - m0
+        assert got_part == pytest.approx(2.0 if parts else 0.0)
+    # the phase's total is what it was with the parts stubbed
+    assert totals == pytest.approx([3.5, 3.5])
+    # the thread was in engine.admit, then in engine.admit.match and NOT in
+    # engine.admit, then in engine.admit again: never in two spans at once
+    assert events == [
+        ("open", "engine.admit"), ("close", "engine.admit"),
+        ("open", "engine.admit.match"), ("close", "engine.admit.match"),
+        ("open", "engine.admit"), ("close", "engine.admit"),
+    ]
+
+
+def test_add_part_sums_without_an_annotation(scripted):
+    clock, events = scripted
+    before = part_seconds()
+    plan0 = phase_seconds()["plan"]
+    with obs.phase("plan", part="arrays"):
+        clock.now += 1.0
+        for _ in range(2):      # a cost a row, timed by the caller
+            obs.add_part("plan", "pages", 0.25)
+    got = {k: v - before.get(k, 0.0) for k, v in part_seconds().items()}
+    # the seconds came out of the part that was open: nothing counts twice
+    assert got[("plan", "pages")] == pytest.approx(0.5)
+    assert got[("plan", "arrays")] == pytest.approx(0.5)
+    assert phase_seconds()["plan"] - plan0 == pytest.approx(1.0)
+    assert [label for _, label in events] == ["engine.plan.arrays"] * 2
+    # under no part the seconds leave the phase's `other`, and with no
+    # phase open the counter still gets them
+    with obs.phase("plan"):
+        clock.now += 1.0
+        obs.add_part("plan", "pages", 0.125)
+    obs.add_part("plan", "pages", 0.125)
+    assert (obs.TICK_PART_SECONDS.value(phase="plan", part="pages")
+            - before.get(("plan", "pages"), 0.0)) == pytest.approx(0.75)
+    assert phase_seconds()["plan"] - plan0 == pytest.approx(2.0)
+    # one tick's host work is what the work phases took since it was asked
+    obs.take_host_work()
+    with obs.phase("commit"):
+        clock.now += 0.3
+        with obs.phase("wait"):
+            clock.now += 5.0
+    assert obs.take_host_work() == pytest.approx(0.3)
+    assert obs.take_host_work() == 0.0
+
+
+def test_the_parts_of_a_driven_engine_partition_their_phases(
+        engine, monkeypatch):
+    """Over a scheduler run: the parts of each phase never add up to more
+    than the phase (what is left is its ``other``), the phases still
+    partition the loop, ``wait`` is ``alone`` exactly when nothing is
+    enqueued behind the step pulled, one observation of a tick's host work
+    a tick, and the ticks a token takes are counted for finished requests."""
+    pulls = []
+    real_pull = Engine._pull
+
+    def spy(self, program, bucket, ticket, out_d, alone=True):
+        # what is enqueued behind the step pulled: a younger async tick, a
+        # younger block; a prefill chunk's pull follows its own dispatch
+        behind = {"mixed": len(self._async._pending),
+                  "decode_block": len(self._inflight)}.get(program, 0)
+        pulls.append((program, alone, behind))
+        return real_pull(self, program, bucket, ticket, out_d, alone=alone)
+
+    sched = Scheduler(engine)
+    sched.start()
+    try:
+        run_requests(sched, 2)          # compiles outside the measured run
+        time.sleep(0.12)
+        monkeypatch.setattr(Engine, "_pull", spy)
+        parts0, phases0, t0 = part_seconds(), phase_seconds(), time.perf_counter()
+        ticks0 = obs.TICKS.value()
+        work0 = obs.TICK_HOST_WORK_SECONDS.count()
+        dt0 = (obs.REQUEST_DECODE_TICKS.value(),
+               obs.REQUEST_DECODE_TOKENS.value())
+        # prompts the trie has not seen, several chunks long: the async
+        # lane runs ticks back to back, a younger one behind each pull
+        reqs = [
+            Request([(53 * i + 3 * j) % 199 + 5 for j in range(60)],
+                    SamplingParams(max_tokens=16, temperature=0.0))
+            for i in range(1, 5)
+        ]
+        for r in reqs:
+            sched.submit(r)
+        for r in reqs:
+            assert r.done.wait(300) and not r.error, r.error
+        time.sleep(0.12)
+        parts1, phases1 = part_seconds(), phase_seconds()
+        wall = time.perf_counter() - t0
+    finally:
+        monkeypatch.undo()
+        sched.stop()
+    phases = {p: phases1[p] - phases0[p] for p in obs.TICK_PHASES}
+    assert sum(phases.values()) == pytest.approx(wall, rel=0.05, abs=0.06)
+    parts: dict[str, dict[str, float]] = {}
+    for (phase, part), v in parts1.items():
+        parts.setdefault(phase, {})[part] = v - parts0.get((phase, part), 0.0)
+    for phase, rows in parts.items():
+        assert all(v >= 0 for v in rows.values()), (phase, rows)
+        other = phases[phase] - sum(rows.values())
+        # (the snapshot rounds each part to a microsecond)
+        assert other >= -1e-5, (phase, rows, phases[phase])
+    # the parts the records point at are there, where the work is
+    for phase, part in [
+        ("admit", "drain"), ("admit", "match"), ("admit", "alloc"),
+        ("admit", "register"), ("admit", "account"), ("plan", "route"),
+        ("plan", "chunks"), ("plan", "rows"), ("plan", "lanes"),
+        ("plan", "arrays"), ("plan", "pages"), ("plan", "account"),
+        ("plan", "book"), ("dispatch", "place"), ("dispatch", "call"),
+        ("commit", "accept"), ("commit", "account"), ("reap", "finish"),
+        ("reap", "account"),
+    ]:
+        assert parts[phase][part] > 0, (phase, part)
+    # a wait is one part or the other, and wholly so
+    assert set(parts["wait"]) <= {"alone", "pipelined"}
+    assert sum(parts["wait"].values()) == pytest.approx(
+        phases["wait"], abs=1e-5)
+    # dispatch is place and call and a sliver between them
+    assert sum(parts["dispatch"].values()) >= 0.8 * phases["dispatch"]
+    # alone exactly when nothing is enqueued behind the step pulled
+    assert pulls and all(alone == (behind == 0) for _, alone, behind in pulls)
+    assert {alone for program, alone, _ in pulls if program == "mixed"} == {
+        True, False}
+    # one observation of a tick's host work for each tick counted
+    ticks = obs.TICKS.value() - ticks0
+    assert ticks > 0
+    assert obs.TICK_HOST_WORK_SECONDS.count() - work0 == ticks
+    # every finished request's intervals are counted, and a tick gives a
+    # row a token or, in a fused block, several: never fewer ticks than
+    # none, never more than the run had
+    tokens = sum(len(r.tokens) - 1 for r in reqs)
+    assert obs.REQUEST_DECODE_TOKENS.value() - dt0[1] == tokens
+    assert 0 < obs.REQUEST_DECODE_TICKS.value() - dt0[0] <= ticks * len(reqs)
+
+
+def test_one_admission_observation_an_attempt_by_outcome(engine):
+    def counts():
+        return {o: obs.ADMISSION_SECONDS.count(outcome=o)
+                for o in ("admitted", "out_of_pages", "rejected")}
+
+    sched = Scheduler(engine)
+    sched.start()
+    before = counts()
+    try:
+        faults.configure("sched.out_of_pages@1..3")
+        ok = sched.submit(
+            Request(list(range(5, 15)), SamplingParams(max_tokens=3)))
+        assert ok.done.wait(120) and not ok.error, ok.error
+        faults.reset()
+        # a prompt past the model's window is refused at begin_request
+        bad = sched.submit(Request(
+            [5] * (engine.model_cfg.max_position + 1),
+            SamplingParams(max_tokens=3)))
+        assert bad.done.wait(120) and bad.error
+    finally:
+        faults.reset()
+        sched.stop()
+    got = {o: n - before[o] for o, n in counts().items()}
+    # three attempts ended in the injected OutOfPages and were made again,
+    # the fourth admitted: attempts, not one long wait
+    assert got == {"admitted": 1, "out_of_pages": 3, "rejected": 1}
+
+
+def test_ticks_per_token_is_one_for_a_plain_lane_and_under_for_a_fast_forward(
+        engine):
+    """The scheduler's reap adds a finished request's ticks and tokens from
+    the stamps ``_accept_token`` left: a lane that gets one token a tick
+    reads 1, one whose dispatch appended a forced run reads under 1."""
+    from opsagent_tpu.serving.engine import Sequence
+
+    sched = Scheduler(engine)       # never started: the test is the loop
+
+    def lane(arrivals: list[int]) -> float:
+        """A request whose tokens arrive at the given ticks, reaped."""
+        sid = engine.begin_request(
+            list(range(5, 12)), SamplingParams(max_tokens=len(arrivals)))
+        seq = engine.sequences[sid]
+        assert isinstance(seq, Sequence)
+        engine._prefilling.pop(sid, None)
+        for at in arrivals:
+            engine.sched_tick = at
+            engine._accept_token(seq, 7)
+        assert seq.done
+        req = Request(list(range(5, 12)), SamplingParams(max_tokens=4))
+        req.seq_id = sid
+        sched._running[sid] = req
+        t0 = obs.REQUEST_DECODE_TICKS.value()
+        n0 = obs.REQUEST_DECODE_TOKENS.value()
+        sched._reap()
+        assert req.done.is_set() and len(req.tokens) == len(arrivals)
+        return ((obs.REQUEST_DECODE_TICKS.value() - t0)
+                / (obs.REQUEST_DECODE_TOKENS.value() - n0))
+
+    tick0 = engine.sched_tick
+    try:
+        assert lane([40, 41, 42, 43, 44]) == pytest.approx(1.0)
+        # a forced run of three lands with the token sampled after it
+        assert lane([50, 51, 51, 51, 51, 52]) == pytest.approx(0.4)
+    finally:
+        engine.sched_tick = tick0
+
+
+def test_the_step_clock_labels_a_sample_with_the_width_its_ticket_carries():
+    clock = FakeClock()
+    sc = obs.StepClock(clock=clock)
+    name = "test_width"
+
+    def samples(**labels):
+        return (obs.STEP_DEVICE_SECONDS.count(program=name, **labels),
+                obs.STEP_DEVICE_SECONDS.sum(program=name, **labels))
+
+    clock.now = 1.0
+    narrow = sc.enqueue("128")
+    clock.now = 1.5
+    sc.pulled(name, 16, narrow, True)
+    clock.now = 2.0
+    wide = sc.enqueue("256")
+    clock.now = 2.75
+    sc.pulled(name, 16, wide, True)
+    clock.now = 3.0
+    plain = sc.enqueue()            # a program that is not mixed
+    clock.now = 3.25
+    sc.pulled(name, 16, plain, True)
+    assert narrow[2] == "128" and plain[2] == ""
+    assert samples(bucket="16", width="128") == (1, pytest.approx(0.5))
+    assert samples(bucket="16", width="256") == (1, pytest.approx(0.75))
+    assert samples(bucket="16", width="") == (1, pytest.approx(0.25))
+    # a reader that names fewer labels sums over the rest, as the
+    # benchmark's `client.total` does over a scrape
+    assert samples(bucket="16") == (3, pytest.approx(1.5))
+    assert samples() == (3, pytest.approx(1.5))
+    assert 'width="128"' in obs.get_registry().render()
