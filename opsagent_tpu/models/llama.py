@@ -41,7 +41,9 @@ from ..ops.attention import (
     paged_decode_attention_auto,
     paged_ragged_attention_auto,
     pallas_interpret,
+    token_slots,
     write_kv_pages,
+    write_kv_tokens,
     write_pages,
 )
 from ..ops.linear_attention import (
@@ -881,20 +883,21 @@ def _qkv_flat(
 
 
 def _heads(q, k, v, lp: Params, cfg: ModelConfig):
-    """``_qkv_flat``'s projections split into heads ``[B, S, heads, D]``.
-    Apart from the projections: inside a branch of ``Pack.dense`` the
-    reshape is a layout the chip's compiler chooses for the branch alone
-    (``docs/ARCHITECTURE.md``, "Two widths in the one program")."""
-    B, S, _ = q.shape
+    """``_qkv_flat``'s projections split into heads ``[..., heads, D]``,
+    each by its own leading shape (a packed step's q is rows by then, its
+    k and v still tokens). Apart from the projections: inside a branch of
+    ``Pack.dense`` the reshape is a layout the chip's compiler chooses for
+    the branch alone (``docs/ARCHITECTURE.md``, "Two widths in the one
+    program")."""
     K, D = cfg.num_kv_heads, cfg.head_dim_
-    q = q.reshape(B, S, cfg.num_heads, D)
-    k = k.reshape(B, S, K, D)
+    q = q.reshape(*q.shape[:-1], cfg.num_heads, D)
+    k = k.reshape(*k.shape[:-1], K, D)
     if cfg.qk_norm and not cfg.qk_norm_whole:
         # Qwen3: per-head RMSNorm over head_dim, BEFORE RoPE (the caller
         # applies rope to this function's outputs).
         q = rms_norm(q, lp["qn"], cfg.rms_norm_eps)
         k = rms_norm(k, lp["kn"], cfg.rms_norm_eps)
-    return (q, k, v.reshape(B, S, K, D))
+    return (q, k, v.reshape(*v.shape[:-1], K, D))
 
 
 @scoped("attn_qkv")
@@ -1042,23 +1045,28 @@ def _yarn_q_scale(cfg: ModelConfig) -> float:
 def _qkv_rope(
     x: jax.Array, lp: Params, cfg: ModelConfig, cos, sin,
     pack: "Pack | None" = None,
+    tok_rope: tuple[jax.Array, jax.Array] | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """q/k/v with RoPE applied, dispatched on the attention family. The
     rope tables must be built with ``cfg.rope_dim_`` (the decoupled rope
     part under MLA, the full head otherwise). With ``pack`` the stream is
-    packed tokens and the tables and the caller want rows: the projections
-    run over the packed tokens (``Pack.dense``), RoPE over rows."""
+    packed tokens: the projections run over them (``Pack.dense``) and q
+    ALONE is un-packed to the rows the attention reader takes, with the
+    rows' tables; k and v stay tokens ``[1, T, K, D]`` on their way to
+    their pages (``ops.attention.write_kv_tokens``), k rotated by
+    ``tok_rope``, the tables' entries at the tokens' own positions. (The
+    MLA branch still hands rows back.)"""
     if cfg.mla is not None:
         return _qkv_mla(x if pack is None else pack.rows(x), lp, cfg, cos, sin)
     q, k, v = _dense(pack, lambda a: _qkv_flat(a, lp, cfg), x)
     if pack is not None:
-        q, k, v = pack.rows(q), pack.rows(k), pack.rows(v)
+        q = pack.rows(q)
     q, k, v = _heads(q, k, v, lp, cfg)
     if cos is None:     # no positional embedding (cfg.use_rope false)
         return q, k, v
     return (
         apply_rope(q, cos, sin) * _yarn_q_scale(cfg),
-        apply_rope(k, cos, sin),
+        apply_rope(k, *(tok_rope or (cos, sin))),
         v,
     )
 
@@ -1137,6 +1145,8 @@ class Pack(NamedTuple):
     on the device."""
 
     dst: jax.Array      # [B, S] the token of each slot (T: padding)
+    row: jax.Array      # [T] the row b of each token (B: none)
+    at: jax.Array       # [T] its place s in that row
     src: jax.Array      # [T] the slot b * S + s of each token (B * S: none)
     valid: jax.Array    # [1, T] real tokens
     last: jax.Array     # [B] the token at each row's last valid position
@@ -1156,7 +1166,7 @@ class Pack(NamedTuple):
         row = jnp.sum(t[:, None] >= ends[None, :], axis=1)      # B: none
         at = t - offsets[jnp.minimum(row, B - 1)]
         src = jnp.where(row < B, row * S + at, B * S)
-        return cls(dst, src, (t < ends[-1])[None, :],
+        return cls(dst, row, at, src, (t < ends[-1])[None, :],
                    jnp.clip(ends - 1, 0, T - 1), ends[-1], narrow)
 
     def rows(self, a: jax.Array) -> jax.Array:
@@ -1203,6 +1213,21 @@ def pack_widths(slots: int, step_tokens: int) -> tuple[int, int] | None:
     if not 0 < narrow < slots:
         return None
     return min(step_tokens, slots), narrow
+
+
+def kv_write_form(
+    cfg: ModelConfig, slots: int, step_tokens: int
+) -> tuple[str, int]:
+    """What the mixed program of ``slots`` row slots hands the page write,
+    and the rows its scatter walks a layer: ("tokens", the packed width)
+    where the program packs and keys and values stay in the packed stream
+    (every model but MLA's, whose k and v are made from rows), else
+    ("rows", its slots). ``mixed_step`` writes by it and ``Engine`` counts
+    and reports by it."""
+    widths = pack_widths(slots, step_tokens)
+    if widths is None or cfg.mla is not None:
+        return "rows", slots
+    return "tokens", widths[0]
 
 
 def _dense(pack: Pack | None, fn, *xs: jax.Array):
@@ -1871,9 +1896,15 @@ def mixed_step(
     Where the rows' slots outnumber HALF of ``step_tokens`` the residual
     stream is the tick's tokens packed ``[1, T, d]`` (``Pack``; ``T`` is
     ``step_tokens``, or the slots where those are fewer): embedding, norms,
-    projections and the MLP run over tokens; RoPE, the page write and
-    attention see ``[B, S, H, D]`` rows as before, and a linear-attention
-    or MLA mixer sees rows of the normed stream. The caller holds
+    projections and the MLP run over tokens, and so do k, v, k's RoPE and
+    the page write: keys and values never leave the packed stream, and the
+    write scatters ``T`` tokens, each to its own slot
+    (``write_kv_tokens``: a scatter costs the rows it is handed, written
+    or dropped, not the bytes it moves). Only q is un-packed, to the
+    ``[B, S, H, D]`` rows the attention reader takes, and a
+    linear-attention or MLA mixer sees rows of the normed stream (the
+    latent write too). No conditional holds the cache: tokens past the
+    tick's last are dropped by index. The caller holds
     ``sum(q_lens)`` to ``step_tokens``. What lies between two mixers (q/k/v,
     the output projection and its residual, the dense MLP with its norm and
     residual) runs over the first ``step_tokens // 2`` packed tokens alone
@@ -1893,6 +1924,19 @@ def mixed_step(
         if token_valid is not None:
             token_valid = pack.valid
     x = _embed(params, tokens, dtype)
+    # Once a program, outside the layer loop: each packed token's slot in
+    # a layer's pages, and the rope tables' entries at its own position
+    # (gathered out of the rows' tables, so a token's k is rotated by the
+    # bits the rows program rotates it by).
+    tok_slots = tok_rope = None
+    if kv_write_form(cfg, B * S, step_tokens)[0] == "tokens":
+        with jax.named_scope("kv_write"):
+            tok_slots = token_slots(
+                page_table, start, pack.row, pack.at,
+                jax.tree.leaves(cache["k"])[0].shape[2])
+        if cos is not None:
+            with jax.named_scope("attn_qkv"):
+                tok_rope = pack.tokens(cos), pack.tokens(sin)
 
     def packed(a):
         if pack is None:
@@ -1902,6 +1946,7 @@ def mixed_step(
 
     def attn_fn(h, lp, kc, vc, li):
         if _latent_cache(cfg):
+            # the latent write still scatters the rows' slots (ROADMAP S3)
             if pack is not None:
                 with jax.named_scope("attn_qkv"):
                     h = pack.rows(h)
@@ -1914,10 +1959,13 @@ def mixed_step(
                 impl=attn_impl, layer=li, mesh=mesh,
             )
             return _mla_latent_out(packed(ctx), lp, cfg), kc, vc
-        q, k, v = _qkv_rope(h, lp, cfg, cos, sin, pack)
-        kc, vc = write_kv_pages(
-            kc, vc, k, v, page_table, start, valid_len=q_lens, layer=li
-        )
+        q, k, v = _qkv_rope(h, lp, cfg, cos, sin, pack, tok_rope)
+        if tok_slots is None:
+            kc, vc = write_kv_pages(
+                kc, vc, k, v, page_table, start, valid_len=q_lens, layer=li
+            )
+        else:
+            kc, vc = write_kv_tokens(kc, vc, k, v, tok_slots, layer=li)
         attn = paged_ragged_attention_auto(
             q, kc, vc, page_table, start, q_lens,
             impl=attn_impl, layer=li, mesh=mesh,

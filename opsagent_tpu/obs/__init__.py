@@ -128,6 +128,14 @@ MIXED_DISPATCH_WIDTH = _reg.counter(
     "(what kind=computed of opsagent_step_tokens_total adds up)",
     labelnames=("width",),
 )
+KV_WRITE_ROWS = _reg.counter(
+    "opsagent_kv_write_rows_total",
+    "Rows a mixed dispatch hands the page write's scatter in each attention "
+    "layer, counted at dispatch: kind=scattered the rows the scatter walks "
+    "(the packed width where the program writes by token, else rows x chunk "
+    "bucket), kind=real the tokens among them that land on a page",
+    labelnames=("kind",),
+)
 # -- async mixed serving runtime (serving/async_runtime.py) -------------------
 STEP_HOST_GAP_SECONDS = _reg.histogram(
     "opsagent_step_host_gap_seconds",

@@ -482,7 +482,63 @@ def write_pages(
     layer: jax.Array | None = None,  # [] int32 when pages carry a layer axis
 ) -> jax.Array:
     """Single-array page scatter (``write_kv_pages`` for one side; the MLA
-    latent cache writes only one array per token).
+    latent cache writes only one array per token): every slot of the rows,
+    ``B * S`` of them, goes to the scatter, the padded ones with the
+    past-the-end index (``_write`` has the page forms)."""
+    S = new.shape[1]
+    return _write(
+        pages, new, layer,
+        lambda P, base, total: _flat_slot_indices(
+            page_table, start, S, P, base, total, valid_len).reshape(-1))
+
+
+def token_slots(
+    page_table: jax.Array,  # [B, MaxP] int32 page indices (-1 = unassigned)
+    start: jax.Array,       # [B] int32 write offset (tokens already in cache)
+    row: jax.Array,         # [T] the row of each packed token (B: none)
+    at: jax.Array,          # [T] its place among the row's new tokens
+    page_size: int,
+) -> jax.Array:
+    """[T] the slot of each packed token in ONE layer's pages, ``page_table[
+    row, pos // P] * P + pos % P`` at ``pos = start[row] + at``; -1 for a
+    token of no row and for an unassigned page. A program computes it once,
+    outside its layer loop; ``write_kv_tokens`` adds a layer's offset."""
+    B, MaxP = page_table.shape
+    r = jnp.minimum(row, B - 1)
+    pos = start[r] + at
+    page = page_table[r, jnp.clip(pos // page_size, 0, MaxP - 1)]
+    return jnp.where(
+        (row < B) & (page >= 0), page * page_size + pos % page_size, -1)
+
+
+@scoped("kv_write")
+def write_kv_tokens(
+    k_pages: jax.Array,     # as ``write_kv_pages``
+    v_pages: jax.Array,
+    k_new: jax.Array,       # [1, T, K, D] packed tokens
+    v_new: jax.Array,       # [1, T, K, D]
+    slots: jax.Array,       # [T] ``token_slots``
+    layer: jax.Array | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """``write_kv_pages`` for a packed stream (``llama.Pack``): the scatter
+    is handed the tick's ``T`` tokens, each with its own slot, not the
+    rows' ``B x S`` slots. A scatter on the chip walks its indices one
+    after another, written or dropped (PERF.md section 6, PR 39), so a
+    write costs the rows it is handed and not the bytes it moves. One
+    scatter of ``T`` rows and no conditional around the cache: tokens past
+    the tick's last are dropped by index (-1 in ``slots``)."""
+    def flat(P, base, total):
+        return jnp.where(slots >= 0, slots + base * P, total * P)
+
+    return (_write(k_pages, k_new, layer, flat),
+            _write(v_pages, v_new, layer, flat))
+
+
+def _write(pages, new: jax.Array, layer, flat_of) -> jax.Array:
+    """``new`` ``[..., K, D]``, a row of it a cache slot, scattered to the
+    flat slots ``flat_of(P, base, total)`` names (``base`` the layer's
+    first flat page, ``total`` the flat pages in all; ``total * P``, one
+    past the end, drops a row: a negative index would WRAP).
 
     The scatter runs in the form the pages are held in (module header):
     rows of ``[K, D]`` into the flat ``[slots, K, D]`` view of split pages,
@@ -492,20 +548,20 @@ def write_pages(
     ``QuantizedPages`` targets quantize the fresh rows on write (absmax
     over the head dim) and scatter values and scales with the same flat
     indices, so the drop-sentinel/validity logic is shared."""
+    K, D = new.shape[-2:]
     if isinstance(pages, QuantizedPages):
         q_new, s_new = quantize_kv_rows(new)
         return QuantizedPages(
-            write_pages(
-                pages.q, q_new, page_table, start,
-                valid_len=valid_len, layer=layer,
-            ),
-            _write_scale_pages(
-                pages.scale, s_new, page_table, start,
-                valid_len=valid_len, layer=layer,
-            ),
+            _write(pages.q, q_new, layer, flat_of),
+            _scatter(pages.scale, s_new, (K,), layer, flat_of),
         )
-    B, S, K, D = new.shape
     row = pages.shape[-1:] if pages_merged(pages, D) else (K, D)
+    return _scatter(pages, new, row, layer, flat_of)
+
+
+def _scatter(pages: jax.Array, new: jax.Array, row: tuple, layer, flat_of):
+    """Rows ``row`` of ``new`` into ``pages`` ``[(L,) N, P, *row]`` (values
+    in either form, or the scale planes ``[(L,) N, P, K]``)."""
     lead = pages.shape[: pages.ndim - len(row) - 1]    # (L, N) or (N,)
     P = pages.shape[len(lead)]
     if len(lead) == 2:
@@ -513,11 +569,9 @@ def write_pages(
         base = (layer if layer is not None else 0) * lead[1]
     else:
         total, base = lead[0], 0
-    flat = _flat_slot_indices(
-        page_table, start, S, P, base, total, valid_len
-    ).reshape(B * S)
     pf = pages.reshape(total * P, *row)
-    pf = pf.at[flat].set(new.reshape(B * S, *row), mode="drop")
+    pf = pf.at[flat_of(P, base, total)].set(
+        new.reshape(-1, *row), mode="drop")
     return pf.reshape(pages.shape)
 
 
@@ -547,34 +601,6 @@ def _flat_slot_indices(
         ok = jnp.arange(S)[None, :] < valid_len[:, None]
         return jnp.where(ok & (page_idx >= 0), flat, oob)
     return jnp.where(page_idx >= 0, flat, oob)
-
-
-def _write_scale_pages(
-    pages: jax.Array,       # [N, P, K] — or [L, N, P, K] with layer
-    new: jax.Array,         # [B, S, K] per-token scales
-    page_table: jax.Array,
-    start: jax.Array,
-    valid_len: jax.Array | None = None,
-    layer: jax.Array | None = None,
-) -> jax.Array:
-    """``write_pages`` for the scale planes of ``QuantizedPages`` (same
-    flat slot math via ``_flat_slot_indices``, one fewer axis)."""
-    if pages.ndim == 4:
-        L, N, P, K = pages.shape
-        total = L * N
-        base = (layer if layer is not None else 0) * N
-    else:
-        N, P, K = pages.shape
-        total = N
-        base = 0
-    B, S = new.shape[:2]
-    flat = _flat_slot_indices(
-        page_table, start, S, P, base, total, valid_len
-    ).reshape(B * S)
-    shape = pages.shape
-    pf = pages.reshape(total * P, K)
-    pf = pf.at[flat].set(new.reshape(B * S, K), mode="drop")
-    return pf.reshape(shape)
 
 
 @scoped("kv_gather")

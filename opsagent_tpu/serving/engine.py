@@ -1061,6 +1061,12 @@ class Engine:
                     self.cfg.max_batch_size * self.cfg.mixed_buckets[-1],
                     self.step_tokens) else "rows"
             ),
+            # what the widest mixed program hands the page write: the
+            # tick's packed tokens, or its rows' slots
+            "kv_write": (
+                self._kv_write(self.cfg.mixed_buckets[-1])[0]
+                if self.cfg.mixed_batching else "rows"
+            ),
         }
         if self.weight_stream_leaves:
             info["weight_stream_leaves"] = dict(self.weight_stream_leaves)
@@ -2165,6 +2171,12 @@ class Engine:
         T, narrow = widths
         return narrow if real is not None and real <= narrow else T
 
+    def _kv_write(self, S: int) -> tuple[str, int]:
+        """``llama.kv_write_form`` of the mixed program of bucket ``S``:
+        "tokens" or "rows", and the rows its page write scatters a layer."""
+        return llama.kv_write_form(
+            self.model_cfg, self.cfg.max_batch_size * S, self.step_tokens)
+
     def _count_step_tokens(self, S: int, real: int) -> str:
         """Count a mixed dispatch of ``real`` tokens; returns the width its
         dense segments run over, as the counter's label has it (the step
@@ -2173,6 +2185,8 @@ class Engine:
         obs.STEP_TOKENS.inc(real, kind="real")
         obs.STEP_TOKENS.inc(width, kind="computed")
         obs.MIXED_DISPATCH_WIDTH.inc(width=str(width))
+        obs.KV_WRITE_ROWS.inc(real, kind="real")
+        obs.KV_WRITE_ROWS.inc(self._kv_write(S)[1], kind="scattered")
         return str(width)
 
     def mixed_hosted(self, seq_id: int) -> bool:

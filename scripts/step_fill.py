@@ -9,8 +9,9 @@ computed (the fill share), of ``opsagent_mixed_dispatch_width_total`` by
 width (the rows the dense segments of each mixed dispatch ran over) with
 the share of dispatches that ran at half the step's tokens, the step
 clock's mixed samples by chunk bucket (how many, and their mean device
-time), the forced tokens the grammar spliced and the mixed dispatches
-counted. Until a ``benchmark`` PR gives the fill share a reader under
+time), the forced tokens the grammar spliced, the mixed dispatches
+counted, and ``opsagent_kv_write_rows_total`` by kind with its ratio (the
+rows the page write's scatter walked a layer for each token that landed). Until a ``benchmark`` PR gives the fill share a reader under
 ``benchmarks/layer_metrics/`` (PERF.md section 7), this is how a builder
 reads it on the chip. A program without the counter prints zeros.
 """
@@ -62,6 +63,8 @@ def main() -> int:
         # slots would count beside it: no cell has one)
         how, _, packed = impl.get("step_rows", "rows").partition(":")
         narrow = str(int(packed) // 2) if how == "packed" else None
+        landed = d("opsagent_kv_write_rows_total", kind="real")
+        walked = d("opsagent_kv_write_rows_total", kind="scattered")
         run.say("step fill: " + json.dumps({
             "real": real, "computed": computed,
             "fill_share": real / computed if computed else None,
@@ -73,6 +76,9 @@ def main() -> int:
             "ffwd_tokens": d("opsagent_ffwd_tokens_total"),
             "mixed_dispatches": d(
                 "opsagent_mixed_dispatch_decode_lanes_count"),
+            "kv_write": impl.get("kv_write"),
+            "kv_write_rows": {"scattered": walked, "real": landed},
+            "kv_write_rows_per_token": walked / landed if landed else None,
         }))
         print_result(result, got)
 

@@ -382,3 +382,196 @@ def test_packed_mixed_step_is_the_rows_step(
     q_lens, S = ragged_case
     with jax.default_matmul_precision("highest"):
         packed_against_rows(cfg, params, q_lens, S, tol=2e-5)
+
+
+# -- the packed step's page write: by token, to the bit ------------------------
+_WRITE_MODELS = {
+    "gqa_qkv_bias_rope": {"attn_bias": True},
+    "mha_no_rope": {"num_kv_heads": 4, "use_rope": False},
+    "qk_norm_by_head": {"qk_norm": True},
+    "qk_norm_whole": {"qk_norm": True, "qk_norm_whole": True},
+}
+_WRITE_CACHES = {      # make_cache's form and kv_quantize
+    "split": ("split", ""), "merged": ("merged", ""),
+    "int8_split": ("split", "int8"), "int8_merged": ("merged", "int8"),
+}
+_SENTINEL = 7
+
+
+@pytest.fixture(scope="module")
+def written_caches():
+    """``caches(model, cache, q_lens, S, start, table)``: the cache trees
+    two packed ``llama.mixed_step`` programs leave (32 tokens; dense
+    segments 16 wide in a tick of 16 or fewer), one that writes by rows as
+    the step did before PR 39 (q, k AND v un-packed to ``[B, S]`` rows
+    ahead of the heads' split and RoPE, the rows' ``B x S`` slots handed to
+    ``write_kv_pages``) and the one the tree has, from pages that hold a
+    sentinel in every slot (values, int8 values and scales alike), as
+    numpy leaves; float32. Both run the projections over the same packed
+    tokens, so the bits can be compared (over ``[B, S]`` rows a CPU matmul
+    rounds another way: ``test_packed_mixed_step_is_the_rows_step`` holds
+    that pair within a tolerance). Six rows, pages of 4."""
+    import dataclasses
+    import functools
+
+    from opsagent_tpu.models import llama
+    from opsagent_tpu.models.config import PRESETS
+
+    B, T, PAGE = 6, 32, 4
+
+    @functools.lru_cache(maxsize=None)
+    def model(name):
+        cfg = dataclasses.replace(PRESETS["tiny-test"], **_WRITE_MODELS[name])
+        params = llama.init_params(cfg, jax.random.PRNGKey(3), jnp.float32)
+        # biases and norm gains off their zeros and ones: a dropped one shows
+        params["layers"] = {
+            leaf_name: leaf + jax.random.normal(
+                jax.random.PRNGKey(9), leaf.shape) * 0.1
+            if leaf_name in ("bq", "bk", "bv", "qn", "kn") else leaf
+            for leaf_name, leaf in params["layers"].items()}
+        return cfg, params
+
+    def by_rows(params, *, cfg, tokens, start, q_lens, cache, page_table):
+        def qkv_rope(x, lp, cfg, cos, sin, pack, tok_rope):
+            q, k, v = pack.dense(lambda a: llama._qkv_flat(a, lp, cfg), x)
+            q, k, v = llama._heads(
+                pack.rows(q), pack.rows(k), pack.rows(v), lp, cfg)
+            if cos is None:
+                return q, k, v
+            return (llama.apply_rope(q, cos, sin) * llama._yarn_q_scale(cfg),
+                    llama.apply_rope(k, cos, sin), v)
+
+        def write(kc, vc, k, v, slots, layer):
+            return llama.write_kv_pages(
+                kc, vc, k, v, page_table, start, valid_len=q_lens,
+                layer=layer)
+
+        with pytest.MonkeyPatch.context() as mp:    # traced once, patched
+            mp.setattr(llama, "_qkv_rope", qkv_rope)
+            mp.setattr(llama, "write_kv_tokens", write)
+            return llama.mixed_step(
+                params, cfg, tokens, start, q_lens, cache, page_table,
+                dtype=jnp.float32, step_tokens=T)
+
+    @functools.lru_cache(maxsize=None)
+    def step(cfg, rows):
+        return jax.jit(functools.partial(
+            by_rows if rows else llama.mixed_step, cfg=cfg,
+            **({} if rows else {"dtype": jnp.float32, "step_tokens": T})))
+
+    def caches(name, cache_form, q_lens, S, start, table):
+        cfg, params = model(name)
+        form, quant = _WRITE_CACHES[cache_form]
+        tokens = jnp.asarray(np.random.default_rng(S).integers(
+            1, cfg.vocab_size, (B, S)), jnp.int32)
+        out = []
+        with jax.default_matmul_precision("highest"):
+            for rows in (True, False):
+                cache = jax.tree.map(
+                    lambda a: jnp.full_like(a, _SENTINEL),
+                    llama.make_cache(
+                        cfg, int(np.max(table)) + 2, PAGE, dtype=jnp.float32,
+                        kv_quantize=quant, form=form))
+                _, cache = step(cfg, rows)(
+                    params, tokens=tokens,
+                    start=jnp.asarray(start, jnp.int32),
+                    q_lens=jnp.asarray(q_lens, jnp.int32), cache=cache,
+                    page_table=jnp.asarray(table, jnp.int32))
+                out.append([np.asarray(a) for a in jax.tree.leaves(cache)])
+        return cfg, out
+
+    return caches
+
+
+def _slots_written(leaf, pages: int, page: int = 4):
+    """[pages, page] which slots of layer 0 no longer hold the sentinel."""
+    per_slot = np.asarray(leaf[0]).reshape(pages, page, -1)
+    return (per_slot != _SENTINEL).any(axis=-1)
+
+
+@pytest.mark.parametrize("cache_form", list(_WRITE_CACHES))
+@pytest.mark.parametrize("name", list(_WRITE_MODELS))
+def test_packed_step_writes_the_rows_steps_cache_to_the_bit(
+        written_caches, ragged_case, name, cache_form):
+    """Keys and values that never leave the packed stream (projection,
+    heads, qk-norm, RoPE at the tokens' own positions, one scatter of ``T``
+    tokens: ``ops.attention.write_kv_tokens``) land in the slots the rows
+    program writes with the bits it writes, in every page slot of every
+    layer, and no other slot changes: GQA with QKV bias and RoPE, MHA
+    without RoPE, a qk-norm a head and one over the whole projection;
+    split and merged pages; int8 pages' values and scales."""
+    q_lens, S = ragged_case
+    table = np.arange(6 * 16, dtype=np.int32).reshape(6, 16)
+    _, (rows, packed) = written_caches(
+        name, cache_form, q_lens, S, [5, 0, 0, 9, 20, 2], table)
+    assert len(rows) == (4 if "int8" in cache_form else 2)
+    for a, b in zip(rows, packed, strict=True):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    # what was written is each row's q_len slots from its start, no more
+    written = _slots_written(packed[0], 6 * 16 + 1).reshape(-1)
+    want = np.zeros_like(written)
+    for b, (st, n) in enumerate(zip([5, 0, 0, 9, 20, 2], q_lens)):
+        want[b * 64 + st: b * 64 + st + n] = True
+    assert np.array_equal(written, want)
+
+
+@pytest.mark.parametrize("cache_form", ["merged", "int8_split"])
+def test_packed_write_drops_what_has_no_slot_and_crosses_pages(
+        written_caches, cache_form):
+    """One tick through the packed write: a row with ``q_len`` 0 and the
+    tokens past the tick's last write nothing; a row whose second page is
+    unassigned (-1) keeps the tokens of its first page and drops the
+    rest; a chunk that starts two slots before a page's end lands on both
+    of its pages (pages that are not neighbours). As the rows program."""
+    table = np.full((6, 16), -1, np.int32)
+    table[0, :4] = [9, 2, 30, 4]       # chunk over pages 9 -> 2 -> 30
+    table[1, :1] = [11]                # second page unassigned
+    table[2, :2] = [5, 6]              # q_len 0: nothing
+    table[3, :3] = [20, 21, 22]        # a decode row
+    q_lens, start = (7, 6, 0, 1, 0, 0), [2, 1, 3, 8, 0, 0]
+    _, (rows, packed) = written_caches(
+        "gqa_qkv_bias_rope", cache_form, q_lens, 16, start, table)
+    for a, b in zip(rows, packed, strict=True):
+        assert np.array_equal(a, b)
+    written = _slots_written(packed[0], 32)
+    want = np.zeros_like(written)
+    want[9, 2:] = want[2, :] = want[30, :1] = True      # 2 + 4 + 1 tokens
+    want[11, 1:] = True                 # 3 of 6: the others had no page
+    want[22, 0] = True                  # position 8: third page, slot 0
+    assert np.array_equal(written, want)
+
+
+@pytest.mark.parametrize("args,rc", [(["--rehearse"], 3), ([], 1)],
+                         ids=["rehearse", "refuses_the_cpu"])
+def test_kv_write_microbench_walks_here_and_times_only_on_the_chip(
+        tmp_path, args, rc):
+    """``scripts/kv_write_microbench.py`` (PERF.md section 6, PR 39: what a
+    row handed to the page write's scatter costs): ``--rehearse`` walks
+    every form at toy shapes on the CPU, checks each form's cache against
+    numpy's, prints no time and exits 3; without it the script refuses a
+    CPU and prints no line."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, os.path.join(root, "scripts/kv_write_microbench.py"),
+         *args],
+        cwd=tmp_path, capture_output=True, text=True, timeout=600,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert done.returncode == rc, done.stderr[-2000:]
+    lines = [json.loads(x) for x in done.stdout.splitlines()
+             if x.startswith("{")]
+    if not args:
+        assert lines == [] and "needs a TPU" in done.stdout
+        return
+    assert len(lines) == 4 and {x["rows"] for x in lines} == {16, 32}
+    for line in lines:
+        assert line["real"] <= line["rows"]
+        assert set(line["us_per_call"]) == {
+            "scatter", "scatter_unique", "write_pages", "write_kv_tokens"}
+        assert all(v is None for v in line["us_per_call"].values())
+    kept = (tmp_path / "chiprun_out/kv_write_microbench.jsonl").read_text()
+    assert [json.loads(x) for x in kept.splitlines()] == lines

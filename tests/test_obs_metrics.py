@@ -196,16 +196,16 @@ def test_perf_timer_paths_unified():
 
 
 @pytest.mark.parametrize("depth", [1, 2], ids=["sync", "async"])
-@pytest.mark.parametrize("rows,budget,chunks,computed,step_rows", [
-    (4, 128, 1, 64, "rows"),        # 4 x 16 slots are half the step's 128
-    (16, 128, 1, 64, "packed:128"),     # 17 tokens: the narrow width
-    (16, 128, 4, 128, "packed:128"),    # 65 tokens: the whole packed width
-    (8, 128, 1, 64, "packed:128"),  # 8 x 16 slots ARE the step's 128 tokens
-    (16, 256, 1, 128, "packed:256"),    # cell 4's [16, 16] at 256
+@pytest.mark.parametrize("rows,budget,chunks,computed,step_rows,scattered", [
+    (4, 128, 1, 64, "rows", 64),    # 4 x 16 slots are half the step's 128
+    (16, 128, 1, 64, "packed:128", 128),    # 17 tokens: the narrow width
+    (16, 128, 4, 128, "packed:128", 128),   # 65 tokens: the whole packed width
+    (8, 128, 1, 64, "packed:128", 128),     # 8 x 16 slots ARE the step's 128
+    (16, 256, 1, 128, "packed:256", 256),   # cell 4's [16, 16] at 256
 ], ids=["rows", "packed_narrow", "packed_wide", "slots_equal_step_tokens",
         "16_rows_of_16_at_256"])
 def test_step_tokens_counts_what_a_mixed_dispatch_carried_and_computed(
-        depth, rows, budget, chunks, computed, step_rows):
+        depth, rows, budget, chunks, computed, step_rows, scattered):
     """opsagent_step_tokens_total, at dispatch on both mixed paths:
     kind=real the tokens carried (one decode lane and chunks of 16),
     kind=computed the rows the dense segments ran over: rows x bucket where
@@ -213,7 +213,11 @@ def test_step_tokens_counts_what_a_mixed_dispatch_carried_and_computed(
     where the program packs, half the step's tokens in a tick that carries
     no more and the packed width in any other.
     opsagent_mixed_dispatch_width_total counts the dispatch under that
-    width, and ``impl.step_rows`` says whether the widest program packs."""
+    width, and ``impl.step_rows`` says whether the widest program packs.
+    opsagent_kv_write_rows_total: kind=scattered the rows the page write's
+    scatter walks a layer (the packed width whatever the tick carries,
+    where the program writes by token: ``impl.kv_write`` "tokens"; else
+    its slots), kind=real the tokens that land."""
     import jax.numpy as jnp
 
     from opsagent_tpu.serving.engine import Engine, EngineConfig
@@ -226,6 +230,8 @@ def test_step_tokens_counts_what_a_mixed_dispatch_carried_and_computed(
         max_step_tokens=budget, async_depth=depth,
     ))
     assert eng.impl_info()["step_rows"] == step_rows
+    assert eng.impl_info()["kv_write"] == (
+        "rows" if step_rows == "rows" else "tokens")
     lane = eng.add_request([257, 9, 8, 7], SamplingParams(max_tokens=8))
     admits = [
         eng.begin_request(
@@ -238,7 +244,9 @@ def test_step_tokens_counts_what_a_mixed_dispatch_carried_and_computed(
 
     before = (read("opsagent_step_tokens_total", kind="real"),
               read("opsagent_step_tokens_total", kind="computed"),
-              read("opsagent_mixed_dispatch_width_total", width=computed))
+              read("opsagent_mixed_dispatch_width_total", width=computed),
+              read("opsagent_kv_write_rows_total", kind="real"),
+              read("opsagent_kv_write_rows_total", kind="scattered"))
     step = eng.step_mixed if depth == 1 else eng.step_mixed_async
     step([lane], {a: 16 for a in admits})
     eng.drain()
@@ -248,3 +256,8 @@ def test_step_tokens_counts_what_a_mixed_dispatch_carried_and_computed(
         "opsagent_step_tokens_total", kind="computed") - before[1] == computed
     assert read(
         "opsagent_mixed_dispatch_width_total", width=computed) - before[2] == 1
+    assert read("opsagent_kv_write_rows_total", kind="real") - before[3] == (
+        1 + 16 * chunks)
+    assert read(
+        "opsagent_kv_write_rows_total", kind="scattered") - before[4] == (
+            scattered)

@@ -664,6 +664,10 @@ def test_cell_1s_mixed_step_runs_its_matmuls_over_the_steps_tokens(v5e):
     assert re.search(rf"bf16\[256,{d}\]\S* convolution\(", hlo)
     assert not re.search(rf"\[32,32,{f}\]", hlo)
     assert re.search(rf"bf16\[1024,{d}\]\S* gather\(", hlo)   # q, un-packed
+    # k and v stay packed: the page write scatters the step's 256 tokens
+    scatters, _ = _scatters_and_gathers(hlo)
+    kv = cfg.num_kv_heads * cfg.head_dim_
+    assert [u for _, u in scatters] == [(256, kv)] * 2
     assert copies == []
     assert not re.search(rf"bf16\[1,{d},{d}\]\S* fusion\(", hlo)  # a weight whole
     scratch = compiled.memory_analysis().temp_size_in_bytes
@@ -729,6 +733,59 @@ def test_a_packed_mixed_step_holds_both_widths_in_one_program(
         if kind in ("bf16", "s8") and dims[-2:] in weights
         and op not in ("parameter", "get-tuple-element", "bitcast")]
     assert written == []
+    assert copies == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
+
+
+def _scatters_and_gathers(hlo: str):
+    """([(result dims, updates dims)] of every scatter, [result dims] of
+    every gather) of an optimized module, the updates' dims read where the
+    scatter's third operand is defined."""
+    dims_of = {
+        name: tuple(int(x) for x in dims.split(","))
+        for name, dims in re.findall(
+            r"^\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]+)\]", hlo, re.M)}
+    found = re.findall(
+        r"^\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]\S* (scatter|gather)"
+        r"\(([^)]*)\)", hlo, re.M)
+    scatters, gathers = [], []
+    for dims, op, operands in found:
+        dims = tuple(int(x) for x in dims.split(","))
+        if op == "gather":
+            gathers.append(dims)
+        else:
+            updates = operands.split(",")[2].split()[-1]
+            scatters.append((dims, dims_of[updates]))
+    return scatters, gathers
+
+
+@pytest.mark.parametrize("preset,rows,tokens,layers", [
+    ("qwen2.5-7b-instruct", 32, 32, 28),     # cell 1's widest mixed program
+    ("qwen2.5-72b-instruct", 16, 64, 8),     # cell 2's
+], ids=["cell_1", "cell_2"])
+def test_a_packed_mixed_step_writes_its_keys_and_values_by_token(
+        v5e, preset, rows, tokens, layers):
+    """The cells' widest mixed programs (1024 slots, packed to 256 tokens)
+    hand the page write the tick's tokens: the two scatters into the K and
+    the V array take ``[256, K*D]`` updates and none takes the rows'
+    ``[1024, K*D]`` (a scatter on the chip walks the rows it is handed,
+    written or dropped: 96 ns a row of 1 KB, my chip run, PR 39); the one
+    array of 1024 rows a layer still gathers is q, un-packed for the
+    attention kernel; k and v reach the scatter without one. The cache is
+    no operand of a conditional (three a layer, as before), no K or V
+    array is copied and the scratch HBM stays where it was (8.4 and 16.3
+    MB; 8.3 and 15.9 with the write by rows; compile, PR 39)."""
+    cfg, cache, copies, compiled = _mixed_step(
+        _one_chip(v5e), preset, "", "pallas-stream", rows=rows,
+        tokens=tokens, step_tokens=256, layers=layers, int8=True)
+    hlo = compiled.as_text()
+    kv = cfg.num_kv_heads * cfg.head_dim_
+    slots = int(np.prod(cache["k"].shape[:3]))
+    scatters, gathers = _scatters_and_gathers(hlo)
+    assert sorted(scatters) == [((slots, kv), (256, kv))] * 2
+    wide = [g for g in gathers if g[0] == rows * tokens]
+    assert wide == [(rows * tokens, cfg.num_heads * cfg.head_dim_)]
+    assert len(re.findall(r" conditional\(", hlo)) == 3
     assert copies == []
     assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
 
