@@ -96,6 +96,16 @@ class MLAConfig:
     def latent_dim(self) -> int:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
+    @property
+    def page_dim(self) -> int:
+        """Width of a token's row in the latent pages: the latent padded
+        with zeros to whole 128-lane tiles (576 -> 640). An array whose
+        minor axis is off the lanes is held by the TPU in a layout of the
+        compiler's choosing (at ``[L, N, P, 576]``: pages innermost), and
+        every step program then copied the whole cache to row-major at its
+        entry and back at its exit (compile, PR 30 and PR 40)."""
+        return -(-self.latent_dim // 128) * 128
+
 
 @dataclass(frozen=True)
 class LinearAttnConfig:
@@ -282,10 +292,20 @@ class ModelConfig:
         and the experts HELD here (a chip's share counts its share; the
         whole model is the preset with every expert held). ``active``
         counts what one token uses: its ``num_experts_per_token`` routed
-        experts instead of all. MLA projections are not itemised (counted
-        as plain attention)."""
+        experts instead of all. MLA counts its published projections (the
+        output projection over the heads' ``v_head_dim``, not the padded
+        rows this program holds)."""
         d, f, v = self.hidden_size, self.intermediate_size, self.vocab_size
         attn = d * self.q_size + 2 * d * self.kv_size + self.q_size * d
+        if self.mla is not None:
+            a, H = self.mla, self.num_heads
+            q = (d * a.q_lora_rank + a.q_lora_rank          # down, its norm
+                 + a.q_lora_rank * H * a.qk_head_dim
+                 if a.q_lora_rank else d * H * a.qk_head_dim)
+            attn = (
+                q + d * a.latent_dim + a.kv_lora_rank       # down, its norm
+                + a.kv_lora_rank * H * (a.qk_nope_head_dim + a.v_head_dim)
+                + H * a.v_head_dim * d)
         if self.attn_output_gate:
             attn += d * self.q_size
         if self.attn_bias:
@@ -590,6 +610,52 @@ DEEPSEEK_V3 = _register(
     )
 )
 
+# GLM-4.7-Flash (zai-org; HF glm4_moe_lite; 29.9B parameters, 3.3B of them
+# a token's outside the embedding and the head): MLA in every layer with a
+# low-rank query, heads of 192 + 64 query dims and 256 value dims (the first
+# shapes here whose value is as wide as its query: nothing is padded), one
+# dense layer, then 46 layers of 64 routed experts, top-4 by sigmoid score
+# with the noaux_tc selection bias (one group: no group limit), weights
+# renormalised and scaled by 1.8, and one shared expert. The router names its
+# own width (``router_experts``), so the expert layers are ``llama._moe_share``
+# with every expert held: dropless, work in proportion to the assignments.
+# ``num_nextn_predict_layers: 1`` is a drafting layer outside the 47 and is
+# not part of this model (ROADMAP M6).
+GLM_4_7_FLASH = _register(
+    ModelConfig(
+        name="glm-4.7-flash",
+        vocab_size=154880,
+        hidden_size=2048,
+        intermediate_size=10240,
+        num_layers=47,
+        num_heads=20,
+        num_kv_heads=20,
+        head_dim=256,               # qk_nope (192) + qk_rope (64)
+        rope_theta=1000000.0,
+        rms_norm_eps=1e-5,
+        max_position=202752,
+        moe=MoEConfig(
+            num_experts=64,
+            num_experts_per_token=4,
+            num_shared_experts=1,
+            expert_intermediate_size=1536,
+            norm_topk_prob=True,
+            routed_scaling_factor=1.8,
+            scoring_func="sigmoid",
+            router_experts=64,
+        ),
+        moe_layer_start=1,
+        mla=MLAConfig(
+            q_lora_rank=768,
+            kv_lora_rank=512,
+            qk_nope_head_dim=192,
+            qk_rope_head_dim=64,
+            v_head_dim=256,
+            latent_cache=True,
+        ),
+    )
+)
+
 # Solar-Open2-250B (upstage; HF solar_open2): 48 layers in periods of four,
 # layer i softmax GQA (64 query / 8 kv heads of 128, NO rotary embedding, a
 # sigmoid output gate) where i % 4 == 0 and delta-rule linear attention
@@ -766,6 +832,44 @@ TINY_MOE = _register(
     )
 )
 
+# GLM-4.7-Flash's shape at toy widths (CPU tests): latent pages under heads
+# whose value is as wide as their query, one dense layer, then expert layers
+# behind a sigmoid router that names its own width, all experts held.
+TINY_GLM_FLASH = _register(
+    ModelConfig(
+        name="tiny-glm-flash",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=3,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=24,                # 16 nope + 8 rope
+        rope_theta=10000.0,
+        rms_norm_eps=1e-5,
+        max_position=4096,
+        moe=MoEConfig(
+            num_experts=8,
+            num_experts_per_token=2,
+            num_shared_experts=1,
+            expert_intermediate_size=32,
+            norm_topk_prob=True,
+            routed_scaling_factor=1.8,
+            scoring_func="sigmoid",
+            router_experts=8,
+        ),
+        moe_layer_start=1,
+        mla=MLAConfig(
+            q_lora_rank=32,
+            kv_lora_rank=32,
+            qk_nope_head_dim=16,
+            qk_rope_head_dim=8,
+            v_head_dim=24,
+            latent_cache=True,
+        ),
+    )
+)
+
 
 def get_config_preset(name: str) -> ModelConfig:
     if name in PRESETS:
@@ -794,11 +898,11 @@ def config_from_hf(path: str, name: str = "") -> ModelConfig:
     mt = hf.get("model_type", "llama")
     if mt not in ("llama", "mistral", "qwen2", "qwen3", "qwen3_moe",
                   "deepseek", "deepseek_v2", "deepseek_v3", "solar_open2",
-                  "olmo_hybrid"):
+                  "olmo_hybrid", "glm4_moe_lite"):
         raise ValueError(
             f"config_from_hf supports model_type llama/mistral/qwen2/"
             f"qwen3/qwen3_moe/deepseek/deepseek_v2/deepseek_v3/solar_open2/"
-            f"olmo_hybrid, got {mt!r}"
+            f"olmo_hybrid/glm4_moe_lite, got {mt!r}"
         )
     name = name or os.path.basename(os.path.normpath(
         path if os.path.isdir(path) else os.path.dirname(cfg_path)
@@ -807,6 +911,8 @@ def config_from_hf(path: str, name: str = "") -> ModelConfig:
         return _solar_open2_from_hf(hf, name)
     if mt == "olmo_hybrid":
         return _olmo_hybrid_from_hf(hf, name)
+    if mt == "glm4_moe_lite":
+        return _glm4_moe_lite_from_hf(hf, name)
     # Sliding-window attention is not implemented; a config that would
     # ACTIVELY use it must be rejected loudly, never silently served
     # with full attention. Mistral (llama-shaped otherwise: same weight
@@ -1075,6 +1181,119 @@ def _solar_open2_dict(cfg: ModelConfig) -> dict:
     return hf
 
 
+def _glm4_moe_lite_from_hf(hf: dict, name: str) -> ModelConfig:
+    """``model_type: glm4_moe_lite`` (GLM-4.7-Flash): DeepSeek-V3's layer
+    under other numbers: MLA with a low-rank query in every layer,
+    ``first_k_dense_replace`` dense layers, then routed experts chosen by
+    sigmoid score plus a selection bias (``topk_method: noaux_tc``; the
+    config has no ``scoring_func`` key, the method implies it) beside shared
+    experts. The router names its own width, so the expert layers run as a
+    share with every expert held (``experts_held`` / ``first_expert_held``,
+    this engine's own keys, name a smaller one). The drafting layer
+    (``num_nextn_predict_layers``) is not part of the served model."""
+    if hf.get("topk_method", "noaux_tc") != "noaux_tc":
+        raise ValueError(
+            f"glm4_moe_lite: topk_method {hf['topk_method']!r} is not "
+            "supported (noaux_tc: sigmoid scores and a selection bias)")
+    if int(hf.get("n_group", 1) or 1) != 1 or int(
+            hf.get("topk_group", 1) or 1) != 1:
+        raise ValueError(
+            "glm4_moe_lite: group-limited routing (n_group > 1) is not "
+            "supported inside an expert share")
+    if float(hf.get("partial_rotary_factor", 1)) != 1:
+        raise ValueError("glm4_moe_lite: partial rotary embedding is not supported")
+    if hf.get("rope_scaling"):
+        raise ValueError("glm4_moe_lite: rope_scaling is not supported")
+    heads = int(hf["num_attention_heads"])
+    if int(hf.get("num_key_value_heads", heads)) != heads:
+        raise ValueError("glm4_moe_lite: MLA has no grouped kv heads")
+    mla = MLAConfig(
+        q_lora_rank=int(hf.get("q_lora_rank") or 0),
+        kv_lora_rank=int(hf["kv_lora_rank"]),
+        qk_nope_head_dim=int(hf["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(hf["qk_rope_head_dim"]),
+        v_head_dim=int(hf["v_head_dim"]),
+        latent_cache=True,
+    )
+    routed = int(hf["n_routed_experts"])
+    return ModelConfig(
+        name=name,
+        vocab_size=int(hf["vocab_size"]),
+        hidden_size=int(hf["hidden_size"]),
+        intermediate_size=int(hf["intermediate_size"]),
+        num_layers=int(hf["num_hidden_layers"]),
+        num_heads=heads,
+        num_kv_heads=heads,
+        head_dim=mla.qk_head_dim,
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rms_norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
+        attn_bias=bool(hf.get("attention_bias", False)),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        max_position=int(hf.get("max_position_embeddings", 8192)),
+        moe=MoEConfig(
+            num_experts=int(hf.get("experts_held", routed)),
+            num_experts_per_token=int(hf["num_experts_per_tok"]),
+            num_shared_experts=int(hf.get("n_shared_experts", 0) or 0),
+            expert_intermediate_size=int(hf["moe_intermediate_size"]),
+            norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            scoring_func="sigmoid",
+            router_experts=routed,
+            first_expert=int(hf.get("first_expert_held", 0)),
+        ),
+        moe_layer_start=int(hf.get("first_k_dense_replace", 0)),
+        mla=mla,
+    )
+
+
+def _glm4_moe_lite_dict(cfg: ModelConfig) -> dict:
+    """The inverse of ``_glm4_moe_lite_from_hf``."""
+    a, m = cfg.mla, cfg.moe
+    if (cfg.rope_scaling or cfg.qk_norm or m.scoring_func != "sigmoid"
+            or m.n_group > 1 or not a.latent_cache):
+        raise ValueError(
+            "hf_config_dict: this model is not expressible as model_type "
+            "glm4_moe_lite"
+        )
+    hf = {
+        "model_type": "glm4_moe_lite",
+        "architectures": ["Glm4MoeLiteForCausalLM"],
+        "vocab_size": cfg.vocab_size,
+        "hidden_size": cfg.hidden_size,
+        "intermediate_size": cfg.intermediate_size,
+        "moe_intermediate_size": m.expert_intermediate_size,
+        "num_hidden_layers": cfg.num_layers,
+        "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.num_kv_heads,
+        "hidden_act": "silu",
+        "attention_bias": cfg.attn_bias,
+        "rope_theta": cfg.rope_theta,
+        "rope_scaling": None,
+        "partial_rotary_factor": 1,
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "tie_word_embeddings": cfg.tie_embeddings,
+        "max_position_embeddings": cfg.max_position,
+        "q_lora_rank": a.q_lora_rank or None,
+        "kv_lora_rank": a.kv_lora_rank,
+        "qk_nope_head_dim": a.qk_nope_head_dim,
+        "qk_rope_head_dim": a.qk_rope_head_dim,
+        "v_head_dim": a.v_head_dim,
+        "topk_method": "noaux_tc",
+        "n_group": 1,
+        "topk_group": 1,
+        "first_k_dense_replace": cfg.moe_layer_start,
+        "n_routed_experts": m.router_width,
+        "n_shared_experts": m.num_shared_experts,
+        "num_experts_per_tok": m.num_experts_per_token,
+        "norm_topk_prob": m.norm_topk_prob,
+        "routed_scaling_factor": m.routed_scaling_factor,
+    }
+    if m.num_experts != m.router_width or m.first_expert:
+        hf["experts_held"] = m.num_experts
+        hf["first_expert_held"] = m.first_expert
+    return hf
+
+
 _OLMO_LAYER_TYPES = {"linear_attention": "linear", "full_attention": "attn"}
 
 
@@ -1201,11 +1420,16 @@ def hf_config_dict(cfg: ModelConfig) -> dict:
     llama/qwen2; qk_norm configs emit qwen3 (or qwen3_moe when paired
     with a plain softmax MoE); other MoE and/or MLA configs emit the
     deepseek family (deepseek_v2/v3 when MLA is present, deepseek
-    otherwise)."""
+    otherwise); latent attention beside an expert share emits
+    glm4_moe_lite."""
     if cfg.post_norm:
         return _olmo_hybrid_dict(cfg)
     if cfg.has_state:
         return _solar_open2_dict(cfg)
+    if cfg.mla and cfg.moe and cfg.moe.router_experts:
+        # latent attention beside an expert share: the router names its
+        # own width, which no deepseek config can say
+        return _glm4_moe_lite_dict(cfg)
     if (cfg.mixer_period or cfg.attn_output_gate or not cfg.use_rope
             or cfg.qk_norm_whole):
         raise ValueError(

@@ -90,6 +90,8 @@ EXTRA_SCOPES = (
     "moe_router",   # router scores, top-k, weights, the dispatch plan
     "moe_experts",  # the routed experts' matmuls
     "moe_shared",   # the always-on shared expert
+    "mla_latent",   # MLA's down-projections, their norms, the latent's assembly
+    "mla_absorb",   # the per-head einsums against ``wukv`` of the absorbed form
 )
 # Accumulators a model with an expert share keeps in ``cache["stats"]``, one
 # uint32 each, added to by every MoE layer of every pass and never reset:
@@ -558,9 +560,12 @@ def cache_form(
     ``[L, N, P, K, D]`` or "merged" ``[L, N, P, K*D]``, from what is known
     where the cache is made: the kv heads one tp shard holds and the
     attention backend (``ops.attention.page_form`` has the tile
-    arithmetic). The MLA latent is one head."""
+    arithmetic). The MLA latent is one head, held merged ``[L, N, P,
+    page_dim]``, with no unit kv-head axis and its row on whole lane
+    tiles: else the chip's compiler copied the whole cache at the entry
+    and exit of every step program (``MLAConfig.page_dim``)."""
     if _latent_cache(cfg):
-        return "split"
+        return "merged"
     return page_form(max(1, cfg.num_kv_heads // kv_shards), attn_impl)
 
 
@@ -582,52 +587,62 @@ def make_cache(
     all of K and of V; merged, the page slots fill the (8, 128) tile and
     write and gather share it. 8 heads fill it split. MLA latent mode
     stores ONE (kv_lora_rank + rope)-dim latent per token in ``k`` — the
-    compression that motivates MLA — with a 1-dim placeholder ``v`` (the
-    pytree shape is shared with the standard layout so the engine's
-    donation/restart plumbing is layout-agnostic).
+    compression that motivates MLA — merged and padded to whole lane
+    tiles, ``[L, N, P, page_dim]`` (``MLAConfig.page_dim``),
+    with a one-number placeholder ``v`` (the pytree shape is shared with
+    the standard layout so the engine's donation/restart plumbing is
+    layout-agnostic); "split" gives it its unit kv-head axis, the form a
+    page has off the device.
 
     ``kv_quantize="int8"`` stores pages as ``ops.attention.QuantizedPages``
     (int8 values + per-token-per-head f32 scales ``[L, N, P, K]`` in
-    either form): halves decode KV reads, the dominant non-weight HBM
-    term at serving shapes (PERF.md). Not supported for the MLA latent
-    layout (latents feed weight-absorbed matmuls, not raw attention; the
-    engine rejects the combination).
+    either form; the merged latent's are ``[L, N, P]``, one a token):
+    halves decode KV reads, the dominant non-weight HBM term at serving
+    shapes (PERF.md).
 
     A model with linear-attention layers holds pages for its attention
     layers only (``L`` counts those) and, beside them under the same tree
     so that they are donated through every step alike, ``state_slots``
-    slots of recurrent state (``make_state``, held for ``state_impl``)."""
+    slots of recurrent state (``make_state``, held for ``state_impl``). A
+    model with an expert share keeps its ``MOE_STATS`` accumulators there
+    too (``stats``)."""
     # pages only for the layers that attend over them; the recurrent
     # state of the others goes beside them
     L = cfg.count_mixers("attn")
-    state = make_state(
+    beside = make_state(
         cfg, state_slots, dtype, state_impl) if cfg.has_state else {}
-    if _latent_cache(cfg):
-        if kv_quantize or state:
-            raise ValueError(
-                "kv_quantize and linear-attention layers are not supported "
-                "with the MLA latent cache")
-        shape_k = (L, num_pages, page_size, 1, cfg.mla.latent_dim)
-        shape_v = (L, num_pages, page_size, 1, 1)
-        return {
-            "k": jnp.zeros(shape_k, dtype), "v": jnp.zeros(shape_v, dtype)
-        }
-    K, D = cfg.num_kv_heads, cfg.head_dim_
+    if _expert_share(cfg):
+        beside["stats"] = jnp.zeros((len(MOE_STATS),), jnp.uint32)
     form = form or cache_form(cfg)
     if form not in PAGE_FORMS:
         raise ValueError(f"page form {form!r}: expected one of {PAGE_FORMS}")
-    scales = (L, num_pages, page_size, K)
-    shape = scales[:-1] + (K * D,) if form == "merged" else scales + (D,)
-    if kv_quantize:
-        if kv_quantize != "int8":
-            raise ValueError(f"unsupported kv_quantize {kv_quantize!r}")
-        return {**state, **{
-            name: QuantizedPages(
-                jnp.zeros(shape, jnp.int8), jnp.ones(scales, jnp.float32)
-            )
-            for name in ("k", "v")
-        }}
-    return {**state, "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    if kv_quantize and kv_quantize != "int8":
+        raise ValueError(f"unsupported kv_quantize {kv_quantize!r}")
+    latent = _latent_cache(cfg)
+    if latent and cfg.has_state:
+        raise ValueError(
+            "linear-attention layers are not supported with the MLA "
+            "latent cache")
+    # the latent is one head, and its ``v`` one number a token
+    K, widths = (1, (cfg.mla.page_dim, 1)) if latent else (
+        cfg.num_kv_heads, (cfg.head_dim_,) * 2)
+    lead = (L, num_pages, page_size)
+    merged = form == "merged"
+    scales = lead if latent and merged else (*lead, K)
+
+    def shape(D: int) -> tuple:
+        if not merged:
+            return (*lead, K, D)
+        return (*lead, K * D) if K * D > 1 else lead
+
+    pages = {
+        name: (
+            QuantizedPages(
+                jnp.zeros(shape(D), jnp.int8), jnp.ones(scales, jnp.float32))
+            if kv_quantize else jnp.zeros(shape(D), dtype))
+        for name, D in zip(("k", "v"), widths)
+    }
+    return {**beside, **pages}
 
 
 # The recurrent state is the sequence's memory, and an error in it never
@@ -665,8 +680,7 @@ def make_state(
     (the last ``conv_kernel - 1`` inputs of the convolved q/k/v stream, in
     the compute type). A slot belongs to a running sequence or holds a
     snapshot the prefix trie can restore; the slot a row uses rides in its
-    table row beside its pages (``split_table``). A model with an expert
-    share also keeps its ``MOE_STATS`` accumulators here (``stats``).
+    table row beside its pages (``split_table``).
 
     ``impl`` is who updates the slots. The state kernel copies a slot's
     tail by its leading index, so under it a tail is held as whole tiles
@@ -675,7 +689,7 @@ def make_state(
     la = cfg.linear_attn
     n = cfg.count_mixers("linear")
     width = (la.conv_kernel - 1) * la.conv_size
-    state = {
+    return {
         "state": jnp.zeros(
             (n, slots, *state_slot_shape(la, impl)), STATE_DTYPE),
         # flat [..., (kernel - 1) * width]: a minor pair of (3, width) would
@@ -684,9 +698,6 @@ def make_state(
             (n, slots, *(conv_slot_shape(width) if impl == "pallas-state"
                          else (width,))), dtype),
     }
-    if _expert_share(cfg):
-        state["stats"] = jnp.zeros((len(MOE_STATS),), jnp.uint32)
-    return state
 
 
 def _expert_share(cfg: ModelConfig) -> bool:
@@ -736,24 +747,29 @@ def cache_specs(
     queries/outputs still shard over heads). Quantized pages: the scale
     plane drops the head-dim axis but keeps the kv-head axis, so it
     shards with its values."""
+    merged = (form or cache_form(cfg)) == "merged"
+    stats = {"stats": P(None)} if _expert_share(cfg) else {}
     if _latent_cache(cfg):
-        return {
-            "k": P(None, None, None, None, None),
-            "v": P(None, None, None, None, None),
-        }
+        def spec(rank: int):
+            values = P(*[None] * rank)
+            if kv_quantize:
+                return QuantizedPages(values, P(*[None] * (3 if merged else 4)))
+            return values
+
+        return {"k": spec(4 if merged else 5),
+                "v": spec(3 if merged else 5), **stats}
     scales = P(None, None, None, "tp")
     values = P(None, None, None, "tp", None)
-    if (form or cache_form(cfg)) == "merged":
+    if merged:
         values = scales     # [L, N, P, K*D]: a shard is K/tp whole heads
     if kv_quantize:
         values = QuantizedPages(values, scales)
     if cfg.has_state:
         rank = 2 + len(state_slot_shape(cfg.linear_attn, state_impl))
-        stats = {"stats": P(None)} if _expert_share(cfg) else {}
         return {"k": values, "v": values, "state": P(*[None] * rank),
                 "conv": P(*[None] * (
                     4 if state_impl == "pallas-state" else 3)), **stats}
-    return {"k": values, "v": values}
+    return {"k": values, "v": values, **stats}
 
 
 # -- building blocks --------------------------------------------------------
@@ -932,11 +948,19 @@ def _qkv_mla(
         [kv[..., dn:], jnp.zeros((B, S, H, dq - dv), kv.dtype)], axis=-1
     )
     if with_latent:
-        latent = jnp.concatenate(
-            [ckv[:, :, None, :], k_rope], axis=-1
-        ).astype(x.dtype)
-        return q, k, v, latent
+        return q, k, v, _latent_row(ckv, k_rope, cfg).astype(x.dtype)
     return q, k, v
+
+
+@scoped("mla_latent")
+def _latent_row(ckv, k_rope, cfg: ModelConfig):
+    """A token's row of the latent pages ``[B, S, 1, page_dim]``: ``[c_kv |
+    k_r]`` and zeros up to whole lane tiles (``MLAConfig.page_dim``)."""
+    m = cfg.mla
+    B, S, _ = ckv.shape
+    pad = jnp.zeros((B, S, 1, m.page_dim - m.latent_dim), ckv.dtype)
+    return jnp.concatenate(
+        [ckv[:, :, None, :], k_rope.astype(ckv.dtype), pad], axis=-1)
 
 
 def _mla_q(x, lp, cfg: ModelConfig, cos, sin):
@@ -946,7 +970,8 @@ def _mla_q(x, lp, cfg: ModelConfig, cos, sin):
     H = cfg.num_heads
     dn, dr = m.qk_nope_head_dim, m.qk_rope_head_dim
     if m.q_lora_rank:
-        cq = rms_norm(_mm(x, lp["wdq"]), lp["q_norm"], cfg.rms_norm_eps)
+        with jax.named_scope("mla_latent"):
+            cq = rms_norm(_mm(x, lp["wdq"]), lp["q_norm"], cfg.rms_norm_eps)
         q = _mm(cq, lp["wuq"])
     else:
         q = _mm(x, lp["wq"])
@@ -954,6 +979,7 @@ def _mla_q(x, lp, cfg: ModelConfig, cos, sin):
     return q[..., :dn], apply_rope(q[..., dn:], cos, sin)
 
 
+@scoped("mla_latent")
 def _mla_kv_latent(x, lp, cfg: ModelConfig, cos, sin):
     """(normed kv latent [B,S,rkv], roped shared key [B,S,1,dr])."""
     m = cfg.mla
@@ -984,10 +1010,11 @@ def _mla_latent_parts(x, lp, cfg: ModelConfig, cos, sin):
 
     Per head h: score_h(t) ∝ q_nope·(W_uk c_t) + q_rope·kr_t
               = (W_uk^T q_nope)·c_t + q_rope·kr_t — one MQA-style dot of
-    q_lat[h] = [W_uk^T q_nope[h], q_rope[h]] against latent_t = [c_t, kr_t].
-    The shared attention ops scale by latent_dim^-0.5, so q_lat is
-    pre-scaled by sqrt(latent_dim/qk_head_dim) (plus the YaRN correction)
-    to restore the true qk_head_dim^-0.5 softmax scale.
+    q_lat[h] = [W_uk^T q_nope[h], q_rope[h]] against latent_t = [c_t, kr_t],
+    both padded with zeros to the page row's width DL (``MLAConfig.
+    page_dim``). The shared attention ops scale by DL^-0.5, so q_lat is
+    pre-scaled by sqrt(DL/qk_head_dim) (plus the YaRN correction) to
+    restore the true qk_head_dim^-0.5 softmax scale.
 
     Returns (q_lat [B,S,H,DL], latent [B,S,1,DL])."""
     m = cfg.mla
@@ -995,15 +1022,17 @@ def _mla_latent_parts(x, lp, cfg: ModelConfig, cos, sin):
     dn = m.qk_nope_head_dim
     dv = m.v_head_dim
     rkv = m.kv_lora_rank
-    DL, dq = m.latent_dim, m.qk_head_dim
+    DL, dq = m.page_dim, m.qk_head_dim
     q_nope, q_rope = _mla_q(x, lp, cfg, cos, sin)
-    w_uk = _dense_weight(lp["wukv"]).reshape(rkv, H, dn + dv)[:, :, :dn]
-    q_abs = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk)      # [B,S,H,rkv]
-    scale = (DL ** 0.5) / (dq ** 0.5) * _yarn_q_scale(cfg)
-    q_lat = jnp.concatenate([q_abs, q_rope], axis=-1) * scale
+    with jax.named_scope("mla_absorb"):
+        w_uk = _dense_weight(lp["wukv"]).reshape(rkv, H, dn + dv)[:, :, :dn]
+        q_abs = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk)  # [B,S,H,rkv]
+        scale = (DL ** 0.5) / (dq ** 0.5) * _yarn_q_scale(cfg)
+        pad = jnp.zeros((*q_abs.shape[:3], DL - m.latent_dim), q_abs.dtype)
+        q_lat = jnp.concatenate(
+            [q_abs, q_rope.astype(q_abs.dtype), pad], axis=-1) * scale
     ckv, k_rope = _mla_kv_latent(x, lp, cfg, cos, sin)
-    latent = jnp.concatenate([ckv[:, :, None, :], k_rope], axis=-1)
-    return q_lat.astype(x.dtype), latent.astype(x.dtype)
+    return q_lat.astype(x.dtype), _latent_row(ckv, k_rope, cfg).astype(x.dtype)
 
 
 @scoped("attn_out")
@@ -1019,11 +1048,13 @@ def _mla_latent_out(ctx, lp, cfg: ModelConfig):
     dn, dv = m.qk_nope_head_dim, m.v_head_dim
     rkv = m.kv_lora_rank
     dq = m.qk_head_dim
-    w_uv = _dense_weight(lp["wukv"]).reshape(rkv, H, dn + dv)[:, :, dn:]
-    o = jnp.einsum("bshr,rhv->bshv", ctx[..., :rkv], w_uv)  # [B,S,H,dv]
-    o = jnp.concatenate(
-        [o, jnp.zeros((B, S, H, dq - dv), o.dtype)], axis=-1
-    )
+    with jax.named_scope("mla_absorb"):
+        w_uv = _dense_weight(lp["wukv"]).reshape(rkv, H, dn + dv)[:, :, dn:]
+        o = jnp.einsum("bshr,rhv->bshv", ctx[..., :rkv], w_uv)  # [B,S,H,dv]
+    if dq > dv:     # equal at GLM-4.7-Flash's 256-wide heads: nothing to pad
+        o = jnp.concatenate(
+            [o, jnp.zeros((B, S, H, dq - dv), o.dtype)], axis=-1
+        )
     return o.reshape(B, S, H * dq).astype(ctx.dtype)
 
 
@@ -1705,10 +1736,17 @@ def _run_stack(
             return (x, aux, cache, (ai + 1, *rest))
         return (x, aux, cache, (ai, si + 1))
 
+    def by_index(moe: bool) -> bool:
+        """A flat stack is scanned by its layers' indices where a layer's
+        leaves are taken where they are asked for (``_LayerView``): under
+        ``Pack.dense``'s conditional, and in an expert share, which reads
+        ONE expert at a time out of the whole stack."""
+        return pack is not None or (moe and share)
+
     def make_body(moe: bool, stack: Params):
-        def body(carry, lp):    # a layer's leaves; its index where packed
-            if pack is not None:
-                lp = _LayerView(stack, (lp,))
+        def body(carry, lp):    # a layer's leaves, or its index (by_index)
+            if by_index(moe):
+                lp = _LayerView(stack, (lp,), moe and share)
             return layer(carry, lp, "attn", moe), None
 
         def period(carry, p):
@@ -1729,12 +1767,12 @@ def _run_stack(
         body = period if runs else body
         return jax.checkpoint(body) if remat else body
 
-    def xs(stack: Params):
+    def xs(stack: Params, moe: bool):
         # A stack of like layers is scanned by its slices where nothing
         # branches: scanned by index the fused decode block compiles to the
         # same scratch, copies and bytes but runs 0.7% slower on the chip
         # (docs/ARCHITECTURE.md, "Two widths in the one program").
-        if not runs and pack is None:
+        if not runs and not by_index(moe):
             return stack
         return jnp.arange(jax.tree.leaves(stack)[0].shape[0])
 
@@ -1757,10 +1795,12 @@ def _run_stack(
     # own explicitly rather than relying on silent key-presence dispatch.
     if Ld and (stacks is None or "layers" in stacks):
         stack = params["layers"]
-        carry, _ = jax.lax.scan(make_body(False, stack), carry, xs(stack))
+        carry, _ = jax.lax.scan(
+            make_body(False, stack), carry, xs(stack, False))
     if Lm and (stacks is None or "moe_layers" in stacks):
         stack = params["moe_layers"]
-        carry, _ = jax.lax.scan(make_body(True, stack), carry, xs(stack))
+        carry, _ = jax.lax.scan(
+            make_body(True, stack), carry, xs(stack, True))
     x, aux, cache, _ = carry
     return x, (None if placeholder else cache), aux
 

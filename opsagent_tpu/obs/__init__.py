@@ -136,6 +136,17 @@ KV_WRITE_ROWS = _reg.counter(
     "bucket), kind=real the tokens among them that land on a page",
     labelnames=("kind",),
 )
+ATTN_CONTEXT_TOKENS = _reg.counter(
+    "opsagent_attn_context_tokens_total",
+    "Context tokens of the rows of mixed and decode dispatches, counted at "
+    "dispatch, a layer's worth a model pass: what=live the tokens alive in "
+    "the rows' contexts (what the attention needs), what=read the key slots "
+    "the reader of paged keys and values is handed (the xla gather: every "
+    "row's whole page table, whatever is alive; the streaming kernel: the "
+    "live rows' pages); live / read is the share of the read that is "
+    "context",
+    labelnames=("what",),
+)
 # -- async mixed serving runtime (serving/async_runtime.py) -------------------
 STEP_HOST_GAP_SECONDS = _reg.histogram(
     "opsagent_step_host_gap_seconds",

@@ -23,7 +23,13 @@ layer (56 copies of 1.17 GB a step at the 7B's 28 layers x 2560 pages,
 200 ms of a 511 ms step, PERF.md PR 25). Merged, the 16 page slots fill
 the tile's rows at any head count and write and gather share the tiling.
 ``write_pages`` and ``_gather_kv`` take either form and tell them apart
-by the trailing axis (``pages_merged``). The streaming kernel
+by the trailing axis (``pages_merged``). ONE kv head that every query head
+shares (MLA's latent) is held merged too, ``[layers, num_pages, page_size,
+page_dim]``, its row padded to whole lane tiles (``MLAConfig.page_dim``):
+576 wide, with or without a unit kv-head axis, the TPU holds the array
+pages-innermost, and the chip's compiler copied the whole cache to row-major
+at the entry of every step program and back at its exit (compile, PRs 30
+and 40). The streaming kernel
 (``paged_attention_stream``, "pallas-stream") reads merged pages at any
 head count, a kv head being a 128-lane slice of the page row.
 """
@@ -106,9 +112,10 @@ def page_form(kv_heads_per_shard: int, attn_impl: str = "xla") -> str:
     bf16 and int8 alike (both get 8-row tiles); at K = 8 split has no
     such copy and merged would add one of each gathered block. The
     streaming kernel gathers nothing and slices a kv head out of the
-    merged row's lanes, so under it every K above 1 is merged. One kv
-    head (MLA's latent, a tp shard of one head) is the same bytes either
-    way and keeps its unit axis."""
+    merged row's lanes, so under it every K above 1 is merged. A tp shard
+    of one head keeps its unit axis (the kv-head axis is what is sharded);
+    MLA's latent, one head by construction, is made without one
+    (``llama.make_cache``)."""
     if kv_heads_per_shard == 1:
         return "split"
     if attn_impl == "pallas-stream":
@@ -116,11 +123,15 @@ def page_form(kv_heads_per_shard: int, attn_impl: str = "xla") -> str:
     return "split" if kv_heads_per_shard % TILE_ROWS == 0 else "merged"
 
 
-def pages_merged(pages, head_dim: int) -> bool:
-    """Whether ``pages`` (an array or ``QuantizedPages``) are held merged.
-    One kv head is never merged (``page_form``), so a trailing axis wider
-    than the head dim says so."""
-    return pages.shape[-1] != head_dim
+def pages_merged(pages, head_dim: int, layer=None) -> bool:
+    """Whether ``pages`` (an array or ``QuantizedPages``) are held merged:
+    a trailing axis wider than the head dim says so. One head held merged
+    (MLA's latent, ``[L, N, P, D]``) has the head dim there, so its rank
+    says so, which takes knowing that the pages carry a layer axis: they
+    do wherever a caller names a ``layer``."""
+    if pages.shape[-1] != head_dim:
+        return True
+    return layer is not None and pages.ndim == 4
 
 
 def page_view(pages: jax.Array, page_shape: tuple[int, ...]) -> jax.Array:
@@ -549,13 +560,17 @@ def _write(pages, new: jax.Array, layer, flat_of) -> jax.Array:
     over the head dim) and scatter values and scales with the same flat
     indices, so the drop-sentinel/validity logic is shared."""
     K, D = new.shape[-2:]
+    merged = pages_merged(pages, D, layer)
     if isinstance(pages, QuantizedPages):
         q_new, s_new = quantize_kv_rows(new)
+        # the scales' row: a number a kv head, or just the number where one
+        # head is held merged (a unit axis would pad each to a tile's lanes)
+        s_row = pages.scale.shape[pages.q.ndim - (1 if merged else 2):]
         return QuantizedPages(
             _write(pages.q, q_new, layer, flat_of),
-            _scatter(pages.scale, s_new, (K,), layer, flat_of),
+            _scatter(pages.scale, s_new, s_row, layer, flat_of),
         )
-    row = pages.shape[-1:] if pages_merged(pages, D) else (K, D)
+    row = pages.shape[-1:] if merged else (K, D)
     return _scatter(pages, new, row, layer, flat_of)
 
 
@@ -621,7 +636,9 @@ def _gather_kv(
     if isinstance(k_pages, QuantizedPages):
         k_pages, k_scale = k_pages.q, k_pages.scale
         v_pages, v_scale = v_pages.q, v_pages.scale
-    lead = k_pages.ndim - (2 if pages_merged(k_pages, head_dim) else 3)
+    shared = v_pages is k_pages     # MLA's latent: keys and values alike
+    lead = k_pages.ndim - (
+        2 if pages_merged(k_pages, head_dim, layer) else 3)
     N, P = k_pages.shape[lead - 1 : lead + 1]
     base, nmax = 0, N - 1
     if lead == 2:
@@ -637,11 +654,14 @@ def _gather_kv(
     L = page_table.shape[1] * P
     safe_table = jnp.clip(page_table + base, 0, nmax)
     k_seq = k_pages[safe_table].reshape(B, L, -1, head_dim)
-    v_seq = v_pages[safe_table].reshape(B, L, k_seq.shape[2], -1)
     if k_scale is not None:
         ks = k_scale[safe_table].reshape(B, L, -1)
-        vs = v_scale[safe_table].reshape(B, L, -1)
         k_seq = _dequantize_gathered(k_seq, ks, dtype)
+    if shared:
+        return k_seq, k_seq
+    v_seq = v_pages[safe_table].reshape(B, L, k_seq.shape[2], -1)
+    if v_scale is not None:
+        vs = v_scale[safe_table].reshape(B, L, -1)
         v_seq = _dequantize_gathered(v_seq, vs, dtype)
     return k_seq, v_seq
 

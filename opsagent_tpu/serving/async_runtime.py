@@ -501,6 +501,7 @@ class AsyncMixedRuntime:
         starts, qlens = host["starts"], host["qlens"]
         with obs.phase("plan", part="account"):
             width = eng._count_step_tokens(S, int(qlens.sum()))
+            eng._count_context(starts + qlens, qlens > 0)
             ticket = eng.step_clock.enqueue(width)
             tick_id, t_disp, _ = ticket
             if eng._mixed_gap_stamp is not None:

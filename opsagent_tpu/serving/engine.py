@@ -259,8 +259,8 @@ class EngineConfig:
     # int8 + per-token-per-head f32 scales, ops.attention.QuantizedPages).
     # Halves decode-step KV reads — the dominant non-weight HBM term at
     # serving shapes (PERF.md roofline: ~4 GB/step at the 8B bench
-    # config). Flows through every paged-attention backend; unsupported
-    # for MLA latent caches.
+    # config). Served through the xla gather (the streaming kernel has no
+    # reader for int8 pages); an MLA latent gets one scale a token.
     kv_quantize: str = ""
     # Weight-stream backend for the quantized decode/mixed hot path: ""
     # (resolve from $OPSAGENT_WEIGHT_STREAM, default "xla") or explicit
@@ -514,13 +514,6 @@ class Engine:
             raise ValueError(
                 f"kv_quantize={cfg.kv_quantize!r}: only 'int8' is supported"
             )
-        if cfg.kv_quantize and self.model_cfg.mla is not None:
-            # MLA's latent cache feeds weight-absorbed matmuls (quantizing
-            # the shared latent is a different fidelity question), and the
-            # materialized layout packs mixed-width k/v planes; neither is
-            # validated under int8 pages — reject rather than silently
-            # degrade a V3-class deployment.
-            raise ValueError("kv_quantize is not supported for MLA models")
         if cfg.quantize and cfg.quantize not in ("int8", "int4"):
             raise ValueError(
                 f"quantize={cfg.quantize!r}: supported values are "
@@ -637,6 +630,12 @@ class Engine:
             ),
         )()
         self.cache_wire = jax.eval_shape(lambda: make("split"))
+        if cfg.offload and "stats" in self.cache:
+            raise BackendRefused(
+                "offload=True is not supported for a model with an expert "
+                "share: the host tier copies every leaf of the cache tree "
+                "by page, and the share's device counters ride in that tree"
+            )
         self.alloc = PageAllocator(
             cfg.num_pages, cfg.page_size, cfg.max_pages_per_seq,
             prefix_cache=cfg.prefix_cache,
@@ -2177,6 +2176,27 @@ class Engine:
         return llama.kv_write_form(
             self.model_cfg, self.cfg.max_batch_size * S, self.step_tokens)
 
+    def _count_context(self, ctx: np.ndarray, passes_of: np.ndarray,
+                       passes: int = 1) -> None:
+        """Count what a dispatch's attention reader is handed, a layer's
+        worth: ``ctx`` [rows] each row's context after the dispatch,
+        ``passes_of`` [rows] the model passes in which the row attends (0 or
+        False: an idle row; a row of a fused block writes a token a pass, so
+        its context was one shorter the pass before), ``passes`` the passes
+        the program runs."""
+        P = self.cfg.page_size
+        back = np.arange(passes)[None, :]
+        live = np.where(
+            back < np.asarray(passes_of, np.int64)[:, None],
+            np.asarray(ctx, np.int64)[:, None] - back, 0)
+        obs.ATTN_CONTEXT_TOKENS.inc(int(live.sum()), what="live")
+        if self.attn_impl == "xla":     # every row's whole table, each pass
+            read = (self.cfg.max_batch_size * self.cfg.max_pages_per_seq
+                    * P * passes)
+        else:                           # the live rows' pages
+            read = int((-(-live // P) * P).sum())
+        obs.ATTN_CONTEXT_TOKENS.inc(read, what="read")
+
     def _count_step_tokens(self, S: int, real: int) -> str:
         """Count a mixed dispatch of ``real`` tokens; returns the width its
         dense segments run over, as the counter's label has it (the step
@@ -2337,6 +2357,7 @@ class Engine:
             perf = get_perf_stats()
             with obs.phase("plan", part="account"):
                 width = self._count_step_tokens(S, int(qlens.sum()))
+                self._count_context(starts + qlens, qlens > 0)
                 ticket = self.step_clock.enqueue(width)
                 tick_id, t_disp, _ = ticket
                 # Dispatch-to-dispatch interval (the async A/B's comparison
@@ -2619,6 +2640,7 @@ class Engine:
                 top_p[i] = s.params.top_p
             perf = get_perf_stats()
             width = self._count_step_tokens(S, int(qlens.sum()))
+            self._count_context(starts + qlens, qlens > 0)
             ticket = self.step_clock.enqueue(width)
             tick_id, t_disp, _ = ticket
             try:
@@ -3662,6 +3684,12 @@ class Engine:
                 if self._inflight:
                     _merge_pulls(out, self._pull_oldest())
                 return out
+            with obs.phase("plan", part="account"):
+                self._count_context(
+                    np.asarray(
+                        [self.alloc.length(sid) if budgets[lane] else 0
+                         for lane, sid in enumerate(lane_seqs)], np.int64),
+                    budgets, block)
             with self._building_arrays():
                 table, _, _ = self.alloc.batch_views(lane_seqs, B)
                 slots = [

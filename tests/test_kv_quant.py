@@ -259,9 +259,12 @@ def test_engine_rejects_bad_kv_quantize_and_mla_combo():
 
     with pytest.raises(ValueError, match="kv_quantize"):
         Engine(EngineConfig(kv_quantize="int4", **_engine_kwargs()))
+    # int8 pages under MLA are served since PR 40 (through the gather; the
+    # latent with one scale a token: tests/test_mla.py has the parity)
     kwargs = dict(_engine_kwargs(), model="tiny-mla")
-    with pytest.raises(ValueError, match="MLA"):
-        Engine(EngineConfig(kv_quantize="int8", **kwargs))
+    eng = Engine(EngineConfig(kv_quantize="int8", **kwargs))
+    assert eng.impl_info()["kv_quantize"] == "int8"
+    assert eng.attn_impl == "xla"
 
 
 def test_engine_kv_quantize_speculative_matches_plain():

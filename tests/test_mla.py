@@ -238,8 +238,11 @@ def test_latent_cache_decode_chain_matches_oracle(params):
     full = llama.forward_full(params, LATENT_CFG, tokens, dtype=DTYPE)
 
     cache = llama.make_cache(LATENT_CFG, num_pages=8, page_size=4, dtype=DTYPE)
-    assert cache["k"].shape[-1] == LATENT_CFG.mla.latent_dim
-    assert cache["k"].shape[-2] == 1
+    # one merged row a token, [L, N, P, page_dim]: no unit kv-head axis,
+    # the 40-wide latent padded to the 128 lanes (as held before, the
+    # chip's compiler copied the whole cache a step)
+    assert (LATENT_CFG.mla.latent_dim, LATENT_CFG.mla.page_dim) == (40, 128)
+    assert cache["k"].shape == (LATENT_CFG.num_layers, 8, 4, 128)
     table = jnp.array([[2, 5, 7]], jnp.int32)
     logits, cache = llama.prefill(
         params, LATENT_CFG, tokens[:, :S_prompt], jnp.array([S_prompt]),

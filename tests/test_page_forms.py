@@ -85,7 +85,8 @@ def test_page_form_by_kv_heads_and_backend(kv_heads, impl, form):
 def test_cache_form_counts_the_heads_of_one_shard(kv_shards, form):
     assert TINY_TEST.num_kv_heads == 2
     assert llama.cache_form(TINY_TEST, kv_shards) == form
-    assert llama.cache_form(TINY_MLA_LATENT, kv_shards) == "split"
+    # the latent is one head whatever the shards: one merged row a token
+    assert llama.cache_form(TINY_MLA_LATENT, kv_shards) == "merged"
 
 
 # -- every token written is the token read -----------------------------------
@@ -209,10 +210,22 @@ def test_merged_and_split_caches_hold_the_same_bytes(kv):
         )
 
 
-def test_the_mla_latent_cache_is_one_split_head():
+def test_the_mla_latent_cache_is_one_merged_row_on_whole_lanes():
+    """Held: ``[L, N, P, page_dim]``, the latent padded to the 128 lanes
+    and no unit kv-head axis (either made the chip's compiler copy the
+    whole cache a step), and one number a token for the placeholder ``v``.
+    Off the device a page is ``[P, 1, page_dim]`` (the split form)."""
+    m = TINY_MLA_LATENT.mla
+    assert (m.latent_dim, m.page_dim) == (40, 128)
+    L = TINY_MLA_LATENT.num_layers
     cache = llama.make_cache(TINY_MLA_LATENT, 8, 4, jnp.float32)
-    assert cache["k"].shape[-2:] == (1, TINY_MLA_LATENT.mla.latent_dim)
-    assert cache["v"].shape[-2:] == (1, 1)
+    assert cache["k"].shape == (L, 8, 4, 128) and cache["v"].shape == (L, 8, 4)
+    wire = llama.make_cache(TINY_MLA_LATENT, 8, 4, jnp.float32, form="split")
+    assert wire["k"].shape == (L, 8, 4, 1, 128)
+    assert wire["v"].shape == (L, 8, 4, 1, 1)
+    int8 = llama.make_cache(TINY_MLA_LATENT, 8, 4, jnp.float32, "int8")
+    assert int8["k"].q.shape == (L, 8, 4, 128) and int8["k"].q.dtype == jnp.int8
+    assert int8["k"].scale.shape == (L, 8, 4)       # one scale a token
 
 
 # -- tensor parallel: a shard of merged pages is whole heads -----------------

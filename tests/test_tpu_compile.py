@@ -234,33 +234,38 @@ def test_int8_pages_gather_compiles_at_the_cells_shapes(v5e, cell, s):
     assert "tpu_custom_call" not in compiled.as_text()
 
 
-def test_mla_latent_gather_compiles_and_copies_the_latent_cache(v5e):
-    """MLA serves through the gather on the chip too: deepseek-v2-lite's
-    absorbed queries (16 heads of the 576-wide latent: 512 + 64 rope,
-    off the 128 lanes) over its one-head latent pages, which keys and
-    values share, at ``serve-engine``'s default geometry. The compiler
-    takes it, and copies the latent cache WHOLE to move the unit
-    kv-head axis out from between a page's 16 slots and the latent (one
-    copy a reader of the array; the whole mixed step has the same two at
-    its entry and its exit, not in the layer loop: ROADMAP S3). Pinned as
-    it is: no cell serves an MLA model, and the cure is a page form."""
-    cfg = get_config_preset("deepseek-v2-lite")
-    assert cfg.mla.latent_cache and cfg.mla.latent_dim % 128
+def test_mla_latent_gather_compiles_and_copies_no_latent_cache(v5e):
+    """MLA serves through the gather on the chip too: GLM-4.7-Flash's
+    absorbed queries (20 heads against the 576-wide latent: 512 + 64 rope)
+    over its one-head latent pages, which keys and values share, at the
+    geometry of ``glm47-flash-l12.longdoc-turns`` (too large for the
+    compiler to stage the array in fast memory, which reads as a copy too).
+    The latent is held merged and padded to whole lane tiles, ``[L, N, P,
+    640]`` (``MLAConfig.page_dim``), and the compiler takes the gather over
+    it with no copy of the cache. Held 576 wide it is copied WHOLE, once
+    for the keys' read and once for the values': the TPU holds an array
+    whose minor axis is off the 128 lanes pages-innermost (``{1,3,2,0}``),
+    with or without a unit kv-head axis (PR 30 pinned the copies and blamed
+    the unit axis), and the gather wants it row-major. Pinned from both
+    sides."""
+    cfg = get_config_preset("glm-4.7-flash")
+    m = cfg.mla
+    assert m.latent_cache and (m.latent_dim, m.page_dim) == (576, 640)
+    assert llama.cache_form(cfg) == "merged"
     sds = _one_chip(v5e)
-    layers = 2
-    pages = sds((layers, N, PAGE, 1, cfg.mla.latent_dim), jnp.bfloat16)
+    layers, n, maxp = 12, 16384, 1216
     assert attention.paged_attention_backend(
         platform="tpu", head_dim=cfg.head_dim_, kv_heads_per_shard=1,
         page_itemsize=2, mla=True,
     ) == "xla"
-    compiled = _gather(
-        sds, pages, b=B, s=16, h=cfg.num_heads, d=cfg.mla.latent_dim,
-        maxp=MAXP,
-    )
-    hlo = compiled.as_text()
-    assert "tpu_custom_call" not in hlo
-    copies = _copies_of(hlo, layers * N * PAGE * cfg.mla.latent_dim)
-    assert len(copies) == 2, copies     # the keys' read and the values'
+    for row, copied in (((640,), 0), ((576,), 2), ((1, 576), 2)):
+        pages = sds((layers, n, PAGE, *row), jnp.bfloat16)
+        compiled = _gather(
+            sds, pages, b=16, s=16, h=cfg.num_heads, d=row[-1], maxp=maxp)
+        hlo = compiled.as_text()
+        assert "tpu_custom_call" not in hlo
+        whole = layers * n * PAGE * row[-1]
+        assert len(_copies_of(hlo, whole)) == copied, row
 
 
 @pytest.mark.parametrize("cell", list(STREAM_CELLS))
@@ -582,15 +587,17 @@ def _mixed_step(sds, preset: str, kv: str, impl: str = "xla", *,
     return cfg, cache, _whole_cache_copies(compiled, cfg, preset, impl), compiled
 
 
-def _decode_block(sds, preset: str, impl: str, steps: int = 8):
+def _decode_block_compiled(sds, preset: str, impl: str, steps: int = 8, *,
+                           rows: int = STEP_ROWS, layers: int = STEP_LAYERS,
+                           int8: bool = False):
     """Compile the fused decode block (decode_loop.decode_block: ``steps``
     greedy passes under one scan, the cache its carry and donated), what
-    cell 2 runs between admissions."""
+    a cell runs between admissions: (config, cache shapes, executable)."""
     from opsagent_tpu.serving import decode_loop
 
-    cfg, params, cache, key = _step_shapes(sds, preset, "", impl)
+    cfg, params, cache, key = _step_shapes(sds, preset, "", impl, layers, int8)
     maxp = GEOMETRY[preset][1]
-    b = STEP_ROWS
+    b = rows
     i32 = lambda *s: sds(s, jnp.int32)       # noqa: E731
     f32 = lambda *s: sds(s, jnp.float32)     # noqa: E731
 
@@ -606,6 +613,12 @@ def _decode_block(sds, preset: str, impl: str, steps: int = 8):
         params, i32(b), i32(b), sds((b,), jnp.bool_), i32(b), cache,
         i32(b, maxp), key, f32(b), i32(b), f32(b), i32(), i32(),
     ).compile()
+    return cfg, cache, compiled
+
+
+def _decode_block(sds, preset: str, impl: str, steps: int = 8):
+    """The whole-K-array copies of ``_decode_block_compiled``'s program."""
+    cfg, cache, compiled = _decode_block_compiled(sds, preset, impl, steps)
     # By shape, not by size alone: at the 72B's widths one bf16 projection
     # stack [2, 8192, 8192] is larger than a K array, and this program
     # copies one at its entry (the cells' weights are int8: not compiled
@@ -1108,3 +1121,81 @@ def test_state_kernel_is_exported_once_a_shape(v5e, tmp_path, monkeypatch):
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
         new_process()
+
+
+
+# -- GLM-4.7-Flash at the new cell's shapes (glm47-flash-l12.longdoc-turns) ----
+# benchmarks/configs/glm47-flash-l12-int8.json: 16 rows, 16,384 pages of 16,
+# 1,216 a sequence, one mixed bucket of 16 packed to the step's 256 tokens,
+# fused decode blocks of 8, int8 weights, 12 layers (one dense, 11 of 64
+# experts), latent pages [12, 16384, 16, 640] (the 576-wide latent on whole lanes).
+GEOMETRY["glm-4.7-flash"] = (16384, 1216)
+CHIP_HBM_BYTES = 15.75 * 2**30      # what a v5e chip's runtime reports
+
+
+def _glm_cell():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmarks", "configs", "glm47-flash-l12-int8.json")) as f:
+        return json.load(f)
+
+
+def test_glm_flash_geometry_is_the_cells():
+    engine = _glm_cell()["engine"]
+    assert GEOMETRY["glm-4.7-flash"] == (
+        engine["num_pages"], engine["max_pages_per_seq"])
+    assert (engine["max_batch_size"], engine["mixed_buckets"],
+            engine["max_step_tokens"], engine["decode_block"]) == (
+        16, [16], 256, 8)
+    assert attention.paged_attention_backend(
+        platform="tpu", head_dim=256, kv_heads_per_shard=20,
+        page_itemsize=2, mla=True) == "xla"
+
+
+@pytest.mark.parametrize("kv", ["", "int8"], ids=["bf16", "int8-pages"])
+def test_glm_flash_mixed_step_copies_no_latent_cache_and_fits_the_chip(v5e, kv):
+    """The cell's one mixed program WHOLE (12 layers, int8 weights, every
+    expert, the full vocabulary, 16,384 latent pages; also with the int8
+    pages of the harness's control): no copy as large as the latent cache,
+    at the program's entry, its exit or in its layer loops (held with a
+    unit axis the cache was copied twice, 3.6 GB each at this size: it
+    would not have fitted), no layer's expert stack written out (an expert
+    share reads one expert at a time out of the whole stack), and
+    arguments, results and scratch together inside the chip's memory."""
+    cfg, cache, _, compiled = _mixed_step(
+        _one_chip(v5e), "glm-4.7-flash", kv, "xla", rows=16, tokens=16,
+        step_tokens=256, layers=12, int8=True)
+    assert cfg.moe_layer_start == 1 and cfg.moe.router_experts == 64
+    latent = jax.tree.leaves(cache["k"])[0]
+    assert latent.shape == (12, 16384, 16, 640)
+    assert "stats" in cache
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" not in hlo
+    assert _copies_of(hlo, int(np.prod(latent.shape))) == []
+    experts = 64 * cfg.hidden_size * cfg.moe.expert_intermediate_size
+    assert _copies_of(hlo, experts) == []
+    assert not re.search(
+        rf"(bf16|s8)\[64,{cfg.hidden_size},1536\]\S* (fusion|copy|dynamic-slice)\(",
+        hlo), "a layer's 64 experts taken out of the stack"
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert held < CHIP_HBM_BYTES, f"{held / 2**30:.2f} GiB"
+    print(f"glm mixed step [{kv or 'bf16'} pages]: arguments "
+          f"{m.argument_size_in_bytes / 2**30:.2f} GiB, scratch "
+          f"{m.temp_size_in_bytes / 2**30:.2f} GiB, held {held / 2**30:.2f} GiB")
+
+
+def test_glm_flash_decode_block_copies_no_latent_cache(v5e):
+    """The fused decode block at the cell's rows (8 passes under one scan,
+    the latent cache its carry): no copy as large as the cache."""
+    _, cache, compiled = _decode_block_compiled(
+        _one_chip(v5e), "glm-4.7-flash", "xla", rows=16, layers=12, int8=True)
+    assert _copies_of(
+        compiled.as_text(), int(np.prod(cache["k"].shape))) == []
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert held < CHIP_HBM_BYTES, f"{held / 2**30:.2f} GiB"
+    print(f"glm decode block: scratch {m.temp_size_in_bytes / 2**30:.2f} GiB, "
+          f"held {held / 2**30:.2f} GiB")
