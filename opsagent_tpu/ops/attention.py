@@ -31,7 +31,9 @@ pages-innermost, and the chip's compiler copied the whole cache to row-major
 at the entry of every step program and back at its exit (compile, PRs 30
 and 40). The streaming kernel
 (``paged_attention_stream``, "pallas-stream") reads merged pages at any
-head count, a kv head being a 128-lane slice of the page row.
+head count, a kv head being a slice of whole 128-lane tiles of the page
+row; the latent is its one head of ``page_dim`` lanes, handed once as
+keys and values alike.
 """
 
 from __future__ import annotations
@@ -168,6 +170,8 @@ def paged_attention_backend(
     kv_heads_per_shard: int,
     page_itemsize: int,
     mla: bool = False,
+    shared_kv: bool = False,
+    tp: int = 1,
 ) -> str:
     """Which reader of paged keys and values an engine runs: "xla" (the
     gather, and the oracle of every test) or "pallas-stream" (the
@@ -175,21 +179,25 @@ def paged_attention_backend(
 
     The choice is the code's, a pure function of what it can observe
     where the engine is built: the platform of the mesh's devices and the
-    shapes ``pallas_refusal`` takes; nothing outside the code names a
-    reader. On a TPU it is the streaming kernel
-    wherever the chip's compiler takes it (head dim on the 128-lane
-    tiling, bf16 pages, no MLA); everywhere else (the CPU, int8 pages,
-    MLA's 192-wide qk heads, head dims off the tiling) the gather. By
-    measurement (PERF.md section 6, PR 29, ``scripts/attn_microbench.py``
-    at the benchmark cells' shapes on a v5e): the kernel is ahead of the
-    gather at every shape the cells run, decode blocks over short rows
-    included, so no shape is sent back to the gather on speed."""
+    shapes ``pallas_refusal`` takes, which are the READER's (an MLA model
+    that holds the latent describes itself as what its reader is handed:
+    one kv head of ``MLAConfig.page_dim`` lanes that is keys and values
+    alike); nothing outside the code names a reader. On a TPU it is the
+    streaming kernel wherever the chip's compiler takes it (head dim on
+    the 128-lane tiling, bf16 pages; MLA's absorbed attention over latent
+    pages among them); everywhere else (the CPU, int8 pages, head dims
+    off the tiling, MLA with materialised heads, the latent under tp > 1)
+    the gather. By measurement (PERF.md section 6, PRs 29 and 41,
+    ``scripts/attn_microbench.py`` at the benchmark cells' shapes on a
+    v5e): the kernel is ahead of the gather at every shape the cells run,
+    decode blocks over short rows included, so no shape is sent back to
+    the gather on speed."""
     if platform != "tpu":
         return "xla"
     refused = pallas_refusal(
         "pallas-stream", head_dim=head_dim,
         kv_heads_per_shard=kv_heads_per_shard,
-        page_itemsize=page_itemsize, mla=mla,
+        page_itemsize=page_itemsize, mla=mla, shared_kv=shared_kv, tp=tp,
     )
     return "xla" if refused else "pallas-stream"
 
@@ -253,12 +261,18 @@ def pallas_refusal(
     kv_heads_per_shard: int,
     page_itemsize: int,
     mla: bool = False,
+    shared_kv: bool = False,
+    tp: int = 1,
 ) -> str | None:
     """Why paged-attention backend ``impl`` cannot serve these shapes, or
-    None when it can: MLA, int8 pages (the streaming kernel has no reader
-    for either) and a head dim off the 128 lanes (Mosaic's refusal when
-    the kernel was compiled for a described v5e device;
-    tests/test_tpu_compile.py keeps both sides of each rule).
+    None when it can. The shapes are what the READER is handed: for an
+    MLA model that holds the latent, one kv head of ``page_dim`` lanes
+    whose pages are keys and values alike (``shared_kv``), under the
+    absorbed queries. What the streaming kernel has no reader for: MLA
+    with materialised heads (``mla`` without ``shared_kv``), the latent
+    under ``tp`` > 1, int8 pages, and a head dim off the 128 lanes
+    (Mosaic's refusal when the kernel was compiled for a described v5e
+    device; tests/test_tpu_compile.py keeps both sides of each rule).
     ``paged_attention_backend`` sends such an engine to the gather, so no
     such combination reaches the chip to fail there. Interpret mode has
     no Mosaic and not its tiling limit.
@@ -268,11 +282,21 @@ def pallas_refusal(
     by; no rule reads it, the kernel takes any head count."""
     if impl == "xla":
         return None
-    if mla:
+    if mla and not shared_kv:
         return (
-            f"paged backend {impl!r} with an MLA model: the qk head dim "
-            "(nope + rope, e.g. 192) breaks the Pallas kernel's last-dim "
-            "tiling; MLA serves through the xla gather"
+            f"paged backend {impl!r} with MLA's materialised heads (no "
+            "latent cache): keys of nope + rope dims (192 or 256 wide) "
+            "beside narrower values padded to them, a form the kernel has "
+            "never been compiled for or run at; it serves through the "
+            "xla gather"
+        )
+    if shared_kv and tp > 1:
+        return (
+            f"paged backend {impl!r} over pages that are keys and values "
+            f"alike (MLA's latent) at tp={tp}: one replicated kv head "
+            "under sharded query heads has never been compiled or run "
+            "inside the kernel's shard_map; it serves through the xla "
+            "gather"
         )
     if page_itemsize == 1:
         return (
@@ -305,16 +329,18 @@ def _require_reader(impl: str) -> None:
         )
 
 
-def _require_form(pages, head_dim: int, tp: int = 1) -> None:
+def _require_form(pages, head_dim: int, tp: int = 1, layer=None) -> None:
     """The streaming kernel takes the form ``page_form`` gives it at the
     kv heads a shard holds (``tp`` shards of the pages' kv-head axis):
-    merged float pages, or one head a shard with its unit axis."""
+    merged float pages (one head held merged among them: MLA's latent,
+    told by ``layer`` as ``pages_merged`` tells it), or one head a shard
+    with its unit axis."""
     if isinstance(pages, QuantizedPages):
         raise ValueError(pallas_refusal(
             "pallas-stream", head_dim=head_dim, kv_heads_per_shard=1,
             page_itemsize=1,
         ))
-    merged = pages_merged(pages, head_dim)
+    merged = pages_merged(pages, head_dim, layer)
     if not merged and pages.shape[-2] != tp:
         raise ValueError(
             f"paged backend 'pallas-stream' was given split pages "
@@ -397,7 +423,7 @@ def paged_decode_attention_auto(
     ``QuantizedPages`` flow through the XLA gather; the streaming kernel
     refuses them by name (``pallas_refusal``)."""
     if impl == "pallas-stream":
-        _require_form(k_pages, q.shape[-1], _tp(mesh))
+        _require_form(k_pages, q.shape[-1], _tp(mesh), layer)
         interpret = pallas_interpret()
         if _tp(mesh) > 1:
             return paged_decode_attention_pallas_tp(
@@ -835,7 +861,7 @@ def paged_ragged_attention_auto(
     (``paged_attention_stream``), which refuses int8 ``QuantizedPages``
     by name; an engine with int8 pages resolves to the gather."""
     if impl == "pallas-stream":
-        _require_form(k_pages, q.shape[-1], _tp(mesh))
+        _require_form(k_pages, q.shape[-1], _tp(mesh), layer)
         interpret = pallas_interpret()
         if _tp(mesh) > 1:
             return paged_ragged_attention_pallas_tp(

@@ -28,10 +28,18 @@ what the arithmetic needs:
   at any head count; the cache is neither re-tiled nor padded. One kv
   head a shard is the same bytes with a unit axis.
 - **Query slots past ``q_len`` cost little.** Live rows are a prefix of
-  the ``s``-major query rows; a block whose live rows fit the first
-  ``ROWS_SMALL`` (a decode row inside a ``[32, 32]`` mixed step) runs the
-  same loop over those rows alone. Key blocks wholly below the first
-  query need no mask and get none.
+  the ``s``-major query rows; a block whose live rows fit the decode
+  branch (a decode row inside a ``[32, 32]`` mixed step) runs the same
+  loop over those rows alone. The branch holds one query slot's heads:
+  ``ROWS_SMALL`` rows wherever the group fits them, the group rounded up
+  to whole tiles above that (32 rows for MLA's 20 absorbed heads). Key
+  blocks wholly below the first query need no mask and get none.
+- **A page that is keys and values alike is fetched once.** MLA's latent
+  cache hands the kernel ONE array twice (``v_pages is k_pages``): one kv
+  head of ``MLAConfig.page_dim`` lanes under the absorbed queries, a
+  group of every query head. The kernel then holds one page buffer, one
+  DMA and one wait a page, and the values are the keys' block in VMEM.
+  Nothing else here knows of MLA: it is ``K`` = 1 at a head dim of 640.
 - **Traced and lowered once a shape.** The kernel's body is Python that
   every step program holding it would trace and lower again; each shape
   is exported once (``_kernel_call``) and the programs inline its bytes,
@@ -39,7 +47,8 @@ what the arithmetic needs:
 
 Correctness oracle: ``ops.attention.paged_ragged_attention`` (interpret
 mode on the CPU, tests/test_pallas_paged.py); the chip's compiler is asked
-at the benchmark cells' shapes in tests/test_tpu_compile.py.
+at the benchmark cells' shapes, the latent's among them, in
+tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -56,7 +65,7 @@ from .pallas_export import exported_call
 NEG_INF = -1e30
 
 QUERY_BLOCK_TOKENS = 64   # query slots a grid step (x G rows a kv head)
-ROWS_SMALL = 16           # the decode branch: one bf16 tile of query rows
+ROWS_SMALL = 16           # the decode branch: bf16 tiles of 16 query rows
 PAGES_UNROLL = 8          # page copies written out a turn of the fetch loop
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
@@ -66,9 +75,14 @@ def key_block_pages(
 ) -> int:
     """Pages a compute step: 512 key positions where a double-buffered K
     and V block of them stays within 4 MB and the f32 scores of the
-    query rows within 512 KB, else 256; never more than the table holds."""
+    query rows within 640 KB, else 256; never more than the table holds.
+    (640 KB is 320 rows, a mixed bucket of 16 under MLA's 20 absorbed
+    heads: there 512 positions read 18 % faster than 256, most rows of
+    such a step being decode rows that pay a block's fixed costs; 448
+    rows and more, the GQA cells' widest blocks, keep 256. PERF.md
+    section 6, PRs 29 and 41.)"""
     tokens = 512
-    if 4 * tokens * row_bytes > 4 * 1024 * 1024 or rows * tokens * 4 > 512 * 1024:
+    if 4 * tokens * row_bytes > 4 * 1024 * 1024 or rows * tokens * 4 > 640 * 1024:
         tokens = 256
     return max(1, min(tokens // page_size, max_pages))
 
@@ -83,12 +97,12 @@ def _kernel(
     q_ref,         # [1, K, TM, D] the block's queries, row s*G + g
     slot_ref,      # [TM, 1] int32 query slot of each row inside the block
     k_hbm,         # [pages, P, K*D] in HBM
-    v_hbm,         # like k_hbm
+    v_hbm,         # like k_hbm; None: the keys' pages are the values too
     o_ref,         # [1, K, TM, D]
     # scratch
     k_buf,         # [2, bp, P, K*D]
-    v_buf,         # [2, bp, P, K*D]
-    sem,           # DMA [2 (k, v), 2 slots]
+    v_buf,         # [2, bp, P, K*D]; None with ``v_hbm``
+    sem,           # DMA [2 (k, v; 1 with no ``v_hbm``), 2 slots]
     m_ref,         # [K, TM, 1] f32 running max
     l_ref,         # [K, TM, 1] f32 running sum
     acc_ref,       # [K, TM, D] f32
@@ -97,6 +111,7 @@ def _kernel(
     group: int,          # G: query heads a kv head
     max_pages: int,
 ):
+    shared = v_hbm is None      # one fetch a page, keys and values alike
     b = pl.program_id(0)
     qi = pl.program_id(1)
     _, K, TM, D = q_ref.shape
@@ -136,16 +151,18 @@ def _kernel(
             jax.lax.fori_loop(0, bp // group_pages, some, None)
 
     def fetch(i, slot):
-        # One DMA a page, K and V: pages of a row are anywhere in the pool.
+        # One DMA a page, K and V (one in all where the page is both):
+        # pages of a row are anywhere in the pool.
         def one(j):
             at = jnp.minimum(i * bp + j, n_pages - 1)
             page = jnp.maximum(table_ref[b, at], 0) + base_ref[0]
             pltpu.make_async_copy(
                 k_hbm.at[page], k_buf.at[slot, j], sem.at[0, slot]
             ).start()
-            pltpu.make_async_copy(
-                v_hbm.at[page], v_buf.at[slot, j], sem.at[1, slot]
-            ).start()
+            if not shared:
+                pltpu.make_async_copy(
+                    v_hbm.at[page], v_buf.at[slot, j], sem.at[1, slot]
+                ).start()
 
         pages_loop(one)
 
@@ -155,11 +172,17 @@ def _kernel(
             pltpu.make_async_copy(
                 k_hbm.at[0], k_buf.at[slot, j], sem.at[0, slot]
             ).wait()
-            pltpu.make_async_copy(
-                v_hbm.at[0], v_buf.at[slot, j], sem.at[1, slot]
-            ).wait()
+            if not shared:
+                pltpu.make_async_copy(
+                    v_hbm.at[0], v_buf.at[slot, j], sem.at[1, slot]
+                ).wait()
 
         pages_loop(one)
+
+    # The decode branch holds one query slot's heads: a tile of 16 rows
+    # wherever the group fits one (every GQA cell), whole tiles above it
+    # (MLA's 20 absorbed heads over the one latent head: 32).
+    small = min(_round_up(group, ROWS_SMALL), TM)
 
     def run(rows: int):
         """The streaming softmax over the block's first ``rows`` rows."""
@@ -183,7 +206,8 @@ def _kernel(
             def head(k, _):
                 lanes = pl.ds(pl.multiple_of(k * D, D), D)
                 keys = k_buf[slot, :, :, lanes].reshape(kb, D)
-                vals = v_buf[slot, :, :, lanes].reshape(kb, D)
+                vals = keys if shared else (
+                    v_buf[slot, :, :, lanes].reshape(kb, D))
                 s = jax.lax.dot_general(
                     q_ref[0, k, :rows], keys,
                     dimension_numbers=(((1,), (1,)), ((), ())),
@@ -209,10 +233,10 @@ def _kernel(
             # dynamic slices read 15-28 % slower (PERF.md section 6, PR 29).
             jax.lax.fori_loop(0, K, head, None, unroll=True)
 
-        # A decode row's 16 query rows mask for nothing; only the wide
+        # A decode row's query rows mask for nothing; only the wide
         # branch is worth a second, unmasked copy of the loop.
         first_masked = 0
-        if rows > ROWS_SMALL:
+        if rows > small:
             first_masked = n_open
             jax.lax.fori_loop(
                 0, n_open, lambda i, _: step(i, masked=False), None
@@ -225,8 +249,6 @@ def _kernel(
         ).astype(o_ref.dtype)
         if rows < TM:
             o_ref[0, :, rows:] = jnp.zeros((K, TM - rows, D), o_ref.dtype)
-
-    small = min(ROWS_SMALL, TM)
 
     @pl.when(live == 0)
     def _inactive():
@@ -242,6 +264,18 @@ def _kernel(
         run(TM)
 
 
+def _kernel_shared(
+    table_ref, start_ref, qlens_ref, base_ref, q_ref, slot_ref, k_hbm, o_ref,
+    k_buf, *scratch, **static,
+):
+    """``_kernel`` for pages that are keys and values alike: no value
+    pages among the operands, no second page buffer in the scratch."""
+    _kernel(
+        table_ref, start_ref, qlens_ref, base_ref, q_ref, slot_ref, k_hbm,
+        None, o_ref, k_buf, None, *scratch, **static,
+    )
+
+
 def _largest_divisor(n: int, cap: int) -> int:
     return next(d for d in range(min(n, cap), 0, -1) if n % d == 0)
 
@@ -252,12 +286,14 @@ def _round_up(n: int, m: int) -> int:
 
 def _pallas_call(
     *, B, nQ, K, TM, D, TS, G, MaxP, bp, P, pages, page_dtype, q_dtype,
-    interpret,
+    interpret, shared=False,
 ):
     """The ``pallas_call`` of one kernel shape: ``(table, start, q_lens,
     base, queries [B, K, nQ*TM, D], slot_of_row, k_pages, v_pages
-    [pages, P, K*D]) -> [B, K, nQ*TM, D]``."""
+    [pages, P, K*D]) -> [B, K, nQ*TM, D]``; ``shared`` (the values are the
+    keys' pages) takes no ``v_pages`` and holds one page buffer."""
     KD = K * D
+    sides = 1 if shared else 2
     q_spec = pl.BlockSpec(
         (1, K, TM, D), lambda b, i, *_: (b, 0, i, 0),
         memory_space=pltpu.VMEM,
@@ -270,14 +306,12 @@ def _pallas_call(
             pl.BlockSpec(
                 (TM, 1), lambda b, i, *_: (0, 0), memory_space=pltpu.VMEM
             ),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            *[pl.BlockSpec(memory_space=pl.ANY)] * sides,
         ],
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((2, bp, P, KD), page_dtype),
-            pltpu.VMEM((2, bp, P, KD), page_dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            *[pltpu.VMEM((2, bp, P, KD), page_dtype)] * sides,
+            pltpu.SemaphoreType.DMA((sides, 2)),
             pltpu.VMEM((K, TM, 1), jnp.float32),
             pltpu.VMEM((K, TM, 1), jnp.float32),
             pltpu.VMEM((K, TM, D), jnp.float32),
@@ -289,7 +323,8 @@ def _pallas_call(
     )
     return pl.pallas_call(
         functools.partial(
-            _kernel, block_tokens=TS, group=G, max_pages=MaxP,
+            _kernel_shared if shared else _kernel,
+            block_tokens=TS, group=G, max_pages=MaxP,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, nQ * TM, D), q_dtype),
@@ -301,7 +336,7 @@ def _pallas_call(
         cost_estimate=pl.CostEstimate(
             flops=2 * 2 * nQ * TM * K * D * live_tokens,
             bytes_accessed=(
-                2 * nQ * live_tokens * KD * page_bytes
+                sides * nQ * live_tokens * KD * page_bytes
                 + 2 * B * K * nQ * TM * D * q_bytes
             ),
             transcendentals=nQ * TM * K * live_tokens,
@@ -328,7 +363,7 @@ def _kernel_call(*, inline: bool, **shape):
     args = (
         i32((B, shape["MaxP"])), i32((B,)), i32((B,)), i32((1,)),
         jax.ShapeDtypeStruct((B, K, nQ * TM, D), shape["q_dtype"]),
-        i32((TM, 1)), pages, pages,
+        i32((TM, 1)), *[pages] * (1 if shape["shared"] else 2),
     )
     return exported_call(
         call, args, name="paged_attention_stream", source=__file__,
@@ -336,11 +371,10 @@ def _kernel_call(*, inline: bool, **shape):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "block_pages"))
 def paged_ragged_attention_stream(
     q: jax.Array,           # [B, S, H, D] right-padded ragged queries
     k_pages: jax.Array,     # [(L,) N, P, K*D] merged, or [(L,) N, P, 1, D]
-    v_pages: jax.Array,     # like k_pages
+    v_pages: jax.Array,     # like k_pages, or ``k_pages`` itself
     page_table: jax.Array,  # [B, MaxP] int32
     start: jax.Array,       # [B] int32 tokens already in cache per row
     q_lens: jax.Array,      # [B] int32 valid query slots (0 = inactive)
@@ -350,7 +384,8 @@ def paged_ragged_attention_stream(
 ) -> jax.Array:
     """Streaming ragged paged attention (module header). Same contract as
     ``ops.attention.paged_ragged_attention``; rows of a block past its
-    live queries come back as zeros."""
+    live queries come back as zeros. Handed ONE array as keys and values
+    (``v_pages is k_pages``: MLA's latent), it fetches a page once."""
     from .attention import QuantizedPages
 
     if isinstance(k_pages, QuantizedPages):
@@ -358,13 +393,28 @@ def paged_ragged_attention_stream(
             "pallas-stream reads bf16/f32 pages; int8 QuantizedPages go "
             "through the xla gather (ops.attention.pallas_refusal)"
         )
+    # Seen here, outside the jit: inside it two arguments are two tracers.
+    return _stream(
+        q, k_pages, None if v_pages is k_pages else v_pages, page_table,
+        start, q_lens, layer, interpret=interpret, block_pages=block_pages,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "block_pages"))
+def _stream(
+    q, k_pages, v_pages, page_table, start, q_lens, layer, *, interpret,
+    block_pages,
+):
+    """``paged_ragged_attention_stream`` with ``v_pages`` None where the
+    values are the keys' pages."""
+    held = [k_pages] if v_pages is None else [k_pages, v_pages]
     B, S, H, D = q.shape
     if k_pages.shape[-1] == D and k_pages.shape[-2] == 1:
         # One kv head a shard: [.., P, 1, D] is the merged row's bytes.
-        k_pages = k_pages.reshape(*k_pages.shape[:-2], D)
-        v_pages = v_pages.reshape(*v_pages.shape[:-2], D)
-    KD = k_pages.shape[-1]
-    if k_pages.ndim not in (3, 4) or KD % D or D % 128 and not interpret:
+        held = [p.reshape(*p.shape[:-2], D) for p in held]
+    lead = held[0].shape[:-2]
+    P, KD = held[0].shape[-2:]
+    if len(lead) not in (1, 2) or KD % D or D % 128 and not interpret:
         raise ValueError(
             f"pallas-stream wants merged pages [(L,) N, P, K*D] and a head "
             f"dim on the 128-lane tiling; got pages {tuple(k_pages.shape)} "
@@ -372,14 +422,10 @@ def paged_ragged_attention_stream(
         )
     K = KD // D
     G = H // K
-    if k_pages.ndim == 4:
-        Lr, N, P, _ = k_pages.shape
-        k_pages = k_pages.reshape(Lr * N, P, KD)
-        v_pages = v_pages.reshape(Lr * N, P, KD)
-        base = (layer if layer is not None else 0) * N
-    else:
-        N, P, _ = k_pages.shape
-        base = 0
+    base = 0
+    if len(lead) == 2:
+        held = [p.reshape(-1, P, KD) for p in held]
+        base = (layer if layer is not None else 0) * lead[1]
     MaxP = page_table.shape[1]
 
     TS = min(S, QUERY_BLOCK_TOKENS)
@@ -400,8 +446,8 @@ def paged_ragged_attention_stream(
 
     call = _kernel_call(
         B=B, nQ=nQ, K=K, TM=TM, D=D, TS=TS, G=G, MaxP=MaxP, bp=bp, P=P,
-        pages=k_pages.shape[0], page_dtype=k_pages.dtype.name,
-        q_dtype=q.dtype.name, interpret=interpret,
+        pages=held[0].shape[0], page_dtype=k_pages.dtype.name,
+        q_dtype=q.dtype.name, interpret=interpret, shared=len(held) == 1,
         # Inside a shard_map (tp > 1) the kernel is one shard's, and an
         # export made there would be lowered for the whole mesh.
         inline=interpret or bool(
@@ -411,7 +457,7 @@ def paged_ragged_attention_stream(
     out = call(
         page_table.astype(jnp.int32), start.astype(jnp.int32),
         q_lens.astype(jnp.int32), jnp.full((1,), base, jnp.int32),
-        qs, slot_of_row, k_pages, v_pages,
+        qs, slot_of_row, *held,
     )
     out = out.reshape(B, K, nQ, TM, D)[:, :, :, : TS * G]
     out = out.reshape(B, K, nQ, TS, G, D).transpose(0, 2, 3, 1, 4, 5)

@@ -451,13 +451,19 @@ class Engine:
                 f"weight_stream=pallas-dma is single-shard only (tp={tp})"
             )
         self.weight_stream_impl = ws
+        # What the reader of the pages is handed: heads of ``head_dim_``,
+        # or, where MLA holds the latent, its one row of ``page_dim``
+        # lanes that is keys and values alike under the absorbed queries.
+        mla = self.model_cfg.mla
+        latent = mla is not None and mla.latent_cache
         shapes = dict(
-            head_dim=self.model_cfg.head_dim_,
-            kv_heads_per_shard=max(1, self.model_cfg.num_kv_heads // tp),
+            head_dim=mla.page_dim if latent else self.model_cfg.head_dim_,
+            kv_heads_per_shard=1 if latent else max(
+                1, self.model_cfg.num_kv_heads // tp),
             page_itemsize=(
                 1 if cfg.kv_quantize else jnp.dtype(cfg.dtype).itemsize
             ),
-            mla=self.model_cfg.mla is not None,
+            mla=mla is not None, shared_kv=latent, tp=tp,
         )
         platform = self.mesh.devices.flat[0].platform
         self.attn_impl = paged_attention_backend(platform=platform, **shapes)

@@ -41,7 +41,14 @@ SHAPES = {
               (32, 64)),
     "cell2": (16, 64, 8, 128, 2048, 104, (512, 1600), 3, (1, 64), (32, 64)),
     "cell3": (32, 64, 8, 128, 12288, 512, (2048, 7000), 4, (1, 16), (32, 64)),
+    "cell4": (16, 30, 30, 128, 2048, 336, (1024, 5000), 2, (1, 16, 32),
+              (32,)),
+    # MLA's absorbed queries over the latent pages, which keys and values
+    # share (one array handed twice, as ``llama.mixed_step`` hands it)
+    "cell5": (16, 20, 1, 640, 16384, 1216, (5000, 18000), 2, (1, 16),
+              (16, 32)),
 }
+SHARED = {"cell5", "toy-latent"}   # values are the keys' own pages
 
 
 def make_rows(rng, B, S, N, MaxP, span, chunk_rows):
@@ -74,10 +81,15 @@ def timed(fn, args):
     return (time.perf_counter() - t0) / REPS / LAYERS * 1e3, out
 
 
-def over_layers(op):
+def over_layers(op, shared=False):
     """``op(q, kc, vc, table, start, q_lens, layer)`` through LAYERS layers
-    of one stacked cache, each layer's output feeding the next query."""
+    of one stacked cache, each layer's output feeding the next query.
+    ``shared``: the values are the keys' pages, one array handed twice (a
+    ``jit`` would make two of it), so a reader can tell."""
     def run(q, kc, vc, table, start, q_lens):
+        if shared:
+            vc = kc
+
         def body(x, layer):
             out = op(x, kc, vc, table, start, q_lens, layer)
             return (q + out * 0.01).astype(q.dtype), out
@@ -132,6 +144,8 @@ def main() -> int:
     if REHEARSE:
         SHAPES.clear()
         SHAPES["toy"] = (3, 8, 4, 128, 40, 12, (60, 150), 1, (1, 16), (2, 4))
+        SHAPES["toy-latent"] = (
+            3, 20, 1, 128, 40, 12, (60, 150), 1, (1, 16), (2,))
     elif dev.platform != "tpu":
         print(f"attn_microbench: needs a TPU, found {dev.platform}")
         return 1
@@ -145,7 +159,7 @@ def main() -> int:
             rng.standard_normal((LAYERS, N, PAGE, K * D), np.float32),
             jnp.bfloat16,
         )
-        vc = jnp.asarray(
+        vc = kc if name in SHARED else jnp.asarray(
             rng.standard_normal((LAYERS, N, PAGE, K * D), np.float32),
             jnp.bfloat16,
         )
@@ -166,10 +180,12 @@ def main() -> int:
                     np.ceil((start + q_lens) / PAGE).sum() / (B * MaxP)), 3),
                 "ms_per_layer": {}, "max_err_vs_gather": {},
             }
+            shared = name in SHARED
             ms, ref = timed(over_layers(
                 lambda x, k_, v_, t, st, ql, ly: (
                     attention.paged_ragged_attention(
-                        x, k_, v_, t, st, ql, layer=ly))
+                        x, k_, v_, t, st, ql, layer=ly)),
+                shared,
             ), args)
             line["ms_per_layer"]["gather"] = ms and round(ms, 4)
             ref = np.asarray(ref, np.float32)
@@ -184,7 +200,7 @@ def main() -> int:
             }
             for cand, op in candidates.items():
                 try:
-                    ms, got = timed(over_layers(op), args)
+                    ms, got = timed(over_layers(op, shared), args)
                 except Exception as e:  # noqa: BLE001 - a refusal is a finding
                     line["ms_per_layer"][cand] = f"refused: {str(e)[:200]}"
                     continue

@@ -100,19 +100,23 @@ def fake_tools():
 def stream_kernel():
     """The tests' handle on the streaming attention kernel off the chip:
     an engine built AND run inside ``with stream_kernel():`` runs
-    "pallas-stream", interpreted, wherever the kernel has a reader (no
-    int8 pages, no MLA; interpret mode has none of Mosaic's tiling limits,
-    so the tiny presets' head dims of 16 pass); outside it the same test
-    builds the gather's engine to compare with. The engine imports the
-    choice function inside ``__init__``, so patching the module's
-    attribute reaches it, and interpret mode is read when a step program
-    is traced. Nothing in the program can name a reader."""
+    "pallas-stream", interpreted, wherever the kernel has a reader: the
+    program's own rules (``pallas_refusal``: no int8 pages, no MLA with
+    materialised heads, no latent under tp > 1) but for Mosaic's lane
+    tiling, which interpret mode has not, so the tiny presets' head dims
+    of 16 and tiny latent rows pass; outside it the same test builds the
+    gather's engine to compare with. The engine imports the choice
+    function inside ``__init__``, so patching the module's attribute
+    reaches it, and interpret mode is read when a step program is traced.
+    Nothing in the program can name a reader."""
     import contextlib
 
     from opsagent_tpu.ops import attention
 
-    def choice(*, page_itemsize, mla=False, **_):
-        return "xla" if mla or page_itemsize == 1 else "pallas-stream"
+    def choice(*, platform, head_dim, **shapes):
+        refused = attention.pallas_refusal(
+            "pallas-stream", head_dim=128, **shapes)
+        return "xla" if refused else "pallas-stream"
 
     @contextlib.contextmanager
     def under():

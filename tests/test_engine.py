@@ -606,6 +606,10 @@ def test_batched_prefill_isolates_bad_row():
 
 # -- which reader of paged keys and values an engine runs ---------------------
 QWEN_7B = dict(head_dim=128, kv_heads_per_shard=4, page_itemsize=2)
+# MLA that holds the latent, as its reader is handed it: ONE head of
+# ``page_dim`` lanes, keys and values alike (GLM-4.7-Flash: 576 -> 640)
+GLM_LATENT = dict(head_dim=640, kv_heads_per_shard=1, page_itemsize=2,
+                  mla=True, shared_kv=True)
 
 
 @pytest.mark.parametrize("platform,shapes,backend", [
@@ -615,7 +619,12 @@ QWEN_7B = dict(head_dim=128, kv_heads_per_shard=4, page_itemsize=2)
     ("tpu", dict(QWEN_7B, page_itemsize=1), "xla"),          # int8 pages
     ("tpu", dict(QWEN_7B, head_dim=64), "xla"),              # off the lanes
     ("tpu", dict(QWEN_7B, head_dim=192, mla=True), "xla"),   # MLA's qk heads
-    ("tpu", dict(QWEN_7B, mla=True), "xla"),
+    ("tpu", GLM_LATENT, "pallas-stream"),                    # cell 5
+    ("tpu", dict(GLM_LATENT, head_dim=576), "xla"),          # the row unpadded
+    ("tpu", dict(GLM_LATENT, page_itemsize=1), "xla"),       # int8 latent
+    ("tpu", dict(GLM_LATENT, tp=4), "xla"),                  # one head, 4 shards
+    ("tpu", dict(GLM_LATENT, shared_kv=False, head_dim=256), "xla"),
+    ("cpu", GLM_LATENT, "xla"),
     ("cpu", QWEN_7B, "xla"),                                 # the tests' oracle
     ("gpu", QWEN_7B, "xla"),
 ])
@@ -838,13 +847,15 @@ def test_the_streaming_kernel_serves_merged_pages_sharded_by_lanes(
 def test_impl_info_names_the_kernel_and_the_form_it_reads(stream_kernel):
     """Under the fixture every engine with a reader says ``pallas-stream``
     with the form ``page_form`` gives it: merged at tiny-test's two kv
-    heads, split (a unit axis) at one head a shard; without a reader (int8
-    pages, MLA) it says ``xla`` with the gather's form."""
+    heads, split (a unit axis) at one head a shard, merged for MLA's
+    latent (one head, no unit axis); without a reader (int8 pages, MLA's
+    materialised heads) it says ``xla`` with the gather's form."""
     rows = [
         (dict(model="tiny-test", tp=1), "pallas-stream", "merged"),
         (dict(model="tiny-test", tp=2), "pallas-stream", "split"),
         (dict(model="tiny-test", tp=1, kv_quantize="int8"), "xla", "merged"),
         (dict(model="tiny-mla", tp=1), "xla", None),
+        (dict(model="tiny-glm-flash", tp=1), "pallas-stream", "merged"),
     ]
     with stream_kernel():
         for cfg, impl, form in rows:

@@ -462,3 +462,54 @@ def test_latent_spec_decoding_deterministic():
         assert stats.get("engine.spec_blocks", {}).get("count", 0) >= 1
     assert outs[0] == outs[1]
     assert all(len(t) >= 1 for row in outs for t in row)
+
+
+# -- the latent pages under the streaming kernel ------------------------------
+def _mixed_steps_then_blocks(eng):
+    """A short row decodes while a longer prompt is admitted in chunks of a
+    mixed step (a decode row's 1 slot beside a chunk's 8 in one ragged
+    call), then both decode in fused blocks (the decode form)."""
+    from opsagent_tpu.serving.sampler import SamplingParams
+
+    short, long_ = [257, 9, 8, 7], [257] + list(range(1, 30))
+    a = eng.add_request(short, SamplingParams(max_tokens=12))
+    b = eng.begin_request(long_, SamplingParams(max_tokens=8))
+    mixed = 0
+    while b in eng._prefilling:
+        done, total = eng.prefill_progress(b)
+        eng.step_mixed([a], {b: min(total - done, 8)})
+        mixed += 1
+    assert mixed >= 3
+    while not (eng.sequences[a].done and eng.sequences[b].done):
+        eng.step_block(
+            [s for s in (a, b) if not eng.sequences[s].done])
+    return [eng.finish(a), eng.finish(b)]
+
+
+@pytest.mark.parametrize("model", ["tiny-mla", "tiny-glm-flash"])
+def test_latent_engine_under_the_streaming_kernel_matches_the_gathers(
+    stream_kernel, model
+):
+    """The absorbed attention over latent pages through the one Pallas
+    kernel (interpreted: ``conftest.stream_kernel``), a page fetched once
+    as keys and values alike: mixed steps and fused decode blocks give the
+    gather engine's tokens, at MLA alone and beside an expert share."""
+    from opsagent_tpu.serving.engine import Engine, EngineConfig
+
+    model_cfg = get_config_preset(model)
+    if not model_cfg.mla.latent_cache:
+        model_cfg = LATENT_CFG
+    cfg = dict(
+        model=model, dtype=DTYPE, tp=1, page_size=4, num_pages=64,
+        max_pages_per_seq=16, max_batch_size=2, prefill_buckets=(8, 16),
+        decode_block=4, mixed_buckets=(8,), max_step_tokens=16, seed=0,
+    )
+    want = _mixed_steps_then_blocks(
+        Engine(EngineConfig(**cfg), model_cfg=model_cfg))
+    with stream_kernel():
+        eng = Engine(EngineConfig(**cfg), model_cfg=model_cfg)
+        info = eng.impl_info()
+        assert (info["attn_impl"], info["kv_page_form"]) == (
+            "pallas-stream", "merged")
+        got = _mixed_steps_then_blocks(eng)
+    assert got == want and all(got)

@@ -345,10 +345,12 @@ def _stream_case(rng, S, K, G, start, q_lens, dtype=jnp.float32, layers=0):
 
 
 @pytest.mark.parametrize("S", [1, 16, 32, 64])
-@pytest.mark.parametrize("K,G", [(1, 7), (4, 7), (8, 8)])
+@pytest.mark.parametrize("K,G", [(1, 7), (4, 7), (8, 8), (1, 20)])
 def test_stream_matches_oracle_with_rows_of_every_kind(K, G, S):
     """One batch holds an inactive row, a decode row, a whole chunk and a
-    part chunk, at 1, 4 (merged, the 7B's) and 8 kv heads."""
+    part chunk, at 1, 4 (merged, the 7B's) and 8 kv heads; and at a group
+    of 20 (MLA's absorbed heads over the one latent head), where a decode
+    row's heads fill a decode branch of 32 rows, not 16."""
     rng = np.random.default_rng(100 + S + K)
     part = max(1, S // 2 - 1)
     start, q_lens = [40, 37, 64, 5], [0, 1, S, part]
@@ -395,6 +397,104 @@ def test_stream_walks_query_blocks_of_an_admission_chunk(S):
     ref = paged_ragged_attention(*args)
     got = paged_ragged_attention_stream(*args, interpret=True)
     _assert_live_rows_match(got, ref, q_lens)
+
+
+def _latent_case(rng, S, G, D, start, q_lens, dtype=jnp.float32, layers=0):
+    """MLA's latent as the kernel is handed it: ONE merged head, ``[(L,)
+    N, P, D]`` with no unit axis (``llama.make_cache``), and the absorbed
+    queries of ``G`` heads."""
+    q, pages, _, table, st, ql = _stream_case(
+        rng, S, 1, G, start, q_lens, dtype=dtype, layers=layers)
+    q = jnp.asarray(rng.standard_normal((*q.shape[:-1], D)), dtype)
+    pages = jnp.asarray(
+        rng.standard_normal((*pages.shape[:-2], D)), dtype)
+    return q, pages, table, st, ql
+
+
+@pytest.mark.parametrize("D", [640, 24], ids=["on-the-lanes", "off-tile"])
+@pytest.mark.parametrize("S", [1, 16])
+def test_stream_reads_pages_that_are_keys_and_values_alike(S, D):
+    """Handed one array twice (``v_pages is k_pages``: the latent), the
+    kernel holds one page buffer and fetches a page once; the answer is
+    the gather's over the same array, and BITWISE what the kernel gives
+    for a second array of the same bytes, fetched twice."""
+    rng = np.random.default_rng(640 + S + D)
+    start, q_lens = [40, 37, 64, 5], [0, 1, S, max(1, S // 2 - 1)]
+    q, pages, table, st, ql = _latent_case(rng, S, 20, D, start, q_lens)
+    # without a layer axis the gather tells one head by its unit axis
+    split = pages[:, :, None, :]
+    ref = paged_ragged_attention(q, split, split, table, st, ql)
+    got = paged_ragged_attention_stream(
+        q, pages, pages, table, st, ql, interpret=True, block_pages=2)
+    _assert_live_rows_match(got, ref, q_lens)
+    np.testing.assert_array_equal(np.asarray(got)[0], 0.0)
+    twice = paged_ragged_attention_stream(
+        q, pages, pages + 0, table, st, ql, interpret=True, block_pages=2)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(twice))
+
+
+def test_stream_fetches_a_shared_page_once():
+    """The kernel of a shared call takes no value pages and holds one
+    page buffer and one semaphore a slot; handed two arrays it holds two
+    of each."""
+    from opsagent_tpu.ops import paged_attention_stream as pas
+
+    shape = dict(B=2, nQ=1, K=1, TM=32, D=640, TS=1, G=20, MaxP=4, bp=2,
+                 P=16, pages=8, page_dtype="bfloat16", q_dtype="bfloat16",
+                 interpret=True)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)      # noqa: E731
+    bf16 = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)  # noqa: E731
+    rows = (i32(2, 4), i32(2), i32(2), i32(1), bf16(2, 1, 32, 640),
+            i32(32, 1))
+    for shared, sides in ((True, 1), (False, 2)):
+        call = pas._pallas_call(**shape, shared=shared)
+        jaxpr = jax.make_jaxpr(call)(*rows, *[bf16(8, 16, 640)] * sides)
+        eqn, = (e for e in jaxpr.eqns if e.primitive.name == "pallas_call")
+        scratch = [
+            v.aval for v in eqn.params["jaxpr"].invars
+        ][-(4 + sides):]
+        bufs = [a for a in scratch if a.shape == (2, 2, 16, 640)]
+        assert len(bufs) == sides
+        sem, = (a for a in scratch if a.shape in ((1, 2), (2, 2)))
+        assert sem.shape == (sides, 2)
+        assert len(eqn.invars) == len(rows) + sides
+
+
+def test_stream_layer_axis_form_over_the_latent(monkeypatch):
+    """The latent cache as the engine threads it through its scan, ``[L,
+    N, P, 640]``: four axes that are layers and ONE merged head, not a
+    unit kv-head axis; the base offsets every page lookup into the layer,
+    through the engine's own door."""
+    from opsagent_tpu.ops.attention import paged_ragged_attention_auto
+
+    monkeypatch.setenv("OPSAGENT_PALLAS_INTERPRET", "1")
+    rng = np.random.default_rng(41)
+    start, q_lens = [33, 0], [1, 16]
+    q, pages, table, st, ql = _latent_case(
+        rng, 16, 20, 640, start, q_lens, layers=3)
+    assert pages.shape == (3, 2 * SMAXP + 3, SP, 640)
+    for layer in (0, 2):
+        ref = paged_ragged_attention(
+            q, pages, pages, table, st, ql, layer=jnp.int32(layer))
+        got = paged_ragged_attention_auto(
+            q, pages, pages, table, st, ql, impl="pallas-stream",
+            layer=jnp.int32(layer))
+        _assert_live_rows_match(got, ref, q_lens)
+
+
+def test_stream_bf16_over_the_latent_is_the_oracles_arithmetic():
+    """bf16 latent pages and absorbed queries at the cell's widths: the
+    kernel and the gather agree to bf16's last place."""
+    rng = np.random.default_rng(43)
+    start, q_lens = [150, 100], [1, 16]
+    q, pages, table, st, ql = _latent_case(
+        rng, 16, 20, 640, start, q_lens, dtype=jnp.bfloat16)
+    split = pages[:, :, None, :]
+    ref = paged_ragged_attention(q, split, split, table, st, ql)
+    got = paged_ragged_attention_stream(
+        q, pages, pages, table, st, ql, interpret=True, block_pages=4)
+    assert got.dtype == jnp.bfloat16
+    _assert_live_rows_match(got, ref, q_lens, tol=1.6e-2)
 
 
 def test_stream_layer_axis_form():

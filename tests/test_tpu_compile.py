@@ -180,7 +180,7 @@ def test_stream_kernel_compiles_at_pages_of_64_slots(v5e, s):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# -- the gather where it is the only reader: int8 pages, MLA ------------------
+# -- the gather where it is the only reader (int8 pages), and over the latent --
 def _gather(sds, pages, *, b, s, h, d, maxp):
     """Compile the xla reader over a layer-stacked cache; s == 0 means the
     decode form (what a fused decode block runs)."""
@@ -234,8 +234,42 @@ def test_int8_pages_gather_compiles_at_the_cells_shapes(v5e, cell, s):
     assert "tpu_custom_call" not in compiled.as_text()
 
 
+def _latent_reader(cfg, **other):
+    """What an engine of an MLA model that holds the latent tells the
+    choice (``Engine.__init__``): the shapes its READER is handed."""
+    return dict(dict(
+        head_dim=cfg.mla.page_dim, kv_heads_per_shard=1, page_itemsize=2,
+        mla=True, shared_kv=True), **other)
+
+
+@pytest.mark.parametrize("b,s", [(16, 16), (16, 1), (1, 64)],
+                         ids=["mixed-bucket", "decode-form", "prefill-bucket"])
+def test_stream_kernel_compiles_over_the_latent_at_the_cells_shapes(v5e, b, s):
+    """``glm47-flash-l12.longdoc-turns``: the absorbed queries ``[16, 16,
+    20, 640]`` of the cell's one mixed bucket, the decode form ``[16, 1,
+    20, 640]`` of its fused blocks and a prefill bucket's 64 slots of one
+    row, against latent pages ``[12, 16384, 16, 640]`` handed ONCE as keys
+    and values (one kv head of 640 = 5 x 128 lanes, a group of 20): Mosaic
+    takes the kernel, and no copy of the cache stands beside it."""
+    sds = _one_chip(v5e)
+    layers, (n, maxp) = 12, (16384, 1216)
+    pages = sds((layers, n, PAGE, 640), jnp.bfloat16)
+    compiled = _compile(
+        lambda q, kc, t, st, ql, ly: attention.paged_ragged_attention_auto(
+            q, kc, kc, t, st, ql, impl="pallas-stream", layer=ly),
+        sds((b, s, 20, 640), jnp.bfloat16), pages,
+        sds((b, maxp), jnp.int32), sds((b,), jnp.int32),
+        sds((b,), jnp.int32), sds((), jnp.int32),
+    )
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert _copies_of(hlo, layers * n * PAGE * 640) == []
+
+
 def test_mla_latent_gather_compiles_and_copies_no_latent_cache(v5e):
-    """MLA serves through the gather on the chip too: GLM-4.7-Flash's
+    """The gather over the latent on the chip: the oracle's record, and
+    the reader of the harness's int8-latent control (the cell itself runs
+    the streaming kernel since PR 41, below). GLM-4.7-Flash's
     absorbed queries (20 heads against the 576-wide latent: 512 + 64 rope)
     over its one-head latent pages, which keys and values share, at the
     geometry of ``glm47-flash-l12.longdoc-turns`` (too large for the
@@ -255,9 +289,9 @@ def test_mla_latent_gather_compiles_and_copies_no_latent_cache(v5e):
     sds = _one_chip(v5e)
     layers, n, maxp = 12, 16384, 1216
     assert attention.paged_attention_backend(
-        platform="tpu", head_dim=cfg.head_dim_, kv_heads_per_shard=1,
-        page_itemsize=2, mla=True,
-    ) == "xla"
+        platform="tpu", **_latent_reader(cfg)) == "pallas-stream"
+    assert attention.paged_attention_backend(
+        platform="tpu", **_latent_reader(cfg, page_itemsize=1)) == "xla"
     for row, copied in (((640,), 0), ((576,), 2), ((1, 576), 2)):
         pages = sds((layers, n, PAGE, *row), jnp.bfloat16)
         compiled = _gather(
@@ -460,20 +494,39 @@ def test_stream_refusals_pinned_from_both_sides(
     ) is None
 
 
-def test_mla_refuses_the_kernel(monkeypatch):
+def test_mla_refusals_that_remain_and_the_latents_acceptance(monkeypatch):
+    """MLA with materialised heads (no latent cache) and the latent under
+    tp > 1 have no reader in the kernel: the choice sends them to the
+    gather, and an engine whose choice is made to answer the kernel all
+    the same refuses at init with the rule's words. The latent on the
+    lanes at tp=1 is accepted (its compiles are above)."""
     from opsagent_tpu.serving.engine import (
         BackendRefused, Engine, EngineConfig,
     )
 
+    glm = get_config_preset("glm-4.7-flash")
+    heads = dict(
+        head_dim=glm.head_dim_, kv_heads_per_shard=glm.num_kv_heads,
+        page_itemsize=2, mla=True)
+    assert glm.head_dim_ % 128 == 0     # so it is the MLA rule that speaks
+    for shapes, words in (
+        (heads, "materialised heads"),
+        (_latent_reader(glm, tp=4), "tp=4"),
+        (_latent_reader(glm, page_itemsize=1), "int8 pages"),
+        (_latent_reader(glm, head_dim=glm.mla.latent_dim),
+         "128-lane tiling"),
+    ):
+        assert words in pallas_refusal("pallas-stream", **shapes)
+        assert attention.paged_attention_backend(
+            platform="tpu", **shapes) == "xla"
+    assert pallas_refusal("pallas-stream", **_latent_reader(glm)) is None
     assert attention.paged_attention_backend(
-        platform="tpu", head_dim=128, kv_heads_per_shard=1, page_itemsize=2,
-        mla=True,
-    ) == "xla"
+        platform="tpu", **_latent_reader(glm)) == "pallas-stream"
     monkeypatch.setattr(
         attention, "paged_attention_backend", lambda **_: "pallas-stream"
     )
     monkeypatch.delenv("OPSAGENT_PALLAS_INTERPRET", raising=False)
-    with pytest.raises(BackendRefused, match="MLA"):
+    with pytest.raises(BackendRefused, match="materialised heads"):
         Engine(EngineConfig(model="tiny-mla"))
 
 
@@ -930,7 +983,7 @@ def test_stream_kernel_is_exported_once_a_shape(v5e, tmp_path, monkeypatch):
 
     def new_process():
         stream._kernel_call.cache_clear()
-        stream.paged_ragged_attention_stream.clear_cache()
+        stream._stream.clear_cache()
 
     def compiled():
         return _stream(
@@ -1148,29 +1201,39 @@ def test_glm_flash_geometry_is_the_cells():
             engine["max_step_tokens"], engine["decode_block"]) == (
         16, [16], 256, 8)
     assert attention.paged_attention_backend(
-        platform="tpu", head_dim=256, kv_heads_per_shard=20,
-        page_itemsize=2, mla=True) == "xla"
+        platform="tpu", **_latent_reader(get_config_preset("glm-4.7-flash"))
+    ) == "pallas-stream"
 
 
-@pytest.mark.parametrize("kv", ["", "int8"], ids=["bf16", "int8-pages"])
-def test_glm_flash_mixed_step_copies_no_latent_cache_and_fits_the_chip(v5e, kv):
+@pytest.mark.parametrize("kv,impl", [
+    ("", "pallas-stream"),      # the cell (PR 41)
+    ("", "xla"),                # the oracle's record (PR 40's program)
+    ("int8", "xla"),            # the harness's control
+], ids=["bf16", "bf16-gather", "int8-pages"])
+def test_glm_flash_mixed_step_copies_no_latent_cache_and_fits_the_chip(
+    v5e, kv, impl
+):
     """The cell's one mixed program WHOLE (12 layers, int8 weights, every
-    expert, the full vocabulary, 16,384 latent pages; also with the int8
-    pages of the harness's control): no copy as large as the latent cache,
+    expert, the full vocabulary, 16,384 latent pages), under the streaming
+    kernel as the cell runs it, under the gather, and with the int8 pages
+    of the harness's control (the gather's): the kernel is in the program
+    where it is the reader; no copy as large as the latent cache,
     at the program's entry, its exit or in its layer loops (held with a
     unit axis the cache was copied twice, 3.6 GB each at this size: it
     would not have fitted), no layer's expert stack written out (an expert
     share reads one expert at a time out of the whole stack), and
-    arguments, results and scratch together inside the chip's memory."""
+    arguments, results and scratch together inside the chip's memory.
+    Under the kernel the gathered rows and the f32 scores are gone from
+    the scratch."""
     cfg, cache, _, compiled = _mixed_step(
-        _one_chip(v5e), "glm-4.7-flash", kv, "xla", rows=16, tokens=16,
+        _one_chip(v5e), "glm-4.7-flash", kv, impl, rows=16, tokens=16,
         step_tokens=256, layers=12, int8=True)
     assert cfg.moe_layer_start == 1 and cfg.moe.router_experts == 64
     latent = jax.tree.leaves(cache["k"])[0]
     assert latent.shape == (12, 16384, 16, 640)
     assert "stats" in cache
     hlo = compiled.as_text()
-    assert "tpu_custom_call" not in hlo
+    assert ("tpu_custom_call" in hlo) == (impl == "pallas-stream")
     assert _copies_of(hlo, int(np.prod(latent.shape))) == []
     experts = 64 * cfg.hidden_size * cfg.moe.expert_intermediate_size
     assert _copies_of(hlo, experts) == []
@@ -1181,21 +1244,28 @@ def test_glm_flash_mixed_step_copies_no_latent_cache_and_fits_the_chip(v5e, kv):
     held = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert held < CHIP_HBM_BYTES, f"{held / 2**30:.2f} GiB"
-    print(f"glm mixed step [{kv or 'bf16'} pages]: arguments "
+    if impl == "pallas-stream":
+        # the gather's rows [16, 19456, 640] bf16 alone are 0.37 GiB
+        assert m.temp_size_in_bytes < 0.3 * 2**30
+    print(f"glm mixed step [{kv or 'bf16'} pages, {impl}]: arguments "
           f"{m.argument_size_in_bytes / 2**30:.2f} GiB, scratch "
           f"{m.temp_size_in_bytes / 2**30:.2f} GiB, held {held / 2**30:.2f} GiB")
 
 
-def test_glm_flash_decode_block_copies_no_latent_cache(v5e):
+@pytest.mark.parametrize("impl", ["pallas-stream", "xla"])
+def test_glm_flash_decode_block_copies_no_latent_cache(v5e, impl):
     """The fused decode block at the cell's rows (8 passes under one scan,
-    the latent cache its carry): no copy as large as the cache."""
+    the latent cache its carry), under the kernel's decode form as the
+    cell runs it and under the gather: no copy as large as the cache."""
     _, cache, compiled = _decode_block_compiled(
-        _one_chip(v5e), "glm-4.7-flash", "xla", rows=16, layers=12, int8=True)
-    assert _copies_of(
-        compiled.as_text(), int(np.prod(cache["k"].shape))) == []
+        _one_chip(v5e), "glm-4.7-flash", impl, rows=16, layers=12, int8=True)
+    hlo = compiled.as_text()
+    assert ("tpu_custom_call" in hlo) == (impl == "pallas-stream")
+    assert _copies_of(hlo, int(np.prod(cache["k"].shape))) == []
     m = compiled.memory_analysis()
     held = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert held < CHIP_HBM_BYTES, f"{held / 2**30:.2f} GiB"
-    print(f"glm decode block: scratch {m.temp_size_in_bytes / 2**30:.2f} GiB, "
+    print(f"glm decode block [{impl}]: scratch "
+          f"{m.temp_size_in_bytes / 2**30:.2f} GiB, "
           f"held {held / 2**30:.2f} GiB")
