@@ -161,8 +161,32 @@ class LinearAttnConfig:
         return 2 * self.key_size + self.value_size
 
 
+@dataclass(frozen=True)
+class MambaConfig:
+    """Mamba-1 selective state-space mixer (Jamba's): a float32 state
+    ``[d_state, d_inner]`` per sequence instead of pages, a diagonal decay
+    ``exp(dt A)`` with no delta update, and a causal depthwise convolution
+    of ``d_conv`` over the ``d_inner`` stream in front of it (its last
+    ``d_conv - 1`` inputs are state too). ``dt`` comes through a low-rank
+    projection of width ``dt_rank``; Jamba norms ``dt``, ``B`` and ``C``
+    (RMSNorm with a learned weight) before they are used."""
+
+    d_inner: int = 128
+    d_state: int = 16
+    d_conv: int = 4
+    dt_rank: int = 8
+    conv_bias: bool = True
+
+    @property
+    def x_proj_size(self) -> int:
+        """Width of ``W_x``'s output: ``dt_low``, ``B`` and ``C``."""
+        return self.dt_rank + 2 * self.d_state
+
+
 # Kinds of token mixer a layer may have (``ModelConfig.mixer_period``).
-MIXERS = ("attn", "linear")
+MIXERS = ("attn", "linear", "mamba")
+# Those that keep a recurrent state in the state slots.
+STATE_MIXERS = ("linear", "mamba")
 
 
 @dataclass(frozen=True)
@@ -218,12 +242,14 @@ class ModelConfig:
     rope_scaling: Optional[RopeScalingConfig] = None
     # The layer pattern, by its period: the kind of token mixer at each
     # position of one period ("attn" softmax attention over pages, "linear"
-    # delta-rule linear attention over a recurrent state); layer ``i`` has
+    # delta-rule linear attention over a recurrent state, "mamba" a
+    # selective state-space scan over one); layer ``i`` has
     # ``mixer_period[i % len(mixer_period)]``. Empty = every layer "attn".
     # Equal at any depth that is a whole number of periods. The MLP's kind
     # stays ``moe`` / ``moe_layer_start``.
     mixer_period: tuple = ()
     linear_attn: Optional[LinearAttnConfig] = None
+    mamba: Optional[MambaConfig] = None
     # softmax-attention output multiplied by sigmoid(x W_gate) before wo
     attn_output_gate: bool = False
     use_rope: bool = True            # False: no positional embedding (NoPE)
@@ -242,6 +268,12 @@ class ModelConfig:
             raise ValueError(f"mixer_period {period}: unknown mixers {bad}")
         if "linear" in period and self.linear_attn is None:
             raise ValueError("mixer_period has linear layers: linear_attn unset")
+        if "mamba" in period and self.mamba is None:
+            raise ValueError("mixer_period has mamba layers: mamba unset")
+        if "linear" in period and "mamba" in period:
+            raise ValueError(
+                "mixer_period mixes linear and mamba layers: the state slots "
+                "hold one kind of recurrent state")
         if self.num_layers % len(period) or (
             self.moe is not None and self.moe_layer_start % len(period)
         ):
@@ -264,9 +296,15 @@ class ModelConfig:
         return self.num_layers // len(p) * sum(1 for m in p if m == kind)
 
     @property
+    def state_mixer(self) -> str:
+        """The mixer whose layers keep a recurrent state beside (or instead
+        of) pages: "linear", "mamba", or "" where no layer does."""
+        return next((m for m in STATE_MIXERS if m in self.period_), "")
+
+    @property
     def has_state(self) -> bool:
         """Some layer keeps a recurrent state beside (or instead of) pages."""
-        return "linear" in self.period_
+        return bool(self.state_mixer)
 
     @property
     def head_dim_(self) -> int:
@@ -329,6 +367,19 @@ class ModelConfig:
                 + la.num_heads + la.decay_size                     # A_log, dt_bias
                 + la.value_head_dim                                # output norm
             )
+        mamba = 0
+        if self.mamba is not None:
+            mc = self.mamba
+            mamba = (
+                d * 2 * mc.d_inner                                 # W_in
+                + mc.d_conv * mc.d_inner                           # conv
+                + (mc.d_inner if mc.conv_bias else 0)
+                + mc.d_inner * mc.x_proj_size + mc.x_proj_size     # W_x, norms
+                + mc.dt_rank * mc.d_inner + mc.d_inner             # W_dt, b_dt
+                + mc.d_inner * mc.d_state + mc.d_inner             # A_log, D
+                + mc.d_inner * d                                   # W_out
+            )
+        mixers = {"attn": attn, "linear": linear, "mamba": mamba}
         dense_mlp = 3 * d * f
         moe_mlp = 0
         if self.moe is not None:
@@ -342,7 +393,7 @@ class ModelConfig:
             )
         total = 0
         for layer in range(self.num_layers):
-            total += attn if self.mixer_of(layer) == "attn" else linear
+            total += mixers[self.mixer_of(layer)]
             is_moe = self.moe is not None and layer >= self.moe_layer_start
             total += (moe_mlp if is_moe else dense_mlp) + 2 * d
         embed = v * d * (1 if self.tie_embeddings else 2)
@@ -789,6 +840,60 @@ TINY_OLMO_HYBRID = _register(
     )
 )
 
+# AI21-Jamba2-3B (ai21labs; HF jamba; 3.03B parameters): 28 pre-norm dense
+# layers in periods of 14, layer i attention where i % 14 == 7 (20 query
+# heads over ONE kv head of 128, no rotary embedding) and a Mamba-1 selective
+# scan otherwise (d_inner 5120, a state of 16 a channel, conv 4, dt through a
+# rank of 160, Jamba's RMSNorm on dt, B and C); ``num_experts`` is 1, so every
+# MLP is the dense SwiGLU of 8192; the head is the embedding.
+JAMBA2_3B = _register(
+    ModelConfig(
+        name="jamba2-3b",
+        vocab_size=65536,
+        hidden_size=2560,
+        intermediate_size=8192,
+        num_layers=28,
+        num_heads=20,
+        num_kv_heads=1,
+        rms_norm_eps=1e-6,
+        tie_embeddings=True,
+        max_position=262144,
+        mixer_period=("mamba",) * 7 + ("attn",) + ("mamba",) * 6,
+        mamba=MambaConfig(
+            d_inner=5120, d_state=16, d_conv=4, dt_rank=160, conv_bias=True),
+        use_rope=False,
+    )
+)
+
+# The same model with a head of its own: what the benchmark serves, whose
+# harness draws the embedding (bfloat16) and the head (int8) apart and whose
+# reference reads the head (``benchmarks/configs/jamba2-3b-int8.json``,
+# ``assumed``). The same matmul, 0.17 GB read a pass where the tied bfloat16
+# rows would be 0.34.
+JAMBA2_3B_UNTIED = _register(
+    replace(JAMBA2_3B, name="jamba2-3b-untied", tie_embeddings=False))
+
+# Two shortened periods of the same pattern at toy widths (CPU tests): Mamba
+# layers on both sides of the one multi-query attention layer.
+TINY_JAMBA = _register(
+    ModelConfig(
+        name="tiny-jamba",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=8,
+        num_heads=4,
+        num_kv_heads=1,
+        rms_norm_eps=1e-6,
+        tie_embeddings=True,
+        max_position=4096,
+        mixer_period=("mamba", "mamba", "attn", "mamba"),
+        mamba=MambaConfig(
+            d_inner=128, d_state=16, d_conv=4, dt_rank=8, conv_bias=True),
+        use_rope=False,
+    )
+)
+
 TINY_MLA = _register(
     ModelConfig(
         name="tiny-mla",
@@ -898,11 +1003,11 @@ def config_from_hf(path: str, name: str = "") -> ModelConfig:
     mt = hf.get("model_type", "llama")
     if mt not in ("llama", "mistral", "qwen2", "qwen3", "qwen3_moe",
                   "deepseek", "deepseek_v2", "deepseek_v3", "solar_open2",
-                  "olmo_hybrid", "glm4_moe_lite"):
+                  "olmo_hybrid", "glm4_moe_lite", "jamba"):
         raise ValueError(
             f"config_from_hf supports model_type llama/mistral/qwen2/"
             f"qwen3/qwen3_moe/deepseek/deepseek_v2/deepseek_v3/solar_open2/"
-            f"olmo_hybrid/glm4_moe_lite, got {mt!r}"
+            f"olmo_hybrid/glm4_moe_lite/jamba, got {mt!r}"
         )
     name = name or os.path.basename(os.path.normpath(
         path if os.path.isdir(path) else os.path.dirname(cfg_path)
@@ -913,6 +1018,8 @@ def config_from_hf(path: str, name: str = "") -> ModelConfig:
         return _olmo_hybrid_from_hf(hf, name)
     if mt == "glm4_moe_lite":
         return _glm4_moe_lite_from_hf(hf, name)
+    if mt == "jamba":
+        return _jamba_from_hf(hf, name)
     # Sliding-window attention is not implemented; a config that would
     # ACTIVELY use it must be rejected loudly, never silently served
     # with full attention. Mistral (llama-shaped otherwise: same weight
@@ -1294,6 +1401,101 @@ def _glm4_moe_lite_dict(cfg: ModelConfig) -> dict:
     return hf
 
 
+def _jamba_from_hf(hf: dict, name: str) -> ModelConfig:
+    """``model_type: jamba``: pre-norm layers, layer ``i`` attention where
+    ``i % attn_layer_period == attn_layer_offset`` (the model type's own
+    rule in transformers; grouped-query, no rotary embedding) and a Mamba-1
+    mixer otherwise, a dense SwiGLU MLP in every layer. A config with
+    ``num_experts`` > 1 puts experts in every ``expert_layer_period``-th
+    MLP, which this engine does not run for this model type: refused by
+    name (the ``expert_layer_*`` keys are inert at one expert)."""
+    experts = int(hf.get("num_experts", 1) or 1)
+    if experts > 1:
+        raise ValueError(
+            f"jamba: num_experts={experts} is not supported: only the dense "
+            "models of the family (num_experts 1, every MLP a SwiGLU)")
+    if hf.get("sliding_window") is not None:
+        raise ValueError("jamba: sliding_window attention is not supported")
+    if hf.get("mamba_proj_bias", False):
+        raise ValueError("jamba: mamba_proj_bias=true is not supported")
+    layers = int(hf["num_hidden_layers"])
+    period = int(hf.get("attn_layer_period", 8))
+    offset = int(hf.get("attn_layer_offset", 4))
+    if not 0 <= offset < period or layers % period:
+        raise ValueError(
+            f"jamba: {layers} layers are not whole periods of "
+            f"attn_layer_period={period} (offset {offset})")
+    d = int(hf["hidden_size"])
+    rank = hf.get("mamba_dt_rank", "auto")
+    return ModelConfig(
+        name=name,
+        vocab_size=int(hf["vocab_size"]),
+        hidden_size=d,
+        intermediate_size=int(hf["intermediate_size"]),
+        num_layers=layers,
+        num_heads=int(hf["num_attention_heads"]),
+        num_kv_heads=int(hf["num_key_value_heads"]),
+        head_dim=int(hf.get("head_dim") or 0),
+        rms_norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        max_position=int(hf.get("max_position_embeddings", 262144)),
+        mixer_period=tuple(
+            "attn" if i == offset else "mamba" for i in range(period)),
+        mamba=MambaConfig(
+            d_inner=int(hf.get("mamba_expand", 2)) * d,
+            d_state=int(hf.get("mamba_d_state", 16)),
+            d_conv=int(hf.get("mamba_d_conv", 4)),
+            dt_rank=-(-d // 16) if rank == "auto" else int(rank),
+            conv_bias=bool(hf.get("mamba_conv_bias", True)),
+        ),
+        use_rope=False,
+    )
+
+
+def _jamba_dict(cfg: ModelConfig) -> dict:
+    """The inverse of ``_jamba_from_hf``."""
+    mc, period = cfg.mamba, cfg.mixer_period
+    if (cfg.moe or cfg.mla or cfg.linear_attn or cfg.use_rope or cfg.qk_norm
+            or cfg.attn_bias or cfg.attn_output_gate or cfg.post_norm
+            or period.count("attn") != 1 or mc.d_inner % cfg.hidden_size):
+        raise ValueError(
+            "hf_config_dict: this model is not expressible as model_type "
+            "jamba"
+        )
+    hf = {
+        "model_type": "jamba",
+        "architectures": ["JambaForCausalLM"],
+        "vocab_size": cfg.vocab_size,
+        "hidden_size": cfg.hidden_size,
+        "intermediate_size": cfg.intermediate_size,
+        "num_hidden_layers": cfg.num_layers,
+        "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.num_kv_heads,
+        "hidden_act": "silu",
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "tie_word_embeddings": cfg.tie_embeddings,
+        "max_position_embeddings": cfg.max_position,
+        "sliding_window": None,
+        "num_logits_to_keep": 1,
+        "attn_layer_period": len(period),
+        "attn_layer_offset": period.index("attn"),
+        "num_experts": 1,
+        "num_experts_per_tok": 1,
+        "expert_layer_period": 2,
+        "expert_layer_offset": 1,
+        "use_mamba_kernels": True,
+        "mamba_expand": mc.d_inner // cfg.hidden_size,
+        "mamba_d_state": mc.d_state,
+        "mamba_d_conv": mc.d_conv,
+        "mamba_dt_rank": mc.dt_rank,
+        "mamba_conv_bias": mc.conv_bias,
+        "mamba_proj_bias": False,
+    }
+    if cfg.head_dim:
+        hf["head_dim"] = cfg.head_dim
+    return hf
+
+
 _OLMO_LAYER_TYPES = {"linear_attention": "linear", "full_attention": "attn"}
 
 
@@ -1421,9 +1623,11 @@ def hf_config_dict(cfg: ModelConfig) -> dict:
     with a plain softmax MoE); other MoE and/or MLA configs emit the
     deepseek family (deepseek_v2/v3 when MLA is present, deepseek
     otherwise); latent attention beside an expert share emits
-    glm4_moe_lite."""
+    glm4_moe_lite; Mamba layers emit jamba."""
     if cfg.post_norm:
         return _olmo_hybrid_dict(cfg)
+    if cfg.mamba is not None:
+        return _jamba_dict(cfg)
     if cfg.has_state:
         return _solar_open2_dict(cfg)
     if cfg.mla and cfg.moe and cfg.moe.router_experts:

@@ -57,6 +57,8 @@ from ..ops.linear_state_pallas import (
     heads_packed,
 )
 from ..ops.rope import apply_rope, rope_table
+from ..ops.selective_scan import selective_scan, selective_scan_step
+from ..ops.selective_scan_pallas import selective_scan_slots
 from ..utils.profiling import scoped
 from .config import ModelConfig
 
@@ -86,6 +88,8 @@ EXTRA_SCOPES = (
     "lin_proj",     # a linear layer's projections, conv, norms and gates
     "lin_scan",     # decay, delta update, read-out
     "state_io",     # state-slot gather / scatter, snapshot copies in a step
+    "ssm_proj",     # a Mamba layer's projections, conv, norms and gate
+    "ssm_scan",     # the selective scan: decay, input, read-out, the skip
     "attn_gate",    # sigmoid output gate of a gated attention layer
     "moe_router",   # router scores, top-k, weights, the dispatch plan
     "moe_experts",  # the routed experts' matmuls
@@ -268,6 +272,37 @@ def _build_tree(cfg: ModelConfig, ks, dtype, big, dense) -> Params:
             "mlp_norm": jnp.ones((*L, d), dtype),
         }
 
+    def mamba_block(L: tuple) -> Params:
+        mc = cfg.mamba
+        di, ds, r = mc.d_inner, mc.d_state, mc.dt_rank
+        block = {
+            "attn_norm": jnp.ones((*L, d), dtype),
+            "m_in": big(next(ks), (*L, d, 2 * di), d),     # x first, then z
+            "m_x": big(next(ks), (*L, di, mc.x_proj_size), di),
+            "m_dt": big(next(ks), (*L, r, di), r),
+            "m_out": big(next(ks), (*L, di, d), di),
+            "conv": dense(next(ks), (*L, mc.d_conv, di), mc.d_conv, dtype),
+            # Jamba's norms of dt, B and C
+            "dt_norm": jnp.ones((*L, r), dtype),
+            "b_norm": jnp.ones((*L, ds), dtype),
+            "c_norm": jnp.ones((*L, ds), dtype),
+            # float32, as the state they drive. ``a_log`` is held [d_state,
+            # d_inner], the state's own layout (a checkpoint's is [d_inner,
+            # d_state]); S4D-real init: A = -(1..d_state) in every channel
+            "a_log": jnp.broadcast_to(
+                jnp.log(jnp.arange(1, ds + 1, dtype=jnp.float32))[:, None],
+                (*L, ds, di)),
+            "dt_bias": jnp.zeros((*L, di), jnp.float32),
+            "d_skip": jnp.ones((*L, di), jnp.float32),
+            "mlp_norm": jnp.ones((*L, d), dtype),
+        }
+        if mc.conv_bias:
+            block["conv_b"] = jnp.zeros((*L, di), dtype)
+        return block
+
+    mixer_blocks = {
+        "attn": attn_block, "linear": linear_block, "mamba": mamba_block}
+
     def dense_mlp(block: Params, L: tuple) -> Params:
         block["wg"] = big(next(ks), (*L, d, f), d)
         block["wu"] = big(next(ks), (*L, d, f), d)
@@ -307,9 +342,7 @@ def _build_tree(cfg: ModelConfig, ks, dtype, big, dense) -> Params:
             return mlp(attn_block((n_layers,)), (n_layers,))
         periods = n_layers // len(cfg.period_)
         return {
-            key: mlp(
-                (attn_block if mixer == "attn" else linear_block)(
-                    (periods, n)), (periods, n))
+            key: mlp(mixer_blocks[mixer]((periods, n)), (periods, n))
             for key, mixer, n in runs
         }
 
@@ -484,6 +517,27 @@ def _linear_block_specs(cfg: ModelConfig) -> Params:
     }
 
 
+def _mamba_block_specs(cfg: ModelConfig) -> Params:
+    """A Mamba layer's leaves, replicated as a linear layer's are: the
+    engine refuses tp > 1 for a model with recurrent state."""
+    two, three = P(None, None), P(None, None, None)
+    specs = {
+        "attn_norm": two, "m_in": three, "m_x": three, "m_dt": three,
+        "m_out": three, "conv": three, "dt_norm": two, "b_norm": two,
+        "c_norm": two, "a_log": three, "dt_bias": two, "d_skip": two,
+        "mlp_norm": two,
+    }
+    if cfg.mamba.conv_bias:
+        specs["conv_b"] = two
+    return specs
+
+
+_MIXER_SPECS = {
+    "attn": _attn_block_specs, "linear": _linear_block_specs,
+    "mamba": _mamba_block_specs,
+}
+
+
 def param_specs(cfg: ModelConfig) -> Params:
     """PartitionSpecs matching ``init_params``' tree (axes: ("dp","sp","tp")).
 
@@ -531,9 +585,8 @@ def param_specs(cfg: ModelConfig) -> Params:
         # a run's leaves lead with [periods, layers of the run]
         return {
             key: {
-                name: P(None, *spec) for name, spec in mlp(
-                    _attn_block_specs(cfg) if mixer == "attn"
-                    else _linear_block_specs(cfg)).items()
+                name: P(None, *spec)
+                for name, spec in mlp(_MIXER_SPECS[mixer](cfg)).items()
             }
             for key, mixer, _n in runs
         }
@@ -672,31 +725,47 @@ def state_slot_shape(la, impl: str = "xla") -> tuple[int, ...]:
     return (H * dk * dv // 128, 128)
 
 
+def slot_shapes(cfg: ModelConfig, impl: str = "xla") -> tuple[tuple, tuple]:
+    """(a slot's state, a slot's conv tail) of ONE state-keeping layer as
+    the cache holds them. A linear-attention layer's by ``state_slot_shape``
+    and, under the state kernel, which copies a slot's tail by its leading
+    index, the tail as whole tiles of rows of 128 (``conv_slot_shape``). A
+    Mamba layer's state is ``[d_state, d_inner]``: the channels on the 128
+    lanes and the 16 state indices on the sublanes, whole (8, 128) tiles
+    with nothing padded, where the published ``[d_inner, 16]`` would pad
+    its minor 16 to 128 lanes, eight times the bytes held and moved. Either
+    tail is flat ``[(kernel - 1) * width]`` under XLA: a minor pair of (3,
+    width) would pad the 3 to a whole tile on the TPU, five times the
+    bytes; under the scan kernel ("pallas-ssm") rows of 128 as under the
+    state kernel."""
+    if cfg.state_mixer == "mamba":
+        mc = cfg.mamba
+        width = (mc.d_conv - 1) * mc.d_inner
+        return (mc.d_state, mc.d_inner), (
+            conv_slot_shape(width) if impl == "pallas-ssm" else (width,))
+    la = cfg.linear_attn
+    width = (la.conv_kernel - 1) * la.conv_size
+    return state_slot_shape(la, impl), (
+        conv_slot_shape(width) if impl == "pallas-state" else (width,))
+
+
 def make_state(
     cfg: ModelConfig, slots: int, dtype=jnp.bfloat16, impl: str = "xla"
 ) -> Params:
-    """The recurrent-state part of the cache: for every linear-attention
-    layer and slot a float32 state (``state_slot_shape``) and the conv tail
-    (the last ``conv_kernel - 1`` inputs of the convolved q/k/v stream, in
-    the compute type). A slot belongs to a running sequence or holds a
-    snapshot the prefix trie can restore; the slot a row uses rides in its
-    table row beside its pages (``split_table``).
+    """The recurrent-state part of the cache: for every state-keeping layer
+    (linear attention, or Mamba) and slot a float32 state and the conv tail
+    (the last ``kernel - 1`` inputs of the convolved stream, in the compute
+    type), shaped by ``slot_shapes``. A slot belongs to a running sequence
+    or holds a snapshot the prefix trie can restore; the slot a row uses
+    rides in its table row beside its pages (``split_table``).
 
-    ``impl`` is who updates the slots. The state kernel copies a slot's
-    tail by its leading index, so under it a tail is held as whole tiles
-    of rows of 128 (``conv_slot_shape``); a step program reads which of the
-    two it was given from the rank of ``conv`` (``_linear_mixer``)."""
-    la = cfg.linear_attn
-    n = cfg.count_mixers("linear")
-    width = (la.conv_kernel - 1) * la.conv_size
+    ``impl`` is who updates the slots; a step program reads which form it
+    was given from the rank of ``conv`` (``_linear_mixer``)."""
+    n = cfg.count_mixers(cfg.state_mixer)
+    state, conv = slot_shapes(cfg, impl)
     return {
-        "state": jnp.zeros(
-            (n, slots, *state_slot_shape(la, impl)), STATE_DTYPE),
-        # flat [..., (kernel - 1) * width]: a minor pair of (3, width) would
-        # pad the 3 to a whole tile on the TPU, five times the bytes
-        "conv": jnp.zeros(
-            (n, slots, *(conv_slot_shape(width) if impl == "pallas-state"
-                         else (width,))), dtype),
+        "state": jnp.zeros((n, slots, *state), STATE_DTYPE),
+        "conv": jnp.zeros((n, slots, *conv), dtype),
     }
 
 
@@ -722,9 +791,9 @@ def split_table(cfg: ModelConfig, table: jax.Array):
 
 
 def copy_state_slots(cache: Params, src: jax.Array, dst: jax.Array) -> Params:
-    """Copy every linear layer's state and conv tail from slots ``src`` to
-    slots ``dst`` ([n] each; a negative ``dst`` copies nothing): a snapshot
-    taken or restored by a dispatch of its own."""
+    """Copy every state-keeping layer's state and conv tail from slots
+    ``src`` to slots ``dst`` ([n] each; a negative ``dst`` copies nothing):
+    a snapshot taken or restored by a dispatch of its own."""
     with jax.named_scope("state_io"):
         n = cache["state"].shape[1]
         to = jnp.where(dst >= 0, dst, n)
@@ -765,10 +834,10 @@ def cache_specs(
     if kv_quantize:
         values = QuantizedPages(values, scales)
     if cfg.has_state:
-        rank = 2 + len(state_slot_shape(cfg.linear_attn, state_impl))
-        return {"k": values, "v": values, "state": P(*[None] * rank),
-                "conv": P(*[None] * (
-                    4 if state_impl == "pallas-state" else 3)), **stats}
+        state, conv = slot_shapes(cfg, state_impl)
+        return {"k": values, "v": values,
+                "state": P(*[None] * (2 + len(state))),
+                "conv": P(*[None] * (2 + len(conv))), **stats}
     return {"k": values, "v": values, **stats}
 
 
@@ -1391,6 +1460,118 @@ def _linear_mixer(h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None):
     return out, cache
 
 
+def _mamba_mixer(
+    h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None,
+    pack: Pack | None = None,
+):
+    """Mamba-1's selective state-space mixer, with Jamba's norms of ``dt``,
+    ``B`` and ``C``, on the layer's normed input h [B, S, d], from and to
+    the rows' state slots (``ctx`` None: from zero, kept nowhere:
+    ``forward_full``). Returns (gated output before the output projection
+    [B, S, d_inner], cache). With ``pack`` h is the tick's packed tokens
+    [1, T, d] and so is what comes back: ``W_in`` (two thirds of the
+    mixer's weights) and the gate run over tokens (``Pack.dense``), and
+    only ``x`` is un-packed to the rows the conv and the scan take.
+
+    ``[x, z] = h W_in``; ``x = silu(conv(x) + b)``; ``[dt_low, B, C] = x
+    W_x``, each RMS-normed; ``dt = softplus(dt_low W_dt + b_dt)``; the scan
+    (``ops.selective_scan``: one token's recurrence for S == 1, a scan over
+    the row's slots else, float32, ``dt = 0`` past a row's ``valid``); ``y +
+    D x`` times ``silu(z)``. Who runs the scan is read from how the cache
+    holds the slots (``slot_shapes``). Held for the scan kernel, one call a
+    layer takes each row's state from its slot through the row's own tokens
+    to the live and the snapshot slot in place, and writes the conv tail by
+    row (``ops.selective_scan_pallas``). Held for XLA, or without slots, a
+    row's slot is gathered a row at a time and scattered twice, as a linear
+    layer's is."""
+    mc = cfg.mamba
+    B, S = h.shape[:2] if pack is None else pack.dst.shape
+    di, ds, r = mc.d_inner, mc.d_state, mc.dt_rank
+    eps = cfg.rms_norm_eps
+    kernel = ctx is not None and cache["conv"].ndim == 4
+    if ctx is None:
+        valid = jnp.full((B,), S, jnp.int32)
+        h0 = jnp.zeros((B, ds, di), jnp.float32)
+        tail = jnp.zeros((B, mc.d_conv - 1, di), h.dtype)
+    else:
+        valid = ctx.valid
+        with jax.named_scope("state_io"):
+            n_slots = cache["state"].shape[1]
+            idx = si * n_slots + ctx.slots
+            fresh = (ctx.start == 0) | (ctx.slots < 0)
+            state_flat = cache["state"].reshape(-1, *cache["state"].shape[2:])
+            conv_flat = cache["conv"].reshape(-1, *cache["conv"].shape[2:])
+            if not kernel:
+                h0 = _state_read(state_flat, idx, fresh)
+            tail = _state_read(conv_flat, idx, fresh)
+            if kernel:      # held as rows of 128, padded to whole tiles
+                tail = tail.reshape(B, -1)[:, :(mc.d_conv - 1) * di]
+            tail = tail.reshape(B, mc.d_conv - 1, di)
+    with jax.named_scope("ssm_proj"):
+        xz = _dense(pack, lambda a: _mm(a, lp["m_in"]), h)
+        x, z = xz[..., :di], xz[..., di:]
+        if pack is not None:
+            x = pack.rows(x)
+        x, tail = conv_with_tail(x, tail, lp["conv"].astype(x.dtype), valid)
+        if mc.conv_bias:
+            x = x + lp["conv_b"].astype(x.dtype)
+        x = jax.nn.silu(x)
+        low = _mm(x, lp["m_x"])
+        dt_low, Bm, Cm = (
+            rms_norm(low[..., a:b], lp[name], eps)
+            for name, a, b in (("dt_norm", 0, r), ("b_norm", r, r + ds),
+                               ("c_norm", r + ds, r + 2 * ds)))
+        dt = jax.nn.softplus(
+            _mm(dt_low, lp["m_dt"]).astype(jnp.float32) + lp["dt_bias"])
+        A = -jnp.exp(lp["a_log"])                               # [ds, di]
+        x32 = x.astype(jnp.float32)
+        Bm, Cm = Bm.astype(jnp.float32), Cm.astype(jnp.float32)
+    if ctx is not None:
+        # where a pass writes: the row's live slot, and its snapshot slot
+        # where the pass leaves the row on a page boundary
+        wrote = (valid > 0) & (ctx.slots >= 0)
+        snaps = wrote & ((ctx.start + valid) % ctx.page_size == 0) & (
+            ctx.snap >= 0)
+    with jax.named_scope("ssm_scan"):
+        if kernel:
+            y, state_flat, conv_flat = selective_scan_slots(
+                x32, dt, A, Bm, Cm, state_flat, conv_flat,
+                tail.reshape(B, -1), jnp.where(ctx.slots >= 0, idx, -1),
+                jnp.where(snaps, si * n_slots + ctx.snap, -1),
+                fresh, valid, interpret=pallas_interpret())
+        elif S == 1:
+            live = (valid > 0)[:, None]
+            y, h1 = selective_scan_step(
+                x32[:, 0], jnp.where(live, dt[:, 0], 0.0), A,
+                Bm[:, 0], Cm[:, 0], h0)
+            y = y[:, None]
+        else:
+            y, h1 = selective_scan(x32, dt, A, Bm, Cm, h0, valid)
+        y = y + lp["d_skip"] * x32
+    if ctx is not None:
+        with jax.named_scope("state_io"):
+            if not kernel:      # the kernel wrote both slots, in place
+                oob = state_flat.shape[0]
+                for to in (
+                    jnp.where(wrote, idx, oob),
+                    jnp.where(snaps, si * n_slots + ctx.snap, oob),
+                ):
+                    state_flat = state_flat.at[to].set(
+                        h1.astype(state_flat.dtype), mode="drop")
+                    conv_flat = conv_flat.at[to].set(
+                        tail.reshape(B, -1).astype(conv_flat.dtype),
+                        mode="drop")
+            cache = dict(
+                cache, state=state_flat.reshape(cache["state"].shape),
+                conv=conv_flat.reshape(cache["conv"].shape))
+    with jax.named_scope("ssm_proj"):
+        y = y.astype(h.dtype)
+        if pack is not None:
+            y = pack.tokens(y)
+        out = y * jax.nn.silu(z)
+    return out, cache
+
+
 def _route(h, lp, cfg: ModelConfig):
     """Router scores over the router's whole width, the top-k choice and
     the combine weights, per the checkpoint's HF config. Returns (probs
@@ -1705,6 +1886,16 @@ def _run_stack(
                     return x + post(_mm(attn, lp["wo"]), "attn_norm")
 
                 x = _dense(pack, out, x, attn, h)
+        elif mixer == "mamba":
+            # packed tokens in and out: only the conv and the scan see rows
+            mixed, cache = _mamba_mixer(
+                h, lp, cfg, cache, si, state_ctx, pack)
+            with jax.named_scope("attn_out"):
+                x = _dense(
+                    pack,
+                    lambda x, mixed: x + post(
+                        _mm(mixed, lp["m_out"]), "attn_norm"),
+                    x, mixed)
         else:
             if pack is not None:
                 with jax.named_scope("lin_proj"):

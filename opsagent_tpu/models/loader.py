@@ -259,9 +259,9 @@ def load_checkpoint(
     if (cfg.mixer_period or cfg.attn_output_gate or not cfg.use_rope
             or cfg.post_norm or cfg.qk_norm_whole):
         raise NotImplementedError(
-            "load_checkpoint: no loader for a solar_open2 or olmo_hybrid "
-            "checkpoint (a layer pattern, gated or NoPE attention, "
-            "linear-attention layers, the post-norm block): its "
+            "load_checkpoint: no loader for a solar_open2, olmo_hybrid or "
+            "jamba checkpoint (a layer pattern, gated or NoPE attention, "
+            "linear-attention or Mamba layers, the post-norm block): its "
             "checkpoint's tensor names are not public here; "
             "config_from_hf reads its config.json, and weights come seeded"
         )

@@ -189,7 +189,10 @@ _QUANT_KEYS = frozenset(
      # gated attention and linear-attention projections (models/llama
      # linear_block): the conv, decay vectors and norms stay exact.
      "wgate", "lq", "lk", "lv", "lo", "f_down", "f_up", "g_down", "g_up",
-     "wb", "wa", "wog"}
+     "wb", "wa", "wog",
+     # a Mamba layer's four projections (models/llama mamba_block): the
+     # conv, its bias, A_log, D, the dt bias and the norms stay exact.
+     "m_in", "m_x", "m_dt", "m_out"}
 )
 
 

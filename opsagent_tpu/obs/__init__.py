@@ -247,6 +247,15 @@ STATE_SLOT_BYTES = _reg.gauge(
     "float32 state, and the conv tail",
     labelnames=("part",),
 )
+SSM_SCAN_STEPS = _reg.counter(
+    "opsagent_ssm_scan_steps_total",
+    "Selective-scan recurrence steps of a model with Mamba layers, counted "
+    "at dispatch over all its Mamba layers: kind=computed the steps the "
+    "step programs ran (rows x slots a pass under XLA, whose scan walks "
+    "every slot of every row), kind=real those that carried a live token; "
+    "real / computed is how full the scan ran",
+    labelnames=("kind",),
+)
 STATE_SNAPSHOTS = _reg.counter(
     "opsagent_state_snapshots_total",
     "State snapshots by event: taken (put on the trie node that ends a "

@@ -202,7 +202,7 @@ def paged_attention_backend(
     return "xla" if refused else "pallas-stream"
 
 
-STATE_BACKENDS = ("xla", "pallas-state")
+STATE_BACKENDS = ("xla", "pallas-state", "pallas-ssm")
 
 
 def linear_state_backend(
@@ -234,6 +234,25 @@ def linear_state_backend(
     if value_dim % 128 and (heads % 2 or (2 * value_dim) % 128):
         return "xla"
     return "pallas-state"
+
+
+def ssm_state_backend(
+    *, platform: str, state_dtype: str, d_state: int, d_inner: int
+) -> str:
+    """Who runs a Mamba layer's selective scan over the state slots in an
+    engine's step programs: "xla" (a slot gathered a row at a time, a
+    ``lax.scan`` over every slot of every row in plain ``jax.numpy``, two
+    scatters; the oracle of every test) or "pallas-ssm" (one kernel a layer
+    from read through the rows' own tokens to both writes,
+    ``selective_scan_pallas``). The code's own choice, as
+    ``linear_state_backend`` is, read back by every step program from how
+    the cache is held (``llama.slot_shapes``): the kernel on a TPU wherever
+    the float32 state ``[d_state, d_inner]`` lies on whole (8, 128) tiles,
+    everywhere else XLA."""
+    if (platform != "tpu" or state_dtype != "float32" or d_state % 8
+            or d_inner % 128):
+        return "xla"
+    return "pallas-ssm"
 
 
 def pallas_interpret() -> bool:

@@ -35,6 +35,11 @@ from .scheduler import Request, RequestError, Scheduler
 log = get_logger("serving.api")
 
 
+# What ``ServingStack._stream_events`` yields where it needs the next item of
+# its stream's queue: the driver sends the item in.
+_NEXT_TOKEN = object()
+
+
 class _StampedChunk(dict):
     """A streamed content chunk that remembers when the scheduler thread
     handed over the newest token it carries (``time.perf_counter()``): the
@@ -505,6 +510,58 @@ class ServingStack:
 
     def chat_completion_stream(self, body: dict[str, Any]):
         """Generator of SSE chunk dicts (sync; drive from a thread)."""
+        # (token, when the scheduler thread handed it over) or None (end).
+        token_q: "queue.Queue[tuple[int, float] | None]" = queue.Queue()
+        events, close = self._open_stream(body, token_q.put)
+        try:
+            out = next(events)
+            while True:
+                if out is _NEXT_TOKEN:
+                    out = events.send(token_q.get())
+                else:
+                    yield out
+                    out = next(events)
+        except StopIteration:
+            return
+        finally:
+            close()
+
+    async def chat_completion_astream(self, body: dict[str, Any]):
+        """The same chunks as an async generator, for a handler on an event
+        loop: the scheduler thread hands each token to the loop itself
+        (``call_soon_threadsafe``) and the chunk is made there, so a stream
+        holds no worker of the loop's pool between tokens and a token costs
+        no executor job: the pool has some 17 workers, an engine of 64 rows
+        and its queue 96 streams. Only the request's translation, once a
+        stream, runs in a worker."""
+        loop = asyncio.get_running_loop()
+        token_q: "asyncio.Queue[tuple[int, float] | None]" = asyncio.Queue()
+
+        def put(item) -> None:
+            try:
+                loop.call_soon_threadsafe(token_q.put_nowait, item)
+            except RuntimeError:
+                pass    # the loop closed under a stream still open
+
+        events, close = await loop.run_in_executor(
+            None, self._open_stream, body, put)
+        try:
+            out = next(events)
+            while True:
+                if out is _NEXT_TOKEN:
+                    out = events.send(await token_q.get())
+                else:
+                    yield out
+                    out = next(events)
+        except StopIteration:
+            return
+        finally:
+            close()
+
+    def _open_stream(self, body: dict[str, Any], put):
+        """Translate and submit one streamed request whose tokens the
+        scheduler thread hands to ``put``. Returns the stream's events
+        (``_stream_events``) and what closes its trace."""
         hop = body.pop("fleet_hop", None) if isinstance(body, dict) \
             else None
         sampling, prompt_ids, mask_fn = self._translate(body)
@@ -520,8 +577,6 @@ class ServingStack:
             raise RequestError(f"invalid n: {e}", 400) from e
         if n != 1:
             raise RequestError("n > 1 is not supported with stream", 400)
-        # (token, when the scheduler thread handed it over) or None (end).
-        token_q: "queue.Queue[tuple[int, float] | None]" = queue.Queue()
         owned, parent, cid = self._request_trace(hop)
         self._stamp_class(parent, body)
         gen_span = (
@@ -530,7 +585,7 @@ class ServingStack:
         )
         req = Request(
             prompt_ids, sampling, mask_fn=mask_fn,
-            on_token=lambda t: token_q.put((t, time.perf_counter())),
+            on_token=lambda t: put((t, time.perf_counter())),
             trace=gen_span,
         )
         self.scheduler.submit(req)
@@ -550,40 +605,32 @@ class ServingStack:
                 ],
             }
 
-        try:
-            yield from self._stream_events(
-                req, token_q, chunk, sampling, eos, sent
-            )
-        finally:
-            # Close the trace no matter how the stream ends (client
-            # disconnect raises GeneratorExit here): the span tree stays
+        def close() -> None:
+            # Close the trace no matter how the stream ends (a client's
+            # disconnect closes the driving generator): the span tree stays
             # retrievable at /api/trace/{cid} with whatever phases ran.
             if gen_span is not None:
                 gen_span.close(tokens=len(sent))
             if owned is not None:
                 owned.finish()
 
-    def _stream_events(self, req, token_q, chunk, sampling, eos, sent):
-        watchdog = threading.Thread(
-            target=lambda: (req.done.wait(600), token_q.put(None)), daemon=True
-        )
-        watchdog.start()
+        threading.Thread(
+            target=lambda: (req.done.wait(600), put(None)), daemon=True
+        ).start()
+        return self._stream_events(req, chunk, sampling, eos, sent), close
+
+    def _stream_events(self, req, chunk, sampling, eos, sent):
+        """One stream's chunks, whoever waits for its tokens: yields
+        ``_NEXT_TOKEN`` where it needs the next item of the stream's queue,
+        which the driver sends in, and a chunk dict otherwise."""
         # Hold the first SSE chunk until the admission outcome is known:
         # admission failures (prompt too long, engine saturated) must surface
         # as an HTTP error status, not a 200 followed by an in-stream error.
-        first_tok = token_q.get()
-        if first_tok is None and req.error:
+        item = yield _NEXT_TOKEN
+        if item is None and req.error:
             raise RequestError(req.error, req.error_status)
         yield chunk({"role": "assistant", "content": ""})
         handed = 0.0    # when the newest token read so far was handed over
-
-        def _tokens():
-            nonlocal handed
-            item = first_tok
-            while item is not None:
-                t, handed = item
-                yield t
-                item = token_q.get()
 
         def content(text: str) -> _StampedChunk:
             out = _StampedChunk(chunk({"content": text}))
@@ -601,14 +648,17 @@ class ServingStack:
         read_off = 0     # tokens already diffed within the window
         pending = ""     # decoded but unemitted (stop-string holdback)
         stopped = False
-        for tok in _tokens():
+
+        def feed(tok: int) -> str:
+            """The text to emit now that ``tok`` has come ("": none yet)."""
+            nonlocal prefix_off, read_off, pending, stopped
             if tok == eos or stopped:
-                continue
+                return ""
             sent.append(tok)
             prefix_text = decode(sent[prefix_off:read_off])
             window_text = decode(sent[prefix_off:])
             if window_text.endswith("�"):
-                continue  # incomplete multi-byte tail; wait for more tokens
+                return ""  # incomplete multi-byte tail; wait for more tokens
             # Both decodes start at prefix_off, so context-dependent effects
             # at the window start (sentencepiece leading-space stripping)
             # cancel in the diff and the windows telescope correctly. Guard
@@ -625,7 +675,7 @@ class ServingStack:
             delta = window_text[cut:]
             prefix_off, read_off = read_off, len(sent)
             if not delta:
-                continue
+                return ""
             pending += delta
             for s in sampling.stop:
                 idx = pending.find(s)
@@ -639,8 +689,14 @@ class ServingStack:
                 emit, pending = pending[: -(max_stop - 1)], pending[-(max_stop - 1):]
             else:
                 emit, pending = pending, ""
+            return emit
+
+        while item is not None:
+            tok, handed = item
+            emit = feed(tok)
             if emit:
                 yield content(emit)
+            item = yield _NEXT_TOKEN
         if req.error:
             yield {"error": {"message": req.error}}
             return
@@ -856,12 +912,12 @@ def build_engine_app(stack: ServingStack, membership=None):
                 }
         loop = asyncio.get_running_loop()
         if body.get("stream"):
-            gen = stack.chat_completion_stream(body)
+            gen = stack.chat_completion_astream(body)
             # Pull the first chunk BEFORE preparing the stream: request-
             # translation errors (bad sampling params, prompt too long)
             # surface as a proper JSON error status, not a dead connection.
             try:
-                first = await loop.run_in_executor(None, lambda: next(gen, None))
+                first = await anext(gen, None)
             except Exception as e:  # noqa: BLE001
                 status = e.status if isinstance(e, RequestError) else 500
                 return web.json_response(
@@ -885,15 +941,15 @@ def build_engine_app(stack: ServingStack, membership=None):
                     await resp.write(
                         b"data: " + json.dumps(chunk).encode("utf-8") + b"\n\n"
                     )
-                    chunk = await loop.run_in_executor(
-                        None, lambda: next(gen, None)
-                    )
+                    chunk = await anext(gen, None)
             except Exception as e:  # noqa: BLE001 - headers already sent
                 log.exception("stream failed mid-flight")
                 err = {"error": {"message": str(e), "type": type(e).__name__}}
                 await resp.write(
                     b"data: " + json.dumps(err).encode("utf-8") + b"\n\n"
                 )
+            finally:
+                await gen.aclose()    # the stream's trace closes now
             await resp.write(b"data: [DONE]\n\n")
             await resp.write_eof()
             return resp

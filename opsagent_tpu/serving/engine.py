@@ -427,7 +427,7 @@ class Engine:
         # reports what runs.
         from ..ops.attention import (
             linear_state_backend, pallas_interpret, pallas_refusal,
-            paged_attention_backend,
+            paged_attention_backend, ssm_state_backend,
         )
 
         ws = cfg.weight_stream or os.environ.get(
@@ -491,18 +491,26 @@ class Engine:
         # too, made here so that the cache is held in the form it reads.
         self.state_impl = "xla"
         if self.model_cfg.has_state:
-            la = self.model_cfg.linear_attn
-            self.state_impl = linear_state_backend(
-                platform=platform,
-                state_dtype=jnp.dtype(llama.STATE_DTYPE).name,
-                key_dim=la.key_head_dim, value_dim=la.value_head_dim,
-                heads=la.num_heads,
-            )
-            log.info("linear-attention state: %s", self.state_impl)
+            la, mc = self.model_cfg.linear_attn, self.model_cfg.mamba
+            state_dtype = jnp.dtype(llama.STATE_DTYPE).name
+            if la is not None:
+                self.state_impl = linear_state_backend(
+                    platform=platform, state_dtype=state_dtype,
+                    key_dim=la.key_head_dim, value_dim=la.value_head_dim,
+                    heads=la.num_heads,
+                )
+            else:
+                self.state_impl = ssm_state_backend(
+                    platform=platform, state_dtype=state_dtype,
+                    d_state=mc.d_state, d_inner=mc.d_inner,
+                )
+            log.info("%s state: %s", self.model_cfg.state_mixer,
+                     self.state_impl)
             # What carries a sequence between steps, tiers or replicas as a
             # page chain alone would serve this model without its
             # recurrent state: refuse it here, by name, instead.
-            why = "a model with linear-attention layers (recurrent state)"
+            why = ("a model with linear-attention layers or Mamba layers "
+                   "(recurrent state)")
             refused = {
                 f"tp={tp}": tp > 1,
                 "weight_stream=pallas-dma": ws == "pallas-dma",
@@ -654,6 +662,7 @@ class Engine:
             for part in ("state", "conv"):
                 a = self.cache[part]
                 obs.STATE_SLOT_BYTES.set(a.nbytes // a.shape[1], part=part)
+        self._scan_layers = self.model_cfg.count_mixers("mamba")
         self._moe_stats_seen = np.zeros((len(llama.MOE_STATS),), np.uint32)
         self._snapshots_seen = [0, 0]    # taken, evicted: obs delta bases
         # Host-RAM offload tier: spills ride every trie eviction, restores
@@ -1016,8 +1025,8 @@ class Engine:
         if client is not None and self.model_cfg.has_state:
             raise BackendRefused(
                 "the fleet page store (peer fault-in of page chains) is not "
-                "supported for a model with linear-attention layers: a "
-                "page chain does not carry its recurrent state"
+                "supported for a model with linear-attention layers or Mamba "
+                "layers: a page chain does not carry its recurrent state"
             )
         self._pagestore = client
 
@@ -1085,7 +1094,11 @@ class Engine:
             # state_slot_shape says which of its two layouts and why
             info["state_layout"] = [state.shape[0], *state.shape[2:]]
             info["state_slot_bytes"] = state.nbytes // state.shape[1]
-            info["lin_decay"] = self.model_cfg.linear_attn.decay
+            conv = self.cache["conv"]
+            info["conv_slot_bytes"] = conv.nbytes // conv.shape[1]
+            info["state_mixer"] = self.model_cfg.state_mixer
+            if self.model_cfg.linear_attn is not None:
+                info["lin_decay"] = self.model_cfg.linear_attn.decay
         return info
 
     def device_memory(self) -> list[dict[str, Any]]:
@@ -1577,8 +1590,8 @@ class Engine:
         if self.model_cfg.has_state:
             raise BackendRefused(
                 "Engine.snapshot (snapshot/writer.py) is not supported for "
-                "a model with linear-attention layers: its paged-KV plan "
-                "has no place for the recurrent state"
+                "a model with linear-attention layers or Mamba layers: its "
+                "paged-KV plan has no place for the recurrent state"
             )
         with self.lock:
             return write_snapshot(self, path)
@@ -1929,6 +1942,7 @@ class Engine:
                         "engine.prefill_tokens", int(sum(chunks)), "tok"
                     )
                     obs.PREFILL_TOKENS.inc(int(sum(chunks)))
+                    self._count_scan(int(sum(chunks)), Bp * bucket)
                     obs.flight.record(
                         "dispatch", op="prefill_batch",
                         seq_ids=list(seq_ids),
@@ -2115,6 +2129,7 @@ class Engine:
                             )
                 done += chunk
                 perf = get_perf_stats()
+                self._count_scan(chunk, bucket)
                 with obs.phase("plan", part="account"):
                     perf.record_metric("engine.prefill_tokens", chunk, "tok")
                     obs.PREFILL_TOKENS.inc(chunk)
@@ -2203,10 +2218,23 @@ class Engine:
             read = int((-(-live // P) * P).sum())
         obs.ATTN_CONTEXT_TOKENS.inc(read, what="read")
 
+    def _count_scan(self, real: int, computed: int) -> None:
+        """Count a dispatch's selective-scan steps, over all the Mamba
+        layers of a model that has them: ``real`` tokens among the
+        ``computed`` slots (rows x slots a pass) the program's scan walks
+        under XLA; the scan kernel walks a row's own tokens and no more."""
+        if self._scan_layers:
+            if self.state_impl == "pallas-ssm":
+                computed = real
+            obs.SSM_SCAN_STEPS.inc(real * self._scan_layers, kind="real")
+            obs.SSM_SCAN_STEPS.inc(
+                computed * self._scan_layers, kind="computed")
+
     def _count_step_tokens(self, S: int, real: int) -> str:
         """Count a mixed dispatch of ``real`` tokens; returns the width its
         dense segments run over, as the counter's label has it (the step
         clock's ticket carries it to the pull)."""
+        self._count_scan(real, self.cfg.max_batch_size * S)
         width = self._step_rows(S, real)
         obs.STEP_TOKENS.inc(real, kind="real")
         obs.STEP_TOKENS.inc(width, kind="computed")
@@ -3383,6 +3411,7 @@ class Engine:
             for s in running:
                 at = self.alloc.length(s.seq_id)
                 self.alloc.note_pass(s.seq_id, at - 1, at)
+            self._count_scan(len(running), B)
             table, lengths, active = self.alloc.batch_views(ids, B)
             # lengths now include the new token; decode wants the write
             # offset (tokens already present before this step).
@@ -3696,6 +3725,7 @@ class Engine:
                         [self.alloc.length(sid) if budgets[lane] else 0
                          for lane, sid in enumerate(lane_seqs)], np.int64),
                     budgets, block)
+                self._count_scan(int(budgets.sum()), B * block)
             with self._building_arrays():
                 table, _, _ = self.alloc.batch_views(lane_seqs, B)
                 slots = [

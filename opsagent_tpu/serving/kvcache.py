@@ -20,7 +20,12 @@ States of a page:
   when the free list runs dry.
 
 Allocation is O(pages) against a free list plus O(prompt/page_size) trie
-walks; page counts are small (thousands).
+walks. Nothing here walks the whole trie on the serving path: the count of
+cached pages is kept (``free_pages`` is read several times a tick), and the
+least recently used cached page, or snapshot, comes off a heap whose entries
+are checked against their node when popped (``_edit`` is the one place a
+node's refcount, children or stamp changes). A pool of 16k pages held by a
+full trie otherwise costs a scan of it a page taken and a gauge read.
 
 **Recurrent state** (``state_slots`` > 0: a model with linear-attention
 layers, ``models.llama.make_state``). Such a layer keeps a fixed-size state
@@ -40,6 +45,7 @@ new sequence needs a slot; evicting one never touches a page.
 
 from __future__ import annotations
 
+import heapq
 import itertools
 from dataclasses import dataclass, field
 
@@ -84,6 +90,8 @@ class TrieNode:
     children: int = 0                # child nodes (only leaves are evictable)
     last_use: int = 0                # LRU stamp
     snapshot: int = -1               # state-snapshot slot at this page's end
+    order: int = 0                   # rank of insertion: equal stamps fall
+    #                                  to the older node, as a scan did
 
 
 class PageAllocator:
@@ -118,6 +126,13 @@ class PageAllocator:
         self._trie: dict[tuple[int, tuple[int, ...]], TrieNode] = {}
         self._by_page: dict[int, TrieNode] = {}
         self._clock = itertools.count()
+        # Cached nodes (refcount 0, no children: evictable), counted, and
+        # the LRU queues of them and of the nodes that hold a snapshot:
+        # (last_use, order, page), true while the node still reads so.
+        self._cached = 0
+        self._order = itertools.count()
+        self._lru: list[tuple[int, int, int]] = []
+        self._snap_lru: list[tuple[int, int, int]] = []
         self.hit_tokens = 0   # cumulative prefix-cache hits (stats)
         self.miss_tokens = 0
         self.evictions = 0    # cumulative trie-leaf evictions (stats)
@@ -172,10 +187,7 @@ class PageAllocator:
     # -- queries -----------------------------------------------------------
     @property
     def free_pages(self) -> int:
-        return len(self._free) + sum(
-            1 for n in self._by_page.values()
-            if n.refcount == 0 and n.children == 0
-        )
+        return len(self._free) + self._cached
 
     def accounting(self) -> dict[str, int]:
         """Page-conservation snapshot: every page is exactly one of free,
@@ -224,7 +236,8 @@ class PageAllocator:
             node = self._trie.get((parent, tuple(tokens[i * P:(i + 1) * P])))
             if node is None:
                 break
-            node.last_use = stamp  # matched chains are fresh, not LRU bait
+            # matched chains are fresh, not LRU bait
+            self._edit(node, stamp=stamp)
             pages.append(node.page)
             parent = node.page
         return pages
@@ -252,16 +265,20 @@ class PageAllocator:
         -1 when running sequences hold them all."""
         if self._free_snaps:
             return self._free_snaps.pop()
-        victim: TrieNode | None = None
-        for node in self._by_page.values():
-            if node.snapshot >= 0 and node.snapshot != keep and (
-                victim is None or node.last_use < victim.last_use
-            ):
-                victim = node
-        if victim is None:
-            return -1
-        slot, victim.snapshot = victim.snapshot, -1
-        self.snapshots_evicted += 1
+        kept = None
+        slot = -1
+        while self._snap_lru and slot < 0:
+            entry = heapq.heappop(self._snap_lru)
+            node = self._node_of(entry)
+            if node is None or node.snapshot < 0:
+                continue
+            if node.snapshot == keep:
+                kept = entry
+                continue
+            slot, node.snapshot = node.snapshot, -1
+            self.snapshots_evicted += 1
+        if kept is not None:
+            heapq.heappush(self._snap_lru, kept)
         return slot
 
     def _drop_snapshot(self, node: TrieNode) -> None:
@@ -317,22 +334,69 @@ class PageAllocator:
         at = self.snapshot_boundary(seq_id, done, n)
         return min(chunk, at - done) if at else chunk
 
+    # -- the trie's nodes: every change goes through these -----------------
+    def _node_of(self, entry: tuple[int, int, int]) -> TrieNode | None:
+        """The node a queue's entry still describes, else None."""
+        stamp, order, page = entry
+        node = self._by_page.get(page)
+        if node is None or node.order != order or node.last_use != stamp:
+            return None
+        return node
+
+    def _queue(self, heap: list, node: TrieNode) -> None:
+        heapq.heappush(heap, (node.last_use, node.order, node.page))
+        if len(heap) > 4 * len(self._by_page) + 1024:
+            # stale entries outnumber the nodes: start both queues afresh
+            nodes = self._by_page.values()
+            self._lru[:] = [
+                (n.last_use, n.order, n.page) for n in nodes
+                if n.refcount == 0 and n.children == 0]
+            self._snap_lru[:] = [
+                (n.last_use, n.order, n.page) for n in nodes
+                if n.snapshot >= 0]
+            heapq.heapify(self._lru)
+            heapq.heapify(self._snap_lru)
+
+    def _edit(self, node: TrieNode, refs: int = 0, kids: int = 0,
+              stamp: int | None = None) -> None:
+        """Change a trie node's refcount, children or LRU stamp, and keep
+        the count of cached nodes and both queues true to it."""
+        was = node.refcount == 0 and node.children == 0
+        node.refcount += refs
+        node.children += kids
+        if stamp is not None:
+            node.last_use = stamp
+            if node.snapshot >= 0:
+                self._queue(self._snap_lru, node)
+        now = node.refcount == 0 and node.children == 0
+        self._cached += now - was
+        if now and (stamp is not None or not was):
+            self._queue(self._lru, node)
+
+    def _insert(self, node: TrieNode) -> None:
+        node.order = next(self._order)
+        self._trie[(node.parent, node.key)] = node
+        self._by_page[node.page] = node
+        if node.parent >= 0 and node.parent in self._by_page:
+            self._edit(self._by_page[node.parent], kids=1)
+        if node.refcount == 0 and node.children == 0:
+            self._cached += 1
+            self._queue(self._lru, node)
+
     def _take_free_page(self) -> int:
         """Pop a free page, evicting the LRU unreferenced trie leaf if the
         free list is dry. Raises OutOfPages when nothing is evictable."""
         if self._free:
             return self._free.pop()
-        victim: TrieNode | None = None
-        for node in self._by_page.values():
-            if node.refcount == 0 and node.children == 0:
-                if victim is None or node.last_use < victim.last_use:
-                    victim = node
-        if victim is None:
-            raise OutOfPages("no free pages and no evictable cached pages")
-        self._evict(victim)
-        return self._free.pop()
+        while self._lru:
+            node = self._node_of(heapq.heappop(self._lru))
+            if node is not None and node.refcount == 0 and node.children == 0:
+                self._evict(node)
+                return self._free.pop()
+        raise OutOfPages("no free pages and no evictable cached pages")
 
     def _evict(self, node: TrieNode) -> None:
+        """Drop a cached node (refcount 0, no children) from the trie."""
         if self._spill is not None:
             # Host tier: copy the content out before the page is reused.
             # The chain is reconstructed BEFORE the node leaves the trie.
@@ -343,11 +407,12 @@ class PageAllocator:
             except Exception:  # noqa: BLE001 - offload is best-effort
                 pass
         self.evictions += 1
+        self._cached -= 1
         self._drop_snapshot(node)
         del self._trie[(node.parent, node.key)]
         del self._by_page[node.page]
         if node.parent >= 0 and node.parent in self._by_page:
-            self._by_page[node.parent].children -= 1
+            self._edit(self._by_page[node.parent], kids=-1)
         self._free.append(node.page)
 
     def evict_chain(self, pages: list[int]) -> int:
@@ -418,17 +483,12 @@ class PageAllocator:
             if node is not None and node.page != page:
                 break
             if node is None:
-                node = TrieNode(
+                self._insert(TrieNode(
                     page=page, parent=parent, key=key,
                     refcount=1, last_use=stamp,
-                )
-                self._trie[(parent, key)] = node
-                self._by_page[page] = node
-                if parent >= 0 and parent in self._by_page:
-                    self._by_page[parent].children += 1
+                ))
             else:
-                node.refcount += 1
-                node.last_use = stamp
+                self._edit(node, refs=1, stamp=stamp)
             seq.num_shared = i + 1
             promoted += 1
             parent = page
@@ -468,32 +528,29 @@ class PageAllocator:
             page = seq.pages[i]
             if i < seq.num_shared:
                 node = self._by_page[page]   # we hold a ref: cannot be evicted
-                node.refcount -= 1
-                node.last_use = stamp
+                self._edit(node, refs=-1, stamp=stamp)
                 parent = page
             elif (node := self._trie.get((parent, key))) is not None:
                 # Same content already cached by someone else: our page is a
                 # duplicate — follow the canonical chain, free ours.
-                node.last_use = stamp
+                self._edit(node, stamp=stamp)
                 parent = node.page
             else:
                 node = TrieNode(
                     page=page, parent=parent, key=key, last_use=stamp)
-                self._trie[(parent, key)] = node
-                self._by_page[page] = node
-                if parent >= 0 and parent in self._by_page:
-                    self._by_page[parent].children += 1
+                self._insert(node)
                 absorbed.add(page)
                 parent = page
             if i == snap_page and node.snapshot < 0:
                 node.snapshot, seq.snap_slot = seq.snap_slot, -1
                 self.snapshots_taken += 1
+                self._queue(self._snap_lru, node)
         # Shared pages past the registered walk (can happen only if tokens
         # shrank, which callers never do — defensive deref).
         for i in range(full_pages, seq.num_shared):
             node = self._by_page.get(seq.pages[i])
             if node is not None:
-                node.refcount -= 1
+                self._edit(node, refs=-1)
         return [
             p for i, p in enumerate(seq.pages)
             if i >= seq.num_shared and p not in absorbed
@@ -522,9 +579,7 @@ class PageAllocator:
         # candidates while _take_free_page hunts for fresh ones — handing
         # the same physical page out as both prefix and tail.
         for p in shared:
-            node = self._by_page[p]
-            node.refcount += 1
-            node.last_use = next(self._clock)
+            self._edit(self._by_page[p], refs=1, stamp=next(self._clock))
         need_fresh = need_total - len(shared)
         fresh: list[int] = []
         try:
@@ -533,14 +588,14 @@ class PageAllocator:
         except OutOfPages:
             self._free.extend(fresh)
             for p in shared:
-                self._by_page[p].refcount -= 1
+                self._edit(self._by_page[p], refs=-1)
             raise
         seq = SeqAlloc(self._next_id)
         if self.state_slots:
             if not self._free_live:
                 self._free.extend(fresh)
                 for p in shared:
-                    self._by_page[p].refcount -= 1
+                    self._edit(self._by_page[p], refs=-1)
                 raise OutOfPages("no free recurrent-state slot")
             seq.state_slot = self._free_live.pop()
             restored_from = (
@@ -621,7 +676,7 @@ class PageAllocator:
                 if i < seq.num_shared:
                     node = self._by_page.get(p)
                     if node is not None:
-                        node.refcount -= 1
+                        self._edit(node, refs=-1)
                 else:
                     self._free.append(p)
         self._release_state(seq)

@@ -61,6 +61,104 @@ class TestAllocatorTrie:
         assert len(a.match_prefix(toks(8))) == 1   # parent survived
         a.free(s3)
 
+    @pytest.mark.parametrize("state", [0, 3], ids=["pages", "state-slots"])
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_queues_pick_what_a_scan_of_the_trie_picks(self, state, seed):
+        """The LRU queues and the kept count against their oracle, a scan
+        of the whole trie: under random admissions over shared prefixes,
+        growth, frees and promotions on a pool that runs dry, both hand
+        out the same pages and snapshot slots, and ``free_pages`` is the
+        scan's count at every step."""
+        import random
+
+        class Scan(PageAllocator):
+            def _take_free_page(self):
+                if self._free:
+                    return self._free.pop()
+                victim = None
+                for node in self._by_page.values():
+                    if node.refcount == 0 and node.children == 0 and (
+                            victim is None
+                            or node.last_use < victim.last_use):
+                        victim = node
+                if victim is None:
+                    raise OutOfPages("dry")
+                self._evict(victim)
+                return self._free.pop()
+
+            def _take_snapshot_slot(self, keep=-1):
+                if self._free_snaps:
+                    return self._free_snaps.pop()
+                victim = None
+                for node in self._by_page.values():
+                    if node.snapshot >= 0 and node.snapshot != keep and (
+                            victim is None
+                            or node.last_use < victim.last_use):
+                        victim = node
+                if victim is None:
+                    return -1
+                slot, victim.snapshot = victim.snapshot, -1
+                self.snapshots_evicted += 1
+                return slot
+
+        def scanned_free(a):
+            return len(a._free) + sum(
+                1 for n in a._by_page.values()
+                if n.refcount == 0 and n.children == 0)
+
+        kw = dict(num_pages=40, page_size=P, max_pages_per_seq=12,
+                  state_slots=state, state_snapshots=2 * state)
+        new, old = PageAllocator(**kw), Scan(**kw)
+        rng = random.Random(seed)
+        stems = [toks(8, base=1000 * k) for k in range(3)]
+        live: dict[int, list[int]] = {}
+        for step in range(600):
+            op = rng.random()
+            if op < 0.45 and len(live) < (state or 6):
+                prompt = rng.choice(stems) + toks(
+                    rng.randrange(1, 20), base=rng.randrange(8) * 50)
+                got = []
+                for a in (new, old):
+                    if state:
+                        pages, _slot, _full = a.match_prefix_state(
+                            prompt[:-1])
+                    else:
+                        pages = a.match_prefix(prompt[:-1])
+                    try:
+                        sid = a.allocate(len(prompt), prefix_pages=pages)
+                        got.append((sid, a.page_table_row(sid).tolist()))
+                    except OutOfPages:
+                        got.append(None)
+                assert got[0] == got[1], step
+                if got[0] is not None:
+                    live[got[0][0]] = prompt
+            elif op < 0.65 and live:
+                sid = rng.choice(list(live))
+                n = rng.randrange(1, 6)
+                grown = [a.extend_upto(sid, n) for a in (new, old)]
+                assert grown[0] == grown[1], step
+                live[sid] = live[sid] + toks(grown[0], base=7000 + step)
+                for a in (new, old):
+                    a.note_pass(sid, 0, len(live[sid]), each_token=True)
+            elif op < 0.75 and live and not state:
+                sid = rng.choice(list(live))
+                assert (new.promote_prefix(sid, live[sid])
+                        == old.promote_prefix(sid, live[sid])), step
+            elif live:
+                sid = rng.choice(list(live))
+                history = live.pop(sid)
+                tokens = history if rng.random() < 0.9 else None
+                for a in (new, old):
+                    a.free(sid, tokens=tokens)
+            assert new.free_pages == scanned_free(new) == scanned_free(old)
+            assert new.accounting() == old.accounting(), step
+            assert [new.pages_of(s) for s in live] == [
+                old.pages_of(s) for s in live], step
+        assert new.evictions == old.evictions > 20
+        assert new.snapshots_evicted == old.snapshots_evicted
+        if state:
+            assert new.snapshots_evicted > 5
+
     def test_disabled_cache_frees_everything(self):
         a = PageAllocator(8, P, 8, prefix_cache=False)
         sid = a.allocate(8)

@@ -328,6 +328,56 @@ def _scripted_stack(tokens):
     return s
 
 
+def test_an_open_stream_holds_no_worker_between_tokens():
+    """More streams than the event loop's executor has workers (at most 32)
+    all stay open at once: the scheduler here hands out no token until all
+    40 requests are in, which a stream that waited for its first token in a
+    worker would never see."""
+    want, text = 40, "ok then"
+
+    class Gated:
+        def __init__(self):
+            self.held, self.lock = [], threading.Lock()
+
+        def submit(self, req):
+            with self.lock:
+                self.held.append(req)
+                go = self.held if len(self.held) == want else []
+            for r in go:
+                for t in text.encode():
+                    r.on_token(t)
+                r.finish_reason = "length"
+                r.done.set()
+            return req
+
+    s = _scripted_stack([])
+    s.scheduler = Gated()
+    app = build_engine_app(s)
+
+    async def scenario():
+        client = TestClient(TestServer(app))
+        await client.start_server()
+        try:
+            async def one(i):
+                r = await client.post("/v1/chat/completions", json={
+                    "messages": [{"role": "user", "content": f"q{i}"}],
+                    "stream": True})
+                assert r.status == 200
+                lines = [json.loads(ln[6:]) for ln in (await r.text())
+                         .splitlines() if ln.startswith("data: {")]
+                return "".join(c["choices"][0]["delta"].get("content", "")
+                               for c in lines)
+
+            got = await asyncio.wait_for(
+                asyncio.gather(*(one(i) for i in range(want))), 60)
+            assert got == [text] * want
+        finally:
+            await client.close()
+
+    asyncio.get_event_loop_policy().new_event_loop().run_until_complete(
+        scenario())
+
+
 def test_stream_stop_string_straddles_chunks():
     """Stop-string holdback: 'END' arriving one byte per token must still be
     caught, and nothing after (or of) the stop string is emitted."""

@@ -1269,3 +1269,187 @@ def test_glm_flash_decode_block_copies_no_latent_cache(v5e, impl):
     print(f"glm decode block [{impl}]: scratch "
           f"{m.temp_size_in_bytes / 2**30:.2f} GiB, "
           f"held {held / 2**30:.2f} GiB")
+
+
+# -- AI21-Jamba2-3B: Mamba layers over the state slots (PR 42) ------------------
+def _jamba_cell():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmarks", "configs", "jamba2-3b-int8.json")) as f:
+        return json.load(f)
+
+
+def _jamba_shapes(sds, state_impl: str = "pallas-ssm"):
+    """The cell's model whole (28 layers, int8 leaves, its own head), its
+    pages for the streaming kernel and its state slots (held for the scan
+    kernel, as the engine holds them on a TPU), as shapes on the chip, with
+    the cell's engine settings."""
+    engine = _jamba_cell()["engine"]
+    cfg = get_config_preset("jamba2-3b-untied")
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: sds(x.shape, x.dtype), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: llama.init_params_random_quantized(cfg, 0)))
+    cache = on_chip(jax.eval_shape(lambda: llama.make_cache(
+        cfg, engine["num_pages"], PAGE, jnp.bfloat16,
+        state_slots=engine["max_batch_size"] + engine["state_snapshots"],
+        form=llama.cache_form(cfg, 1, "pallas-stream"),
+        state_impl=state_impl)))
+    key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    return engine, cfg, params, cache, key
+
+
+def _held(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
+def test_jambas_state_slots_are_held_with_nothing_padded(v5e):
+    """A slot of the 3B: 26 layers of ``[16, 5120]`` float32 (the channels
+    on the lanes, the 16 state indices on the sublanes) and a flat conv
+    tail of 3 x 5120 bfloat16: 9,318,400 B, what ISSUE 42 reckoned. Held
+    row-major, the published ``[5120, 16]`` would pad its minor 16 to 128
+    lanes (or be held in a layout of the compiler's choosing, copied at
+    every program's entry: ``MLAConfig.page_dim``). Pinned on the chip's own
+    layouts: the restore program (``copy_state_slots``, the cache donated)
+    holds the arrays' bytes, pages at one kv head included, and not a tile
+    more."""
+    sds = _one_chip(v5e)
+
+    def per_slot(cache, slots):
+        return sum(
+            int(np.prod(cache[p].shape)) * cache[p].dtype.itemsize // slots
+            for p in ("state", "conv"))
+
+    engine, cfg, _, cache, _ = _jamba_shapes(sds, "xla")
+    slots = engine["max_batch_size"] + engine["state_snapshots"]
+    assert cache["state"].shape == (26, slots, 16, 5120)
+    assert cache["state"].dtype == jnp.float32
+    assert cache["conv"].shape == (26, slots, 15360)
+    assert per_slot(cache, slots) == 9_318_400 == 26 * (
+        16 * 5120 * 4 + 3 * 5120 * 2)
+    # as the engine holds them on the chip: the same state, and a tail as
+    # whole tiles of rows of 128 (120 rows of it used), 0.6% more a slot
+    assert attention.ssm_state_backend(
+        platform="tpu", state_dtype="float32", d_state=16, d_inner=5120
+    ) == "pallas-ssm"
+    engine, cfg, _, cache, _ = _jamba_shapes(sds)
+    assert cache["state"].shape == (26, slots, 16, 5120)
+    assert cache["conv"].shape == (26, slots, 128, 128)
+    assert per_slot(cache, slots) == 9_371_648 < 1.006 * 9_318_400
+    assert jax.tree.leaves(cache["k"])[0].shape == (2, 16384, PAGE, 1, 128)
+    # 2 attention layers x (k, v) x one kv head of 128 bfloat16
+    assert 2 * 2 * 128 * 2 == 1024
+    i32 = lambda *d: sds(d, jnp.int32)       # noqa: E731
+    compiled = jax.jit(
+        llama.copy_state_slots, donate_argnames=("cache",)
+    ).lower(cache, i32(8), i32(8)).compile()
+    m = compiled.memory_analysis()
+    arrays = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                 for a in jax.tree.leaves(cache))
+    assert arrays <= m.argument_size_in_bytes < 1.001 * arrays + 4096
+
+
+def test_jambas_mixed_step_whole_fits_the_chip(v5e):
+    """The cell's one mixed program WHOLE (28 layers, int8 weights, the
+    full vocabulary, 64 rows x 16 slots packed to 256 tokens, 16,384 pages,
+    256 state slots): the streaming kernel reads the two attention layers'
+    pages at one kv head, no operation copies the whole state array, and
+    arguments, results and scratch together fit the chip's memory."""
+    from opsagent_tpu.serving import decode_loop
+
+    sds = _one_chip(v5e)
+    engine, cfg, params, cache, key = _jamba_shapes(sds)
+    b, s = engine["max_batch_size"], engine["mixed_buckets"][-1]
+    assert (b, engine["mixed_buckets"], engine["max_step_tokens"]) == (
+        64, [s], 256)
+    assert llama.pack_widths(b * s, 256) == (256, 128)
+    i32 = lambda *d: sds(d, jnp.int32)       # noqa: E731
+    f32 = lambda *d: sds(d, jnp.float32)     # noqa: E731
+    flag = lambda *d: sds(d, jnp.bool_)      # noqa: E731
+
+    def step(params, tokens, use_carry, carry, starts, qlens, emits, cache,
+             table, key, temps, top_k, top_p):
+        return decode_loop.mixed_step_carry(
+            params, cfg, tokens, use_carry, carry, starts, qlens, emits,
+            cache, table, key, temps, top_k, top_p,
+            attn_impl="pallas-stream", step_tokens=256)
+
+    compiled = jax.jit(step, donate_argnames=("cache",)).lower(
+        params, i32(b, s), flag(b), i32(b), i32(b), i32(b), flag(b), cache,
+        i32(b, engine["max_pages_per_seq"] + llama.STATE_COLUMNS), key,
+        f32(b), i32(b), f32(b),
+    ).compile()
+    hlo = compiled.as_text()
+    # the streaming attention kernel and the scan kernel, once a run's body
+    assert hlo.count("tpu_custom_call") >= 3 and "mini-gather" not in hlo
+    assert _copies_of(hlo, int(np.prod(cache["state"].shape))) == []
+    # no operation shaped like the whole ``state`` or ``conv`` array: the
+    # kernel takes a row's slot in and out itself, both arrays in place
+    # (all 64 rows' state at once is the shape of this bucket's x and dt,
+    # 64 x 16 x 5120, so that is asked of the decode block)
+    assert _state_sized(hlo, cache, b)[0] == []
+    m = compiled.memory_analysis()
+    held = _held(compiled)
+    assert held < CHIP_HBM_BYTES, f"{held / 2**30:.2f} GiB"
+    print(f"jamba mixed step: arguments {m.argument_size_in_bytes / 2**30:.2f}"
+          f" GiB, scratch {m.temp_size_in_bytes / 2**30:.2f} GiB, held "
+          f"{held / 2**30:.2f} GiB")
+
+
+def test_jambas_decode_block_whole_fits_the_chip(v5e):
+    """Eight greedy passes of all 64 rows under one scan, pages and slots
+    its carry and donated: no whole-state copy, and it fits."""
+    from opsagent_tpu.serving import decode_loop
+
+    sds = _one_chip(v5e)
+    engine, cfg, params, cache, key = _jamba_shapes(sds)
+    b = engine["max_batch_size"]
+    i32 = lambda *d: sds(d, jnp.int32)       # noqa: E731
+    f32 = lambda *d: sds(d, jnp.float32)     # noqa: E731
+
+    def block(params, tokens, write_at, active, budgets, cache, table, key,
+              temps, top_k, top_p, eos, pad):
+        return decode_loop.decode_block(
+            params, cfg, tokens, write_at, active, budgets, cache, table,
+            key, temps, top_k, top_p, eos, pad,
+            n_steps=engine["decode_block"], greedy=True,
+            attn_impl="pallas-stream")
+
+    compiled = jax.jit(block, donate_argnames=("cache",)).lower(
+        params, i32(b), i32(b), sds((b,), jnp.bool_), i32(b), cache,
+        i32(b, engine["max_pages_per_seq"] + llama.STATE_COLUMNS), key,
+        f32(b), i32(b), f32(b), i32(), i32(),
+    ).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 3
+    assert _copies_of(hlo, int(np.prod(cache["state"].shape))) == []
+    passes, per_row = _state_sized(hlo, cache, b)
+    assert passes == [] and not per_row
+    held = _held(compiled)
+    assert held < CHIP_HBM_BYTES, f"{held / 2**30:.2f} GiB"
+    print(f"jamba decode block: scratch "
+          f"{compiled.memory_analysis().temp_size_in_bytes / 2**30:.2f} GiB, "
+          f"held {held / 2**30:.2f} GiB")
+
+
+@pytest.mark.parametrize("b,s", [(64, 16), (64, 1), (1, 256), (8, 256)],
+                         ids=["mixed", "decode", "prefill-1", "prefill-8"])
+def test_scan_kernel_compiles_at_the_cells_shapes(v5e, b, s):
+    """The scan kernel alone at the 3B's ``[16, 5120]`` state over the
+    cell's 26 x 256 slots: the mixed bucket, a decode pass, and the prefill
+    bucket of 256 (a row's channels in four blocks)."""
+    from opsagent_tpu.ops import selective_scan_pallas as ssp
+
+    sds = _one_chip(v5e)
+    c, n, slots, w = 5120, 16, 26 * 256, 15360
+    f32 = lambda *d: sds(d, jnp.float32)     # noqa: E731
+    i32 = lambda *d: sds(d, jnp.int32)       # noqa: E731
+    compiled = jax.jit(ssp.selective_scan_slots, donate_argnums=(5, 6)).lower(
+        f32(b, s, c), f32(b, s, c), f32(n, c), f32(b, s, n), f32(b, s, n),
+        f32(slots, n, c), sds((slots, *lsp.conv_slot_shape(w)), jnp.bfloat16),
+        sds((b, w), jnp.bfloat16), i32(b), i32(b), sds((b,), jnp.bool_),
+        i32(b)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
