@@ -1624,6 +1624,17 @@ def _shared_experts(h, lp) -> jax.Array:
     return _mm(jax.nn.silu(_mm(h, lp["sg"])) * _mm(h, lp["su"]), lp["sd"])
 
 
+def _block_experts(block_ends: jax.Array, blocks: int) -> jax.Array:
+    """The expert of each of the ``blocks`` blocks of ``_moe_share``'s
+    buffer, where expert e's blocks end at ``block_ends[e]`` (cumulative, so
+    an expert without work ends where it starts): the count of ends not
+    above the block's index, for all blocks at once. Blocks past the last
+    end read ``len(block_ends)``, no expert."""
+    return jnp.sum(
+        block_ends[None, :] <= jnp.arange(blocks)[:, None],
+        axis=1, dtype=jnp.int32)
+
+
 def _moe_share(h, lp, cfg: ModelConfig, token_valid):
     """The expert layer of a model whose ``MoEConfig`` names a router width
     (``router_experts``): the router scores and ranks ALL its experts, this
@@ -1637,8 +1648,11 @@ def _moe_share(h, lp, cfg: ModelConfig, token_valid):
     buffer in which each expert's rows are padded to whole blocks of
     ``bm`` rows, and a loop over the blocks IN USE runs one expert's three
     matmuls a block (a ``while`` of data-dependent length: no capacity, no
-    all-experts pass). ``token_valid`` [B, S] (or None) keeps the padding
-    positions of ragged rows out of the experts and the counts.
+    all-experts pass). A block's expert is read from ``block_expert``, the
+    map of every block of the buffer to its expert that the plan makes once
+    a layer; the loop searches for nothing. ``token_valid`` [B, S] (or
+    None) keeps the padding positions of ragged rows out of the experts and
+    the counts.
 
     Returns (output [B, S, d], the MOE_STATS increments [5])."""
     m = cfg.moe
@@ -1673,7 +1687,7 @@ def _moe_share(h, lp, cfg: ModelConfig, token_valid):
         xs = jnp.zeros((rows, d), h.dtype).at[dest_sorted].set(
             x[order // k], mode="drop")
         blocks_used = ends[-1] // bm
-        block_ends = ends // bm
+        block_expert = _block_experts(ends // bm, rows // bm)
         stats = jnp.stack([
             jnp.int32(1),
             jnp.sum(here & real, dtype=jnp.int32),
@@ -1683,7 +1697,7 @@ def _moe_share(h, lp, cfg: ModelConfig, token_valid):
         ]).astype(jnp.uint32)
     with jax.named_scope("moe_experts"):
         def one_block(b, ys):
-            e = jnp.searchsorted(block_ends, b, side="right")
+            e = block_expert[b]
             w = [_one_expert(lp[name], e) for name in _EXPERT_STACKS]
             xb = jax.lax.dynamic_slice_in_dim(xs, b * bm, bm)
             y = _mm(jax.nn.silu(_mm(xb, w[0])) * _mm(xb, w[1]), w[2])

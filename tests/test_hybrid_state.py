@@ -345,6 +345,106 @@ def test_the_expert_share_drops_no_assignment_at_any_token_count(params):
         assert float(stats[1]) == 2 * T and float(stats[4]) == T
 
 
+_RNG = np.random.default_rng(43)
+BLOCK_SIZES = {
+    # per-expert assignment counts that a block-to-expert map can get wrong
+    "idle-at-the-start": (8, [0, 0, 5, 9]),
+    "idle-in-the-middle": (8, [3, 0, 0, 17]),
+    "idle-at-the-end": (8, [7, 1, 0, 0]),
+    "idle-between-every-two": (8, [0, 9, 0, 1, 0, 0, 24, 0]),
+    "one-holds-all": (8, [0, 40, 0, 0]),
+    "the-last-holds-all": (16, [0] * 63 + [1024]),
+    "nothing-lands-here": (8, [0, 0, 0, 0]),
+    "whole-blocks": (8, [8, 16, 8, 24]),
+    "whole-blocks-and-gaps": (16, [0, 32, 0, 16, 16, 0]),
+    "one-each": (16, [1] * 64),
+    # a tick's counts at the cells' means, some experts without work
+    "cell-5-like": (16, _RNG.poisson(10.9, 64) * (_RNG.random(64) > 0.3)),
+    "cell-3-like": (8, _RNG.poisson(4.7, 40) * (_RNG.random(40) > 0.2)),
+}
+
+
+@pytest.mark.parametrize("case", list(BLOCK_SIZES))
+def test_the_block_map_is_the_search_it_replaced_block_for_block(case):
+    """``_block_experts`` against the per-block binary search that
+    ``one_block`` ran before (``searchsorted(block_ends, b, "right")``), on
+    the plan's own arithmetic: the same expert for every block in use, and
+    ``E`` (no expert) for every block past them, as the search gave too."""
+    bm, sizes = BLOCK_SIZES[case]
+    sizes = np.asarray(sizes)
+    E = len(sizes)
+    padded = -(-sizes // bm) * bm
+    block_ends = np.cumsum(padded) // bm
+    blocks_used = int(block_ends[-1])
+    blocks = blocks_used + E + 3        # the buffer holds every case
+    got = np.asarray(llama._block_experts(
+        jnp.asarray(block_ends, jnp.int32), blocks))
+    assert got.dtype == np.int32 and got.shape == (blocks,)
+    np.testing.assert_array_equal(got, jnp.searchsorted(
+        jnp.asarray(block_ends), jnp.arange(blocks), side="right"))
+    assert (got[blocks_used:] == E).all()
+    # and by the definition: a block in use lies inside its expert's rows
+    for b in range(blocks_used):
+        e = got[b]
+        assert block_ends[e] - padded[e] // bm <= b < block_ends[e]
+        assert sizes[e] > 0
+
+
+def routed_to(w, pairs):
+    """A layer's weights ``w`` with a router, and an input, whose tokens go
+    where ``pairs`` says: ``[((expert, expert), tokens), ...]`` by the
+    router's own numbering over its width of 8. Token classes are one-hot
+    on the first hidden dimensions and the router reads those alone, so
+    the choice is exact."""
+    T = sum(n for _, n in pairs)
+    router = np.zeros((CFG.hidden_size, 8), np.float32)
+    h = np.array(jax.random.normal(jax.random.PRNGKey(3), (T, CFG.hidden_size)))
+    h[:, :len(pairs)] = 0.0
+    at = 0
+    for c, ((e0, e1), n) in enumerate(pairs):
+        router[c, e0], router[c, e1] = 1.0, 0.75
+        h[at:at + n, c] = 4.0
+        at += n
+    w = dict(w, router=jnp.asarray(router),
+             router_bias=jnp.zeros((8,), jnp.float32))
+    return w, jnp.asarray(h)[None]
+
+
+@pytest.mark.parametrize("first_expert,pairs,landed,busy", [
+    (0, [((2, 3), 13)], 26, 2),                     # none at the start
+    (0, [((0, 3), 21)], 42, 2),                     # none in the middle
+    (0, [((0, 1), 9), ((1, 0), 2)], 22, 2),         # none at the end
+    (0, [((2, 6), 33)], 33, 1),                     # one holds everything
+    (0, [((4, 5), 19), ((7, 6), 6)], 0, 0),         # nothing lands here
+    (0, [((0, 1), 8), ((2, 3), 16)], 48, 4),        # whole blocks of 8
+    (4, [((5, 7), 5), ((4, 6), 12), ((1, 5), 7)], 41, 4),
+    (4, [((0, 7), 16), ((3, 2), 8)], 16, 1),        # only the last, whole
+], ids=["idle-start", "idle-middle", "idle-end", "one-holds-all",
+        "none-lands", "whole-blocks", "first-expert-4", "last-alone"])
+def test_the_share_is_the_references_wherever_the_work_lands(
+        params, first_expert, pairs, landed, busy):
+    """``_moe_share`` against the reference's loop over experts with the
+    assignments steered so that held experts go without work at the start,
+    in the middle and at the end of the buffer, one expert holds every
+    assignment, sizes are whole blocks, the share starts at
+    ``first_expert`` > 0, or nothing lands here at all: then the loop runs
+    no block and the output is the shared expert's alone."""
+    cfg = dataclasses.replace(CFG, moe=dataclasses.replace(
+        CFG.moe, first_expert=first_expert))
+    w, h = routed_to(layers_of(params)[0][1], pairs)
+    got, stats = llama._moe_share(h, w, cfg, None)
+    want = ref.experts(h[0], w, top_k=2, scale=1.0, held=(first_expert, 4))
+    assert float(jnp.max(jnp.abs(got[0] - want))) < 1e-5
+    T = h.shape[1]
+    assert (int(stats[1]), int(stats[2]), int(stats[3])) == (
+        landed, 2 * T - landed, busy)
+    if landed == 0:
+        np.testing.assert_array_equal(got, llama._shared_experts(h, w))
+    else:
+        assert float(jnp.max(jnp.abs(
+            got - llama._shared_experts(h, w)))) > 1e-3
+
+
 # -- the allocator: slots, snapshots, the trie ----------------------------------
 def alloc(snapshots=2, pages=32):
     return PageAllocator(pages, PAGE, MAXP, state_slots=3,

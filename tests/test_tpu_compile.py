@@ -1271,6 +1271,32 @@ def test_glm_flash_decode_block_copies_no_latent_cache(v5e, impl):
           f"held {held / 2**30:.2f} GiB")
 
 
+@pytest.mark.parametrize("cell", ["glm47-flash-l12.longdoc-turns",
+                                  "solar-open2-ep8-l8.doc-turns"])
+def test_the_expert_loop_holds_no_loop_and_no_search(v5e, cell):
+    """The mixed program of each cell with an expert share (GLM's at three
+    layers, two of them with experts; Solar's at one period): a block of
+    ``_moe_share``'s loop reads its expert from the map the plan made, so
+    nothing under the loop's body comes from a ``searchsorted`` and no
+    ``while`` lies inside it (the search was one, of log2(E) + 1 steps of a
+    scalar read each, in every block: a ninth of cell 5's device time,
+    ledger, PR 42). The expert loop itself is there to be found."""
+    sds = _one_chip(v5e)
+    if cell.startswith("glm"):
+        *_, compiled = _mixed_step(
+            sds, "glm-4.7-flash", "", "pallas-stream", rows=16, tokens=16,
+            step_tokens=256, layers=3, int8=True)
+    else:
+        _, compiled = _state_cell_mixed_step(sds, cell, "pallas-state")
+    hlo = compiled.as_text()
+    assert re.search(r'\bwhile\(.*op_name="[^"]*moe_experts/while"', hlo)
+    lines = [line for line in hlo.splitlines()
+             if "moe_experts/while/body" in line]
+    assert lines, "nothing of the expert loop's body is named"
+    assert [line for line in lines if "searchsorted" in line] == []
+    assert [line for line in lines if re.search(r"\bwhile\(", line)] == []
+
+
 # -- AI21-Jamba2-3B: Mamba layers over the state slots (PR 42) ------------------
 def _jamba_cell():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
