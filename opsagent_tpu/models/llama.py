@@ -889,8 +889,11 @@ def _mm(x: jax.Array, w: Any) -> jax.Array:
     in their narrow storage type. Under an active
     ``weight_stream_scope("pallas-dma")``, 2D quantized leaves (the
     per-layer scan slices plus lm_head) route through the Pallas
-    double-buffered weight-streaming kernel instead; stacked/MoE leaves
-    and plain arrays keep the XLA path."""
+    double-buffered weight-streaming kernel instead; stacked/MoE 3D leaves
+    (``_ein``'s) and plain arrays keep the XLA path. An expert share's
+    stacks reach neither on a TPU: ``_moe_share`` hands them whole to the
+    grouped kernel (``ops.moe_experts_pallas``), and only its XLA loop
+    comes here, with one expert's 2D slices."""
     from .quant import QuantizedBase
 
     if isinstance(w, QuantizedBase):
@@ -1635,6 +1638,18 @@ def _block_experts(block_ends: jax.Array, blocks: int) -> jax.Array:
         axis=1, dtype=jnp.int32)
 
 
+def _share_buffer(m, tokens: int, least: int = 8) -> tuple[int, int]:
+    """(rows of a block, rows of the buffer) of ``_moe_share`` at a token
+    count: a block is the assignments an expert expects there, up to a
+    power of two, between ``least`` (8; under the grouped kernel a
+    bfloat16 tile's 16) and 128; the buffer holds every case, in whole
+    blocks."""
+    E, k = m.num_experts, m.num_experts_per_token
+    expected = max(1, tokens * k // m.router_width)
+    bm = min(128, max(least, 1 << (expected - 1).bit_length()))
+    return bm, -(-(tokens * min(k, E) + E * bm) // bm) * bm
+
+
 def _moe_share(h, lp, cfg: ModelConfig, token_valid):
     """The expert layer of a model whose ``MoEConfig`` names a router width
     (``router_experts``): the router scores and ranks ALL its experts, this
@@ -1646,19 +1661,35 @@ def _moe_share(h, lp, cfg: ModelConfig, token_valid):
     Dropless at any token count, with work in proportion to the
     assignments that land here: assignments are sorted by expert into one
     buffer in which each expert's rows are padded to whole blocks of
-    ``bm`` rows, and a loop over the blocks IN USE runs one expert's three
-    matmuls a block (a ``while`` of data-dependent length: no capacity, no
-    all-experts pass). A block's expert is read from ``block_expert``, the
-    map of every block of the buffer to its expert that the plan makes once
-    a layer; the loop searches for nothing. ``token_valid`` [B, S] (or
-    None) keeps the padding positions of ragged rows out of the experts and
-    the counts.
+    ``bm`` rows, and the blocks IN USE run one expert's three matmuls a
+    block (a data-dependent count of them: no capacity, no all-experts
+    pass). A block's expert is read from ``block_expert``, the map of
+    every block of the buffer to its expert that the plan makes once a
+    layer; nothing is searched for. ``token_valid`` [B, S] (or None) keeps
+    the padding positions of ragged rows out of the experts and the counts.
+
+    Who runs the blocks is the code's choice, made once where an engine is
+    built (``ops.attention.moe_experts_backend``) and read back here at
+    trace time (``moe_experts_scope``): on a TPU with int8 stacks held
+    whole, ONE Pallas call a layer over the whole buffer
+    (``ops.moe_experts_pallas``: the plan's ``block_expert`` and
+    ``blocks_used`` are its scalar prefetch, the whole stack and the
+    layer's index its operands, the next block's int8 tiles in flight while
+    this block multiplies; its blocks are a bfloat16 tile's 16 rows at
+    least); everywhere else, and as the oracle of the kernel's tests, a
+    ``while`` over the blocks of XLA matmuls on one expert's slices. The
+    plan, the scatter into ``xs``, the gather back and the sum over ``k``
+    are the same code for both.
 
     Returns (output [B, S, d], the MOE_STATS increments [5])."""
+    from ..ops import moe_experts_pallas as grouped
+    from ..ops.attention import moe_experts_impl, pallas_interpret
+
     m = cfg.moe
     E, k = m.num_experts, m.num_experts_per_token
     B, S, d = h.shape
     T = B * S
+    kernel = moe_experts_impl() == grouped.IMPL
     with jax.named_scope("moe_router"):
         _, idx, vals = _route(h, lp, cfg)
         local = idx.reshape(T * k) - m.first_expert
@@ -1666,12 +1697,8 @@ def _moe_share(h, lp, cfg: ModelConfig, token_valid):
         real = jnp.ones((T * k,), bool) if token_valid is None else (
             jnp.repeat(token_valid.reshape(T), k))
         group = jnp.where(here & real, local, E)        # E: not computed here
-        # rows of a block: the assignments an expert expects at this
-        # token count, up to a power of two, between 8 and 128
-        expected = max(1, T * k // m.router_width)
-        bm = min(128, max(8, 1 << (expected - 1).bit_length()))
-        rows = T * min(k, E) + E * bm                   # every case fits
-        rows = -(-rows // bm) * bm
+        bm, rows = _share_buffer(
+            m, T, grouped.MIN_BLOCK_ROWS if kernel else 8)
         order = jnp.argsort(group, stable=True)
         sorted_group = group[order]
         sizes = jnp.zeros((E + 1,), jnp.int32).at[group].add(1)
@@ -1703,7 +1730,17 @@ def _moe_share(h, lp, cfg: ModelConfig, token_valid):
             y = _mm(jax.nn.silu(_mm(xb, w[0])) * _mm(xb, w[1]), w[2])
             return jax.lax.dynamic_update_slice_in_dim(ys, y, b * bm, 0)
 
-        ys = jax.lax.fori_loop(0, blocks_used, one_block, jnp.zeros_like(xs))
+        if kernel:
+            stacks = [lp[name] for name in _EXPERT_STACKS]
+            whole = isinstance(stacks[0], _Indexed)
+            ys = grouped.moe_expert_blocks(
+                xs, block_expert, blocks_used,
+                [w.tree if whole else w for w in stacks],
+                stacks[0].idx if whole else (),
+                bm=bm, interpret=pallas_interpret())
+        else:
+            ys = jax.lax.fori_loop(
+                0, blocks_used, one_block, jnp.zeros_like(xs))
         dest = jnp.zeros((T * k,), jnp.int32).at[order].set(dest_sorted)
         per = ys.at[dest].get(mode="fill", fill_value=0)
         out = jnp.sum(

@@ -38,7 +38,9 @@ keys and values alike.
 
 from __future__ import annotations
 
+import contextlib
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -253,6 +255,62 @@ def ssm_state_backend(
             or d_inner % 128):
         return "xla"
     return "pallas-ssm"
+
+
+MOE_BACKENDS = ("xla", "pallas-grouped")
+
+
+def moe_experts_backend(
+    *,
+    platform: str,
+    quantize: str,
+    hidden_size: int,
+    expert_width: int,
+    tp: int = 1,
+    ep: int = 1,
+) -> str:
+    """Who runs the blocks of an expert share (``llama._moe_share``) in an
+    engine's step programs: "xla" (a ``while`` over the blocks in use, an
+    expert's three matmuls a block as fusion calls; the oracle of every
+    test) or "pallas-grouped" (one kernel a layer over the whole sorted
+    buffer, the next block's int8 tiles in flight while this block
+    multiplies, ``moe_experts_pallas``).
+
+    The code's own choice, as ``paged_attention_backend`` is, from what it
+    can observe where the engine is built: on a TPU the kernel wherever
+    the expert stacks are int8 leaves (``quantize`` "int8": per-channel
+    scales) held whole on the one shard, with the model width and the
+    experts' intermediate width on whole 128-lane tiles; everywhere else
+    (the CPU, bfloat16 or int4 stacks, ``tp`` or ``ep`` above 1, widths
+    off the lanes) the loop. The engine resolves it once and every step
+    program reads it back at trace time (``moe_experts_scope``)."""
+    if (platform != "tpu" or quantize != "int8" or tp > 1 or ep > 1
+            or hidden_size % 128 or expert_width % 128):
+        return "xla"
+    return "pallas-grouped"
+
+
+_MOE_TLS = threading.local()
+
+
+@contextlib.contextmanager
+def moe_experts_scope(impl: str):
+    """Activate an expert-share backend for the programs traced inside:
+    thread-local like the trace itself, read by ``llama._moe_share`` the
+    way ``llama._mm`` reads ``weight_stream_scope``."""
+    if impl not in MOE_BACKENDS:
+        raise ValueError(
+            f"expert-share backend {impl!r}: expected one of {MOE_BACKENDS}")
+    prev = moe_experts_impl()
+    _MOE_TLS.impl = impl
+    try:
+        yield
+    finally:
+        _MOE_TLS.impl = prev
+
+
+def moe_experts_impl() -> str:
+    return getattr(_MOE_TLS, "impl", "xla")
 
 
 def pallas_interpret() -> bool:

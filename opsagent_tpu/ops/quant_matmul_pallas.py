@@ -193,7 +193,9 @@ def supports(w) -> bool:
     a 2D QuantizedLinear, or a 2D QuantizedLinear4 whose scale group is
     even (the packed layout pairs rows, so an odd group would split a
     byte across two scale groups). Stacked/MoE 3D leaves and anything
-    else stay on the XLA dequant path."""
+    else stay on the XLA dequant path of ``llama._mm`` / ``_ein`` (an
+    expert share's whole int8 stacks have a kernel of their own on a TPU,
+    ``ops.moe_experts_pallas``, and do not come through here)."""
     from ..models.quant import QuantizedLinear, QuantizedLinear4
 
     if isinstance(w, QuantizedLinear4):

@@ -426,8 +426,9 @@ class Engine:
         # never a quiet xla run under the kernel's name. impl_info()
         # reports what runs.
         from ..ops.attention import (
-            linear_state_backend, pallas_interpret, pallas_refusal,
-            paged_attention_backend, ssm_state_backend,
+            linear_state_backend, moe_experts_backend, moe_experts_scope,
+            pallas_interpret, pallas_refusal, paged_attention_backend,
+            ssm_state_backend,
         )
 
         ws = cfg.weight_stream or os.environ.get(
@@ -524,6 +525,18 @@ class Engine:
             for what, hit in refused.items():
                 if hit:
                     raise BackendRefused(f"{what} is not supported for {why}")
+        # Who runs an expert share's blocks: the code's choice as well;
+        # every step program reads it back at trace time (mesh_ctx).
+        self._moe_scope = moe_experts_scope
+        self.moe_impl = "xla"
+        if llama._expert_share(self.model_cfg):
+            self.moe_impl = moe_experts_backend(
+                platform=platform, quantize=cfg.quantize,
+                hidden_size=self.model_cfg.hidden_size,
+                expert_width=self.model_cfg.moe.expert_intermediate_size,
+                tp=tp, ep=cfg.ep,
+            )
+            log.info("expert share: %s", self.moe_impl)
         if cfg.kv_quantize and cfg.kv_quantize != "int8":
             raise ValueError(
                 f"kv_quantize={cfg.kv_quantize!r}: only 'int8' is supported"
@@ -1042,7 +1055,7 @@ class Engine:
             return
         self._mesh_tls.active = True
         try:
-            with self.mesh:
+            with self.mesh, self._moe_scope(self.moe_impl):
                 yield
         finally:
             self._mesh_tls.active = False
@@ -1084,6 +1097,8 @@ class Engine:
         }
         if self.weight_stream_leaves:
             info["weight_stream_leaves"] = dict(self.weight_stream_leaves)
+        if llama._expert_share(self.model_cfg):
+            info["moe_impl"] = self.moe_impl
         if self.model_cfg.has_state:
             state = self.cache["state"]
             info["state_impl"] = self.state_impl
