@@ -25,10 +25,8 @@ both phases plus an identical-output check), the sessions-offload A/B
 the fleet-affinity A/B (two engine replicas behind the fleet router:
 prefix-affinity + sticky placement vs stateless least-loaded, reporting
 re-prefill-avoided tokens and p50 TTFT per phase),
-the agent-turns stage (north-star p50 TTFT per tool-call turn), a
-cold-restart TTFT probe against the stage-1-primed compilation cache, and last a
-speculative-decoding overhead run (its question is already
-measurement-closed).
+the agent-turns stage (north-star p50 TTFT per tool-call turn), and a
+cold-restart TTFT probe against the stage-1-primed compilation cache.
 EVERY result line is printed
 and flushed the moment it exists (the driver kills this process at an
 unknown wall clock; an already-earned number must survive), and a
@@ -383,13 +381,11 @@ def run_orchestrated() -> None:
     it the whole run), then the bench-8b int8 headline and its int4,
     int8-KV, and combined int4+int8-KV variants, the BASELINE config-5
     concurrent-sessions run, the sessions-mixed A/B, the agent-turns
-    stage, the ragged sweep, the cold-restart TTFT probe, and the
-    speculative-decoding overhead run
-    last; the later stages only start if the
-    remaining budget plausibly covers them. Mode/spec env vars are
-    stripped from stages
-    they don't belong to, so an operator-set OPSAGENT_BENCH_SPEC cannot
-    contaminate the baseline stages."""
+    stage, the ragged sweep and the cold-restart TTFT probe last; the
+    later stages only start if the remaining budget plausibly covers them.
+    Mode env vars are stripped from stages they don't belong to, so an
+    operator-set OPSAGENT_BENCH_MODE cannot contaminate the baseline
+    stages."""
     budget = float(os.environ.get("OPSAGENT_BENCH_BUDGET", "850"))
     t_start = time.perf_counter()
 
@@ -397,10 +393,9 @@ def run_orchestrated() -> None:
         return budget - (time.perf_counter() - t_start)
 
     # None-valued entries REMOVE inherited vars (see _run_child): an
-    # operator-exported spec/mode var must not contaminate the stages it
+    # operator-exported mode var must not contaminate the stages it
     # doesn't belong to.
     base = {
-        "OPSAGENT_BENCH_SPEC": None,
         "OPSAGENT_BENCH_MODE": None,
         "OPSAGENT_BENCH_QUANT": None,
         "OPSAGENT_BENCH_KV": None,
@@ -632,16 +627,6 @@ def run_orchestrated() -> None:
          "OPSAGENT_BENCH_STEPS": "64"},
         150, "cold-start",
     )
-    # Speculative overhead LAST: the question is already answered by
-    # measurement (k=4 was -76 % on chip; accept rate 6.6 % on the
-    # trained agent; default 0) — under a tight driver budget the
-    # decision-relevant stages above must land first.
-    SPEC_K = 4
-    rspec = stage(
-        {"OPSAGENT_BENCH_MODEL": "bench-1b",
-         "OPSAGENT_BENCH_SPEC": str(SPEC_K)},
-        120, "spec",
-    )
 
     # Combined headline, printed last: the driver records one parsed line.
     extra = dict(headline.get("extra", {}))
@@ -776,8 +761,6 @@ def run_orchestrated() -> None:
         extra["agent_conveyor_outputs_identical"] = ve.get(
             "outputs_identical"
         )
-    if rspec is not None:
-        extra[f"spec{SPEC_K}_overhead_tok_s_chip"] = rspec["value"]
     if rsweep is not None:
         se = rsweep.get("extra", {})
         if headline is not rsweep:
@@ -817,7 +800,7 @@ def run_orchestrated() -> None:
     exit_if_perf_regression([
         r1, r8b, r8b4, r8bkv, r8b4kv, rsess, rsessmix, rsessasync,
         rsessoff, rfleet, rchaos, rfgkv, ragent, rconvey,
-        rcold, rcoldstart, rspec, robsh, *sweep_rows,
+        rcold, rcoldstart, robsh, *sweep_rows,
     ])
 
 
@@ -867,7 +850,6 @@ def run_single() -> None:
     # Large pages (fewer gather/grid steps per decode) and a page budget of
     # 128 prompt + 512 generated + slack for the decode pipeline's lookahead
     # (decode_block x (pipeline_depth + 1) tokens are pre-booked).
-    spec_k = int(os.environ.get("OPSAGENT_BENCH_SPEC", "0"))
     if mode == "agent-conveyor":
         # Trains its own tiny checkpoint and builds its own engine (BPE
         # tokenizer, trained weights) — intercept before the shared
@@ -881,13 +863,6 @@ def run_single() -> None:
         run_ragged_sweep(platform, n_chips, model, batch, steps,
                          prompt_len)
         return
-    if mode in ("sessions", "agent", "sessions-mixed", "sessions-offload",
-                "sessions-async", "sessions-ffwd", "fleet-affinity",
-                "fleet-chaos", "fleet-global-kv", "fleet-journey",
-                "audit-fanout", "obs-history", "cold-start"):
-        # Full-stack modes measure concurrency/TTFT; keep speculation out
-        # of them (their warmup level does not compile the spec program).
-        spec_k = 0
     # Mixed prefill+decode batching (EngineConfig.mixed_batching):
     # OPSAGENT_BENCH_MIXED=0 pins the split prefill/decode tick; the
     # sessions-mixed stage measures both in one child.
@@ -954,7 +929,6 @@ def run_single() -> None:
         prefill_buckets=(prompt_len,),
         quantize=quantize,
         kv_quantize=kv_quantize,
-        speculative_k=spec_k,
         decode_block=decode_block,
         mixed_batching=mixed_on,
         async_depth=async_depth,
@@ -1003,8 +977,6 @@ def run_single() -> None:
                 "fleet-chaos", "fleet-global-kv", "fleet-journey",
                 "audit-fanout", "obs-history"):
         level = "sessions"
-    elif spec_k > 0:
-        level = "bench-spec"
     else:
         level = "bench"
     warmup_s = eng.warmup(level)
@@ -1120,8 +1092,6 @@ def run_single() -> None:
     qtag = f",{quantize}" if quantize else ""
     if kv_quantize:
         qtag += f",kv-{kv_quantize}"
-    if spec_k:
-        qtag += f",spec{spec_k}"
     emit({
         "metric": f"paged_decode_throughput[{model}{qtag},B={batch},{platform}]",
         "value": round(tok_s_chip, 1),
@@ -1136,7 +1106,7 @@ def run_single() -> None:
             "chips": n_chips,
             "platform": platform,
             **eng.impl_info(),
-            "paged_backend": eng.attn_impl,
+            "paged_backend": eng.kernels.attn,
             "decode_block": eng.cfg.decode_block,
             "page_size": eng.cfg.page_size,
             "metrics": metrics_snapshot(),
@@ -1250,7 +1220,7 @@ def run_ragged_sweep(platform, n_chips, model, batch, steps,
     KV page dtype x weight quant x weight-stream cells on one model
     shape, one self-describing tok/s/chip row per cell. Every cell runs
     the attention reader its engine chooses
-    (``ops.attention.paged_attention_backend``; the row names it). The
+    (``ops.kernels.paged_attention_backend``; the row names it). The
     weight-stream axis needs quantized weights, so it adds one
     pallas-dma prefetch cell per quantized weight mode, each beside the
     xla weight-stream cell that anchors its byte-identity check.
@@ -1285,7 +1255,7 @@ def run_ragged_sweep(platform, n_chips, model, batch, steps,
     t_start = time.perf_counter()
     if not on_tpu:
         # No Mosaic off-chip: run the weight-stream cell in interpret
-        # mode so the full chain (engine gate -> weight_stream_scope ->
+        # mode so the full chain (engine gate -> Kernels.weights ->
         # quant-matmul kernel) still executes end to end on CPU.
         os.environ["OPSAGENT_PALLAS_INTERPRET"] = "1"
     kv_modes = ("", "int8")
@@ -1539,7 +1509,7 @@ def run_sessions(eng, model, batch, steps, prompt_len, platform, n_chips,
             "chips": n_chips,
             "platform": platform,
             **eng.impl_info(),
-            "paged_backend": eng.attn_impl,
+            "paged_backend": eng.kernels.attn,
             "metrics": metrics_snapshot(),
             "attribution": attribution_snapshot(),
             "slo": slo_verdicts(),
@@ -1696,7 +1666,7 @@ def run_sessions_mixed(eng, model, batch, steps, prompt_len, platform,
             "chips": n_chips,
             "platform": platform,
             **eng.impl_info(),
-            "paged_backend": eng.attn_impl,
+            "paged_backend": eng.kernels.attn,
             "metrics": metrics_snapshot(),
             "attribution": attribution_snapshot(),
             "slo": slo_verdicts(),
@@ -1794,7 +1764,7 @@ def run_sessions_async(eng, model, batch, steps, prompt_len, platform,
             "chips": n_chips,
             "platform": platform,
             **eng.impl_info(),
-            "paged_backend": eng.attn_impl,
+            "paged_backend": eng.kernels.attn,
             "metrics": metrics_snapshot(),
             "attribution": attribution_snapshot(),
             "slo": slo_verdicts(),
@@ -1891,7 +1861,7 @@ def run_sessions_ffwd(eng, model, batch, steps, prompt_len, platform,
             "chips": n_chips,
             "platform": platform,
             **eng.impl_info(),
-            "paged_backend": eng.attn_impl,
+            "paged_backend": eng.kernels.attn,
             "metrics": metrics_snapshot(),
             "attribution": attribution_snapshot(),
             "slo": slo_verdicts(),
@@ -1992,7 +1962,7 @@ def run_sessions_offload(eng, model, batch, steps, prompt_len, platform,
             "chips": n_chips,
             "platform": platform,
             **eng.impl_info(),
-            "paged_backend": eng.attn_impl,
+            "paged_backend": eng.kernels.attn,
             "metrics": metrics_snapshot(),
             "attribution": attribution_snapshot(),
             "slo": slo_verdicts(),
@@ -2179,7 +2149,7 @@ def run_fleet_affinity(eng, cfg, model, batch, steps, prompt_len, platform,
             "chips": n_chips,
             "platform": platform,
             **eng.impl_info(),
-            "paged_backend": eng.attn_impl,
+            "paged_backend": eng.kernels.attn,
             "metrics": snap,
             "attribution": attribution_snapshot(),
             "slo": slo_verdicts(),
@@ -3110,7 +3080,7 @@ def run_obs_history(eng, model, batch, steps, prompt_len, platform,
             "chips": n_chips,
             "platform": platform,
             **eng.impl_info(),
-            "paged_backend": eng.attn_impl,
+            "paged_backend": eng.kernels.attn,
             "metrics": metrics_snapshot(),
             "attribution": attribution_snapshot(),
             "slo": slo_verdicts(),
@@ -3275,7 +3245,7 @@ def run_agent_turns(eng, model, batch, prompt_len, platform, n_chips,
             "chips": n_chips,
             "platform": platform,
             **eng.impl_info(),
-            "paged_backend": eng.attn_impl,
+            "paged_backend": eng.kernels.attn,
             "metrics": metrics_snapshot(),
             "attribution": attribution_snapshot(),
             "slo": slo_verdicts(),

@@ -268,14 +268,6 @@ def build_parser() -> argparse.ArgumentParser:
     se.add_argument("--tp", type=int, default=0, help="tensor-parallel size (0 = all devices)")
     se.add_argument("--sp", type=int, default=1, help="sequence-parallel size for long-context prefill (ragged ring attention)")
     se.add_argument("--ep", type=int, default=1, help="expert-parallel size for MoE models (experts shard over ep)")
-    se.add_argument(
-        "--speculative-k", type=int, default=0,
-        help="prompt-lookup speculative decoding: draft k tokens per decode "
-             "iteration from the sequence's own history (exact for greedy). "
-             "Measured ~6%% draft acceptance on the agent JSON workload "
-             "(PERF.md) — enable only for genuinely repetitive outputs. "
-             "0 disables",
-    )
     se.add_argument("--max-batch-size", type=int, default=8)
     se.add_argument(
         "--quantize",
@@ -459,13 +451,11 @@ def build_parser() -> argparse.ArgumentParser:
     snc.add_argument("--max-batch-size", type=int, default=8)
     snc.add_argument("--quantize", default="", choices=("", "int8"))
     snc.add_argument("--kv-quantize", default="", choices=("", "int8"))
-    snc.add_argument("--speculative-k", type=int, default=0)
     snc.add_argument("--offload", action="store_true", default=False)
     snc.add_argument("--async-depth", type=int, default=2)
     snc.add_argument(
         "--warmup-level", default="full",
-        help="warmup sweep before capture (full/bench/bench-spec/"
-             "sessions): whatever compiles here is what restore replays "
+        help="warmup sweep before capture (full/bench/sessions): whatever compiles here is what restore replays "
              "as cache hits",
     )
     snc.add_argument(
@@ -736,7 +726,6 @@ def main(argv: list[str] | None = None) -> int:
             max_batch_size=args.max_batch_size,
             quantize=args.quantize,
             kv_quantize=args.kv_quantize,
-            speculative_k=args.speculative_k,
             offload=args.offload,
             async_depth=args.async_depth,
             join_fleet=args.join_fleet,
@@ -814,7 +803,6 @@ def main(argv: list[str] | None = None) -> int:
             max_batch_size=args.max_batch_size,
             quantize=args.quantize,
             kv_quantize=args.kv_quantize,
-            speculative_k=args.speculative_k,
             offload=args.offload,
             async_depth=args.async_depth,
             warmup=False,

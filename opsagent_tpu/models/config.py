@@ -307,6 +307,12 @@ class ModelConfig:
         return bool(self.state_mixer)
 
     @property
+    def expert_share(self) -> bool:
+        """The MLP of the MoE layers is ``llama._moe_share``: the router
+        names its own width, of which this chip holds a share."""
+        return self.moe is not None and self.moe.router_experts > 0
+
+    @property
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 

@@ -40,12 +40,12 @@ from ..ops.attention import (
     page_form,
     paged_decode_attention_auto,
     paged_ragged_attention_auto,
-    pallas_interpret,
     token_slots,
     write_kv_pages,
     write_kv_tokens,
     write_pages,
 )
+from ..ops.kernels import Kernels, pallas_interpret, require_kernels
 from ..ops.linear_attention import (
     conv_with_tail,
     delta_rule_chunk,
@@ -664,7 +664,7 @@ def make_cache(
     L = cfg.count_mixers("attn")
     beside = make_state(
         cfg, state_slots, dtype, state_impl) if cfg.has_state else {}
-    if _expert_share(cfg):
+    if cfg.expert_share:
         beside["stats"] = jnp.zeros((len(MOE_STATS),), jnp.uint32)
     form = form or cache_form(cfg)
     if form not in PAGE_FORMS:
@@ -705,7 +705,7 @@ STATE_DTYPE = jnp.float32
 
 def state_slot_shape(la, impl: str = "xla") -> tuple[int, ...]:
     """One linear layer's state of one slot as the cache holds it, by who
-    updates it (``ops.attention.linear_state_backend``). Under XLA: ``[heads,
+    updates it (``ops.kernels.linear_state_backend``). Under XLA: ``[heads,
     key dim, value dim]`` where the value dim fills whole 128-lane tiles of
     the TPU, else the same numbers in the same order as rows of 128: a
     minor dim of 192 pads to 256 lanes (a third more bytes held and moved
@@ -759,20 +759,17 @@ def make_state(
     or holds a snapshot the prefix trie can restore; the slot a row uses
     rides in its table row beside its pages (``split_table``).
 
-    ``impl`` is who updates the slots; a step program reads which form it
-    was given from the rank of ``conv`` (``_linear_mixer``)."""
+    ``impl`` is who updates the slots (``Kernels.state``): the slots are
+    held in the form it reads, and every step program is told the same name
+    (``kernels``)."""
     n = cfg.count_mixers(cfg.state_mixer)
     state, conv = slot_shapes(cfg, impl)
+    # a kernel copies a slot's tail as whole tiles of rows of 128
+    assert (len(conv) == 2) == (impl != "xla"), (impl, conv)
     return {
         "state": jnp.zeros((n, slots, *state), STATE_DTYPE),
         "conv": jnp.zeros((n, slots, *conv), dtype),
     }
-
-
-def _expert_share(cfg: ModelConfig) -> bool:
-    """The MLP of the model's MoE layers is ``_moe_share``: the router names
-    its own width, of which this chip holds a share."""
-    return cfg.moe is not None and cfg.moe.router_experts > 0
 
 
 # A row of the table that goes into every step program holds the row's
@@ -817,7 +814,7 @@ def cache_specs(
     plane drops the head-dim axis but keeps the kv-head axis, so it
     shards with its values."""
     merged = (form or cache_form(cfg)) == "merged"
-    stats = {"stats": P(None)} if _expert_share(cfg) else {}
+    stats = {"stats": P(None)} if cfg.expert_share else {}
     if _latent_cache(cfg):
         def spec(rank: int):
             values = P(*[None] * rank)
@@ -857,37 +854,33 @@ def _ep_constrain(x: jax.Array, spec: P) -> jax.Array:
     return x
 
 
-# Trace-time weight-stream backend scope: "xla" (dequantize fused into
-# the matmul operand read) or "pallas-dma" (ops.quant_matmul_pallas —
-# weight tiles double-buffered HBM->VMEM under the dot). Thread-local
-# like the jit trace itself; set by mixed_step/decode_step from the
-# engine's RESOLVED EngineConfig.weight_stream so every _mm under the
-# layer scan dispatches without threading a parameter through each
-# helper (same trace-time-read pattern as ops.attention.pallas_interpret).
+# ``Kernels.weights`` between ``_run_stack`` / ``_lm_head``, which are
+# handed it, and ``_mm``, which some forty call sites down the layer reach
+# with a leaf and nothing else: "xla" (dequantize fused into the matmul
+# operand read) or "pallas-dma" (ops.quant_matmul_pallas: weight tiles
+# double-buffered HBM->VMEM under the dot). Thread-local like the jit trace
+# itself, and private to this module: nothing outside enters it, a step
+# function names the weight stream in its ``kernels`` like every other
+# kernel (ROADMAP D3a deletes the kernel and this with it).
 _WS_TLS = threading.local()
 
 
 @contextlib.contextmanager
-def weight_stream_scope(impl: str):
-    """Activate a weight-stream backend for the ops traced inside."""
+def _weights(impl: str):
     prev = getattr(_WS_TLS, "impl", "xla")
-    _WS_TLS.impl = impl or "xla"
+    _WS_TLS.impl = impl
     try:
         yield
     finally:
         _WS_TLS.impl = prev
 
 
-def _weight_stream_impl() -> str:
-    return getattr(_WS_TLS, "impl", "xla")
-
-
 def _mm(x: jax.Array, w: Any) -> jax.Array:
     """Matmul against a plain array or a weight-only quantized leaf
     (models.quant, any width): the dequantize multiplies fuse into the
     matmul operand read under XLA, so quantized weights stream from HBM
-    in their narrow storage type. Under an active
-    ``weight_stream_scope("pallas-dma")``, 2D quantized leaves (the
+    in their narrow storage type. In a step program whose ``kernels.weights``
+    is "pallas-dma" (``_weights``), 2D quantized leaves (the
     per-layer scan slices plus lm_head) route through the Pallas
     double-buffered weight-streaming kernel instead; stacked/MoE 3D leaves
     (``_ein``'s) and plain arrays keep the XLA path. An expert share's
@@ -897,12 +890,10 @@ def _mm(x: jax.Array, w: Any) -> jax.Array:
     from .quant import QuantizedBase
 
     if isinstance(w, QuantizedBase):
-        if _weight_stream_impl() == "pallas-dma":
+        if getattr(_WS_TLS, "impl", "xla") == "pallas-dma":
             from ..ops import quant_matmul_pallas as qmp
 
             if qmp.supports(w):
-                from ..ops.attention import pallas_interpret
-
                 lead = x.shape[:-1]
                 y = qmp.quant_matmul_pallas(
                     x.reshape(-1, x.shape[-1]), w,
@@ -916,7 +907,7 @@ def _mm(x: jax.Array, w: Any) -> jax.Array:
 def weight_stream_leaf_paths(params: Params) -> dict[str, int]:
     """How many quantized leaves ``_mm`` routes through the Pallas
     weight-stream kernel and how many it leaves on the XLA dequant under
-    ``weight_stream="pallas-dma"`` — the same ``supports`` test, applied
+    ``Kernels.weights`` "pallas-dma" — the same ``supports`` test, applied
     to each leaf as the layer scan presents it (stacked leaves lose their
     leading layer axis)."""
     from ..ops import quant_matmul_pallas as qmp
@@ -1351,7 +1342,10 @@ def _state_read(flat: jax.Array, idx: jax.Array, fresh: jax.Array):
     return jnp.where(fresh.reshape(-1, *([1] * (got.ndim - 1))), 0, got)
 
 
-def _linear_mixer(h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None):
+def _linear_mixer(
+    h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None,
+    state: str = "xla",
+):
     """Gated delta-rule linear attention on the layer's input h [B, S, d]
     (normed, in a pre-norm block), from and to the rows' state slots
     (``ctx`` None: from zero, kept nowhere: ``forward_full``, the training
@@ -1359,19 +1353,22 @@ def _linear_mixer(h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None):
     (``LinearAttnConfig``). Returns (mixer output before the output
     projection [B, S, H * dv], cache).
 
-    Who updates the slots is read from how the cache holds them
-    (``make_state``). Held for the state kernel, one call a layer takes
-    each row's state from its slot through its tokens to the live and the
-    snapshot slot in place, and writes the conv tail by row
-    (``ops.linear_state_pallas``). Held for XLA, or without slots: the
-    chunk form for S > 1 and the one-token recurrence for S == 1 in plain
+    ``state`` (``Kernels.state``) is who updates the slots, and the cache
+    holds them for it (``make_state``). Under the state kernel, one call a
+    layer takes each row's state from its slot through its tokens to the
+    live and the snapshot slot in place, and writes the conv tail by row
+    (``ops.linear_state_pallas``). Under XLA, or without slots: the chunk
+    form for S > 1 and the one-token recurrence for S == 1 in plain
     ``jax.numpy``, the oracle of the kernel's tests, with a slot gathered a
     row at a time and scattered twice."""
     la = cfg.linear_attn
     B, S, _ = h.shape
     H, dk, dv = la.num_heads, la.key_head_dim, la.value_head_dim
     width = (la.conv_kernel - 1) * la.conv_size
-    kernel = ctx is not None and cache["conv"].ndim == 4
+    kernel = ctx is not None and state != "xla"
+    # The slots are held for the state kernel named (``make_state``).
+    assert ctx is None or (cache["conv"].ndim == 4) == kernel, (
+        state, cache["conv"].shape)
     if ctx is None:
         valid = jnp.full((B,), S, jnp.int32)
         S0 = jnp.zeros((B, H, dk, dv), jnp.float32)
@@ -1465,7 +1462,7 @@ def _linear_mixer(h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None):
 
 def _mamba_mixer(
     h, lp, cfg: ModelConfig, cache, si, ctx: StateCtx | None,
-    pack: Pack | None = None,
+    pack: Pack | None = None, state: str = "xla",
 ):
     """Mamba-1's selective state-space mixer, with Jamba's norms of ``dt``,
     ``B`` and ``C``, on the layer's normed input h [B, S, d], from and to
@@ -1480,18 +1477,21 @@ def _mamba_mixer(
     W_x``, each RMS-normed; ``dt = softplus(dt_low W_dt + b_dt)``; the scan
     (``ops.selective_scan``: one token's recurrence for S == 1, a scan over
     the row's slots else, float32, ``dt = 0`` past a row's ``valid``); ``y +
-    D x`` times ``silu(z)``. Who runs the scan is read from how the cache
-    holds the slots (``slot_shapes``). Held for the scan kernel, one call a
-    layer takes each row's state from its slot through the row's own tokens
-    to the live and the snapshot slot in place, and writes the conv tail by
-    row (``ops.selective_scan_pallas``). Held for XLA, or without slots, a
-    row's slot is gathered a row at a time and scattered twice, as a linear
-    layer's is."""
+    D x`` times ``silu(z)``. ``state`` (``Kernels.state``) is who runs the
+    scan, and the cache holds the slots for it (``slot_shapes``). Under the
+    scan kernel, one call a layer takes each row's state from its slot
+    through the row's own tokens to the live and the snapshot slot in place,
+    and writes the conv tail by row (``ops.selective_scan_pallas``). Under
+    XLA, or without slots, a row's slot is gathered a row at a time and
+    scattered twice, as a linear layer's is."""
     mc = cfg.mamba
     B, S = h.shape[:2] if pack is None else pack.dst.shape
     di, ds, r = mc.d_inner, mc.d_state, mc.dt_rank
     eps = cfg.rms_norm_eps
-    kernel = ctx is not None and cache["conv"].ndim == 4
+    kernel = ctx is not None and state != "xla"
+    # The slots are held for the state kernel named (``make_state``).
+    assert ctx is None or (cache["conv"].ndim == 4) == kernel, (
+        state, cache["conv"].shape)
     if ctx is None:
         valid = jnp.full((B,), S, jnp.int32)
         h0 = jnp.zeros((B, ds, di), jnp.float32)
@@ -1650,7 +1650,7 @@ def _share_buffer(m, tokens: int, least: int = 8) -> tuple[int, int]:
     return bm, -(-(tokens * min(k, E) + E * bm) // bm) * bm
 
 
-def _moe_share(h, lp, cfg: ModelConfig, token_valid):
+def _moe_share(h, lp, cfg: ModelConfig, token_valid, experts: str = "xla"):
     """The expert layer of a model whose ``MoEConfig`` names a router width
     (``router_experts``): the router scores and ranks ALL its experts, this
     chip computes the chosen ones among the ``num_experts`` it holds (from
@@ -1668,9 +1668,9 @@ def _moe_share(h, lp, cfg: ModelConfig, token_valid):
     layer; nothing is searched for. ``token_valid`` [B, S] (or None) keeps
     the padding positions of ragged rows out of the experts and the counts.
 
-    Who runs the blocks is the code's choice, made once where an engine is
-    built (``ops.attention.moe_experts_backend``) and read back here at
-    trace time (``moe_experts_scope``): on a TPU with int8 stacks held
+    Who runs the blocks is ``experts`` (``Kernels.experts``: the code's
+    choice, made once where an engine is built,
+    ``ops.kernels.moe_experts_backend``): on a TPU with int8 stacks held
     whole, ONE Pallas call a layer over the whole buffer
     (``ops.moe_experts_pallas``: the plan's ``block_expert`` and
     ``blocks_used`` are its scalar prefetch, the whole stack and the
@@ -1683,13 +1683,12 @@ def _moe_share(h, lp, cfg: ModelConfig, token_valid):
 
     Returns (output [B, S, d], the MOE_STATS increments [5])."""
     from ..ops import moe_experts_pallas as grouped
-    from ..ops.attention import moe_experts_impl, pallas_interpret
 
     m = cfg.moe
     E, k = m.num_experts, m.num_experts_per_token
     B, S, d = h.shape
     T = B * S
-    kernel = moe_experts_impl() == grouped.IMPL
+    kernel = experts == grouped.IMPL
     with jax.named_scope("moe_router"):
         _, idx, vals = _route(h, lp, cfg)
         local = idx.reshape(T * k) - m.first_expert
@@ -1887,6 +1886,7 @@ def _run_stack(
     state_ctx: StateCtx | None = None,
     token_valid: jax.Array | None = None,
     pack: Pack | None = None,
+    kernels: Kernels = Kernels(),
 ) -> tuple[jax.Array, Params | None, jax.Array]:
     """Scan the model's stacks of WHOLE PERIODS: the dense-MLP stack then
     (if configured) the MoE stack, each a ``lax.scan`` over its periods; a
@@ -1905,10 +1905,16 @@ def _run_stack(
     ``token_valid`` [B, S] keeps the padding of ragged rows out of an
     expert share. With ``pack`` the stream ``x`` is the rows' tokens packed
     ``[1, T, d]`` (``token_valid`` [1, T]): ``attn_fn`` takes and returns
-    packed tokens, and a linear layer gets rows and hands rows back."""
+    packed tokens, and a linear layer gets rows and hands rows back.
+
+    ``kernels`` names who does the work below ``attn_fn`` (which closes over
+    ``kernels.attn`` itself): ``state`` goes to the two state mixers,
+    ``experts`` to ``_moe_share``, and every ``_mm`` of the stack runs under
+    ``weights``. The default is plain XLA throughout."""
+    require_kernels(kernels)
     Ld, Lm = _layer_split(cfg)
     runs = period_runs(cfg)
-    share = _expert_share(cfg)
+    share = cfg.expert_share
 
     def layer(carry, lp, mixer: str, moe: bool):
         x, aux, cache, (ai, *rest) = carry
@@ -1940,7 +1946,7 @@ def _run_stack(
         elif mixer == "mamba":
             # packed tokens in and out: only the conv and the scan see rows
             mixed, cache = _mamba_mixer(
-                h, lp, cfg, cache, si, state_ctx, pack)
+                h, lp, cfg, cache, si, state_ctx, pack, kernels.state)
             with jax.named_scope("attn_out"):
                 x = _dense(
                     pack,
@@ -1951,7 +1957,8 @@ def _run_stack(
             if pack is not None:
                 with jax.named_scope("lin_proj"):
                     h = pack.rows(h)
-            mixed, cache = _linear_mixer(h, lp, cfg, cache, si, state_ctx)
+            mixed, cache = _linear_mixer(
+                h, lp, cfg, cache, si, state_ctx, kernels.state)
             with jax.named_scope("attn_out"):
                 if pack is not None:
                     mixed = pack.tokens(mixed)
@@ -1961,7 +1968,8 @@ def _run_stack(
                     x, mixed)
         with jax.named_scope("ffn"):
             if moe and share:
-                y, stats = _moe_share(pre(x, "mlp_norm"), lp, cfg, token_valid)
+                y, stats = _moe_share(
+                    pre(x, "mlp_norm"), lp, cfg, token_valid, kernels.experts)
                 x = x + post(y, "mlp_norm")
                 if "stats" in cache:
                     cache = dict(cache, stats=cache["stats"] + stats)
@@ -2035,14 +2043,15 @@ def _run_stack(
     # stacks=None runs the full config-implied stack (missing keys raise
     # loudly); pipeline stages (parallel/pipeline.py) pass the subset they
     # own explicitly rather than relying on silent key-presence dispatch.
-    if Ld and (stacks is None or "layers" in stacks):
-        stack = params["layers"]
-        carry, _ = jax.lax.scan(
-            make_body(False, stack), carry, xs(stack, False))
-    if Lm and (stacks is None or "moe_layers" in stacks):
-        stack = params["moe_layers"]
-        carry, _ = jax.lax.scan(
-            make_body(True, stack), carry, xs(stack, True))
+    with _weights(kernels.weights):
+        if Ld and (stacks is None or "layers" in stacks):
+            stack = params["layers"]
+            carry, _ = jax.lax.scan(
+                make_body(False, stack), carry, xs(stack, False))
+        if Lm and (stacks is None or "moe_layers" in stacks):
+            stack = params["moe_layers"]
+            carry, _ = jax.lax.scan(
+                make_body(True, stack), carry, xs(stack, True))
     x, aux, cache, _ = carry
     return x, (None if placeholder else cache), aux
 
@@ -2057,6 +2066,7 @@ def prefill(
     page_table: jax.Array,   # [B, MaxP]
     dtype: jnp.dtype = jnp.bfloat16,
     prefill_attn: Callable | None = None,  # e.g. parallel.ring (sp-sharded)
+    kernels: Kernels = Kernels(),  # who runs what (ops.kernels)
 ) -> tuple[jax.Array, Params]:
     """Full-sequence forward; writes KV into pages; returns (logits of the
     last valid position [B, V], updated cache). ``prefill_attn`` swaps the
@@ -2091,10 +2101,11 @@ def prefill(
         return attn.reshape(B, S, -1), kc, vc
 
     x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache,
-                             state_ctx=ctx, token_valid=token_valid)
+                             state_ctx=ctx, token_valid=token_valid,
+                             kernels=kernels)
     x = _final_norm(params, cfg, x)
     x_last = _last_valid(x, lengths)
-    logits = _lm_head(params, cfg, x_last)
+    logits = _lm_head(params, cfg, x_last, kernels)
     return logits, cache
 
 
@@ -2107,7 +2118,7 @@ def prefill_with_prefix(
     cache: Params,
     page_table: jax.Array,   # [B, MaxP] (prefix pages + fresh tail pages)
     dtype: jnp.dtype = jnp.bfloat16,
-    attn_impl: str = "xla",  # ops.paged_attention_backend choice
+    kernels: Kernels = Kernels(),  # who runs what (ops.kernels)
     mesh=None,               # Mesh for the shard_mapped pallas-under-tp path
 ) -> tuple[jax.Array, Params]:
     """Prefix-cache admission: forward only the tail, attending over the
@@ -2128,7 +2139,7 @@ def prefill_with_prefix(
             )
             ctx = paged_ragged_attention_auto(
                 q_lat, kc, kc, page_table, start, lengths, layer=li,
-                impl=attn_impl, mesh=mesh,
+                impl=kernels.attn, mesh=mesh,
             )
             return _mla_latent_out(ctx, lp, cfg), kc, vc
         q, k, v = _qkv_rope(h, lp, cfg, cos, sin)
@@ -2137,15 +2148,16 @@ def prefill_with_prefix(
         )
         attn = paged_ragged_attention_auto(
             q, kc, vc, page_table, start, lengths, layer=li,
-            impl=attn_impl, mesh=mesh,
+            impl=kernels.attn, mesh=mesh,
         )
         return attn.reshape(B, S, -1), kc, vc
 
     x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache,
-                             state_ctx=sctx, token_valid=token_valid)
+                             state_ctx=sctx, token_valid=token_valid,
+                             kernels=kernels)
     x = _final_norm(params, cfg, x)
     x_last = _last_valid(x, lengths)
-    logits = _lm_head(params, cfg, x_last)
+    logits = _lm_head(params, cfg, x_last, kernels)
     return logits, cache
 
 
@@ -2158,9 +2170,8 @@ def mixed_step(
     cache: Params,
     page_table: jax.Array,   # [B, MaxP]
     dtype: jnp.dtype = jnp.bfloat16,
-    attn_impl: str = "xla",  # ops.paged_attention_backend choice
+    kernels: Kernels = Kernels(),  # who runs what (ops.kernels)
     mesh=None,               # Mesh for the shard_mapped pallas-under-tp path
-    weight_stream: str = "xla",  # xla | pallas-dma (quant_matmul_pallas)
     step_tokens: int = 0,    # the most tokens a step carries (0: B * S)
 ) -> tuple[jax.Array, Params]:
     """The unified mixed prefill+decode forward: one program advances
@@ -2238,7 +2249,7 @@ def mixed_step(
             )
             ctx = paged_ragged_attention_auto(
                 q_lat, kc, kc, page_table, start, q_lens,
-                impl=attn_impl, layer=li, mesh=mesh,
+                impl=kernels.attn, layer=li, mesh=mesh,
             )
             return _mla_latent_out(packed(ctx), lp, cfg), kc, vc
         q, k, v = _qkv_rope(h, lp, cfg, cos, sin, pack, tok_rope)
@@ -2250,76 +2261,16 @@ def mixed_step(
             kc, vc = write_kv_tokens(kc, vc, k, v, tok_slots, layer=li)
         attn = paged_ragged_attention_auto(
             q, kc, vc, page_table, start, q_lens,
-            impl=attn_impl, layer=li, mesh=mesh,
+            impl=kernels.attn, layer=li, mesh=mesh,
         )
         return packed(attn.reshape(B, S, -1)), kc, vc
 
-    with weight_stream_scope(weight_stream):
-        x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache,
-                                 state_ctx=sctx, token_valid=token_valid,
-                                 pack=pack)
-        x = _final_norm(params, cfg, x)
-        x_last = _last_valid(x, q_lens, pack)
-        logits = _lm_head(params, cfg, x_last)
-    return logits, cache
-
-
-def verify_step(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jax.Array,       # [B, S] int32 draft-chunk inputs
-    start: jax.Array,        # [B] write offset (tokens already in cache)
-    valid: jax.Array,        # [B] valid chunk lengths (KV writes + attn)
-    cache: Params,
-    page_table: jax.Array,   # [B, MaxP]
-    dtype: jnp.dtype = jnp.bfloat16,
-    attn_impl: str = "xla",  # ops.paged_attention_backend choice
-    mesh=None,               # Mesh for the shard_mapped pallas-under-tp path
-) -> tuple[jax.Array, Params]:
-    """Speculative-decoding verify forward: process an S-token draft chunk
-    per row in ONE pass, returning logits for EVERY chunk position
-    [B, S, V] (``prefill_with_prefix`` with the last-position gather
-    removed). Each position's argmax is the model's true greedy
-    continuation given the chunk prefix before it — the acceptance test
-    for prompt-lookup drafts. KV for all S positions is written at
-    ``start``; rejected positions simply get overwritten by later real
-    tokens, because the write offset only advances by the accepted count.
-    Costs ~one decode step of HBM traffic (weights stream once per
-    forward, the whole point of speculation)."""
-    if cfg.has_state:
-        raise ValueError(
-            "verify_step: a rejected draft cannot be taken back out of a "
-            "recurrent state; speculative decoding is not supported for a "
-            "model with linear-attention layers")
-    B, S = tokens.shape
-    positions = start[:, None] + jnp.arange(S)[None, :]
-    cos, sin = _rope_tables(cfg, positions)
-    x = _embed(params, tokens, dtype)
-
-    def attn_fn(h, lp, kc, vc, li):
-        if _latent_cache(cfg):
-            q_lat, latent = _mla_latent_parts(h, lp, cfg, cos, sin)
-            kc = write_pages(
-                kc, latent, page_table, start, valid_len=valid, layer=li
-            )
-            ctx = paged_ragged_attention_auto(
-                q_lat, kc, kc, page_table, start, valid, layer=li,
-                impl=attn_impl, mesh=mesh,
-            )
-            return _mla_latent_out(ctx, lp, cfg), kc, vc
-        q, k, v = _qkv_rope(h, lp, cfg, cos, sin)
-        kc, vc = write_kv_pages(
-            kc, vc, k, v, page_table, start, valid_len=valid, layer=li
-        )
-        attn = paged_ragged_attention_auto(
-            q, kc, vc, page_table, start, valid, layer=li,
-            impl=attn_impl, mesh=mesh,
-        )
-        return attn.reshape(B, S, -1), kc, vc
-
-    x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache)
+    x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache,
+                             state_ctx=sctx, token_valid=token_valid,
+                             pack=pack, kernels=kernels)
     x = _final_norm(params, cfg, x)
-    logits = _lm_head(params, cfg, x)
+    x_last = _last_valid(x, q_lens, pack)
+    logits = _lm_head(params, cfg, x_last, kernels)
     return logits, cache
 
 
@@ -2332,9 +2283,8 @@ def decode_step(
     page_table: jax.Array,   # [B, MaxP]
     active: jax.Array,       # [B] bool; inactive slots skip the page write
     dtype: jnp.dtype = jnp.bfloat16,
-    attn_impl: str = "xla",  # ops.paged_attention_backend choice
+    kernels: Kernels = Kernels(),  # who runs what (ops.kernels)
     mesh=None,               # Mesh for the shard_mapped pallas-under-tp path
-    weight_stream: str = "xla",  # xla | pallas-dma (quant_matmul_pallas)
 ) -> tuple[jax.Array, Params]:
     """One decode step for a batch of sequences; returns ([B, V] logits,
     updated cache)."""
@@ -2354,7 +2304,7 @@ def decode_step(
             )
             ctx = paged_decode_attention_auto(
                 q_lat[:, 0], kc, kc, page_table, lengths + valid,
-                impl=attn_impl, layer=li, mesh=mesh,
+                impl=kernels.attn, layer=li, mesh=mesh,
             )
             return _mla_latent_out(ctx[:, None], lp, cfg), kc, vc
         q, k, v = _qkv_rope(h, lp, cfg, cos, sin)
@@ -2363,15 +2313,15 @@ def decode_step(
         )
         attn = paged_decode_attention_auto(
             q[:, 0], kc, vc, page_table, lengths + valid,
-            impl=attn_impl, layer=li, mesh=mesh,
+            impl=kernels.attn, layer=li, mesh=mesh,
         )
         return attn.reshape(B, 1, -1), kc, vc
 
-    with weight_stream_scope(weight_stream):
-        x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache,
-                                 state_ctx=sctx, token_valid=token_valid)
-        x = _final_norm(params, cfg, x)
-        logits = _lm_head(params, cfg, x[:, 0])
+    x, cache, _ = _run_stack(params, cfg, x, attn_fn, cache,
+                             state_ctx=sctx, token_valid=token_valid,
+                             kernels=kernels)
+    x = _final_norm(params, cfg, x)
+    logits = _lm_head(params, cfg, x[:, 0], kernels)
     return logits, cache
 
 
@@ -2389,7 +2339,7 @@ def _row_state(cfg: ModelConfig, cache, table, start, valid, S: int):
     recurrent state or an expert share: (page table, StateCtx or None, the
     mask [B, S] of real positions or None). Any other model gets its
     table back and nothing else."""
-    share = _expert_share(cfg)
+    share = cfg.expert_share
     token_valid = (
         jnp.arange(S)[None, :] < valid[:, None] if share else None)
     if not cfg.has_state:
@@ -2421,10 +2371,14 @@ def _last_valid(
 
 
 @scoped("lm_head")
-def _lm_head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+def _lm_head(
+    params: Params, cfg: ModelConfig, x: jax.Array,
+    kernels: Kernels = Kernels(),
+) -> jax.Array:
     if cfg.tie_embeddings:
         return (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
-    return _mm(x, params["lm_head"]).astype(jnp.float32)
+    with _weights(kernels.weights):     # the head streams as the stack does
+        return _mm(x, params["lm_head"]).astype(jnp.float32)
 
 
 def forward_full(

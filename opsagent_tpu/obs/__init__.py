@@ -100,7 +100,7 @@ PREFIX_HIT_TOKENS = _reg.counter(
 )
 DECODE_DISPATCHES = _reg.counter(
     "opsagent_decode_dispatches_total",
-    "Device decode dispatches by kind (block, single, speculative, mixed)",
+    "Device decode dispatches by kind (block, single, mixed, mixed_async)",
     labelnames=("kind",),
 )
 MIXED_DECODE_LANES = _reg.histogram(

@@ -29,7 +29,7 @@ Known approximations (documented, deliberate):
   bytes assume each resident token's K/V is streamed once per dispatch
   (the paged kernels' design goal — the XLA gather can read page-table
   capacity instead, which again shows up as drift).
-- Block/speculative decode scans stream weights once per SCAN STEP
+- Block decode scans stream weights once per SCAN STEP
   (``n_steps`` times per dispatch), regardless of how few lanes carry a
   budget — inactive lanes still ride the stream.
 

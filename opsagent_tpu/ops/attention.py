@@ -38,15 +38,12 @@ keys and values alike.
 
 from __future__ import annotations
 
-import contextlib
-import os
-import threading
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..utils.profiling import scoped
+from . import kernels
 from .paged_attention_stream import (
     paged_decode_attention_stream,
     paged_ragged_attention_stream,
@@ -111,7 +108,7 @@ def page_form(kv_heads_per_shard: int, attn_impl: str = "xla") -> str:
     header): "merged" ``[.., P, K*D]`` or "split" ``[.., P, K, D]``, by
     the reader. Under the xla gather: merged where split pages would get
     a part-empty tile (K neither 1 nor a multiple of 8). By the compiler,
-    for a described v5e (tests/test_tpu_compile.py): split at K = 2 and 4
+    for a described v5e (tests/test_tpu_compile_steps.py): split at K = 2 and 4
     re-tiles the whole cache between write and gather in every layer,
     bf16 and int8 alike (both get 8-row tiles); at K = 8 split has no
     such copy and merged would add one of each gathered block. The
@@ -162,237 +159,6 @@ def _dequantize_gathered(seq: jax.Array, scale: jax.Array, dtype) -> jax.Array:
     return (seq.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
-PAGED_BACKENDS = ("xla", "pallas-stream")
-
-
-def paged_attention_backend(
-    *,
-    platform: str,
-    head_dim: int,
-    kv_heads_per_shard: int,
-    page_itemsize: int,
-    mla: bool = False,
-    shared_kv: bool = False,
-    tp: int = 1,
-) -> str:
-    """Which reader of paged keys and values an engine runs: "xla" (the
-    gather, and the oracle of every test) or "pallas-stream" (the
-    streaming ragged kernel, ``paged_attention_stream``).
-
-    The choice is the code's, a pure function of what it can observe
-    where the engine is built: the platform of the mesh's devices and the
-    shapes ``pallas_refusal`` takes, which are the READER's (an MLA model
-    that holds the latent describes itself as what its reader is handed:
-    one kv head of ``MLAConfig.page_dim`` lanes that is keys and values
-    alike); nothing outside the code names a reader. On a TPU it is the
-    streaming kernel wherever the chip's compiler takes it (head dim on
-    the 128-lane tiling, bf16 pages; MLA's absorbed attention over latent
-    pages among them); everywhere else (the CPU, int8 pages, head dims
-    off the tiling, MLA with materialised heads, the latent under tp > 1)
-    the gather. By measurement (PERF.md section 6, PRs 29 and 41,
-    ``scripts/attn_microbench.py`` at the benchmark cells' shapes on a
-    v5e): the kernel is ahead of the gather at every shape the cells run,
-    decode blocks over short rows included, so no shape is sent back to
-    the gather on speed."""
-    if platform != "tpu":
-        return "xla"
-    refused = pallas_refusal(
-        "pallas-stream", head_dim=head_dim,
-        kv_heads_per_shard=kv_heads_per_shard,
-        page_itemsize=page_itemsize, mla=mla, shared_kv=shared_kv, tp=tp,
-    )
-    return "xla" if refused else "pallas-stream"
-
-
-STATE_BACKENDS = ("xla", "pallas-state", "pallas-ssm")
-
-
-def linear_state_backend(
-    *,
-    platform: str,
-    state_dtype: str,
-    key_dim: int,
-    value_dim: int,
-    heads: int,
-) -> str:
-    """Who updates a linear-attention layer's recurrent state in an
-    engine's step programs: "xla" (a slot gathered a row at a time, the
-    chunk form or the one-token recurrence in plain ``jax.numpy``, two
-    scatters; the oracle of every test) or "pallas-state" (one kernel a
-    layer from read through update to both writes,
-    ``linear_state_pallas``).
-
-    The code's own choice, as ``paged_attention_backend`` is, from what it
-    can observe where the engine is built; the cache is then held in the
-    form the answer reads (``llama.state_slot_shape``) and every step
-    program reads the answer back from that form. On a TPU it is the
-    kernel wherever a float32 state tile lies on whole (8, 128) tiles as
-    held: the key dim a multiple of 8, and the value dim of one head, or
-    of two heads side by side where the heads pair up, a multiple of 128
-    (Mosaic takes no other; tests/test_tpu_compile.py compiles both of the
-    benchmark's shapes). Everywhere else XLA."""
-    if platform != "tpu" or state_dtype != "float32" or key_dim % 8:
-        return "xla"
-    if value_dim % 128 and (heads % 2 or (2 * value_dim) % 128):
-        return "xla"
-    return "pallas-state"
-
-
-def ssm_state_backend(
-    *, platform: str, state_dtype: str, d_state: int, d_inner: int
-) -> str:
-    """Who runs a Mamba layer's selective scan over the state slots in an
-    engine's step programs: "xla" (a slot gathered a row at a time, a
-    ``lax.scan`` over every slot of every row in plain ``jax.numpy``, two
-    scatters; the oracle of every test) or "pallas-ssm" (one kernel a layer
-    from read through the rows' own tokens to both writes,
-    ``selective_scan_pallas``). The code's own choice, as
-    ``linear_state_backend`` is, read back by every step program from how
-    the cache is held (``llama.slot_shapes``): the kernel on a TPU wherever
-    the float32 state ``[d_state, d_inner]`` lies on whole (8, 128) tiles,
-    everywhere else XLA."""
-    if (platform != "tpu" or state_dtype != "float32" or d_state % 8
-            or d_inner % 128):
-        return "xla"
-    return "pallas-ssm"
-
-
-MOE_BACKENDS = ("xla", "pallas-grouped")
-
-
-def moe_experts_backend(
-    *,
-    platform: str,
-    quantize: str,
-    hidden_size: int,
-    expert_width: int,
-    tp: int = 1,
-    ep: int = 1,
-) -> str:
-    """Who runs the blocks of an expert share (``llama._moe_share``) in an
-    engine's step programs: "xla" (a ``while`` over the blocks in use, an
-    expert's three matmuls a block as fusion calls; the oracle of every
-    test) or "pallas-grouped" (one kernel a layer over the whole sorted
-    buffer, the next block's int8 tiles in flight while this block
-    multiplies, ``moe_experts_pallas``).
-
-    The code's own choice, as ``paged_attention_backend`` is, from what it
-    can observe where the engine is built: on a TPU the kernel wherever
-    the expert stacks are int8 leaves (``quantize`` "int8": per-channel
-    scales) held whole on the one shard, with the model width and the
-    experts' intermediate width on whole 128-lane tiles; everywhere else
-    (the CPU, bfloat16 or int4 stacks, ``tp`` or ``ep`` above 1, widths
-    off the lanes) the loop. The engine resolves it once and every step
-    program reads it back at trace time (``moe_experts_scope``)."""
-    if (platform != "tpu" or quantize != "int8" or tp > 1 or ep > 1
-            or hidden_size % 128 or expert_width % 128):
-        return "xla"
-    return "pallas-grouped"
-
-
-_MOE_TLS = threading.local()
-
-
-@contextlib.contextmanager
-def moe_experts_scope(impl: str):
-    """Activate an expert-share backend for the programs traced inside:
-    thread-local like the trace itself, read by ``llama._moe_share`` the
-    way ``llama._mm`` reads ``weight_stream_scope``."""
-    if impl not in MOE_BACKENDS:
-        raise ValueError(
-            f"expert-share backend {impl!r}: expected one of {MOE_BACKENDS}")
-    prev = moe_experts_impl()
-    _MOE_TLS.impl = impl
-    try:
-        yield
-    finally:
-        _MOE_TLS.impl = prev
-
-
-def moe_experts_impl() -> str:
-    return getattr(_MOE_TLS, "impl", "xla")
-
-
-def pallas_interpret() -> bool:
-    """Whether the Pallas kernels should run in interpret mode
-    (OPSAGENT_PALLAS_INTERPRET=1): how the CPU tests run the attention
-    and weight-stream kernels' dispatch paths end-to-end off-TPU, where a
-    compiled pallas_call cannot lower. Read at trace time by the ``*_auto``
-    dispatchers. On the chip it is an error, not a slow success:
-    interpret mode is orders of magnitude slower and skips Mosaic
-    entirely, so whatever it produced there would carry the kernel's
-    name without having run the kernel."""
-    on = os.environ.get("OPSAGENT_PALLAS_INTERPRET", "") == "1"
-    if on and jax.default_backend() == "tpu":
-        raise RuntimeError(
-            "OPSAGENT_PALLAS_INTERPRET=1 on the tpu backend: interpret "
-            "mode is for CPU tests only; unset it"
-        )
-    return on
-
-
-def pallas_refusal(
-    impl: str,
-    *,
-    head_dim: int,
-    kv_heads_per_shard: int,
-    page_itemsize: int,
-    mla: bool = False,
-    shared_kv: bool = False,
-    tp: int = 1,
-) -> str | None:
-    """Why paged-attention backend ``impl`` cannot serve these shapes, or
-    None when it can. The shapes are what the READER is handed: for an
-    MLA model that holds the latent, one kv head of ``page_dim`` lanes
-    whose pages are keys and values alike (``shared_kv``), under the
-    absorbed queries. What the streaming kernel has no reader for: MLA
-    with materialised heads (``mla`` without ``shared_kv``), the latent
-    under ``tp`` > 1, int8 pages, and a head dim off the 128 lanes
-    (Mosaic's refusal when the kernel was compiled for a described v5e
-    device; tests/test_tpu_compile.py keeps both sides of each rule).
-    ``paged_attention_backend`` sends such an engine to the gather, so no
-    such combination reaches the chip to fail there. Interpret mode has
-    no Mosaic and not its tiling limit.
-
-    ``page_itemsize``: bytes per stored KV element (1 for int8 pages).
-    ``kv_heads_per_shard``: one of the shapes an engine describes itself
-    by; no rule reads it, the kernel takes any head count."""
-    if impl == "xla":
-        return None
-    if mla and not shared_kv:
-        return (
-            f"paged backend {impl!r} with MLA's materialised heads (no "
-            "latent cache): keys of nope + rope dims (192 or 256 wide) "
-            "beside narrower values padded to them, a form the kernel has "
-            "never been compiled for or run at; it serves through the "
-            "xla gather"
-        )
-    if shared_kv and tp > 1:
-        return (
-            f"paged backend {impl!r} over pages that are keys and values "
-            f"alike (MLA's latent) at tp={tp}: one replicated kv head "
-            "under sharded query heads has never been compiled or run "
-            "inside the kernel's shard_map; it serves through the xla "
-            "gather"
-        )
-    if page_itemsize == 1:
-        return (
-            "pallas-stream with int8 pages: a 16-token page is half "
-            "of an int8 tile's 32 rows, so a key block cannot be "
-            "read out of the page buffers without a re-tiling, and "
-            "the per-token scales would need a lane-to-sublane move "
-            "a kv head; QuantizedPages serve through the xla gather"
-        )
-    if head_dim % 128:
-        return (
-            f"pallas-stream with head_dim {head_dim}: a kv head is a "
-            "slice of the merged page row's lanes, and Mosaic wants "
-            "it on the 128-lane tiling; such heads serve through the "
-            "xla gather"
-        )
-    return None
-
-
 def _tp(mesh: Mesh | None) -> int:
     return 1 if mesh is None else mesh.shape.get("tp", 1)
 
@@ -400,9 +166,10 @@ def _tp(mesh: Mesh | None) -> int:
 def _require_reader(impl: str) -> None:
     """A name that is no reader's (a deleted kernel's, a typo) is an
     error where the dispatch would otherwise run the gather under it."""
-    if impl not in PAGED_BACKENDS:
+    if impl not in kernels.PAGED_BACKENDS:
         raise ValueError(
-            f"paged backend {impl!r}: expected one of {PAGED_BACKENDS}"
+            f"paged backend {impl!r}: "
+            f"expected one of {kernels.PAGED_BACKENDS}"
         )
 
 
@@ -413,7 +180,7 @@ def _require_form(pages, head_dim: int, tp: int = 1, layer=None) -> None:
     told by ``layer`` as ``pages_merged`` tells it), or one head a shard
     with its unit axis."""
     if isinstance(pages, QuantizedPages):
-        raise ValueError(pallas_refusal(
+        raise ValueError(kernels.pallas_refusal(
             "pallas-stream", head_dim=head_dim, kv_heads_per_shard=1,
             page_itemsize=1,
         ))
@@ -501,7 +268,7 @@ def paged_decode_attention_auto(
     refuses them by name (``pallas_refusal``)."""
     if impl == "pallas-stream":
         _require_form(k_pages, q.shape[-1], _tp(mesh), layer)
-        interpret = pallas_interpret()
+        interpret = kernels.pallas_interpret()
         if _tp(mesh) > 1:
             return paged_decode_attention_pallas_tp(
                 q, k_pages, v_pages, page_table, lengths, mesh, layer=layer,
@@ -939,7 +706,7 @@ def paged_ragged_attention_auto(
     by name; an engine with int8 pages resolves to the gather."""
     if impl == "pallas-stream":
         _require_form(k_pages, q.shape[-1], _tp(mesh), layer)
-        interpret = pallas_interpret()
+        interpret = kernels.pallas_interpret()
         if _tp(mesh) > 1:
             return paged_ragged_attention_pallas_tp(
                 q, k_pages, v_pages, page_table, start, q_lens, mesh,

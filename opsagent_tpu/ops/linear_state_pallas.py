@@ -1,7 +1,7 @@
 """The delta rule over state slots, Pallas TPU ("pallas-state").
 
 What a linear-attention layer of a serving step does to its rows'
-recurrent state where the code chooses it (``ops.attention.
+recurrent state where the code chooses it (``ops.kernels.
 linear_state_backend``). Under XLA a row's float32 state crosses HBM some
 eight times a layer: copied out of its slot, read three times and written
 once by the chunk form at the bucket's width for every row, scattered to

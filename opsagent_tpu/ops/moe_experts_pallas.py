@@ -33,7 +33,7 @@ read: no assignment's destination lies there).
 
 Correctness oracle: ``llama._moe_share``'s XLA loop (interpret mode on the
 CPU, tests/test_moe_experts_kernel.py); the chip's compiler is asked at
-both expert cells' shapes in tests/test_tpu_compile.py.
+both expert cells' shapes in tests/test_tpu_compile_experts.py.
 """
 
 from __future__ import annotations
@@ -200,7 +200,7 @@ def moe_expert_blocks(
         raise ValueError(
             f"{IMPL} reads int8 QuantizedLinear stacks; got "
             f"{[type(w).__name__ for w in stacks]} "
-            "(ops.attention.moe_experts_backend sends others to the loop)")
+            "(ops.kernels.moe_experts_backend sends others to the loop)")
     *lead, E, _, f = gate.q.shape
     if len(idx) != len(lead):
         raise ValueError(

@@ -1,7 +1,7 @@
 """Streaming ragged paged attention, Pallas TPU ("pallas-stream").
 
 The reader of paged keys and values that the code chooses on a TPU
-(``ops.attention.paged_attention_backend``). The XLA reader gathers every
+(``ops.kernels.paged_attention_backend``). The XLA reader gathers every
 row's ``MaxP`` pages into a ``[B, MaxP*P, K, D]`` block whatever the row's
 length, scores all ``S`` query slots against it in f32 and writes the
 ``[B, K, G, S, MaxP*P]`` scores to HBM (PERF.md PR 29: 251 ms of a 339 ms
@@ -48,7 +48,7 @@ what the arithmetic needs:
 Correctness oracle: ``ops.attention.paged_ragged_attention`` (interpret
 mode on the CPU, tests/test_pallas_paged.py); the chip's compiler is asked
 at the benchmark cells' shapes, the latent's among them, in
-tests/test_tpu_compile.py.
+tests/test_tpu_compile_attention.py.
 """
 
 from __future__ import annotations
@@ -391,7 +391,7 @@ def paged_ragged_attention_stream(
     if isinstance(k_pages, QuantizedPages):
         raise ValueError(
             "pallas-stream reads bf16/f32 pages; int8 QuantizedPages go "
-            "through the xla gather (ops.attention.pallas_refusal)"
+            "through the xla gather (ops.kernels.pallas_refusal)"
         )
     # Seen here, outside the jit: inside it two arguments are two tracers.
     return _stream(

@@ -1,7 +1,7 @@
 """Mamba-1's selective scan over state slots, Pallas TPU ("pallas-ssm").
 
 What a Mamba layer of a serving step does to its rows' recurrent state
-where the code chooses it (``ops.attention.ssm_state_backend``). Under XLA
+where the code chooses it (``ops.kernels.ssm_state_backend``). Under XLA
 (``ops.selective_scan``, the oracle) a mixed step's scan walks every slot of
 every row: a ``lax.scan`` whose carry is all the rows' states, read and
 written once a SLOT (16 passes over 21 MB a layer at 64 rows x 16 slots,

@@ -1329,7 +1329,6 @@ def run_engine_server(
     max_batch_size: int = 8,
     quantize: str = "",
     kv_quantize: str = "",
-    speculative_k: int = 0,
     offload: bool = False,
     async_depth: int = 2,
     join_fleet: str = "",
@@ -1374,7 +1373,6 @@ def run_engine_server(
             max_batch_size=max_batch_size,
             quantize=quantize,
             kv_quantize=kv_quantize,
-            speculative_k=speculative_k,
             offload=offload,
             async_depth=async_depth,
             # Production server: compile everything before accepting requests

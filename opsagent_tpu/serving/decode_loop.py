@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from ..models import llama
 from ..models.config import ModelConfig
+from ..ops.kernels import Kernels
 from .sampler import NEG_INF, sample
 
 
@@ -51,8 +52,8 @@ def record_dispatch(
     module. The loop bodies themselves are jitted — their Python runs only
     at trace time, so instrumentation inside them would count compiles,
     not dispatches. The engine calls this once per enqueued program:
-    ``kind`` is "block" (decode_block_carry), "spec"
-    (speculative_block_carry), or "single" (the fused one-step path);
+    ``kind`` is "block" (decode_block_carry) or "single" (the fused
+    one-step path);
     ``rows`` is how many lanes got a budget and ``steps`` the largest
     per-lane budget in the dispatch. ``attr``/``attr_kw`` carry the
     dispatch's roofline composition to the attribution ledger."""
@@ -166,9 +167,8 @@ def mixed_step_carry(
     top_k: jax.Array,       # [B] int32
     top_p: jax.Array,       # [B] float32
     dtype: jnp.dtype = jnp.bfloat16,
-    attn_impl: str = "xla",
+    kernels: Kernels = Kernels(),   # who runs what (ops.kernels)
     mesh=None,
-    weight_stream: str = "xla",  # llama.weight_stream_scope backend
     # Device-side constrained decoding, same table layout as
     # decode_block_carry: row 0 of fsm_mask/fsm_dest is the FREE sentinel,
     # DFA state s lives at row s+1. carry_fsm rides the dispatch chain;
@@ -199,8 +199,7 @@ def mixed_step_carry(
         tokens = tokens.at[:, 0].set(first)
     logits, cache = llama.mixed_step(
         params, cfg, tokens, starts, q_lens, cache, page_table,
-        dtype=dtype, attn_impl=attn_impl, mesh=mesh,
-        weight_stream=weight_stream, step_tokens=step_tokens,
+        dtype=dtype, kernels=kernels, mesh=mesh, step_tokens=step_tokens,
     )
     with_fsm = fsm_mask is not None
     with jax.named_scope("sample"):
@@ -218,52 +217,6 @@ def mixed_step_carry(
         else:
             fsm_out = jnp.zeros_like(out)
     return out, cache, fsm_out
-
-
-def decode_block(
-    params: Any,
-    cfg: ModelConfig,
-    tokens: jax.Array,      # [B] int32: last sampled (not yet written) token
-    write_at: jax.Array,    # [B] int32: tokens already written to cache
-    active: jax.Array,      # [B] bool
-    budgets: jax.Array,     # [B] int32: max tokens this row may emit now
-    cache: Any,             # paged KV pytree (donated by the jit wrapper)
-    page_table: jax.Array,  # [B, MaxP] — pages for the whole block are
-                            # pre-allocated by the caller
-    key: jax.Array,         # PRNG key (threaded through, returned updated)
-    temps: jax.Array,       # [B] float32
-    top_k: jax.Array,       # [B] int32
-    top_p: jax.Array,       # [B] float32
-    eos_id: jax.Array,      # [] int32
-    pad_id: jax.Array,      # [] int32
-    n_steps: int,
-    greedy: bool = False,
-    dtype: jnp.dtype = jnp.bfloat16,
-    attn_impl: str = "xla",
-    mesh=None,               # Mesh for the shard_mapped pallas-under-tp path
-    weight_stream: str = "xla",  # llama.weight_stream_scope backend
-) -> tuple[jax.Array, Any, jax.Array]:
-    """One self-contained block: ``decode_block_carry`` with every lane
-    host-initialized (override all) and the carry discarded. Returns
-    (tokens_out [B, n_steps] int32 — pad past a row's finish —, cache, key).
-
-    ``greedy=True`` (trace-time) replaces the sampler with a bare argmax —
-    the agent-loop default (temperature 0, reference pkg/llms/openai.go:73)
-    — because even a top-k candidate scan over a 128k vocab inside the
-    decode loop costs several times the decode step itself on TPU.
-    """
-    toks, cache, (_, _, _, _, key) = decode_block_carry(
-        params, cfg,
-        carry_tok=tokens, carry_at=write_at,
-        carry_eos=jnp.zeros_like(active), key=key,
-        override=jnp.ones_like(active), ov_tok=tokens, ov_at=write_at,
-        alive=active, budgets=budgets, cache=cache, page_table=page_table,
-        temps=temps, top_k=top_k, top_p=top_p,
-        eos_id=eos_id, pad_id=pad_id, n_steps=n_steps, greedy=greedy,
-        dtype=dtype, attn_impl=attn_impl, mesh=mesh,
-        weight_stream=weight_stream,
-    )
-    return toks, cache, key
 
 
 def decode_block_carry(
@@ -290,9 +243,8 @@ def decode_block_carry(
     n_steps: int,
     greedy: bool = False,
     dtype: jnp.dtype = jnp.bfloat16,
-    attn_impl: str = "xla",
+    kernels: Kernels = Kernels(),   # who runs what (ops.kernels)
     mesh=None,               # Mesh for the shard_mapped pallas-under-tp path
-    weight_stream: str = "xla",  # llama.weight_stream_scope backend
     # Device-side constrained decoding (SURVEY §7's hard part: the FSM
     # steps on device, no host sync per token). fsm_mask/fsm_dest are the
     # shared [S+1, V] tables — ROW 0 is the FREE sentinel (everything
@@ -303,9 +255,14 @@ def decode_block_carry(
     carry_fsm: jax.Array | None = None,   # [B] int32
     ov_fsm: jax.Array | None = None,      # [B] int32
 ) -> tuple[jax.Array, Any, tuple]:
-    """``decode_block`` with the loop state living ON DEVICE across
-    dispatches, so the host can enqueue block k+1 before pulling block k's
-    tokens (the pipelined engine path).
+    """``n_steps`` decode+sample steps in one program, the loop state
+    living ON DEVICE across dispatches, so the host can enqueue block k+1
+    before pulling block k's tokens (the pipelined engine path).
+
+    ``greedy=True`` (trace-time) replaces the sampler with a bare argmax —
+    the agent-loop default (temperature 0, reference pkg/llms/openai.go:73)
+    — because even a top-k candidate scan over a 128k vocab inside the
+    decode loop costs several times the decode step itself on TPU.
 
     Chaining dispatches through the returned carry keeps the device busy
     while the previous block's [B, n_steps] token pull and host
@@ -315,7 +272,8 @@ def decode_block_carry(
     strings, cancellations) with one-dispatch lag; everything else — EOS
     detection, per-dispatch budgets, KV writes — is decided on device.
 
-    Returns (tokens [B, n_steps], cache, new carry (tok, at, eos, key)).
+    Returns (tokens [B, n_steps] int32, pad past a row's finish; cache; the
+    new carry (tok, at, eos, fsm, key)).
     """
     tok = jnp.where(override, ov_tok, carry_tok).astype(jnp.int32)
     at = jnp.where(override, ov_at, carry_at).astype(jnp.int32)
@@ -331,8 +289,7 @@ def decode_block_carry(
         tok, at, eos, act, fstate, cache, key = carry
         logits, cache = llama.decode_step(
             params, cfg, tok, at, cache, page_table, act,
-            dtype=dtype, attn_impl=attn_impl, mesh=mesh,
-            weight_stream=weight_stream,
+            dtype=dtype, kernels=kernels, mesh=mesh,
         )
         with jax.named_scope("sample"):
             if with_fsm:
@@ -360,149 +317,3 @@ def decode_block_carry(
         jnp.arange(n_steps),
     )
     return toks.T, cache, (tok, at, eos, fstate, key)
-
-
-# -- speculative decoding (prompt-lookup / n-gram drafting) ------------------
-def ngram_draft(
-    hist: jax.Array,    # [B, H] token history (prompt + accepted generation)
-    at: jax.Array,      # [B] written-token counts (hist[:, :at] is real)
-    tok: jax.Array,     # [B] next input token (not yet in hist)
-    k: int,             # draft length
-    ngram: int,         # match-gram length (includes tok as its last item)
-) -> jax.Array:
-    """Prompt-lookup drafting, fully on device: find the LAST earlier
-    occurrence of the trailing ``ngram`` (history tail + tok) and propose
-    the k tokens that followed it. Agent ReAct loops re-emit the same JSON
-    scaffolding every iteration, so lookups hit constantly. Rows with no
-    match draft pad-like junk that simply fails verification (costing
-    nothing extra — the verify forward runs regardless). Returns
-    [B, k] draft tokens."""
-    B, H = hist.shape
-    g = ngram
-    gpos = at[:, None] - (g - 1) + jnp.arange(g)[None, :]          # [B, g]
-    gram = jnp.take_along_axis(hist, jnp.clip(gpos, 0, H - 1), axis=1)
-    gram = gram.at[:, -1].set(tok)
-    W = H - g + 1
-    eq = jnp.ones((B, W), bool)
-    for i in range(g):
-        eq &= hist[:, i : W + i] == gram[:, i : i + 1]
-    jpos = jnp.arange(W)[None, :]
-    # The candidate window must end strictly before the current tail gram
-    # (else it matches itself), and its draft must be written history.
-    ok = eq & (jpos + g + k - 1 <= at[:, None] - 1)
-    j = jnp.max(jnp.where(ok, jpos, -1), axis=1)                   # [B]
-    dpos = j[:, None] + g + jnp.arange(k)[None, :]
-    draft = jnp.take_along_axis(hist, jnp.clip(dpos, 0, H - 1), axis=1)
-    return jnp.where(j[:, None] >= 0, draft, -1)
-
-
-def speculative_block_carry(
-    params: Any,
-    cfg: ModelConfig,
-    carry_tok: jax.Array,   # [B] int32 last sampled (not yet written) token
-    carry_at: jax.Array,    # [B] int32 tokens already written to cache
-    carry_eos: jax.Array,   # [B] bool
-    carry_hist: jax.Array,  # [B, H] int32 device-resident token history
-    override: jax.Array,    # [B] bool  lane newly (re)assigned
-    ov_tok: jax.Array,      # [B] int32
-    ov_at: jax.Array,       # [B] int32
-    ov_hist: jax.Array,     # [B, H] int32 host-supplied history for overrides
-    alive: jax.Array,       # [B] bool
-    budgets: jax.Array,     # [B] int32 max tokens this dispatch may emit
-    cache: Any,             # paged KV pytree (donated)
-    page_table: jax.Array,  # [B, MaxP]
-    eos_id: jax.Array,
-    pad_id: jax.Array,
-    n_steps: int,           # scan iterations (each emits 1..k+1 tokens)
-    k: int,                 # draft tokens per iteration
-    ngram: int = 2,
-    dtype: jnp.dtype = jnp.bfloat16,
-    attn_impl: str = "xla",
-    mesh=None,
-) -> tuple[jax.Array, jax.Array, Any, tuple]:
-    """GREEDY decode with prompt-lookup speculation, device-resident like
-    ``decode_block_carry``: each scan step drafts k tokens from the row's
-    own history, verifies them in ONE multi-position forward
-    (llama.verify_step), and emits the accepted prefix + the model's own
-    next token — 1 to k+1 tokens per weight-streaming pass. Emission stops
-    at EOS and at the per-dispatch budget; KV written for rejected draft
-    positions is overwritten later (write offset advances by accepted
-    count only), and writes past the booked pages land on -1 table slots,
-    which drop.
-
-    Returns (tokens [B, n_steps, k+1] — pad past each step's count —,
-    counts [B, n_steps], cache, new carry (tok, at, eos, hist)).
-    """
-    B = carry_tok.shape[0]
-    tok = jnp.where(override, ov_tok, carry_tok).astype(jnp.int32)
-    at = jnp.where(override, ov_at, carry_at).astype(jnp.int32)
-    eos = jnp.where(override, False, carry_eos)
-    hist = jnp.where(override[:, None], ov_hist, carry_hist).astype(jnp.int32)
-    iota = jnp.arange(k + 1)[None, :]
-
-    def body(carry, _):
-        tok, at, eos, act, emitted, cache, hist = carry
-        with jax.named_scope("sample"):    # draft (llama.SCOPES)
-            rem = budgets - emitted
-            draft = ngram_draft(hist, at, tok, k, ngram)
-            inputs = jnp.concatenate([tok[:, None], draft], axis=1)  # [B, k+1]
-            valid = jnp.where(act, jnp.minimum(k + 1, rem), 0)
-        logits, cache = llama.verify_step(
-            params, cfg, inputs, at, valid, cache, page_table, dtype=dtype,
-            attn_impl=attn_impl, mesh=mesh,
-        )
-        with jax.named_scope("sample"):    # accept
-            a = jnp.argmax(logits, axis=-1).astype(jnp.int32)      # [B, k+1]
-            match = (draft == a[:, :k]).astype(jnp.int32)
-            prefix_ok = jnp.cumprod(match, axis=1)                 # [B, k]
-            can = jnp.concatenate(
-                [jnp.ones((B, 1), jnp.int32), prefix_ok], axis=1
-            )                                                      # [B, k+1]
-            no_eos_before = jnp.cumprod(
-                jnp.concatenate(
-                    [jnp.ones((B, 1), jnp.int32),
-                     (a[:, :k] != eos_id).astype(jnp.int32)],
-                    axis=1,
-                ),
-                axis=1,
-            )
-            emit = (
-                (can * no_eos_before) > 0
-            ) & (iota < rem[:, None]) & act[:, None]
-            n_emit = jnp.sum(emit, axis=1).astype(jnp.int32)       # [B]
-            out_toks = jnp.where(emit, a, pad_id).astype(jnp.int32)
-            eos_new = eos | jnp.any(emit & (a == eos_id), axis=1)
-            # History: the ACCEPTED inputs land at positions at..at+n_emit-1.
-            wpos = at[:, None] + iota
-            H = hist.shape[1]
-            hpos = jnp.where(
-                (iota < n_emit[:, None]) & (wpos < H), wpos, H
-            )
-            hist = jax.vmap(
-                lambda h, p, v: h.at[p].set(v, mode="drop")
-            )(hist, hpos, inputs)
-            last = jnp.take_along_axis(
-                a, jnp.clip(n_emit - 1, 0, k)[:, None], axis=1
-            )[:, 0]
-            tok = jnp.where(n_emit > 0, last, tok)
-            at = at + n_emit
-            emitted = emitted + n_emit
-            act = act & ~eos_new & (emitted < budgets)
-        return (tok, at, eos_new, act, emitted, cache, hist), (
-            out_toks, n_emit
-        )
-
-    act0 = alive & ~eos & (budgets > 0)
-    (tok, at, eos, _, _, cache, hist), (toks, counts) = jax.lax.scan(
-        body,
-        (tok, at, eos, act0, jnp.zeros((B,), jnp.int32), cache, hist),
-        None,
-        length=n_steps,
-    )
-    # scan stacks leading: toks [n_steps, B, k+1] -> [B, n_steps, k+1].
-    return (
-        jnp.transpose(toks, (1, 0, 2)),
-        counts.T,
-        cache,
-        (tok, at, eos, hist),
-    )

@@ -171,18 +171,6 @@ class EngineConfig:
     # dispatch compute) shard over the ep mesh axis (DeepSeek-V3-class
     # scale-out). No effect on dense models.
     ep: int = 1
-    # Speculative decoding (prompt-lookup / n-gram drafting): draft this
-    # many tokens per decode iteration from the sequence's own history and
-    # verify them in one multi-position forward — 1..k+1 tokens per
-    # weight-streaming pass. Exact for greedy sampling (the agent-loop
-    # default); non-greedy batches fall back to the vanilla pipeline.
-    # Default 0 BY MEASUREMENT (PERF.md r04): on the trained-agent ReAct
-    # workload lookup drafts hit only ~6.6 % — replies share JSON keys
-    # with the prompt but the values are novel — so dispatches per token
-    # rise and speculation is a net loss there. Enable for workloads with
-    # genuinely repetitive continuations (templated YAML etc.).
-    speculative_k: int = 0
-    speculative_ngram: int = 2
     # Max admitting sequences prefilled per batched dispatch (scheduler
     # groups same-bucket chunks; rows pad to powers of two). Divides
     # per-session TTFT under concurrent admissions by up to this factor.
@@ -270,9 +258,10 @@ class EngineConfig:
     # l's compute; "xla" keeps the fused dequantize-in-operand-read path.
     # Default xla BY MEASUREMENT policy (same rule as the paged-attention
     # backend): the ragged-sweep bench covers the axis, and the default
-    # flips only on on-chip evidence. Resolved ONCE at engine init (like
-    # attn_impl): requires quantized weights and tp == 1, else the engine
-    # refuses to start (BackendRefused); what runs is in impl_info().
+    # flips only on on-chip evidence. Resolved ONCE at engine init (into
+    # Engine.kernels.weights): requires quantized weights and tp == 1, else
+    # the engine refuses to start (BackendRefused); what runs is in
+    # impl_info().
     weight_stream: str = ""
     # Grammar-accelerated decoding: when a constrained row's FSM state
     # admits exactly ONE legal token (JSON punctuation, known key names,
@@ -415,21 +404,18 @@ class Engine:
         # ambient depth is exactly one, no matter how call paths compose.
         self._mesh_tls = threading.local()
 
-        # Execution backends, resolved ONCE here, before anything is built
-        # on the device. The attention reader is the code's own choice
-        # from the platform and the model's shapes, and nothing outside
-        # the code can name one (ops.attention.paged_attention_backend:
-        # the streaming kernel on a TPU where it has a reader, else the
-        # gather); the weight stream is an explicit request (config field
-        # / env knob, default xla), and a weight stream asked for that
-        # cannot be honoured is an error with the reason (BackendRefused),
-        # never a quiet xla run under the kernel's name. impl_info()
-        # reports what runs.
-        from ..ops.attention import (
-            linear_state_backend, moe_experts_backend, moe_experts_scope,
-            pallas_interpret, pallas_refusal, paged_attention_backend,
-            ssm_state_backend,
-        )
+        # Which kernels run (``self.kernels``), resolved ONCE here, before
+        # anything is built on the device, and handed to every step program
+        # as one argument. The attention reader, the state kernel and the
+        # expert blocks are the code's own choice from the platform and the
+        # model's shapes, and nothing outside the code can name one
+        # (ops.kernels.choose_kernels: a kernel on a TPU where it has the
+        # shapes, else XLA); the weight stream is an explicit request
+        # (config field / env knob, default xla), and a weight stream asked
+        # for that cannot be honoured is an error with the reason
+        # (BackendRefused), never a quiet xla run under the kernel's name.
+        # impl_info() reports what runs.
+        from ..ops import kernels as kernel_rules
 
         ws = cfg.weight_stream or os.environ.get(
             "OPSAGENT_WEIGHT_STREAM", ""
@@ -451,62 +437,40 @@ class Engine:
             raise BackendRefused(
                 f"weight_stream=pallas-dma is single-shard only (tp={tp})"
             )
-        self.weight_stream_impl = ws
-        # What the reader of the pages is handed: heads of ``head_dim_``,
-        # or, where MLA holds the latent, its one row of ``page_dim``
-        # lanes that is keys and values alike under the absorbed queries.
-        mla = self.model_cfg.mla
-        latent = mla is not None and mla.latent_cache
-        shapes = dict(
-            head_dim=mla.page_dim if latent else self.model_cfg.head_dim_,
-            kv_heads_per_shard=1 if latent else max(
-                1, self.model_cfg.num_kv_heads // tp),
-            page_itemsize=(
-                1 if cfg.kv_quantize else jnp.dtype(cfg.dtype).itemsize
-            ),
-            mla=mla is not None, shared_kv=latent, tp=tp,
-        )
         platform = self.mesh.devices.flat[0].platform
-        self.attn_impl = paged_attention_backend(platform=platform, **shapes)
-        refused = pallas_refusal("pallas-stream", **shapes)
+        self.kernels = kernel_rules.choose_kernels(
+            self.model_cfg, platform=platform, tp=tp, ep=cfg.ep,
+            dtype=cfg.dtype, quantize=cfg.quantize,
+            kv_quantize=cfg.kv_quantize,
+            state_dtype=jnp.dtype(llama.STATE_DTYPE).name, weights=ws,
+        )
+        refused = kernel_rules.pallas_refusal(
+            "pallas-stream", **kernel_rules.reader_shapes(
+                self.model_cfg, tp=tp, dtype=cfg.dtype,
+                kv_quantize=cfg.kv_quantize))
         # The choice never answers the kernel where pallas_refusal has a
         # reason, so this raises only where a test has put the kernel in
         # the choice's place. Interpret mode (the CPU tests) has no Mosaic
         # and none of its tiling limits; pages the kernel has no reader
         # for (int8 under pallas-stream) stay refused there too.
-        if self.attn_impl == "pallas-stream" and refused and (
-            cfg.kv_quantize or not pallas_interpret()
+        if self.kernels.attn == "pallas-stream" and refused and (
+            cfg.kv_quantize or not kernel_rules.pallas_interpret()
         ):
             raise BackendRefused(refused)
         log.info(
             "paged attention reader: %s on %s%s, weight stream: %s (tp=%d%s)",
-            self.attn_impl, platform,
-            f" ({refused})" if self.attn_impl == "xla" and refused else "",
+            self.kernels.attn, platform,
+            f" ({refused})" if self.kernels.attn == "xla" and refused else "",
             ws, tp,
             ", shard_map over tp"
-            if self.attn_impl == "pallas-stream" and tp > 1
+            if self.kernels.attn == "pallas-stream" and tp > 1
             else "",
         )
 
-        # Who updates a linear layer's recurrent state: the code's choice
-        # too, made here so that the cache is held in the form it reads.
-        self.state_impl = "xla"
+        # The cache is made below in the form the state kernel chosen reads.
         if self.model_cfg.has_state:
-            la, mc = self.model_cfg.linear_attn, self.model_cfg.mamba
-            state_dtype = jnp.dtype(llama.STATE_DTYPE).name
-            if la is not None:
-                self.state_impl = linear_state_backend(
-                    platform=platform, state_dtype=state_dtype,
-                    key_dim=la.key_head_dim, value_dim=la.value_head_dim,
-                    heads=la.num_heads,
-                )
-            else:
-                self.state_impl = ssm_state_backend(
-                    platform=platform, state_dtype=state_dtype,
-                    d_state=mc.d_state, d_inner=mc.d_inner,
-                )
             log.info("%s state: %s", self.model_cfg.state_mixer,
-                     self.state_impl)
+                     self.kernels.state)
             # What carries a sequence between steps, tiers or replicas as a
             # page chain alone would serve this model without its
             # recurrent state: refuse it here, by name, instead.
@@ -515,9 +479,6 @@ class Engine:
             refused = {
                 f"tp={tp}": tp > 1,
                 "weight_stream=pallas-dma": ws == "pallas-dma",
-                f"speculative_k={cfg.speculative_k} (verify_step cannot "
-                "take a rejected draft back out of the state)":
-                    cfg.speculative_k > 0,
                 "offload=True (the host tier, fleet page transfer and "
                 "peer fault-in carry page chains only)": cfg.offload,
                 "sp > 1 ring prefill": cfg.sp > 1,
@@ -525,18 +486,8 @@ class Engine:
             for what, hit in refused.items():
                 if hit:
                     raise BackendRefused(f"{what} is not supported for {why}")
-        # Who runs an expert share's blocks: the code's choice as well;
-        # every step program reads it back at trace time (mesh_ctx).
-        self._moe_scope = moe_experts_scope
-        self.moe_impl = "xla"
-        if llama._expert_share(self.model_cfg):
-            self.moe_impl = moe_experts_backend(
-                platform=platform, quantize=cfg.quantize,
-                hidden_size=self.model_cfg.hidden_size,
-                expert_width=self.model_cfg.moe.expert_intermediate_size,
-                tp=tp, ep=cfg.ep,
-            )
-            log.info("expert share: %s", self.moe_impl)
+        if self.model_cfg.expert_share:
+            log.info("expert share: %s", self.kernels.experts)
         if cfg.kv_quantize and cfg.kv_quantize != "int8":
             raise ValueError(
                 f"kv_quantize={cfg.kv_quantize!r}: only 'int8' is supported"
@@ -630,7 +581,8 @@ class Engine:
         # (ops.attention.page_form); outside the step programs a page is
         # [P, K, D] whatever is held (``cache_wire``: the split cache's
         # shapes, which the host tier and the snapshot manifest use).
-        self.page_form = llama.cache_form(self.model_cfg, tp, self.attn_impl)
+        self.page_form = llama.cache_form(
+            self.model_cfg, tp, self.kernels.attn)
         # Recurrent-state slots of a model with linear-attention layers:
         # live ones and the snapshot pool, one device array (a restore is
         # one copy between slots).
@@ -643,7 +595,7 @@ class Engine:
             return llama.make_cache(
                 self.model_cfg, cfg.num_pages, cfg.page_size,
                 dtype=cfg.dtype, kv_quantize=cfg.kv_quantize, form=form,
-                state_slots=live + snaps, state_impl=self.state_impl,
+                state_slots=live + snaps, state_impl=self.kernels.state,
             )
 
         self.cache = jax.jit(
@@ -651,7 +603,7 @@ class Engine:
             out_shardings=spec_tree_shardings(
                 llama.cache_specs(
                     self.model_cfg, kv_quantize=cfg.kv_quantize,
-                    form=self.page_form, state_impl=self.state_impl,
+                    form=self.page_form, state_impl=self.kernels.state,
                 ),
                 self.mesh,
             ),
@@ -712,7 +664,7 @@ class Engine:
         # experts) stay on the XLA dequant inside the same program: say
         # how many take which path.
         self.weight_stream_leaves: dict[str, int] = {}
-        if self.weight_stream_impl == "pallas-dma":
+        if self.kernels.weights == "pallas-dma":
             self.weight_stream_leaves = llama.weight_stream_leaf_paths(
                 self.params
             )
@@ -725,7 +677,7 @@ class Engine:
         # host float math — nothing here is jitted or device-resident, so
         # the zero-post-warmup-compiles invariant is untouched.
         self.attr = obs.attribution.Attribution.for_engine(
-            self.model_cfg, cfg, weight_stream=self.weight_stream_impl
+            self.model_cfg, cfg, weight_stream=self.kernels.weights
         )
         obs.attribution.set_current(self.attr)
 
@@ -750,16 +702,21 @@ class Engine:
         else:
             prefill_attn = None
 
+        # The two prefill programs never streamed their weights through the
+        # kernel (it was compiled and compared at the step programs' shapes
+        # alone) and do not start to.
+        prefill_kernels = self.kernels._replace(weights="xla")
+
         def _prefill(params, tokens, lengths, cache, table):
             return llama.prefill(
                 params, mc, tokens, lengths, cache, table, dtype=dt,
-                prefill_attn=prefill_attn,
+                prefill_attn=prefill_attn, kernels=prefill_kernels,
             )
 
         def _prefill_prefix(params, tokens, start, lengths, cache, table):
             return llama.prefill_with_prefix(
                 params, mc, tokens, start, lengths, cache, table, dtype=dt,
-                attn_impl=self.attn_impl, mesh=self.mesh,
+                kernels=prefill_kernels, mesh=self.mesh,
             )
 
         def _decode_sample(
@@ -771,8 +728,7 @@ class Engine:
             OpenAI logit_bias and presence/frequency penalties."""
             logits, cache = llama.decode_step(
                 params, mc, tokens, lengths, cache, table, active, dtype=dt,
-                attn_impl=self.attn_impl, mesh=self.mesh,
-                weight_stream=self.weight_stream_impl,
+                kernels=self.kernels, mesh=self.mesh,
             )
             if bias is not None:
                 logits = logits + bias
@@ -791,8 +747,7 @@ class Engine:
             sampled from."""
             logits, cache = llama.decode_step(
                 params, mc, tokens, lengths, cache, table, active, dtype=dt,
-                attn_impl=self.attn_impl, mesh=self.mesh,
-                weight_stream=self.weight_stream_impl,
+                kernels=self.kernels, mesh=self.mesh,
             )
             if bias is not None:
                 logits = logits + bias
@@ -823,8 +778,7 @@ class Engine:
             sampled token discarded on host; q_len=0 rows are inert."""
             logits, cache = llama.mixed_step(
                 params, mc, tokens, starts, qlens, cache, table, dtype=dt,
-                attn_impl=self.attn_impl, mesh=self.mesh,
-                weight_stream=self.weight_stream_impl,
+                kernels=self.kernels, mesh=self.mesh,
                 step_tokens=self.step_tokens,
             )
             tok = sample(logits, key, temps, top_k, top_p, None)
@@ -849,9 +803,8 @@ class Engine:
                 n_steps=self.cfg.decode_block,
                 greedy=greedy,
                 dtype=dt,
-                attn_impl=self.attn_impl,
+                kernels=self.kernels,
                 mesh=self.mesh,
-                weight_stream=self.weight_stream_impl,
             )
 
         self._prefill_jit = jax.jit(_prefill, donate_argnames=("cache",))
@@ -883,8 +836,7 @@ class Engine:
             return mixed_step_carry(
                 params, mc, tokens, use_carry, carry_tok, starts, qlens,
                 emits, cache, table, key, temps, top_k, top_p,
-                dtype=dt, attn_impl=self.attn_impl, mesh=self.mesh,
-                weight_stream=self.weight_stream_impl,
+                dtype=dt, kernels=self.kernels, mesh=self.mesh,
                 fsm_mask=fsm_mask, fsm_dest=fsm_dest,
                 carry_fsm=carry_fsm, ov_fsm=ov_fsm,
                 step_tokens=self.step_tokens,
@@ -900,43 +852,8 @@ class Engine:
         )
         self._sample_jit = jax.jit(sample)
 
-        # Speculative decode pipeline (greedy batches, speculative_k > 0):
-        # scan steps sized so the worst case (everything accepted) emits
-        # exactly one decode_block of tokens per dispatch.
-        self._spec_steps = max(
-            1, cfg.decode_block // (cfg.speculative_k + 1)
-        )
-        self._hist = None  # device [B, H] token history for drafting
-        self._ov_hist_zeros = None  # cached all-zeros ov_hist (no overrides)
         self._bias_buf = None  # reused host [B, V] logit-bias batch buffer
         self._fsm_dev: dict = {}  # id(fsm) -> (fsm, device mask, device dest)
-
-        def _spec_pipeline(
-            params, carry_tok, carry_at, carry_eos, carry_hist,
-            override, ov_tok, ov_at, ov_hist, alive, budgets, cache, table,
-        ):
-            from .decode_loop import speculative_block_carry
-
-            return speculative_block_carry(
-                params, mc, carry_tok, carry_at, carry_eos, carry_hist,
-                override, ov_tok, ov_at, ov_hist, alive, budgets, cache,
-                table,
-                jnp.int32(self.tokenizer.eos_id),
-                jnp.int32(self.tokenizer.pad_id),
-                n_steps=self._spec_steps,
-                k=cfg.speculative_k,
-                ngram=cfg.speculative_ngram,
-                dtype=dt,
-                attn_impl=self.attn_impl,
-                mesh=self.mesh,
-            )
-
-        self._spec_pipeline_jit = jax.jit(
-            _spec_pipeline,
-            donate_argnames=(
-                "cache", "carry_tok", "carry_at", "carry_eos", "carry_hist"
-            ),
-        )
 
         # -- pipelined decode state (see step_block) -------------------------
         B = cfg.max_batch_size
@@ -994,14 +911,11 @@ class Engine:
     # pay for those.
     WARMUP_LEVELS: dict = {
         "bench": frozenset({"prefill", "sample", "decode_greedy"}),
-        "bench-spec": frozenset(
-            {"prefill", "sample", "decode_greedy", "spec"}
-        ),
         # The ragged-backend sweep drives the engine through sync
         # step_mixed only (admission chunks AND decode ticks both ride
         # the mixed program), so it needs exactly the mixed family — one
         # compile per mixed bucket, tracing through the RESOLVED
-        # attn_impl, which is how each sweep cell's kernel gets compiled
+        # kernels, which is how each sweep cell's kernel gets compiled
         # before the timed window. Paying for the prefill/decode-block
         # cross-product per sweep cell would blow the stage budget.
         "bench-mixed": frozenset({"mixed"}),
@@ -1025,7 +939,7 @@ class Engine:
         "full": frozenset({
             "prefill", "prefill_prefix", "prefill_batched", "sample",
             "decode_single", "logprobs", "decode_greedy", "decode_sampled",
-            "fsm", "spec", "mixed", "mixed_async", "ffwd", "offload",
+            "fsm", "mixed", "mixed_async", "ffwd", "offload",
         }),
     }
 
@@ -1055,7 +969,7 @@ class Engine:
             return
         self._mesh_tls.active = True
         try:
-            with self.mesh, self._moe_scope(self.moe_impl):
+            with self.mesh:
                 yield
         finally:
             self._mesh_tls.active = False
@@ -1076,8 +990,8 @@ class Engine:
             "device_count": len(jax.devices()),
             "mesh": {k: v for k, v in self.mesh.shape.items() if v > 1},
             "dtype": jnp.dtype(self.cfg.dtype).name,
-            "attn_impl": self.attn_impl,
-            "weight_stream": self.weight_stream_impl,
+            "attn_impl": self.kernels.attn,
+            "weight_stream": self.kernels.weights,
             "quantize": self.cfg.quantize or "none",
             "kv_quantize": self.cfg.kv_quantize or "none",
             "kv_page_form": self.page_form,
@@ -1097,11 +1011,11 @@ class Engine:
         }
         if self.weight_stream_leaves:
             info["weight_stream_leaves"] = dict(self.weight_stream_leaves)
-        if llama._expert_share(self.model_cfg):
-            info["moe_impl"] = self.moe_impl
+        if self.model_cfg.expert_share:
+            info["moe_impl"] = self.kernels.experts
         if self.model_cfg.has_state:
             state = self.cache["state"]
-            info["state_impl"] = self.state_impl
+            info["state_impl"] = self.kernels.state
             info["state_dtype"] = state.dtype.name
             info["state_slots"] = self.alloc.state_slots
             info["state_snapshots"] = self.alloc.state_snapshots
@@ -1157,9 +1071,9 @@ class Engine:
         buffer is consumed, and ``self.cache`` is untouched.
 
         Only the straight-line program families are listed. The
-        carry-chained variants (mixed_async, ffwd, pipeline second call,
-        spec) take device OUTPUTS as inputs — their argument shardings
-        only exist after the first dispatch — so they stay sequential.
+        carry-chained variants (mixed_async, ffwd, pipeline second call)
+        take device OUTPUTS as inputs — their argument shardings only
+        exist after the first dispatch — so they stay sequential.
         """
         B = self.cfg.max_batch_size
         MaxP = self.alloc.table_width
@@ -1557,25 +1471,7 @@ class Engine:
             # ... and the copy a /metrics scrape reads the expert share's
             # accumulators from.
             self.sync_device_counters()
-            if "spec" in progs and self.cfg.speculative_k > 0:
-                H = self.cfg.max_pages_per_seq * self.cfg.page_size
-                ov_hist = jnp.zeros((B, H), jnp.int32)
-                carry_s = (
-                    jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
-                    jnp.zeros((B,), bool), jnp.zeros((B, H), jnp.int32),
-                )
-                for _ in range(2):
-                    c_tok, c_at, c_eos, c_hist = carry_s
-                    toks, _, self.cache, carry_out = self._spec_pipeline_jit(
-                        self.params,
-                        c_tok, c_at, c_eos, c_hist,
-                        jnp.zeros((B,), bool), zi, zi,
-                        ov_hist, inactive, zi,
-                        self.cache, dropB,
-                    )
-                    carry_s = carry_out
             self._carry = None  # warmup carries are throwaways
-            self._hist = None
             # A real device->host pull: on async backends block_until_ready
             # returns immediately, and the point of warmup is that the
             # FIRST request finds an idle, fully-compiled device.
@@ -2226,7 +2122,7 @@ class Engine:
             back < np.asarray(passes_of, np.int64)[:, None],
             np.asarray(ctx, np.int64)[:, None] - back, 0)
         obs.ATTN_CONTEXT_TOKENS.inc(int(live.sum()), what="live")
-        if self.attn_impl == "xla":     # every row's whole table, each pass
+        if self.kernels.attn == "xla":  # every row's whole table, each pass
             read = (self.cfg.max_batch_size * self.cfg.max_pages_per_seq
                     * P * passes)
         else:                           # the live rows' pages
@@ -2239,7 +2135,7 @@ class Engine:
         ``computed`` slots (rows x slots a pass) the program's scan walks
         under XLA; the scan kernel walks a row's own tokens and no more."""
         if self._scan_layers:
-            if self.state_impl == "pallas-ssm":
+            if self.kernels.state == "pallas-ssm":
                 computed = real
             obs.SSM_SCAN_STEPS.inc(real * self._scan_layers, kind="real")
             obs.SSM_SCAN_STEPS.inc(
@@ -3133,7 +3029,6 @@ class Engine:
         self._lanes = [None] * self.cfg.max_batch_size
         self._lane_of.clear()
         self._carry = None
-        self._hist = None
 
     def _async_settle(self) -> None:
         """Commit every in-flight ASYNC mixed tick (results buffered for
@@ -3238,20 +3133,17 @@ class Engine:
         round trip per dispatch) and fold them into host state. Records are
         pulled FIFO, so the host always sees a row's EOS before any of its
         later pad-only blocks."""
-        toks_d, lane_seqs, budgets, counts_d, ticket, program = (
+        toks_d, lane_seqs, budgets, ticket, program = (
             self._inflight.popleft()
         )
         # alone: no younger block is enqueued behind the one pulled
         toks = self._pull(
             *program, ticket, toks_d, alone=not self._inflight)
-        counts = None if counts_d is None else np.asarray(counts_d)
         with self._accepting(tick=ticket[0]):
-            return self._commit_block(
-                toks, counts, lane_seqs, budgets, ticket
-            )
+            return self._commit_block(toks, lane_seqs, budgets, ticket)
 
     def _commit_block(
-        self, toks, counts, lane_seqs, budgets, ticket
+        self, toks, lane_seqs, budgets, ticket
     ) -> dict[int, list[int]]:
         """Fold one pulled decode block into host state (the commit phase:
         accept, stop scan, detokenize, stream, roll bookings back)."""
@@ -3277,33 +3169,10 @@ class Engine:
             # to the span that was live while the block ran.
             dspan = s.decode_span
             try:
-                if counts is None:
-                    for j in range(int(budgets[lane])):
-                        self._accept_token(s, int(toks[lane, j]))
-                        if s.done:
-                            break
-                else:
-                    # Speculative block: toks is [B, n_steps, k+1] with an
-                    # explicit accepted count per scan step (pads within a
-                    # step are rejection holes, not end-of-output).
-                    # Accept-rate observability: each LIVE verify step
-                    # (count > 0) emitted 1 corrected/bonus token plus its
-                    # accepted drafts, so mean(spec_step_tokens) - 1 over k
-                    # IS the draft accept rate on this workload. Recorded
-                    # per step BEFORE the done-break so post-EOS steps
-                    # (drafting from dead context) cannot drag the mean.
-                    for st in range(counts.shape[1]):
-                        c = int(counts[lane, st])
-                        if c > 0 and not s.done:
-                            perf.record_metric(
-                                "engine.spec_step_tokens", float(c), "tok"
-                            )
-                        for j in range(c):
-                            self._accept_token(s, int(toks[lane, st, j]))
-                            if s.done:
-                                break
-                        if s.done:
-                            break
+                for j in range(int(budgets[lane])):
+                    self._accept_token(s, int(toks[lane, j]))
+                    if s.done:
+                        break
             except Exception as e:  # noqa: BLE001 - raising stream callback
                 if first_exc is None:
                     first_exc = e
@@ -3331,21 +3200,6 @@ class Engine:
                     # ones, so reuse is safe without draining.
                     self.alloc.truncate(sid, self._host_written(s))
                     self._free_lane(sid)
-                elif counts is not None:
-                    # Speculative rows emit <= their booking (draft misses),
-                    # so unspent booking would drift the allocator length
-                    # ahead of content without bound, truncating long
-                    # generations early. Roll back to what in-flight
-                    # dispatches can still touch: written content + their
-                    # bookings + the draft-overhang slack (k+1 positions a
-                    # verify step writes past its accepted count).
-                    keep = (
-                        self._host_written(s)
-                        + self._inflight_steps.get(sid, 0)
-                        + self.cfg.speculative_k + 1
-                    )
-                    if self.alloc.length(sid) > keep:
-                        self.alloc.truncate(sid, keep)
         t0 = time.perf_counter()
         perf.record_metric("engine.decode_tokens", produced, "tok")
         self._observe_occupancy()
@@ -3750,9 +3604,9 @@ class Engine:
                 temps, top_k, top_p, _ = self._sampling_arrays(slots, B)
                 greedy = bool(np.all(temps <= 0.0))
                 # A constrained row that failed to get a lane (batch full) must
-                # not force FSM tables (and disable speculation) on a dispatch
-                # where no SEATED row is constrained — it isn't advancing
-                # anyway. Re-derive from what actually seated.
+                # not force FSM tables on a dispatch where no SEATED row is
+                # constrained — it isn't advancing anyway. Re-derive from what
+                # actually seated.
                 if fsm_obj is not None and not any(
                     isinstance(
                         getattr(self.sequences.get(sid), "mask_fn", None),
@@ -3784,38 +3638,10 @@ class Engine:
                         )
                 c_tok, c_at, c_eos, c_fsm, c_key = self._carry
                 perf = get_perf_stats()
-                speculate = (
-                    self.cfg.speculative_k > 0 and greedy and fsm_obj is None
-                )
-                counts = None
                 if fsm_obj is not None:
                     fsm_mask_d, fsm_dest_d = self._fsm_device_tables(fsm_obj)
                 else:
                     fsm_mask_d = fsm_dest_d = None
-                if speculate:
-                    # Host history for newly seated lanes, prepared OUTSIDE the
-                    # dispatch timing block. Drafting is advisory (a stale row
-                    # only costs draft quality), and the common all-False
-                    # override case reuses one cached device-resident zeros
-                    # array instead of transferring B x H zeros per block.
-                    H = self.cfg.max_pages_per_seq * self.cfg.page_size
-                    if self._hist is None:
-                        self._hist = jnp.zeros((B, H), jnp.int32)
-                    if override.any():
-                        ov_hist = np.zeros((B, H), np.int32)
-                        for lane, flag in enumerate(override):
-                            if not flag:
-                                continue
-                            s = self.sequences.get(self._lanes[lane])
-                            if s is None:
-                                continue
-                            ids_h = (s.prompt_ids + s.tokens)[:H]
-                            ov_hist[lane, : len(ids_h)] = ids_h
-                        ov_hist_dev = jnp.asarray(ov_hist)
-                    else:
-                        if self._ov_hist_zeros is None:
-                            self._ov_hist_zeros = jnp.zeros((B, H), jnp.int32)
-                        ov_hist_dev = self._ov_hist_zeros
             ticket = self.step_clock.enqueue()
             tick_id, t_disp, _ = ticket
             with obs.phase("dispatch", tick=tick_id), self.mesh_ctx():
@@ -3824,60 +3650,35 @@ class Engine:
                      table_d) = (
                         jnp.asarray(a) for a in (
                             override, ov_tok, ov_at, alive, budgets, table))
-                    if not speculate:
-                        temps_d, top_k_d, top_p_d, ov_fsm_d = (
-                            jnp.asarray(a)
-                            for a in (temps, top_k, top_p, ov_fsm))
+                    temps_d, top_k_d, top_p_d, ov_fsm_d = (
+                        jnp.asarray(a)
+                        for a in (temps, top_k, top_p, ov_fsm))
                 with obs.phase("dispatch", part="call"), \
                         annotate("engine.decode_block"):
-                    if speculate:
-                        toks, counts, self.cache, carry = (
-                            self._spec_pipeline_jit(
-                                self.params,
-                                c_tok, c_at, c_eos, self._hist,
-                                override_d,
-                                ov_tok_d,
-                                ov_at_d,
-                                ov_hist_dev,
-                                alive_d,
-                                budgets_d,
-                                self.cache,
-                                table_d,
-                            )
-                        )
-                        n_tok, n_at, n_eos, self._hist = carry
-                        self._carry = (n_tok, n_at, n_eos, c_fsm, c_key)
-                    else:
-                        toks, self.cache, carry = self._decode_pipeline_jit(
-                            self.params,
-                            c_tok, c_at, c_eos, c_key,
-                            override_d,
-                            ov_tok_d,
-                            ov_at_d,
-                            alive_d,
-                            budgets_d,
-                            self.cache,
-                            table_d,
-                            temps_d,
-                            top_k_d,
-                            top_p_d,
-                            greedy=greedy,
-                            fsm_mask=fsm_mask_d,
-                            fsm_dest=fsm_dest_d,
-                            carry_fsm=c_fsm,
-                            ov_fsm=ov_fsm_d,
-                        )
-                        n_tok, n_at, n_eos, n_fsm, n_key = carry
-                        self._carry = (n_tok, n_at, n_eos, n_fsm, n_key)
+                    toks, self.cache, self._carry = self._decode_pipeline_jit(
+                        self.params,
+                        c_tok, c_at, c_eos, c_key,
+                        override_d,
+                        ov_tok_d,
+                        ov_at_d,
+                        alive_d,
+                        budgets_d,
+                        self.cache,
+                        table_d,
+                        temps_d,
+                        top_k_d,
+                        top_p_d,
+                        greedy=greedy,
+                        fsm_mask=fsm_mask_d,
+                        fsm_dest=fsm_dest_d,
+                        carry_fsm=c_fsm,
+                        ov_fsm=ov_fsm_d,
+                    )
             with obs.phase("plan", part="account"):
                 perf.record_metric(
                     "engine.block_dispatch",
                     (time.perf_counter() - t_disp) * 1e3, "ms",
                 )
-                if speculate:
-                    # Observability for the speculative path (also the
-                    # signal tests use to prove speculation engaged).
-                    perf.record_metric("engine.spec_blocks", 1, "blk")
                 from .decode_loop import record_dispatch
 
                 # Attribution: each budgeted lane writes `b` tokens, step
@@ -3894,15 +3695,12 @@ class Engine:
                     attr_q += b
                     attr_read += b * s0 + b * (b + 1) // 2
                 record_dispatch(
-                    "spec" if speculate else "block",
+                    "block",
                     rows=int(np.count_nonzero(budgets)),
                     steps=int(budgets.max()),
                     attr=self.attr,
                     attr_kw=dict(
-                        weight_streams=(
-                            self._spec_steps if speculate
-                            else self.cfg.decode_block
-                        ),
+                        weight_streams=self.cfg.decode_block,
                         q_tokens=attr_q,
                         kv_read_tokens=attr_read,
                         kv_write_tokens=attr_q,
@@ -3910,16 +3708,14 @@ class Engine:
                     ),
                 )
                 obs.flight.record(
-                    "dispatch", op="spec" if speculate else "decode_block",
+                    "dispatch", op="decode_block",
                     seq_ids=[sid for sid, b in zip(lane_seqs, budgets)
                              if sid is not None and b],
                     steps=int(budgets.max()), tick=tick_id,
                 )
             with obs.phase("plan", part="book"):
                 self._inflight.append((
-                    toks, lane_seqs, budgets, counts, ticket,
-                    ("spec", self._spec_steps) if speculate
-                    else ("decode_block", block),
+                    toks, lane_seqs, budgets, ticket, ("decode_block", block),
                 ))
                 for sid, b in zip(lane_seqs, budgets):
                     if sid is not None and b:

@@ -814,7 +814,7 @@ class Scheduler:
         # its host-side state (allocator, sequences) in case the rebuild
         # fails — admission is host-only, so queued work survives.
         for buf in (
-            "params", "cache", "_carry", "_hist",
+            "params", "cache", "_carry",
             "_async_carry", "_async_fsm_carry",
         ):
             try:

@@ -55,7 +55,7 @@ log = get_logger("snapshot")
 # regardless of where its weights originally came from.
 _ENGINE_FINGERPRINT_FIELDS = (
     "model", "tokenizer", "tp", "dp", "sp", "ep",
-    "speculative_k", "speculative_ngram", "prefill_batch",
+    "prefill_batch",
     "page_size", "num_pages", "max_pages_per_seq", "max_batch_size",
     "decode_block", "pipeline_depth", "prefill_buckets",
     "mixed_batching", "max_step_tokens", "mixed_buckets", "async_depth",

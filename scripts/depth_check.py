@@ -7,17 +7,20 @@ logits leave the float32 reference's?
         [--tokens 2048] [--depths 1,2,3,6,12]
 
 For each depth ``L`` the first ``L`` layers of the configuration are served
-in its stated precision through ``llama.verify_step`` (one sequence through
-paged attention over a fresh cache: for an MLA model the absorbed form over
-latent pages) and through the family's reference (float32, ``highest``),
-and one line says how far the logits are apart: the share of positions
-whose first choice agrees, the gap ``check.py`` compares, and the RMS error
-over the logits' spread. A model with an expert layer is read a second time
+in its stated precision through ``llama.prefill_with_prefix`` (one sequence
+through paged attention over a fresh cache: for an MLA model the absorbed
+form over latent pages; it gives the logits of a row's last position, so
+the one compiled program is run at ``POSITIONS`` lengths evenly spaced up
+to ``--tokens``) and through the family's reference (float32, ``highest``),
+and one line says how far the logits are apart at those positions: the
+share whose first choice agrees, the gap ``check.py`` compares, and the RMS
+error over the logits' spread. A model with an expert layer is read a second time
 with its router's matmul at ``highest``. PR 40 used it to tell an attention
 fault (none: 0.5 % at the dense layer) from near-tied experts that flip
-under bfloat16 (3 % a layer of experts at the plain fan-in scale). It
-refuses a model with recurrent state (``verify_step`` does). ``--rehearse``
-walks it at the rehearsal sizes on any backend.
+under bfloat16 (3 % a layer of experts at the plain fan-in scale), through
+the step function the program then had for all positions at once. It
+refuses a model with recurrent state (it makes no state slots).
+``--rehearse`` walks it at the rehearsal sizes on any backend.
 """
 import argparse, functools, json, os, sys, time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -35,8 +38,12 @@ ap.add_argument("--seed", type=int, default=4000000101)
 ap.add_argument("--rehearse", action="store_true")
 args = ap.parse_args()
 T, SEED = args.tokens, args.seed
+POSITIONS = 32
+ENDS = np.unique(np.linspace(1, T, min(POSITIONS, T)).astype(np.int32))   # lengths; the position read is ENDS - 1
 config = load_data(os.path.join(ROOT, args.config), rehearse=args.rehearse)
 family = load_family(config); ref = load_module("reference", config["reference"])
+if family.model_config(config).has_state:
+    sys.exit("depth_check: a model with recurrent state is not served here")
 tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(3), (T,), 0, config["vocab_size"]))
 
 def reference(cfg):
@@ -55,7 +62,7 @@ def reference(cfg):
     q, scale = W.matrix(root, family.LEAF_NO["lm_head"], 0, sz["d"], sz["v"])
     norm = W.norm(root, family.LEAF_NO["final_norm"], 0, sz["d"]).astype(jnp.float32)
     head = jax.jit(lambda x: ref.logits(x, norm, W.dequantize(q, scale), cfg["rms_norm_eps"]))
-    return {L: np.asarray(head(x)) for L, x in outs.items()}
+    return {L: np.asarray(head(x[ENDS - 1])) for L, x in outs.items()}
 
 def program(cfg, precise_router=False):
     dtype = jnp.dtype(cfg["engine"]["dtype"])
@@ -71,9 +78,10 @@ def program(cfg, precise_router=False):
                 return orig(h, lp, c)
         llama._route = route
     try:
-        f = jax.jit(lambda p, t, c: llama.verify_step(
-            p, mc, t, jnp.zeros((1,), jnp.int32), jnp.full((1,), T, jnp.int32), c, table, dtype=dtype)[0])
-        return np.asarray(f(params, jnp.asarray(tokens[None]), cache)[0], np.float32)
+        f = jax.jit(lambda p, t, n, c: llama.prefill_with_prefix(
+            p, mc, t, jnp.zeros((1,), jnp.int32), n[None], c, table, dtype=dtype)[0])
+        toks = jnp.asarray(tokens[None])
+        return np.stack([np.asarray(f(params, toks, jnp.int32(n), cache)[0], np.float32) for n in ENDS])
     finally:
         llama._route = orig
 

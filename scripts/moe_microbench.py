@@ -36,7 +36,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from opsagent_tpu.models import llama  # noqa: E402
 from opsagent_tpu.models.config import get_config_preset  # noqa: E402
-from opsagent_tpu.ops import attention  # noqa: E402
 from opsagent_tpu.ops import moe_experts_pallas as grouped  # noqa: E402
 
 LAYERS, REPS = 4, 20
@@ -99,14 +98,13 @@ def run(impl: str, cfg, layers, h):
         first = None
         for i, idx in where:
             y, _ = llama._moe_share(
-                h, llama._LayerView(stacks[i], idx, True), cfg, None)
+                h, llama._LayerView(stacks[i], idx, True), cfg, None, impl)
             first = y if first is None else first
             h = h + y
         return first, h
 
-    with attention.moe_experts_scope(impl):
-        fn = jax.jit(step)
-        first, _ = jax.block_until_ready(fn(h, stacks))
+    fn = jax.jit(step)
+    first, _ = jax.block_until_ready(fn(h, stacks))
     times = []
     for _ in range(REPS):
         t0 = time.perf_counter()

@@ -105,23 +105,24 @@ def stream_kernel():
     materialised heads, no latent under tp > 1) but for Mosaic's lane
     tiling, which interpret mode has not, so the tiny presets' head dims
     of 16 and tiny latent rows pass; outside it the same test builds the
-    gather's engine to compare with. The engine imports the choice
-    function inside ``__init__``, so patching the module's attribute
-    reaches it, and interpret mode is read when a step program is traced.
+    gather's engine to compare with. ``choose_kernels`` looks the rule up
+    in its module when an engine is built, so patching the module's
+    attribute reaches it, and interpret mode is read when a step program
+    is traced.
     Nothing in the program can name a reader."""
     import contextlib
 
-    from opsagent_tpu.ops import attention
+    from opsagent_tpu.ops import kernels
 
     def choice(*, platform, head_dim, **shapes):
-        refused = attention.pallas_refusal(
+        refused = kernels.pallas_refusal(
             "pallas-stream", head_dim=128, **shapes)
         return "xla" if refused else "pallas-stream"
 
     @contextlib.contextmanager
     def under():
         with pytest.MonkeyPatch.context() as mp:
-            mp.setattr(attention, "paged_attention_backend", choice)
+            mp.setattr(kernels, "paged_attention_backend", choice)
             mp.setenv("OPSAGENT_PALLAS_INTERPRET", "1")
             yield
 
@@ -270,7 +271,6 @@ SLOW_TESTS = (
     "test_engine.py::test_warmup_compiles_without_disturbing_state",
     "test_serving_api.py::test_tpu_scheme_lazy_registration_fresh_process",
     "test_constrained.py::TestEngineWiring::test_response_format_constrains",
-    "test_speculative.py::test_speculative_matches_vanilla_greedy",
     "test_moe.py::test_sharded_moe_training_step",
     "test_ring_attention.py::test_ring_gradients_flow",
     "test_tool_choice.py::test_required_constrains_to_listed_tools",

@@ -634,7 +634,7 @@ def test_the_attention_backend_is_chosen_from_platform_and_shapes(
     """No knob: the choice resolves from what the engine can observe
     where it is built. The streaming kernel on a TPU wherever the chip's
     compiler takes it, the gather everywhere else."""
-    from opsagent_tpu.ops.attention import (
+    from opsagent_tpu.ops.kernels import (
         paged_attention_backend, pallas_refusal,
     )
 
@@ -650,7 +650,7 @@ def test_the_choice_is_a_pure_function_whatever_the_environment_names(
     """The variable that once named a backend outright is read by nothing:
     set to a reader, to a kernel that is gone or to nonsense, the choice
     is what it is without it, and nothing is raised."""
-    from opsagent_tpu.ops.attention import (
+    from opsagent_tpu.ops.kernels import (
         PAGED_BACKENDS, paged_attention_backend,
     )
 
@@ -802,21 +802,11 @@ def _run_cached_prefix(eng):
     return first + second
 
 
-def _run_speculative(eng):
-    """Prompt-lookup speculation: ``verify_step`` scores k drafts and the
-    token before them in one ragged pass (k + 1 query slots a row); exact
-    for greedy, so it generates what plain decoding does."""
-    return eng.generate(
-        [[7, 8, 9, 7, 8, 9, 7, 8]], SamplingParams(max_tokens=10)
-    )
-
-
 @pytest.mark.parametrize("run,cfg", [
     pytest.param(_run_mixed_async, dict(async_depth=2), id="mixed-async"),
     pytest.param(_run_blocks, {}, id="blocks"),
     pytest.param(_run_single_steps, {}, id="single-steps"),
     pytest.param(_run_cached_prefix, {}, id="cached-prefix"),
-    pytest.param(_run_speculative, dict(speculative_k=3), id="speculative"),
 ])
 def test_the_kernels_engine_generates_what_the_gathers_does(
     stream_kernel, run, cfg
@@ -880,9 +870,9 @@ def test_what_the_kernel_cannot_read_on_a_tpu_goes_to_the_gather_and_the_log_say
     that names the reader carries the refusal's reason."""
     import logging
 
-    from opsagent_tpu.ops import attention
+    from opsagent_tpu.ops import kernels
 
-    choice = attention.paged_attention_backend
+    choice = kernels.paged_attention_backend
     asked, lines = [], []
     # The program's loggers do not propagate to the root that caplog reads.
     handler = logging.Handler(logging.INFO)
@@ -893,14 +883,14 @@ def test_what_the_kernel_cannot_read_on_a_tpu_goes_to_the_gather_and_the_log_say
         asked.append(shapes)
         return choice(platform="tpu", **shapes)
 
-    monkeypatch.setattr(attention, "paged_attention_backend", on_a_tpu)
+    monkeypatch.setattr(kernels, "paged_attention_backend", on_a_tpu)
     logger.addHandler(handler)
     try:
         eng = Engine(EngineConfig(**cfg, **dict(SMALL, dtype=jnp.bfloat16)))
     finally:
         logger.removeHandler(handler)
     assert eng.impl_info()["attn_impl"] == "xla"
-    why = attention.pallas_refusal("pallas-stream", **asked[0])
+    why = kernels.pallas_refusal("pallas-stream", **asked[0])
     assert words in why
     line = next(x for x in lines if "paged attention reader" in x)
     assert "reader: xla" in line and why in line

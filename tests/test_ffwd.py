@@ -399,7 +399,7 @@ def test_ffwd_readers_byte_identical_and_int8_kv_compiles_nothing(
         eng = Engine(EngineConfig(
             grammar_ffwd=True, async_depth=2, **kw, **BASE
         ))
-        assert eng.attn_impl == impl
+        assert eng.kernels.attn == impl
         eng.warmup("full")
         con = json_constraint(eng.tokenizer, TOOLPROMPT_SCHEMA)
         sid = eng.begin_request(

@@ -424,7 +424,14 @@ class TestOverload:
                 "messages": [{"role": "user", "content": "operator"}],
                 "max_tokens": 4, "temperature": 0,
             }, force_replica="r0")
-            assert resp["choices"][0]["message"]["content"]
+            # past the shed, on the replica named, to its end (what a
+            # random model's 4 tokens decode to is not the router's)
+            assert resp["fleet"]["replica"] == "r0"
+            assert resp["choices"][0]["finish_reason"] in ("stop", "length")
+            assert 1 <= resp["usage"]["completion_tokens"] <= 4
+            assert obs.FLEET_REQUESTS.value(outcome="completed") == 1
+            assert obs.FLEET_REQUESTS.value(outcome="shed") == 0
+            assert not _flight("request_shed")
         finally:
             _close(stacks)
 

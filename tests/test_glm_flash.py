@@ -240,11 +240,13 @@ def test_a_fused_decode_block_serves_the_references_first_choices(tiny, model):
         params, mc, jnp.asarray(prompt[None]), jnp.asarray([6]), cache,
         table[:1], dtype=jnp.float32)
     first = int(jnp.argmax(logits[0]))
-    toks, cache, _ = decode_loop.decode_block(
-        params, mc, jnp.asarray([first, 0]), jnp.asarray([6, 0]),
-        jnp.asarray([True, False]), jnp.asarray([8, 0]), cache, table,
-        jax.random.PRNGKey(0), jnp.zeros((2,)), jnp.zeros((2,), jnp.int32),
-        jnp.ones((2,)), jnp.int32(-1), jnp.int32(0), n_steps=8, greedy=True,
+    tok, at = jnp.asarray([first, 0]), jnp.asarray([6, 0])
+    active = jnp.asarray([True, False])
+    toks, cache, _ = decode_loop.decode_block_carry(    # every lane seated anew
+        params, mc, tok, at, jnp.zeros_like(active), jax.random.PRNGKey(0),
+        jnp.ones_like(active), tok, at, active, jnp.asarray([8, 0]), cache,
+        table, jnp.zeros((2,)), jnp.zeros((2,), jnp.int32), jnp.ones((2,)),
+        jnp.int32(-1), jnp.int32(0), n_steps=8, greedy=True,
         dtype=jnp.float32)
     served = [first, *np.asarray(toks[0]).tolist()]
     want = reference_logits(tiny, model, [*prompt, *served[:-1]])

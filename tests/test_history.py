@@ -503,8 +503,11 @@ class TestTailRetention:
             monkeypatch.setenv("OPSAGENT_SLO_TTFT_MS", "60000")
 
             # Phase 2b — mid-stream failover: the journey is marked
-            # anomalous on the resume path.
-            faults.configure("fleet.stream_disconnect@5")
+            # anomalous on the resume path. The SECOND chunk pull dies:
+            # every stream has a role chunk and a final one, while how many
+            # lie between is the random model's (its 12 tokens decode to
+            # one or two printable deltas; a later pull may never come).
+            faults.configure("fleet.stream_disconnect@2")
             try:
                 chunks = list(router.complete_stream({
                     "messages": [

@@ -126,7 +126,6 @@ def engine_cfg(**kw) -> EngineConfig:
 
 @pytest.mark.parametrize("kw,said", [
     ({"tp": 2}, "tp=2"),
-    ({"speculative_k": 2}, "speculative_k=2"),
     ({"offload": True}, "offload=True"),
     ({"weight_stream": "pallas-dma", "quantize": "int8"}, "pallas-dma"),
 ])
@@ -170,12 +169,6 @@ def test_the_snapshot_writer_and_the_page_store_are_refused(engine, tmp_path):
     with pytest.raises(BackendRefused, match="fleet page store"):
         engine.pagestore = object()
     engine.pagestore = None
-
-
-def test_verify_step_refuses_a_recurrent_state(engine):
-    with pytest.raises(ValueError, match="verify_step"):
-        llama.verify_step(engine.params, engine.model_cfg, None, None, None,
-                          None, None)
 
 
 def test_the_loader_refuses_a_solar_open2_checkpoint(tmp_path):

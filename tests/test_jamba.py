@@ -11,7 +11,7 @@ last axis, the convolution continued from a tail against one pass over the
 whole sequence, attention over pages against one block), so they differ by
 rounding alone: logits of magnitude ~4 agree to 2e-4 absolute (measured
 1.4e-5 at the worst; some ten times that, the tolerance of
-``tests/test_hybrid_state.py``). A recurrent state held in bfloat16 between
+``tests/hybrid_state_common.py``). A recurrent state held in bfloat16 between
 steps errs by more than ten times the tolerance after a few dozen tokens
 (asserted below): the comparison would catch it.
 """
@@ -48,7 +48,7 @@ def highest():
 def release_compiled_programs():
     """Drop JAX's in-process caches of compiled programs once the process
     holds more than two fifths of the memory mappings it may have
-    (``tests/test_hybrid_state.py`` has the reason)."""
+    (``tests/hybrid_state_common.py`` has the reason)."""
     yield
     try:
         with open("/proc/sys/vm/max_map_count") as f:
@@ -443,11 +443,13 @@ def test_a_fused_decode_block_is_the_single_steps(params, tokens, truth):
             jnp.asarray([21 + i, 0]), single, table,
             jnp.asarray([True, False]), dtype=jnp.float32)
         served.append(int(jnp.argmax(lg[0])))
-    toks, block, _ = decode_loop.decode_block(
-        params, CFG, jnp.asarray([first, 0]), jnp.asarray([21, 0]),
-        jnp.asarray([True, False]), jnp.asarray([8, 0]), cache, table,
-        jax.random.PRNGKey(0), jnp.zeros((2,)), jnp.zeros((2,), jnp.int32),
-        jnp.ones((2,)), jnp.int32(-1), jnp.int32(0), n_steps=8, greedy=True,
+    tok, at = jnp.asarray([first, 0]), jnp.asarray([21, 0])
+    active = jnp.asarray([True, False])
+    toks, block, _ = decode_loop.decode_block_carry(    # every lane seated anew
+        params, CFG, tok, at, jnp.zeros_like(active), jax.random.PRNGKey(0),
+        jnp.ones_like(active), tok, at, active, jnp.asarray([8, 0]), cache,
+        table, jnp.zeros((2,)), jnp.zeros((2,), jnp.int32), jnp.ones((2,)),
+        jnp.int32(-1), jnp.int32(0), n_steps=8, greedy=True,
         dtype=jnp.float32)
     assert np.asarray(toks[0]).tolist() == served[1:]
     for part in ("state", "conv"):
@@ -513,7 +515,6 @@ def test_two_turns_through_the_engine_are_the_references_choice():
     # (tp=2 never reaches the refusal: one kv head does not divide over two
     # shards, and the engine falls back to tp=1 before it)
     for change, said in (
-            ({"speculative_k": 2}, "speculative_k=2"),
             ({"offload": True}, "offload=True"),
             ({"weight_stream": "pallas-dma", "quantize": "int8"},
              "pallas-dma")):

@@ -195,7 +195,7 @@ def test_engine_kv_quantize_generates():
     from opsagent_tpu.serving.sampler import SamplingParams
 
     eng = Engine(EngineConfig(kv_quantize="int8", **_engine_kwargs()))
-    assert eng.attn_impl == "xla"
+    assert eng.kernels.attn == "xla"
     sid = eng.begin_request(
         [5, 6, 7, 8], SamplingParams(max_tokens=6, temperature=0.0)
     )
@@ -264,30 +264,7 @@ def test_engine_rejects_bad_kv_quantize_and_mla_combo():
     kwargs = dict(_engine_kwargs(), model="tiny-mla")
     eng = Engine(EngineConfig(kv_quantize="int8", **kwargs))
     assert eng.impl_info()["kv_quantize"] == "int8"
-    assert eng.attn_impl == "xla"
-
-
-def test_engine_kv_quantize_speculative_matches_plain():
-    """Speculative decoding over the quantized cache (verify_step writes
-    and reads QuantizedPages) must emit exactly the plain quantized
-    engine's greedy tokens — speculation is exact for greedy regardless
-    of the cache's storage format."""
-    from opsagent_tpu.serving.engine import Engine, EngineConfig
-    from opsagent_tpu.serving.sampler import SamplingParams
-
-    prompt = [7, 8, 9, 7, 8, 9, 7, 8]  # repetitive: lets drafts engage
-    outs = []
-    for k in (0, 3):
-        eng = Engine(EngineConfig(
-            kv_quantize="int8", speculative_k=k, **_engine_kwargs()
-        ))
-        sid = eng.begin_request(
-            prompt, SamplingParams(max_tokens=10, temperature=0.0)
-        )
-        while not eng.sequences[sid].done:
-            eng.step_block([sid])
-        outs.append(eng.finish(sid))
-    assert outs[0] == outs[1]
+    assert eng.kernels.attn == "xla"
 
 
 def test_engine_kv_quantize_under_tp_mesh():

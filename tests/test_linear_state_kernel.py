@@ -1,7 +1,7 @@
 """The state kernel (``ops/linear_state_pallas.py``) in interpret mode on the
 CPU against ``delta_rule_chunk`` / ``delta_rule_step`` from the same slots.
 
-Float32 at this file's tolerance, which is ``tests/test_hybrid_state.py``'s:
+Float32 at this file's tolerance, which is ``tests/hybrid_state_common.py``'s:
 the kernel runs the recurrence a token at a time where the chunk form solves
 a block's triangular system, and folds ``beta`` into k and v as its square
 root, so the two agree to float32's rounding and not to the bit. A state
@@ -18,7 +18,8 @@ import pytest
 
 from opsagent_tpu.models import llama
 from opsagent_tpu.models.config import PRESETS
-from opsagent_tpu.ops import attention
+from opsagent_tpu.ops import kernels
+from opsagent_tpu.ops.kernels import Kernels
 from opsagent_tpu.ops import linear_state_pallas as lsp
 from opsagent_tpu.ops.linear_attention import delta_rule_chunk, delta_rule_step
 
@@ -34,7 +35,7 @@ def highest():
 
 @pytest.fixture(autouse=True)
 def release_compiled_programs():
-    """As ``tests/test_hybrid_state.py``'s: an interpreted kernel is a large
+    """As ``tests/hybrid_state_common.py``'s: an interpreted kernel is a large
     CPU program, and a process may hold only so many memory mappings."""
     yield
     try:
@@ -63,7 +64,7 @@ def state_kernel():
     def under():
         with pytest.MonkeyPatch.context() as mp:
             mp.setattr(
-                attention, "linear_state_backend", lambda **_: "pallas-state")
+                kernels, "linear_state_backend", lambda **_: "pallas-state")
             mp.setenv("OPSAGENT_PALLAS_INTERPRET", "1")
             yield
 
@@ -277,10 +278,10 @@ def test_a_narrower_state_is_refused():
 ])
 def test_the_choice_is_a_function_of_what_it_is_given(
         platform, dtype, dk, dv, heads, want):
-    assert attention.linear_state_backend(
+    assert kernels.linear_state_backend(
         platform=platform, state_dtype=dtype, key_dim=dk, value_dim=dv,
         heads=heads) == want
-    assert want in attention.STATE_BACKENDS
+    assert want in kernels.STATE_BACKENDS
 
 
 @pytest.mark.parametrize("preset,xla,kernel,tail", [
@@ -355,15 +356,18 @@ def test_the_step_programs_equal_the_xla_path_and_snapshot_on_a_page_boundary(
         cache = dict(cache, state=cache["state"] + 0.25)   # snapshots' canary
         _, cache = llama.mixed_step(
             p, cfg, jnp.asarray(first), jnp.zeros((3,), jnp.int32),
-            jnp.asarray([32, 20, 0]), cache, table, dtype=jnp.float32)
+            jnp.asarray([32, 20, 0]), cache, table, dtype=jnp.float32,
+            kernels=Kernels(state=impl))
         after_first = cache["state"]
         mixed, cache = llama.mixed_step(
             p, cfg, jnp.asarray(second), jnp.asarray([32, 20, 0]),
-            jnp.asarray([1, 7, 0]), cache, table, dtype=jnp.float32)
+            jnp.asarray([1, 7, 0]), cache, table, dtype=jnp.float32,
+            kernels=Kernels(state=impl))
         decoded, cache = llama.decode_step(
             p, cfg, jnp.asarray([int(toks[0, 33]), int(toks[1, 27]), 0]),
             jnp.asarray([33, 27, 0]), cache, table,
-            jnp.asarray([True, True, False]), dtype=jnp.float32)
+            jnp.asarray([True, True, False]), dtype=jnp.float32,
+            kernels=Kernels(state=impl))
 
         def slots(state):       # [layers, slots, H, dk, dv] whatever is held
             n, s = state.shape[:2]

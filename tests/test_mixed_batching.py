@@ -329,7 +329,7 @@ def test_mixed_readers_byte_identical_and_int8_kv_compiles_nothing(
 
     def run(impl, **kw):
         eng = Engine(EngineConfig(mixed_batching=True, **kw, **BASE))
-        assert eng.attn_impl == impl
+        assert eng.kernels.attn == impl
         eng.warmup("sessions")
         sampling = SamplingParams(max_tokens=8)
         n0 = len(_COMPILES)

@@ -5,7 +5,7 @@ is the token read, in either form, through every reader; that a tp shard
 of merged pages is whole heads; and that pages leave the device (host
 tier, snapshot manifest, fleet transfer records) in the one split format
 whatever is held. The tile arithmetic that chooses a form is the
-compiler's (tests/test_tpu_compile.py)."""
+compiler's (tests/test_tpu_compile_steps.py)."""
 
 import dataclasses
 

@@ -546,12 +546,12 @@ def test_stream_decode_form_matches_the_decode_oracle():
 
 def test_stream_refuses_int8_pages_by_name():
     """No cell holds int8 pages; the kernel has no reader for them (a
-    16-token page is half an int8 tile) and says so, and the choice
-    function sends such an engine to the gather."""
+    16-token page is half an int8 tile) and says so (the choice function
+    sends such an engine to the gather: tests/test_kernels.py)."""
     from opsagent_tpu.ops.attention import (
-        QuantizedPages, paged_attention_backend, paged_ragged_attention_auto,
-        pallas_refusal,
+        QuantizedPages, paged_ragged_attention_auto,
     )
+    from opsagent_tpu.ops.kernels import pallas_refusal
 
     q = jnp.zeros((1, 1, 8, SD), jnp.float32)
     pages = QuantizedPages(
@@ -569,9 +569,6 @@ def test_stream_refuses_int8_pages_by_name():
         "pallas-stream", page_itemsize=1, **shapes
     )
     assert pallas_refusal("pallas-stream", page_itemsize=2, **shapes) is None
-    assert paged_attention_backend(
-        platform="tpu", page_itemsize=1, **shapes
-    ) == "xla"
 
 
 def test_stream_refuses_split_pages():

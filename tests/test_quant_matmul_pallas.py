@@ -231,7 +231,7 @@ def _run_mixed(eng, level):
     outs = [eng.finish(s) for s in sids]
     assert len(_COMPILES) == n0, (
         f"{len(_COMPILES) - n0} post-warmup compiles with "
-        f"weight_stream={eng.weight_stream_impl}"
+        f"weight_stream={eng.kernels.weights}"
     )
     return outs
 
@@ -257,7 +257,7 @@ def test_engine_weight_streams_byte_identical(monkeypatch, quant, level):
         eng = Engine(EngineConfig(
             quantize=quant, weight_stream=ws, **ENGINE_BASE
         ))
-        assert eng.weight_stream_impl == ws
+        assert eng.kernels.weights == ws
         assert eng.impl_info()["weight_stream"] == ws
         outs[ws] = _run_mixed(eng, level)
     assert outs["xla"] == outs["pallas-dma"], outs
@@ -271,7 +271,7 @@ def test_engine_weight_stream_env_knob(monkeypatch):
     monkeypatch.setenv("OPSAGENT_PALLAS_INTERPRET", "1")
     monkeypatch.setenv("OPSAGENT_WEIGHT_STREAM", "pallas-dma")
     eng = Engine(EngineConfig(quantize="int8", **ENGINE_BASE))
-    assert eng.weight_stream_impl == "pallas-dma"
+    assert eng.kernels.weights == "pallas-dma"
 
 
 def test_engine_refuses_weight_stream_without_quantized_weights():

@@ -15,7 +15,8 @@ import pytest
 
 from opsagent_tpu.models import llama
 from opsagent_tpu.models.config import PRESETS
-from opsagent_tpu.ops import attention
+from opsagent_tpu.ops import kernels
+from opsagent_tpu.ops.kernels import Kernels
 from opsagent_tpu.ops import selective_scan_pallas as ssp
 from opsagent_tpu.ops.linear_state_pallas import conv_slot_shape
 from opsagent_tpu.ops.selective_scan import selective_scan
@@ -33,7 +34,7 @@ def highest():
 
 @pytest.fixture(autouse=True)
 def release_compiled_programs():
-    """As ``tests/test_hybrid_state.py``'s: an interpreted kernel is a large
+    """As ``tests/hybrid_state_common.py``'s: an interpreted kernel is a large
     CPU program, and a process may hold only so many memory mappings."""
     yield
     try:
@@ -62,7 +63,7 @@ def ssm_kernel():
     def under():
         with pytest.MonkeyPatch.context() as mp:
             mp.setattr(
-                attention, "ssm_state_backend", lambda **_: "pallas-ssm")
+                kernels, "ssm_state_backend", lambda **_: "pallas-ssm")
             mp.setenv("OPSAGENT_PALLAS_INTERPRET", "1")
             yield
 
@@ -144,14 +145,9 @@ def test_the_blocks_of_the_cells_shapes():
     assert ssp.block_channels(5120, 16) == 5120
     assert ssp.block_channels(5120, 8) == 5120
     assert ssp.block_channels(5120, 256) == 1280
-    assert attention.ssm_state_backend(
+    assert kernels.ssm_state_backend(
         platform="tpu", state_dtype="float32", d_state=16, d_inner=5120
-    ) == "pallas-ssm"
-    for other in (dict(platform="cpu"), dict(state_dtype="bfloat16"),
-                  dict(d_inner=5000), dict(d_state=12)):
-        assert attention.ssm_state_backend(**{**dict(
-            platform="tpu", state_dtype="float32", d_state=16,
-            d_inner=5120), **other}) == "xla"
+    ) == "pallas-ssm"   # (each reason it is not: tests/test_kernels.py)
 
 
 # -- inside the model --------------------------------------------------------------
@@ -180,10 +176,12 @@ def test_a_mixed_step_under_the_kernel_is_the_step_under_xla(monkeypatch):
         assert cache["conv"].ndim == (4 if impl == "pallas-ssm" else 3)
         _, cache = llama.mixed_step(
             params, CFG, first, jnp.zeros((3,), jnp.int32),
-            jnp.asarray([16, 9, 0]), cache, table, dtype=jnp.float32)
+            jnp.asarray([16, 9, 0]), cache, table, dtype=jnp.float32,
+            kernels=Kernels(state=impl))
         logits, cache = llama.mixed_step(
             params, CFG, second, jnp.asarray([16, 9, 0]),
-            jnp.asarray([1, 7, 0]), cache, table, dtype=jnp.float32)
+            jnp.asarray([1, 7, 0]), cache, table, dtype=jnp.float32,
+            kernels=Kernels(state=impl))
         got[impl] = (logits[:2], cache)
     (want, a), (have, b) = got["xla"], got["pallas-ssm"]
     assert float(jnp.max(jnp.abs(want - have))) < TOL
